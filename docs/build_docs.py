@@ -11,7 +11,6 @@ Or: make -C docs html
 from __future__ import annotations
 
 import os
-import re
 import sys
 
 try:
@@ -43,16 +42,9 @@ PAGES = [("index", os.path.join(ROOT, "README.md"), "Overview"),
          ("analysis", os.path.join(DOCS, "analysis.md"),
           "fflint static analysis (strategy passes + ffsan "
           "concurrency/trace-stability passes & runtime sanitizer)"),
-         ("install", os.path.join(ROOT, "INSTALL.md"), "Install")]
-# every round-notes file, newest first (numeric: round10 > round9)
-_rounds = []
-for fn in os.listdir(DOCS):
-    m = re.match(r"round(\d+)_notes\.md$", fn)
-    if m:
-        _rounds.append((int(m.group(1)), fn))
-for n_round, fn in sorted(_rounds, reverse=True):
-    PAGES.append((f"round{n_round}", os.path.join(DOCS, fn),
-                  f"Round {n_round} notes"))
+         ("install", os.path.join(ROOT, "INSTALL.md"), "Install"),
+         ("perf", os.path.join(ROOT, "PERF.md"),
+          "PERF.md — what was measured on the chip, and how")]
 
 TEMPLATE = """<!DOCTYPE html>
 <html><head><meta charset="utf-8"><title>{title} — flexflow_tpu</title>

@@ -15,15 +15,6 @@ Public API mirrors the reference's FFModel surface
 
 import os as _os
 
-# sharding-invariant RNG: without it, old-jax GSPMD generates different
-# random bits for dim-0-sharded weight inits (see _env docstring) — a
-# CONTRACT/FSDP model then trains from DIFFERENT initial weights than its
-# replicated twin. Must precede any traced jax.random use in the package.
-from flexflow_tpu._env import \
-    enable_sharding_invariant_rng as _enable_invariant_rng
-
-_enable_invariant_rng()
-
 if _os.environ.get("FLEXFLOW_FORCE_CPU_DEVICES"):
     # FLEXFLOW_FORCE_CPU_DEVICES=N provisions an N-device virtual CPU
     # platform, provided flexflow_tpu is imported before any jax use (the

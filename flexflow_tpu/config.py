@@ -278,8 +278,9 @@ class FFConfig:
     #              search/kernel_tune.py tune_paged_attention for this
     #              engine's exact shape+dtype overrides the backend
     #              default (measured costs beat heuristics)
-    #   "pallas" — force the kernel everywhere (interpret mode off-TPU,
-    #              so CPU CI executes the real kernel code path)
+    #   "pallas" — force the kernel everywhere (off-TPU that needs
+    #              FF_PALLAS_INTERPRET=1, which the CPU suite and CI set
+    #              to execute the real kernel code path)
     #   "einsum" — force the page-gather oracle (bitwise the dense-cache
     #              attention) — the parity baseline
     # Greedy serving streams are token-identical under either impl
@@ -344,10 +345,6 @@ class FFConfig:
     # tenants share a replica with zero recompiles.
     serve_adapter_pool_pages: int = 0
     serve_lora_rank: int = 8
-    # jax persistent compilation cache directory ("" = off): set before
-    # the first trace (FFModel.compile / launcher) so repeated runs skip
-    # recompiles; serving logs hit/miss per program build
-    compilation_cache_dir: str = ""
     # ---- unified telemetry plane (runtime/telemetry.py, ISSUE 13) ----
     # "on" (default): the metrics registry records counters/histograms
     # and the trace ring records per-request / per-step spans — the

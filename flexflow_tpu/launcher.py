@@ -54,15 +54,8 @@ def _retried_initialize(jax):
     init = jax.distributed.initialize
     timeout = os.environ.get("FF_INIT_TIMEOUT_S", "")
     if timeout:
-        import inspect
-
-        try:  # only pass the kwarg where this jax build accepts it
-            if "initialization_timeout" in \
-                    inspect.signature(init).parameters:
-                init = functools.partial(
-                    init, initialization_timeout=int(float(timeout)))
-        except (TypeError, ValueError):
-            pass
+        init = functools.partial(
+            init, initialization_timeout=int(float(timeout)))
     return retry(attempts=int(os.environ.get("FF_INIT_ATTEMPTS", "3")),
                  base_delay=float(os.environ.get("FF_INIT_DELAY_S", "2")),
                  max_delay=30.0, retryable=(RuntimeError, OSError),
@@ -174,17 +167,10 @@ def main(argv=None):
         args.cpu_devices = int(plan.last_value)
 
     if args.cpu_devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.cpu_devices}")
         import jax
 
         from flexflow_tpu._env import force_cpu_devices
 
-        # _env handles the jax-version skew: jax_num_cpu_devices where the
-        # build has it, the XLA_FLAGS device-count fallback otherwise
-        # (0.4.37) — an unguarded config.update here killed every worker
-        # at startup on the older builds
         force_cpu_devices(args.cpu_devices)
         if args.num_processes and args.num_processes > 1:
             # CPU cross-process collectives need an explicit backend
@@ -277,14 +263,14 @@ def main(argv=None):
                   f"({type(e).__name__}: {e}) — continuing SINGLE-process",
                   file=sys.stderr)
 
-    cache_dir = os.environ.get("FF_COMPILATION_CACHE_DIR", "")
-    if cache_dir:
-        # persistent compilation cache for the launched script: enabled
-        # HERE, before the script's first trace, so even programs built
-        # ahead of FFModel.compile() (warmup probes, custom jits) hit it
-        from flexflow_tpu._env import enable_compilation_cache
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # the launched script's persistent compilation cache is placed
+        # from outside; checked HERE, before the script's first trace, so
+        # an unusable directory stops the launch instead of costing every
+        # compile
+        from flexflow_tpu._env import resolve_compilation_cache
 
-        enable_compilation_cache(cache_dir)
+        resolve_compilation_cache()
 
     sys.argv = [args.script] + rest
     runpy.run_path(args.script, run_name="__main__")

@@ -491,19 +491,6 @@ class FFModel:
             from flexflow_tpu.runtime.elastic import apply_elastic_policy
 
             self._elastic = apply_elastic_policy(self)
-        if cfg.compilation_cache_dir:
-            # persistent compilation cache: must be on BEFORE the first
-            # trace so the train/serve programs are covered; repeated runs
-            # then load executables instead of recompiling
-            from flexflow_tpu._env import (compilation_cache_entries,
-                                           enable_compilation_cache)
-            from flexflow_tpu.logger import fflogger
-
-            if enable_compilation_cache(cfg.compilation_cache_dir):
-                fflogger.info(
-                    "persistent compilation cache: %s (%d entries)",
-                    cfg.compilation_cache_dir,
-                    compilation_cache_entries(cfg.compilation_cache_dir))
         self.mesh = make_mesh(cfg.mesh_shape)
 
         if cfg.search_budget > 0:

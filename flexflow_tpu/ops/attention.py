@@ -45,9 +45,9 @@ def resolve_paged_attention_impl(impl=None, config=None) -> str:
 
       * ``pallas`` — the paged-attention kernel (ops/pallas_kernels.py
         paged_attention_fwd_pallas): page-table lookup inside the grid,
-        only a slot's live pages stream through VMEM. Off-TPU it runs in
-        interpret mode, so forcing it executes the REAL kernel code path
-        in every CPU CI tier.
+        only a slot's live pages stream through VMEM. Off-TPU it runs
+        only in interpret mode, which the CPU test suite and CI tiers ask
+        for (FF_PALLAS_INTERPRET=1) to execute the REAL kernel code path.
       * ``einsum`` — the page-gather + grouped einsum path, bitwise the
         dense-cache attention: the parity oracle, and the default where
         no native Mosaic backend exists.
@@ -818,7 +818,7 @@ class MultiHeadAttention(Op):
         mesh = (shard_ctx or {}).get("mesh")
         if mesh is None:
             return flash_attention(qh, kh, vh, self.causal, scale)
-        from flexflow_tpu.parallel import shard_entries, shard_map_compat
+        from flexflow_tpu.parallel import shard_entries
 
         axis_map = (shard_ctx or {}).get("axis_map") or {}
         # indivisible groups drop out alone (GSPMD pads that dim instead),
@@ -832,8 +832,8 @@ class MultiHeadAttention(Op):
         def inner(q, k, v):
             return flash_attention(q, k, v, self.causal, scale)
 
-        return shard_map_compat(inner, mesh, (spec, spec, spec), spec)(
-            qh, kh, vh)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(qh, kh, vh)
 
     def _sp_attention(self, qh, kh, vh, shard_ctx, seq_axes, scale,
                       training=False, rng=None):
@@ -844,7 +844,6 @@ class MultiHeadAttention(Op):
         semantics)."""
         from jax.sharding import PartitionSpec as P
 
-        from flexflow_tpu.parallel import shard_map_compat
         from flexflow_tpu.parallel.ring_attention import (ring_attention,
                                                           ulysses_attention)
 
@@ -875,15 +874,16 @@ class MultiHeadAttention(Op):
                           dropout_rng=key)
 
             key_spec = P(*([None] * jnp.asarray(rng).ndim))
-            return shard_map_compat(inner, mesh, (spec, spec, spec, key_spec),
-                                    spec)(qh, kh, vh, rng)
+            return jax.shard_map(
+                inner, mesh=mesh, in_specs=(spec, spec, spec, key_spec),
+                out_specs=spec, check_vma=False)(qh, kh, vh, rng)
 
         def inner(q, k, v):
             return fn(q, k, v, axis_name=seq_axis, causal=self.causal,
                       scale=scale)
 
-        return shard_map_compat(inner, mesh, (spec, spec, spec), spec)(
-            qh, kh, vh)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(qh, kh, vh)
 
     _contracted_output_dims = (2,)  # hidden dim comes from the wo contraction
 

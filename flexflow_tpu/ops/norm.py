@@ -8,11 +8,28 @@ primitives); first-class here because every modern transformer needs them.
 
 from __future__ import annotations
 
+import math
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import Op, WeightSpec
+
+
+def fused_add_ln_refusal(rows: int, dim: int, dtype) -> Optional[str]:
+    """Why the fused add+layernorm kernel cannot take a (rows, dim) input,
+    or None when it can. The one eligibility rule AddLayerNorm and the
+    chip_smoke.py kernel sweep both ask."""
+    from flexflow_tpu.ops.pallas_kernels import add_ln_block_rows
+
+    if dim % 128 != 0:
+        return f"hidden {dim} is not a multiple of the 128-lane tile"
+    if not add_ln_block_rows(rows, dim, dtype):
+        return (f"no row block of a ({rows}, {dim}) "
+                f"{jnp.dtype(dtype).name} input fits VMEM")
+    return None
 
 
 class Softmax(Op):
@@ -133,25 +150,47 @@ class AddLayerNorm(Op):
         return [WeightSpec("scale", (self.dim,), init="one"),
                 WeightSpec("bias", (self.dim,), init="zero")]
 
-    def _fused_ok(self) -> bool:
-        """Kernel eligibility, mirroring attention._flash_ok: lane-aligned
-        hidden dim, kill switch (FF_FUSED_LN_DISABLE=1) for deployments
-        whose Mosaic build rejects a shape — ineligible shapes fall back to
-        the plain-JAX branch, never fail to compile."""
+    def _fused_ok(self, rows: int, dtype) -> bool:
+        """Whether this call runs the Pallas kernel: on a TPU backend (or
+        when tests force kernels with FF_FORCE_FLASH_ATTENTION=1) and the
+        per-shard (rows, dim) input is one the kernel takes. A shape the
+        kernel refuses is logged with its reason and runs the plain-JAX
+        branch — decided here at trace time, never a Mosaic failure."""
         import os
 
-        if os.environ.get("FF_FUSED_LN_DISABLE") == "1":
+        if not (jax.default_backend() == "tpu"
+                or os.environ.get("FF_FORCE_FLASH_ATTENTION") == "1"):
             return False
-        if self.dim % 128 != 0:
-            return False
-        return (jax.default_backend() == "tpu"
-                or os.environ.get("FF_FORCE_FLASH_ATTENTION") == "1")
+        reason = fused_add_ln_refusal(rows, self.dim, dtype)
+        if reason is not None:
+            from flexflow_tpu.logger import fflogger
+
+            fflogger.warning("%s: fused add+layernorm kernel refused (%s); "
+                             "running the unfused ops", self.name, reason)
+        return reason is None
 
     def forward(self, params, xs, *, training=False, rng=None,
                 shard_ctx=None):
         x, r = xs[0], xs[1]
         scale, bias = params["scale"], params["bias"]
-        if self._fused_ok():
+        # a pallas_call is a Mosaic custom call GSPMD cannot partition:
+        # under a sharded strategy the kernel runs per-shard inside
+        # shard_map over whichever sharded non-last dims divide evenly
+        # (same pattern as attention._flash_dense); the op is row-wise,
+        # so shards need no collectives
+        mesh = (shard_ctx or {}).get("mesh")
+        entries = [None] * (x.ndim - 1)
+        if mesh is not None:
+            from flexflow_tpu.parallel import shard_entries
+
+            axis_map = (shard_ctx or {}).get("axis_map") or {}
+            ent = shard_entries(mesh, axis_map, x.shape, range(x.ndim - 1))
+            entries = [ent[d] for d in range(x.ndim - 1)]
+        axes = [ax for e in entries if e
+                for ax in (e if isinstance(e, tuple) else (e,))]
+        rows = (math.prod(x.shape[:-1])
+                // math.prod(mesh.shape[ax] for ax in axes))
+        if self._fused_ok(rows, x.dtype):
             from flexflow_tpu.ops.pallas_kernels import fused_add_layernorm
 
             def run(x_, r_, scale_, bias_):
@@ -161,29 +200,16 @@ class AddLayerNorm(Op):
                     scale_, bias_, self.eps)
                 return s2.reshape(shape), y2.reshape(shape)
 
-            # a pallas_call is a Mosaic custom call GSPMD cannot partition:
-            # under a sharded strategy run the kernel per-shard inside
-            # shard_map over whichever sharded non-last dims divide evenly
-            # (same pattern as attention._flash_dense); the op is row-wise,
-            # so shards need no collectives
-            mesh = (shard_ctx or {}).get("mesh")
-            if mesh is not None:
+            if any(e is not None for e in entries):
                 from jax.sharding import PartitionSpec as P
 
-                from flexflow_tpu.parallel import (shard_entries,
-                                                   shard_map_compat)
-
-                axis_map = (shard_ctx or {}).get("axis_map") or {}
-                ent = shard_entries(mesh, axis_map, x.shape,
-                                    range(x.ndim - 1))
-                entries = [ent[d] for d in range(x.ndim - 1)]
-                if any(e is not None for e in entries):
-                    spec = P(*entries, None)
-                    w_spec = P(None)
-                    s2, y2 = shard_map_compat(
-                        run, mesh, (spec, spec, w_spec, w_spec),
-                        (spec, spec))(x, r, scale, bias)
-                    return [s2, y2]
+                spec = P(*entries, None)
+                w_spec = P(None)
+                s2, y2 = jax.shard_map(
+                    run, mesh=mesh, in_specs=(spec, spec, w_spec, w_spec),
+                    out_specs=(spec, spec), check_vma=False)(
+                        x, r, scale, bias)
+                return [s2, y2]
             s2, y2 = run(x, r, scale, bias)
             return [s2, y2]
         s = x + r

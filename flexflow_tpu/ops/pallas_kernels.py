@@ -16,13 +16,15 @@ sequential inner grid axis) carry the online-softmax / gradient state.
 VMEM use is therefore O(block^2) regardless of sequence length — the 4k
 sequence cap of the staged round-2 kernels is gone.
 
-On CPU (tests/emulated meshes) kernels run with interpret=True.
+Kernels compile through Mosaic; interpret mode is an explicit request
+(FF_PALLAS_INTERPRET=1, see _interpret).
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import os
 from typing import Optional
 
 import jax
@@ -36,24 +38,25 @@ LANES = 128
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; resolve
-# whichever this build ships (interpret mode never constructs one, which is
-# why the old hard reference compiled everywhere CI runs but would have
-# broken on a real-TPU 0.4.37 build)
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
+    """Interpret mode is asked for, never fallen into: the CPU test suite
+    (tests/conftest.py), the CPU CI tiers and `chip_smoke.py
+    --cpu-rehearsal` set FF_PALLAS_INTERPRET=1. Everywhere else kernels
+    compile through Mosaic, so a process that lands on a backend without
+    it fails at its first pallas_call instead of quietly interpreting."""
+    if os.environ.get("FF_PALLAS_INTERPRET") != "1":
+        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "FF_PALLAS_INTERPRET=1 on a TPU backend: interpret mode is "
+            "for CPU tests and rehearsals; unset it to compile the kernels")
+    return True
 
 
 def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
     """Outer grid axes are parallel (independent (bh, own-block) tiles); the
     innermost axis streams opposing-side tiles and must run sequentially —
     the scratch accumulators carry state across it."""
-    if _interpret() or _COMPILER_PARAMS_CLS is None:
-        return None
-    return _COMPILER_PARAMS_CLS(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def _pick_block(seq: int, want: int) -> int:
@@ -458,8 +461,28 @@ def _add_ln_fwd_kernel(x_ref, r_ref, scale_ref, bias_ref, s_ref, y_ref,
         rstd_ref[...] = jnp.broadcast_to(rstd, (bn, 8))
 
 
+# Mosaic's scoped-VMEM stack is 16 MiB on v5e; the add+LN row block is
+# sized against half of it, leaving the rest to the compiler's own temps.
+_ADD_LN_VMEM_BUDGET = 8 << 20
+
+
+def add_ln_block_rows(n: int, d: int, dtype) -> int:
+    """Rows per grid step of the fused add+LN kernel for an (n, d) input,
+    or 0 when no legal block fits VMEM — the eligibility gate
+    (ops/norm.py AddLayerNorm) declines on 0, so an oversized row is a
+    named refusal at graph build and never a Mosaic allocation failure.
+    Per row the pipeline holds the x, r, s, y tiles double-buffered plus
+    about three live f32 temporaries of the row."""
+    per_row = d * (4 * 2 * jnp.dtype(dtype).itemsize + 3 * 4)
+    cap = _ADD_LN_VMEM_BUDGET // per_row
+    fits = [b for b in (256, 128, 64, 32, 16, 8) if b <= cap]
+    if not fits:
+        return 0
+    block = _pick_block(n, fits[0])
+    return block if block <= cap else 0
+
+
 def fused_add_layernorm_fwd_pallas(x, r, scale, bias, eps: float,
-                                   block_n: int = 256,
                                    need_stats: bool = True):
     """(N, D) x + r -> (s, ln(s)) in ONE HBM pass (the unfused graph writes
     s, re-reads it for the norm, and re-reads it again on the next block's
@@ -467,7 +490,11 @@ def fused_add_layernorm_fwd_pallas(x, r, scale, bias, eps: float,
     materializing the (N, 8) mean/rstd residuals, which exist only for the
     VJP — same pattern as the flash kernel's need_lse."""
     n, d = x.shape
-    block_n = _pick_block(n, block_n)
+    block_n = add_ln_block_rows(n, d, x.dtype)
+    if not block_n:
+        raise ValueError(
+            f"fused add+layernorm: no row block of a ({n}, {d}) "
+            f"{jnp.dtype(x.dtype).name} input fits VMEM")
     grid = (n // block_n,)
     scale2 = scale.reshape(1, d)
     bias2 = bias.reshape(1, d)
@@ -636,12 +663,15 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
         # position i attends at its OWN frontier wp[b, i], which gives
         # in-slab causality for the verify slab (position i's window
         # holds exactly the slab writes <= i plus committed history)
-        rows = []
-        for i in range(s):
-            j = t * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            live = (j < rl) | ((j >= pp) & (j <= wp_ref[b, i]))
-            rows.append(jnp.broadcast_to(live, (grp, ps)))
-        live = jnp.concatenate(rows, axis=0)        # (S*G, ps)
+        # The per-row frontier is selected into an int32 (S*G, ps) tile
+        # from the SMEM scalars: Mosaic has no register cast for
+        # concatenated i1 rows, so the mask is compared once, whole.
+        j = t * ps + jax.lax.broadcasted_iota(jnp.int32, (s * grp, ps), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (s * grp, ps), 0)
+        wp = jnp.full((s * grp, ps), wp_ref[b, 0], jnp.int32)
+        for i in range(1, s):
+            wp = jnp.where(row >= i * grp, wp_ref[b, i], wp)
+        live = (j < rl) | ((j >= pp) & (j <= wp))   # (S*G, ps)
         for kh in range(kvh):
             sl = slice(kh * s * grp, (kh + 1) * s * grp)
             qk = q[:, kh * grp:(kh + 1) * grp, :].reshape(s * grp, -1)
@@ -688,8 +718,7 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
 
 def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
                                row_len, prompt_pad, scale: float,
-                               k_scales=None, v_scales=None,
-                               interpret: Optional[bool] = None):
+                               k_scales=None, v_scales=None):
     """Paged-pool attention: q (B, S, H, Dqk) against k_pages/v_pages
     ((P_pool, page_size, KVH, D)) through per-slot page tables
     ((B, pages_per_slot) int32) -> (B, S, H, Dv) context.
@@ -709,11 +738,7 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     scale before the score/context matmuls — per-page HBM traffic is
     the quantized bytes, and the full-width KV is never materialized
     anywhere. The einsum page-gather path applies the same dequant
-    after its gather, staying the parity oracle.
-
-    `interpret` defaults to the module rule (interpret off-TPU), which
-    is how FFConfig.paged_attention_impl='pallas' executes the REAL
-    kernel code path in every CPU CI tier."""
+    after its gather, staying the parity oracle."""
     b, s, h, dqk = q.shape
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[3]
@@ -772,12 +797,11 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, h, dv), q.dtype),
         compiler_params=_compiler_params(("parallel", "arbitrary")),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=_interpret(),
     )(*prefetch, q, k_pages, v_pages)
 
 
-def paged_prefill_write_pallas(cache, kh, vh, pages,
-                               interpret: Optional[bool] = None):
+def paged_prefill_write_pallas(cache, kh, vh, pages):
     """Prefill/append page scatter: write a (1, S, KVH, D) KV slab into
     the paged pool page-at-a-time from VMEM (ISSUE 18 tentpole (c)).
 
@@ -801,12 +825,10 @@ def paged_prefill_write_pallas(cache, kh, vh, pages,
     Quantized pools recompute attention.page_scale / page_quantize
     inside the kernel via the imported helpers themselves — elementwise
     f32 ops, so interpret mode is BITWISE against the einsum oracle and
-    the PR 11 published-state contract (scales + payload) holds.
-
-    `interpret` defaults to the module rule (interpret off-TPU), which
-    is how FFConfig.paged_attention_impl='pallas' executes the real
-    kernel code path in every CPU CI tier. Returns a new cache dict
-    with the k/v pools (and scales) replaced."""
+    the PR 11 published-state contract (scales + payload) holds; compiled
+    natively, a payload element may sit one quantization step from the
+    oracle's where the two f32 divisions differ in the last place.
+    Returns a new cache dict with the k/v pools (and scales) replaced."""
     from flexflow_tpu.ops.attention import (page_quantize, page_scale,
                                             storage_qmax)
 
@@ -838,7 +860,7 @@ def paged_prefill_write_pallas(cache, kh, vh, pages,
         return (pages_ref[t], 0, 0, 0)
 
     def scale_map(t, pages_ref):
-        return (pages_ref[t], 0)
+        return (pages_ref[t], 0, 0)
 
     def kernel(pages_ref, *refs):
         if quantized:
@@ -849,7 +871,7 @@ def paged_prefill_write_pallas(cache, kh, vh, pages,
                 pf = x_ref[...].astype(jnp.float32)   # (1, ps, kvh, d)
                 scale = page_scale(pf, qmax)          # (1, kvh)
                 p_out[...] = page_quantize(pf, scale, qmax, p_out.dtype)
-                s_out[...] = scale
+                s_out[...] = scale[:, None, :]
         else:
             kp_ref, vp_ref, _pk, _pv, pk_out, pv_out = refs
             pk_out[...] = kp_ref[...].astype(pk_out.dtype)
@@ -872,11 +894,16 @@ def paged_prefill_write_pallas(cache, kh, vh, pages,
     # positional in out_shape
     aliases = {3: 0, 4: 1}
     if quantized:
-        ksc, vsc = cache["k_scale"], cache["v_scale"]
-        in_specs += [pl.BlockSpec((1, kvh), scale_map),
-                     pl.BlockSpec((1, kvh), scale_map)]
-        out_specs += [pl.BlockSpec((1, kvh), scale_map),
-                      pl.BlockSpec((1, kvh), scale_map)]
+        # the (P_pool, KVH) scale planes ride as (P_pool, 1, KVH): a
+        # one-page block's last two dims then EQUAL the array's, which is
+        # the only form Mosaic's (8, 128) block rule accepts for a row
+        # this narrow
+        ksc = cache["k_scale"][:, None, :]
+        vsc = cache["v_scale"][:, None, :]
+        in_specs += [pl.BlockSpec((1, 1, kvh), scale_map),
+                     pl.BlockSpec((1, 1, kvh), scale_map)]
+        out_specs += [pl.BlockSpec((1, 1, kvh), scale_map),
+                      pl.BlockSpec((1, 1, kvh), scale_map)]
         out_shape += [jax.ShapeDtypeStruct(ksc.shape, ksc.dtype),
                       jax.ShapeDtypeStruct(vsc.shape, vsc.dtype)]
         inputs += [ksc, vsc]
@@ -894,10 +921,10 @@ def paged_prefill_write_pallas(cache, kh, vh, pages,
         out_shape=out_shape,
         input_output_aliases=aliases,
         compiler_params=_compiler_params(("arbitrary",)),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=_interpret(),
     )(pages, *inputs)
     out = dict(cache)
     out["k"], out["v"] = outs[0], outs[1]
     if quantized:
-        out["k_scale"], out["v_scale"] = outs[2], outs[3]
+        out["k_scale"], out["v_scale"] = outs[2][:, 0], outs[3][:, 0]
     return out

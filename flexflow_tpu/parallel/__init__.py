@@ -24,19 +24,3 @@ def shard_entries(mesh, axis_map, shape, dims):
             out[d] = axes[0] if len(axes) == 1 else tuple(axes)
     return out
 
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: new jax.shard_map takes check_vma,
-    older jax.experimental.shard_map takes check_rep."""
-    import jax as _jax
-
-    if hasattr(_jax, "shard_map"):
-        return _jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    try:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except TypeError:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)

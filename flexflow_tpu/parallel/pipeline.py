@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from flexflow_tpu._env import lax_axis_size
 
 
 def gpipe_loop(stage_fn: Callable, stage_params, x_mb, axis_name: str):
@@ -40,7 +39,7 @@ def gpipe_loop(stage_fn: Callable, stage_params, x_mb, axis_name: str):
     microbatched input (replicated; only stage 0 reads it). Returns
     (num_micro, mb, ...) outputs (valid on the LAST stage; use
     `pipeline()` below for the replicated gather)."""
-    n_stage = lax_axis_size(axis_name)
+    n_stage = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     num_micro = x_mb.shape[0]
     steps = num_micro + n_stage - 1
@@ -102,14 +101,13 @@ def pipeline(stage_fn: Callable, stacked_params, x, mesh, axis_name: str = "pipe
         contrib = jnp.where(idx == n_stage - 1, outs, jnp.zeros_like(outs))
         return lax.psum(contrib, axis_name)
 
-    from flexflow_tpu.parallel import shard_map_compat
-
     dp = (data_axis if data_axis and mesh.shape.get(data_axis, 1) > 1
           else None)
     pspec = jax.tree_util.tree_map(
         lambda a: P(axis_name, *([None] * (a.ndim - 1))), stacked_params)
     xspec = P(None, dp) if dp else P()
-    out = shard_map_compat(inner, mesh, (pspec, xspec), xspec)(
+    out = jax.shard_map(inner, mesh=mesh, in_specs=(pspec, xspec),
+                        out_specs=xspec, check_vma=False)(
         stacked_params, x_mb)
     return out.reshape(b, *out.shape[2:])
 
@@ -128,7 +126,7 @@ def _1f1b_loop(stage_fn, loss_fn, params, x_mb, lab_mb, head_params,
     S = min(m, 2n-1) slots is aliasing-safe: a live F(j) and live B(j')
     share a slot only if j - j' is a positive multiple of S, impossible
     with both live (j - j' < m <= S or masked)."""
-    n = lax_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = x_mb.shape[0]
     S = min(m, 2 * n - 1)
@@ -269,15 +267,14 @@ def pipeline_train_1f1b(stage_fn: Callable, loss_fn: Callable,
             dx = dx / nd
         return g, gh, dx, loss
 
-    from flexflow_tpu.parallel import shard_map_compat
     pspec = jax.tree_util.tree_map(
         lambda a: P(axis_name, *([None] * (a.ndim - 1))), stacked_params)
     hspec = jax.tree_util.tree_map(lambda a: P(*([None] * a.ndim)),
                                    head_params)
     xspec = P(None, dp) if dp else P()
-    g, gh, dx, loss = shard_map_compat(
-        inner, mesh, (pspec, xspec, xspec, hspec),
-        (pspec, hspec, xspec, P()))(stacked_params, x_mb, lab_mb,
-                                    head_params)
+    g, gh, dx, loss = jax.shard_map(
+        inner, mesh=mesh, in_specs=(pspec, xspec, xspec, hspec),
+        out_specs=(pspec, hspec, xspec, P()), check_vma=False)(
+            stacked_params, x_mb, lab_mb, head_params)
     return (loss, g, gh,
             dx.reshape(b, *dx.shape[2:]))

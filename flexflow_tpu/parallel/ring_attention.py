@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from flexflow_tpu._env import lax_axis_size
 
 NEG_INF = -1e30
 
@@ -111,7 +110,7 @@ def ring_attention_flash(q, k, v, axis_name: str, causal: bool = False,
 
 
 def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale):
-    p_size = lax_axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -160,7 +159,7 @@ def _ring_flash_bwd(axis_name, causal, scale, res, do):
     from flexflow_tpu.ops.pallas_kernels import flash_attention_bwd_pallas
 
     q, k, v, o, lse = res
-    p_size = lax_axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -253,7 +252,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
                           or max(q.shape[1], k.shape[1]) <= cap))
     if use_flash:
         return ring_attention_flash(q, k, v, axis_name, causal, scale)
-    p_size = lax_axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -306,7 +305,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     """Ulysses (DeepSpeed-style) SP inside shard_map: all-to-all swaps the
     sequence shard for a head shard, attention runs with full sequence on
     1/P of the heads, then swaps back. Requires num_heads % P == 0."""
-    p_size = lax_axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     b, sq, h, d = q.shape
     assert h % p_size == 0, f"heads {h} not divisible by seq-parallel {p_size}"
 

@@ -20,24 +20,25 @@ import numpy as np
 
 from flexflow_tpu.runtime import locks
 
-_CSRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "csrc")
-_LIB_PATH = os.path.join(_CSRC, "libffdl.so")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "csrc",
+                    "dataloader.cc")
 _lib = None
 _lib_lock = locks.make_lock("native-loader")
 
 
 def load_lib():
-    """Compile (if stale) and load libffdl.so; returns None when no g++.
+    """Build (keyed by the hash of dataloader.cc) and load the native
+    loader; returns None, with the reason logged, when it cannot be built.
     Failures are cached (sentinel False) so fit() doesn't re-spawn g++ every
-    call; the build goes to a temp file + os.rename so concurrent processes
-    sharing the package dir never dlopen a half-written .so."""
+    call."""
     global _lib
     with _lib_lock:
         if _lib is False:
             return None
         if _lib is not None:
             return _lib
-        src = os.path.join(_CSRC, "dataloader.cc")
+        from flexflow_tpu._native import build_native_lib
+        from flexflow_tpu.logger import fflogger
         from flexflow_tpu.runtime.resilience import retry
 
         # a concurrent process can race the build (dlopen of a just-
@@ -50,21 +51,18 @@ def load_lib():
                and not isinstance(e, FileNotFoundError),
                name="native dataloader build")
         def _build_and_open():
-            if (not os.path.exists(_LIB_PATH)
-                    or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-                tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
-                subprocess.run(
-                    ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-                     "-shared", "-o", tmp, src],
-                    check=True, capture_output=True)
-                os.rename(tmp, _LIB_PATH)
-            return ctypes.CDLL(_LIB_PATH)
+            return ctypes.CDLL(
+                build_native_lib(_SRC, "libffdl", ("-pthread",)))
 
         try:
             lib = _build_and_open()
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            fflogger.warning(
+                "native dataloader unavailable (%s: %s) — batches come "
+                "from the Python loader", type(e).__name__, e)
             _lib = False
             return None
+        fflogger.info("native dataloader: %s", lib._name)
         lib.ffdl_create.restype = ctypes.c_void_p
         lib.ffdl_create.argtypes = [
             ctypes.c_int, ctypes.POINTER(ctypes.c_void_p),

@@ -224,8 +224,6 @@ class ShardedFusedUpdate(Optimizer):
         return self.inner.init_state(flat)
 
     def update(self, params, grads, state):
-        from flexflow_tpu.parallel import shard_map_compat
-
         pspecs = self.specs
         sspecs = self._state_specs(state)
 
@@ -235,10 +233,10 @@ class ShardedFusedUpdate(Optimizer):
             nfp, nstate = self.inner.update(fp, fg, s_local)
             return FusedUpdate._unflatten(nfp, spec), nstate
 
-        return shard_map_compat(body, self.mesh,
-                                in_specs=(pspecs, pspecs, sspecs),
-                                out_specs=(pspecs, sspecs)
-                                )(params, grads, state)
+        return jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(pspecs, pspecs, sspecs),
+                             out_specs=(pspecs, sspecs), check_vma=False
+                             )(params, grads, state)
 
 
 def apply_tree_shardings(tree, shardings, fn, default=None):

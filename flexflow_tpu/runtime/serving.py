@@ -143,7 +143,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from flexflow_tpu._env import compilation_cache_entries
+from flexflow_tpu._env import (compilation_cache_dir,
+                               compilation_cache_entries)
 from flexflow_tpu.logger import fflogger
 from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import faultinject, flightrec, locks, telemetry
@@ -1772,8 +1773,8 @@ class ServingEngine:
     def _compiled_call(self, key, build, *args):
         """Program-cache lookup; a miss builds + runs the program and
         bumps recompile_count, logging whether jax's persistent
-        compilation cache (FFConfig.compilation_cache_dir) absorbed the
-        compile. Every shape-affecting datum is part of `key`, so this
+        compilation cache (placed by _env.resolve_compilation_cache or
+        JAX_COMPILATION_CACHE_DIR) absorbed the compile. Every shape-affecting datum is part of `key`, so this
         counter is exactly the number of XLA compiles the engine caused."""
         fn = self._programs.get(key)
         if fn is not None:
@@ -1784,7 +1785,7 @@ class ServingEngine:
         self._retrace.note_miss(key, args)
         fn = self._programs[key] = build()
         self.recompile_count += 1
-        cache_dir = getattr(self.model.config, "compilation_cache_dir", "")
+        cache_dir = compilation_cache_dir()
         before = compilation_cache_entries(cache_dir) if cache_dir else 0
         t0 = time.perf_counter()
         out = fn(*args)
@@ -3814,6 +3815,7 @@ class ServingEngine:
             # autotune table's process-wide hit/miss deltas since engine
             # construction (see the baseline note in __init__)
             "paged_attention_impl": self.paged_attention_impl,
+            "paged_prefill_impl": self.paged_prefill_impl,
             "pages_touched": self._pages_touched,
             "last_pages_touched": self._last_pages_touched,
             **{f"kernel_tune_{k}": v - self._ktune_base.get(k, 0)

@@ -3,8 +3,8 @@
 Builds the cost tables the C++ simulator consumes: per-op choice lists
 (legal axis maps) with compute + grad-sync + per-device-memory costs and the
 device count each choice spans, plus per-edge resharding cost matrices and
-tensor sizes (for placement transfers). Compiles libffsim.so on first use
-(g++, no pybind11 in this environment — plain C ABI + ctypes).
+tensor sizes (for placement transfers). Compiles the simulator on first use
+(g++ through _native.build_native_lib — plain C ABI + ctypes).
 
 Strategies evaluated here are (choice, place) pairs per op: the axis map
 plus the contiguous aligned device block the op runs on (reference
@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -23,22 +22,21 @@ import numpy as np
 from flexflow_tpu.ops.base import InputOp
 from flexflow_tpu.parallel.pconfig import ParallelConfig
 
-_CSRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "csrc")
-_LIB_PATH = os.path.join(_CSRC, "libffsim.so")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "csrc",
+                    "sim.cc")
 _lib = None
 
 
 def _load_lib():
+    """Build (keyed by the hash of sim.cc) and load the native simulator.
+    Raises OSError without a toolchain — search/driver.optimize_strategies
+    decides, and logs, whether the Python annealer may stand in."""
     global _lib
     if _lib is not None:
         return _lib
-    src = os.path.join(_CSRC, "sim.cc")
-    if (not os.path.exists(_LIB_PATH)
-            or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)):
-        subprocess.run(["g++", "-O3", "-std=c++17", "-fPIC", "-Wall",
-                        "-shared", "-o", _LIB_PATH, src],
-                       check=True, capture_output=True)
-    lib = ctypes.CDLL(_LIB_PATH)
+    from flexflow_tpu._native import build_native_lib
+
+    lib = ctypes.CDLL(build_native_lib(_SRC, "libffsim"))
     d, i32, i64 = (np.ctypeslib.ndpointer(dtype=np.float64, flags="C"),
                    np.ctypeslib.ndpointer(dtype=np.int32, flags="C"),
                    np.ctypeslib.ndpointer(dtype=np.int64, flags="C"))

@@ -275,14 +275,26 @@ def optimize_strategies(model, budget: int = 1000, alpha: float = 0.05,
     eap = getattr(cfgflags, "enable_attribute_parallel", True)
     warm = warm_start_seed(model, mesh_shape, warm_start, epp, eap)
 
+    # which simulator priced the strategy rides the model (into
+    # _search_summary) and the log: a search that quietly ran the Python
+    # annealer is not the search the docs describe
+    model._search_simulator = "python"
     if use_native:
+        from flexflow_tpu.logger import fflogger
+
         try:
             from flexflow_tpu.search.csim import native_optimize
 
-            return native_optimize(model, cost, mesh_shape, budget, alpha, seed,
-                                   verbose=verbose, warm_start=warm)
-        except (ImportError, OSError):
-            pass  # fall through to the Python annealer
+            out = native_optimize(model, cost, mesh_shape, budget, alpha,
+                                  seed, verbose=verbose, warm_start=warm)
+            model._search_simulator = "native"
+            fflogger.info("search: native C++ simulator ran (budget %d)",
+                          budget)
+            return out
+        except (ImportError, OSError) as e:
+            fflogger.warning(
+                "search: native simulator unavailable (%s: %s) — running "
+                "the Python annealer", type(e).__name__, e)
 
     rng = random.Random(seed)
     ops = [op for op in model.ops if not isinstance(op, InputOp)]
@@ -415,6 +427,7 @@ def optimize_strategies_multi(model, budget: int = 1000, alpha: float = 0.05,
     predicted = base_time + overhead
     model._predicted_step_time = predicted
     model._search_summary = {
+        "simulator": model._search_simulator,
         "predicted_step_s": predicted,
         "base_step_s": base_time,
         "mem_overhead_s": overhead,

@@ -235,8 +235,8 @@ def tune_paged_attention(*, page_size: int = 16, pages_per_slot: int = 8,
     The pool's storage dtype is the signature's dtype, so int8 and
     full-width entries can never shadow each other. ServingEngine
     consults the entry under paged_attention_impl='auto'
-    (lookup_paged_impl). Off-TPU the kernel runs in interpret mode:
-    the sweep exercises the full tune->persist->consume path (the CI
+    (lookup_paged_impl). Off-TPU the kernel runs only in interpret
+    mode (FF_PALLAS_INTERPRET=1): the sweep exercises the full tune->persist->consume path (the CI
     smoke + bench demonstration), it just measures the interpreter —
     einsum wins there by construction, which is itself the right
     'auto' answer for a CPU backend."""
@@ -379,7 +379,8 @@ def tune_paged_prefill(*, page_size: int = 16, pages_per_slot: int = 8,
     QUANTIZED pool, and persist the winning impl under the
     'paged_prefill' kernel key. ServingEngine consults the entry under
     paged_attention_impl='auto' (lookup_paged_prefill_impl). Off-TPU
-    the kernel runs in interpret mode: the sweep exercises the full
+    the kernel runs only in interpret mode (FF_PALLAS_INTERPRET=1): the
+    sweep exercises the full
     tune->persist->consume path, it just measures the interpreter —
     einsum wins there by construction, the right 'auto' answer for a
     CPU backend."""
@@ -485,8 +486,8 @@ def tune_flash_attention(seq_q: int, seq_k: Optional[int] = None, *,
     Timing goes through measure.time_scalar_program — the same
     dispatch-floor harness the strategy search trusts for op costs (the
     kernel call is wrapped in a scalar-reducing jit so each timed call
-    fetches 4 bytes). Off-TPU the kernels run in interpret mode: the
-    sweep still exercises the full tune->persist->consume path (the CI
+    fetches 4 bytes). Off-TPU the kernels run only in interpret mode
+    (FF_PALLAS_INTERPRET=1): the sweep still exercises the full tune->persist->consume path (the CI
     smoke), it just measures the interpreter."""
     import jax
     import jax.numpy as jnp

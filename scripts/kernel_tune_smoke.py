@@ -14,8 +14,9 @@ module-reload boundary a fresh process would cross:
      same numbers as the static-pick baseline (block size is a schedule
      choice, not semantics).
 
-Run under JAX_PLATFORMS=cpu the kernels execute in interpret mode: the
-smoke exercises exactly the code path a TPU re-tune takes.
+Run under JAX_PLATFORMS=cpu FF_PALLAS_INTERPRET=1 (what ci/run_ci.sh
+exports) the kernels execute in interpret mode: the smoke exercises
+exactly the code path a TPU re-tune takes.
 """
 
 import os

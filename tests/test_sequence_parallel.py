@@ -27,16 +27,6 @@ def dense_reference(q, k, v, causal=False):
     return np.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def _shard_map():
-    # jax 0.4.x has no top-level jax.shard_map (its module __getattr__
-    # raises); fall back to the experimental spelling there
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def make_qkv(b=2, s=32, h=4, d=8, seed=0):
     rs = np.random.RandomState(seed)
     return (rs.randn(b, s, h, d).astype(np.float32),
@@ -50,7 +40,7 @@ def test_ring_attention_matches_dense(causal):
     q, k, v = make_qkv()
     spec = P(None, "seq", None, None)
 
-    fn = _shard_map()(
+    fn = jax.shard_map(
         lambda a, b_, c: ring_attention(a, b_, c, "seq", causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     got = np.asarray(jax.jit(fn)(q, k, v))
@@ -63,7 +53,7 @@ def test_ulysses_attention_matches_dense(causal):
     mesh = make_mesh({"seq": 4})
     q, k, v = make_qkv()
     spec = P(None, "seq", None, None)
-    fn = _shard_map()(
+    fn = jax.shard_map(
         lambda a, b_, c: ulysses_attention(a, b_, c, "seq", causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     got = np.asarray(jax.jit(fn)(q, k, v))
@@ -87,7 +77,7 @@ def test_ring_attention_grads_flow():
     spec = P(None, "seq", None, None)
 
     def loss(a, b_, c):
-        out = _shard_map()(
+        out = jax.shard_map(
             lambda x, y, z: ring_attention(x, y, z, "seq", causal=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(a, b_, c)
         return jnp.sum(out ** 2)
@@ -142,7 +132,7 @@ def test_sp_attention_dropout_applied_and_unbiased():
 
     def run(rate, seed):
         key = jax.random.PRNGKey(seed)
-        fn = _shard_map()(
+        fn = jax.shard_map(
             lambda a, b_, c, kk: ring_attention(
                 a, b_, c, "seq", dropout_rate=rate, dropout_rng=kk),
             mesh=mesh, in_specs=(spec, spec, spec, key_spec), out_specs=spec)
@@ -189,29 +179,29 @@ def test_mha_sp_dropout_training_runs():
 def test_ring_attention_flash_matches_dense(causal, monkeypatch):
     """Flash-kernel ring attention (Pallas block compute + logsumexp merge)
     must match dense numerics, forward and backward."""
-    from flexflow_tpu.parallel import shard_map_compat
-
     monkeypatch.setenv("FF_FORCE_FLASH_ATTENTION", "1")
     mesh = make_mesh({"seq": 4})
     q, k, v = make_qkv(s=64, d=16)
     spec = P(None, "seq", None, None)
 
     # pallas_call outputs carry no vma annotation, so the product path runs
-    # shard_map with check_vma off (parallel.shard_map_compat)
-    fn = shard_map_compat(
+    # shard_map with check_vma off
+    fn = jax.shard_map(
         lambda a, b_, c: ring_attention(a, b_, c, "seq", causal=causal,
                                         use_flash=True),
-        mesh, (spec, spec, spec), spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     got = np.asarray(jax.jit(fn)(q, k, v))
     want = dense_reference(q, k, v, causal)
     np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-5)
 
     # gradient parity vs the pure-JAX ring path
     def loss(flash):
-        f = shard_map_compat(
+        f = jax.shard_map(
             lambda x, y, z: ring_attention(x, y, z, "seq", causal=causal,
                                            use_flash=flash),
-            mesh, (spec, spec, spec), spec)
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)
         return lambda a, b_, c: jnp.sum(f(a, b_, c) ** 2)
 
     gf = jax.jit(jax.grad(loss(True), (0, 1, 2)))(q, k, v)
@@ -228,7 +218,7 @@ def test_ring_attention_long_context():
     mesh = make_mesh({"seq": 8})
     q, k, v = make_qkv(b=1, s=2048, h=2, d=32, seed=4)
     spec = P(None, "seq", None, None)
-    fn = _shard_map()(
+    fn = jax.shard_map(
         lambda a, b_, c: ring_attention(a, b_, c, "seq", causal=True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     got = np.asarray(jax.jit(fn)(q, k, v))
