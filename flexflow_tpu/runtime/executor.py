@@ -309,8 +309,8 @@ class GraphExecutor:
                     "sp_mode": getattr(self.model.config, "sp_mode", "ring"),
                 }
             # named_scope stamps the op name into the HLO metadata of every
-            # instruction it traces, so xla_trace/Perfetto spans of the
-            # PRODUCTION jitted program attribute back to graph ops — the
+            # instruction it traces, so a jax.profiler trace's device ops
+            # of the PRODUCTION jitted program attribute back to graph ops — the
             # in-situ analog of the reference's --profiling per-op events
             # (linear.cu:526-553); profiler.profile_step stays the unfused
             # wall-timer variant

@@ -6,21 +6,20 @@ Reference observability (SURVEY §5.1): per-op cudaEvent timing behind
 
   * IN-SITU attribution: the executors trace every op under
     jax.named_scope(op.name), so each instruction of the PRODUCTION jitted
-    program carries the op name in its HLO metadata — Perfetto spans from
-    xla_trace attribute back to graph ops, and in_situ_op_summary reads the
+    program carries the op name in its HLO metadata — a
+    jax.profiler.start_trace() trace attributes device ops back to graph
+    ops (the host's ff.* spans of runtime/telemetry.py lie in the same
+    trace), and in_situ_op_summary reads the
     optimized program's per-op instruction breakdown without running
     anything unfused
   * profile_step: op-by-op eager execution with wall timers — the analog of
     the per-op printf path, for wall-clock per op at the price of fusion
-  * xla_trace: jax.profiler context writing a Perfetto/TensorBoard trace dir
-    (the -lg:prof analog; spans carry the named_scope op names)
   * export_taskgraph: the op graph + strategy as Graphviz DOT (the
     simulator's DotFile analog, simulator.h:78-131)
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Dict, List, Optional
 
@@ -244,16 +243,6 @@ def step_phase_breakdown(model, batch: Optional[Dict] = None,
         rows.update({"collective_instructions": -1,
                      "collective_bytes": -1.0})
     return rows
-
-
-@contextlib.contextmanager
-def xla_trace(logdir: str):
-    """Perfetto/TensorBoard trace of whatever runs inside the context."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def export_taskgraph(model, filename: str):

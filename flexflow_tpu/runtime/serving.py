@@ -223,6 +223,7 @@ class Request:
     private_pages: List[int] = field(default_factory=list)
     prefix_tokens: int = 0          # prefill positions served from cache
     t_submit: float = 0.0
+    t_admit: float = 0.0            # left the queue for a slot (perf_counter)
     ttft: float = 0.0               # submit -> first emitted token (s)
     t_done: float = 0.0
     error: str = ""
@@ -1375,6 +1376,8 @@ class ServingEngine:
         # approximate when training or a second engine traces alongside
         self._pages_touched = 0
         self._last_pages_touched = 0
+        self._kv_read_bytes = 0
+        self._tick_seq = 0
         # (the kernel-tune counter baseline _ktune_base is snapshotted
         # in the impl-resolution block above, before the construction-
         # time table lookup)
@@ -1387,13 +1390,6 @@ class ServingEngine:
         self._adapter_requests: Dict[str, int] = {}
         self._adapter_spec: Dict[str, List[int]] = {}
         self._sampled_requests = 0
-        if self.lora is not None:
-            # compile + run the one fixed-shape adapter writer NOW
-            # (writing the null page's zeros is a no-op): every later
-            # fault-in of a real adapter reuses this program, so tenant
-            # churn never compiles — and recompile-flatness tests see
-            # the build at construction, outside any warm window
-            self._write_adapter_page(0, self._zero_payload, 0.0)
 
         # ---- unified telemetry plane (ISSUE 13) ----
         # the engine's latency histograms (TTFT / inter-token / queue
@@ -1407,6 +1403,13 @@ class ServingEngine:
                            "role": "solo"}
         self._retrace.owner = self._tm_labels["replica"]
         self._tm_ch: Dict = {}
+        if self.lora is not None:
+            # compile + run the one fixed-shape adapter writer NOW
+            # (writing the null page's zeros is a no-op): every later
+            # fault-in of a real adapter reuses this program, so tenant
+            # churn never compiles — and recompile-flatness tests see
+            # the build at construction, outside any warm window
+            self._write_adapter_page(0, self._zero_payload, 0.0)
         # flight recorder + SLO plane adopt the config's knobs
         # UNCONDITIONALLY: configure() is how telemetry="off" reaches
         # the recorder's own gate — skipping it when off would leave an
@@ -1768,6 +1771,17 @@ class ServingEngine:
                 or len(req.tokens) >= req.max_new_tokens:
             self._retire(slot, "done")
 
+    def _span(self, name: str, **counts):
+        """One phase of the tick as a live span on this engine's track:
+        a ring event and, under a running profiler trace, ``ff.<name>``
+        in its host plane (telemetry.Tracer.span). One per phase per
+        tick, never one per token or slot; a span whose name ends in
+        ``_fetch`` is the host BLOCKED on the device and holds nothing
+        else."""
+        if not self._tm_on:
+            return telemetry.NULL_SPAN
+        return telemetry.tracer().span(name, track=self._tm_track, **counts)
+
     # ---- compiled programs --------------------------------------------------
 
     def _compiled_call(self, key, build, *args):
@@ -1788,8 +1802,10 @@ class ServingEngine:
         cache_dir = compilation_cache_dir()
         before = compilation_cache_entries(cache_dir) if cache_dir else 0
         t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
+        with self._span("compile", key=str(key)):
+            out = fn(*args)
+            with self._span("compile_fetch"):
+                jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         if cache_dir:
             grew = compilation_cache_entries(cache_dir) - before
@@ -1921,7 +1937,7 @@ class ServingEngine:
             for key in payloads[0]}
 
         def build():
-            def write(pool, dpool, payload, pages):
+            def kv_page_write(pool, dpool, payload, pages):
                 out = {op.name: op.import_page(pool[op.name], pages,
                                                payload[("t", op.name)])
                        for op in self.gen.attn_ops}
@@ -1932,7 +1948,7 @@ class ServingEngine:
                         for op in self.draft_gen.attn_ops}
                 return out, dout
 
-            return jax.jit(write, donate_argnums=(0, 1))
+            return jax.jit(kv_page_write, donate_argnums=(0, 1))
 
         self.pool, dp = self._compiled_call(
             ("page_import",), build, self.pool, self.draft_pool, stacked,
@@ -2070,7 +2086,8 @@ class ServingEngine:
         chunk = self.prefill_chunk
 
         if st == 0:
-            def chunk0(params, state, tokens, lora_pool, lora_pages):
+            def prefill_chunk0(params, state, tokens, lora_pool,
+                               lora_pages):
                 caches = {op.name: op.init_cache(1, bucket, cdtype)
                           for op in gen.attn_ops}
                 lora = ({"pool": lora_pool, "pages": lora_pages}
@@ -2080,10 +2097,10 @@ class ServingEngine:
                     chunk_start=0, skip_tail=True, lora=lora)
                 return caches
 
-            return jax.jit(chunk0)
+            return jax.jit(prefill_chunk0)
 
-        def chunk_fn(params, state, tokens, caches, lora_pool,
-                     lora_pages):
+        def prefill_chunk(params, state, tokens, caches, lora_pool,
+                          lora_pages):
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             _, caches = gen._walk(
@@ -2091,7 +2108,7 @@ class ServingEngine:
                 chunk_start=st, skip_tail=True, lora=lora)
             return caches
 
-        return jax.jit(chunk_fn, donate_argnums=(3,))
+        return jax.jit(prefill_chunk, donate_argnums=(3,))
 
     def _build_prefill_ifinal(self, bucket: int, n_pages: int):
         """The last quantum of an interleaved prefill: the ragged
@@ -2104,9 +2121,9 @@ class ServingEngine:
         gen = self.gen
         has_lora = self.lora_pool is not None
 
-        def final(params, state, tokens, length, caches, pool, pages,
-                  poison, temps, top_ps, top_ks, seeds, lora_pool,
-                  lora_pages):
+        def prefill_final(params, state, tokens, length, caches, pool,
+                          pages, poison, temps, top_ps, top_ks, seeds,
+                          lora_pool, lora_pages):
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             tok_last = jnp.take_along_axis(
@@ -2125,7 +2142,7 @@ class ServingEngine:
         # donate the pool only: the chunk caches feed the scatter but
         # back no output (tok/ok are tiny, pool aliases the pool input),
         # so donating them just trips jax's unusable-donation warning
-        return jax.jit(final, donate_argnums=(5,))
+        return jax.jit(prefill_final, donate_argnums=(5,))
 
     def _build_verify(self, k: int):
         """Speculative verify: ONE dispatch scores all K+1 candidate
@@ -2141,9 +2158,9 @@ class ServingEngine:
         gen = self.gen
         has_lora = self.lora_pool is not None
 
-        def verify(params, state, pool, page_table, slab, write_pos,
-                   rope_pos0, row_len, prompt_pad, poison,
-                   temps, top_ps, top_ks, lora_pool, lora_pages):
+        def decode_verify(params, state, pool, page_table, slab,
+                          write_pos, rope_pos0, row_len, prompt_pad, poison,
+                          temps, top_ps, top_ks, lora_pool, lora_pages):
             paged = {"page_table": page_table, "write_pos": write_pos,
                      "rope_pos": rope_pos0, "row_len": row_len,
                      "prompt_pad": prompt_pad,
@@ -2163,7 +2180,7 @@ class ServingEngine:
                 jnp.repeat(top_ks, s)).reshape(b, s, v)
             return toks, probs, ok, pool
 
-        return jax.jit(verify, donate_argnums=(2,))
+        return jax.jit(decode_verify, donate_argnums=(2,))
 
     def _build_decode(self, n_steps: int):
         gen = self.gen
@@ -2220,9 +2237,9 @@ class ServingEngine:
         resample."""
         gen = self.draft_gen
 
-        def propose(params, state, pool, page_table, last_tok,
-                    write_pos0, rope_pos0, row_len, prompt_pad, budget,
-                    temps, top_ps, top_ks, seeds, ctr0):
+        def decode_propose(params, state, pool, page_table, last_tok,
+                           write_pos0, rope_pos0, row_len, prompt_pad,
+                           budget, temps, top_ps, top_ks, seeds, ctr0):
             rope_cap = budget - prompt_pad + row_len - 1
 
             def body(carry, i):
@@ -2248,7 +2265,7 @@ class ServingEngine:
                 jnp.arange(n_steps, dtype=jnp.int32))
             return toks, probs, pool        # (k, B), (k, B, V)
 
-        return jax.jit(propose, donate_argnums=(2,))
+        return jax.jit(decode_propose, donate_argnums=(2,))
 
     def _split_key(self):
         self._key, sub = jax.random.split(self._key)
@@ -2314,11 +2331,11 @@ class ServingEngine:
         lora_ops = self._lora_ops
 
         def build():
-            def write(pool, page, payload, scale):
+            def adapter_page_write(pool, page, payload, scale):
                 return lora_ops.write_adapter_page(pool, page, payload,
                                                    scale)
 
-            return jax.jit(write, donate_argnums=(0,))
+            return jax.jit(adapter_page_write, donate_argnums=(0,))
 
         self.lora_pool = self._compiled_call(
             ("adapter_write",), build, self.lora_pool,
@@ -2354,12 +2371,20 @@ class ServingEngine:
         version bit-identical to the bare adapter key."""
         return version_ns(self.weight_version, adapter)
 
-    def _admit(self):
+    def _admit(self) -> int:
         """Move queued requests into free slots: look up the longest
         cached prompt prefix, allocate fresh pages for everything past it
         (copy-on-write — shared pages are never written), prefill the
-        tail (bucket-shaped program) and seed the slot."""
+        tail (bucket-shaped program) and seed the slot; one ``prefill``
+        span per admitted request, from the bucket program's dispatch to
+        its first token. Returns the number admitted. The phase stays
+        ONE function with the span as a ``with`` block: every Python
+        frame between ``step()`` and a program's first call makes
+        tracing and lowering that program slower (0.2-0.4 s a frame for
+        a 24-layer prefill on the chip's host: PERF.md section 6,
+        PR 23)."""
         self._expire_queued()
+        admitted = 0
         while self._queue:
             try:
                 # a mid-prefill slot is inactive but HELD (slot_req set)
@@ -2367,7 +2392,7 @@ class ServingEngine:
                             if not self.active[i]
                             and self.slot_req[i] is None)
             except StopIteration:
-                return
+                return admitted
             req = self._queue[0]
             total = req.bucket + req.max_new_tokens
             n_total = math.ceil(total / self.page_size)
@@ -2405,7 +2430,7 @@ class ServingEngine:
                     # trie is fully evictable once its users retire),
                     # so progress is always possible. The request stays
                     # QUEUED with no refcounts or pages held.
-                    return
+                    return admitted
             if n_host:
                 # H2D the host-tier part of the match; a failed
                 # promotion truncates the path (cold prefill past it)
@@ -2414,7 +2439,8 @@ class ServingEngine:
                 need = n_total - full   # promoted pages left the free
                 #                         list; the rest is fresh pages
                 if len(self._free_pages) < need:
-                    return  # raced shortfall after a failed promotion
+                    # raced shortfall after a failed promotion
+                    return admitted
             adapter_page = 0
             if req.adapter is not None:
                 # pin the tenant's adapter page; a miss FAULTS it in
@@ -2424,19 +2450,21 @@ class ServingEngine:
                 # resumes when a retirement releases a page.
                 got = self.lora.checkout(req.adapter)
                 if got is None:
-                    return
+                    return admitted
                 adapter_page, ent = got
                 if ent is not None:
                     self._write_adapter_page(adapter_page,
                                              ent["payload"],
                                              ent["scale"])
             self._queue.pop(0)
-            # telemetry: the engine queue wait ends here (admission
-            # starts); the prefill span opens here and closes after the
-            # dispatch below, tagged cold vs hit (a handoff-import shows
-            # as a preceding handoff_import span on the same trace id)
+            admitted += 1
+            # the engine queue wait ends here (admission starts): a
+            # wait, not host work, so its span is retrospective and
+            # ring-only. The prefill span opens at the dispatch below,
+            # tagged cold vs hit (a handoff-import shows as a preceding
+            # handoff_import span on the same trace id)
+            req.t_admit = t_adm = time.perf_counter()
             tm = self._tm_on and telemetry.enabled()
-            t_adm = time.perf_counter() if tm else 0.0
             if tm:
                 wait = t_adm - req.t_submit
                 self._tm_ch["queue"].observe(wait)
@@ -2509,106 +2537,112 @@ class ServingEngine:
                     "t_adm": t_adm, "tm": tm, "poison": poison,
                     "adapter_page": adapter_page}
                 continue
-            # slot-resident sampling + adapter state: the fixed-shape
-            # programs read these arrays every dispatch
-            self.temps[slot] = req.temperature
-            self.top_ps[slot] = req.top_p
-            self.top_ks[slot] = req.top_k
-            self.seeds[slot] = req.seed
-            self.lora_pages[slot] = adapter_page
-            self.poison[slot] = poison
-            table = np.zeros((self.pages_per_slot,), np.int32)
-            table[:n_total] = req.pages
-            self.page_tables[slot] = table
-            self.row_len[slot] = req.prompt.size
-            self.prompt_pad[slot] = req.bucket
-            self.emitted[slot] = 0
+            with self._span("prefill", trace_id=req.trace_id,
+                            kind="hit" if full else "cold",
+                            bucket=req.bucket,
+                            prompt_tokens=int(req.prompt.size),
+                            matched_pages=full) as psp:
+                # slot-resident sampling + adapter state: the fixed-shape
+                # programs read these arrays every dispatch
+                self.temps[slot] = req.temperature
+                self.top_ps[slot] = req.top_p
+                self.top_ks[slot] = req.top_k
+                self.seeds[slot] = req.seed
+                self.lora_pages[slot] = adapter_page
+                self.poison[slot] = poison
+                table = np.zeros((self.pages_per_slot,), np.int32)
+                table[:n_total] = req.pages
+                self.page_tables[slot] = table
+                self.row_len[slot] = req.prompt.size
+                self.prompt_pad[slot] = req.bucket
+                self.emitted[slot] = 0
 
-            if full:
-                # prefix hit: gather the matched pages read-only, prefill
-                # only the tail slab [full*ps, bucket) into FRESH pages —
-                # the matched prefix's partial last page (tokens past
-                # full*ps) is re-materialized into the request's own
-                # first tail page, never written in the donor's (the COW
-                # rule). One program per (bucket, full): bounded like the
-                # buckets themselves, flat after warmup.
-                p0 = full * self.page_size
-                padded_tail = np.full((1, req.bucket - p0), self.pad_id,
-                                      np.int32)
-                tail = req.prompt[p0:]
-                padded_tail[0, :tail.size] = tail
-                tok_last = np.asarray([[req.prompt[-1]]], np.int32)
-                tok, ok, self.pool = self._compiled_call(
-                    ("prefill_hit", req.bucket, full),
-                    lambda: self._build_prefill_hit(req.bucket, full),
-                    self.gen._params(), self.model.bn_state, padded_tail,
-                    tok_last, np.asarray([req.prompt.size], np.int32),
-                    self.pool, np.asarray(req.pages[:full], np.int32),
-                    np.asarray(req.pages[full:n_prefill], np.int32),
-                    np.float32(self.poison[slot]),
-                    *self._sampling_args_1(req),
-                    *self._lora_args_1(adapter_page))
-            else:
-                padded = np.full((1, req.bucket), self.pad_id, np.int32)
-                padded[0, :req.prompt.size] = req.prompt
-                tok, ok, self.pool = self._compiled_call(
-                    ("prefill", req.bucket, n_prefill, self.prefill_chunk),
-                    lambda: self._build_prefill(req.bucket, n_prefill),
-                    self.gen._params(), self.model.bn_state, padded,
-                    np.asarray([req.prompt.size], np.int32), self.pool,
-                    np.asarray(req.pages[:n_prefill], np.int32),
-                    np.float32(self.poison[slot]),
-                    *self._sampling_args_1(req),
-                    *self._lora_args_1(adapter_page))
-            if self.draft_gen is not None:
-                # the draft model's prefix KV rides the same page ids, so
-                # its prefill mirrors the target's hit/cold split exactly
                 if full:
-                    self.draft_pool = self._compiled_call(
-                        ("draft_prefill_hit", req.bucket, full),
-                        lambda: self._build_draft_prefill_hit(req.bucket,
-                                                              full),
-                        self.draft_gen._params(), self.draft_model.bn_state,
-                        padded_tail, self.draft_pool,
-                        np.asarray(req.pages[:full], np.int32),
-                        np.asarray(req.pages[full:n_prefill], np.int32))
+                    # prefix hit: gather the matched pages read-only, prefill
+                    # only the tail slab [full*ps, bucket) into FRESH pages —
+                    # the matched prefix's partial last page (tokens past
+                    # full*ps) is re-materialized into the request's own
+                    # first tail page, never written in the donor's (the COW
+                    # rule). One program per (bucket, full): bounded like the
+                    # buckets themselves, flat after warmup.
+                    p0 = full * self.page_size
+                    padded_tail = np.full((1, req.bucket - p0), self.pad_id,
+                                          np.int32)
+                    tail = req.prompt[p0:]
+                    padded_tail[0, :tail.size] = tail
+                    tok_last = np.asarray([[req.prompt[-1]]], np.int32)
+                    tok, ok, self.pool = self._compiled_call(
+                        ("prefill_hit", req.bucket, full),
+                        lambda: self._build_prefill_hit(req.bucket, full),
+                        self.gen._params(), self.model.bn_state, padded_tail,
+                        tok_last, np.asarray([req.prompt.size], np.int32),
+                        self.pool, np.asarray(req.pages[:full], np.int32),
+                        np.asarray(req.pages[full:n_prefill], np.int32),
+                        np.float32(self.poison[slot]),
+                        *self._sampling_args_1(req),
+                        *self._lora_args_1(adapter_page))
                 else:
-                    self.draft_pool = self._compiled_call(
-                        ("draft_prefill", req.bucket, n_prefill),
-                        lambda: self._build_draft_prefill(req.bucket,
-                                                          n_prefill),
-                        self.draft_gen._params(), self.draft_model.bn_state,
-                        padded, self.draft_pool,
-                        np.asarray(req.pages[:n_prefill], np.int32))
-            ok_host = bool(np.asarray(ok)[0])
-            if tm:
-                telemetry.tracer().complete(
-                    "prefill", t_adm, time.perf_counter() - t_adm,
-                    trace_id=req.trace_id, track=self._tm_track,
-                    kind="hit" if full else "cold", bucket=req.bucket,
-                    matched_pages=full, ok=ok_host)
-                req.decode_span = telemetry.tracer().begin(
-                    "decode", trace_id=req.trace_id,
-                    track=self._tm_track)
-            if self.prefix_cache is not None and ok_host:
-                # publish this prompt's FULL pages beyond the matched
-                # prefix for future sharing (poisoned/non-finite prefills
-                # are never published — a NaN prompt cache must not
-                # infect later requests). Published pages move from
-                # private to trie-owned: decref'd at retirement, freed
-                # only by eviction.
-                last = req.prompt.size // self.page_size
-                if last > full:
-                    created = self.prefix_cache.insert(
-                        req.prompt, matched, full, req.pages[full:last],
-                        ns=self._cache_ns(req.adapter))
-                    if created:
-                        adopted = {n.page for n in created}
-                        req.trie_nodes.extend(created)
-                        req.private_pages = [p for p in req.private_pages
-                                             if p not in adopted]
-            self.active[slot] = True
-            self._record_token(slot, int(np.asarray(tok)[0]), ok_host)
+                    padded = np.full((1, req.bucket), self.pad_id, np.int32)
+                    padded[0, :req.prompt.size] = req.prompt
+                    tok, ok, self.pool = self._compiled_call(
+                        ("prefill", req.bucket, n_prefill, self.prefill_chunk),
+                        lambda: self._build_prefill(req.bucket, n_prefill),
+                        self.gen._params(), self.model.bn_state, padded,
+                        np.asarray([req.prompt.size], np.int32), self.pool,
+                        np.asarray(req.pages[:n_prefill], np.int32),
+                        np.float32(self.poison[slot]),
+                        *self._sampling_args_1(req),
+                        *self._lora_args_1(adapter_page))
+                if self.draft_gen is not None:
+                    # the draft model's prefix KV rides the same page ids, so
+                    # its prefill mirrors the target's hit/cold split exactly
+                    if full:
+                        self.draft_pool = self._compiled_call(
+                            ("draft_prefill_hit", req.bucket, full),
+                            lambda: self._build_draft_prefill_hit(req.bucket,
+                                                                  full),
+                            self.draft_gen._params(),
+                            self.draft_model.bn_state,
+                            padded_tail, self.draft_pool,
+                            np.asarray(req.pages[:full], np.int32),
+                            np.asarray(req.pages[full:n_prefill], np.int32))
+                    else:
+                        self.draft_pool = self._compiled_call(
+                            ("draft_prefill", req.bucket, n_prefill),
+                            lambda: self._build_draft_prefill(req.bucket,
+                                                              n_prefill),
+                            self.draft_gen._params(),
+                            self.draft_model.bn_state,
+                            padded, self.draft_pool,
+                            np.asarray(req.pages[:n_prefill], np.int32))
+                with self._span("prefill_fetch"):
+                    ok_host = bool(np.asarray(ok)[0])
+                    tok_host = int(np.asarray(tok)[0])
+                psp.annotate(ok=ok_host)
+                if self._tm_on:
+                    req.decode_span = telemetry.tracer().begin(
+                        "decode", trace_id=req.trace_id,
+                        track=self._tm_track)
+                if self.prefix_cache is not None and ok_host:
+                    # publish this prompt's FULL pages beyond the matched
+                    # prefix for future sharing (poisoned/non-finite prefills
+                    # are never published — a NaN prompt cache must not
+                    # infect later requests). Published pages move from
+                    # private to trie-owned: decref'd at retirement, freed
+                    # only by eviction.
+                    last = req.prompt.size // self.page_size
+                    if last > full:
+                        created = self.prefix_cache.insert(
+                            req.prompt, matched, full, req.pages[full:last],
+                            ns=self._cache_ns(req.adapter))
+                        if created:
+                            adopted = {n.page for n in created}
+                            req.trie_nodes.extend(created)
+                            req.private_pages = [p for p in req.private_pages
+                                                 if p not in adopted]
+                self.active[slot] = True
+                self._record_token(slot, tok_host, ok_host)
+        return admitted
 
     # ---- chunk-interleaved prefill scheduling (ISSUE 18) ------------------
 
@@ -2622,19 +2656,21 @@ class ServingEngine:
         ever decoding."""
         if not self._partial:
             return
-        now = time.perf_counter()
-        for slot in sorted(self._partial):
-            req = self._partial[slot]["req"]
-            if req.deadline is not None and now >= req.deadline:
-                self._abort_partial(slot, "timeout",
-                                    "deadline expired mid-prefill")
-        budget = self.prefill_interleave_chunks
-        while budget > 0 and self._partial:
-            slots = sorted(self._partial)
-            slot = slots[self._prefill_rr % len(slots)]
-            self._prefill_rr += 1
-            self._run_prefill_chunk(slot)
-            budget -= 1
+        with self._span("prefill_tick") as sp:
+            now = time.perf_counter()
+            for slot in sorted(self._partial):
+                req = self._partial[slot]["req"]
+                if req.deadline is not None and now >= req.deadline:
+                    self._abort_partial(slot, "timeout",
+                                        "deadline expired mid-prefill")
+            budget = self.prefill_interleave_chunks
+            while budget > 0 and self._partial:
+                slots = sorted(self._partial)
+                slot = slots[self._prefill_rr % len(slots)]
+                self._prefill_rr += 1
+                self._run_prefill_chunk(slot)
+                budget -= 1
+            sp.annotate(chunks=self.prefill_interleave_chunks - budget)
         if self._partial:
             # chunks remained when the tick's budget ran out — the
             # decode streams get the device back; this counter is the
@@ -2647,22 +2683,23 @@ class ServingEngine:
         ps = self._partial[slot]
         req = ps["req"]
         st = ps["next"]
-        if st == 0:
-            ps["caches"] = self._compiled_call(
-                ("prefill_ichunk", req.bucket, 0),
-                lambda: self._build_prefill_ichunk(req.bucket, 0),
-                self.gen._params(), self.model.bn_state, ps["padded"],
-                *self._lora_args_1(ps["adapter_page"]))
-        else:
-            ps["caches"] = self._compiled_call(
-                ("prefill_ichunk", req.bucket, st),
-                lambda: self._build_prefill_ichunk(req.bucket, st),
-                self.gen._params(), self.model.bn_state, ps["padded"],
-                ps["caches"], *self._lora_args_1(ps["adapter_page"]))
-        ps["next"] = st + self.prefill_chunk
-        self._prefill_chunks_interleaved += 1
-        if ps["next"] >= req.bucket:
-            self._finish_prefill(slot)
+        with self._span("prefill_chunk", slot=slot, bucket=req.bucket):
+            if st == 0:
+                ps["caches"] = self._compiled_call(
+                    ("prefill_ichunk", req.bucket, 0),
+                    lambda: self._build_prefill_ichunk(req.bucket, 0),
+                    self.gen._params(), self.model.bn_state, ps["padded"],
+                    *self._lora_args_1(ps["adapter_page"]))
+            else:
+                ps["caches"] = self._compiled_call(
+                    ("prefill_ichunk", req.bucket, st),
+                    lambda: self._build_prefill_ichunk(req.bucket, st),
+                    self.gen._params(), self.model.bn_state, ps["padded"],
+                    ps["caches"], *self._lora_args_1(ps["adapter_page"]))
+            ps["next"] = st + self.prefill_chunk
+            self._prefill_chunks_interleaved += 1
+            if ps["next"] >= req.bucket:
+                self._finish_prefill(slot)
 
     def _finish_prefill(self, slot: int):
         """The last interleaved quantum: run the gather-last + COW
@@ -2692,7 +2729,9 @@ class ServingEngine:
                 self.draft_gen._params(), self.draft_model.bn_state,
                 ps["padded"], self.draft_pool,
                 np.asarray(req.pages[:n_prefill], np.int32))
-        ok_host = bool(np.asarray(ok)[0])
+        with self._span("prefill_fetch"):
+            ok_host = bool(np.asarray(ok)[0])
+            tok_host = int(np.asarray(tok)[0])
         # decode-state arrays applied only NOW: until this instant every
         # decode dispatch saw this slot as idle
         self.temps[slot] = req.temperature
@@ -2732,7 +2771,7 @@ class ServingEngine:
                     req.private_pages = [p for p in req.private_pages
                                          if p not in adopted]
         self.active[slot] = True
-        self._record_token(slot, int(np.asarray(tok)[0]), ok_host)
+        self._record_token(slot, tok_host, ok_host)
 
     def _abort_partial(self, slot: int, state: str, error: str):
         """Retire a mid-prefill slot (deadline/poison/fault paths): the
@@ -3211,45 +3250,69 @@ class ServingEngine:
                 budget[slot] = req.bucket + req.max_new_tokens
         return write_pos, rope_pos, budget
 
-    def _note_pages_touched(self, frontier, budget):
+    def _note_pages_touched(self, frontier, budget) -> int:
         """Record the pool pages this dispatch's attention READS: per
         active slot, pages up to its final-step write frontier (what the
         pallas kernel streams through VMEM — the einsum path gathers the
         whole table width regardless, which is exactly the delta the
-        kernel exists to remove)."""
-        fr = np.minimum(frontier, budget - 1)
-        touched = int(np.sum((fr // self.page_size + 1)[self.active])) \
-            if self.active.any() else 0
+        kernel exists to remove). ``frontier`` is (slots, steps): the
+        write position of each attention pass the dispatch makes.
+        Returns the KV bytes those passes stream for the active slots
+        (live pages x page_size x kv_bytes_per_token, summed over
+        passes), which ``kv_read_bytes`` accumulates."""
+        fr = np.minimum(frontier, (budget - 1)[:, None])
+        pages = (fr // self.page_size + 1)[self.active]  # (active, steps)
+        touched = int(pages[:, -1].sum())
         self._last_pages_touched = touched
         self._pages_touched += touched
+        kv_read = int(int(pages.sum()) * self.page_size
+                      * self._kv_bytes_per_token)
+        self._kv_read_bytes += kv_read
+        return kv_read
 
     def _decode_step(self):
         k = self.decode_chunk
-        write_pos, rope_pos, budget = self._slot_decode_state()
-        self._note_pages_touched(write_pos + k - 1, budget)
-        # per-slot draw counters: the next token's index is exactly the
-        # count already emitted — slot- and replica-invariant, so a
-        # failover replay reproduces the stream
-        toks, oks, self.pool = self._compiled_call(
-            ("decode", k), lambda: self._build_decode(k),
-            self.gen._params(), self.model.bn_state, self.pool,
-            self.page_tables, self.last_tok, write_pos, rope_pos,
-            self.row_len, self.prompt_pad, budget, self.poison,
-            self.temps, self.top_ps, self.top_ks, self.seeds,
-            self.emitted.copy(), *self._lora_args_slots())
-        toks = np.asarray(toks)                        # (k, B_slots)
-        oks = np.asarray(oks)
+        with self._span("decode_prepare"):
+            write_pos, rope_pos, budget = self._slot_decode_state()
+            live = int(self.active.sum())
+            # what the paged kernel attends at the chunk's first step,
+            # and what its k steps stream: the engine alone knows both
+            # at dispatch (the bytes roofline of the kernel reads them)
+            context = int((np.minimum(write_pos, budget - 1)
+                           + 1)[self.active].sum())
+            kv_read = self._note_pages_touched(
+                write_pos[:, None] + np.arange(k), budget)
+            # per-slot draw counters: the next token's index is exactly
+            # the count already emitted — slot- and replica-invariant,
+            # so a failover replay reproduces the stream
+            args = (self.gen._params(), self.model.bn_state, self.pool,
+                    self.page_tables, self.last_tok, write_pos, rope_pos,
+                    self.row_len, self.prompt_pad, budget, self.poison,
+                    self.temps, self.top_ps, self.top_ks, self.seeds,
+                    self.emitted.copy(), *self._lora_args_slots())
+        with self._span("decode_dispatch", k=k, slots=live,
+                        context_tokens=context, kv_read_bytes=kv_read):
+            toks, oks, self.pool = self._compiled_call(
+                ("decode", k), lambda: self._build_decode(k), *args)
+        with self._span("token_fetch"):
+            toks = np.asarray(toks)                    # (k, B_slots)
+            oks = np.asarray(oks)
         self.decode_steps += k
-        for slot in range(self.slots):
-            for t in range(k):
-                if not self.active[slot]:
-                    break  # retired mid-chunk: later tokens are truncated
-                # occupancy counts USEFUL slot-steps only — a slot that
-                # retires mid-chunk stops counting, so the metric is not
-                # inflated by the truncated past-retirement steps
-                self._occupancy_sum += 1
-                self._record_token(slot, int(toks[t, slot]),
-                                   bool(oks[t, slot]))
+        with self._span("record_tokens") as sp:
+            kept0 = self._tokens_emitted
+            for slot in range(self.slots):
+                for t in range(k):
+                    if not self.active[slot]:
+                        break  # retired mid-chunk: the rest is truncated
+                    # occupancy counts USEFUL slot-steps only — a slot
+                    # that retires mid-chunk stops counting, so the
+                    # metric is not inflated by the truncated
+                    # past-retirement steps
+                    self._occupancy_sum += 1
+                    self._record_token(slot, int(toks[t, slot]),
+                                       bool(oks[t, slot]))
+            sp.annotate(tokens=self._tokens_emitted - kept0,
+                        retired=live - int(self.active.sum()))
 
     def _spec_step(self):
         """One speculative iteration: the draft proposes K tokens per
@@ -3283,7 +3346,8 @@ class ServingEngine:
         iteration's slab position 0, exactly like the greedy path's
         mismatch token."""
         k = self.speculate_k
-        write_pos, rope_pos, budget = self._slot_decode_state()
+        with self._span("decode_prepare"):
+            write_pos, rope_pos, budget = self._slot_decode_state()
         ctr0 = self.emitted.copy().astype(np.int32)
         # greedy-only iterations never read the p/q probability tensors
         # — skip their device-to-host transfers (B*(K+1)*V floats per
@@ -3293,7 +3357,7 @@ class ServingEngine:
         sampled_live = bool(self.active.any()) and bool(
             np.any(self.temps[self.active] > 0.0))
         # verify-slab frontier (the draft's decode mirrors the same pages)
-        self._note_pages_touched(write_pos + k, budget)
+        self._note_pages_touched((write_pos + k)[:, None], budget)
         d_toks, d_probs, self.draft_pool = self._compiled_call(
             ("draft_propose", k),
             lambda: self._build_draft_propose(k),
@@ -3301,9 +3365,10 @@ class ServingEngine:
             self.draft_pool, self.page_tables, self.last_tok, write_pos,
             rope_pos, self.row_len, self.prompt_pad, budget,
             self.temps, self.top_ps, self.top_ks, self.seeds, ctr0)
-        d_toks = np.asarray(d_toks)                    # (k, B_slots)
-        if sampled_live:
-            d_probs = np.asarray(d_probs)              # (k, B_slots, V)
+        with self._span("draft_fetch"):
+            d_toks = np.asarray(d_toks)                # (k, B_slots)
+            if sampled_live:
+                d_probs = np.asarray(d_probs)          # (k, B_slots, V)
         slab = np.concatenate(
             [self.last_tok[:, None].astype(np.int32), d_toks.T], axis=1)
         # per-position write slots, clamped to each request's own budget
@@ -3320,10 +3385,11 @@ class ServingEngine:
             self.prompt_pad, self.poison,
             self.temps, self.top_ps, self.top_ks,
             *self._lora_args_slots())
-        t_toks = np.asarray(t_toks)                    # (B_slots, k+1)
-        if sampled_live:
-            t_probs = np.asarray(t_probs)              # (B, k+1, V)
-        t_oks = np.asarray(t_oks)
+        with self._span("verify_fetch"):
+            t_toks = np.asarray(t_toks)                # (B_slots, k+1)
+            if sampled_live:
+                t_probs = np.asarray(t_probs)          # (B, k+1, V)
+            t_oks = np.asarray(t_oks)
         self.decode_steps += k + 1
         self._spec_dispatches += 1
         # sampled slots need the accept uniforms; greedy-only iterations
@@ -3331,11 +3397,13 @@ class ServingEngine:
         # first sampled request mid-traffic compiles nothing)
         u = None
         if sampled_live:
-            u = np.asarray(self._compiled_call(
+            u = self._compiled_call(
                 ("spec_uniforms", k),
                 lambda: jax.jit(
                     lambda s, c: sampling_ops.accept_uniforms(s, c, k)),
-                self.seeds, ctr0))                     # (B_slots, k)
+                self.seeds, ctr0)
+            with self._span("uniforms_fetch"):
+                u = np.asarray(u)                      # (B_slots, k)
         # ---- the HOST-side accept rule --------------------------------
         accepts = np.zeros((self.slots,), np.int32)
         p_rows = np.zeros((self.slots, self._vocab), np.float32)
@@ -3375,11 +3443,13 @@ class ServingEngine:
             # the in-graph residual re-draw (one fixed-shape dispatch
             # covers every sampled slot's rejection OR bonus draw; the
             # draw index is the emitted token's position)
-            res = np.asarray(self._compiled_call(
+            res = self._compiled_call(
                 ("spec_resample",),
                 lambda: jax.jit(sampling_ops.residual_sample),
                 p_rows, q_rows, self.seeds,
-                (ctr0 + accepts).astype(np.int32)))
+                (ctr0 + accepts).astype(np.int32))
+            with self._span("resample_fetch"):
+                res = np.asarray(res)
         # ---- emit -----------------------------------------------------
         for slot in range(self.slots):
             if not self.active[slot]:
@@ -3405,23 +3475,16 @@ class ServingEngine:
                 self._record_token(slot, tok, bool(t_oks[slot, m]))
 
     def _decode_tick(self):
-        tm = self._tm_on and telemetry.enabled()
-        if tm:
-            t0 = time.perf_counter()
-            slots = int(self.active.sum())
-            toks0 = self._tokens_emitted
-        if self.speculate_k > 0 and self.draft_gen is not None:
-            self._spec_step()
-        else:
-            self._decode_step()
-        if tm:
-            # one engine-track span per decode dispatch: the fleet
-            # timeline shows each replica's chunk cadence without
-            # per-token events
-            telemetry.tracer().complete(
-                "decode_chunk", t0, time.perf_counter() - t0,
-                track=self._tm_track, slots=slots,
-                tokens=self._tokens_emitted - toks0)
+        # one engine-track span per decode dispatch: the fleet timeline
+        # shows each replica's chunk cadence without per-token events
+        toks0 = self._tokens_emitted
+        with self._span("decode_chunk",
+                        slots=int(self.active.sum())) as sp:
+            if self.speculate_k > 0 and self.draft_gen is not None:
+                self._spec_step()
+            else:
+                self._decode_step()
+            sp.annotate(tokens=self._tokens_emitted - toks0)
 
     def step(self) -> bool:
         """One scheduler tick: admit what fits (unless draining), then one
@@ -3434,19 +3497,29 @@ class ServingEngine:
         the tick itself never contends)."""
         try:
             with self._lock:
-                if not self._draining:
-                    self._admit()
-                # mid-prefill slots spend their per-tick chunk budget
-                # between admit and the decode dispatch — draining
-                # included (an admitted request is never cancelled, so
-                # a drain must finish its prefill to retire it)
-                self._prefill_tick()
-                if self.active.any():
-                    self._decode_tick()
-                if self._draining:
-                    out = bool(self.active.any()) or bool(self._partial)
-                else:
-                    out = self.pending()
+                self._tick_seq += 1
+                with self._span("engine_step", tick=self._tick_seq,
+                                queued=len(self._queue),
+                                active=int(self.active.sum())):
+                    if self._queue and not self._draining:
+                        with self._span("admit") as sp:
+                            # requeued: still waiting for a slot, pages
+                            # or an adapter page
+                            sp.annotate(admitted=self._admit(),
+                                        requeued=len(self._queue))
+                    # mid-prefill slots spend their per-tick chunk
+                    # budget between admit and the decode dispatch —
+                    # draining included (an admitted request is never
+                    # cancelled, so a drain must finish its prefill to
+                    # retire it)
+                    self._prefill_tick()
+                    if self.active.any():
+                        self._decode_tick()
+                    if self._draining:
+                        out = bool(self.active.any()) \
+                            or bool(self._partial)
+                    else:
+                        out = self.pending()
         except Exception as e:  # noqa: BLE001 — an uncaught engine
             #   exception is a flight-recorder trigger (the lock is
             #   released by the time we get here; trip() only schedules,
@@ -3460,7 +3533,8 @@ class ServingEngine:
         if self._tm_on:
             # serving-side SLO tick: one predicate + one time compare
             # until a full window has elapsed
-            flightrec.slo_monitor().maybe_evaluate()
+            with self._span("slo_tick"):
+                flightrec.slo_monitor().maybe_evaluate()
         return out
 
     def run(self, prompts=None, max_new_tokens: int = 32,
@@ -3817,6 +3891,7 @@ class ServingEngine:
             "paged_attention_impl": self.paged_attention_impl,
             "paged_prefill_impl": self.paged_prefill_impl,
             "pages_touched": self._pages_touched,
+            "kv_read_bytes": self._kv_read_bytes,
             "last_pages_touched": self._last_pages_touched,
             **{f"kernel_tune_{k}": v - self._ktune_base.get(k, 0)
                for k, v in _ktune_stats().items()

@@ -29,7 +29,13 @@ Three pieces, one process-wide substrate:
 
   * **Per-request tracing** — ``span()`` / ``begin()``+``end()`` /
     ``complete()`` record into one bounded in-memory ring (fixed memory:
-    old events fall off; ``TRACE_RING_CAP`` events). Every event carries
+    old events fall off; ``TRACE_RING_CAP`` events). A live ``span()``
+    has a second sink: in the same enter/exit it is a
+    ``jax.profiler.TraceAnnotation`` named ``ff.<name>`` with the span's
+    args as stats, so whenever a profiler trace is running the span lies
+    in the profiler's host plane on the clock of the device ops (one
+    inactive TraceMe check when none is). ``begin()``/``end()``,
+    ``complete()`` and ``instant()`` stay ring-only. Every event carries
     a ``trace_id`` that rides the request across threads, replicas,
     resubmission and the prefill->decode page handoff, so the span tree
     for one request is reconstructible fleet-wide
@@ -60,6 +66,8 @@ import threading
 import time
 import weakref
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from flexflow_tpu.runtime import locks
 
@@ -482,12 +490,20 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """Context-manager span: records one Chrome "X" (complete) event at
-    exit. Pushes its trace id on the thread-local stack so nested spans
-    and log lines inherit it."""
+# a span's name in a profiler trace is its ring name with this in front
+PROFILER_PREFIX = "ff."
 
-    __slots__ = ("tracer", "name", "trace_id", "track", "args", "_t0")
+
+class _Span:
+    """Context-manager span with two sinks: one Chrome "X" (complete)
+    event in the ring at exit, and a ``jax.profiler.TraceAnnotation``
+    (``ff.<name>``, the args as stats) entered and left with it, which a
+    running profiler trace records on the device ops' clock. Pushes its
+    trace id on the thread-local stack so nested spans and log lines
+    inherit it."""
+
+    __slots__ = ("tracer", "name", "trace_id", "track", "args", "_t0",
+                 "_ann")
 
     def __init__(self, tracer, name, trace_id, track, args):
         self.tracer = tracer
@@ -496,12 +512,22 @@ class _Span:
         self.track = track
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def annotate(self, **kv):
+        """Counts known only once the phase has run (tokens emitted,
+        requests retired): into the ring event's args and, while the
+        span is open, the annotation's stats."""
         self.args.update(kv)
+        if self._ann is not None:
+            self._ann.set_metadata(**kv)
         return self
 
     def __enter__(self):
+        stats = self.args if self.trace_id is None \
+            else {**self.args, "trace_id": self.trace_id}
+        self._ann = TraceAnnotation(PROFILER_PREFIX + self.name, **stats)
+        self._ann.__enter__()
         self._t0 = _now_us()
         stack = getattr(_tls, "trace_stack", None)
         if stack is None:
@@ -516,6 +542,8 @@ class _Span:
         if etype is not None:
             self.args.setdefault("error", f"{etype.__name__}: {evalue}")
         t1 = _now_us()
+        self._ann.__exit__(etype, evalue, tb)
+        self._ann = None
         self.tracer._emit(self.name, "X", self._t0, t1 - self._t0,
                           self.trace_id, self.track, self.args)
         return False
@@ -553,8 +581,10 @@ class Tracer:
 
     def span(self, name: str, trace_id: Optional[str] = None,
              track: Optional[str] = None, **args):
-        """Context-manager span (same-thread begin/end). Returns the
-        shared no-op span when telemetry is off."""
+        """Context-manager span (same-thread begin/end), written to the
+        ring and to the profiler's host plane as ``ff.<name>`` with
+        ``args`` as its stats. Returns the shared no-op span when
+        telemetry is off: neither sink."""
         if not _enabled:
             return NULL_SPAN
         if trace_id is None:

@@ -643,3 +643,259 @@ def test_fit_emits_step_spans_and_histogram():
     sid = steps[-1]["args"]["trace_id"]
     names = set(telemetry.trace_tree(sid)["names"])
     assert "host_wait" in names and "dispatch" in names
+
+
+# ------------------------------- the tick's spans, ring and profiler (ISSUE 23)
+
+# a decode tick's phases, in the order they run inside `decode_chunk`
+DECODE_PHASES = ["decode_prepare", "decode_dispatch", "token_fetch",
+                 "record_tokens"]
+
+
+def _inside(child, parent, slack=1.0):
+    """Ring events: child's [ts, ts + dur] within parent's (us)."""
+    return (parent["ts"] - slack <= child["ts"]
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + slack)
+
+
+def _ticks(eng, since_us):
+    """[(engine_step event, [the events under it, by start])] of `eng`'s
+    track since `since_us`, from the ring."""
+    evs = [e for e in telemetry.tracer().events()
+           if e["pid"] == eng._tm_track and e["ph"] == "X"
+           and e["ts"] >= since_us]
+    out = []
+    for step in (e for e in evs if e["name"] == "engine_step"):
+        kids = sorted((e for e in evs if e is not step
+                       and e["name"] not in ("queue_wait", "decode")
+                       and _inside(e, step)),
+                      key=lambda e: (e["ts"], -e["dur"]))
+        out.append((step, kids))
+    return out
+
+
+def _run_warm(eng, seed, lengths, max_new):
+    """Run the same prompts twice (the first run compiles); returns the
+    second run's requests, the ring clock before it and the stats delta."""
+    eng.run(_prompts(seed, lengths), max_new_tokens=max_new)
+    eng.flush_prefix_cache()
+    st0, t0 = eng.stats(), telemetry.now_us()
+    reqs = eng.run(_prompts(seed, lengths), max_new_tokens=max_new)
+    st1 = eng.stats()
+    return reqs, t0, {k: st1[k] - st0[k] for k in
+                      ("decode_steps", "pages_touched", "kv_read_bytes",
+                       "tokens_generated", "completed")}
+
+
+@pytest.fixture(scope="module")
+def profiled(ff, tmp_path_factory):
+    """ONE jax.profiler trace on the CPU holding hand-made spans, a tiny
+    engine run with telemetry on and one with telemetry off, each under a
+    marker annotation; the host plane's `ff.*` and marker events with their
+    stats, and what the ring and the engine's counters said meanwhile."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    eng = ff.make_serving_engine(max_seq_len=32, kv_page_size=8,
+                                 decode_chunk=2)
+    eng.run(_prompts(21, [5, 9, 12]), max_new_tokens=5)      # compiles
+    eng.flush_prefix_cache()
+    prev = ff.config.telemetry
+    ff.config.telemetry = "off"
+    try:
+        off = ff.make_serving_engine(max_seq_len=32, kv_page_size=8,
+                                     decode_chunk=2)
+    finally:
+        ff.config.telemetry = prev
+    off.run(_prompts(21, [5, 9, 12]), max_new_tokens=5)
+    off.flush_prefix_cache()
+
+    logdir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    tr = telemetry.tracer()
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        with TraceAnnotation("mark.manual"):
+            with tr.span("outer", trace_id="prof-1", track="t", depth=0):
+                with tr.span("x", n=3) as sp:
+                    sp.annotate(tokens=5)
+            was = telemetry.set_enabled(False)
+            with tr.span("disabled", n=1):
+                pass
+            telemetry.set_enabled(was)
+        st0, t0 = eng.stats(), telemetry.now_us()
+        with TraceAnnotation("mark.on"):
+            reqs = eng.run(_prompts(21, [5, 9, 12]), max_new_tokens=5)
+        st1 = eng.stats()
+        ring_before = len(tr)
+        with TraceAnnotation("mark.off"):
+            off_reqs = off.run(_prompts(21, [5, 9, 12]), max_new_tokens=5)
+        ring_after = len(tr)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(logdir + "/plugins/profile/*/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            events += [{"name": e.name, "t0": e.start_ns,
+                        "t1": e.start_ns + e.duration_ns,
+                        "stats": dict(e.stats)}
+                       for e in line.events
+                       if e.name.startswith(("ff.", "mark."))]
+
+    def under(mark):
+        (m,) = [e for e in events if e["name"] == "mark." + mark]
+        return [e for e in events if e["name"].startswith("ff.")
+                and m["t0"] <= e["t0"] and e["t1"] <= m["t1"]]
+
+    return {"under": under, "eng": eng, "reqs": reqs, "off_reqs": off_reqs,
+            "ring_since": t0, "ring_grew_off": ring_after - ring_before,
+            "delta": {k: st1[k] - st0[k] for k in
+                      ("decode_steps", "kv_read_bytes", "pages_touched")}}
+
+
+@pytest.mark.parametrize("case", ["count at entry", "count from annotate()",
+                                  "nests inside the enclosing span",
+                                  "trace id rides as a stat",
+                                  "set_enabled(False) writes no annotation"])
+def test_span_lies_in_the_profilers_host_plane(profiled, case):
+    by = {e["name"]: e for e in profiled["under"]("manual")}
+    if case == "count at entry":
+        assert by["ff.x"]["stats"]["n"] == 3
+    elif case == "count from annotate()":
+        assert by["ff.x"]["stats"]["tokens"] == 5
+    elif case == "nests inside the enclosing span":
+        assert by["ff.outer"]["t0"] <= by["ff.x"]["t0"]
+        assert by["ff.x"]["t1"] <= by["ff.outer"]["t1"]
+    elif case == "trace id rides as a stat":
+        assert by["ff.outer"]["stats"] == {"depth": 0, "trace_id": "prof-1"}
+        assert by["ff.x"]["stats"]["trace_id"] == "prof-1"   # inherited
+    else:
+        assert set(by) == {"ff.outer", "ff.x"}
+
+
+def test_ring_and_profiler_hold_the_same_tick_spans(profiled):
+    """One call site, two sinks: every live span of the engine's run is in
+    the ring under its name and in the host plane under `ff.` + name, as
+    often; the waits and lifecycles that cross ticks are ring-only."""
+    eng = profiled["eng"]
+    ring = [e["name"] for e in telemetry.tracer().events()
+            if e["pid"] == eng._tm_track and e["ph"] == "X"
+            and e["ts"] >= profiled["ring_since"]]
+    plane = [e["name"] for e in profiled["under"]("on")]
+    ring_only = {"queue_wait", "decode"}
+    assert ring_only <= set(ring)
+    assert sorted("ff." + n for n in ring if n not in ring_only) \
+        == sorted(plane)
+    assert {"ff.engine_step", "ff.admit", "ff.prefill", "ff.prefill_fetch",
+            "ff.decode_chunk", "ff.slo_tick"} \
+        | {"ff." + n for n in DECODE_PHASES} == set(plane)
+    # the host blocked on the device only under a *_fetch name, and the
+    # dispatch's counts reached the profiler as stats
+    disp = [e["stats"] for e in profiled["under"]("on")
+            if e["name"] == "ff.decode_dispatch"]
+    assert sum(d["k"] for d in disp) == profiled["delta"]["decode_steps"]
+    assert sum(d["kv_read_bytes"] for d in disp) \
+        == profiled["delta"]["kv_read_bytes"] > 0
+
+
+def test_telemetry_off_writes_neither_ring_event_nor_annotation(profiled):
+    assert all(r.state == "done" for r in profiled["off_reqs"])
+    assert profiled["under"]("off") == []
+    assert profiled["ring_grew_off"] == 0
+
+
+def test_tick_span_tree_and_dispatch_counts(ff):
+    eng = ff.make_serving_engine(max_seq_len=32, kv_page_size=8,
+                                 decode_chunk=2)
+    reqs, since, delta = _run_warm(eng, 23, [5, 9, 12, 7], 6)
+    assert all(r.state == "done" for r in reqs)
+    ticks = _ticks(eng, since)
+    assert ticks and [s["args"]["tick"] for s, _ in ticks] \
+        == list(range(ticks[0][0]["args"]["tick"],
+                      ticks[0][0]["args"]["tick"] + len(ticks)))
+    admitted = 0
+    for step, kids in ticks:
+        by = {}
+        for e in kids:
+            by.setdefault(e["name"], []).append(e)
+        assert set(by) <= {"admit", "prefill", "prefill_fetch",
+                           "decode_chunk", *DECODE_PHASES}
+        for adm in by.get("admit", []):
+            assert len(by["prefill"]) == adm["args"]["admitted"]
+            admitted += adm["args"]["admitted"]
+            for p, f in zip(by["prefill"], by["prefill_fetch"]):
+                assert _inside(p, adm) and _inside(f, p)
+                assert p["args"]["kind"] == "cold" and p["args"]["ok"]
+        if "decode_chunk" in by:
+            (chunk,) = by["decode_chunk"]
+            phases = [by[n][0] for n in DECODE_PHASES]
+            assert all(len(by[n]) == 1 for n in DECODE_PHASES)
+            assert all(_inside(p, chunk) for p in phases)
+            # one after another, in the order they run
+            assert all(a["ts"] + a["dur"] <= b["ts"] + 1.0
+                       for a, b in zip(phases, phases[1:]))
+            assert chunk["args"]["tokens"] \
+                == by["record_tokens"][0]["args"]["tokens"]
+    assert admitted == len(reqs)
+    disp = [e["args"] for _, kids in ticks for e in kids
+            if e["name"] == "decode_dispatch"]
+    assert sum(d["k"] for d in disp) == delta["decode_steps"]
+    assert sum(d["kv_read_bytes"] for d in disp) == delta["kv_read_bytes"]
+    # pages_touched counts the final step's frontier once per dispatch: k
+    # steps of it bound what the k steps stream
+    st = eng.stats()
+    per_page = eng.page_size * st["kv_bytes_per_token"]
+    assert 0 < delta["kv_read_bytes"] \
+        <= delta["pages_touched"] * eng.decode_chunk * per_page
+    retired = sum(e["args"]["retired"] for _, kids in ticks for e in kids
+                  if e["name"] == "record_tokens")
+    first_token_retired = sum(1 for r in reqs if len(r.tokens) == 1)
+    assert retired + first_token_retired == delta["completed"]
+
+
+@pytest.mark.parametrize("slots,chunk", [(2, 2), (8, 8)])
+def test_spans_per_tick_do_not_grow_with_slots_or_chunk(ff, slots, chunk):
+    """One span per phase per tick: 7 under `engine_step` and itself, plus
+    two per admitted request; never one per token or per slot."""
+    eng = ff.make_serving_engine(max_seq_len=48, kv_page_size=8,
+                                 serve_slots=slots, decode_chunk=chunk)
+    reqs, since, delta = _run_warm(eng, 29, [5, 9, 12, 7, 6, 11, 8, 10], 17)
+    assert all(r.state == "done" for r in reqs)
+    assert delta["tokens_generated"] == 8 * 17
+    ticks = _ticks(eng, since)
+    for step, kids in ticks:
+        admitted = sum(e["args"]["admitted"] for e in kids
+                       if e["name"] == "admit")
+        assert len(kids) <= 6 + 2 * admitted
+    assert max(len(kids) for _, kids in ticks
+               if not any(e["name"] == "admit" for e in kids)) == 5
+    slo = [e for e in telemetry.tracer().events(name="slo_tick")
+           if e["pid"] == eng._tm_track and e["ts"] >= since]
+    assert len(slo) == len(ticks)
+
+
+@pytest.mark.parametrize("mode", ["on", "off"])
+def test_t_admit_splits_ttft(ff, mode):
+    """queue wait + prefill: t_submit <= t_admit <= first token, whether or
+    not telemetry is on (the stamp is the request's, not the ring's)."""
+    prev = ff.config.telemetry
+    ff.config.telemetry = mode
+    try:
+        eng = ff.make_serving_engine(max_seq_len=32, kv_page_size=8,
+                                     serve_slots=2)
+    finally:
+        ff.config.telemetry = prev
+    reqs = eng.run(_prompts(31, [5, 9, 12, 7, 6]), max_new_tokens=3)
+    assert all(r.state == "done" for r in reqs)
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_submit + r.ttft
+    # 5 requests through 2 slots: the later ones really waited
+    assert max(r.t_admit - r.t_submit for r in reqs) \
+        > min(r.t_admit - r.t_submit for r in reqs)
