@@ -29,10 +29,23 @@ greedy streams are bitwise-identical to a greedy-only engine.
 
 Warping semantics (shared by the sampler and ``sampling_probs`` — the
 rejection-sampling accept rule depends on the two agreeing): logits are
-divided by temperature, then the top-k and top-p keep-sets are computed
-independently on that warped distribution and intersected; the top-1
-token always survives. The sampling distribution is the softmax over
-the surviving logits.
+divided by temperature and ranked in descending order, ties by
+vocabulary index (a stable sort). Both filters are rank cutoffs on that
+warped distribution: top-k keeps ranks ``< top_k``, top-p keeps the
+ranked prefix whose exclusive cumulative probability mass is
+``< top_p``. So the keep-set is "ranks ``< m``" with ``m = min(top_k or
+V, nucleus prefix length)``, at least 1: the top-1 token always
+survives. The sampling distribution is the softmax over the surviving
+logits.
+
+How the keep-set is computed (``_masked_warped``): nothing is ranked.
+One values-only sort gives the warped values in descending order; the
+nucleus prefix length is counted on them; the value at sorted position
+``m - 1`` is the row's threshold. Everything strictly above the
+threshold is kept, and of the entries tied AT it the first few by
+vocabulary index (one scan over the tie mask), exactly the entries a
+stable ranking puts below ``m``. No index array, no inverse
+permutation and no vocabulary-wide gather is built.
 """
 
 from __future__ import annotations
@@ -85,26 +98,38 @@ def slot_keys(seeds, counters, tag: int):
 def _masked_warped(logits, temps, top_ps, top_ks):
     """(B, V) f32 masked warped logits for the temperature>0 rows (rows
     with temperature 0 are resolved by the callers via argmax). The
-    surviving set is (top-k keep) AND (top-p keep), computed on the
-    warped distribution; rank 0 always survives."""
+    surviving set is ranks ``< m`` of the warped distribution, ``m`` the
+    smaller of the top-k and the top-p cutoff (module docstring); rank 0
+    always survives."""
     logits = logits.astype(jnp.float32)
     temps = temps.astype(jnp.float32)
     safe_t = jnp.where(temps > 0.0, temps, 1.0)[:, None]
     warped = logits / safe_t
-    # rank every vocab position by warped value (jnp.argsort is stable,
-    # so ties break by vocab index — the lax.top_k order)
-    order = jnp.argsort(-warped, axis=-1)
-    ranks = jnp.argsort(order, axis=-1)
-    k = jnp.asarray(top_ks, jnp.int32)[:, None]
-    keep_k = (k <= 0) | (ranks < k)
-    probs = jax.nn.softmax(warped, axis=-1)
-    sorted_probs = jnp.take_along_axis(probs, order, axis=-1)
+    # the warped values in descending order: values only, and unstable,
+    # because a stable sort carries an index payload to break ties and
+    # equal values need no order
+    sv = -jnp.sort(-warped, axis=-1, stable=False)
+    # their probabilities, with softmax(warped)'s own maximum and
+    # denominator (summed in vocabulary order), so sorted position j
+    # holds exactly the probability of the entry a ranking puts there
+    top = sv[:, :1]
+    denom = jnp.sum(jnp.exp(warped - top), axis=-1, keepdims=True)
+    sorted_probs = jnp.exp(sv - top) / denom
     csum = jnp.cumsum(sorted_probs, axis=-1)
-    # keep sorted position j iff the mass strictly BEFORE it is < top_p:
-    # the smallest prefix reaching top_p survives, rank 0 always does
-    keep_sorted = (csum - sorted_probs) < top_ps.astype(jnp.float32)[:, None]
-    keep_p = jnp.take_along_axis(keep_sorted, ranks, axis=-1)
-    keep = keep_k & keep_p
+    # sorted position j is in the nucleus iff the mass strictly BEFORE
+    # it is < top_p: the smallest prefix reaching top_p, never empty
+    in_nucleus = (csum - sorted_probs) < top_ps.astype(jnp.float32)[:, None]
+    m = jnp.sum(in_nucleus, axis=-1, keepdims=True, dtype=jnp.int32)
+    k = jnp.asarray(top_ks, jnp.int32)[:, None]
+    m = jnp.maximum(jnp.where(k > 0, jnp.minimum(k, m), m), 1)
+    thr = jnp.take_along_axis(sv, m - 1, axis=-1)        # (B, 1)
+    above = warped > thr
+    tied = warped == thr
+    # ranks < m = everything above the threshold + the first
+    # m - count(above) of the entries tied at it, by vocabulary index
+    room = m - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    keep = above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                            <= room))
     return jnp.where(keep, warped, -jnp.inf)
 
 

@@ -1390,6 +1390,7 @@ class ServingEngine:
         self._adapter_requests: Dict[str, int] = {}
         self._adapter_spec: Dict[str, List[int]] = {}
         self._sampled_requests = 0
+        self._sampled_slot_steps = 0
 
         # ---- unified telemetry plane (ISSUE 13) ----
         # the engine's latency histograms (TTFT / inter-token / queue
@@ -3270,7 +3271,7 @@ class ServingEngine:
         self._kv_read_bytes += kv_read
         return kv_read
 
-    def _decode_step(self):
+    def _decode_step(self, sampled: int):
         k = self.decode_chunk
         with self._span("decode_prepare"):
             write_pos, rope_pos, budget = self._slot_decode_state()
@@ -3290,7 +3291,7 @@ class ServingEngine:
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
-        with self._span("decode_dispatch", k=k, slots=live,
+        with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read):
             toks, oks, self.pool = self._compiled_call(
                 ("decode", k), lambda: self._build_decode(k), *args)
@@ -3478,13 +3479,19 @@ class ServingEngine:
         # one engine-track span per decode dispatch: the fleet timeline
         # shows each replica's chunk cadence without per-token events
         toks0 = self._tokens_emitted
+        steps0 = self.decode_steps
+        # live slots whose sampler's warp is USED this dispatch (the
+        # program computes it for every row; temperature-0 rows discard
+        # it): the engage share of a future all-greedy gate
+        sampled = int(np.count_nonzero(self.temps[self.active] > 0.0))
         with self._span("decode_chunk",
                         slots=int(self.active.sum())) as sp:
             if self.speculate_k > 0 and self.draft_gen is not None:
                 self._spec_step()
             else:
-                self._decode_step()
+                self._decode_step(sampled)
             sp.annotate(tokens=self._tokens_emitted - toks0)
+        self._sampled_slot_steps += sampled * (self.decode_steps - steps0)
 
     def step(self) -> bool:
         """One scheduler tick: admit what fits (unless draining), then one
@@ -3783,6 +3790,10 @@ class ServingEngine:
             "occupancy": (self._occupancy_sum
                           / max(1, self.decode_steps) / self.slots),
             "occupied_slot_steps": self._occupancy_sum,
+            # slot-steps DISPATCHED with temperature > 0 (live sampled
+            # slots x the dispatch's steps, counted at dispatch: a slot
+            # that retires mid-chunk still counts its whole chunk)
+            "sampled_slot_steps": self._sampled_slot_steps,
             "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
             "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
             "free_pages": len(self._free_pages),

@@ -215,6 +215,152 @@ def test_sampler_top_k_top_p_masks():
     assert np.allclose(pk.sum(-1), 1.0, atol=1e-5)
 
 
+# The keep-set, from the module docstring's definition, in plain numpy:
+# rank the warped row in descending order with ties by vocabulary index;
+# top-k keeps ranks < top_k, top-p keeps the ranked prefix whose
+# exclusive cumulative mass is < top_p; rank 0 always survives. The mass
+# is summed in f64 here and in f32 (in another order) by the program, so
+# a cutoff whose exclusive mass is within _MASS_TOL of top_p may fall on
+# either side: the oracle gives the range [m_lo, m_hi] of cutoffs it
+# allows, and m_lo == m_hi wherever top-k binds or the margin is wide.
+_MASS_TOL = 4e-6
+
+
+def _oracle_row(row, temp, top_p, top_k):
+    w = (row / np.float32(temp if temp > 0 else 1.0)).astype(np.float32)
+    order = np.argsort(-w, kind="stable")
+    ranks = np.empty(w.size, np.int64)
+    ranks[order] = np.arange(w.size)
+    e = np.exp(w[order].astype(np.float64) - float(w.max()))
+    p = e / e.sum()
+    excl = np.cumsum(p) - p
+    tp = float(np.float32(top_p))
+    m_lo = max(1, int((excl < tp - _MASS_TOL).sum()))
+    m_hi = max(1, int((excl < tp + _MASS_TOL).sum()))
+    if top_k > 0:
+        m_lo, m_hi = min(m_lo, top_k), min(m_hi, top_k)
+    return w, ranks, m_lo, m_hi
+
+
+def _ranked_masked_warped(logits, temps, top_ps, top_ks):
+    """The full ranking this sampler used to compute (two argsorts, two
+    vocabulary-wide gathers), kept as the reference: (masked, whether
+    each row's nucleus was a prefix of the ranking)."""
+    import jax
+    import jax.numpy as jnp
+    safe_t = jnp.where(temps > 0.0, temps, 1.0)[:, None]
+    warped = jnp.asarray(logits, jnp.float32) / safe_t
+    order = jnp.argsort(-warped, axis=-1)
+    ranks = jnp.argsort(order, axis=-1)
+    k = jnp.asarray(top_ks, jnp.int32)[:, None]
+    keep_k = (k <= 0) | (ranks < k)
+    sorted_probs = jnp.take_along_axis(
+        jax.nn.softmax(warped, axis=-1), order, axis=-1)
+    csum = jnp.cumsum(sorted_probs, axis=-1)
+    keep_sorted = (csum - sorted_probs) < jnp.asarray(top_ps)[:, None]
+    keep = keep_k & jnp.take_along_axis(keep_sorted, ranks, axis=-1)
+    is_prefix = ~jnp.any(keep_sorted[:, 1:] & ~keep_sorted[:, :-1], axis=-1)
+    return jnp.where(keep, warped, -jnp.inf), is_prefix
+
+
+_TOP_KS = (0, 1, 5, 50, 1 << 20)        # the last is >= V for every V
+_TOP_PS = (1.0, 0.9, 0.5, 1e-6)
+_TEMPS = (0.7, 1.0, 0.0, 1.3, 0.5)
+
+
+def _sampler_case(kind, vocab):
+    """One row per (top_k, top_p) pair, temperatures cycling through
+    _TEMPS (0 included): (logits, temps, top_ps, top_ks, seeds, ctrs)."""
+    rs = np.random.RandomState(vocab + len(kind))
+    n = len(_TOP_KS) * len(_TOP_PS)
+    x = rs.randn(n, vocab).astype(np.float32)
+    if kind == "bf16":           # bf16-valued logits: 8 bits, many ties
+        x = (x.view(np.uint32) & np.uint32(0xFFFF0000)).view(np.float32)
+    elif kind == "equal":        # every entry ties with every other
+        x[:] = np.float32(1.5)
+    elif kind == "cutoff-ties":
+        # 6 distinct values: every cutoff, top-k or top-p, falls inside
+        # a group of tied entries
+        x = rs.randint(0, 6, (n, vocab)).astype(np.float32)
+    else:
+        assert kind == "f32"
+    tks, tps = np.meshgrid(_TOP_KS, _TOP_PS, indexing="ij")
+    return (x, np.resize(np.asarray(_TEMPS, np.float32), n),
+            tps.ravel().astype(np.float32), tks.ravel().astype(np.int32),
+            np.arange(n, dtype=np.int32) + 11,
+            np.arange(n, dtype=np.int32) * 3)
+
+
+_SAMPLER_CASES = [(kind, vocab)
+                  for kind in ("f32", "bf16", "equal", "cutoff-ties")
+                  for vocab in (97, 1000, 92544)]
+
+
+@pytest.mark.parametrize("kind,vocab", _SAMPLER_CASES)
+def test_sampler_keep_set_matches_numpy_oracle(kind, vocab):
+    import jax
+    x, temps, tps, tks, seeds, ctrs = _sampler_case(kind, vocab)
+    masked = np.asarray(S._masked_warped(x, temps, tps, tks))
+    want = np.empty_like(masked)
+    for i in range(x.shape[0]):
+        w, ranks, m_lo, m_hi = _oracle_row(x[i], temps[i], tps[i], tks[i])
+        m = int(np.isfinite(masked[i]).sum())
+        assert m_lo <= m <= m_hi, (i, temps[i], tps[i], tks[i])
+        want[i] = np.where(ranks < m, w, -np.inf)
+    # ranks < m exactly, ties by vocabulary index, kept values untouched
+    np.testing.assert_array_equal(masked, want)
+    probs = np.asarray(S.sampling_probs(x, temps, tps, tks))
+    toks = np.asarray(S.sample_tokens(x, temps, tps, tks, seeds, ctrs))
+    hot = temps > 0
+    np.testing.assert_array_equal(probs[hot] > 0, np.isfinite(want[hot]))
+    greedy = np.argmax(x, -1)
+    np.testing.assert_array_equal(np.argmax(probs[~hot], -1), greedy[~hot])
+    assert ((probs[~hot] > 0).sum(-1) == 1).all()
+    draws = np.asarray(jax.vmap(jax.random.categorical)(
+        S.slot_keys(seeds, ctrs, S.TAG_TARGET), want))
+    np.testing.assert_array_equal(toks, np.where(hot, draws, greedy))
+
+
+@pytest.mark.parametrize("kind,vocab", _SAMPLER_CASES)
+def test_sampler_bitwise_equals_the_full_ranking(kind, vocab):
+    """The threshold computation returns the ranking's array bit for bit
+    wherever the ranking's nucleus was a prefix (the definition); where
+    rounding in the cumulative sum broke that (mass == top_p to the ulp,
+    deep in the tail), it keeps as many entries, as a prefix."""
+    x, temps, tps, tks, _, _ = _sampler_case(kind, vocab)
+    got = np.asarray(S._masked_warped(x, temps, tps, tks))
+    ref, is_prefix = map(np.asarray,
+                         _ranked_masked_warped(x, temps, tps, tks))
+    assert is_prefix.any()
+    np.testing.assert_array_equal(got[is_prefix], ref[is_prefix])
+    np.testing.assert_array_equal(np.isfinite(got).sum(-1),
+                                  np.isfinite(ref).sum(-1))
+
+
+def test_sampler_program_holds_one_sort_and_no_wide_gather():
+    """The ranking cannot come back unnoticed: at the serving cells'
+    shape the lowered sampler holds ONE sort (values only) and no gather
+    with a vocabulary-wide result (the [B, 1] threshold lookup is the
+    only one)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    b, v = 16, 92544
+    row = [jax.ShapeDtypeStruct((b,), d) for d in
+           (jnp.float32, jnp.float32, jnp.int32, jnp.int32, jnp.int32)]
+    text = jax.jit(S.sample_tokens).lower(
+        jax.ShapeDtypeStruct((b, v), jnp.float32), *row).as_text()
+    sorts = re.findall(r'"?stablehlo\.sort"?\(([^)]*)\)', text)
+    assert len(sorts) == 1 and "," not in sorts[0], sorts
+    gathers = re.findall(r'"?stablehlo\.gather"?\(.*->\s*tensor<([^>]*)>',
+                         text)
+    assert gathers, "the threshold lookup is a gather"
+    wide = [g for g in gathers
+            if int(np.prod([int(d) for d in g.split("x")[:-1]])) >= v]
+    assert not wide, wide
+    assert "stablehlo.dynamic_gather" not in text
+
+
 def test_residual_sample_math():
     """q = 0 degenerates to p; a one-hot residual is deterministic."""
     p = np.zeros((2, VOCAB), np.float32)
@@ -310,6 +456,35 @@ def test_eight_tenants_mixed_sampling_zero_recompiles(ff):
                     adapter=names[0], temperature=0.0)[0]
     assert again.tokens == reqs[0].tokens
     assert eng.recompile_count == warm
+
+
+def test_sampled_slot_steps_counts_dispatched_sampled_slots(ff):
+    """stats()["sampled_slot_steps"]: live slots with temperature > 0 x
+    the steps of each decode dispatch, the same count the dispatch's
+    span carries; greedy traffic leaves it at 0."""
+    from flexflow_tpu.runtime import telemetry
+    k = 2
+    eng = _mk_engine(ff, serve_slots=4, decode_chunk=k)
+    prompts = _prompts(9, [5, 9, 3, 7])
+    eng.run(prompts, max_new_tokens=7)
+    assert eng.stats()["sampled_slot_steps"] == 0
+    assert eng.stats()["occupied_slot_steps"] > 0
+    since = telemetry.now_us()
+    reqs = [eng.submit(p, 7, temperature=t, top_p=0.9, top_k=5, seed=i)
+            for i, (p, t) in enumerate(zip(prompts, (0.8, 0.0, 1.2, 0.0)))]
+    while eng.step():
+        pass
+    assert [r.state for r in reqs] == ["done"] * 4
+    # a request's first token comes from its prefill; the others from
+    # ceil((tokens - 1) / k) dispatches of k steps, each counted whole
+    want = sum(-(-(len(r.tokens) - 1) // k) * k
+               for r in reqs if r.temperature > 0)
+    st = eng.stats()
+    assert st["sampled_slot_steps"] == want > 0
+    disp = [e["args"] for e in
+            telemetry.tracer().events(name="decode_dispatch")
+            if e["pid"] == eng._tm_track and e["ts"] >= since]
+    assert sum(d["sampled"] * d["k"] for d in disp) == want
 
 
 def test_adapter_prefix_cache_isolation(ff):
