@@ -218,17 +218,24 @@ class FFModel:
                              hidden_size, return_sequences))
 
     def moe(self, input: Tensor, num_experts: int, hidden_dim: int,
-            k: int = 2, capacity_factor: float = 1.25,
-            dispatch: str = "auto", name: Optional[str] = None) -> Tensor:
+            k: int = 2, capacity_factor: Optional[float] = 1.25,
+            dispatch: str = "auto", expert: str = "gelu",
+            renormalize: bool = True,
+            name: Optional[str] = None) -> Tensor:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
-        aux loss is folded into the training loss automatically. dispatch:
-        "auto" (dense einsums when experts are mesh-sharded, else sort-based)
-        | "dense" | "sort"."""
+        aux loss is folded into the training loss automatically.
+        capacity_factor=None is the dropless op (one grouped-matmul
+        lowering, no dropped token; `dispatch` is not read). Otherwise
+        dispatch: "auto" (dense einsums when experts are mesh-sharded, else
+        sort-based) | "dense" | "sort". expert: "gelu" (w_in, w_out) |
+        "swiglu" (w_gate, w_up, w_down); renormalize: kept gates rescaled
+        to sum to 1 per token."""
         from flexflow_tpu.ops.moe import MoE
 
         op = MoE(self, self._name("moe", name), [input], num_experts,
-                 hidden_dim, k, capacity_factor, dispatch=dispatch)
+                 hidden_dim, k, capacity_factor, dispatch=dispatch,
+                 expert=expert, renormalize=renormalize)
         outs = self._add(op)
         self._aux_tensors.append(outs[1])
         return outs[0]
@@ -256,12 +263,13 @@ class FFModel:
                             add_zero_attn: bool = False, causal: bool = False,
                             num_kv_heads: int = 0, rope: bool = False,
                             rope_theta: float = 10000.0,
+                            qk_norm: bool = False, eps: float = 1e-6,
                             name: Optional[str] = None, **kw) -> Tensor:
         return self._add(MultiHeadAttention(
             self, self._name("multihead_attention", name), [query, key, value],
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, num_kv_heads=num_kv_heads, rope=rope,
-            rope_theta=rope_theta))
+            rope_theta=rope_theta, qk_norm=qk_norm, eps=eps))
 
     def transformer_pipeline_stack(self, input: Tensor, num_layers: int,
                                    num_heads: int, ffn_mult: int = 4,

@@ -33,27 +33,29 @@ def llama_lm(ff: FFModel, batch_size: int, seq_len: int = 256,
              hidden: int = 512, layers: int = 4, heads: int = 4,
              kv_heads: int = 0, ffn_hidden: int = 0,
              vocab_size: int = 32_000, rope_theta: float = 10000.0,
-             tie_embeddings: bool = False):
+             tie_embeddings: bool = False, rms_norm_eps: float = 1e-6):
     """Decoder-only causal LM in the Llama shape. kv_heads=0 -> MHA;
     kv_heads < heads -> grouped-query attention. ffn_hidden defaults to
     the Llama-style ~8/3 * hidden rounded to a multiple of 128.
     tie_embeddings shares the lm_head with the token embedding
-    (FFModel.tie_weights) — vocab x hidden params stored once."""
+    (FFModel.tie_weights) — vocab x hidden params stored once.
+    rms_norm_eps is every RMSNorm's epsilon (most sources publish 1e-5)."""
     if not ffn_hidden:
         ffn_hidden = max(128, (8 * hidden // 3 + 127) // 128 * 128)
     tokens = ff.create_tensor([batch_size, seq_len], dtype=DataType.DT_INT32,
                               name="input")
     t = ff.embedding(tokens, vocab_size, hidden, name="tok_embed")
     for i in range(layers):
-        a = ff.rms_norm(t, name=f"ln1_{i}")
+        a = ff.rms_norm(t, eps=rms_norm_eps, name=f"ln1_{i}")
         a = ff.multihead_attention(
             a, a, a, hidden, heads, causal=True, bias=False,
             num_kv_heads=kv_heads, rope=True, rope_theta=rope_theta,
             name=f"attn_{i}")
         t = ff.add(t, a, name=f"res1_{i}")
-        f = swiglu(ff, ff.rms_norm(t, name=f"ln2_{i}"), hidden, ffn_hidden, i)
+        f = swiglu(ff, ff.rms_norm(t, eps=rms_norm_eps, name=f"ln2_{i}"),
+                   hidden, ffn_hidden, i)
         t = ff.add(t, f, name=f"res2_{i}")
-    t = ff.rms_norm(t, name="ln_f")
+    t = ff.rms_norm(t, eps=rms_norm_eps, name="ln_f")
     logits = ff.dense(t, vocab_size, use_bias=False, name="lm_head")
     if tie_embeddings:
         ff.tie_weights("lm_head", "kernel", "tok_embed", "kernel",

@@ -182,7 +182,8 @@ class MultiHeadAttention(Op):
                  bias: bool = True, add_bias_kv: bool = False,
                  add_zero_attn: bool = False, causal: bool = False,
                  num_kv_heads: int = 0, rope: bool = False,
-                 rope_theta: float = 10000.0):
+                 rope_theta: float = 10000.0, qk_norm: bool = False,
+                 eps: float = 1e-6):
         super().__init__(model, name, inputs)
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
@@ -204,6 +205,13 @@ class MultiHeadAttention(Op):
         # the sequence dim (ring/Ulysses lowering happens further down)
         self.rope = rope
         self.rope_theta = rope_theta
+        # QK-norm (OLMoE, OLMo-2): RMSNorm with a learned scale over the
+        # WHOLE q and k projections (all heads together), after the
+        # projection and before the head split's RoPE. It lives in
+        # _project_qkv, the one place every lowering shares, so the KV
+        # cache holds k already normed and no attention kernel knows of it
+        self.qk_norm = qk_norm
+        self.eps = eps
         # kdim/vdim are total projection sizes (reference kProjSize*num_heads
         # semantics via cudnnSetAttnDescriptor, attention.cu:533-570)
         self.kdim = kdim if kdim > 0 else embed_dim
@@ -241,6 +249,10 @@ class MultiHeadAttention(Op):
             WeightSpec("wo", (self.num_heads, self.v_head_dim, self.embed_dim),
                        init="glorot", fan=(self.vdim, self.embed_dim)),
         ]
+        if self.qk_norm:
+            ws += [WeightSpec("q_norm", (self.kdim,), init="one"),
+                   WeightSpec("k_norm", (kvh * self.qk_head_dim,),
+                              init="one")]
         if self.bias:
             ws += [WeightSpec("bias_q", (self.num_heads, self.qk_head_dim), init="zero"),
                    WeightSpec("bias_k", (kvh, self.qk_head_dim), init="zero"),
@@ -259,10 +271,22 @@ class MultiHeadAttention(Op):
             qh = qh + params["bias_q"]
             kh = kh + params["bias_k"]
             vh = vh + params["bias_v"]
+        if self.qk_norm:
+            qh = self._whole_rms_norm(qh, params["q_norm"])
+            kh = self._whole_rms_norm(kh, params["k_norm"])
         if self.rope:
             qh = _apply_rope(qh, self.rope_theta, rope_offset)
             kh = _apply_rope(kh, self.rope_theta, rope_offset)
         return qh, kh, vh
+
+    def _whole_rms_norm(self, xh, scale):
+        """RMSNorm of a (B, S, H, Hd) projection over all H*Hd entries of
+        a position, statistics in f32."""
+        x = xh.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=(-2, -1), keepdims=True)
+        x = x * jax.lax.rsqrt(ms + self.eps) \
+            * scale.astype(jnp.float32).reshape(xh.shape[-2:])
+        return x.astype(xh.dtype)
 
     def _broadcast_kv(self, kh, vh):
         if self.num_kv_heads != self.num_heads:
@@ -923,6 +947,11 @@ class MultiHeadAttention(Op):
             "wv": P(None, kv_ax, None),
             "wo": P(ax, None, None),
         }
+        if self.qk_norm:
+            # the norm's mean runs over every head: GSPMD adds the
+            # cross-shard reduction, the scales stay whole
+            out["q_norm"] = P(None)
+            out["k_norm"] = P(None)
         if self.bias:
             out["bias_q"] = P(ax, None)
             out["bias_k"] = P(kv_ax, None)

@@ -16,11 +16,26 @@ and produce identical outputs when capacity does not bind (tested).
 Includes the standard load-balancing auxiliary loss (Shazeer et al.),
 surfaced through the op-aux mechanism so the executor folds it into the
 training loss.
+
+`capacity_factor=None` is the DROPLESS op (OLMoE, Mixtral and every served
+expert model: no token is ever dropped). It has one lowering, shared by
+fit(), predict, prefill and decode: the N*k (token, expert) assignments
+are stable-sorted by expert and the experts run as grouped matmuls
+(`jax.lax.ragged_dot`, on the TPU XLA's own Mosaic grouped-matmul kernel)
+over exactly N*k rows, so work and expert-weight traffic follow the
+routing instead of a capacity buffer. It reads no `dispatch` and no
+`capacity`.
+
+The architecture is described by constructor arguments, none of them a
+performance selector: `expert` ("gelu": two matrices w_in/w_out as above;
+"swiglu": w_gate, w_up (E, D, F), w_down (E, F, D)) and `renormalize`
+(kept gates rescaled to sum to 1 per token; OLMoE's `norm_topk_prob` is
+false).
 """
 
 from __future__ import annotations
 
-from typing import List
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,13 +45,19 @@ from flexflow_tpu.ffconst import DataType, OperatorType
 from flexflow_tpu.ops.base import Op, WeightSpec
 
 
+def _buffer_mm(x, w):
+    """(E, C, in) capacity buffer x (E, in, out) expert matrices."""
+    return jnp.einsum("eci,eio->eco", x, w)
+
+
 class MoE(Op):
     op_type = OperatorType.OP_MOE
     has_aux = True  # second output = scalar load-balancing loss
 
     def __init__(self, model, name, inputs, num_experts: int, hidden_dim: int,
-                 k: int = 2, capacity_factor: float = 1.25,
-                 aux_weight: float = 1e-2, dispatch: str = "auto"):
+                 k: int = 2, capacity_factor: Optional[float] = 1.25,
+                 aux_weight: float = 1e-2, dispatch: str = "auto",
+                 expert: str = "gelu", renormalize: bool = True):
         super().__init__(model, name, inputs)
         self.num_experts = num_experts
         self.hidden_dim = hidden_dim
@@ -45,14 +66,24 @@ class MoE(Op):
         self.aux_weight = aux_weight
         if dispatch not in ("auto", "dense", "sort"):
             raise ValueError(f"dispatch must be auto|dense|sort, got {dispatch!r}")
+        if expert not in ("gelu", "swiglu"):
+            raise ValueError(f"expert must be gelu|swiglu, got {expert!r}")
         self.dispatch = dispatch
+        self.expert = expert
+        self.renormalize = renormalize
         self.dim = inputs[0].dims[-1]
-        ntokens = 1
-        for s in inputs[0].dims[:-1]:
-            ntokens *= s
-        self.capacity = max(
-            1, int(capacity_factor * ntokens * self.k / num_experts))
+        self.capacity = None            # dropless: no buffer to size
+        if capacity_factor is not None:
+            ntokens = 1
+            for s in inputs[0].dims[:-1]:
+                ntokens *= s
+            self.capacity = max(
+                1, int(capacity_factor * ntokens * self.k / num_experts))
         self.finalize()
+
+    @property
+    def dropless(self) -> bool:
+        return self.capacity_factor is None
 
     def output_shapes(self):
         return ([self.inputs[0].dims, ()],
@@ -60,11 +91,22 @@ class MoE(Op):
 
     def weights(self) -> List[WeightSpec]:
         E, D, F = self.num_experts, self.dim, self.hidden_dim
-        return [
-            WeightSpec("router", (D, E), init="glorot", fan=(D, E)),
-            WeightSpec("w_in", (E, D, F), init="glorot", fan=(D, F)),
-            WeightSpec("w_out", (E, F, D), init="glorot", fan=(F, D)),
-        ]
+        up = ("w_gate", "w_up") if self.expert == "swiglu" else ("w_in",)
+        down = "w_down" if self.expert == "swiglu" else "w_out"
+        return [WeightSpec("router", (D, E), init="glorot", fan=(D, E))] + [
+            WeightSpec(w, (E, D, F), init="glorot", fan=(D, F)) for w in up
+        ] + [WeightSpec(down, (E, F, D), init="glorot", fan=(F, D))]
+
+    def _expert_ffn(self, params, x, mm):
+        """Every expert's FFN on its own rows; `mm(rows, w)` multiplies
+        each row by its expert's matrix (a batched einsum over the
+        capacity buffer, a grouped matmul over the sorted rows)."""
+        w = {k: v.astype(x.dtype) for k, v in params.items()
+             if k != "router"}
+        if self.expert == "swiglu":
+            return mm(jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"]),
+                      w["w_down"])
+        return mm(jax.nn.gelu(mm(x, w["w_in"])), w["w_out"])
 
     def _use_sort_dispatch(self) -> bool:
         if self.dispatch != "auto":
@@ -78,8 +120,15 @@ class MoE(Op):
         return not ep
 
     def forward(self, params, xs, *, training=False, rng=None,
-                capacity=None):
-        """`capacity` overrides the build-time training capacity. The
+                capacity=None, row_mask=None, routing=None):
+        """Dropless op: `row_mask` (bool, the shape of x without its last
+        dim; None = every row live) gives masked rows group size 0 and
+        output 0, so the free slots of a decode batch stream no expert;
+        `routing`, if a list, receives this call's int32 (2,) counts
+        [assignments, experts hit] (traced values: the serving engine
+        sums them inside its programs).
+
+        Capacity op: `capacity` overrides the build-time training capacity. The
         inference path (runtime/generation.py) passes N (the slab's token
         count): a token never picks the same expert twice, so per-expert
         assignments are <= N and C=N guarantees ZERO drops — standard
@@ -96,6 +145,9 @@ class MoE(Op):
         logits = t @ params["router"].astype(t.dtype)       # (N, E)
         gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
 
+        if self.dropless:
+            return self._forward_dropless(params, t, gates, orig_shape,
+                                          row_mask, routing)
         if self._use_sort_dispatch():
             return self._forward_sort(params, t, gates, orig_shape,
                                       capacity=C)
@@ -125,17 +177,14 @@ class MoE(Op):
             slots_used = slots_used + jnp.sum(onehot * fits[:, None], axis=0)
             remaining = remaining * (1.0 - onehot)
 
-        # renormalize kept gates over selected experts
-        denom = jnp.sum(combine, axis=(1, 2), keepdims=True)
-        combine = jnp.where(denom > 0, combine / jnp.maximum(denom, 1e-9),
-                            combine)
+        if self.renormalize:    # kept gates over the selected experts
+            denom = jnp.sum(combine, axis=(1, 2), keepdims=True)
+            combine = jnp.where(denom > 0,
+                                combine / jnp.maximum(denom, 1e-9), combine)
         dispatch = (combine > 0).astype(t.dtype)             # (N, E, C)
 
         expert_in = jnp.einsum("nec,nd->ecd", dispatch, t)   # (E, C, D)
-        h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in,
-                                   params["w_in"].astype(t.dtype)))
-        expert_out = jnp.einsum("ecf,efd->ecd", h,
-                                params["w_out"].astype(t.dtype))  # (E, C, D)
+        expert_out = self._expert_ffn(params, expert_in, _buffer_mm)
         y = jnp.einsum("nec,ecd->nd", combine.astype(t.dtype), expert_out)
 
         # load-balancing aux loss: E * sum(mean_gate * mean_assignment)
@@ -165,19 +214,16 @@ class MoE(Op):
         token = order % N                                   # round-major flatten
         gate = flat_g[order] * keep
 
-        # renormalize kept gates over each token's surviving experts
-        denom = jnp.zeros((N,), jnp.float32).at[token].add(gate)
-        gate = gate / jnp.maximum(denom[token], 1e-9)
+        if self.renormalize:    # over each token's surviving experts
+            denom = jnp.zeros((N,), jnp.float32).at[token].add(gate)
+            gate = gate / jnp.maximum(denom[token], 1e-9)
 
         # gather tokens into the expert buffer (each kept assignment owns a
         # distinct slot; dropped ones contribute zero to a clipped slot)
         buf = jnp.zeros((E * C, D), t.dtype)
         buf = buf.at[dest].add(t[token] * keep[:, None].astype(t.dtype))
-        expert_in = buf.reshape(E, C, D)
-        h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in,
-                                   params["w_in"].astype(t.dtype)))
-        expert_out = jnp.einsum("ecf,efd->ecd", h,
-                                params["w_out"].astype(t.dtype))
+        expert_out = self._expert_ffn(params, buf.reshape(E, C, D),
+                                      _buffer_mm)
         flat_out = expert_out.reshape(E * C, D)
         y = jnp.zeros((N, D), t.dtype).at[token].add(
             flat_out[dest] * gate[:, None].astype(t.dtype))
@@ -186,6 +232,48 @@ class MoE(Op):
         ce = counts.astype(jnp.float32) / N
         aux = self.aux_weight * E * jnp.sum(me * (ce / k))
         return [y.reshape(orig_shape), aux.astype(jnp.float32)]
+
+    def _forward_dropless(self, params, t, gates, orig_shape, row_mask,
+                          routing):
+        """No capacity, no dropped token: top-k of the f32 softmax, the
+        N*k assignments stable-sorted by expert, the experts as grouped
+        matmuls over exactly those rows, each token's k results weighted
+        by its gates and summed. A row's output depends on no other row."""
+        E, k = self.num_experts, self.k
+        N, D = t.shape
+        top_g, top_e = jax.lax.top_k(gates, k)              # (N, k)
+        if self.renormalize:
+            top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+        flat_e = top_e.reshape(-1)                          # token-major
+        if row_mask is not None:
+            live = row_mask.reshape(N)
+            # a dead row's k assignments go to no expert: the id E sorts
+            # behind every group and bincount drops it
+            flat_e = jnp.where(jnp.repeat(live, k), flat_e, E)
+            top_g = top_g * live[:, None]
+        order = jnp.argsort(flat_e, stable=True)
+        sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        # unsort: where each (token, choice) landed in the sorted rows
+        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+            jnp.arange(N * k, dtype=jnp.int32))
+        rows = t[order // k]                                # (N*k, D)
+        out = self._expert_ffn(
+            params, rows, lambda x, w: jax.lax.ragged_dot(x, w, sizes))
+        if row_mask is not None:
+            # rows past the last group belong to no expert; what a grouped
+            # matmul leaves there is unspecified
+            out = jnp.where((jnp.arange(N * k) < jnp.sum(sizes))[:, None],
+                            out, 0)
+        y = jnp.einsum("nk,nkd->nd", top_g,
+                       out[back].reshape(N, k, D).astype(jnp.float32))
+        if routing is not None:
+            routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
+                           .astype(jnp.int32))
+        me = jnp.mean(gates, axis=0)
+        ce = sizes.astype(jnp.float32) / N
+        aux = self.aux_weight * E * jnp.sum(me * (ce / k))
+        return [y.astype(t.dtype).reshape(orig_shape),
+                aux.astype(jnp.float32)]
 
     def partitionable_output_dims(self):
         return list(range(self.outputs[0].num_dims - 1))
@@ -210,15 +298,14 @@ class MoE(Op):
                 eaxes = ["expert"]
         e = None if not eaxes else (eaxes[0] if len(eaxes) == 1
                                     else tuple(eaxes))
-        return {
-            "router": P(None, None),
-            "w_in": P(e, None, None),
-            "w_out": P(e, None, None),
-        }
+        return {w.name: P(None, None) if w.name == "router"
+                else P(e, None, None) for w in self.weight_specs()}
 
     def flops(self):
         ntokens = self.inputs[0].volume() // self.dim
-        return 2 * 2 * ntokens * self.k * self.dim * self.hidden_dim
+        matmuls = 3 if self.expert == "swiglu" else 2
+        return (2 * matmuls * ntokens * self.k * self.dim
+                * self.hidden_dim)
 
     def input_axis_map(self, axis_map, input_idx):
         # negative sentinels (CONTRACT/STAGE/EXPERT) must not leak into the

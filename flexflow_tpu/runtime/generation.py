@@ -66,10 +66,10 @@ _DECODE_SAFE = {
     OperatorType.OP_EW_MAX,
     OperatorType.OP_EW_MIN,
     # MoE routes each token independently (router logits -> top-k expert
-    # FFNs); the inference walk overrides capacity to the slab's token
-    # count, which guarantees ZERO drops (a token never picks the same
-    # expert twice) — standard inference semantics for capacity-trained
-    # MoE, and the row-independence guarantee decode promises
+    # FFNs). A dropless op never drops; for a capacity-trained op the
+    # walk sets the buffer to the slab's token count, which guarantees
+    # ZERO drops (a token never picks the same expert twice): either
+    # way the row independence that decode promises
     OperatorType.OP_MOE,
 }
 
@@ -158,6 +158,10 @@ class Generator:
                     "path; generate() supports transformer decoder graphs")
         if not self.attn_ops:
             raise ValueError("graph has no attention ops; nothing to cache")
+        # dropless MoE ops count their routing inside the serve programs
+        self.dropless_moe_ops = [
+            op for op in model.ops
+            if op.op_type == OperatorType.OP_MOE and op.dropless]
         # topo index of the last attention op: beyond it every op is
         # per-position, so the prefill tail (lm_head included) can run on
         # the final position only instead of the whole prompt
@@ -250,7 +254,7 @@ class Generator:
     def _walk(self, params, state, tokens, caches, pos, last_only=False,
               rope_pos=None, row_lengths=None, prompt_len=None,
               chunk_start=None, skip_tail=False, gather_last=False,
-              paged=None, lora=None):
+              paged=None, lora=None, routing=None):
         """Interpret the graph on a (B, S) token slab. pos=None means
         prefill (positions 0..S-1, fills cache); otherwise S == 1 and pos
         is the traced cache slot of the token. last_only=True narrows the
@@ -260,7 +264,9 @@ class Generator:
         materialization; with `row_lengths` (ragged right-padded prompts)
         the tail gathers each row's own last valid position instead of
         column -1, and decode steps get per-row RoPE positions + a pad-
-        slot cache mask (see MultiHeadAttention.decode_forward)."""
+        slot cache mask (see MultiHeadAttention.decode_forward).
+        `routing`, if a list, collects each dropless MoE op's (2,) routing
+        counts of this walk (ops/moe.py)."""
         bf16 = self._compute_dtype() == jnp.bfloat16
 
         def to_compute(a):
@@ -364,11 +370,21 @@ class Generator:
                         kwargs["lora"] = gather_op_lora(
                             lora["pool"], op.name, lora["pages"])
                     if op.op_type == OperatorType.OP_MOE:
-                        # inference capacity = the slab's token count:
-                        # guarantees zero drops (see MoE.forward), hence
-                        # row independence for ragged/batched decode
-                        kwargs["capacity"] = int(
-                            np.prod(xs[0].shape[:-1]))
+                        if op.dropless:
+                            # rows of free slots and of a prompt's
+                            # padding route nowhere: no expert is
+                            # streamed for a row nobody reads
+                            kwargs["row_mask"] = self._live_rows(
+                                xs[0].shape[:-1], paged, row_lengths,
+                                chunk_start, gather_last)
+                            kwargs["routing"] = routing
+                        else:
+                            # a capacity op drops nothing at inference
+                            # when its buffer holds the whole slab (see
+                            # MoE.forward), hence row independence for
+                            # ragged/batched decode
+                            kwargs["capacity"] = int(
+                                np.prod(xs[0].shape[:-1]))
                     if op.stateful:
                         outs, _ = op.forward_stateful(
                             p, state.get(op.name, {}), xs,
@@ -380,8 +396,21 @@ class Generator:
                 vals[t] = outs[i]
         return vals[self.model._final_tensor], new_caches
 
+    @staticmethod
+    def _live_rows(shape, paged, row_lengths, chunk_start, gather_last):
+        """(B, S) bool: which rows of a slab some request reads. A paged
+        decode slot is live while a request holds it (the engine zeroes
+        `row_len` on retirement); a ragged prompt's rows are live up to
+        its length; None where every row is."""
+        if paged is not None:
+            return jnp.broadcast_to((paged["row_len"] > 0)[:, None], shape)
+        if row_lengths is None or gather_last:
+            return None
+        at = (chunk_start or 0) + jnp.arange(shape[1])
+        return at[None, :] < row_lengths[:, None]
+
     def _prefill(self, params, state, tokens, caches, row_lengths,
-                 prefill_chunk, lora=None):
+                 prefill_chunk, lora=None, routing=None):
         """Whole-prompt prefill, or chunked (`prefill_chunk` > 0 and the
         prompt longer than it): each chunk writes its k/v and attends the
         static prefix slice under the same causal rule — score memory is
@@ -401,25 +430,30 @@ class Generator:
         if not prefill_chunk or s0 <= prefill_chunk:
             return self._walk(params, state, tokens, caches, None,
                               last_only=True, row_lengths=row_lengths,
-                              prompt_len=s0, lora=lora)
+                              prompt_len=s0, lora=lora, routing=routing)
         starts = list(range(0, s0, prefill_chunk))
         if row_lengths is not None:
             for st in starts:
+                # row_lengths: a chunk's attention does not read it, a
+                # dropless MoE masks the prompt's padding rows by it
                 _, caches = self._walk(
                     params, state, tokens[:, st:st + prefill_chunk],
-                    caches, None, chunk_start=st, skip_tail=True, lora=lora)
+                    caches, None, chunk_start=st, skip_tail=True, lora=lora,
+                    row_lengths=row_lengths, routing=routing)
             tok_last = jnp.take_along_axis(
                 tokens, (row_lengths - 1)[:, None], axis=1)      # (B, 1)
             return self._walk(params, state, tok_last, caches, None,
                               last_only=True, row_lengths=row_lengths,
-                              gather_last=True, lora=lora)
+                              gather_last=True, lora=lora, routing=routing)
         for st in starts[:-1]:
             _, caches = self._walk(
                 params, state, tokens[:, st:st + prefill_chunk], caches,
-                None, chunk_start=st, skip_tail=True, lora=lora)
+                None, chunk_start=st, skip_tail=True, lora=lora,
+                routing=routing)
         st = starts[-1]
         return self._walk(params, state, tokens[:, st:], caches, None,
-                          last_only=True, chunk_start=st, lora=lora)
+                          last_only=True, chunk_start=st, lora=lora,
+                          routing=routing)
 
     # ---- sampling ----------------------------------------------------------
 

@@ -146,7 +146,7 @@ class Seq2SeqGenerator:
                 kwargs = {}
                 if getattr(op, "wants_shard_ctx", False):
                     kwargs["shard_ctx"] = None
-                if op.op_type == OperatorType.OP_MOE:
+                if op.op_type == OperatorType.OP_MOE and not op.dropless:
                     kwargs["capacity"] = int(np.prod(xs[0].shape[:-1]))
                 outs = op.forward(p, xs, training=False, rng=None, **kwargs)
         return outs

@@ -1391,6 +1391,11 @@ class ServingEngine:
         self._adapter_spec: Dict[str, List[int]] = {}
         self._sampled_requests = 0
         self._sampled_slot_steps = 0
+        # dropless MoE routing of the DECODE dispatches, summed over
+        # steps and MoE layers (counted inside the program, live rows
+        # only): assignments, experts with at least one row
+        self._moe_assignments = 0
+        self._moe_experts_hit = 0
 
         # ---- unified telemetry plane (ISSUE 13) ----
         # the engine's latency histograms (TTFT / inter-token / queue
@@ -1973,6 +1978,14 @@ class ServingEngine:
         self._free_pages.extend(pages[k:])
         return matched[:n_hbm + k]
 
+    @staticmethod
+    def _routing_sum(routing):
+        """The extra trailing output of a serve program whose model has
+        dropless MoE ops: their int32 (2,) routing counts [assignments,
+        experts hit] summed over the ops one walk ran.
+        Nothing for any other model, whose programs are unchanged."""
+        return (sum(routing),) if routing else ()
+
     def _build_prefill(self, bucket: int, n_pages: int):
         gen = self.gen
         cdtype = gen._compute_dtype()
@@ -1984,16 +1997,18 @@ class ServingEngine:
                       for op in gen.attn_ops}
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
+            routing = [] if gen.dropless_moe_ops else None
             logits, caches = gen._prefill(params, state, tokens, caches,
                                           length, self.prefill_chunk,
-                                          lora=lora)
+                                          lora=lora, routing=routing)
             logits = logits[:, -1] + poison            # (1, V)
             ok = jnp.isfinite(logits).all(axis=-1)
             # the request's first emitted token is TARGET-stream draw 0
             tok = sampling_ops.sample_tokens(
                 logits, temps, top_ps, top_ks, seeds,
                 jnp.zeros_like(seeds))
-            return tok, ok, self._scatter_tail(gen, pool, caches, pages)
+            return (tok, ok, self._scatter_tail(gen, pool, caches, pages),
+                    *self._routing_sum(routing))
 
         return jax.jit(prefill, donate_argnums=(4,))
 
@@ -2018,20 +2033,26 @@ class ServingEngine:
                     if has_lora else None)
             caches = self._seed_prefix_caches(gen, bucket, p0, pool,
                                               prefix_pages)
+            routing = [] if gen.dropless_moe_ops else None
+            # row_lengths on the tail walk: its attention does not read
+            # it, a dropless MoE masks the padding rows by it
             _, caches = gen._walk(params, state, tokens_tail, caches,
                                   None, chunk_start=p0, skip_tail=True,
-                                  lora=lora)
+                                  lora=lora, row_lengths=length,
+                                  routing=routing)
             logits, caches = gen._walk(params, state, tok_last, caches,
                                        None, last_only=True,
                                        row_lengths=length,
-                                       gather_last=True, lora=lora)
+                                       gather_last=True, lora=lora,
+                                       routing=routing)
             logits = logits[:, -1] + poison            # (1, V)
             ok = jnp.isfinite(logits).all(axis=-1)
             tok = sampling_ops.sample_tokens(
                 logits, temps, top_ps, top_ks, seeds,
                 jnp.zeros_like(seeds))
-            return tok, ok, self._scatter_tail(gen, pool, caches,
-                                               tail_pages, p0)
+            return (tok, ok, self._scatter_tail(gen, pool, caches,
+                                                tail_pages, p0),
+                    *self._routing_sum(routing))
 
         return jax.jit(prefill, donate_argnums=(5,))
 
@@ -2080,7 +2101,11 @@ class ServingEngine:
         FULL padded (1, bucket) prompt is the input and the chunk slice
         is static, so every chunk of a bucket shares one argument
         signature; st=0 creates the caches, later chunks take + donate
-        them (the cursor state the scheduler carries between ticks)."""
+        them (the cursor state the scheduler carries between ticks).
+        These programs take no prompt length, so a dropless MoE sees no
+        live-row mask here: a chunk's padding rows route to experts
+        (outputs unchanged, rows are independent) and the routing is not
+        counted; the draft prefill programs likewise."""
         gen = self.gen
         cdtype = gen._compute_dtype()
         has_lora = self.lora_pool is not None
@@ -2212,19 +2237,22 @@ class ServingEngine:
                     "rope_pos": jnp.minimum(rope_pos0 + i, rope_cap),
                     "row_len": row_len, "prompt_pad": prompt_pad,
                     "impl": self.paged_attention_impl}
+                routing = [] if gen.dropless_moe_ops else None
                 logits, pool = gen._walk(params, state, tok[:, None],
                                          pool, None, paged=paged,
-                                         lora=lora)
+                                         lora=lora, routing=routing)
                 logits = logits[:, 0] + poison[:, None]  # (B_slots, V)
                 ok = jnp.isfinite(logits).all(axis=-1)
                 nxt = sampling_ops.sample_tokens(
                     logits, temps, top_ps, top_ks, seeds, ctr0 + i)
-                return (pool, nxt), (nxt, ok)
+                return (pool, nxt), (nxt, ok, *self._routing_sum(routing))
 
-            (pool, _), (toks, oks) = jax.lax.scan(
+            (pool, _), (toks, oks, *routed) = jax.lax.scan(
                 body, (pool, last_tok),
                 jnp.arange(n_steps, dtype=jnp.int32))
-            return toks, oks, pool                     # (n_steps, B_slots)
+            # (n_steps, B_slots) tokens; an expert model adds its (2,)
+            # routing counts summed over the dispatch's steps and layers
+            return toks, oks, pool, *(r.sum(axis=0) for r in routed)
 
         return jax.jit(decode, donate_argnums=(2,))
 
@@ -2572,7 +2600,7 @@ class ServingEngine:
                     tail = req.prompt[p0:]
                     padded_tail[0, :tail.size] = tail
                     tok_last = np.asarray([[req.prompt[-1]]], np.int32)
-                    tok, ok, self.pool = self._compiled_call(
+                    tok, ok, self.pool, *routed = self._compiled_call(
                         ("prefill_hit", req.bucket, full),
                         lambda: self._build_prefill_hit(req.bucket, full),
                         self.gen._params(), self.model.bn_state, padded_tail,
@@ -2585,7 +2613,7 @@ class ServingEngine:
                 else:
                     padded = np.full((1, req.bucket), self.pad_id, np.int32)
                     padded[0, :req.prompt.size] = req.prompt
-                    tok, ok, self.pool = self._compiled_call(
+                    tok, ok, self.pool, *routed = self._compiled_call(
                         ("prefill", req.bucket, n_prefill, self.prefill_chunk),
                         lambda: self._build_prefill(req.bucket, n_prefill),
                         self.gen._params(), self.model.bn_state, padded,
@@ -2617,9 +2645,13 @@ class ServingEngine:
                             padded, self.draft_pool,
                             np.asarray(req.pages[:n_prefill], np.int32))
                 with self._span("prefill_fetch"):
-                    ok_host = bool(np.asarray(ok)[0])
-                    tok_host = int(np.asarray(tok)[0])
+                    # ONE device_get: the copies back start together
+                    ok, tok, *routed = jax.device_get((ok, tok, *routed))
+                    ok_host, tok_host = bool(ok[0]), int(tok[0])
                 psp.annotate(ok=ok_host)
+                if routed:
+                    psp.annotate(assignments=int(routed[0][0]),
+                                 experts_hit=int(routed[0][1]))
                 if self._tm_on:
                     req.decode_span = telemetry.tracer().begin(
                         "decode", trace_id=req.trace_id,
@@ -2731,8 +2763,8 @@ class ServingEngine:
                 ps["padded"], self.draft_pool,
                 np.asarray(req.pages[:n_prefill], np.int32))
         with self._span("prefill_fetch"):
-            ok_host = bool(np.asarray(ok)[0])
-            tok_host = int(np.asarray(tok)[0])
+            ok, tok = jax.device_get((ok, tok))
+            ok_host, tok_host = bool(ok[0]), int(tok[0])
         # decode-state arrays applied only NOW: until this instant every
         # decode dispatch saw this slot as idle
         self.temps[slot] = req.temperature
@@ -3293,13 +3325,20 @@ class ServingEngine:
                     self.emitted.copy(), *self._lora_args_slots())
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read):
-            toks, oks, self.pool = self._compiled_call(
+            toks, oks, self.pool, *routed = self._compiled_call(
                 ("decode", k), lambda: self._build_decode(k), *args)
         with self._span("token_fetch"):
-            toks = np.asarray(toks)                    # (k, B_slots)
-            oks = np.asarray(oks)
+            # ONE device_get: the copies back start together, so neither
+            # `oks` nor the routing counts wait a round trip of their own
+            toks, oks, *routed = jax.device_get((toks, oks, *routed))
         self.decode_steps += k
         with self._span("record_tokens") as sp:
+            if routed:
+                # counted on the device, over live rows only
+                assigned, hit = (int(v) for v in routed[0])
+                self._moe_assignments += assigned
+                self._moe_experts_hit += hit
+                sp.annotate(assignments=assigned, experts_hit=hit)
             kept0 = self._tokens_emitted
             for slot in range(self.slots):
                 for t in range(k):
@@ -3794,6 +3833,12 @@ class ServingEngine:
             # slots x the dispatch's steps, counted at dispatch: a slot
             # that retires mid-chunk still counts its whole chunk)
             "sampled_slot_steps": self._sampled_slot_steps,
+            # decode dispatches of a model with dropless MoE ops (0
+            # otherwise): experts_hit / (experts x MoE layers x
+            # decode_steps) is the share of the expert weights a step
+            # streams
+            "moe_assignments": self._moe_assignments,
+            "moe_experts_hit": self._moe_experts_hit,
             "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
             "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
             "free_pages": len(self._free_pages),
