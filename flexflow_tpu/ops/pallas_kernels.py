@@ -598,12 +598,36 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # (runtime/serving.py). The einsum path reassembles the ENTIRE pool into a
 # dense (B, max_len, KVH, Dh) logical cache with ck[page_table].reshape(...)
 # on every step — an HBM round-trip that grows with POOL size, not with the
-# tokens a slot actually holds. This kernel does the page-table lookup
-# inside the grid instead (scalar prefetch: the table is in SMEM before the
-# body runs, and each inner step's BlockSpec index_map picks the slot's
-# t-th pool page directly), so only the slot's LIVE pages —
-# ceil((max(write_pos)+1)/page_size) of them — ever stream through VMEM,
-# with an online-softmax accumulator carrying state across the page axis.
+# tokens a slot actually holds. This kernel leaves the pool in HBM and
+# fetches, by hand, exactly the pages the live rule can reach: its time
+# follows the pages it streams.
+#
+#   * The grid is the SLOTS (sequential). Inside a grid step a fori_loop
+#     runs over the slot's live pages, 0 .. last_page[b], so no step of
+#     any kind exists for a dead page. (Until PR 26 the grid was (slots,
+#     table width) with dead steps clamped and pl.when-skipped, on the
+#     assumption that a dead step costs nothing. It costs 0.13-0.15 us:
+#     with 16 x 66 steps of which 5 were live, that was nearly all of the
+#     157 us a call took in chat-steady — PERF.md section 6, PR 26.)
+#   * The pages of ALL slots form one stream, slot by slot, page by page.
+#     A cursor in SMEM walks it `_paged_ring(...) - 1` pages ahead of the
+#     arithmetic and issues one DMA per page and tensor (a page of the
+#     pool is one contiguous copy, all KV heads in it) into a ring of
+#     VMEM buffers; because the cursor crosses slot boundaries, the DMA
+#     queue never drains between slots, which matters when most slots
+#     hold one or two pages. Every slot has >= 1 live page (the live rule
+#     admits j = 0 even for an inactive slot, whose zeroed table row
+#     points at scratch page 0), so the cursor's advance is O(1).
+#   * A page's arithmetic is one pass for all heads: the page is viewed
+#     as (page_size * KVH, D) rows in the pool's own layout, all S * H
+#     query rows are scored against it in one matmul, and a column whose
+#     KV head is not the row's (h // G != c % KVH) is masked together
+#     with the live rule. That spends KVH x the FLOPs, on an MXU whose
+#     cost here is loading the page as weights — the same whether 1 or
+#     S * H rows stream through — and it removes the per-head strided
+#     slices of the page, which were what a live page cost (1.5-1.9 us
+#     against 0.64 us for its DMA; now 0.69).
+#
 # The Flex-TPU analogue (PAPERS.md 2407.08700): keep the data resident in
 # the compute unit; don't materialize the logical view in HBM.
 #
@@ -615,105 +639,148 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # head h // group). The einsum page-gather stays as the parity oracle
 # (tests/test_pallas_paged.py).
 
+# VMEM the page ring may take: half the 16 MiB a kernel gets by default,
+# the rest is for the scores, a dequantized page and Mosaic's own use
+_PAGED_RING_BUDGET = 8 << 20
+# pages in flight beyond which a deeper ring hides no more DMA latency
+_PAGED_RING_MAX = 4
+
+
+def _paged_ring(ps: int, kvh: int, dqk: int, dv: int, dtype) -> int:
+    """Number of page buffers per tensor, from the shapes the kernel sees:
+    as many as fit _PAGED_RING_BUDGET at the size a (page_size, KVH, D)
+    page takes in VMEM (minor dims padded to the dtype's tile), between 2
+    (fetch one page while another is read) and _PAGED_RING_MAX."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    rows = -(-kvh // sublanes) * sublanes
+    page = sum(ps * rows * -(-d // LANES) * LANES * itemsize
+               for d in (dqk, dv))
+    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // page)))
+
 
 def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
-                       s: int, kvh: int, grp: int, ps: int, scale: float,
-                       quantized: bool = False):
-    """One (slot, page) grid step: score the slot's (S, H, Dh) query slab
-    against this page's (ps, KVH, Dh) k/v and fold into the running
-    online softmax. Scalar-prefetch refs: page table (B, P), last live
-    page (B,), per-position write frontier (B, S), row_len (B,),
-    prompt_pad (B,) — and, for a quantized pool, the per-(pool page,
-    kv head) f32 k/v scales (P_pool, KVH): the quantized payload streams
-    through VMEM and dequantizes HERE, against the scalar-prefetched
-    scale of the pool page this grid step fetched — the full-width KV
-    never exists in HBM (the Flex-TPU keep-it-resident rule applied to
-    quantization). Scratch rows are kv-head-major: row
-    kh*(S*G) + i*G + g accumulates query head kh*G+g at slab position i."""
+                       s: int, h: int, kvh: int, ps: int, nbuf: int,
+                       scale: float, quantized: bool = False):
+    """One slot per grid step: score the slot's (S*H, Dqk) query rows
+    against each of its live pages in turn and fold into the running
+    online softmax (f32 m, l, acc carried by the loop). Scalar-prefetch
+    refs: page table (B, P), last live page (B,), per-position write
+    frontier (B, S), row_len (B,), prompt_pad (B,) — and, for a quantized
+    pool, the per-(pool page, kv head) f32 k/v scales (P_pool, KVH): the
+    quantized payload is what the DMA moves, and it dequantizes HERE, in
+    VMEM, against the scales of the pool page it came from — the
+    full-width KV never exists in HBM.
+
+    k_hbm / v_hbm are the whole pools, left in HBM. `cur` (SMEM, kept
+    across grid steps) is the stream's state: [slot, page] of the next
+    page to fetch and [2] how many pages were consumed; page n of the
+    stream lives in buffer n % nbuf. Fetches run nbuf - 1 pages ahead:
+    the page fetched at the top of an iteration lands in the buffer the
+    previous iteration finished reading."""
     if quantized:
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, \
-            m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref, \
+            k_buf, v_buf, sem, cur = rest
     else:
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur = rest
     b = pl.program_id(0)
-    t = pl.program_id(1)
-    nt = pl.num_programs(1)
+    nb = pl.num_programs(0)
+    grp = h // kvh
+    rows, cols = s * h, ps * kvh
 
-    @pl.when(t == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def page_copies(page, buf):
+        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf],
+                                      sem.at[1, buf]))
 
-    # pages past the slot's write frontier are dead: skip their compute
-    # (the index_map already clamps their DMA to the resident last live
-    # page, so a dead step costs nothing — same trick as the causal
-    # clamp in the flash kernels)
-    @pl.when(t <= lp_ref[b])
-    def _step():
-        q = q_ref[0]                                # (S, H, Dqk)
-        k = k_ref[0]                                # (ps, KVH, Dqk)
-        v = v_ref[0]                                # (ps, KVH, Dv)
-        rl = rl_ref[b]
-        pp = pp_ref[b]
-        # the pool page this step's k/v block came from (same lookup as
-        # kv_map's clamped DMA) — indexes the scale rows when quantized
-        pg = pt_ref[b, jnp.minimum(t, lp_ref[b])]
-        # live mask rows in (slab position, group) order — each slab
-        # position i attends at its OWN frontier wp[b, i], which gives
-        # in-slab causality for the verify slab (position i's window
-        # holds exactly the slab writes <= i plus committed history)
-        # The per-row frontier is selected into an int32 (S*G, ps) tile
-        # from the SMEM scalars: Mosaic has no register cast for
-        # concatenated i1 rows, so the mask is compared once, whole.
-        j = t * ps + jax.lax.broadcasted_iota(jnp.int32, (s * grp, ps), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (s * grp, ps), 0)
-        wp = jnp.full((s * grp, ps), wp_ref[b, 0], jnp.int32)
-        for i in range(1, s):
-            wp = jnp.where(row >= i * grp, wp_ref[b, i], wp)
-        live = (j < rl) | ((j >= pp) & (j <= wp))   # (S*G, ps)
-        for kh in range(kvh):
-            sl = slice(kh * s * grp, (kh + 1) * s * grp)
-            qk = q[:, kh * grp:(kh + 1) * grp, :].reshape(s * grp, -1)
-            kk = k[:, kh, :]                        # (ps, Dqk)
-            vv = v[:, kh, :]                        # (ps, Dv)
-            if quantized:
-                # in-VMEM dequant: one scalar per (page, head), read
-                # from SMEM — the int8/fp8 tile was the only HBM read
-                kk = kk.astype(jnp.float32) * ks_ref[pg, kh]
-                vv = vv.astype(jnp.float32) * vs_ref[pg, kh]
-            elif kk.dtype != q.dtype:
-                # mixed-width pool (kv_cache_dtype='bf16' under f32
-                # compute): upcast in VMEM so the probs matmul runs at
-                # query precision, matching the einsum oracle's cast
-                kk = kk.astype(q.dtype)
-                vv = vv.astype(q.dtype)
-            sc = jnp.dot(qk, kk.T,
-                         preferred_element_type=jnp.float32) * scale
-            sc = jnp.where(live, sc, NEG_INF)
-            m_prev = m_scr[sl, 0:1]
-            l_prev = l_scr[sl, 0:1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(sc, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(sc - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[sl, :] = acc_scr[sl, :] * alpha + jnp.dot(
-                p.astype(vv.dtype), vv,
-                preferred_element_type=jnp.float32)
-            m_scr[sl, :] = jnp.broadcast_to(m_new, (s * grp, LANES))
-            l_scr[sl, :] = jnp.broadcast_to(l_new, (s * grp, LANES))
+    def fetch_next(buf):
+        fs, fp = cur[0], cur[1]
 
-    @pl.when(t == nt - 1)
-    def _finish():
-        # every slab row has >= 1 live position (its own write frontier:
-        # prompt_pad <= write_pos always holds, and the inactive-slot
-        # zeros satisfy j == 0 <= write_pos == 0), so l > 0 — no guard
-        for kh in range(kvh):
-            sl = slice(kh * s * grp, (kh + 1) * s * grp)
-            o = acc_scr[sl, :] / l_scr[sl, 0:1]
-            o_ref[0, :, kh * grp:(kh + 1) * grp, :] = \
-                o.reshape(s, grp, -1).astype(o_ref.dtype)
+        @pl.when(fs < nb)
+        def _():
+            for c in page_copies(pt_ref[fs, fp], buf):
+                c.start()
+            more = fp < lp_ref[fs]
+            cur[0] = jnp.where(more, fs, fs + 1)
+            cur[1] = jnp.where(more, fp + 1, 0)
+
+    @pl.when(b == 0)
+    def _prime():
+        for i in range(3):
+            cur[i] = 0
+        for i in range(nbuf - 1):
+            fetch_next(i)
+
+    # what does not change from page to page: which columns of a page
+    # belong to a row's KV head, each column's token, each row's frontier
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    own_head = (row % h) // grp == col % kvh            # (rows, cols)
+    tok = col // kvh                                    # (1, cols)
+    wp = jnp.full((rows, 1), wp_ref[b, 0], jnp.int32)
+    for i in range(1, s):
+        # slab position i attends at its OWN frontier wp[b, i]: in-slab
+        # causality for the verify slab
+        wp = jnp.where(row >= i * h, wp_ref[b, i], wp)
+    rl = rl_ref[b]
+    pp = pp_ref[b]
+    q = q_ref[0]                                        # (S*H, Dqk)
+
+    def head_scales(sc_ref, page, d):
+        # (KVH, d) tile of the page's per-head scales, from SMEM scalars
+        kh = jax.lax.broadcasted_iota(jnp.int32, (kvh, d), 0)
+        tile = jnp.full((kvh, d), sc_ref[page, 0], jnp.float32)
+        for i in range(1, kvh):
+            tile = jnp.where(kh == i, sc_ref[page, i], tile)
+        return tile
+
+    def one_page(t, carry):
+        m_prev, l_prev, acc = carry
+        n = cur[2]
+        cur[2] = n + 1
+        fetch_next((n + nbuf - 1) % nbuf)
+        buf = n % nbuf
+        for c in page_copies(0, buf):
+            c.wait()
+        k = k_buf[buf]                                  # (ps, KVH, Dqk)
+        v = v_buf[buf]                                  # (ps, KVH, Dv)
+        if quantized:
+            page = pt_ref[b, t]
+            k = k.astype(jnp.float32) * head_scales(ks_ref, page,
+                                                    k.shape[-1])
+            v = v.astype(jnp.float32) * head_scales(vs_ref, page,
+                                                    v.shape[-1])
+        # mixed-width pool (kv_cache_dtype='bf16' under f32 compute) and
+        # the dequantized page: both matmuls run at query precision,
+        # matching the einsum oracle's cast
+        k = k.reshape(cols, -1).astype(q.dtype)
+        v = v.reshape(cols, -1).astype(q.dtype)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (rows, cols)
+        j = t * ps + tok
+        live = (j < rl) | ((j >= pp) & (j <= wp))
+        sc = jnp.where(live & own_head, sc, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    _, l_fin, acc = jax.lax.fori_loop(
+        0, lp_ref[b] + 1, one_page,
+        (jnp.full((rows, 1), NEG_INF, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32),
+         jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)))
+    # every row has >= 1 live position (its own write frontier:
+    # prompt_pad <= write_pos always holds, and the inactive-slot zeros
+    # satisfy j == 0 <= write_pos == 0), so l > 0 — no guard. A page in
+    # which a row has NO live column leaves p = 1 everywhere while m is
+    # still NEG_INF; the first live score then scales that away (alpha = 0)
+    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
 
 
 def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
@@ -725,20 +792,25 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
 
     write_pos (B, S) int32 is each slab position's logical write
     frontier (host-clamped, nondecreasing over S); row_len / prompt_pad
-    (B,) the ragged-prompt live-rule bounds. Grid is (slots, pages_per_
-    slot) with the page axis sequential; pages past a slot's frontier
-    are skipped (clamped DMA + pl.when), so the per-step HBM traffic is
-    the slot's LIVE pages, not the pool. Inference-only: no VJP (the
-    serving engine never differentiates through decode).
+    (B,) the ragged-prompt live-rule bounds. The grid is the slots; each
+    grid step loops over its slot's live pages only, whose DMAs were
+    issued by hand some pages earlier (possibly during the previous
+    slot) into a ring of VMEM buffers as deep as _paged_ring says for
+    these shapes. HBM traffic and time both follow the LIVE pages, not
+    the pool and not the table's width. A slot that is not active
+    (write_pos == row_len == prompt_pad == 0) is indistinguishable from
+    a one-token context and streams its one page (scratch page 0), as
+    the oracle reads it. Inference-only: no VJP (the serving engine never
+    differentiates through decode).
 
     ``k_scales``/``v_scales`` ((P_pool, KVH) f32, both or neither) mark
     a QUANTIZED pool (int8/fp8 payload, ISSUE 11): they ride the
     scalar-prefetch stream into SMEM next to the page table, and each
-    grid step dequantizes its VMEM-resident tile against its own page's
-    scale before the score/context matmuls — per-page HBM traffic is
-    the quantized bytes, and the full-width KV is never materialized
-    anywhere. The einsum page-gather path applies the same dequant
-    after its gather, staying the parity oracle."""
+    page dequantizes in its VMEM buffer against its own scales before
+    the score/context matmuls — per-page HBM traffic is the quantized
+    bytes, and the full-width KV is never materialized anywhere. The
+    einsum page-gather path applies the same dequant after its gather,
+    staying the parity oracle."""
     b, s, h, dqk = q.shape
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[3]
@@ -746,8 +818,7 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     assert (k_scales is None) == (v_scales is None), \
         "quantized pools carry BOTH k and v scales"
     quantized = k_scales is not None
-    grp = h // kvh
-    pps = page_table.shape[1]
+    nbuf = _paged_ring(ps, kvh, dqk, dv, k_pages.dtype)
     # last live page per slot: the live rule's bound is max(write
     # frontier, prompt tail) — a serving dispatch always has write_pos
     # >= prompt_pad >= row_len, but the kernel honors the FULL rule so
@@ -758,17 +829,9 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     last_idx = jnp.maximum(jnp.max(write_pos, axis=1), row_len - 1)
     last_page = (last_idx // ps).astype(jnp.int32)
 
-    # extra trailing prefetch refs (the quantized scales) ride into the
-    # index maps as *_ — the maps only ever read the table + last page
-    def q_map(bi, t, pt, lp, *_):
-        return (bi, 0, 0, 0)
-
-    def kv_map(bi, t, pt, lp, *_):
-        # the paged lookup: this grid step's k/v block IS pool page
-        # page_table[slot, t], fetched straight from HBM — dead steps
-        # (t past the frontier) clamp to the already-resident last live
-        # page so they trigger no DMA
-        return (pt[bi, jnp.minimum(t, lp[bi])], 0, 0, 0)
+    # trailing prefetch refs ride into the index map as *_
+    def slot_map(bi, *_):
+        return (bi, 0, 0)
 
     prefetch = [page_table.astype(jnp.int32), last_page,
                 write_pos.astype(jnp.int32), row_len.astype(jnp.int32),
@@ -778,27 +841,30 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
                      v_scales.astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, pps),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, s, h, dqk), q_map),
-            pl.BlockSpec((1, ps, kvh, dqk), kv_map),
-            pl.BlockSpec((1, ps, kvh, dv), kv_map),
+            pl.BlockSpec((1, s * h, dqk), slot_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, s, h, dv), q_map),
+        out_specs=pl.BlockSpec((1, s * h, dv), slot_map),
         scratch_shapes=[
-            pltpu.VMEM((s * h, LANES), jnp.float32),   # running max
-            pltpu.VMEM((s * h, LANES), jnp.float32),   # running sum
-            pltpu.VMEM((s * h, dv), jnp.float32),      # ctx accumulator
+            pltpu.VMEM((nbuf, ps, kvh, dqk), k_pages.dtype),
+            pltpu.VMEM((nbuf, ps, kvh, dv), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, nbuf)),
+            pltpu.SMEM((3,), jnp.int32),               # the stream's state
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, s=s, kvh=kvh, grp=grp,
-                          ps=ps, scale=scale, quantized=quantized),
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
+                          nbuf=nbuf, scale=scale, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, dv), q.dtype),
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, s * h, dv), q.dtype),
+        # sequential: the cursor and the ring carry over from slot to slot
+        compiler_params=_compiler_params(("arbitrary",)),
         interpret=_interpret(),
-    )(*prefetch, q, k_pages, v_pages)
+    )(*prefetch, q.reshape(b, s * h, dqk), k_pages, v_pages)
+    return out.reshape(b, s, h, dv)
 
 
 def paged_prefill_write_pallas(cache, kh, vh, pages):

@@ -12,7 +12,10 @@ Correctness anchors:
     TOKEN-IDENTICAL between impl='pallas' and impl='einsum' — the kernel
     is a perf mechanism, never semantics;
   * the recompile counter stays flat under warm traffic with the kernel
-    path enabled (the kernel does not break the one-program contract).
+    path enabled (the kernel does not break the one-program contract);
+  * the kernel's page stream (ISSUE 26): every seam between slots, pages
+    and ring buffers against the oracle, and a poisoned pool proving that
+    a dead page never reaches the arithmetic.
 
 On CPU the kernel runs in interpret mode — the REAL kernel code path,
 executed by every CI tier (the ISSUE-7 routing requirement).
@@ -296,6 +299,168 @@ def test_recompile_flat_with_pallas_impl(ff):
     st = eng.stats()
     assert st["paged_attention_impl"] == "pallas"
     assert st["pages_touched"] > 0
+
+
+# ---- the page stream and its ring (ISSUE 26) ------------------------------
+#
+# The kernel walks ONE stream of pages over all slots through a ring of VMEM
+# buffers, fetching some pages ahead of the arithmetic. These cases put the
+# stream's seams where the ring's are not: live pages that are no multiple of
+# the ring, tables narrower than it, slots of one page, all at three ring
+# depths (the depth the shapes give, and two small ones forced through the
+# module's cap so that buffer indices wrap many times).
+
+
+def _oracle(q, pool, table, wp, row_len, pad, scale):
+    """Gather + grouped einsum over the full live rule: the
+    _grouped_cache_attention math with an explicit scale, dequantizing a
+    quantized pool after the gather as the einsum path does."""
+    from flexflow_tpu.ops.attention import page_dequantize
+
+    b, s, h, d = q.shape
+    page, kvh = pool["k"].shape[1], pool["k"].shape[2]
+    max_len = table.shape[1] * page
+    gk, gv = pool["k"][table], pool["v"][table]
+    if "k_scale" in pool:
+        gk = page_dequantize(gk, pool["k_scale"][table])
+        gv = page_dequantize(gv, pool["v_scale"][table])
+    gk = gk.reshape(b, max_len, kvh, -1)
+    gv = gv.reshape(b, max_len, kvh, -1)
+    idx = jnp.arange(max_len)
+    live = (idx[None, None, :] < row_len[:, None, None]) \
+        | ((idx[None, None, :] >= pad[:, None, None])
+           & (idx[None, None, :] <= wp[:, :, None]))
+    qg = q.reshape(b, s, kvh, h // kvh, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk,
+                        preferred_element_type=jnp.float32) * scale
+    # where, not a bias: a dead position may hold anything (the poison test)
+    logits = jnp.where(live[:, None, None, :, :], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, gv).reshape(b, s, h, -1)
+
+
+def _stream_case(name):
+    """(heads, kv_heads, table width, per-slot (row_len, pad, first
+    frontier), slab length, quantized) at page size 4."""
+    page = 4
+    return {
+        # live pages 3 + 1 + 5 + 2 = 11: no multiple of a ring of 2, 3 or 8
+        "ragged-live-pages": (4, 2, 6, [(3, 4, 9), (1, 2, 3), (7, 8, 19),
+                                        (2, 4, 6)], 1, False),
+        # every slot ends inside its first page
+        "one-live-page": (4, 2, 4, [(1, 1, 1), (2, 2, 3), (0, 0, 0),
+                                    (3, 3, 3)], 1, False),
+        # a table of 3 pages: narrower than the ring of 8, no multiple of 2
+        "narrow-table": (4, 2, 3, [(5, 8, 11), (2, 4, 5), (9, 9, 10)], 1,
+                         False),
+        "gqa-g2": (4, 2, 5, [(3, 4, 17), (6, 8, 9)], 1, False),
+        "mha-g1": (4, 4, 5, [(3, 4, 17), (6, 8, 9)], 1, False),
+        # slab frontiers 6..9 and 14..17 cross from one page into the next
+        "verify-straddle": (4, 2, 5, [(3, 4, 6), (10, 12, 14)], 4, False),
+        "quantized": (4, 2, 6, [(3, 4, 9), (1, 2, 3), (7, 8, 19)], 1, True),
+        "quantized-verify": (4, 2, 5, [(3, 4, 6), (10, 12, 14)], 3, True),
+        # the engine's idle state in every slot: zeroed table rows
+        "all-inactive": (4, 2, 4, [(0, 0, 0)] * 4, 1, False),
+    }[name] + (page,)
+
+
+def _stream_inputs(name, seed):
+    h, kvh, pps, slots, s, quantized, page = _stream_case(name)
+    rs = np.random.RandomState(seed)
+    b, d = len(slots), 16
+    n_pages = 1 + b * pps
+    kf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
+    vf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
+    if quantized:
+        from flexflow_tpu.ops.attention import page_quantize, page_scale
+
+        ks, vs = page_scale(kf, 127.0), page_scale(vf, 127.0)
+        pool = {"k": page_quantize(kf, ks, 127.0, jnp.int8),
+                "v": page_quantize(vf, vs, 127.0, jnp.int8),
+                "k_scale": ks, "v_scale": vs}
+    else:
+        pool = {"k": jnp.asarray(kf), "v": jnp.asarray(vf)}
+    row_len = np.asarray([r for r, _, _ in slots], np.int32)
+    pad = np.asarray([p for _, p, _ in slots], np.int32)
+    wp = (np.asarray([w for _, _, w in slots], np.int32)[:, None]
+          + np.arange(s, dtype=np.int32)[None, :])
+    assert wp.max() < pps * page
+    table = rs.permutation(np.arange(1, n_pages)).reshape(b, pps)
+    last = np.maximum(wp.max(axis=1), row_len - 1) // page
+    if name == "all-inactive":
+        table[:] = 0
+    table = table.astype(np.int32)
+    q = jnp.asarray(rs.randn(b, s, h, d), jnp.float32)
+    return q, pool, table, wp, row_len, pad, last
+
+
+def _run_kernel(q, pool, table, wp, row_len, pad, scale=0.29):
+    return paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table), jnp.asarray(wp),
+        jnp.asarray(row_len), jnp.asarray(pad), scale,
+        k_scales=pool.get("k_scale"), v_scales=pool.get("v_scale"))
+
+
+@pytest.mark.parametrize("ring", [2, 3, 4])
+@pytest.mark.parametrize("name", [
+    "ragged-live-pages", "one-live-page", "narrow-table", "gqa-g2",
+    "mha-g1", "verify-straddle", "quantized", "quantized-verify",
+    "all-inactive"])
+def test_kernel_page_stream_matches_oracle(monkeypatch, name, ring):
+    """Every seam of the page stream against the einsum oracle."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_PAGED_RING_MAX", ring)
+    q, pool, table, wp, row_len, pad, _ = _stream_inputs(name, 29)
+    out = _run_kernel(q, pool, table, wp, row_len, pad)
+    want = _oracle(q, pool, jnp.asarray(table), jnp.asarray(wp),
+                   jnp.asarray(row_len), jnp.asarray(pad), 0.29)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+@pytest.mark.parametrize("name", ["ragged-live-pages", "verify-straddle",
+                                  "all-inactive"])
+def test_kernel_never_reads_a_dead_page(name):
+    """Poison: every pool page that is live for NO slot is NaN — the
+    table's entries past each slot's last live page point at such pages
+    too — and the output still equals the oracle's on the clean pool:
+    a dead page is neither fetched into the arithmetic nor multiplied by
+    a zero probability."""
+    q, pool, table, wp, row_len, pad, last = _stream_inputs(name, 31)
+    want = _oracle(q, pool, jnp.asarray(table), jnp.asarray(wp),
+                   jnp.asarray(row_len), jnp.asarray(pad), 0.29)
+    live_pages = {int(table[b, t]) for b in range(table.shape[0])
+                  for t in range(int(last[b]) + 1)}
+    dead = np.asarray([p for p in range(pool["k"].shape[0])
+                       if p not in live_pages])
+    assert len(dead) > 0
+    poisoned = {n: pool[n].at[dead].set(jnp.nan) for n in ("k", "v")}
+    out = _run_kernel(q, poisoned, table, wp, row_len, pad)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+def test_ring_depth_follows_the_shapes():
+    """The ring is sized from what the kernel sees (page bytes in VMEM,
+    padded to the dtype's tile), never from a model's name: both serving
+    cells' pages get the 4 buffers a tensor past which the chip showed
+    no gain (PERF.md section 6, PR 26); a page eight times InternLM2's
+    gets as many as the budget holds, and never under 2."""
+    from flexflow_tpu.ops.pallas_kernels import (_PAGED_RING_BUDGET,
+                                                 _paged_ring)
+
+    assert _paged_ring(128, 8, 128, 128, jnp.bfloat16) == 4    # InternLM2
+    assert _paged_ring(128, 16, 128, 128, jnp.bfloat16) == 4   # OLMoE
+    assert _paged_ring(128, 8, 128, 128, jnp.int8) == 4
+    wide = _paged_ring(256, 32, 128, 128, jnp.bfloat16)
+    assert wide == 2
+    assert 2 * wide * 256 * 32 * 128 * 2 <= _PAGED_RING_BUDGET
+    assert _paged_ring(256, 16, 128, 128, jnp.bfloat16) == 4
+    assert _paged_ring(256, 16, 256, 128, jnp.bfloat16) == 2
+    assert _paged_ring(512, 64, 256, 256, jnp.float32) == 2    # the floor
+    assert _paged_ring(4, 2, 16, 16, jnp.float32) == 4         # the tests'
 
 
 # ---- paged prefill/append write kernel (ISSUE 18) -------------------------
