@@ -557,7 +557,7 @@ def serve_leg(ff, z: Sizes, rehearsal):
     # compiled kernel vs einsum oracle on the engine's OWN pool: the K/V
     # the traffic above wrote, read back through page tables over it
     op = eng.gen.attn_ops[0]
-    cache = eng.pool[op.name]
+    cache = eng.kv.pool[op.name]
     pps = eng.pages_per_slot
     table = rs.permutation(np.arange(1, eng.num_pages))[:eng.slots * pps]
     table = jnp.asarray(table.reshape(eng.slots, pps), jnp.int32)

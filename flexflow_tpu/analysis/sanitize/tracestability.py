@@ -38,7 +38,7 @@ from flexflow_tpu.analysis.sanitize.lockgraph import LockGraph
 # rule 2's scope: the serving/migration hot paths named by the issue —
 # a shape-dependent slice in offline checkpoint code is not a per-tick
 # hazard
-_HOT_MODULES = ("serving", "router")
+_HOT_MODULES = ("serving", "router", "kv_pool")
 
 
 def check_tracestability(graph: LockGraph) -> List[Violation]:

@@ -218,7 +218,7 @@ class FFConfig:
     # two from 8 — warm prefill programs are reused within a bucket, and
     # ServingEngine.recompile_count proves it
     decode_buckets: Optional[List[int]] = None
-    # radix prefix cache (runtime/serving.py RadixPrefixCache): share KV
+    # radix prefix cache (runtime/kv_pool.py RadixPrefixCache): share KV
     # pages across requests whose prompts start with the same page-aligned
     # token prefix — admission mounts the cached pages read-only and
     # prefills only the tail (copy-on-write: shared pages are never

@@ -578,7 +578,7 @@ class MultiHeadAttention(Op):
     def export_page(self, cache, page):
         """Slice pool page(s) out as the serializable migration payload
         — the unit both the prefill->decode fleet handoff and the
-        HBM->host tier demotion move (runtime/serving.py). ``page`` is a
+        HBM->host tier demotion move (runtime/kv_pool.py). ``page`` is a
         scalar or a (n,) index array (ONE gather per pool array serves a
         whole demotion sweep). Returns device arrays (the caller starts
         ``copy_to_host_async`` and resolves to numpy off the hot path);

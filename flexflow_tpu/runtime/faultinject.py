@@ -52,7 +52,7 @@ Consumers:
   * ``ServingEngine._admit`` checks ``slow(<ms>)@serve:<n>`` and stalls
     the n-th admission host-side by ``<ms>`` — the slow-replica drill
     that expires an in-flight deadline deterministically;
-  * the tiered prefix cache (``runtime/serving.py RadixPrefixCache``)
+  * the tiered prefix cache (``runtime/kv_pool.py RadixPrefixCache``)
     checks ``d2h_fail@migrate:<n>`` on the n-th HBM->host demotion (the
     page dies exactly as it would without a host tier) and
     ``h2d_fail@promote:<n>`` on the n-th host->HBM promotion (the host

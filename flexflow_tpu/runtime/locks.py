@@ -80,7 +80,7 @@ LOCK_RANKS: Dict[str, int] = {
     #                          beneath it, never the deploy lock)
     "router": 10,            # ServingRouter fleet ledger (RLock)
     "engine": 20,            # ServingEngine tick/queue/slots (RLock)
-    "prefix-cache": 30,      # RadixPrefixCache tiered-migration publisher cv
+    "prefix-cache": 30,      # kv_pool.RadixPrefixCache tier publisher cv
     "adapter-pool": 40,      # LoraAdapterPool host allocator
     "pipeline-loader": 45,   # PipelineLoader prefetch cv
     "checkpoint-saver": 48,  # _AsyncSaver publisher cv

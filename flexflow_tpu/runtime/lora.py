@@ -2,7 +2,7 @@
 
 The device pool (ops/lora.py) is fixed geometry; this module is the
 pure-host state machine that decides WHICH adapter lives in WHICH page
-— the RadixPrefixCache discipline applied to adapters:
+— the RadixPrefixCache (runtime/kv_pool.py) discipline, for adapters:
 
   * a REGISTRY of adapters (host-RAM weights, the fault-in source) that
     can be far larger than the device pool;

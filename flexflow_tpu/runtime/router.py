@@ -111,7 +111,8 @@ import numpy as np
 from flexflow_tpu.logger import fflogger
 from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import faultinject, flightrec, locks, telemetry
-from flexflow_tpu.runtime.serving import RadixPrefixCache, version_ns
+from flexflow_tpu.runtime.kv_pool import RadixPrefixCache
+from flexflow_tpu.runtime.serving import version_ns
 
 
 class ReplicaCrash(RuntimeError):

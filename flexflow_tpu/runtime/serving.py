@@ -44,17 +44,17 @@ of it:
     probe; ``submit(..., deadline=)`` retires requests that expire while
     queued as ``"timeout"`` without ever prefilling; ``load()`` is the
     lock-free dispatch signal.
-  * RADIX PREFIX CACHE (RadixPrefixCache): a trie over page-aligned
-    prompt token chunks maps each full KV page a finished prefill
-    produced to its pool page id, with a per-page refcount of the live
-    requests referencing it. Admission looks up the longest cached
-    page-aligned prefix, bumps refcounts, and prefills ONLY the tail —
-    page writes are copy-on-write: a shared page is never written in
-    place (the tail, including the recompute of the matched prefix's
-    partial last page, scatters into fresh pages; decode appends land
-    past the prompt bucket, also in the request's own pages).
-    Retirement decrefs; refcount-0 pages stay cached for future hits
-    until an LRU evictor reclaims them under pool pressure. Identical
+  * ONE OWNER OF THE PAGES (runtime/kv_pool.py KVPagePool): the free
+    list, the pool's device arrays, the page movers and the RADIX
+    PREFIX CACHE — a trie over page-aligned prompt chunks with a
+    refcount per page and an optional pinned-host second tier. The
+    engine only asks: admission RESERVES the longest cached prefix plus
+    fresh pages for the rest and prefills ONLY the tail (copy-on-write:
+    a shared page is never written in place; the tail, including the
+    recompute of the matched prefix's partial last page, and every
+    decode append land in the request's own pages), a finished prefill
+    PUBLISHES its full pages, retirement RELEASES (refcount-0 pages
+    stay cached until LRU eviction under pool pressure). Identical
     prompts across millions of requests then share prefill compute AND
     the HBM pages it produced (ROADMAP item 1).
   * SPECULATIVE DECODING (``draft_model`` + ``speculate_k``): a small
@@ -105,23 +105,17 @@ of it:
     (docs/serving.md "Quantized tier"); pallas-vs-einsum token identity
     and pool bitwise equality still hold exactly.
 
-  * TIERED PREFIX CACHE + DISAGGREGATION PRIMITIVES (ISSUE 12):
-    ``host_kv_pages`` gives the radix trie a pinned host-memory second
-    tier — refcount-0 pages evicted under pool pressure DEMOTE (async
-    ordered D2H publisher, generation-checked) instead of dying, and a
-    trie match against a host-resident edge PROMOTES the payload back
-    (H2D, bitwise), so the shared-prefix corpus is host-RAM-sized. The
-    same page-payload plumbing powers the prefill/decode role split
-    (runtime/router.py): ``prefill_into_cache()`` runs a prompt's
-    prefill through the normal bucket programs and publishes its full
-    pages at refcount 0, ``export_prefix_slab()`` serializes them (+
-    draft-pool KV + quantized scales) to host bytes, and a decode
-    replica's ``import_prefix_slab()`` scatters them in through ONE
-    fixed-shape page-writer program and republishes the trie path — the
-    subsequent submit admits as a prefix hit, so the handoff moves
-    pages, never tokens. ``warmup(prompts)`` drives every reachable
-    (bucket, matched_pages) prefill variant plus the page writer, the
-    thrice-relearned bench gotcha promoted to an API.
+  * DISAGGREGATION PRIMITIVES over the pool's page slabs:
+    ``prefill_into_cache()`` runs a prompt's prefill through the normal
+    bucket programs and publishes its full pages at refcount 0,
+    ``export_prefix_slab()`` serializes them (+ draft-pool KV +
+    quantized scales) to host bytes, and a decode replica's
+    ``import_prefix_slab()`` scatters them in through ONE fixed-shape
+    page-writer program and republishes the trie path — the subsequent
+    submit admits as a prefix hit, so the prefill/decode role split
+    (runtime/router.py) moves pages, never tokens. ``warmup(prompts)``
+    drives every reachable (bucket, matched_pages) prefill variant plus
+    the page writer.
 
 Per-slot cache layout (identical to the ragged rule of
 MultiHeadAttention.decode_forward, with a per-slot prompt pad width):
@@ -134,7 +128,6 @@ from __future__ import annotations
 
 import collections
 import math
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -142,6 +135,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from flexflow_tpu._env import (compilation_cache_dir,
                                compilation_cache_entries)
@@ -149,6 +143,7 @@ from flexflow_tpu.logger import fflogger
 from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import faultinject, flightrec, locks, telemetry
 from flexflow_tpu.runtime.generation import Generator
+from flexflow_tpu.runtime.kv_pool import KVPagePool, Lease
 from flexflow_tpu.runtime.lora import LoraAdapterPool
 
 # process-wide engine ids: the default telemetry `replica` label when no
@@ -167,8 +162,8 @@ def version_ns(version, adapter=None):
     """The prefix-cache namespace for (weight version, LoRA adapter) —
     the ISSUE-14 ``("ns", adapter)`` salt extended to versions (ISSUE
     17): KV depends on the weights that produced it, so cached prefixes
-    must never cross weight versions during an A/B roll. Kept next to
-    RadixPrefixCache.first_chunk so the engine, router affinity, and
+    must never cross weight versions during an A/B roll. Kept in one place
+    (beside its users) so the engine, router affinity, and
     slab import/export derive the SAME key and cannot drift. The default
     version maps to the bare adapter (None for no adapter): zero change
     to any pre-deploy trie or affinity key."""
@@ -215,12 +210,10 @@ class Request:
     tokens: List[int] = field(default_factory=list)  # emitted tokens
     slot: int = -1
     bucket: int = 0
-    pages: List[int] = field(default_factory=list)   # full logical table
-    # prefix-cache bookkeeping: trie nodes whose refcount this request
-    # holds (shared prefix pages + pages it published), and the pages it
-    # owns outright (freed at retirement; trie pages are only decref'd)
-    trie_nodes: List = field(default_factory=list)
-    private_pages: List[int] = field(default_factory=list)
+    # what the request holds of the page pool (runtime/kv_pool.py): the
+    # shared prefix + published pages by reference, the rest outright;
+    # ``lease.pages`` is its full logical page table
+    lease: Optional[Lease] = None
     prefix_tokens: int = 0          # prefill positions served from cache
     t_submit: float = 0.0
     t_admit: float = 0.0            # left the queue for a slot (perf_counter)
@@ -249,635 +242,6 @@ def _pow2_bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
-
-
-class _TrieNode:
-    """One cached KV page: the page_size-token chunk it encodes (its edge
-    label from the parent), the pool page id holding its k/v, and the
-    refcount of live requests whose page tables reference it.
-
-    Tiering (ISSUE 12): ``tier`` is "hbm" (``page`` is a live pool page),
-    "host" (the page was demoted — ``page`` is -1 and ``hostdata`` holds
-    the pinned host copy, None while the async D2H publish is still in
-    flight) or "dead" (a failed migration marked it for lazy reaping).
-    ``gen`` is the migration generation: every demote/kill bumps it, so a
-    late-completing publish for an abandoned migration is dropped by the
-    ordered publisher instead of resurrecting a reused node."""
-
-    __slots__ = ("chunk", "page", "parent", "children", "ref", "last_use",
-                 "tier", "hostdata", "gen")
-
-    def __init__(self, chunk, page, parent):
-        self.chunk = chunk
-        self.page = page
-        self.parent = parent
-        self.children = {}
-        self.ref = 0
-        self.last_use = 0
-        self.tier = "hbm"
-        self.hostdata = None
-        self.gen = 0
-
-
-class RadixPrefixCache:
-    """Radix/trie index over prompt token prefixes at PAGE granularity.
-
-    Each trie edge is exactly ``page_size`` tokens, so a path of depth d
-    names a d-page prompt prefix and maps it to the d pool pages holding
-    its KV — the page, not the token, is the unit of sharing because the
-    pool scatters, gathers and refcounts pages. A page's KV at position j
-    depends only on tokens [0..j] (causal attention), so any request
-    whose prompt starts with the same ``d * page_size`` tokens can mount
-    those pages read-only and prefill just its tail.
-
-    TIERED (HBM -> host) CACHE (ISSUE 12): with ``host_pages > 0`` a
-    refcount-0 page reclaimed under pool pressure MIGRATES to a pinned
-    host-memory tier instead of dying — the node stays in the trie with
-    ``tier == "host"``, its HBM page frees immediately, and the page
-    payload (pool storage bytes + quantized scales, target AND draft
-    pools) publishes to host memory on ONE ordered background publisher
-    thread (the async-checkpointing pattern, runtime/checkpoint.py): the
-    D2H starts in device order before the page can be reused, resolves
-    off the hot path, and a generation check drops the publish if the
-    node was killed/reused meanwhile. A later match against a
-    host-resident edge PROMOTES it back: allocate a fresh HBM page, H2D
-    the payload (bitwise — export/import never requantize), mount. The
-    effective shared-prefix corpus is then host-RAM-sized, not
-    HBM-sized. Tier invariant: on any root->node path the tiers read
-    ``hbm* host*`` — demotion picks nodes with no HBM children,
-    promotion walks the matched path root-down — so a mounted (hbm,
-    ref>0) prefix never sits below a host page. The host tier itself is
-    LRU-bounded at ``host_pages``: overflow evicts the oldest host leaf
-    for real. Failure policy (FF_FAULT ``d2h_fail@migrate:<n>`` /
-    ``h2d_fail@promote:<n>``): a failed demotion means the page dies
-    exactly as it did without the tier; a failed promotion kills the
-    host copy and falls back to cold prefill — never a stall, never a
-    corrupt page mounted.
-
-    Ownership protocol (the copy-on-write rule lives HERE, not in the
-    kernels): a page in the trie is never written again — its producer
-    published it only after prefill, and every borrower's tail/decode
-    writes land in freshly allocated pages past the matched prefix.
-    ``ref`` counts live requests mounting the page; retirement decrefs.
-    A refcount-0 page stays cached (warm for the next hit) until
-    ``evict()`` reclaims it under pool pressure, LRU-first and leaves
-    only — an interior page must outlive its children, since a match
-    walks through it. All host-side, O(prompt/page_size) per lookup;
-    ``evict()`` walks the whole trie per pressure call, which is fine at
-    the pool sizes this engine runs (hundreds of pages) — a
-    persistently-maintained ref-0-leaf LRU makes reclaim O(need) if
-    pool sizes grow by orders of magnitude."""
-
-    def __init__(self, page_size: int, host_pages: int = 0,
-                 d2h=None, h2d=None):
-        self.page_size = int(page_size)
-        self.root = _TrieNode(None, -1, None)
-        self.pages = 0          # HBM-page-holding nodes currently cached
-        self.lookups = 0
-        self.hits = 0
-        self.tokens_saved = 0   # prefill positions served from cache
-        self.evictions = 0      # PRESSURE evictions only (flushes don't
-        #                         count — they are not a pool signal)
-        self._tick = 0          # monotonic LRU clock (bumped per lookup)
-        # incremental mirrors of the trie's refcount state, so stats()
-        # and the per-tick health() probe never walk the trie
-        self._live_refs = 0     # sum of node.ref
-        self._shared = 0        # nodes with ref > 1 right now
-        # ---- host tier (ISSUE 12) ----
-        # d2h(pages) -> resolver() -> [payload, ...]: starts the async
-        # copy of a LIST of pool pages host-ward (one batched gather per
-        # demotion sweep) and returns the callable the ordered publisher
-        # resolves off the hot path; h2d(pages, payloads): writes
-        # payloads back into fresh pool pages (one batched writer
-        # dispatch). The engine injects real device IO; the pure-host
-        # tier tests inject fakes — the state machine itself never
-        # touches a device.
-        self.host_pages = int(host_pages)
-        if self.host_pages < 0:
-            raise ValueError(f"host_pages={host_pages}: must be >= 0")
-        if self.host_pages and (d2h is None or h2d is None):
-            raise ValueError("host_pages > 0 needs d2h and h2d callables")
-        self.d2h = d2h
-        self.h2d = h2d
-        self.host_used = 0      # host-resident pages (pending included)
-        self.demotions = 0
-        self.promotions = 0
-        self.demote_failures = 0
-        self.promote_failures = 0
-        self.host_evictions = 0  # host-LRU overflow kills (pages died)
-        # ordered publisher: demotions publish host-ward in submission
-        # order on ONE daemon thread (the async-checkpointing pattern);
-        # _cv guards hostdata/gen/queue handoff between that thread and
-        # the engine-lock holder. Structural trie mutation stays under
-        # the ENGINE lock only.
-        self._cv = locks.make_condition("prefix-cache")
-        self._pending = collections.deque()
-        self._inflight = 0
-        self._publisher: Optional[threading.Thread] = None
-        # depth-1 tier transitions for the router's tier-aware affinity:
-        # (first-page chunk, "host"|"hbm"|None) — None means the prefix
-        # died entirely (affinity entries pointing at it should drop)
-        self.tier_events = collections.deque(maxlen=4096)
-
-    def _chunk(self, prompt, i: int, ns=None):
-        ps = self.page_size
-        tup = tuple(int(t) for t in prompt[i * ps:(i + 1) * ps])
-        if ns is not None and i == 0:
-            # namespace salt (ISSUE 14): KV depends on the LoRA adapter
-            # the prompt was prefilled under, so cached prefixes must
-            # never cross tenants — salting the FIRST edge partitions
-            # the whole trie per adapter (every deeper edge hangs under
-            # it). The salted first chunk is also the router's
-            # adapter-aware affinity key (first_chunk()).
-            return ("ns", ns) + tup
-        return tup
-
-    @staticmethod
-    def first_chunk(tokens, ns=None):
-        """The trie's first-edge key for ``tokens`` (one page worth of
-        prompt) under adapter namespace ``ns`` — the fleet router's
-        affinity hash, kept in one place so the two layers cannot
-        drift."""
-        tup = tuple(int(t) for t in tokens)
-        return (("ns", ns) + tup) if ns is not None else tup
-
-    def match(self, prompt, max_pages: int, ns=None) -> List[_TrieNode]:
-        """Longest cached page-aligned prefix of ``prompt``, capped at
-        ``max_pages``; returns the node path root-down (possibly empty).
-        Does NOT take references or bump hit statistics — the caller
-        commits with acquire()/note_admitted() only once admission is
-        certain (a request that stays queued on pool pressure re-matches
-        every tick and must leave refcounts AND counters untouched)."""
-        self._tick += 1
-        node, path = self.root, []
-        limit = min(int(max_pages), len(prompt) // self.page_size)
-        for i in range(limit):
-            child = node.children.get(self._chunk(prompt, i, ns))
-            if child is None:
-                break
-            if child.tier == "dead":
-                # a migration failed on the publisher thread; the node
-                # was only MARKED there (trie structure is engine-lock
-                # territory) — reap it lazily here
-                self._kill_subtree(child)
-                break
-            path.append(child)
-            node = child
-        for n in path:
-            n.last_use = self._tick
-        return path
-
-    def note_admitted(self, matched_pages: int):
-        """Commit one admission's lookup to the hit statistics — called
-        exactly once per ADMITTED request, never for retried matches."""
-        self.lookups += 1
-        if matched_pages:
-            self.hits += 1
-            self.tokens_saved += matched_pages * self.page_size
-
-    def acquire(self, nodes):
-        for n in nodes:
-            if n.tier != "hbm":  # the cross-tier refcount rule: only a
-                #  resident page can be mounted — promote first
-                raise AssertionError(
-                    f"acquire on a {n.tier}-tier page: host-resident "
-                    f"prefix pages must be promoted before mounting")
-            n.ref += 1
-            self._live_refs += 1
-            if n.ref == 2:
-                self._shared += 1
-
-    def release(self, nodes):
-        for n in nodes:
-            n.ref -= 1
-            self._live_refs -= 1
-            if n.ref == 1:
-                self._shared -= 1
-            if n.ref < 0:  # accounting bug, not a recoverable state
-                raise AssertionError(
-                    f"prefix-cache refcount underflow on page {n.page}")
-
-    def insert(self, prompt, matched, start: int,
-               pages: List[int], ns=None) -> List[_TrieNode]:
-        """Publish a finished prefill's full-prompt pages: ``pages[j]``
-        holds chunk ``start + j`` of ``prompt``, appended under the
-        ``matched`` path. Each created node starts at ref 1 (the
-        publishing request still mounts it). Stops at the first chunk
-        that already exists — the caller's duplicate page for it stays
-        private (only possible when the match was capped below an
-        existing deeper path)."""
-        node = matched[-1] if matched else self.root
-        created = []
-        for j, page in enumerate(pages):
-            chunk = self._chunk(prompt, start + j, ns)
-            if chunk in node.children:
-                break
-            child = _TrieNode(chunk, page, node)
-            child.ref = 1
-            self._live_refs += 1
-            child.last_use = self._tick
-            node.children[chunk] = child
-            node = child
-            created.append(child)
-            self.pages += 1
-        return created
-
-    def _iter_nodes(self):
-        stack = list(self.root.children.values())
-        while stack:
-            n = stack.pop()
-            yield n
-            stack.extend(n.children.values())
-
-    def cached_paths(self) -> List[Tuple[np.ndarray, object, int]]:
-        """Every root-to-leaf cached prefix, hottest first, as
-        ``(tokens, ns, last_use)`` — the evacuation manifest a
-        preempted/retiring replica walks (ISSUE 20). Tokens are
-        reconstructed from the edge chunks themselves (the first edge's
-        ``("ns", ns)`` salt is peeled back into the namespace), so the
-        caller can re-export each path with export_prefix_slab under the
-        exact per-version/per-adapter key it was cached under. Leaves
-        only: exporting a leaf path carries every interior page, and the
-        importer dedupes shared prefixes. Dead (lost-host-copy) nodes
-        prune their subtrees — there is nothing to evacuate below them."""
-        out = []
-        for first, child in self.root.children.items():
-            if first and first[0] == "ns":
-                ns, toks0 = first[1], first[2:]
-            else:
-                ns, toks0 = None, first
-            stack = [(child, toks0)]
-            while stack:
-                node, toks = stack.pop()
-                if node.tier == "dead":
-                    continue
-                kids = [(c.chunk, c) for c in node.children.values()
-                        if c.tier != "dead"]
-                if not kids:
-                    out.append((np.asarray(toks, np.int32), ns,
-                                node.last_use))
-                    continue
-                for chunk, c in kids:
-                    stack.append((c, toks + chunk))
-        out.sort(key=lambda e: -e[2])
-        return out
-
-    def evict(self, need: int, protect=(), pressure: bool = True) \
-            -> List[int]:
-        """Reclaim up to ``need`` HBM pages, oldest last_use first;
-        returns the freed page ids. Without a host tier this evicts
-        refcount-0 LEAVES and the page dies; with ``host_pages > 0`` and
-        ``pressure=True`` the page DEMOTES instead — the node stays in
-        the trie host-resident (eligible nodes are ref-0 with no HBM
-        children, preserving the hbm*-then-host* path invariant) and the
-        payload publishes host-ward asynchronously in order. ``protect``
-        excludes a just-matched path the caller is about to acquire.
-        Reclaiming a node may expose its parent — the sweep cascades.
-        ``pressure=False`` (hot-swap flush, leak accounting) kills
-        outright — host copies included, since both tiers hold KV that a
-        weight swap staled — and stays out of the ``evictions``
-        pool-pressure signal."""
-        import heapq
-
-        keep = set(id(n) for n in protect)
-        demote = pressure and self.host_pages > 0
-
-        def reclaimable(n):
-            if n.ref != 0 or id(n) in keep or n.tier == "reaped":
-                return False
-            if not pressure:
-                # flush kills outright — any tier, leaves only
-                return not n.children
-            if n.tier != "hbm":
-                return False
-            if demote:
-                # demotion keeps the node: children only need to be
-                # non-HBM so the hbm*-then-host* path invariant holds
-                return all(c.tier != "hbm" for c in n.children.values())
-            return not n.children
-
-        heap = [(n.last_use, id(n), n) for n in self._iter_nodes()
-                if reclaimable(n)]
-        heapq.heapify(heap)
-        freed: List[int] = []
-        selected: List[_TrieNode] = []
-        while heap and (len(freed) + len(selected) < need
-                        or not pressure):
-            _, _, n = heapq.heappop(heap)
-            if not reclaimable(n):
-                continue        # a cascade re-push raced a state change
-            parent = n.parent
-            if demote and n.tier == "hbm":
-                if faultinject.active_plan().fire("d2h_fail", "migrate"):
-                    # failed demotion: the page dies exactly as it did
-                    # before a host tier existed
-                    self.demote_failures += 1
-                    freed.extend(self._kill_subtree(n))
-                else:
-                    # mark now (the cascade must see a non-HBM child);
-                    # the ONE batched D2H snapshot happens below,
-                    # before any freed page can be reused
-                    n.tier = "host"
-                    n.hostdata = None
-                    n.gen += 1
-                    self.pages -= 1
-                    self.host_used += 1
-                    self.demotions += 1
-                    self._tier_event(n, "host")
-                    selected.append(n)
-                self.evictions += 1
-            else:
-                freed.extend(self._kill_subtree(n))
-                if pressure:
-                    self.evictions += 1
-            if parent is not self.root and reclaimable(parent):
-                heapq.heappush(heap, (parent.last_use, id(parent), parent))
-        # a failed-demotion kill (d2h_fail on a parent) may have reaped
-        # an already-selected descendant — its page was freed by the
-        # kill, so it must not reach the snapshot (a page -1 gather
-        # would read junk and double-free)
-        selected = [n for n in selected if n.tier == "host"]
-        if selected:
-            freed.extend(self._demote_sweep(selected))
-            # host-LRU capacity is enforced per SWEEP (a mid-sweep
-            # victim could be a selected-but-unsnapshot node, whose kill
-            # would leak its pool page): after the snapshot every host
-            # node is a legal victim
-            self._make_host_room()
-        return freed
-
-    # ---- the HBM -> host tier state machine (ISSUE 12) -------------------
-
-    def _tier_event(self, node, tier):
-        """Record a depth-1 tier transition for the router's tier-aware
-        prefix affinity: the first-page chunk IS the affinity key."""
-        if node.parent is self.root:
-            self.tier_events.append((node.chunk, tier))
-
-    def _kill_subtree(self, node) -> List[int]:
-        """Remove ``node`` (and its now-unreachable descendants — all
-        non-HBM by the path invariant when a migration kills an interior
-        node) from the trie. Bumps every generation so late publishes
-        abandon, returns the HBM pages freed."""
-        if node.tier == "reaped":
-            return []
-        if node.parent is not None \
-                and node.parent.children.get(node.chunk) is node:
-            del node.parent.children[node.chunk]
-        self._tier_event(node, None)
-        freed: List[int] = []
-        stack = [node]
-        while stack:
-            n = stack.pop()
-            stack.extend(n.children.values())
-            n.children = {}
-            if n.ref:
-                raise AssertionError(
-                    f"killing a mounted prefix page (ref={n.ref})")
-            if n.tier == "hbm":
-                freed.append(n.page)
-                self.pages -= 1
-            elif n.tier in ("host", "dead"):
-                self.host_used -= 1
-                if n.page >= 0:
-                    # selected-for-demotion but not yet snapshot: its
-                    # pool page is still allocated — free it too
-                    freed.append(n.page)
-            n.tier = "reaped"
-            n.page = -1
-            n.hostdata = None
-            n.gen += 1      # abandon any in-flight migration publish
-        with self._cv:
-            self._cv.notify_all()   # wake promoters waiting on a corpse
-        return freed
-
-    def _demote_sweep(self, nodes) -> List[int]:
-        """ONE batched D2H snapshot for a whole eviction sweep's
-        demotions (per-page slicing was measurable host overhead on
-        small hosts): the slices are enqueued BEFORE the freed pages can
-        be reused (device programs execute in order — the PR-9
-        snapshot-before-donate rule), and the ordered publisher resolves
-        them to pinned host memory off the hot path. Returns the freed
-        HBM page ids."""
-        pages = [n.page for n in nodes]
-        handle = self.d2h(list(pages))
-        gens = []
-        for n in nodes:
-            n.page = -1
-            gens.append(n.gen)
-        with self._cv:
-            self._pending.append((list(nodes), gens, handle))
-            self._inflight += len(nodes)
-            self._cv.notify_all()
-        self._ensure_publisher()
-        return pages
-
-    def _make_host_room(self):
-        """LRU within the host tier: overflow evicts the oldest host
-        LEAVES for real (host nodes' children are host by the
-        invariant, so a leaf always exists while host_used > 0). ONE
-        trie walk collects a whole sweep's victims — dead nodes (failed
-        publishes awaiting reap: budget, no data) first, then oldest
-        last_use — and the outer loop re-walks only when killing leaves
-        exposed new ones. Nodes selected for demotion in the CURRENT
-        sweep (page still >= 0, snapshot not yet taken) are never
-        victims — killing one would leak its pool page."""
-        while self.host_used > self.host_pages:
-            cands = [n for n in self._iter_nodes()
-                     if n.tier in ("host", "dead") and not n.children
-                     and n.page < 0]
-            if not cands:
-                return
-            cands.sort(key=lambda n: (0 if n.tier == "dead" else 1,
-                                      n.last_use))
-            for n in cands:
-                if self.host_used <= self.host_pages:
-                    break
-                if n.tier == "reaped" or n.children:
-                    continue
-                self._kill_subtree(n)
-                self.host_evictions += 1
-
-    def promote(self, node, page) -> bool:
-        """H2D one host-resident node into freshly allocated HBM
-        ``page``; True on success (see promote_path)."""
-        if node.tier == "hbm":
-            return True
-        return self.promote_path([node], [page]) == 1
-
-    def promote_path(self, nodes, pages) -> int:
-        """Promote host-resident ``nodes`` (a matched path's host tail,
-        root-down) into ``pages``: per-node failure checks first —
-        FF_FAULT ``h2d_fail@promote:<n>``, a publish that never landed —
-        truncate the run and KILL the failed copy (the caller falls back
-        to cold prefill past it: never a stall, never a corrupt page
-        mounted); then ONE batched H2D writes the surviving prefix back
-        bitwise. Returns the number promoted; unused pages are the
-        caller's to reclaim."""
-        ok_nodes, payloads = [], []
-        for node in nodes:
-            if node.tier != "host":
-                break
-            if faultinject.active_plan().fire("h2d_fail", "promote"):
-                self.promote_failures += 1
-                self._kill_subtree(node)
-                break
-            payload = self.host_payload(node)
-            if payload is None:
-                self.promote_failures += 1
-                self._kill_subtree(node)
-                break
-            ok_nodes.append(node)
-            payloads.append(payload)
-        if not ok_nodes:
-            return 0
-        use = list(pages[:len(ok_nodes)])
-        try:
-            self.h2d(use, payloads)
-        except Exception:   # noqa: BLE001 — any H2D loss falls back cold
-            self.promote_failures += 1
-            for node in ok_nodes:
-                self._kill_subtree(node)
-            return 0
-        for node, page in zip(ok_nodes, use):
-            node.page = int(page)
-            node.tier = "hbm"
-            node.hostdata = None
-            node.gen += 1   # abandon any stale pending publish
-            self.pages += 1
-            self.host_used -= 1
-            self.promotions += 1
-            self._tier_event(node, "hbm")
-        return len(ok_nodes)
-
-    def host_payload(self, node, timeout: float = 60.0):
-        """The node's host-tier payload, waiting (bounded) for an
-        in-flight ordered publish; None if the node died or the publish
-        never lands (the caller treats it as a promotion failure)."""
-        deadline = time.monotonic() + timeout
-        with self._cv:
-            while node.tier == "host" and node.hostdata is None:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    return None
-                self._cv.wait(left)
-            return node.hostdata if node.tier == "host" else None
-
-    def _ensure_publisher(self):
-        if self._publisher is None or not self._publisher.is_alive():
-            self._publisher = threading.Thread(
-                target=self._publisher_main, daemon=True,
-                name="ff-prefix-tier-publisher")
-            self._publisher.start()
-
-    def _publisher_main(self):
-        """ONE background thread publishes demoted pages host-ward in
-        submission order (the async-checkpointing ordered-publisher
-        contract): resolve the D2H handle, then commit the payload ONLY
-        if the node's generation still matches — an abandoned migration
-        (the node was killed, flushed or re-promoted meanwhile) is
-        dropped, never resurrected."""
-        while True:
-            with self._cv:
-                while not self._pending:
-                    self._cv.wait()
-                nodes, gens, handle = self._pending.popleft()
-            payloads, err = None, None
-            try:
-                payloads = handle()
-            except Exception as e:  # noqa: BLE001 — a failed resolve is
-                #   a failed demotion: the pages die, serving continues
-                err = e
-            with self._cv:
-                self._inflight -= len(nodes)
-                for i, (node, gen) in enumerate(zip(nodes, gens)):
-                    if node.gen != gen or node.tier != "host":
-                        continue    # abandoned migration: gen check
-                    if err is not None:
-                        # structural removal needs the engine lock —
-                        # mark dead for lazy reaping by the next
-                        # match/evict walk
-                        node.tier = "dead"
-                        node.hostdata = None
-                        self.demote_failures += 1
-                    else:
-                        node.hostdata = payloads[i]
-                self._cv.notify_all()
-            if err is not None:
-                fflogger.warning(
-                    "prefix tier: D2H publish failed (%s) — %d pages "
-                    "die as if untiered", err, len(nodes))
-
-    def pending_migrations(self) -> int:
-        with self._cv:
-            return self._inflight
-
-    def wait_migrations(self, timeout: float = 60.0) -> bool:
-        """Quiesce the ordered publisher (drain/tests): True when every
-        submitted demotion has published or abandoned."""
-        deadline = time.monotonic() + timeout
-        with self._cv:
-            while self._inflight > 0:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    return False
-                self._cv.wait(left)
-            return True
-
-    def forget(self, prompt, ns=None) -> List[int]:
-        """Kill the deepest unmounted, childless tail of ``prompt``'s
-        cached path (any tier); returns freed HBM pages. The
-        warm-the-import-writer helper: export, forget, re-import leaves
-        the trie state unchanged with the writer program compiled."""
-        path = self.match(prompt, len(prompt) // self.page_size, ns)
-        freed: List[int] = []
-        for n in reversed(path):
-            if n.children or n.ref:
-                break
-            freed.extend(self._kill_subtree(n))
-        return freed
-
-    def flush_namespace(self, ns) -> List[int]:
-        """Kill EVERY cached page under adapter namespace ``ns``, both
-        tiers: the adapter's weights are being replaced, so KV computed
-        under the old weights must never serve a prefix hit for the new
-        ones (it would splice two weight versions into one stream).
-        Refuses while any namespace page is mounted — impossible when
-        the adapter itself is unpinned, since a mounted ns page always
-        belongs to a live request holding the adapter. Returns the
-        freed HBM pages."""
-        roots = [c for c in self.root.children.values()
-                 if isinstance(c.chunk, tuple) and len(c.chunk) >= 2
-                 and c.chunk[0] == "ns" and c.chunk[1] == ns]
-        for node in roots:
-            stack = [node]
-            while stack:
-                n = stack.pop()
-                if n.ref:
-                    raise ValueError(
-                        f"adapter namespace {ns!r} has a mounted cached "
-                        f"page (ref={n.ref}): drain its requests before "
-                        f"replacing the adapter")
-                stack.extend(n.children.values())
-        freed: List[int] = []
-        for node in roots:
-            freed.extend(self._kill_subtree(node))
-        return freed
-
-    def drain_tier_events(self) -> List:
-        """Pop the recorded depth-1 tier transitions (router affinity
-        feed)."""
-        out = []
-        while self.tier_events:
-            out.append(self.tier_events.popleft())
-        return out
-
-    def live_refs(self) -> int:
-        return self._live_refs
-
-    def shared_pages(self) -> int:
-        """Pages mounted by more than one live request right now."""
-        return self._shared
 
 
 class ServingEngine:
@@ -1065,22 +429,61 @@ class ServingEngine:
             # pays the quantization pass, and the params cache cannot
             # invalidate mid-stream
             self.gen._quantized_params()
-        # the pool is COMMITTED (replicated on the model's mesh) up front:
-        # an uncommitted fresh pool has a different pjit signature
-        # (UnspecifiedValue) than the committed arrays every program
-        # RETURNS, so the second call to each warm program would silently
-        # retrace and recompile it — a ~0.5 s stall in the serving loop
-        # that the recompile counter could not see
-        from jax.sharding import NamedSharding, PartitionSpec
+        # host_kv_pages > 0 gives the radix prefix cache a pinned
+        # host-memory second tier (runtime/kv_pool.py): the shared-prefix
+        # corpus is then host-RAM-sized
+        hp = int(host_kv_pages if host_kv_pages is not None
+                 else getattr(cfg, "host_kv_pages", 0))
+        if hp < 0:
+            raise ValueError(f"host_kv_pages={hp}: must be >= 0")
+        if hp and not enable_prefix:
+            raise ValueError(
+                "host_kv_pages > 0 needs the radix prefix cache: the "
+                "host tier lives UNDER the trie (prefix_cache=False "
+                "engines have nothing to demote)")
+        self.host_kv_pages = hp
 
-        repl = NamedSharding(model.mesh, PartitionSpec())
-        self.pool = {
-            op.name: jax.tree.map(
-                lambda a: jax.device_put(a, repl),
-                op.init_paged_cache(self.num_pages, self.page_size,
-                                    cdtype, kv_dtype=self._kv_dtype_arg))
-            for op in self.gen.attn_ops}
-        self._free_pages = list(range(self.num_pages - 1, 0, -1))
+        # speculative decoding: a draft model proposes K greedy tokens
+        # per slot from its own paged pool; one fixed-shape verify
+        # program scores all K+1 positions in a single dispatch
+        self.speculate_k = int(speculate_k if speculate_k is not None
+                               else getattr(cfg, "serve_speculate_k", 0))
+        self.draft_model = (draft_model if draft_model is not None
+                            else getattr(cfg, "draft_model", None))
+        if self.speculate_k < 0:
+            raise ValueError(
+                f"speculate_k={self.speculate_k}: must be >= 0")
+        self.draft_gen = None
+        if self.speculate_k > 0:
+            if self.draft_model is None:
+                raise ValueError(
+                    "speculate_k > 0 needs a draft model (FFConfig."
+                    "draft_model or the draft_model constructor arg): "
+                    "speculative decoding verifies a DRAFT's proposals")
+            tgt_v = int(model._final_tensor.dims[-1])
+            dft_v = int(self.draft_model._final_tensor.dims[-1])
+            if tgt_v != dft_v:
+                raise ValueError(
+                    f"draft/target vocab mismatch: draft emits {dft_v} "
+                    f"logits, target {tgt_v} — the accept rule compares "
+                    f"token ids, so the vocabularies must be identical")
+            self.draft_gen = Generator(
+                self.draft_model, temperature=0.0, top_k=0, eos_id=eos_id,
+                pad_id=pad_id, quantize=quantize)
+            if self.draft_gen.quantize:
+                self.draft_gen._quantized_params()  # once, at init
+
+        # the page pool: free list, device arrays (target and draft),
+        # trie and page movers; its one compiled program, the page
+        # writer, goes through this engine's program table
+        self.kv = KVPagePool(
+            self.gen, self.draft_gen, self.num_pages, self.page_size,
+            self.pages_per_slot, self._kv_dtype_arg, enable_prefix, hp,
+            lambda build, *args: self._compiled_call(
+                ("page_import",), build, *args))
+        # the trie, for the router and stats() to READ: pages enter and
+        # leave it only through self.kv
+        self.prefix_cache = self.kv.prefix_cache
 
         # pool-capacity observability (the router/bench signals ROADMAP
         # item 1 calls for), computed once — the pool's geometry is fixed
@@ -1088,7 +491,7 @@ class ServingEngine:
         # geometry at 2 bytes/element, so kv_capacity_vs_bf16 is exactly
         # the capacity multiplier a quantized pool buys at equal HBM.
         self._pool_bytes = sum(
-            int(a.nbytes) for a in jax.tree_util.tree_leaves(self.pool))
+            int(a.nbytes) for a in jax.tree_util.tree_leaves(self.kv.pool))
         self._kv_bytes_per_token = (
             self._pool_bytes / (self.num_pages * self.page_size))
         self._bf16_bytes_per_token = sum(
@@ -1127,7 +530,7 @@ class ServingEngine:
                 page_size=self.page_size,
                 pages_per_slot=self.pages_per_slot,
                 head_dim=op0.qk_head_dim,
-                dtype=self.pool[op0.name]["k"].dtype,
+                dtype=self.kv.pool[op0.name]["k"].dtype,
                 batch=self.slots, heads=op0.num_heads)
             if tuned is not None:
                 self.paged_attention_impl = tuned
@@ -1147,7 +550,7 @@ class ServingEngine:
                 page_size=self.page_size,
                 pages_per_slot=self.pages_per_slot,
                 head_dim=op0.qk_head_dim,
-                dtype=self.pool[op0.name]["k"].dtype,
+                dtype=self.kv.pool[op0.name]["k"].dtype,
                 batch=self.slots, heads=op0.num_heads)
             if tuned_pf is not None:
                 self.paged_prefill_impl = tuned_pf
@@ -1159,79 +562,6 @@ class ServingEngine:
             self.kv_cache_dtype,
             self.weight_dtype, self._kv_bytes_per_token,
             self._bf16_bytes_per_token / self._kv_bytes_per_token)
-
-        # radix prefix cache: page-granular prompt-prefix sharing with
-        # copy-on-write allocation (shared pages are read-only; every
-        # tail/decode write goes to the request's own fresh pages).
-        # enable_prefix was resolved above, before the kv_pages derive.
-        # tiered prefix cache (ISSUE 12): host_kv_pages > 0 gives the
-        # trie a pinned host-memory second tier — ref-0 pages evicted
-        # under pool pressure demote (async ordered D2H) instead of
-        # dying, and a match against a host-resident edge promotes the
-        # payload back (H2D through the same compiled page writer the
-        # fleet handoff uses). The effective shared-prefix corpus is
-        # then host-RAM-sized.
-        hp = int(host_kv_pages if host_kv_pages is not None
-                 else getattr(cfg, "host_kv_pages", 0))
-        if hp < 0:
-            raise ValueError(f"host_kv_pages={hp}: must be >= 0")
-        if hp and not enable_prefix:
-            raise ValueError(
-                "host_kv_pages > 0 needs the radix prefix cache: the "
-                "host tier lives UNDER the trie (prefix_cache=False "
-                "engines have nothing to demote)")
-        self.host_kv_pages = hp
-        self.prefix_cache = (RadixPrefixCache(
-            self.page_size, host_pages=hp,
-            d2h=self._page_d2h, h2d=self._page_h2d)
-            if enable_prefix else None)
-
-        # speculative decoding: a draft model proposes K greedy tokens
-        # per slot; one fixed-shape verify program scores all K+1
-        # positions in a single dispatch. Greedy-only: every emitted
-        # token is the TARGET's argmax, so the stream is token-identical
-        # to non-speculative decode by construction.
-        self.speculate_k = int(speculate_k if speculate_k is not None
-                               else getattr(cfg, "serve_speculate_k", 0))
-        self.draft_model = (draft_model if draft_model is not None
-                            else getattr(cfg, "draft_model", None))
-        if self.speculate_k < 0:
-            raise ValueError(
-                f"speculate_k={self.speculate_k}: must be >= 0")
-        self.draft_gen = None
-        self.draft_pool = None
-        if self.speculate_k > 0:
-            if self.draft_model is None:
-                raise ValueError(
-                    "speculate_k > 0 needs a draft model (FFConfig."
-                    "draft_model or the draft_model constructor arg): "
-                    "speculative decoding verifies a DRAFT's proposals")
-            tgt_v = int(model._final_tensor.dims[-1])
-            dft_v = int(self.draft_model._final_tensor.dims[-1])
-            if tgt_v != dft_v:
-                raise ValueError(
-                    f"draft/target vocab mismatch: draft emits {dft_v} "
-                    f"logits, target {tgt_v} — the accept rule compares "
-                    f"token ids, so the vocabularies must be identical")
-            self.draft_gen = Generator(
-                self.draft_model, temperature=0.0, top_k=0, eos_id=eos_id,
-                pad_id=pad_id, quantize=quantize)
-            if self.draft_gen.quantize:
-                self.draft_gen._quantized_params()  # once, at init
-            ddtype = self.draft_gen._compute_dtype()
-            drepl = NamedSharding(self.draft_model.mesh, PartitionSpec())
-            # the draft pool mirrors the target pool's page GEOMETRY,
-            # page IDS and storage dtype (its own KVH/Dh): one
-            # allocator, one page table, one radix trie govern both — a
-            # shared prefix page id means target AND draft prefix KV
-            # are both resident
-            self.draft_pool = {
-                op.name: jax.tree.map(
-                    lambda a: jax.device_put(a, drepl),
-                    op.init_paged_cache(self.num_pages, self.page_size,
-                                        ddtype,
-                                        kv_dtype=self._kv_dtype_arg))
-                for op in self.draft_gen.attn_ops}
 
         # ---- paged LoRA adapter pool (ISSUE 14) ----
         # fixed-geometry adapter pages mirroring the KV pool's design: a
@@ -1273,6 +603,7 @@ class ServingEngine:
             self._lora_ops = lora_ops
             self._lora_targets = targets
             self.lora = LoraAdapterPool(app, self.lora_rank, targets)
+            repl = NamedSharding(model.mesh, PartitionSpec())
             self.lora_pool = jax.tree.map(
                 lambda a: jax.device_put(a, repl),
                 lora_ops.init_lora_pool(targets, app, self.lora_rank))
@@ -1381,8 +712,6 @@ class ServingEngine:
         # (the kernel-tune counter baseline _ktune_base is snapshotted
         # in the impl-resolution block above, before the construction-
         # time table lookup)
-        import collections
-
         self._ttfts = collections.deque(maxlen=4096)
         # per-adapter ledgers (ISSUE 14 telemetry satellite): requests,
         # spec proposals/accepts — keyed by adapter label ("none" for
@@ -1568,19 +897,17 @@ class ServingEngine:
         host) memory, per subsystem — the per-pool resolution the
         memory-objective search consumes. Geometry is fixed for the
         engine's life, so these are cheap nbytes sums."""
-        import jax as _jax
-
         def _nbytes(tree):
             return sum(int(a.nbytes)
-                       for a in _jax.tree_util.tree_leaves(tree))
+                       for a in jax.tree_util.tree_leaves(tree))
 
         subs = {"kv_pool": self._pool_bytes}
         pc = self.prefix_cache
         if pc is not None and pc.host_pages:
             page_bytes = self._pool_bytes / max(1, self.num_pages)
             subs["kv_host_tier"] = int(pc.host_used * page_bytes)
-        if self.draft_pool is not None:
-            subs["kv_draft_pool"] = _nbytes(self.draft_pool)
+        if self.kv.draft_pool is not None:
+            subs["kv_draft_pool"] = _nbytes(self.kv.draft_pool)
         if self.lora_pool is not None:
             subs["adapter_pool"] = _nbytes(self.lora_pool)
         if self.gen.quantize:
@@ -1588,7 +915,7 @@ class ServingEngine:
             # (native-weight serving reads the model params, which the
             # model's own ledger row counts — never double-book)
             subs["serve_weights"] = _nbytes(self.gen._quantized_params())
-        dg = getattr(self, "draft_gen", None)
+        dg = self.draft_gen
         if dg is not None and dg.quantize:
             subs["draft_weights"] = _nbytes(dg._quantized_params())
         return (f"engine-{self._tm_labels['replica']}", subs)
@@ -1720,16 +1047,9 @@ class ServingEngine:
                                tokens=len(req.tokens),
                                **({"error": error} if error else {}))
         req.decode_span = 0
-        # COW teardown: pages the trie owns (matched prefix + the pages
-        # this request published) are DECREF'd — they stay cached, warm
-        # for the next hit, until the evictor needs them. Only the
-        # request's private pages (partial prompt page, bucket padding,
-        # decode appends) return to the free list.
-        if req.trie_nodes:
-            self.prefix_cache.release(req.trie_nodes)
-            req.trie_nodes = []
-        self._free_pages.extend(req.private_pages)
-        req.private_pages = []
+        # COW teardown: shared and published pages are decref'd (they
+        # stay cached), only the private ones return to the free list
+        self.kv.release(req.lease)
         # unpin the adapter page (it stays RESIDENT, warm for the
         # tenant's next request, until adapter-pool pressure evicts it)
         if req.adapter is not None and self.lora is not None:
@@ -1748,6 +1068,24 @@ class ServingEngine:
         self.top_ks[slot] = 0
         self.seeds[slot] = 0
         self.lora_pages[slot] = 0
+
+    def _seed_slot(self, slot: int, req: Request, poison):
+        """_retire's inverse: the slot-resident state the fixed-shape
+        programs read every dispatch (page table, lengths, sampling and
+        adapter state). Until it is set a decode dispatch sees the slot
+        as idle."""
+        self.temps[slot] = req.temperature
+        self.top_ps[slot] = req.top_p
+        self.top_ks[slot] = req.top_k
+        self.seeds[slot] = req.seed
+        self.lora_pages[slot] = req.adapter_page
+        self.poison[slot] = poison
+        table = np.zeros((self.pages_per_slot,), np.int32)
+        table[:len(req.lease.pages)] = req.lease.pages
+        self.page_tables[slot] = table
+        self.row_len[slot] = req.prompt.size
+        self.prompt_pad[slot] = req.bucket
+        self.emitted[slot] = 0
 
     def _record_token(self, slot: int, tok: int, ok: bool):
         """Append a sampled token to the slot's request and retire on
@@ -1850,133 +1188,12 @@ class ServingEngine:
         (ISSUE 18); both are bitwise-identical so the choice is purely
         a perf knob — resolution happens at TRACE time inside the
         prefill builders, warm programs pay nothing."""
-        impl = getattr(self, "paged_prefill_impl", "einsum")
         return {
             op.name: op.paged_prefill_write(
                 pool[op.name], caches[op.name]["k"][:, p0:],
-                caches[op.name]["v"][:, p0:], pages, impl=impl)
+                caches[op.name]["v"][:, p0:], pages,
+                impl=self.paged_prefill_impl)
             for op in gen.attn_ops}
-
-    # ---- page migration primitives (tier + fleet handoff, ISSUE 12) ------
-
-    def _page_d2h(self, pages):
-        """Start the async D2H snapshot of a LIST of pool pages — ONE
-        gather per pool array covers a whole demotion sweep or slab
-        export; target AND draft pools (they share page ids), quantized
-        scales included. Returns the resolver the ordered publisher (or
-        a synchronous export) calls for the per-page payload list. The
-        gathers are enqueued BEFORE any page can be reused, and device
-        programs execute in order (the PR-9 snapshot-before-donate
-        rule), so the HBM pages free immediately."""
-        # FIXED gather width: eager jax ops compile per shape, so a
-        # per-sweep-sized index would compile a fresh gather executable
-        # every time the eviction need changes (~100 ms each on CPU —
-        # measured as the whole tier overhead). Chunk to pages_per_slot
-        # rows padded with scratch page 0; the pad payloads are dropped
-        # at resolve.
-        cap = self.pages_per_slot
-        n = len(pages)
-        chunks = []
-        for i in range(0, n, cap):
-            idx = np.zeros((cap,), np.int32)
-            part = pages[i:i + cap]
-            idx[:len(part)] = part
-            chunks.append(idx)
-        parts = []
-        for idx in chunks:
-            sub = {}
-            for op in self.gen.attn_ops:
-                sub[("t", op.name)] = op.export_page(
-                    self.pool[op.name], idx)
-            if self.draft_pool is not None:
-                for op in self.draft_gen.attn_ops:
-                    sub[("d", op.name)] = op.export_page(
-                        self.draft_pool[op.name], idx)
-            parts.append(sub)
-        for sub in parts:
-            for arrs in sub.values():
-                for a in arrs.values():
-                    try:
-                        a.copy_to_host_async()
-                    except (AttributeError, RuntimeError):
-                        pass    # no async copy: resolve() blocks
-
-        def resolve():
-            out = []
-            for ci, sub in enumerate(parts):
-                host = {key: {name: np.asarray(a)
-                              for name, a in arrs.items()}
-                        for key, arrs in sub.items()}
-                rows = min(cap, n - ci * cap)
-                out.extend(
-                    {key: {name: arr[i] for name, arr in arrs.items()}
-                     for key, arrs in host.items()}
-                    for i in range(rows))
-            return out
-
-        return resolve
-
-    def _page_h2d(self, pages, payloads):
-        """Write migrated/handed-off page payloads back into the pools —
-        ONE fixed-shape compiled writer serves EVERY promotion and
-        handoff import: batches are padded to ``pages_per_slot`` rows
-        with scratch page 0 (+ zero payload — the pool's designated
-        garbage page absorbs the pad writes), so the program is
-        count-independent and the tier/handoff hot paths compile nothing
-        per page. Payload bytes land verbatim (scales ride along): the
-        imported pages are BITWISE the donor's."""
-        cap = self.pages_per_slot
-        for i in range(0, len(pages), cap):
-            self._page_h2d_chunk(pages[i:i + cap], payloads[i:i + cap])
-
-    def _page_h2d_chunk(self, pages, payloads):
-        have_draft = self.draft_pool is not None
-        cap = self.pages_per_slot
-        n = len(pages)
-        idx = np.zeros((cap,), np.int32)
-        idx[:n] = pages
-        stacked = {
-            key: {name: np.stack(
-                [p[key][name] for p in payloads]
-                + [np.zeros_like(payloads[0][key][name])] * (cap - n))
-                for name in payloads[0][key]}
-            for key in payloads[0]}
-
-        def build():
-            def kv_page_write(pool, dpool, payload, pages):
-                out = {op.name: op.import_page(pool[op.name], pages,
-                                               payload[("t", op.name)])
-                       for op in self.gen.attn_ops}
-                dout = dpool
-                if have_draft:
-                    dout = {op.name: op.import_page(
-                        dpool[op.name], pages, payload[("d", op.name)])
-                        for op in self.draft_gen.attn_ops}
-                return out, dout
-
-            return jax.jit(kv_page_write, donate_argnums=(0, 1))
-
-        self.pool, dp = self._compiled_call(
-            ("page_import",), build, self.pool, self.draft_pool, stacked,
-            idx)
-        if have_draft:
-            self.draft_pool = dp
-
-    def _promote_matched(self, matched):
-        """Promote the host-resident tail of a matched path HBM-ward,
-        root-down (parents first keeps the hbm*-then-host* invariant)
-        through ONE batched H2D. The caller has already reserved enough
-        free pages. A failed promotion truncates the path there —
-        everything past it prefills cold — and unused pages return to
-        the free list."""
-        host = [n for n in matched if n.tier != "hbm"]
-        if not host:
-            return matched
-        n_hbm = len(matched) - len(host)
-        pages = [self._free_pages.pop() for _ in host]
-        k = self.prefix_cache.promote_path(host, pages)
-        self._free_pages.extend(pages[k:])
-        return matched[:n_hbm + k]
 
     @staticmethod
     def _routing_sum(routing):
@@ -2341,9 +1558,8 @@ class ServingEngine:
         with self._lock:
             replacing = name in self.lora.registry
             self.lora.register(name, weights, alpha)
-            if replacing and self.prefix_cache is not None:
-                self._free_pages.extend(
-                    self.prefix_cache.flush_namespace(name))
+            if replacing:
+                self.kv.flush_namespace(name)
 
     def _write_adapter_page(self, page: int, payload: Dict, scale: float):
         """Fault an adapter into pool ``page`` through the ONE
@@ -2400,14 +1616,68 @@ class ServingEngine:
         version bit-identical to the bare adapter key."""
         return version_ns(self.weight_version, adapter)
 
+    def _run_prefill(self, prompt, bucket: int, lease, sampling,
+                     adapter_page: int, poison):
+        """Dispatch one run-to-completion prefill of ``prompt`` into
+        ``lease``'s pages, target then draft; returns the device values
+        ``(tok, ok, routed)``. A prefix hit gathers the matched pages
+        read-only and prefills only the tail slab [full*ps, bucket) into
+        FRESH pages — the matched prefix's partial last page (tokens
+        past full*ps) is re-materialized into the lease's own first tail
+        page, never written in the donor's (the COW rule). One program
+        per (bucket, full): bounded like the buckets themselves, flat
+        after warmup. The ONE frame admission adds between ``step()``
+        and a prefill program's first call (see _admit)."""
+        kv = self.kv
+        full = len(lease.matched)
+        n_prefill = math.ceil(bucket / self.page_size)
+        prefix_pages = np.asarray(lease.pages[:full], np.int32)
+        tail_pages = np.asarray(lease.pages[full:n_prefill], np.int32)
+        length = np.asarray([prompt.size], np.int32)
+        p0 = full * self.page_size
+        padded = np.full((1, bucket - p0), self.pad_id, np.int32)
+        padded[0, :prompt.size - p0] = prompt[p0:]
+        if full:
+            tok, ok, kv.pool, *routed = self._compiled_call(
+                ("prefill_hit", bucket, full),
+                lambda: self._build_prefill_hit(bucket, full),
+                self.gen._params(), self.model.bn_state, padded,
+                np.asarray([[prompt[-1]]], np.int32), length, kv.pool,
+                prefix_pages, tail_pages, poison, *sampling,
+                *self._lora_args_1(adapter_page))
+        else:
+            tok, ok, kv.pool, *routed = self._compiled_call(
+                ("prefill", bucket, n_prefill, self.prefill_chunk),
+                lambda: self._build_prefill(bucket, n_prefill),
+                self.gen._params(), self.model.bn_state, padded, length,
+                kv.pool, tail_pages, poison, *sampling,
+                *self._lora_args_1(adapter_page))
+        if self.draft_gen is not None:
+            # the draft model's prefix KV rides the same page ids, so its
+            # prefill mirrors the target's hit/cold split exactly
+            if full:
+                kv.draft_pool = self._compiled_call(
+                    ("draft_prefill_hit", bucket, full),
+                    lambda: self._build_draft_prefill_hit(bucket, full),
+                    self.draft_gen._params(), self.draft_model.bn_state,
+                    padded, kv.draft_pool, prefix_pages, tail_pages)
+            else:
+                kv.draft_pool = self._compiled_call(
+                    ("draft_prefill", bucket, n_prefill),
+                    lambda: self._build_draft_prefill(bucket, n_prefill),
+                    self.draft_gen._params(), self.draft_model.bn_state,
+                    padded, kv.draft_pool, tail_pages)
+        return tok, ok, routed
+
     def _admit(self) -> int:
         """Move queued requests into free slots: look up the longest
         cached prompt prefix, allocate fresh pages for everything past it
         (copy-on-write — shared pages are never written), prefill the
         tail (bucket-shaped program) and seed the slot; one ``prefill``
         span per admitted request, from the bucket program's dispatch to
-        its first token. Returns the number admitted. The phase stays
-        ONE function with the span as a ``with`` block: every Python
+        its first token. Returns the number admitted. The span stays a
+        ``with`` block here and the dispatch (_run_prefill, shared with
+        prefill_into_cache) is the only call in between: every Python
         frame between ``step()`` and a program's first call makes
         tracing and lowering that program slower (0.2-0.4 s a frame for
         a 24-layer prefill on the chip's host: PERF.md section 6,
@@ -2425,51 +1695,21 @@ class ServingEngine:
             req = self._queue[0]
             total = req.bucket + req.max_new_tokens
             n_total = math.ceil(total / self.page_size)
-            # longest cached page-aligned prefix, capped so at least the
-            # prompt's LAST token is always prefilled (its logits seed
-            # the first emitted token). No refcounts move until the
-            # admission is certain.
-            matched: List[_TrieNode] = []
-            if self.prefix_cache is not None:
-                cap = (req.prompt.size - 1) // self.page_size
-                # the trie is namespaced per (weight version, adapter):
-                # KV depends on both the adapter's deltas and the
-                # weights that produced it — tenants never share prefix
-                # pages, and neither do weight versions mid-roll
-                matched = self.prefix_cache.match(
-                    req.prompt, cap, ns=self._cache_ns(req.adapter))
-            full = len(matched)
-            # host-resident matched pages each need a fresh HBM page to
-            # promote into before they can be mounted read-only
-            n_host = sum(1 for n in matched if n.tier != "hbm")
-            need = n_total - full + n_host
-            if len(self._free_pages) < need:
-                if self.prefix_cache is not None:
-                    # pool pressure: reclaim cold cached pages (LRU,
-                    # refcount-0 only; with a host tier they demote
-                    # instead of dying; the just-matched path is
-                    # protected — it is about to be mounted)
-                    self._free_pages.extend(self.prefix_cache.evict(
-                        need - len(self._free_pages), protect=matched))
-                if len(self._free_pages) < need:
-                    # still short: wait for a retirement to free pages.
-                    # Head-of-line blocking is deliberate — FIFO
-                    # admission keeps TTFT fairness; submit() already
-                    # guarantees the request fits an EMPTY pool (the
-                    # trie is fully evictable once its users retire),
-                    # so progress is always possible. The request stays
-                    # QUEUED with no refcounts or pages held.
-                    return admitted
-            if n_host:
-                # H2D the host-tier part of the match; a failed
-                # promotion truncates the path (cold prefill past it)
-                matched = self._promote_matched(matched)
-                full = len(matched)
-                need = n_total - full   # promoted pages left the free
-                #                         list; the rest is fresh pages
-                if len(self._free_pages) < need:
-                    # raced shortfall after a failed promotion
-                    return admitted
+            # the cached prefix is capped so at least the prompt's LAST
+            # token is always prefilled (its logits seed the first
+            # emitted token), and namespaced per (weight version,
+            # adapter): KV depends on both. None = pool pressure: wait
+            # for a retirement, QUEUED with nothing held. Head-of-line
+            # blocking is deliberate — FIFO admission keeps TTFT
+            # fairness; submit() already guarantees the request fits an
+            # EMPTY pool (the trie is fully evictable once its users
+            # retire), so progress is always possible.
+            ns = self._cache_ns(req.adapter)
+            lease = self.kv.reserve(
+                req.prompt, ns, (req.prompt.size - 1) // self.page_size,
+                n_total, hold=True)
+            if lease is None:
+                return admitted
             adapter_page = 0
             if req.adapter is not None:
                 # pin the tenant's adapter page; a miss FAULTS it in
@@ -2521,15 +1761,10 @@ class ServingEngine:
                 # the injected breach itself
                 time.sleep((faultinject.active_plan().last_value or 0)
                            / 1000.0)
-            fresh = [self._free_pages.pop() for _ in range(need)]
-            if self.prefix_cache is not None:
-                self.prefix_cache.note_admitted(full)
-            if matched:
-                self.prefix_cache.acquire(matched)
-                req.trie_nodes = list(matched)
-                req.prefix_tokens = full * self.page_size
-            req.private_pages = list(fresh)
-            req.pages = [n.page for n in matched] + fresh
+            self.kv.commit(lease)
+            full = len(lease.matched)
+            req.lease = lease
+            req.prefix_tokens = full * self.page_size
             req.slot = slot
             req.state = "running"
             req.adapter_page = adapter_page
@@ -2571,79 +1806,10 @@ class ServingEngine:
                             bucket=req.bucket,
                             prompt_tokens=int(req.prompt.size),
                             matched_pages=full) as psp:
-                # slot-resident sampling + adapter state: the fixed-shape
-                # programs read these arrays every dispatch
-                self.temps[slot] = req.temperature
-                self.top_ps[slot] = req.top_p
-                self.top_ks[slot] = req.top_k
-                self.seeds[slot] = req.seed
-                self.lora_pages[slot] = adapter_page
-                self.poison[slot] = poison
-                table = np.zeros((self.pages_per_slot,), np.int32)
-                table[:n_total] = req.pages
-                self.page_tables[slot] = table
-                self.row_len[slot] = req.prompt.size
-                self.prompt_pad[slot] = req.bucket
-                self.emitted[slot] = 0
-
-                if full:
-                    # prefix hit: gather the matched pages read-only, prefill
-                    # only the tail slab [full*ps, bucket) into FRESH pages —
-                    # the matched prefix's partial last page (tokens past
-                    # full*ps) is re-materialized into the request's own
-                    # first tail page, never written in the donor's (the COW
-                    # rule). One program per (bucket, full): bounded like the
-                    # buckets themselves, flat after warmup.
-                    p0 = full * self.page_size
-                    padded_tail = np.full((1, req.bucket - p0), self.pad_id,
-                                          np.int32)
-                    tail = req.prompt[p0:]
-                    padded_tail[0, :tail.size] = tail
-                    tok_last = np.asarray([[req.prompt[-1]]], np.int32)
-                    tok, ok, self.pool, *routed = self._compiled_call(
-                        ("prefill_hit", req.bucket, full),
-                        lambda: self._build_prefill_hit(req.bucket, full),
-                        self.gen._params(), self.model.bn_state, padded_tail,
-                        tok_last, np.asarray([req.prompt.size], np.int32),
-                        self.pool, np.asarray(req.pages[:full], np.int32),
-                        np.asarray(req.pages[full:n_prefill], np.int32),
-                        np.float32(self.poison[slot]),
-                        *self._sampling_args_1(req),
-                        *self._lora_args_1(adapter_page))
-                else:
-                    padded = np.full((1, req.bucket), self.pad_id, np.int32)
-                    padded[0, :req.prompt.size] = req.prompt
-                    tok, ok, self.pool, *routed = self._compiled_call(
-                        ("prefill", req.bucket, n_prefill, self.prefill_chunk),
-                        lambda: self._build_prefill(req.bucket, n_prefill),
-                        self.gen._params(), self.model.bn_state, padded,
-                        np.asarray([req.prompt.size], np.int32), self.pool,
-                        np.asarray(req.pages[:n_prefill], np.int32),
-                        np.float32(self.poison[slot]),
-                        *self._sampling_args_1(req),
-                        *self._lora_args_1(adapter_page))
-                if self.draft_gen is not None:
-                    # the draft model's prefix KV rides the same page ids, so
-                    # its prefill mirrors the target's hit/cold split exactly
-                    if full:
-                        self.draft_pool = self._compiled_call(
-                            ("draft_prefill_hit", req.bucket, full),
-                            lambda: self._build_draft_prefill_hit(req.bucket,
-                                                                  full),
-                            self.draft_gen._params(),
-                            self.draft_model.bn_state,
-                            padded_tail, self.draft_pool,
-                            np.asarray(req.pages[:full], np.int32),
-                            np.asarray(req.pages[full:n_prefill], np.int32))
-                    else:
-                        self.draft_pool = self._compiled_call(
-                            ("draft_prefill", req.bucket, n_prefill),
-                            lambda: self._build_draft_prefill(req.bucket,
-                                                              n_prefill),
-                            self.draft_gen._params(),
-                            self.draft_model.bn_state,
-                            padded, self.draft_pool,
-                            np.asarray(req.pages[:n_prefill], np.int32))
+                self._seed_slot(slot, req, poison)
+                tok, ok, routed = self._run_prefill(
+                    req.prompt, req.bucket, lease,
+                    self._sampling_args_1(req), adapter_page, poison)
                 with self._span("prefill_fetch"):
                     # ONE device_get: the copies back start together
                     ok, tok, *routed = jax.device_get((ok, tok, *routed))
@@ -2656,23 +1822,7 @@ class ServingEngine:
                     req.decode_span = telemetry.tracer().begin(
                         "decode", trace_id=req.trace_id,
                         track=self._tm_track)
-                if self.prefix_cache is not None and ok_host:
-                    # publish this prompt's FULL pages beyond the matched
-                    # prefix for future sharing (poisoned/non-finite prefills
-                    # are never published — a NaN prompt cache must not
-                    # infect later requests). Published pages move from
-                    # private to trie-owned: decref'd at retirement, freed
-                    # only by eviction.
-                    last = req.prompt.size // self.page_size
-                    if last > full:
-                        created = self.prefix_cache.insert(
-                            req.prompt, matched, full, req.pages[full:last],
-                            ns=self._cache_ns(req.adapter))
-                        if created:
-                            adopted = {n.page for n in created}
-                            req.trie_nodes.extend(created)
-                            req.private_pages = [p for p in req.private_pages
-                                                 if p not in adopted]
+                self.kv.publish(lease, req.prompt, ns, ok_host)
                 self.active[slot] = True
                 self._record_token(slot, tok_host, ok_host)
         return admitted
@@ -2743,44 +1893,30 @@ class ServingEngine:
         ps = self._partial.pop(slot)
         req = ps["req"]
         n_prefill = ps["n_prefill"]
-        tok, ok, self.pool = self._compiled_call(
+        pages = np.asarray(req.lease.pages[:n_prefill], np.int32)
+        tok, ok, self.kv.pool = self._compiled_call(
             ("prefill_ifinal", req.bucket, n_prefill),
             lambda: self._build_prefill_ifinal(req.bucket, n_prefill),
             self.gen._params(), self.model.bn_state, ps["padded"],
             np.asarray([req.prompt.size], np.int32), ps["caches"],
-            self.pool, np.asarray(req.pages[:n_prefill], np.int32),
-            ps["poison"], *self._sampling_args_1(req),
+            self.kv.pool, pages, ps["poison"], *self._sampling_args_1(req),
             *self._lora_args_1(ps["adapter_page"]))
         if self.draft_gen is not None:
             # the draft pool rides the same page ids; its cold prefill
             # program (shared with run-to-completion admission) fills
             # them in one pass — the TARGET's prefill is the
             # head-of-line blocker this path splits, not the draft's
-            self.draft_pool = self._compiled_call(
+            self.kv.draft_pool = self._compiled_call(
                 ("draft_prefill", req.bucket, n_prefill),
                 lambda: self._build_draft_prefill(req.bucket, n_prefill),
                 self.draft_gen._params(), self.draft_model.bn_state,
-                ps["padded"], self.draft_pool,
-                np.asarray(req.pages[:n_prefill], np.int32))
+                ps["padded"], self.kv.draft_pool, pages)
         with self._span("prefill_fetch"):
             ok, tok = jax.device_get((ok, tok))
             ok_host, tok_host = bool(ok[0]), int(tok[0])
         # decode-state arrays applied only NOW: until this instant every
         # decode dispatch saw this slot as idle
-        self.temps[slot] = req.temperature
-        self.top_ps[slot] = req.top_p
-        self.top_ks[slot] = req.top_k
-        self.seeds[slot] = req.seed
-        self.lora_pages[slot] = ps["adapter_page"]
-        self.poison[slot] = ps["poison"]
-        n_total = math.ceil((req.bucket + req.max_new_tokens)
-                            / self.page_size)
-        table = np.zeros((self.pages_per_slot,), np.int32)
-        table[:n_total] = req.pages
-        self.page_tables[slot] = table
-        self.row_len[slot] = req.prompt.size
-        self.prompt_pad[slot] = req.bucket
-        self.emitted[slot] = 0
+        self._seed_slot(slot, req, ps["poison"])
         if ps["tm"]:
             telemetry.tracer().complete(
                 "prefill", ps["t_adm"],
@@ -2790,19 +1926,8 @@ class ServingEngine:
                 matched_pages=0, ok=ok_host)
             req.decode_span = telemetry.tracer().begin(
                 "decode", trace_id=req.trace_id, track=self._tm_track)
-        if self.prefix_cache is not None and ok_host:
-            # publish the prompt's full pages for future sharing —
-            # the same rule (and the same insert) as _admit's cold leg
-            last = req.prompt.size // self.page_size
-            if last > 0:
-                created = self.prefix_cache.insert(
-                    req.prompt, [], 0, req.pages[:last],
-                    ns=self._cache_ns(req.adapter))
-                if created:
-                    adopted = {n.page for n in created}
-                    req.trie_nodes.extend(created)
-                    req.private_pages = [p for p in req.private_pages
-                                         if p not in adopted]
+        self.kv.publish(req.lease, req.prompt,
+                        self._cache_ns(req.adapter), ok_host)
         self.active[slot] = True
         self._record_token(slot, tok_host, ok_host)
 
@@ -2870,103 +1995,28 @@ class ServingEngine:
             try:
                 # the checkout pins the adapter only for the duration of
                 # the prefill (no slot holds it afterwards)
-                return self._prefill_into_cache_locked(prompt, bucket,
-                                                       adapter, apage)
+                ns = self._cache_ns(adapter)
+                last = prompt.size // self.page_size  # publishable pages
+                lease = self.kv.reserve(
+                    prompt, ns, (prompt.size - 1) // self.page_size,
+                    math.ceil(bucket / self.page_size), hold=False)
+                if lease is None:
+                    return None
+                if not lease.need:
+                    return last             # already fully published
+                self.kv.commit(lease)
+                _, ok, _ = self._run_prefill(
+                    prompt, bucket, lease, self._sampling_args_greedy(),
+                    apage, np.float32(0.0))
+                ok = bool(np.asarray(ok)[0])
+                self.kv.publish(lease, prompt, ns, ok)
+                if not ok:
+                    return None
+                self._prefill_only += 1
+                return last
             finally:
                 if adapter is not None:
                     self.lora.release(adapter)
-
-    def _prefill_into_cache_locked(self, prompt, bucket: int,
-                                   adapter: Optional[str], apage: int):
-            ps_sz = self.page_size
-            last = prompt.size // ps_sz     # publishable full pages
-            cap = (prompt.size - 1) // ps_sz
-            matched = self.prefix_cache.match(
-                prompt, cap, ns=self._cache_ns(adapter))
-            full = len(matched)
-            if last <= full:
-                return last                 # already fully published
-            n_prefill = math.ceil(bucket / ps_sz)
-            n_host = sum(1 for n in matched if n.tier != "hbm")
-            need = n_prefill - full + n_host
-            if len(self._free_pages) < need:
-                self._free_pages.extend(self.prefix_cache.evict(
-                    need - len(self._free_pages), protect=matched))
-                if len(self._free_pages) < need:
-                    return None
-            if n_host:
-                matched = self._promote_matched(matched)
-                full = len(matched)
-                if last <= full:
-                    return last
-                if len(self._free_pages) < n_prefill - full:
-                    return None
-            fresh = [self._free_pages.pop()
-                     for _ in range(n_prefill - full)]
-            prefix_pages = np.asarray([n.page for n in matched], np.int32)
-            if full:
-                p0 = full * ps_sz
-                padded_tail = np.full((1, bucket - p0), self.pad_id,
-                                      np.int32)
-                tail = prompt[p0:]
-                padded_tail[0, :tail.size] = tail
-                tok_last = np.asarray([[prompt[-1]]], np.int32)
-                _, ok, self.pool = self._compiled_call(
-                    ("prefill_hit", bucket, full),
-                    lambda: self._build_prefill_hit(bucket, full),
-                    self.gen._params(), self.model.bn_state, padded_tail,
-                    tok_last, np.asarray([prompt.size], np.int32),
-                    self.pool, prefix_pages,
-                    np.asarray(fresh, np.int32), np.float32(0.0),
-                    *self._sampling_args_greedy(),
-                    *self._lora_args_1(apage))
-            else:
-                padded = np.full((1, bucket), self.pad_id, np.int32)
-                padded[0, :prompt.size] = prompt
-                _, ok, self.pool = self._compiled_call(
-                    ("prefill", bucket, n_prefill, self.prefill_chunk),
-                    lambda: self._build_prefill(bucket, n_prefill),
-                    self.gen._params(), self.model.bn_state, padded,
-                    np.asarray([prompt.size], np.int32), self.pool,
-                    np.asarray(fresh, np.int32), np.float32(0.0),
-                    *self._sampling_args_greedy(),
-                    *self._lora_args_1(apage))
-            if self.draft_gen is not None:
-                # the slab must carry the draft pool's prefix KV too —
-                # it rides the same page ids on the decode replica
-                if full:
-                    self.draft_pool = self._compiled_call(
-                        ("draft_prefill_hit", bucket, full),
-                        lambda: self._build_draft_prefill_hit(bucket,
-                                                              full),
-                        self.draft_gen._params(),
-                        self.draft_model.bn_state, padded_tail,
-                        self.draft_pool, prefix_pages,
-                        np.asarray(fresh, np.int32))
-                else:
-                    self.draft_pool = self._compiled_call(
-                        ("draft_prefill", bucket, n_prefill),
-                        lambda: self._build_draft_prefill(bucket,
-                                                          n_prefill),
-                        self.draft_gen._params(),
-                        self.draft_model.bn_state, padded,
-                        self.draft_pool, np.asarray(fresh, np.int32))
-            if not bool(np.asarray(ok)[0]):
-                # a non-finite prefill must never publish (the PR-6
-                # rule): the pages return to the pool untracked
-                self._free_pages.extend(fresh)
-                return None
-            pages = [n.page for n in matched] + fresh
-            created = self.prefix_cache.insert(
-                prompt, matched, full, pages[full:last],
-                ns=self._cache_ns(adapter))
-            # the publisher holds no mount: published pages sit warm at
-            # refcount 0, exportable and evictable like any cached page
-            self.prefix_cache.release(created)
-            adopted = {n.page for n in created}
-            self._free_pages.extend(p for p in fresh if p not in adopted)
-            self._prefill_only += 1
-            return last
 
     def export_prefix_slab(self, prompt,
                            adapter: Optional[str] = None,
@@ -2987,56 +2037,25 @@ class ServingEngine:
         prefix must still be cached HERE (shards import their
         predecessors' slabs before prefilling), so the exported pages'
         KV attends the true full prefix."""
-        return self._export_slab_ns(prompt, self._cache_ns(adapter),
-                                    start_page)
+        return self.export_prefix_path(prompt, self._cache_ns(adapter),
+                                       start_page)
 
-    def _export_slab_ns(self, prompt, ns, start_page: int = 0) \
+    def export_prefix_path(self, prompt, ns, start_page: int = 0) \
             -> Optional[Dict]:
         """export_prefix_slab against an EXPLICIT salted namespace — the
-        evacuation path (ISSUE 20) re-exports trie paths whose
-        (version, adapter) salt was read back off the trie itself, so a
-        retiring replica's A/B-versioned and per-adapter prefixes land
-        on survivors under the exact key they were cached under."""
+        evacuation path (ISSUE 20) re-exports each
+        cached_prefix_manifest() entry under the (version, adapter) salt
+        read back off the trie itself, so a retiring replica's
+        A/B-versioned and per-adapter prefixes land on survivors under
+        the exact key they were cached under. None when the path's pages
+        were evicted since the manifest walk — the entry simply drops
+        out of the evacuation."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         with self._lock:
-            if self.prefix_cache is None:
-                return None
-            last = prompt.size // self.page_size
-            if start_page < 0 or start_page >= last:
-                if start_page == 0:
-                    return None     # last < 1: nothing page-aligned
-                raise ValueError(
-                    f"start_page={start_page}: must be in [0, {last}) "
-                    f"for this prompt's {last} full prefix pages")
-            path = self.prefix_cache.match(prompt, last, ns=ns)
-            if len(path) < last:
-                return None
-            tail = path[start_page:]
-            # host-tier pages export from their pinned payloads; the
-            # HBM part D2Hs in ONE batched gather
-            hbm = [n for n in tail if n.tier == "hbm"]
-            hbm_payloads = (self._page_d2h([n.page for n in hbm])()
-                            if hbm else [])
-            by_node = {id(n): p for n, p in zip(hbm, hbm_payloads)}
-            payloads = []
-            for node in tail:
-                if node.tier == "host":
-                    payload = self.prefix_cache.host_payload(node)
-                    if payload is None:
-                        return None
-                else:
-                    payload = by_node[id(node)]
-                payloads.append(payload)
-            self._slab_exports += 1
-            # the slab carries the exporter's SALTED namespace: an
-            # importer on a different weight version files it under the
-            # exporter's version key, so its own traffic can never hit
-            # cross-version KV (zero stale hits by construction)
-            return {"page_size": self.page_size,
-                    "tokens": prompt[:last * self.page_size].copy(),
-                    "ns": ns,
-                    "start_page": int(start_page),
-                    "payload": payloads}
+            slab = self.kv.export_slab(prompt, ns, start_page)
+            if slab is not None:
+                self._slab_exports += 1
+            return slab
 
     def import_prefix_slab(self, slab) -> int:
         """Decode-side handoff ingestion: scatter a peer replica's page
@@ -3054,99 +2073,11 @@ class ServingEngine:
         refused (return 0, no pages written): publishing pages past a
         gap would cache a prefix whose middle was never written."""
         with self._lock:
-            if self.prefix_cache is None:
-                return 0
-            if int(slab["page_size"]) != self.page_size:
-                raise ValueError(
-                    f"slab page_size {slab['page_size']} != engine "
-                    f"page_size {self.page_size}: fleet replicas must "
-                    f"share the pool geometry")
-            if not slab["payload"]:
-                return 0
-            have_draft = any(k[0] == "d" for k in slab["payload"][0])
-            if have_draft != (self.draft_pool is not None):
-                raise ValueError(
-                    "slab draft-pool payload mismatch: exporter and "
-                    "importer must agree on speculation")
-            # the payload must match THIS pool's storage exactly:
-            # import_page casts silently, so a dtype/geometry mismatch
-            # (e.g. a bf16 slab into an int8 engine) would publish
-            # saturating-cast garbage served as a prefix hit — reject
-            # loudly instead, like the page_size check above
-            p0 = slab["payload"][0]
-            for op in self.gen.attn_ops:
-                sub = p0.get(("t", op.name))
-                if sub is None:
-                    raise ValueError(
-                        f"slab payload missing attention op {op.name!r}:"
-                        f" exporter and importer must run the same "
-                        f"model")
-                pool = self.pool[op.name]
-                pk = np.asarray(sub["k"])
-                if pk.dtype != pool["k"].dtype \
-                        or pk.shape != pool["k"].shape[1:]:
-                    raise ValueError(
-                        f"slab payload for {op.name!r} is {pk.dtype}"
-                        f"{pk.shape} but this engine's pool stores "
-                        f"{pool['k'].dtype}{pool['k'].shape[1:]}: fleet "
-                        f"replicas must share kv_cache_dtype and pool "
-                        f"geometry")
-                if ("k_scale" in pool) != ("k_scale" in sub):
-                    raise ValueError(
-                        f"slab scale presence mismatch for {op.name!r}: "
-                        f"quantized and full-width pools cannot exchange"
-                        f" pages")
-            tokens = np.asarray(slab["tokens"], np.int32).reshape(-1)
-            ns = slab.get("ns")
-            sp = int(slab.get("start_page", 0))
-            n = sp + len(slab["payload"])
-            path = self.prefix_cache.match(tokens, n, ns=ns)
-            if len(path) < sp:
-                # a partial slab landing before its predecessors: pages
-                # [len(path), sp) are neither cached here nor in this
-                # payload — importing would publish a gapped prefix
-                return 0
-            # only extend under a fully HBM-resident prefix: inserting
-            # fresh hbm nodes below a host-tier tail would break the
-            # hbm*-then-host* path invariant that promotion truncation
-            # and freed-page accounting depend on. A host-resident tail
-            # means the prefix IS cached — the next submit promotes it;
-            # there is nothing to import here.
-            if any(nd.tier != "hbm" for nd in path):
-                return 0
-            start = len(path)
-            missing = n - start
-            if missing <= 0:
-                return 0
-            if len(self._free_pages) < missing:
-                self._free_pages.extend(self.prefix_cache.evict(
-                    missing - len(self._free_pages), protect=path))
-            take = min(missing, len(self._free_pages))
-            if take <= 0:
-                return 0
-            pages = [self._free_pages.pop() for _ in range(take)]
-            # ONE batched writer dispatch (padded to pages_per_slot
-            # chunks) scatters the whole slab in; a partial slab's
-            # payload list starts at page ``sp``, so index relative
-            self._page_h2d(pages,
-                           slab["payload"][start - sp:start - sp + take])
-            imported = 0
-            node_path = list(path)
-            for j, page in enumerate(pages, start=start):
-                created = self.prefix_cache.insert(
-                    tokens, node_path, j, [page], ns=ns)
-                if not created:
-                    break
-                self.prefix_cache.release(created)
-                node_path.extend(created)
-                imported += 1
-            # partial import (an insert collision) keeps a valid prefix;
-            # any unpublished written pages simply return to the pool
-            self._free_pages.extend(pages[imported:])
+            imported = self.kv.import_slab(slab)
             if imported:
                 self._slab_imports += 1
                 self._import_pages += imported
-                if sp > 0:
+                if int(slab.get("start_page", 0)) > 0:
                     self._partial_slab_imports += 1
             return imported
 
@@ -3162,13 +2093,6 @@ class ServingEngine:
                 return []
             return [(t, ns) for t, ns, _ in self.prefix_cache
                     .cached_paths()]
-
-    def export_prefix_path(self, tokens, ns) -> Optional[Dict]:
-        """One evacuation slab: a cached_prefix_manifest() entry
-        re-exported verbatim under its original namespace. None when the
-        path's pages were evicted since the manifest walk — the entry
-        simply drops out of the evacuation."""
-        return self._export_slab_ns(tokens, ns)
 
     def warm_page_import(self, prompt) -> bool:
         """Compile and run the shared page-import writer once (H2D tier
@@ -3188,7 +2112,7 @@ class ServingEngine:
             slab = self.export_prefix_slab(prompt)
             if slab is None:
                 return False
-            self._free_pages.extend(self.prefix_cache.forget(prompt))
+            self.kv.forget(prompt)
             return self.import_prefix_slab(slab) > 0
 
     def warmup(self, prompts, max_new_tokens: int = 4) -> Dict:
@@ -3318,14 +2242,14 @@ class ServingEngine:
             # per-slot draw counters: the next token's index is exactly
             # the count already emitted — slot- and replica-invariant,
             # so a failover replay reproduces the stream
-            args = (self.gen._params(), self.model.bn_state, self.pool,
+            args = (self.gen._params(), self.model.bn_state, self.kv.pool,
                     self.page_tables, self.last_tok, write_pos, rope_pos,
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read):
-            toks, oks, self.pool, *routed = self._compiled_call(
+            toks, oks, self.kv.pool, *routed = self._compiled_call(
                 ("decode", k), lambda: self._build_decode(k), *args)
         with self._span("token_fetch"):
             # ONE device_get: the copies back start together, so neither
@@ -3398,11 +2322,11 @@ class ServingEngine:
             np.any(self.temps[self.active] > 0.0))
         # verify-slab frontier (the draft's decode mirrors the same pages)
         self._note_pages_touched((write_pos + k)[:, None], budget)
-        d_toks, d_probs, self.draft_pool = self._compiled_call(
+        d_toks, d_probs, self.kv.draft_pool = self._compiled_call(
             ("draft_propose", k),
             lambda: self._build_draft_propose(k),
             self.draft_gen._params(), self.draft_model.bn_state,
-            self.draft_pool, self.page_tables, self.last_tok, write_pos,
+            self.kv.draft_pool, self.page_tables, self.last_tok, write_pos,
             rope_pos, self.row_len, self.prompt_pad, budget,
             self.temps, self.top_ps, self.top_ks, self.seeds, ctr0)
         with self._span("draft_fetch"):
@@ -3418,9 +2342,9 @@ class ServingEngine:
         pos = np.minimum(
             write_pos[:, None] + np.arange(k + 1, dtype=np.int32)[None, :],
             (budget - 1)[:, None]).astype(np.int32)
-        t_toks, t_probs, t_oks, self.pool = self._compiled_call(
+        t_toks, t_probs, t_oks, self.kv.pool = self._compiled_call(
             ("verify", k), lambda: self._build_verify(k),
-            self.gen._params(), self.model.bn_state, self.pool,
+            self.gen._params(), self.model.bn_state, self.kv.pool,
             self.page_tables, slab, pos, rope_pos, self.row_len,
             self.prompt_pad, self.poison,
             self.temps, self.top_ps, self.top_ks,
@@ -3780,9 +2704,7 @@ class ServingEngine:
         if self.prefix_cache is None:
             return 0
         with self._lock:
-            freed = self.prefix_cache.evict(self.num_pages, pressure=False)
-            self._free_pages.extend(freed)
-            return len(freed)
+            return self.kv.flush()
 
     def stats(self) -> Dict:
         with self._lock:
@@ -3841,7 +2763,7 @@ class ServingEngine:
             "moe_experts_hit": self._moe_experts_hit,
             "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
             "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
-            "free_pages": len(self._free_pages),
+            "free_pages": self.kv.free_pages,
             "kv_pages": self.num_pages,
             "kv_page_size": self.page_size,
             "serve_slots": self.slots,
@@ -3869,7 +2791,7 @@ class ServingEngine:
             # + cached), cached the pages the radix trie holds (warm,
             # reclaimable at refcount 0), shared those mounted by >1
             # live request right now
-            "pages_in_use": self.num_pages - 1 - len(self._free_pages),
+            "pages_in_use": self.num_pages - 1 - self.kv.free_pages,
             "kv_pages_cached": pc.pages if pc else 0,
             "kv_pages_shared": pc.shared_pages() if pc else 0,
             # tiered-cache observability (ISSUE 12): pages by tier (hbm

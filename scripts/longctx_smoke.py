@@ -152,9 +152,9 @@ def seq_parallel_phase(ff):
     assert len(rpath) == len(dpath) == 6
     for op in ref.gen.attn_ops:
         for plane in ("k", "v"):
-            want = np.stack([np.asarray(ref.pool[op.name][plane][n.page])
+            want = np.stack([np.asarray(ref.kv.pool[op.name][plane][n.page])
                              for n in rpath])
-            got = np.stack([np.asarray(dec.pool[op.name][plane][n.page])
+            got = np.stack([np.asarray(dec.kv.pool[op.name][plane][n.page])
                             for n in dpath])
             assert (want == got).all(), \
                 f"sharded merge diverged at {op.name}/{plane}"

@@ -32,8 +32,8 @@ from flexflow_tpu.models.llama import llama_lm
 from flexflow_tpu.runtime import checkpoint, faultinject
 from flexflow_tpu.runtime.deploy import (RollingDeployer,
                                          WeightArtifactRegistry)
-from flexflow_tpu.runtime.serving import (DEFAULT_WEIGHT_VERSION,
-                                          RadixPrefixCache, version_ns)
+from flexflow_tpu.runtime.kv_pool import RadixPrefixCache
+from flexflow_tpu.runtime.serving import DEFAULT_WEIGHT_VERSION, version_ns
 
 VOCAB = 61
 

@@ -160,7 +160,6 @@ def test_dispatch_skips_saturated_prefill_tier(ff):
 # ---- engine-level handoff primitives --------------------------------------
 
 
-@pytest.mark.slow  # ~20 s; the disagg CI tier runs the full file
 def test_slab_roundtrip_bitwise_and_token_identity(ff):
     """prefill_into_cache -> export -> import on a second engine: the
     imported pages are BITWISE the donor's, the subsequent submit admits
@@ -198,11 +197,11 @@ def test_slab_roundtrip_bitwise_and_token_identity(ff):
     for op in donor.gen.attn_ops:
         for dn, im in zip(donor_path, imp_path):
             np.testing.assert_array_equal(
-                np.asarray(donor.pool[op.name]["k"][dn.page]),
-                np.asarray(imp.pool[op.name]["k"][im.page]))
+                np.asarray(donor.kv.pool[op.name]["k"][dn.page]),
+                np.asarray(imp.kv.pool[op.name]["k"][im.page]))
             np.testing.assert_array_equal(
-                np.asarray(donor.pool[op.name]["v"][dn.page]),
-                np.asarray(imp.pool[op.name]["v"][im.page]))
+                np.asarray(donor.kv.pool[op.name]["v"][dn.page]),
+                np.asarray(imp.kv.pool[op.name]["v"][im.page]))
     # a second import of the same slab is a no-op (chunks cached)
     assert imp.import_prefix_slab(slab) == 0
     got = [list(r.tokens) for r in imp.run(prompts, max_new_tokens=6)]
@@ -239,14 +238,13 @@ def test_quantized_slab_handoff_is_bitwise(ff):
     op = donor.gen.attn_ops[0]
     for dn, im in zip(dpath, ipath):
         np.testing.assert_array_equal(
-            np.asarray(donor.pool[op.name]["k"][dn.page]),
-            np.asarray(imp.pool[op.name]["k"][im.page]))
+            np.asarray(donor.kv.pool[op.name]["k"][dn.page]),
+            np.asarray(imp.kv.pool[op.name]["k"][im.page]))
         np.testing.assert_array_equal(
-            np.asarray(donor.pool[op.name]["k_scale"][dn.page]),
-            np.asarray(imp.pool[op.name]["k_scale"][im.page]))
+            np.asarray(donor.kv.pool[op.name]["k_scale"][dn.page]),
+            np.asarray(imp.kv.pool[op.name]["k_scale"][im.page]))
 
 
-@pytest.mark.slow  # ~20 s; disagg CI tier runs the full file
 def test_import_refuses_dtype_mismatch_and_host_tail(ff):
     """Two slab-import guards: (a) a payload whose storage dtype does
     not match the importer's pool is rejected loudly (import_page casts
@@ -275,7 +273,7 @@ def test_import_refuses_dtype_mismatch_and_host_tail(ff):
                                  max_seq_len=48, host_kv_pages=32)
     assert imp.import_prefix_slab(slab_short) == 2
     with imp._lock:
-        imp._free_pages.extend(imp.prefix_cache.evict(2))
+        imp.kv.make_room(imp.kv.free_pages + 2)
     assert imp.stats()["kv_pages_host"] == 2
     assert imp.import_prefix_slab(slab_long) == 0, \
         "import below a host-resident tail must refuse"
@@ -362,7 +360,6 @@ def test_prefill_replica_crash_cold_path_fallback(ff, monkeypatch):
 # ---- tiered cache, engine-integrated --------------------------------------
 
 
-@pytest.mark.slow  # ~30 s; disagg CI tier runs the full file
 def test_tiered_cache_outhits_untired_and_stays_identical(ff):
     """Working set ~3x the pool: the tiered engine demotes instead of
     dying and promotes on re-match — hit where the untiered engine goes
@@ -396,7 +393,6 @@ def test_tiered_cache_outhits_untired_and_stays_identical(ff):
         "drain must quiesce the ordered publisher"
 
 
-@pytest.mark.slow  # ~25 s; disagg CI tier runs the full file
 def test_tier_faults_fall_back_token_identical(ff, monkeypatch):
     rs = np.random.RandomState(33)
     prompts = [rs.randint(1, VOCAB, (9,)).astype(np.int32)

@@ -135,7 +135,6 @@ def test_longctx_stats_keys_pinned(ff):
 # ---- token identity -------------------------------------------------------
 
 
-@pytest.mark.slow  # ~20 s; longctx CI tier runs the full file
 def test_interleaved_prefill_token_identical(ff):
     """Interleaved admission vs run-to-completion, greedy and sampled,
     more requests than slots so mid-prefill slots coexist with live

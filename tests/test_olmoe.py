@@ -213,7 +213,7 @@ def test_dense_model_programs_carry_no_routing_output():
     assert eng.gen.dropless_moe_ops == []
     eng.run([np.arange(1, 6, dtype=np.int32)], max_new_tokens=3)
     out = jax.eval_shape(
-        eng._build_decode(2), eng.gen._params(), dense.bn_state, eng.pool,
+        eng._build_decode(2), eng.gen._params(), dense.bn_state, eng.kv.pool,
         eng.page_tables, eng.last_tok, *eng._slot_decode_state()[:2],
         eng.row_len, eng.prompt_pad, eng._slot_decode_state()[2],
         eng.poison, eng.temps, eng.top_ps, eng.top_ks, eng.seeds,

@@ -360,7 +360,7 @@ def test_cow_isolation_quantized(ff):
     assert len(shared) >= 2
     shared = np.asarray(shared, np.int32)
     names = ("k", "v", "k_scale", "v_scale")
-    before = {op.name: {n: np.asarray(eng.pool[op.name][n][shared]).copy()
+    before = {op.name: {n: np.asarray(eng.kv.pool[op.name][n][shared]).copy()
                         for n in names}
               for op in eng.gen.attn_ops}
     reqs = eng.run(prompts[1:], max_new_tokens=4)
@@ -370,7 +370,7 @@ def test_cow_isolation_quantized(ff):
         for n in names:
             np.testing.assert_array_equal(
                 before[op.name][n],
-                np.asarray(eng.pool[op.name][n][shared]),
+                np.asarray(eng.kv.pool[op.name][n][shared]),
                 err_msg=f"shared quantized page of {op.name}/{n} was "
                         f"written in place (COW violated)")
     st = eng.stats()
@@ -503,5 +503,5 @@ def test_bf16_pool_serves(ff):
                     for n in (5, 9, 3)], max_new_tokens=5)
     assert [r.state for r in reqs] == ["done"] * 3
     for op in eng.gen.attn_ops:
-        assert eng.pool[op.name]["k"].dtype == jnp.bfloat16
-        assert "k_scale" not in eng.pool[op.name]
+        assert eng.kv.pool[op.name]["k"].dtype == jnp.bfloat16
+        assert "k_scale" not in eng.kv.pool[op.name]

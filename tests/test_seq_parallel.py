@@ -62,7 +62,7 @@ def _prefix_pages(eng, prompt, n_pages):
     assert len(path) == n_pages, "prefix not fully cached"
     out = {}
     for op in eng.gen.attn_ops:
-        pool = eng.pool[op.name]
+        pool = eng.kv.pool[op.name]
         for plane in pool:
             out[(op.name, plane)] = np.stack(
                 [np.asarray(pool[plane][nd.page]) for nd in path])
@@ -160,7 +160,6 @@ def test_sharded_merge_bitwise_int8(ff, shards):
             f"int8 {shards}-shard merge diverged at {key}"
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
 def test_gapped_shard_slab_refused(ff):
     """Shard 1's slab arriving before shard 0 has merged must be
     refused outright: publishing pages past a gap would cache a prefix
@@ -184,7 +183,6 @@ def test_gapped_shard_slab_refused(ff):
     assert dec.stats()["partial_slab_imports"] == 1
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
 def test_partial_export_bounds_validated(ff):
     prompt = _prompt(53, 24)
     eng = _engine(ff)

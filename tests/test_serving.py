@@ -225,7 +225,6 @@ def test_poisoned_request_retired_without_stalling(ff, monkeypatch):
     assert eng.stats()["free_pages"] == st["kv_pages"] - 1
 
 
-@pytest.mark.slow  # 7 s; serving CI tier runs the full file
 def test_page_pool_pressure_blocks_admission_not_progress(ff):
     """A pool too small for all slots at once: admission waits for
     retirements instead of deadlocking, and every request still finishes
@@ -300,7 +299,7 @@ def test_decode_chunk_invariance(ff):
 
 
 def _trie(ps=4):
-    from flexflow_tpu.runtime.serving import RadixPrefixCache
+    from flexflow_tpu.runtime.kv_pool import RadixPrefixCache
 
     return RadixPrefixCache(ps)
 
@@ -368,7 +367,6 @@ def test_radix_trie_refcounts_and_eviction():
 # ---- radix prefix cache: engine semantics --------------------------------
 
 
-@pytest.mark.slow  # 20 s; serving CI tier runs the full file
 def test_prefix_cache_token_identical_to_cold(ff):
     """Skewed shared-prefix traffic: requests sharing a system prompt hit
     the cache (prefill only the tail) yet emit exactly the tokens a
@@ -404,7 +402,6 @@ def test_prefix_cache_token_identical_to_cold(ff):
     assert ws["free_pages"] + ws["kv_pages_cached"] == ws["kv_pages"] - 1
 
 
-@pytest.mark.slow  # 15 s; serving CI tier runs the full file
 def test_prefix_cow_isolation(ff):
     """Copy-on-write: concurrent requests mounting the same cached prefix
     write their divergent tails and decode tokens into their OWN pages —
@@ -426,7 +423,7 @@ def test_prefix_cow_isolation(ff):
         shared.append(node.page)
     assert len(shared) >= 2                      # the 2 system pages
     shared = np.asarray(shared, np.int32)
-    before = {op.name: {n: np.asarray(eng.pool[op.name][n][shared])
+    before = {op.name: {n: np.asarray(eng.kv.pool[op.name][n][shared])
                         for n in ("k", "v")}
               for op in eng.gen.attn_ops}
 
@@ -436,7 +433,7 @@ def test_prefix_cow_isolation(ff):
         solo = ff.generate(r.prompt[None, :], max_new_tokens=4)
         np.testing.assert_array_equal(np.asarray(r.tokens, np.int32),
                                       solo[0, r.prompt.size:])
-    after = {op.name: {n: np.asarray(eng.pool[op.name][n][shared])
+    after = {op.name: {n: np.asarray(eng.kv.pool[op.name][n][shared])
                        for n in ("k", "v")}
              for op in eng.gen.attn_ops}
     for name, kv in before.items():
@@ -447,7 +444,6 @@ def test_prefix_cow_isolation(ff):
                         f"(copy-on-write violated)")
 
 
-@pytest.mark.slow  # 12 s; serving CI tier runs the full file
 def test_prefix_evict_under_pressure(ff):
     """A pool sized for exactly one max request: cached pages from
     retired traffic are reclaimed (LRU) when admission needs them, and
@@ -470,7 +466,6 @@ def test_prefix_evict_under_pressure(ff):
     assert st["prefix_refs_live"] == 0
 
 
-@pytest.mark.slow  # 10 s; serving CI tier runs the full file
 def test_prefix_refcounts_clean_after_drain(ff):
     """drain() with slots mid-flight: every trie refcount drops to zero,
     pages are either free or cached, and flush_prefix_cache() returns the
@@ -495,7 +490,6 @@ def test_prefix_refcounts_clean_after_drain(ff):
     assert eng.stats()["free_pages"] == st["kv_pages"] - 1
 
 
-@pytest.mark.slow  # 14 s; serving CI tier runs the full file
 def test_pool_exhaustion_flood_tiny_pool(ff):
     """Regression (satellite): flooding a tiny pool must never fail a
     request — admission leaves what doesn't fit in the queue and run()
@@ -704,7 +698,7 @@ def test_engine_deadline_expires_in_queue_without_dispatch(ff):
     dead = eng.submit(np.arange(1, 6, dtype=np.int32), 4, deadline=now)
     live = eng.submit(np.arange(1, 7, dtype=np.int32), 4,
                       deadline=now + 3600.0)
-    free0 = len(eng._free_pages)
+    free0 = eng.kv.free_pages
     eng._expire_queued()   # what _admit runs first, without the prefill
     assert dead.state == "timeout" and "deadline" in dead.error
     assert dead.tokens == [] and dead.t_done > 0
@@ -712,7 +706,7 @@ def test_engine_deadline_expires_in_queue_without_dispatch(ff):
     st = eng.stats()
     assert st["timeouts"] == 1 and st["requests"] == 2
     assert eng.recompile_count == 0, "expired work must never compile"
-    assert len(eng._free_pages) == free0, "expired work must hold no pages"
+    assert eng.kv.free_pages == free0, "expired work must hold no pages"
     assert "timeouts" in eng.health()
     # load() is the router's lock-free dispatch signal
     assert eng.load() == {"active_slots": 0, "queued": 1}

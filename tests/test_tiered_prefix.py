@@ -1,7 +1,7 @@
 """Tiered (HBM -> host) prefix cache: the tier state machine alone.
 
 Sub-second pure-host unit tests (ISSUE 12 satellite) for
-runtime/serving.py RadixPrefixCache's host tier — no engine, no device,
+runtime/kv_pool.py RadixPrefixCache's host tier — no engine, no device,
 no compiles: the D2H/H2D callables are injected fakes, so demote/promote
 ordering under the ordered publisher, the cross-tier refcount rules, the
 host-tier LRU and the abandoned-migration generation check are all
@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.runtime import faultinject
-from flexflow_tpu.runtime.serving import RadixPrefixCache
+from flexflow_tpu.runtime.kv_pool import RadixPrefixCache
 
 PS = 2  # page size: tiny, so prompts stay readable
 
