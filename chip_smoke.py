@@ -340,6 +340,50 @@ def kernel_cases(z: Sizes):
             # (~2e-2 here), one fp8-e4m3 step 2^-3 of the value
             {"bf16": 0.0, "int8": BF16_TOL, "fp8": 0.13}[label]))
 
+    # the expert-stream kernel as a decode step (32 rows) and the largest
+    # prefill bucket it takes (128) call it: 8 SwiGLU experts of width
+    # hidden / 2 (OLMoE's ratio), top-2, experts 6 and 7 hit by nobody; at
+    # the real size also a hidden size between the multiples of 1024 whose
+    # experts stream in two chunks of their width
+    def stream_args(n, d, f, experts=8, k=2):
+        def make(rs):
+            gates = np.full((n, experts), pk.MOE_NOT_CHOSEN, np.float32)
+            for r in range(n):
+                gates[r, rs.choice(experts - 2, k, replace=False)] = \
+                    rs.rand(k)
+            sizes = (gates != pk.MOE_NOT_CHOSEN).sum(0).astype(np.int32)
+            w = [jnp.asarray(rs.randn(experts, a, b) / math.sqrt(a), bf16)
+                 for a, b in ((d, f), (d, f), (f, d))]
+            return (jnp.asarray(rs.randn(n, d), bf16),
+                    jnp.asarray(gates), jnp.asarray(sizes), *w)
+        return make
+
+    def stream_oracle(x, gates, sizes, wg, wu, wd):
+        xf = x.astype(jnp.float32)
+        y = jnp.zeros(xf.shape, jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            for e in range(wg.shape[0]):
+                hid = jax.nn.silu(xf @ wg[e].astype(jnp.float32)) \
+                    * (xf @ wu[e].astype(jnp.float32))
+                col = gates[:, e:e + 1]
+                y += jnp.where(col != pk.MOE_NOT_CHOSEN,
+                               col * (hid @ wd[e].astype(jnp.float32)), 0.0)
+        return y
+
+    stream_shapes = [(rows, z.hidden, max(128, z.hidden // 2))
+                     for rows in (32, 128)]
+    if z is FULL:
+        stream_shapes.append((32, 2560, 1024))
+    for rows, dim, width in stream_shapes:
+        cases.append(KernelCase(
+            f"moe_expert_stream n{rows} e8 k2 d{dim} f{width} bf16 chunk"
+            f"{pk.moe_stream_chunk(dim, width, bf16)}",
+            stream_args(rows, dim, width),
+            pk.moe_expert_stream_pallas, stream_oracle,
+            # h rounds to bf16 before the down projection (2^-8 of
+            # values of order 1), outputs of order 1
+            BF16_TOL))
+
     # fused add+layernorm at the model's hidden, forward (inference) and
     # with the backward's saved statistics; plus the 4096 x 4096 width the
     # README's encoder runs, which the row-block budget must fit

@@ -225,8 +225,8 @@ class FFModel:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
         aux loss is folded into the training loss automatically.
-        capacity_factor=None is the dropless op (one grouped-matmul
-        lowering, no dropped token; `dispatch` is not read). Otherwise
+        capacity_factor=None is the dropless op (no dropped token; lowered
+        by the call's static shape, ops/moe.py; no `dispatch`). Otherwise
         dispatch: "auto" (dense einsums when experts are mesh-sharded, else
         sort-based) | "dense" | "sort". expert: "gelu" (w_in, w_out) |
         "swiglu" (w_gate, w_up, w_down); renormalize: kept gates rescaled

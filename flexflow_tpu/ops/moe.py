@@ -18,13 +18,25 @@ surfaced through the op-aux mechanism so the executor folds it into the
 training loss.
 
 `capacity_factor=None` is the DROPLESS op (OLMoE, Mixtral and every served
-expert model: no token is ever dropped). It has one lowering, shared by
-fit(), predict, prefill and decode: the N*k (token, expert) assignments
-are stable-sorted by expert and the experts run as grouped matmuls
-(`jax.lax.ragged_dot`, on the TPU XLA's own Mosaic grouped-matmul kernel)
-over exactly N*k rows, so work and expert-weight traffic follow the
-routing instead of a capacity buffer. It reads no `dispatch` and no
-`capacity`.
+expert model: no token is ever dropped). It reads no `dispatch` and no
+`capacity`. One routing (top-k of the f32 softmax, the same counts and aux
+value) feeds two lowerings of the experts, chosen by `dropless_lowering`
+from static facts of the call alone, never by an option:
+
+  * grouped: the N*k (token, expert) assignments are stable-sorted by
+    expert and the experts run as grouped matmuls (`jax.lax.ragged_dot`,
+    on the TPU XLA's own Mosaic grouped-matmul kernel) over exactly N*k
+    rows, so work and expert-weight traffic follow the routing instead of
+    a capacity buffer. fit() (it has the gradient), predict and every call
+    of more than MOE_STREAM_MAX_ROWS tokens run it, on any backend.
+  * streamed: a call of few tokens (a decode step, a short prefill bucket)
+    of SwiGLU experts on one TPU chip, outside training. There XLA's kernel
+    walks each group in 256-row tiles of which about four rows are live and
+    is MXU-bound at 61 % of the bandwidth the weights need; the Pallas
+    kernel `moe_expert_stream_pallas` keeps the N rows in VMEM, multiplies
+    all of them by each HIT expert's three matrices as they stream past
+    once, and picks each row's own experts by select on an (N, E) gate
+    matrix: no sort, no gather into expert order, no scatter back.
 
 The architecture is described by constructor arguments, none of them a
 performance selector: `expert` ("gelu": two matrices w_in/w_out as above;
@@ -48,6 +60,32 @@ from flexflow_tpu.ops.base import Op, WeightSpec
 def _buffer_mm(x, w):
     """(E, C, in) capacity buffer x (E, in, out) expert matrices."""
     return jnp.einsum("eci,eio->eco", x, w)
+
+
+def dropless_lowering(backend: str, expert: str, training: bool,
+                      devices: int, n_tokens: int, dim: int,
+                      hidden_dim: int, dtype) -> str:
+    """'streamed' or 'grouped': which lowering a dropless call takes, from
+    static facts of the call alone. Streamed needs the Pallas kernel's
+    preconditions: a TPU (Mosaic; interpret mode is for tests), SwiGLU
+    experts (the kernel's one arithmetic), no gradient (it has no VJP:
+    fit() keeps `ragged_dot`), a program on ONE device (over a mesh GSPMD
+    owns the op: an 'expert' axis shards the matrices, a 'data' axis the
+    rows), at most MOE_STREAM_MAX_ROWS tokens (up to the MXU tile's height
+    every row rides along with every hit expert for free; beyond it that
+    trade loses to sorted groups), and sizes Mosaic can tile."""
+    from flexflow_tpu.ops.pallas_kernels import (MOE_STREAM_MAX_ROWS,
+                                                 moe_stream_chunk)
+
+    if (backend == "tpu" and expert == "swiglu" and not training
+            and devices == 1 and n_tokens <= MOE_STREAM_MAX_ROWS
+            and moe_stream_chunk(dim, hidden_dim, dtype) is not None):
+        return "streamed"
+    return "grouped"
+
+
+def _backend() -> str:
+    return jax.default_backend()
 
 
 class MoE(Op):
@@ -120,13 +158,16 @@ class MoE(Op):
         return not ep
 
     def forward(self, params, xs, *, training=False, rng=None,
-                capacity=None, row_mask=None, routing=None):
+                capacity=None, row_mask=None, routing=None,
+                lowerings=None):
         """Dropless op: `row_mask` (bool, the shape of x without its last
         dim; None = every row live) gives masked rows group size 0 and
         output 0, so the free slots of a decode batch stream no expert;
         `routing`, if a list, receives this call's int32 (2,) counts
         [assignments, experts hit] (traced values: the serving engine
-        sums them inside its programs).
+        sums them inside its programs); `lowerings`, if a list, receives
+        'streamed' or 'grouped', the lowering this call took (a static
+        fact, known while tracing).
 
         Capacity op: `capacity` overrides the build-time training capacity. The
         inference path (runtime/generation.py) passes N (the slab's token
@@ -147,7 +188,8 @@ class MoE(Op):
 
         if self.dropless:
             return self._forward_dropless(params, t, gates, orig_shape,
-                                          row_mask, routing)
+                                          row_mask, routing, training,
+                                          lowerings)
         if self._use_sort_dispatch():
             return self._forward_sort(params, t, gates, orig_shape,
                                       capacity=C)
@@ -233,20 +275,51 @@ class MoE(Op):
         aux = self.aux_weight * E * jnp.sum(me * (ce / k))
         return [y.reshape(orig_shape), aux.astype(jnp.float32)]
 
+    def lowering(self, n_tokens: int, training: bool, dtype) -> str:
+        """The dropless lowering a call of `n_tokens` rows takes here
+        (`dropless_lowering` on this op, this process and this mesh)."""
+        mesh = getattr(self.model, "mesh", None)
+        return dropless_lowering(
+            _backend(), self.expert, training,
+            1 if mesh is None else mesh.size, n_tokens, self.dim,
+            self.hidden_dim, dtype)
+
     def _forward_dropless(self, params, t, gates, orig_shape, row_mask,
-                          routing):
+                          routing, training, lowerings):
         """No capacity, no dropped token: top-k of the f32 softmax, the
-        N*k assignments stable-sorted by expert, the experts as grouped
-        matmuls over exactly those rows, each token's k results weighted
-        by its gates and summed. A row's output depends on no other row."""
+        experts over exactly the chosen (token, expert) pairs (grouped or
+        streamed, see the module docstring), each token's k results
+        weighted by its gates and summed. A row's output depends on no
+        other row."""
         E, k = self.num_experts, self.k
-        N, D = t.shape
+        N = t.shape[0]
         top_g, top_e = jax.lax.top_k(gates, k)              # (N, k)
         if self.renormalize:
             top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+        live = None if row_mask is None else row_mask.reshape(N)
+        took = self.lowering(N, training, t.dtype)
+        if lowerings is not None:
+            lowerings.append(took)
+        experts = (self._experts_streamed if took == "streamed"
+                   else self._experts_grouped)
+        y, sizes = experts(params, t, top_g, top_e, live)
+        if routing is not None:
+            routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
+                           .astype(jnp.int32))
+        me = jnp.mean(gates, axis=0)
+        ce = sizes.astype(jnp.float32) / N
+        aux = self.aux_weight * E * jnp.sum(me * (ce / k))
+        return [y.astype(t.dtype).reshape(orig_shape),
+                aux.astype(jnp.float32)]
+
+    def _experts_grouped(self, params, t, top_g, top_e, live):
+        """(y (N, D) f32, sizes (E,) int32): the N*k assignments
+        stable-sorted by expert, grouped matmuls over exactly those rows,
+        unsorted, gate-weighted and summed per token."""
+        E, k = self.num_experts, self.k
+        N, D = t.shape
         flat_e = top_e.reshape(-1)                          # token-major
-        if row_mask is not None:
-            live = row_mask.reshape(N)
+        if live is not None:
             # a dead row's k assignments go to no expert: the id E sorts
             # behind every group and bincount drops it
             flat_e = jnp.where(jnp.repeat(live, k), flat_e, E)
@@ -259,21 +332,35 @@ class MoE(Op):
         rows = t[order // k]                                # (N*k, D)
         out = self._expert_ffn(
             params, rows, lambda x, w: jax.lax.ragged_dot(x, w, sizes))
-        if row_mask is not None:
+        if live is not None:
             # rows past the last group belong to no expert; what a grouped
             # matmul leaves there is unspecified
             out = jnp.where((jnp.arange(N * k) < jnp.sum(sizes))[:, None],
                             out, 0)
         y = jnp.einsum("nk,nkd->nd", top_g,
                        out[back].reshape(N, k, D).astype(jnp.float32))
-        if routing is not None:
-            routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
-                           .astype(jnp.int32))
-        me = jnp.mean(gates, axis=0)
-        ce = sizes.astype(jnp.float32) / N
-        aux = self.aux_weight * E * jnp.sum(me * (ce / k))
-        return [y.astype(t.dtype).reshape(orig_shape),
-                aux.astype(jnp.float32)]
+        return y, sizes
+
+    def _experts_streamed(self, params, t, top_g, top_e, live):
+        """(y (N, D), sizes (E,) int32) through the expert-stream kernel:
+        the (N, E) gate matrix (a row's gate where it chose the expert,
+        MOE_NOT_CHOSEN elsewhere and in every dead row) and the rows per
+        expert are all the routing state there is. `sizes` counts what
+        `bincount` counts in the grouped lowering, and a token never picks
+        an expert twice, so the max over its k picks is the one gate."""
+        from flexflow_tpu.ops.pallas_kernels import (
+            MOE_NOT_CHOSEN, moe_expert_stream_pallas)
+
+        picked = top_e[:, :, None] == jnp.arange(self.num_experts)
+        if live is not None:                                # (N, k, E)
+            picked &= live[:, None, None]
+        gmat = jnp.max(jnp.where(picked, top_g[:, :, None], MOE_NOT_CHOSEN),
+                       axis=1)
+        sizes = jnp.sum(picked, axis=(0, 1), dtype=jnp.int32)
+        y = moe_expert_stream_pallas(
+            t, gmat, sizes, *(params[n].astype(t.dtype)
+                              for n in ("w_gate", "w_up", "w_down")))
+        return y, sizes
 
     def partitionable_output_dims(self):
         return list(range(self.outputs[0].num_dims - 1))

@@ -994,3 +994,209 @@ def paged_prefill_write_pallas(cache, kh, vh, pages):
     if quantized:
         out["k_scale"], out["v_scale"] = outs[2][:, 0], outs[3][:, 0]
     return out
+
+
+# ------------------------------------------------------ MoE expert stream
+#
+# The dropless SwiGLU experts of a call that holds FEW tokens (a decode
+# step, a short prefill bucket: ops/moe.py picks it from the call's static
+# shape). At a few rows per expert the work is streaming each hit expert's
+# three matrices out of HBM once; XLA's grouped matmul (`ragged_dot`) pushes
+# a 256-row tile through the MXU for every weight tile although about four
+# rows are live and ends MXU-bound at 61 % of the bandwidth roof (PERF.md
+# section 6, PR 28). Here:
+#
+#   * The call's N <= 128 token rows stay resident in VMEM and ALL of them
+#     are multiplied by every hit expert: up to the MXU tile's height a
+#     weight tile costs its load whatever the rows, so nothing is sorted,
+#     gathered or scattered and no (N*k, D) intermediate exists in HBM. A
+#     row that did not choose the expert is dropped by SELECT on the
+#     (N, E) gate matrix (MOE_NOT_CHOSEN marks "not chosen"), never by a
+#     zero gate: a non-finite value in one row's result cannot reach
+#     another row.
+#   * Only experts with at least one live row stream: the per-expert row
+#     counts arrive by scalar prefetch, the kernel lists the hit experts
+#     in SMEM, and the loop runs n_hit x (F / chunk) times. Each step
+#     takes one chunk of the expert's width F: w_gate[:, f] and
+#     w_up[:, f] (D x chunk) and w_down[f, :] (chunk x D), copied by hand
+#     into one of TWO VMEM buffers a matrix, one chunk ahead of the
+#     arithmetic (the copies of the next chunk are issued before the wait
+#     for this one, so the DMA queue never drains while the arithmetic of
+#     a chunk is shorter than its copy; the chip read a third buffer
+#     within 0.5 % of two, PERF.md section 6, PR 28).
+#   * h = silu(x @ w_gate[:, f]) * (x @ w_up[:, f]) never leaves VMEM; the
+#     gate-weighted sum over a token's experts accumulates in f32.
+
+# `gates` value of a (row, expert) pair the row did not choose; a gate is
+# a softmax output, so never negative
+MOE_NOT_CHOSEN = -1.0
+# token rows up to which every row can be multiplied by every hit expert
+# for free: the MXU tile's height
+MOE_STREAM_MAX_ROWS = 128
+# VMEM the two buffers of each of the three matrices may take together
+# (v5e: 128 MiB in all; the call raises its scoped limit to them plus its
+# resident blocks)
+_MOE_BUFFER_BUDGET = 24 << 20
+
+
+def moe_stream_chunk(d: int, f: int, dtype):
+    """Columns of the experts' width `f` that one step of the stream takes:
+    the widest chunk (F itself, or a multiple of the 128 lanes that divides
+    it) whose three matrices over hidden size `d` fit _MOE_BUFFER_BUDGET
+    twice. None where Mosaic could not tile the copies (D or F off the
+    lanes)."""
+    if d % LANES or f % LANES:
+        return None
+    one = 3 * d * jnp.dtype(dtype).itemsize     # bytes a column of F
+    for chunk in range(f, 0, -LANES):
+        if f % chunk == 0 and 2 * one * chunk <= _MOE_BUFFER_BUDGET:
+            return chunk
+    return None
+
+
+def _moe_stream_kernel(sizes_ref, x_ref, g_ref, wg_hbm, wu_hbm, wd_hbm,
+                       o_ref, gbuf, ubuf, dbuf, sem, acc_ref, ids_ref,
+                       *, nf: int, chunk: int):
+    """The whole call in one invocation. Scalar prefetch: sizes (E,), the
+    rows that chose each expert; the experts with any are listed in
+    ids_ref (SMEM) first. x (N, D) and the gate matrix g (N, E) f32 sit in
+    VMEM; the expert matrices stay in HBM. Chunk c of the stream is columns
+    [f0, f0 + chunk) of expert ids[c // nf] with f0 = (c % nf) * chunk, and
+    lives in buffer c % 2."""
+
+    def list_hit(e, n_hit):
+        @pl.when(sizes_ref[e] > 0)
+        def _():
+            ids_ref[n_hit] = e
+        return n_hit + (sizes_ref[e] > 0).astype(jnp.int32)
+
+    n_chunks = jax.lax.fori_loop(0, sizes_ref.shape[0], list_hit,
+                                 jnp.int32(0)) * nf
+    # plain lax on purpose: each jnp convenience (`%`, `//`, `where`,
+    # `silu`) is a nested jit of several equations to trace and lower
+    nf_, two = jnp.int32(nf), jnp.int32(2)
+
+    def expert(c):
+        return ids_ref[c] if nf == 1 else ids_ref[jax.lax.div(c, nf_)]
+
+    def copies(e, f0, slot):
+        if nf == 1:
+            srcs = (wg_hbm.at[e], wu_hbm.at[e], wd_hbm.at[e])
+        else:
+            srcs = (wg_hbm.at[e, :, pl.ds(f0, chunk)],
+                    wu_hbm.at[e, :, pl.ds(f0, chunk)],
+                    wd_hbm.at[e, pl.ds(f0, chunk), :])
+        return [pltpu.make_async_copy(src, buf.at[slot], sem.at[i, slot])
+                for i, (src, buf) in enumerate(zip(srcs,
+                                                   (gbuf, ubuf, dbuf)))]
+
+    def fetch(c):
+        @pl.when(c < n_chunks)
+        def _():
+            f0 = 0 if nf == 1 else pl.multiple_of(
+                jax.lax.rem(c, nf_) * chunk, chunk)
+            for cp in copies(expert(c), f0, jax.lax.rem(c, two)):
+                cp.start()
+
+    acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+    fetch(jnp.int32(0))
+    lane = jax.lax.broadcasted_iota(jnp.int32, g_ref.shape, 1)
+    no_gate = jnp.zeros(g_ref.shape, jnp.float32)
+    nothing = jnp.zeros(acc_ref.shape, jnp.float32)
+    mm = functools.partial(jax.lax.dot_general,
+                           dimension_numbers=(((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+    def one_chunk(c, carry):
+        fetch(c + 1)
+        slot = jax.lax.rem(c, two)
+        for cp in copies(0, 0, slot):     # a wait reads the size only
+            cp.wait()
+        x = x_ref[...]
+        gate = mm(x, gbuf[slot])
+        h = (gate * jax.lax.logistic(gate) * mm(x, ubuf[slot])
+             ).astype(x.dtype)
+        # this expert's column of the gate matrix, by select
+        ge = jnp.sum(jax.lax.select(lane == expert(c), g_ref[...], no_gate),
+                     axis=1, keepdims=True)                 # (N, 1)
+        chosen = jnp.broadcast_to(ge != MOE_NOT_CHOSEN, nothing.shape)
+        acc_ref[...] += jax.lax.select(chosen, ge * mm(h, dbuf[slot]),
+                                       nothing)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, one_chunk, 0)
+    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+# inline=True: traced once per shape and re-emitted under each caller's
+# named scope, so the ten layers of a program (and the programs of an engine)
+# share one trace and one Mosaic lowering of the kernel, and each call's
+# instruction is still named after its own `moe_<i>` scope. Without it a
+# 10-layer program traced and lowered the kernel ten times: +5 s of warm
+# set-up in moe-chat-steady (PERF.md section 6, PR 28)
+@functools.partial(jax.jit, inline=True)
+def moe_expert_stream_pallas(x, gates, sizes, w_gate, w_up, w_down):
+    """Dropless SwiGLU experts over a few token rows, each hit expert's
+    matrices streamed once: x (N, D), N <= MOE_STREAM_MAX_ROWS; gates
+    (N, E) f32, a row's gate for each expert it chose and MOE_NOT_CHOSEN
+    elsewhere (every entry of a dead row); sizes (E,) int32, the rows
+    that chose each expert (an expert with none is not read); w_gate,
+    w_up (E, D, F), w_down (E, F, D) in x's dtype -> (N, D) in x's dtype:
+    sum over a row's chosen experts of gate * (silu(x w_gate) * (x w_up))
+    w_down, the three products and the sum in f32. A row that chose no
+    expert comes out zero. Inference-only: no VJP."""
+    n, d = x.shape
+    e, _, f = w_gate.shape
+    chunk = moe_stream_chunk(d, f, x.dtype)
+    if n > MOE_STREAM_MAX_ROWS or chunk is None:
+        raise ValueError(
+            f"{n} rows of experts ({d}, {f}) {x.dtype}: at most "
+            f"{MOE_STREAM_MAX_ROWS} rows, D and F multiples of {LANES}")
+    # rows to the dtype's sublane tile (16 for bf16, 8 for f32)
+    itemsize = jnp.dtype(x.dtype).itemsize
+    tile = 8 * max(1, 4 // itemsize)
+    rows = -(-n // tile) * tile
+    if rows != n:
+        x = jnp.pad(x, ((0, rows - n), (0, 0)))
+        gates = jnp.pad(gates, ((0, rows - n), (0, 0)),
+                        constant_values=MOE_NOT_CHOSEN)
+    # what VMEM holds beside the weight buffers: x, the output and the gate
+    # matrix (E padded to the lanes), each twice (the pipeline double-
+    # buffers its blocks); the f32 sum; and a chunk's values (the two
+    # products and h over the chunk, the down product and its select over
+    # D, in f32)
+    resident = rows * (2 * (2 * d * itemsize + 4 * -(-e // LANES) * LANES)
+                       + 4 * d + 4 * (3 * chunk + 2 * d))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(1,),
+        in_specs=[
+            pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
+            pl.BlockSpec((rows, e), lambda i, *_: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, d, chunk), w_gate.dtype),
+            pltpu.VMEM((2, d, chunk), w_up.dtype),
+            pltpu.VMEM((2, chunk, d), w_down.dtype),
+            pltpu.SemaphoreType.DMA((3, 2)),
+            pltpu.VMEM((rows, d), jnp.float32),             # the sum
+            pltpu.SMEM((e,), jnp.int32),                    # hit experts
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_moe_stream_kernel, nf=f // chunk, chunk=chunk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the buffers, the resident blocks, room for Mosaic's own
+            vmem_limit_bytes=int(2 * 3 * d * chunk * itemsize + resident
+                                 + (8 << 20))),
+        interpret=_interpret(),
+    )(sizes.astype(jnp.int32), x, gates.astype(jnp.float32), w_gate, w_up,
+      w_down)
+    return out[:n]

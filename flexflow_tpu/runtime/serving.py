@@ -725,6 +725,13 @@ class ServingEngine:
         # only): assignments, experts with at least one row
         self._moe_assignments = 0
         self._moe_experts_hit = 0
+        # {program key: 'streamed' | 'grouped' of each dropless MoE call of
+        # the program, in trace order}: a static fact of the program
+        # (ops/moe.py `dropless_lowering`), collected by a list of the
+        # program's own that its builder hands to the walk; and the decode
+        # / prefill dispatches of programs whose calls all streamed
+        self._moe_took: Dict = {}
+        self._moe_streamed_dispatches = 0
 
         # ---- unified telemetry plane (ISSUE 13) ----
         # the engine's latency histograms (TTFT / inter-token / queue
@@ -1203,7 +1210,28 @@ class ServingEngine:
         Nothing for any other model, whose programs are unchanged."""
         return (sum(routing),) if routing else ()
 
-    def _build_prefill(self, bucket: int, n_pages: int):
+    def _moe_took_list(self, key):
+        """The list in which program `key` collects the lowering of each
+        of its dropless MoE calls as it is traced (its builder hands it to
+        the walks); None for a model without such an op."""
+        if not self.gen.dropless_moe_ops:
+            return None
+        took = self._moe_took[key] = []
+        return took
+
+    def _note_moe_lowering(self, key, span):
+        """A dispatch of an expert model's program `key` says on its span
+        whether the program's MoE calls all took the expert-stream kernel
+        (`moe_streamed` 1 or 0) and counts it; nothing for a program that
+        holds no dropless MoE op. Called after the dispatch: a program's
+        list fills as its first call traces it."""
+        took = self._moe_took.get(key)
+        if took:
+            streamed = int(all(t == "streamed" for t in took))
+            self._moe_streamed_dispatches += streamed
+            span.annotate(moe_streamed=streamed)
+
+    def _build_prefill(self, bucket: int, n_pages: int, took=None):
         gen = self.gen
         cdtype = gen._compute_dtype()
         has_lora = self.lora_pool is not None
@@ -1217,7 +1245,8 @@ class ServingEngine:
             routing = [] if gen.dropless_moe_ops else None
             logits, caches = gen._prefill(params, state, tokens, caches,
                                           length, self.prefill_chunk,
-                                          lora=lora, routing=routing)
+                                          lora=lora, routing=routing,
+                                          lowerings=took)
             logits = logits[:, -1] + poison            # (1, V)
             ok = jnp.isfinite(logits).all(axis=-1)
             # the request's first emitted token is TARGET-stream draw 0
@@ -1229,7 +1258,7 @@ class ServingEngine:
 
         return jax.jit(prefill, donate_argnums=(4,))
 
-    def _build_prefill_hit(self, bucket: int, full: int):
+    def _build_prefill_hit(self, bucket: int, full: int, took=None):
         """Prefix-hit prefill: ``full`` cached pages are gathered
         READ-ONLY into the front of a contiguous per-request cache, the
         tail slab [full*ps, bucket) runs as one chunk_forward pass (each
@@ -1256,12 +1285,12 @@ class ServingEngine:
             _, caches = gen._walk(params, state, tokens_tail, caches,
                                   None, chunk_start=p0, skip_tail=True,
                                   lora=lora, row_lengths=length,
-                                  routing=routing)
+                                  routing=routing, lowerings=took)
             logits, caches = gen._walk(params, state, tok_last, caches,
                                        None, last_only=True,
                                        row_lengths=length,
                                        gather_last=True, lora=lora,
-                                       routing=routing)
+                                       routing=routing, lowerings=took)
             logits = logits[:, -1] + poison            # (1, V)
             ok = jnp.isfinite(logits).all(axis=-1)
             tok = sampling_ops.sample_tokens(
@@ -1425,7 +1454,7 @@ class ServingEngine:
 
         return jax.jit(decode_verify, donate_argnums=(2,))
 
-    def _build_decode(self, n_steps: int):
+    def _build_decode(self, n_steps: int, took=None):
         gen = self.gen
         has_lora = self.lora_pool is not None
 
@@ -1457,7 +1486,8 @@ class ServingEngine:
                 routing = [] if gen.dropless_moe_ops else None
                 logits, pool = gen._walk(params, state, tok[:, None],
                                          pool, None, paged=paged,
-                                         lora=lora, routing=routing)
+                                         lora=lora, routing=routing,
+                                         lowerings=took)
                 logits = logits[:, 0] + poison[:, None]  # (B_slots, V)
                 ok = jnp.isfinite(logits).all(axis=-1)
                 nxt = sampling_ops.sample_tokens(
@@ -1617,10 +1647,11 @@ class ServingEngine:
         return version_ns(self.weight_version, adapter)
 
     def _run_prefill(self, prompt, bucket: int, lease, sampling,
-                     adapter_page: int, poison):
+                     adapter_page: int, poison, span=telemetry.NULL_SPAN):
         """Dispatch one run-to-completion prefill of ``prompt`` into
         ``lease``'s pages, target then draft; returns the device values
-        ``(tok, ok, routed)``. A prefix hit gathers the matched pages
+        ``(tok, ok, routed)`` and notes the target program's MoE lowering
+        on ``span``. A prefix hit gathers the matched pages
         read-only and prefills only the tail slab [full*ps, bucket) into
         FRESH pages — the matched prefix's partial last page (tokens
         past full*ps) is re-materialized into the lease's own first tail
@@ -1638,20 +1669,23 @@ class ServingEngine:
         padded = np.full((1, bucket - p0), self.pad_id, np.int32)
         padded[0, :prompt.size - p0] = prompt[p0:]
         if full:
+            key = ("prefill_hit", bucket, full)
             tok, ok, kv.pool, *routed = self._compiled_call(
-                ("prefill_hit", bucket, full),
-                lambda: self._build_prefill_hit(bucket, full),
+                key, lambda: self._build_prefill_hit(
+                    bucket, full, self._moe_took_list(key)),
                 self.gen._params(), self.model.bn_state, padded,
                 np.asarray([[prompt[-1]]], np.int32), length, kv.pool,
                 prefix_pages, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page))
         else:
+            key = ("prefill", bucket, n_prefill, self.prefill_chunk)
             tok, ok, kv.pool, *routed = self._compiled_call(
-                ("prefill", bucket, n_prefill, self.prefill_chunk),
-                lambda: self._build_prefill(bucket, n_prefill),
+                key, lambda: self._build_prefill(
+                    bucket, n_prefill, self._moe_took_list(key)),
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page))
+        self._note_moe_lowering(key, span)
         if self.draft_gen is not None:
             # the draft model's prefix KV rides the same page ids, so its
             # prefill mirrors the target's hit/cold split exactly
@@ -1809,7 +1843,7 @@ class ServingEngine:
                 self._seed_slot(slot, req, poison)
                 tok, ok, routed = self._run_prefill(
                     req.prompt, req.bucket, lease,
-                    self._sampling_args_1(req), adapter_page, poison)
+                    self._sampling_args_1(req), adapter_page, poison, psp)
                 with self._span("prefill_fetch"):
                     # ONE device_get: the copies back start together
                     ok, tok, *routed = jax.device_get((ok, tok, *routed))
@@ -2248,9 +2282,12 @@ class ServingEngine:
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
-                        context_tokens=context, kv_read_bytes=kv_read):
+                        context_tokens=context, kv_read_bytes=kv_read) as sp:
+            key = ("decode", k)
             toks, oks, self.kv.pool, *routed = self._compiled_call(
-                ("decode", k), lambda: self._build_decode(k), *args)
+                key, lambda: self._build_decode(k, self._moe_took_list(key)),
+                *args)
+            self._note_moe_lowering(key, sp)
         with self._span("token_fetch"):
             # ONE device_get: the copies back start together, so neither
             # `oks` nor the routing counts wait a round trip of their own
@@ -2761,6 +2798,9 @@ class ServingEngine:
             # streams
             "moe_assignments": self._moe_assignments,
             "moe_experts_hit": self._moe_experts_hit,
+            # decode and run-to-completion prefill dispatches whose
+            # program's MoE calls all took the expert-stream kernel
+            "moe_streamed_dispatches": self._moe_streamed_dispatches,
             "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
             "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
             "free_pages": self.kv.free_pages,

@@ -35,13 +35,13 @@ SIZES = dict(num_hidden_layers=LAYERS, rope_theta=10000.0,
 LOGIT_ATOL = 5e-5
 
 
-def build(batch=2, seq=SEQ, seed=3):
+def build(batch=2, seq=SEQ, seed=3, expert_hidden=64):
     cfg = FFConfig(batch_size=batch, mesh_shape={"data": 1}, seed=seed)
     ff = FFModel(cfg)
     _, logits = olmoe_lm(ff, batch, seq_len=seq, hidden=128, layers=LAYERS,
                          heads=4, kv_heads=4, num_experts=EXPERTS,
-                         experts_per_token=TOP_K, expert_hidden=64,
-                         vocab_size=VOCAB)
+                         experts_per_token=TOP_K,
+                         expert_hidden=expert_hidden, vocab_size=VOCAB)
     ff.compile(final_tensor=logits)
     # norm scales initialise to one, where a missing or misplaced scale
     # would pass: spread them
@@ -200,6 +200,61 @@ def test_serving_routing_counters_count_live_rows_only(served):
     assert by_len[40] == (40 * (LAYERS - 1) + LAYERS) * TOP_K
 
 
+def test_streamed_dispatches_count_what_the_programs_took(monkeypatch):
+    """With the backend read as a TPU (the kernel itself interprets here)
+    the decode program (4 rows) and the 16- and 32-token prefills take the
+    expert-stream kernel in every MoE call; the 256-token prefill runs its
+    first layer over 256 rows through ragged_dot (its last layer sees one
+    row and streams), so its dispatch is not a streamed one. The spans say
+    it per dispatch, stats() sums it, and the tokens are still the
+    reference's argmax."""
+    from flexflow_tpu.ops import moe as moe_mod
+
+    monkeypatch.setattr(moe_mod, "_backend", lambda: "tpu")
+    wide = build(expert_hidden=128)     # a width the kernel can tile
+    telemetry.set_enabled(True)
+    since = len(telemetry.tracer().events())
+    eng = wide.make_serving_engine(serve_slots=4, kv_page_size=8,
+                                   max_seq_len=320, decode_chunk=4,
+                                   prefix_cache=False)
+    rs = np.random.RandomState(9)
+    prompts = [rs.randint(1, VOCAB, (n,)).astype(np.int32)
+               for n in (11, 150, 23)]
+    reqs = eng.run(prompts, max_new_tokens=8)
+    assert [r.state for r in reqs] == ["done"] * 3
+    events = [e for e in telemetry.tracer().events()[since:]
+              if e["pid"] == eng._tm_track]
+    by_len = {e["args"]["prompt_tokens"]: e["args"]["moe_streamed"]
+              for e in events if e["name"] == "prefill"}
+    assert by_len == {11: 1, 23: 1, 150: 0}
+    disp = [e["args"] for e in events if e["name"] == "decode_dispatch"]
+    assert disp and all(d["moe_streamed"] == 1 for d in disp)
+    assert eng.stats()["moe_streamed_dispatches"] == len(disp) + 2
+    # each program's own list, filled as it was traced: two layers a walk,
+    # the 256-token prefill's first layer over 256 rows, its last over one
+    assert eng._moe_took == {
+        ("decode", 4): ["streamed"] * 2,
+        ("prefill", 16, 2, 0): ["streamed"] * 2,
+        ("prefill", 32, 4, 0): ["streamed"] * 2,
+        ("prefill", 256, 32, 0): ["grouped", "streamed"]}
+    for r in reqs:
+        full = np.concatenate([r.prompt, np.asarray(r.tokens, np.int32)])
+        logits = np.asarray(ref.forward(wide.params, full, SIZES))
+        rows = logits[r.prompt.size - 1:-1]
+        margin = rows.max(axis=-1) - rows[np.arange(8), full[r.prompt.size:]]
+        assert margin.max() <= 2 * LOGIT_ATOL, margin.max()
+
+
+def test_grouped_dispatches_say_so_on_this_host(served):
+    """The same engine on a TPU-less host: every program keeps ragged_dot,
+    the spans say 0 and nothing is counted."""
+    eng, _, events = served
+    took = [e["args"]["moe_streamed"] for e in events
+            if e["name"] in ("decode_dispatch", "prefill")]
+    assert took and not any(took)
+    assert eng.stats()["moe_streamed_dispatches"] == 0
+
+
 def test_dense_model_programs_carry_no_routing_output():
     """A model without a dropless MoE op traces the serve programs it
     traced before: three outputs, no counter moved."""
@@ -221,6 +276,7 @@ def test_dense_model_programs_carry_no_routing_output():
     assert len(out) == 3
     st = eng.stats()
     assert st["moe_assignments"] == st["moe_experts_hit"] == 0
+    assert st["moe_streamed_dispatches"] == 0 and eng._moe_took == {}
 
 
 WRT = [("moe_0", "router"), ("moe_1", "w_gate"), ("moe_1", "w_up"),
