@@ -220,7 +220,10 @@ class FFModel:
     def moe(self, input: Tensor, num_experts: int, hidden_dim: int,
             k: int = 2, capacity_factor: Optional[float] = 1.25,
             dispatch: str = "auto", expert: str = "gelu",
-            renormalize: bool = True,
+            renormalize: bool = True, scoring: str = "softmax",
+            score_bias: Optional[float] = None, n_group: int = 1,
+            topk_group: int = 1, routed_scaling: float = 1.0,
+            shared_hidden_dim: int = 0, experts_held=None,
             name: Optional[str] = None) -> Tensor:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
@@ -230,12 +233,20 @@ class FFModel:
         dispatch: "auto" (dense einsums when experts are mesh-sharded, else
         sort-based) | "dense" | "sort". expert: "gelu" (w_in, w_out) |
         "swiglu" (w_gate, w_up, w_down); renormalize: kept gates rescaled
-        to sum to 1 per token."""
+        to sum to 1 per token. The dropless SwiGLU op also takes the
+        router's form (scoring, score_bias, n_group / topk_group,
+        routed_scaling), a shared expert (shared_hidden_dim) and
+        experts_held=(first, count), one chip's share of an
+        expert-parallel layer: ops/moe.py."""
         from flexflow_tpu.ops.moe import MoE
 
         op = MoE(self, self._name("moe", name), [input], num_experts,
                  hidden_dim, k, capacity_factor, dispatch=dispatch,
-                 expert=expert, renormalize=renormalize)
+                 expert=expert, renormalize=renormalize, scoring=scoring,
+                 score_bias=score_bias, n_group=n_group,
+                 topk_group=topk_group, routed_scaling=routed_scaling,
+                 shared_hidden_dim=shared_hidden_dim,
+                 experts_held=experts_held)
         outs = self._add(op)
         self._aux_tensors.append(outs[1])
         return outs[0]
@@ -270,6 +281,28 @@ class FFModel:
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, num_kv_heads=num_kv_heads, rope=rope,
             rope_theta=rope_theta, qk_norm=qk_norm, eps=eps))
+
+    def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
+                         q_lora_rank: int, kv_lora_rank: int,
+                         qk_nope_head_dim: int, qk_rope_head_dim: int,
+                         v_head_dim: int, index_n_heads: int,
+                         index_head_dim: int, index_topk: int,
+                         rope_theta: float = 10000.0,
+                         rope_scaling: Optional[dict] = None,
+                         eps: float = 1e-6, uq_init_gain: float = 1.0,
+                         name: Optional[str] = None) -> Tensor:
+        """Causal multi-head latent self-attention with a learned top-k
+        selection of the cached tokens (DeepSeek MLA + lightning indexer,
+        ops/mla.py): the cache holds one latent row and one index key a
+        token instead of K and V per head."""
+        from flexflow_tpu.ops.mla import LatentAttention
+
+        return self._add(LatentAttention(
+            self, self._name("latent_attention", name), [input], embed_dim,
+            num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+            qk_rope_head_dim, v_head_dim, index_n_heads, index_head_dim,
+            index_topk, rope_theta=rope_theta, rope_scaling=rope_scaling,
+            eps=eps, uq_init_gain=uq_init_gain))
 
     def transformer_pipeline_stack(self, input: Tensor, num_layers: int,
                                    num_heads: int, ffn_mult: int = 4,

@@ -176,6 +176,9 @@ class MultiHeadAttention(Op):
     op_type = OperatorType.OP_MULTIHEAD_ATTENTION
     needs_rng = True
     wants_shard_ctx = True  # executor passes (mesh, axis_map) for SP lowering
+    # implements the cache protocol generate() and the serving engine drive
+    # (init_cache ... gather_paged_kv); ops/mla.py is the other op that does
+    kv_cache_protocol = True
 
     def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
                  kdim: int = 0, vdim: int = 0, dropout: float = 0.0,
@@ -574,6 +577,30 @@ class MultiHeadAttention(Op):
                 page_quantize(pf, new, qmax, pool.dtype))
             out[name + "_scale"] = sc.at[page_ids].set(new)
         return out
+
+    def scatter_cache_tail(self, cache, contiguous, p0: int, pages,
+                           impl="einsum"):
+        """Write a request's contiguous prefill cache past position p0
+        into its own fresh pool `pages` (paged_prefill_write on the k and
+        v slices)."""
+        return self.paged_prefill_write(
+            cache, contiguous["k"][:, p0:], contiguous["v"][:, p0:], pages,
+            impl=impl)
+
+    def cache_bytes_per_token(self) -> int:
+        """bf16 bytes one cached token takes in this op's pool."""
+        return self.num_kv_heads * (self.qk_head_dim + self.v_head_dim) * 2
+
+    def paged_kernel_shape(self, cache):
+        """What the kernel autotuner's table is keyed by (None from an op
+        whose paged kernels it does not hold)."""
+        return {"head_dim": self.qk_head_dim, "dtype": cache["k"].dtype,
+                "heads": self.num_heads}
+
+    def decode_span_counts(self, context):
+        """Counts a decode dispatch adds to its span beside the engine's
+        own: none for plain attention."""
+        return {}
 
     def export_page(self, cache, page):
         """Slice pool page(s) out as the serializable migration payload

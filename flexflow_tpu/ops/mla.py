@@ -1,0 +1,597 @@
+"""Multi-head LATENT attention with a learned sparse selection (DeepSeek-V2/V3
+MLA, DeepSeek-V3.2's lightning indexer): `FFModel.latent_attention`.
+
+What is cached per token is not K and V per head but ONE latent row shared
+by all heads, and one index key:
+
+    cQ = RMSNorm(a W_DQ);  q_i = [(cQ W_UQ)_i^nope ; RoPE((cQ W_UQ)_i^rope)]
+    [cKV ; kR] = a W_DKV;  cKV = RMSNorm(cKV);  kR = RoPE(kR)   (one for all heads)
+    k_{s,i} = [cKV_s W_UK,i ; kR_s]        v_{s,i} = cKV_s W_UV,i
+    qI_j = (cQ W_IQ)_j, kI = LayerNorm(a W_IK)   (RoPE on their first d_R dims)
+    w = a W_Iw * J^-0.5 * d_I^-0.5
+    I_{t,s} = sum_j w_{t,j} ReLU(qI_{t,j} . kI_s)
+    S_t = the index_topk live positions of largest I_{t,s} (all, while fewer)
+    o_{t,i} = sum_{s in S_t} softmax_{S_t}(q_{t,i} . k_{s,i} * scale) v_{s,i}
+
+Two forms of the same numbers. EXPANDED (`forward`: predict, fit): K and V
+are built per head from the latents. ABSORBED (everything that reads a
+cache): W_UK moves into the query and W_UV into the output,
+
+    q_{t,i} . k_{s,i} = [q^nope_{t,i} W_UK,i^T ; q^rope_{t,i}] . [cKV_s ; kR_s]
+    o_{t,i} = (sum_s p_{t,i,s} cKV_s) W_UV,i
+
+so attention runs against the cached rows as they are: H heads x (c + d_R)
+against one row a token.
+
+Cache layout: `lat` rows of LAT = c + d_R rounded up to 128 lanes ([cKV ; kR
+; zeros]: at c 512, d_R 64 that is 640, 64 lanes = 128 B a token of padding,
+which a tiled HBM layout of a 576-wide minor dim would add anyway; it is
+counted in `cache_bytes_per_token`), and `ki` rows of d_I. Both pools ride the
+same page ids.
+
+Selection is exact: `dsa_threshold` finds each row's index_topk-th largest
+score digit by digit on the scores' bit patterns (8 passes of 15 counts) and
+cuts ties by position (lowest first, 4 more passes); the same two numbers a row drive the
+XLA mask here and the Pallas core kernel's (ops/pallas_kernels.py).
+
+YaRN rotary (`rope_scaling`: factor, original_max_position_embeddings,
+beta_fast, beta_slow, mscale, mscale_all_dim): frequencies interpolated
+between theta^(-2i/d) and the same / factor over the correction range; cos
+and sin carry mscale / mscale_all_dim's ratio (1 when they are equal), and
+the softmax scale is (d_nope + d_R)^-0.5 * (0.1 mscale_all_dim ln factor + 1)^2.
+Pairs are rotate-half, as everywhere in this package.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from flexflow_tpu.ffconst import OperatorType
+from flexflow_tpu.ops.attention import (kv_storage_dtype,
+                                        resolve_paged_attention_impl)
+from flexflow_tpu.ops.base import Op, WeightSpec
+
+LANES = 128
+# f32 bytes one block of query rows may spend on its (rows, heads, keys)
+# logits: the row block is the largest power of two under it
+_BLOCK_LOGIT_BYTES = 256 << 20
+# the fewest keys a prefill chunk is given (its cache allowing): on the v5e
+# the softmax reduction XLA builds for a block of rows against 6144 or 8192
+# keys runs 30 x slower than against 4096 or 10240 (two of the eight chunks
+# of a 16 k cold prefill took 4.7 of its 6.95 s; the block's rows do not
+# matter, 32 read like 64), so a chunk whose causal range ends earlier sees
+# the cache's rows up to here, dead under the live rule (PERF.md section 6,
+# PR 30)
+_MIN_CHUNK_KEYS = 10240
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """(dim // 2,) f32 rotary frequencies, YaRN-interpolated when `scaling`
+    is given (HF `DeepseekV3YarnRotaryEmbedding`)."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** i
+    if not scaling:
+        return extra.astype(np.float32)
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                     # 1 = extrapolate (high frequency)
+    return ((extra / factor) * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_rotate(x, pos, inv_freq, amp: float = 1.0):
+    """Rotate-half rotary of x (B, S, ..., d) at positions pos (B, S), d =
+    2 * len(inv_freq); f32 angles and arithmetic."""
+    half = x.shape[-1] // 2
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+    cos, sin = jnp.cos(ang) * amp, jnp.sin(ang) * amp
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _f32_key(x):
+    """uint32 keys ordered as the floats are (no NaN, no -0.0)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def dsa_threshold(scores, k: int):
+    """Per row of `scores` (..., L) f32 (dead positions hold -inf): (thr
+    f32, tie_cut int32) such that the k positions of largest score, ties
+    going to the lowest position, are exactly those with score > thr, or
+    score == thr at a position <= tie_cut. A row with at most k live
+    positions gets (-inf, L): all of them."""
+    L = scores.shape[-1]
+    key = _f32_key(scores)
+    live = scores > -jnp.inf
+    n_live = jnp.sum(live, axis=-1)
+    lead = scores.shape[:-1]
+
+    digits = jnp.arange(1, 16, dtype=jnp.uint32)
+
+    def key_digit(i, prefix):
+        # four bits a pass: of the 15 candidates prefix | d << shift the
+        # counts fall as d grows, so the digit is how many reach k
+        shift = (28 - 4 * i).astype(jnp.uint32)
+        cand = prefix[..., None] | (digits << shift)            # (..., 15)
+        cnt = jnp.sum(live[..., None, :]
+                      & (key[..., None, :] >= cand[..., None]), axis=-1)
+        digit = jnp.sum(cnt >= k, axis=-1).astype(jnp.uint32)
+        return prefix | (digit << shift)
+
+    # the largest key that at least k live keys reach: the k-th largest
+    kth = jax.lax.fori_loop(0, 8, key_digit, jnp.zeros(lead, jnp.uint32))
+    above = jnp.sum(live & (key > kth[..., None]), axis=-1)
+    need = k - above                    # ties at the threshold to take
+    tied = live & (key == kth[..., None])
+    pos = jnp.arange(L, dtype=jnp.int32)
+    passes = -(-max(1, (L - 1).bit_length()) // 4)
+
+    def pos_digit(i, p):
+        shift = 4 * (passes - 1 - i)
+        cand = p[..., None] | (digits.astype(jnp.int32) << shift)
+        cnt = jnp.sum(tied[..., None, :] & (pos < cand[..., None]), axis=-1)
+        digit = jnp.sum(cnt < need[..., None], axis=-1).astype(jnp.int32)
+        return p | (digit << shift)
+
+    # the largest position with fewer than `need` ties before it: that of
+    # the need-th tie
+    cut = jax.lax.fori_loop(0, passes, pos_digit,
+                            jnp.zeros(lead, jnp.int32))
+    kth_f = jax.lax.bitcast_convert_type(
+        jnp.where(kth >> 31 == 1, kth & jnp.uint32(0x7FFFFFFF), ~kth),
+        jnp.float32)
+    few = n_live <= k
+    return (jnp.where(few, -jnp.inf, kth_f),
+            jnp.where(few, jnp.int32(L), cut))
+
+
+def dsa_chosen(scores, thr, tie_cut):
+    """(..., L) bool: the positions `dsa_threshold`'s pair selects."""
+    pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    t = thr[..., None]
+    return (scores > -jnp.inf) & (
+        (scores > t) | ((scores == t) & (pos <= tie_cut[..., None])))
+
+
+class LatentAttention(Op):
+    op_type = OperatorType.OP_MULTIHEAD_ATTENTION
+    # the serving engine's and generate()'s cache protocol (init_cache ...
+    # gather_paged_kv): runtime/generation.py dispatches on this
+    kv_cache_protocol = True
+    causal = True
+    # a chunked prefill closes each chunk with an optimization barrier: the
+    # next chunks' projections depend on the tokens alone, XLA hoists them
+    # above this chunk's work, and at 16 chunks of a 32 k prompt 5 GB of
+    # them were live at once (runtime/generation.py `_prefill`)
+    prefill_chunk_barrier = True
+
+    def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
+                 q_lora_rank: int, kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int, index_n_heads: int,
+                 index_head_dim: int, index_topk: int,
+                 rope_theta: float = 10000.0,
+                 rope_scaling: Optional[dict] = None, eps: float = 1e-6,
+                 uq_init_gain: float = 1.0):
+        super().__init__(model, name, inputs)
+        self.embed_dim, self.num_heads = embed_dim, num_heads
+        self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
+        self.d_nope, self.d_rope = qk_nope_head_dim, qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.index_n_heads, self.index_head_dim = index_n_heads, index_head_dim
+        self.index_topk = int(index_topk)
+        assert qk_rope_head_dim % 2 == 0 and index_head_dim >= qk_rope_head_dim
+        self.rope_theta = float(rope_theta)
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.eps = eps
+        # the seeded draw of W_UQ is this much wider than glorot's (a
+        # configuration that wants peaked attention from random weights)
+        self.uq_init_gain = float(uq_init_gain)
+        self.in_dim = inputs[0].dims[-1]
+        self.lat_width = -(-(kv_lora_rank + qk_rope_head_dim) // LANES) * LANES
+        self.inv_freq = yarn_inv_freq(qk_rope_head_dim, self.rope_theta,
+                                      self.rope_scaling)
+        sc = self.rope_scaling or {}
+        factor = float(sc.get("factor", 1.0))
+        all_dim = yarn_mscale(factor, float(sc.get("mscale_all_dim", 0.0)))
+        self.rope_amp = yarn_mscale(factor, float(sc.get("mscale", 1.0))) \
+            / all_dim if sc else 1.0
+        self.scale = (self.d_nope + self.d_rope) ** -0.5 * all_dim * all_dim
+        self.index_scale = index_n_heads ** -0.5 * index_head_dim ** -0.5
+        self.finalize()
+
+    def output_shapes(self):
+        x = self.inputs[0]
+        return [tuple(x.dims[:-1]) + (self.embed_dim,)], [x.dtype]
+
+    def weights(self) -> List[WeightSpec]:
+        D, H, rq, c = self.in_dim, self.num_heads, self.q_lora_rank, \
+            self.kv_lora_rank
+        dq = self.d_nope + self.d_rope
+        J, dI, dv = self.index_n_heads, self.index_head_dim, self.v_head_dim
+        g2 = self.uq_init_gain ** 2
+        return [
+            WeightSpec("w_dq", (D, rq)),
+            WeightSpec("q_norm", (rq,), init="one"),
+            WeightSpec("w_uq", (rq, H, dq), fan=(rq / g2, H * dq / g2)),
+            WeightSpec("w_dkv", (D, c + self.d_rope)),
+            WeightSpec("kv_norm", (c,), init="one"),
+            WeightSpec("w_uk", (c, H, self.d_nope),
+                       fan=(c, H * self.d_nope)),
+            WeightSpec("w_uv", (c, H, dv), fan=(c, H * dv)),
+            WeightSpec("wo", (H, dv, self.embed_dim),
+                       fan=(H * dv, self.embed_dim)),
+            WeightSpec("w_iq", (rq, J, dI), fan=(rq, J * dI)),
+            WeightSpec("w_ik", (D, dI)),
+            WeightSpec("ik_norm_scale", (dI,), init="one"),
+            WeightSpec("ik_norm_bias", (dI,), init="zero"),
+            WeightSpec("w_iw", (D, J)),
+        ]
+
+    # ---- projections -------------------------------------------------------
+
+    def _rms(self, x, scale):
+        xf = x.astype(jnp.float32)
+        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                + self.eps)
+        return (xf * scale.astype(jnp.float32)).astype(x.dtype)
+
+    def _layer_norm(self, x, scale, bias):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+        xf = (xf - mu) * jax.lax.rsqrt(var + self.eps)
+        return (xf * scale.astype(jnp.float32)
+                + bias.astype(jnp.float32)).astype(x.dtype)
+
+    def _rope_head(self, x, pos):
+        """RoPE on the first d_rope dims of an index query/key."""
+        r = self.d_rope
+        return jnp.concatenate(
+            [rope_rotate(x[..., :r], pos, self.inv_freq, self.rope_amp),
+             x[..., r:]], axis=-1)
+
+    def _project(self, params, a, pos):
+        """Everything one slab of tokens a (B, S, D) at positions pos (B, S)
+        contributes: queries (`q_nope`, `q_rope` (B, S, H, .), `qi` (B, S, J,
+        dI), `w` (B, S, J) f32) and what is cached (`lat` (B, S, LAT) =
+        [cKV ; kR ; 0], `ki` (B, S, dI))."""
+        c = self.kv_lora_rank
+        cq = self._rms(a @ params["w_dq"], params["q_norm"])
+        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+        kv = a @ params["w_dkv"]
+        ckv = self._rms(kv[..., :c], params["kv_norm"])
+        kr = rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp)
+        pad = self.lat_width - c - self.d_rope
+        lat = jnp.concatenate(
+            [ckv, kr] + ([jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype)]
+                         if pad else []), axis=-1)
+        qi = self._rope_head(
+            jnp.einsum("bsr,rjk->bsjk", cq, params["w_iq"]), pos)
+        ki = self._rope_head(self._layer_norm(
+            a @ params["w_ik"], params["ik_norm_scale"],
+            params["ik_norm_bias"]), pos)
+        w = (a @ params["w_iw"]).astype(jnp.float32) * self.index_scale
+        return {"q_nope": q[..., :self.d_nope],
+                "q_rope": rope_rotate(q[..., self.d_nope:], pos,
+                                      self.inv_freq, self.rope_amp),
+                "qi": qi, "w": w, "lat": lat, "ki": ki}
+
+    def _absorb(self, params, q_nope, q_rope):
+        """(..., H, LAT) queries against latent rows: [q_nope W_UK^T ;
+        q_rope ; 0]."""
+        qa = jnp.einsum("...hk,chk->...hc", q_nope, params["w_uk"])
+        pad = self.lat_width - self.kv_lora_rank - self.d_rope
+        parts = [qa, q_rope]
+        if pad:
+            parts.append(jnp.zeros(qa.shape[:-1] + (pad,), qa.dtype))
+        return jnp.concatenate(parts, axis=-1)
+
+    def _out(self, params, o):
+        """(B, S, H, d_v) head outputs -> (B, S, D)."""
+        return jnp.einsum("bshv,hvd->bsd", o, params["wo"])
+
+    # ---- the blocked attention both forms share ----------------------------
+
+    def _row_block(self, s: int, n_keys: int) -> int:
+        cap = max(1, _BLOCK_LOGIT_BYTES // (4 * self.num_heads * n_keys))
+        return math.gcd(s, 1 << (cap.bit_length() - 1))
+
+    def _blocked(self, pr, frontier, row_len, prompt_pad, ki, attend):
+        """Selection and attention in blocks of query rows, so that neither
+        an (S, L) score matrix nor an (S, H, L) logit tensor ever exists
+        whole. Query row (b, s) may see key j iff  j < row_len[b]  or
+        prompt_pad[b] <= j <= frontier[b, s]; of those its index scores
+        keep index_topk. `attend(block, chosen)` -> (B, R, H, .) gets each
+        block's slices of `pr` and the (B, R, L) mask."""
+        b, s = frontier.shape
+        L = ki.shape[1]
+        r = self._row_block(s, L)
+        j = jnp.arange(L, dtype=jnp.int32)
+        rows = {n: pr[n] for n in ("q_nope", "q_rope", "qi", "w")}
+        rows["frontier"] = frontier
+
+        def split(x):       # (B, S, ...) -> (S // r, B, r, ...)
+            return jnp.moveaxis(
+                x.reshape((b, s // r, r) + x.shape[2:]), 1, 0)
+
+        def one(blk):
+            fr = blk["frontier"]                            # (B, r)
+            live = (j < row_len[:, None, None]) | (
+                (j >= prompt_pad[:, None, None]) & (j <= fr[..., None]))
+            sc = jnp.einsum("brjd,bld->brjl", blk["qi"], ki.astype(
+                blk["qi"].dtype), preferred_element_type=jnp.float32)
+            sc = jnp.einsum("brjl,brj->brl", jnp.maximum(sc, 0.0),
+                            blk["w"]) + 0.0
+            sc = jnp.where(live, sc, -jnp.inf)
+            thr, cut = dsa_threshold(sc, self.index_topk)
+            return attend(blk, dsa_chosen(sc, thr, cut))
+
+        out = jax.lax.map(one, {n: split(x) for n, x in rows.items()})
+        return jnp.moveaxis(out, 0, 1).reshape((b, s) + out.shape[3:])
+
+    def _attend_latent(self, params, pr, lat, ki, frontier, row_len,
+                       prompt_pad):
+        """ABSORBED: pr's queries against cached rows lat (B, L, LAT), ki
+        (B, L, dI) -> (B, S, D)."""
+        c = self.kv_lora_rank
+        latc = lat.astype(pr["q_nope"].dtype)
+
+        def attend(blk, chosen):
+            # absorbed per block: a chunk's (S, H, LAT) queries never exist
+            q_lat = self._absorb(params, blk["q_nope"], blk["q_rope"])
+            logits = jnp.einsum("brhc,blc->brhl", q_lat, latc,
+                                preferred_element_type=jnp.float32)
+            logits = jnp.where(chosen[:, :, None, :], logits * self.scale,
+                               jnp.finfo(jnp.float32).min)
+            p = jax.nn.softmax(logits, axis=-1).astype(latc.dtype)
+            ctx = jnp.einsum("brhl,blc->brhc", p, latc[..., :c])
+            return jnp.einsum("brhc,chv->brhv", ctx, params["w_uv"])
+
+        return self._out(params, self._blocked(
+            pr, frontier, row_len, prompt_pad, ki, attend))
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        """EXPANDED: per-head K and V from the slab's own latents, causal."""
+        a = xs[0]
+        b, s = a.shape[:2]
+        pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        pr = self._project(params, a, pos)
+        c = self.kv_lora_rank
+        ckv = pr["lat"][..., :c]
+        kr = pr["lat"][..., c:c + self.d_rope]
+        k = jnp.concatenate(
+            [jnp.einsum("blc,chk->blhk", ckv, params["w_uk"]),
+             jnp.broadcast_to(kr[:, :, None, :], (b, s, self.num_heads,
+                                                  self.d_rope))], axis=-1)
+        v = jnp.einsum("blc,chv->blhv", ckv, params["w_uv"])
+
+        def attend(blk, chosen):
+            q = jnp.concatenate([blk["q_nope"], blk["q_rope"]], axis=-1)
+            logits = jnp.einsum("brhk,blhk->brhl", q, k,
+                                preferred_element_type=jnp.float32)
+            logits = jnp.where(chosen[:, :, None, :], logits * self.scale,
+                               jnp.finfo(jnp.float32).min)
+            p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+            return jnp.einsum("brhl,blhv->brhv", p, v)
+
+        zero = jnp.zeros((b,), jnp.int32)
+        return [self._out(params, self._blocked(
+            pr, pos, zero, zero, pr["ki"], attend))]
+
+    def selection(self, params, a):
+        """(B, S, S) bool: the positions each row of a causal slab a
+        (B, S, D) selects (what `forward` attends; for tests and checks)."""
+        b, s = a.shape[:2]
+        pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        pr = self._project(params, a, pos)
+        zero = jnp.zeros((b,), jnp.int32)
+        return self._blocked(pr, pos, zero, zero, pr["ki"],
+                             lambda blk, chosen: chosen[:, :, None])[:, :, 0]
+
+    # ---- contiguous cache (generate(), and a request's prefill) ------------
+
+    def init_cache(self, batch: int, max_len: int, dtype):
+        return {"lat": jnp.zeros((batch, max_len, self.lat_width), dtype),
+                "ki": jnp.zeros((batch, max_len, self.index_head_dim),
+                                dtype)}
+
+    @staticmethod
+    def _write(cache, pr, start):
+        return {n: jax.lax.dynamic_update_slice(
+            cache[n], pr[n].astype(cache[n].dtype), (0, start, 0))
+            for n in ("lat", "ki")}
+
+    def chunk_forward(self, params, xs, cache, start):
+        """Positions [start, start + C) of a prompt: write their rows,
+        attend the static prefix [0, start + C) causally (given at least
+        _MIN_CHUNK_KEYS of the cache's rows: what lies past a row's own
+        position is dead under the live rule)."""
+        a = xs[0]
+        b, c = a.shape[:2]
+        pos = jnp.broadcast_to(start + jnp.arange(c, dtype=jnp.int32),
+                               (b, c))
+        pr = self._project(params, a, pos)
+        cache = self._write(cache, pr, start)
+        keys = min(cache["lat"].shape[1], max(start + c, _MIN_CHUNK_KEYS))
+        zero = jnp.zeros((b,), jnp.int32)
+        out = self._attend_latent(params, pr, cache["lat"][:, :keys],
+                                  cache["ki"][:, :keys], pos, zero, zero)
+        return out, cache
+
+    def prefill_forward(self, params, xs, cache):
+        return self.chunk_forward(params, xs, cache, 0)
+
+    def query_forward(self, params, xs, cache, rope_pos, row_lengths):
+        """Read-only: each row's last prompt token (already written) at its
+        own position against its live prefix j < row_lengths."""
+        pr = self._project(params, xs[0], rope_pos[:, None])
+        zero = jnp.zeros_like(row_lengths)
+        out = self._attend_latent(
+            params, pr, cache["lat"], cache["ki"],
+            (row_lengths - 1)[:, None], zero, zero)
+        return out, cache
+
+    def decode_forward(self, params, xs, cache, pos, rope_pos=None,
+                       row_lengths=None, prompt_len=None):
+        """generate()'s one-token step at cache slot `pos`; ragged rows as
+        MultiHeadAttention.decode_forward."""
+        a = xs[0]
+        b = a.shape[0]
+        rp = jnp.broadcast_to(pos if rope_pos is None else rope_pos, (b,))
+        pr = self._project(params, a, rp[:, None])
+        cache = self._write(cache, pr, pos)
+        zero = jnp.zeros((b,), jnp.int32)
+        out = self._attend_latent(
+            params, pr, cache["lat"], cache["ki"],
+            jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32),
+            zero if row_lengths is None else row_lengths,
+            zero if row_lengths is None else zero + prompt_len)
+        return out, cache
+
+    # ---- paged pool (runtime/serving.py, runtime/kv_pool.py) ---------------
+
+    def cache_bytes_per_token(self) -> int:
+        """bf16 bytes one cached token takes in this op's pools, the
+        latent row's padding lanes included."""
+        return (self.lat_width + self.index_head_dim) * 2
+
+    def paged_kernel_shape(self, cache):
+        """None: the kernel autotuner's table holds the K/V paged kernels,
+        which this op does not run."""
+        return None
+
+    def decode_span_counts(self, context):
+        """Host-side counts of one decode dispatch from the live rows'
+        context lengths (an int array, one entry a row and step): bytes of
+        index keys read, tokens the selection keeps, tokens it saw."""
+        ctx = np.asarray(context, np.int64)
+        return {"index_read_bytes": int(ctx.sum()) * self.index_head_dim * 2,
+                "dsa_selected_tokens": int(np.minimum(
+                    ctx, self.index_topk).sum()),
+                "dsa_context_tokens": int(ctx.sum())}
+
+    def init_paged_cache(self, num_pages: int, page_size: int, dtype,
+                         kv_dtype=None):
+        sdtype, qmax = kv_storage_dtype(kv_dtype)
+        if qmax is not None:
+            raise NotImplementedError(
+                f"{self.name}: a quantized latent cache ({kv_dtype}) is not "
+                f"built; kv_cache_dtype native or bf16")
+        store = sdtype if sdtype is not None else dtype
+        return {"lat": jnp.zeros((num_pages, page_size, self.lat_width),
+                                 store),
+                "ki": jnp.zeros((num_pages, page_size, self.index_head_dim),
+                                store)}
+
+    def scatter_cache_tail(self, pool, cache, p0: int, pages, impl="einsum"):
+        """Write a request's contiguous cache past position p0 into its own
+        fresh `pages` (whole pages: one update-slice a page)."""
+        ps = pool["lat"].shape[1]
+        out = {}
+        for n in ("lat", "ki"):
+            x = cache[n][0, p0:]
+            pad = pages.shape[0] * ps - x.shape[0]
+            if pad:
+                x = jnp.pad(x, ((0, pad), (0, 0)))
+            out[n] = pool[n].at[pages].set(
+                x.reshape(pages.shape[0], ps, -1).astype(pool[n].dtype))
+        return out
+
+    def export_page(self, cache, page):
+        return {n: cache[n][page] for n in ("lat", "ki")}
+
+    def import_page(self, cache, page, payload):
+        return {n: cache[n].at[page].set(
+            jnp.asarray(payload[n]).astype(cache[n].dtype))
+            for n in ("lat", "ki")}
+
+    def gather_paged_kv(self, cache, pages):
+        return {n: cache[n][pages].reshape(1, -1, cache[n].shape[-1])
+                for n in ("lat", "ki")}
+
+    def paged_decode_forward(self, params, xs, cache, page_table, write_pos,
+                             rope_pos, row_len, prompt_pad, impl=None):
+        """One decode step of every slot over the paged pools: append the
+        token's latent row and index key at (page_table[b, write_pos //
+        ps], write_pos % ps), then score, select and attend through the
+        page tables. `pallas`: the two kernels read the pools in place;
+        `einsum`: the slots' pages gathered into contiguous rows and the
+        blocked XLA attention, the parity oracle."""
+        ps = cache["lat"].shape[1]
+        pr = self._project(params, xs[0], rope_pos[:, None])
+        page_ids = jnp.take_along_axis(
+            page_table, (write_pos // ps)[:, None], axis=1)[:, 0]
+        offs = write_pos % ps
+        cache = {n: cache[n].at[page_ids, offs].set(
+            pr[n][:, 0].astype(cache[n].dtype)) for n in ("lat", "ki")}
+        if resolve_paged_attention_impl(
+                impl, getattr(self.model, "config", None)) != "pallas":
+            b = page_table.shape[0]
+            lat = cache["lat"][page_table].reshape(b, -1, self.lat_width)
+            ki = cache["ki"][page_table].reshape(b, -1, self.index_head_dim)
+            return self._attend_latent(params, pr, lat, ki,
+                                       write_pos[:, None], row_len,
+                                       prompt_pad), cache
+        from flexflow_tpu.ops.pallas_kernels import (
+            dsa_index_scores_pallas, mla_paged_core_pallas)
+
+        scores = dsa_index_scores_pallas(
+            pr["qi"][:, 0], pr["w"][:, 0], cache["ki"], page_table,
+            write_pos, row_len, prompt_pad)
+        thr, cut = dsa_threshold(scores, self.index_topk)
+        ctx = mla_paged_core_pallas(
+            self._absorb(params, pr["q_nope"][:, 0], pr["q_rope"][:, 0]),
+            scores, thr, cut, cache["lat"], page_table, write_pos, row_len,
+            prompt_pad, self.scale, self.kv_lora_rank)
+        o = jnp.einsum("bhc,chv->bhv", ctx, params["w_uv"])
+        return self._out(params, o[:, None]), cache
+
+    def paged_verify_forward(self, *args, **kw):
+        raise NotImplementedError(
+            f"{self.name}: speculative verify over a latent cache is not "
+            f"built (the selection would run per slab position); serve "
+            f"this model without a draft")
+
+    # ---- strategy search -----------------------------------------------------
+
+    def partitionable_output_dims(self):
+        return [0]
+
+    def weight_partition(self, axis_map):
+        return {w.name: P(*([None] * len(w.shape)))
+                for w in self.weight_specs()}
+
+    def flops(self):
+        b, s = self.inputs[0].dims[0], self.inputs[0].dims[1]
+        proj = 2 * b * s * sum(
+            int(np.prod(w.shape)) for w in self.weight_specs()
+            if len(w.shape) > 1)
+        keys = min(s, self.index_topk)
+        core = 2 * b * s * self.num_heads * keys * (
+            self.d_nope + self.d_rope + self.v_head_dim)
+        index = 2 * b * s * s * self.index_n_heads * self.index_head_dim
+        return proj + core + index

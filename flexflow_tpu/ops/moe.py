@@ -42,7 +42,19 @@ The architecture is described by constructor arguments, none of them a
 performance selector: `expert` ("gelu": two matrices w_in/w_out as above;
 "swiglu": w_gate, w_up (E, D, F), w_down (E, F, D)) and `renormalize`
 (kept gates rescaled to sum to 1 per token; OLMoE's `norm_topk_prob` is
-false).
+false). The dropless op also takes the router's form (`MoE._route`, the one
+routing function both lowerings are fed by): `scoring` ("softmax" |
+"sigmoid"), `score_bias` (a learned (E,) bias added to the scores for the
+SELECTION only, never to the gates: DeepSeek-V3's `e_score_correction_bias`),
+`n_group` / `topk_group` (group-limited top-k: a group's score is the sum of
+its two best biased scores, only the `topk_group` best groups' experts can be
+chosen), `routed_scaling` (a factor on the kept gates), `shared_hidden_dim`
+(a SwiGLU expert every token passes through, beside the routed ones) and
+`experts_held=(first, count)`: the layer routes over all `num_experts` but
+holds, and computes, only experts first .. first + count - 1, which is one
+chip's share of an expert-parallel layer (its output is the shared expert
+plus ITS experts' part of the routed sum; the exchange that adds the other
+chips' parts is not in this op).
 """
 
 from __future__ import annotations
@@ -95,7 +107,11 @@ class MoE(Op):
     def __init__(self, model, name, inputs, num_experts: int, hidden_dim: int,
                  k: int = 2, capacity_factor: Optional[float] = 1.25,
                  aux_weight: float = 1e-2, dispatch: str = "auto",
-                 expert: str = "gelu", renormalize: bool = True):
+                 expert: str = "gelu", renormalize: bool = True,
+                 scoring: str = "softmax",
+                 score_bias: Optional[float] = None, n_group: int = 1,
+                 topk_group: int = 1, routed_scaling: float = 1.0,
+                 shared_hidden_dim: int = 0, experts_held=None):
         super().__init__(model, name, inputs)
         self.num_experts = num_experts
         self.hidden_dim = hidden_dim
@@ -106,9 +122,39 @@ class MoE(Op):
             raise ValueError(f"dispatch must be auto|dense|sort, got {dispatch!r}")
         if expert not in ("gelu", "swiglu"):
             raise ValueError(f"expert must be gelu|swiglu, got {expert!r}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"scoring must be softmax|sigmoid, got {scoring!r}")
         self.dispatch = dispatch
         self.expert = expert
         self.renormalize = renormalize
+        self.scoring = scoring
+        # std of the seeded draw of the selection-only bias; None = no bias
+        self.score_bias = score_bias
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.routed_scaling = float(routed_scaling)
+        self.shared_hidden_dim = int(shared_hidden_dim)
+        self.held_first, self.held_count = (
+            (0, num_experts) if experts_held is None
+            else (int(experts_held[0]), int(experts_held[1])))
+        plain = (scoring == "softmax" and score_bias is None
+                 and self.n_group == 1 and self.routed_scaling == 1.0
+                 and not self.shared_hidden_dim and experts_held is None)
+        if not plain and (capacity_factor is not None or expert != "swiglu"):
+            raise ValueError(
+                "scoring, score_bias, n_group, routed_scaling, "
+                "shared_hidden_dim and experts_held belong to the dropless "
+                "SwiGLU op (capacity_factor=None, expert='swiglu')")
+        if num_experts % self.n_group or not (
+                1 <= self.topk_group <= self.n_group):
+            raise ValueError(
+                f"n_group {n_group} must divide num_experts {num_experts} "
+                f"and topk_group {topk_group} lie in 1..n_group")
+        if not (0 <= self.held_first and self.held_count >= 1
+                and self.held_first + self.held_count <= num_experts):
+            raise ValueError(
+                f"experts_held {experts_held}: (first, count) inside "
+                f"0..{num_experts}")
         self.dim = inputs[0].dims[-1]
         self.capacity = None            # dropless: no buffer to size
         if capacity_factor is not None:
@@ -129,18 +175,30 @@ class MoE(Op):
 
     def weights(self) -> List[WeightSpec]:
         E, D, F = self.num_experts, self.dim, self.hidden_dim
+        H = self.held_count         # the expert matrices this layer holds
         up = ("w_gate", "w_up") if self.expert == "swiglu" else ("w_in",)
         down = "w_down" if self.expert == "swiglu" else "w_out"
-        return [WeightSpec("router", (D, E), init="glorot", fan=(D, E))] + [
-            WeightSpec(w, (E, D, F), init="glorot", fan=(D, F)) for w in up
-        ] + [WeightSpec(down, (E, F, D), init="glorot", fan=(F, D))]
+        ws = [WeightSpec("router", (D, E), init="glorot", fan=(D, E))] + [
+            WeightSpec(w, (H, D, F), init="glorot", fan=(D, F)) for w in up
+        ] + [WeightSpec(down, (H, F, D), init="glorot", fan=(F, D))]
+        if self.score_bias is not None:
+            ws.append(WeightSpec("score_bias", (E,), init="normal",
+                                 init_args=(0.0, float(self.score_bias))))
+        if self.shared_hidden_dim:
+            Fs = self.shared_hidden_dim
+            ws += [WeightSpec("shared_gate", (D, Fs), init="glorot"),
+                   WeightSpec("shared_up", (D, Fs), init="glorot"),
+                   WeightSpec("shared_down", (Fs, D), init="glorot")]
+        return ws
+
+    _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down", "w_in", "w_out")
 
     def _expert_ffn(self, params, x, mm):
         """Every expert's FFN on its own rows; `mm(rows, w)` multiplies
         each row by its expert's matrix (a batched einsum over the
         capacity buffer, a grouped matmul over the sorted rows)."""
         w = {k: v.astype(x.dtype) for k, v in params.items()
-             if k != "router"}
+             if k in self._EXPERT_WEIGHTS}
         if self.expert == "swiglu":
             return mm(jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"]),
                       w["w_down"])
@@ -154,7 +212,7 @@ class MoE(Op):
         # experts actually shard over the 'expert' axis (all-to-all lowering)
         ep = (mesh is not None and "expert" in getattr(mesh, "axis_names", ())
               and mesh.shape["expert"] > 1
-              and self.num_experts % mesh.shape["expert"] == 0)
+              and self.held_count % mesh.shape["expert"] == 0)
         return not ep
 
     def forward(self, params, xs, *, training=False, rng=None,
@@ -183,13 +241,11 @@ class MoE(Op):
         N = t.shape[0]
         C = capacity if capacity is not None else self.capacity
 
+        if self.dropless:
+            return self._forward_dropless(params, t, orig_shape, row_mask,
+                                          routing, training, lowerings)
         logits = t @ params["router"].astype(t.dtype)       # (N, E)
         gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-        if self.dropless:
-            return self._forward_dropless(params, t, gates, orig_shape,
-                                          row_mask, routing, training,
-                                          lowerings)
         if self._use_sort_dispatch():
             return self._forward_sort(params, t, gates, orig_shape,
                                       capacity=C)
@@ -284,18 +340,69 @@ class MoE(Op):
             1 if mesh is None else mesh.size, n_tokens, self.dim,
             self.hidden_dim, dtype)
 
-    def _forward_dropless(self, params, t, gates, orig_shape, row_mask,
-                          routing, training, lowerings):
-        """No capacity, no dropped token: top-k of the f32 softmax, the
-        experts over exactly the chosen (token, expert) pairs (grouped or
-        streamed, see the module docstring), each token's k results
-        weighted by its gates and summed. A row's output depends on no
-        other row."""
+    def _route(self, params, t):
+        """The dropless op's one routing function: (scores (N, E) f32,
+        top_g (N, k) f32 gates, top_e (N, k) int32 experts) over ALL
+        `num_experts`, whichever of them this layer holds.
+
+        softmax: top-k of the f32 softmax of a compute-dtype matmul (OLMoE).
+        sigmoid: s = sigmoid(t W_r), the matmul in f32; the selection runs on
+        s' = s + score_bias and, with n_group > 1, only inside the
+        `topk_group` groups whose two best s' sum highest; the gates are
+        s (never s') of the chosen experts, renormalised over them and
+        times `routed_scaling` (DeepSeek-V3)."""
         E, k = self.num_experts, self.k
-        N = t.shape[0]
-        top_g, top_e = jax.lax.top_k(gates, k)              # (N, k)
+        if self.scoring == "softmax":
+            router = params["router"].astype(t.dtype)
+            scores = jax.nn.softmax((t @ router).astype(jnp.float32),
+                                    axis=-1)
+        else:
+            # float32 inputs, as the published gate computes it: a bf16
+            # product's rounding would flip choices at near-ties that the
+            # model itself does not have
+            scores = jax.nn.sigmoid(jnp.dot(
+                t.astype(jnp.float32), params["router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+        sel = scores
+        if self.score_bias is not None:
+            sel = sel + params["score_bias"].astype(jnp.float32)
+        if self.n_group > 1:
+            G = self.n_group
+            grouped = sel.reshape(-1, G, E // G)
+            gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            kept = jax.lax.top_k(gscore, self.topk_group)[1]    # (N, tg)
+            in_kept = jnp.any(kept[:, :, None] == jnp.arange(G), axis=1)
+            sel = jnp.where(jnp.repeat(in_kept, E // G, axis=1), sel,
+                            -jnp.inf)
+        if sel is scores:
+            top_g, top_e = jax.lax.top_k(scores, k)         # (N, k)
+        else:
+            top_e = jax.lax.top_k(sel, k)[1]
+            top_g = jnp.take_along_axis(scores, top_e, axis=-1)
         if self.renormalize:
             top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)
+        if self.routed_scaling != 1.0:
+            top_g = top_g * self.routed_scaling
+        return scores, top_g, top_e
+
+    def _shared_expert(self, params, t):
+        """The SwiGLU expert every row passes through."""
+        g, u, d = (params[n].astype(t.dtype)
+                   for n in ("shared_gate", "shared_up", "shared_down"))
+        return (jax.nn.silu(t @ g) * (t @ u)) @ d
+
+    def _forward_dropless(self, params, t, orig_shape, row_mask, routing,
+                          training, lowerings):
+        """No capacity, no dropped token: `_route`'s top-k, the held
+        experts over exactly the (token, expert) pairs that chose them
+        (grouped or streamed, see the module docstring), each token's
+        results weighted by its gates and summed, plus the shared expert
+        where the layer has one. A row's output depends on no other
+        row."""
+        k = self.k
+        N = t.shape[0]
+        gates, top_g, top_e = self._route(params, t)
+        lo, E = self.held_first, self.held_count
         live = None if row_mask is None else row_mask.reshape(N)
         took = self.lowering(N, training, t.dtype)
         if lowerings is not None:
@@ -307,19 +414,34 @@ class MoE(Op):
             routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
                            .astype(jnp.int32))
         me = jnp.mean(gates, axis=0)
+        if E != self.num_experts:
+            me = me[lo:lo + E]
         ce = sizes.astype(jnp.float32) / N
         aux = self.aux_weight * E * jnp.sum(me * (ce / k))
-        return [y.astype(t.dtype).reshape(orig_shape),
-                aux.astype(jnp.float32)]
+        y = y.astype(t.dtype)
+        if self.shared_hidden_dim:
+            y = y + self._shared_expert(params, t)
+        return [y.reshape(orig_shape), aux.astype(jnp.float32)]
 
     def _experts_grouped(self, params, t, top_g, top_e, live):
         """(y (N, D) f32, sizes (E,) int32): the N*k assignments
         stable-sorted by expert, grouped matmuls over exactly those rows,
         unsorted, gate-weighted and summed per token."""
-        E, k = self.num_experts, self.k
+        lo, E, k = self.held_first, self.held_count, self.k
         N, D = t.shape
         flat_e = top_e.reshape(-1)                          # token-major
-        if live is not None:
+        masked = live is not None
+        if E != self.num_experts:
+            # assignments to experts held elsewhere, like a dead row's, go
+            # to no expert here
+            masked = True
+            flat_e = flat_e - lo
+            here = (flat_e >= 0) & (flat_e < E)
+            if live is not None:
+                here &= jnp.repeat(live, k)
+            flat_e = jnp.where(here, flat_e, E)
+            top_g = top_g * here.reshape(N, k)
+        elif live is not None:
             # a dead row's k assignments go to no expert: the id E sorts
             # behind every group and bincount drops it
             flat_e = jnp.where(jnp.repeat(live, k), flat_e, E)
@@ -332,7 +454,7 @@ class MoE(Op):
         rows = t[order // k]                                # (N*k, D)
         out = self._expert_ffn(
             params, rows, lambda x, w: jax.lax.ragged_dot(x, w, sizes))
-        if live is not None:
+        if masked:
             # rows past the last group belong to no expert; what a grouped
             # matmul leaves there is unspecified
             out = jnp.where((jnp.arange(N * k) < jnp.sum(sizes))[:, None],
@@ -351,7 +473,10 @@ class MoE(Op):
         from flexflow_tpu.ops.pallas_kernels import (
             MOE_NOT_CHOSEN, moe_expert_stream_pallas)
 
-        picked = top_e[:, :, None] == jnp.arange(self.num_experts)
+        held = jnp.arange(self.held_count)
+        if self.held_count != self.num_experts:
+            held = held + self.held_first
+        picked = top_e[:, :, None] == held
         if live is not None:                                # (N, k, E)
             picked &= live[:, None, None]
         gmat = jnp.max(jnp.where(picked, top_g[:, :, None], MOE_NOT_CHOSEN),
@@ -366,7 +491,7 @@ class MoE(Op):
         return list(range(self.outputs[0].num_dims - 1))
 
     def expert_parallel_size(self):
-        return self.num_experts
+        return self.held_count
 
     def weight_partition(self, axis_map):
         from flexflow_tpu.parallel.pconfig import EXPERT
@@ -381,18 +506,23 @@ class MoE(Op):
             if (mesh_axes is not None
                     and "expert" in getattr(mesh_axes, "axis_names", ())
                     and mesh_axes.shape["expert"] > 1
-                    and self.num_experts % mesh_axes.shape["expert"] == 0):
+                    and self.held_count % mesh_axes.shape["expert"] == 0):
                 eaxes = ["expert"]
         e = None if not eaxes else (eaxes[0] if len(eaxes) == 1
                                     else tuple(eaxes))
-        return {w.name: P(None, None) if w.name == "router"
-                else P(e, None, None) for w in self.weight_specs()}
+        # the router, the selection bias and the shared expert stay whole
+        return {w.name: P(e, None, None) if w.name in self._EXPERT_WEIGHTS
+                else P(*([None] * len(w.shape)))
+                for w in self.weight_specs()}
 
     def flops(self):
         ntokens = self.inputs[0].volume() // self.dim
         matmuls = 3 if self.expert == "swiglu" else 2
-        return (2 * matmuls * ntokens * self.k * self.dim
-                * self.hidden_dim)
+        # the share of a token's k picks that lands on the experts held here
+        routed = ntokens * self.k * self.held_count / self.num_experts
+        return int(2 * matmuls * self.dim
+                   * (routed * self.hidden_dim
+                      + ntokens * self.shared_hidden_dim))
 
     def input_axis_map(self, axis_map, input_idx):
         # negative sentinels (CONTRACT/STAGE/EXPERT) must not leak into the

@@ -1200,3 +1200,228 @@ def moe_expert_stream_pallas(x, gates, sizes, w_gate, w_up, w_down):
     )(sizes.astype(jnp.int32), x, gates.astype(jnp.float32), w_gate, w_up,
       w_down)
     return out[:n]
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged decode with a learned sparse selection (DeepSeek-V3.2)
+# ---------------------------------------------------------------------------
+#
+# The cached state of a token is ONE latent row shared by all heads
+# ([cKV ; kR ; zero pad], ops/mla.py) and one index key. A decode step reads
+# both pools in place, in two kernels built like `_paged_attn_kernel` (grid
+# over slots, a loop to the slot's last live page, page DMAs issued by hand
+# through a ring that runs ahead across slot boundaries):
+#
+#   dsa_index_scores  a page's index keys (ps, dI) against the slot's J index
+#                     queries: I_s = sum_j w_j ReLU(qI_j . kI_s), one f32 row
+#                     of scores per slot, -inf where the live rule excludes s.
+#   mla_paged_core    the H absorbed queries (H, W) against a page's latents
+#                     (ps, W) once: one matmul scores all heads, the selection
+#                     (score above the row's threshold, ties by position:
+#                     ops/mla.py `dsa_threshold`) and the live rule mask the
+#                     columns, and the online softmax accumulates p @ cKV.
+#
+# Between them the k-th largest score of each row is found by XLA
+# (`dsa_threshold`: 12 passes over a (slots, context) f32 array). The
+# core streams EVERY live page under the mask: a page of 128 tokens nearly
+# always holds a selected one (2048 of up to 33 k), so skipping pages would
+# save little; its useful share is `mla_core_roofline_share`.
+
+
+def _page_stream(pt_ref, lp_ref, hbm, buf, sem, cur, nbuf):
+    """The hand-issued page stream of one pool array: (prime, take). `cur`
+    (SMEM, kept across grid steps) holds [slot, page] of the next page to
+    fetch and [2] the pages consumed; page n of the stream lives in buffer
+    n % nbuf and fetches run nbuf - 1 pages ahead, across slots."""
+    nb = pl.num_programs(0)
+
+    def copy(page, b):
+        return pltpu.make_async_copy(hbm.at[page], buf.at[b], sem.at[b])
+
+    def fetch_next(b):
+        fs, fp = cur[0], cur[1]
+
+        @pl.when(fs < nb)
+        def _():
+            copy(pt_ref[fs, fp], b).start()
+            more = fp < lp_ref[fs]
+            cur[0] = jnp.where(more, fs, fs + 1)
+            cur[1] = jnp.where(more, fp + 1, 0)
+
+    def prime():
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            for i in range(3):
+                cur[i] = 0
+            for i in range(nbuf - 1):
+                fetch_next(i)
+
+    def take():
+        """The next page of the stream, waited for: its ring buffer."""
+        n = cur[2]
+        cur[2] = n + 1
+        fetch_next((n + nbuf - 1) % nbuf)
+        b = n % nbuf
+        copy(0, b).wait()
+        return b
+
+    return prime, take
+
+
+def _stream_ring(ps: int, width: int, dtype) -> int:
+    """Page buffers of a (ps, width) page stream: 2 .. _PAGED_RING_MAX."""
+    page = ps * -(-width // LANES) * LANES * jnp.dtype(dtype).itemsize
+    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // page)))
+
+
+def _live_columns(t, ps, rl, pp, wp):
+    """(1, ps) token index of page t's columns and the live rule on them."""
+    j = t * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+    return j, (j < rl) | ((j >= pp) & (j <= wp))
+
+
+def _dsa_index_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
+                      k_hbm, o_ref, k_buf, sem, cur, *, ps: int, nbuf: int):
+    b = pl.program_id(0)
+    prime, take = _page_stream(pt_ref, lp_ref, k_hbm, k_buf, sem, cur, nbuf)
+    prime()
+    q = q_ref[0]                                        # (J, dI)
+    w = w_ref[0]                                        # (J, 1) f32
+    rl, pp, wp = rl_ref[b], pp_ref[b], wp_ref[b]
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, jnp.float32)
+
+    def one_page(t, carry):
+        buf = take()
+        k = k_buf[buf].astype(q.dtype)                  # (ps, dI)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        # + 0.0: a -0.0 would order below +0.0 in the threshold's key
+        sc = jnp.sum(jnp.maximum(s, 0.0) * w, axis=0, keepdims=True) + 0.0
+        _, live = _live_columns(t, ps, rl, pp, wp)
+        o_ref[0, pl.ds(t, 1), :] = jnp.where(live, sc, -jnp.inf)
+        return carry
+
+    jax.lax.fori_loop(0, lp_ref[b] + 1, one_page, 0)
+
+
+def _mla_core_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, tc_ref, q_ref,
+                     thr_ref, sc_ref, lat_hbm, o_ref, lat_buf, sem, cur, *,
+                     ps: int, nbuf: int, scale: float, c: int):
+    b = pl.program_id(0)
+    prime, take = _page_stream(pt_ref, lp_ref, lat_hbm, lat_buf, sem, cur,
+                               nbuf)
+    prime()
+    q = q_ref[0]                                        # (H, W)
+    thr = thr_ref[0]                                    # (1, 1) f32
+    rl, pp, wp, tc = rl_ref[b], pp_ref[b], wp_ref[b], tc_ref[b]
+    h = q.shape[0]
+
+    def one_page(t, carry):
+        m_prev, l_prev, acc = carry
+        buf = take()
+        page = lat_buf[buf].astype(q.dtype)             # (ps, W)
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, ps)
+        isc = sc_ref[0, pl.ds(t, 1), :]                 # (1, ps)
+        j, live = _live_columns(t, ps, rl, pp, wp)
+        chosen = live & ((isc > thr) | ((isc == thr) & (j <= tc)))
+        s = jnp.where(chosen, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(page.dtype), page[:, :c],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    _, l_fin, acc = jax.lax.fori_loop(
+        0, lp_ref[b] + 1, one_page,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, c), jnp.float32)))
+    # the row's own token is live and chosen (all live tokens are while
+    # they are at most top-k, the top-k otherwise), so l > 0; a page with
+    # no chosen column before the first chosen one is scaled away (alpha 0)
+    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+
+
+def _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps):
+    last = jnp.maximum(write_pos, row_len - 1) // ps
+    return [page_table.astype(jnp.int32), last.astype(jnp.int32),
+            write_pos.astype(jnp.int32), row_len.astype(jnp.int32),
+            prompt_pad.astype(jnp.int32)]
+
+
+def dsa_index_scores_pallas(qi, w, ki_pages, page_table, write_pos, row_len,
+                            prompt_pad):
+    """Index scores of one decode step, read from the pool in place: qi
+    (B, J, dI), w (B, J) f32, ki_pages (P_pool, ps, dI), page tables
+    (B, P) -> (B, P * ps) f32, I_s = sum_j w_j ReLU(qi_j . kI_s) at the
+    slot's live positions (j < row_len or prompt_pad <= j <= write_pos)
+    and -inf elsewhere."""
+    b, jn, di = qi.shape
+    ps = ki_pages.shape[1]
+    p = page_table.shape[1]
+    nbuf = _stream_ring(ps, di, ki_pages.dtype)
+
+    def slot_map(bi, *_):
+        return (bi, 0, 0)
+
+    prefetch = _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=(b,),
+        in_specs=[pl.BlockSpec((1, jn, di), slot_map),
+                  pl.BlockSpec((1, jn, 1), slot_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, p, ps), slot_map),
+        scratch_shapes=[pltpu.VMEM((nbuf, ps, di), ki_pages.dtype),
+                        pltpu.SemaphoreType.DMA((nbuf,)),
+                        pltpu.SMEM((3,), jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_dsa_index_kernel, ps=ps, nbuf=nbuf),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, p, ps), jnp.float32),
+        compiler_params=_compiler_params(("arbitrary",)),
+        interpret=_interpret(), name="dsa_index_scores",
+    )(*prefetch, qi, w.astype(jnp.float32)[:, :, None], ki_pages)
+    return out.reshape(b, p * ps)
+
+
+def mla_paged_core_pallas(q_lat, scores, thr, tie_cut, lat_pages, page_table,
+                          write_pos, row_len, prompt_pad, scale: float,
+                          c: int):
+    """The attention core of one decode step over the latent pool: q_lat
+    (B, H, W) absorbed queries, lat_pages (P_pool, ps, W), `scores`
+    (B, P * ps) the step's index scores with each row's threshold `thr`
+    (B,) f32 and `tie_cut` (B,) int32 -> (B, H, c): softmax over the live
+    positions whose score is above thr, or equal to it at a position <=
+    tie_cut, of q . latent * scale, times the latents' first c columns."""
+    b, h, wdt = q_lat.shape
+    ps = lat_pages.shape[1]
+    p = page_table.shape[1]
+    nbuf = _stream_ring(ps, wdt, lat_pages.dtype)
+
+    def slot_map(bi, *_):
+        return (bi, 0, 0)
+
+    prefetch = _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps)
+    prefetch.append(tie_cut.astype(jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, wdt), slot_map),
+                  pl.BlockSpec((1, 1, 1), slot_map),
+                  pl.BlockSpec((1, p, ps), slot_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, c), slot_map),
+        scratch_shapes=[pltpu.VMEM((nbuf, ps, wdt), lat_pages.dtype),
+                        pltpu.SemaphoreType.DMA((nbuf,)),
+                        pltpu.SMEM((3,), jnp.int32)])
+    return pl.pallas_call(
+        functools.partial(_mla_core_kernel, ps=ps, nbuf=nbuf, scale=scale,
+                          c=c),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
+        compiler_params=_compiler_params(("arbitrary",)),
+        interpret=_interpret(), name="mla_paged_core",
+    )(*prefetch, q_lat, thr.astype(jnp.float32)[:, None, None],
+      scores.reshape(b, p, ps), lat_pages)

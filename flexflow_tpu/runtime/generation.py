@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from flexflow_tpu.ffconst import DataType, OperatorType
-from flexflow_tpu.ops.attention import MultiHeadAttention
 from flexflow_tpu.ops.base import InputOp
 from flexflow_tpu.runtime.executor import resolve_tied_params
 
@@ -136,11 +135,11 @@ class Generator:
         for op in model.ops:
             if isinstance(op, InputOp):
                 continue
-            if isinstance(op, MultiHeadAttention):
+            if getattr(op, "kv_cache_protocol", False):
                 if not op.causal:
                     raise ValueError(
                         f"{op.name}: generate() requires causal attention")
-                if not (op.inputs[0] is op.inputs[1] is op.inputs[2]):
+                if any(t is not op.inputs[0] for t in op.inputs):
                     raise ValueError(
                         f"{op.name}: generate() supports self-attention "
                         "only (q, k, v must be the same tensor)")
@@ -315,7 +314,7 @@ class Generator:
             if bf16:
                 p = {k: to_compute(v) for k, v in p.items()}
             with jax.named_scope(op.name):
-                if isinstance(op, MultiHeadAttention):
+                if getattr(op, "kv_cache_protocol", False):
                     cache = caches[op.name]
                     if paged is not None:
                         # continuous-batching slot decode over the paged
@@ -428,6 +427,17 @@ class Generator:
         — right-padding keeps this sound: a real position's causal window
         holds only real positions, and pad slots' garbage k/v are masked
         by row_lengths in the gather and in every decode step."""
+        # An attention op may ask (`prefill_chunk_barrier`) that each chunk
+        # end in a barrier, which keeps XLA from hoisting the NEXT chunks'
+        # projections above this chunk's work; models whose ops do not ask
+        # keep the program they had.
+        if any(getattr(op, "prefill_chunk_barrier", False)
+               for op in self.attn_ops):
+            def close(caches, tokens):
+                return jax.lax.optimization_barrier((caches, tokens))
+        else:
+            def close(caches, tokens):
+                return caches, tokens
         b, s0 = tokens.shape
         if not prefill_chunk or s0 <= prefill_chunk:
             return self._walk(params, state, tokens, caches, None,
@@ -444,6 +454,7 @@ class Generator:
                     caches, None, chunk_start=st, skip_tail=True, lora=lora,
                     row_lengths=row_lengths, routing=routing,
                     lowerings=lowerings)
+                caches, tokens = close(caches, tokens)
             tok_last = jnp.take_along_axis(
                 tokens, (row_lengths - 1)[:, None], axis=1)      # (B, 1)
             return self._walk(params, state, tok_last, caches, None,
@@ -455,6 +466,7 @@ class Generator:
                 params, state, tokens[:, st:st + prefill_chunk], caches,
                 None, chunk_start=st, skip_tail=True, lora=lora,
                 routing=routing, lowerings=lowerings)
+            caches, tokens = close(caches, tokens)
         st = starts[-1]
         return self._walk(params, state, tokens[:, st:], caches, None,
                           last_only=True, chunk_start=st, lora=lora,

@@ -1031,13 +1031,16 @@ class KVPagePool:
                     f" exporter and importer must run the same "
                     f"model")
             pool = self.pool[op.name]
-            pk = np.asarray(sub["k"])
-            if pk.dtype != pool["k"].dtype \
-                    or pk.shape != pool["k"].shape[1:]:
+            first = next(iter(pool))        # "k", or a latent op's "lat"
+            pk = np.asarray(sub[first]) if first in sub else None
+            if pk is None or pk.dtype != pool[first].dtype \
+                    or pk.shape != pool[first].shape[1:]:
                 raise ValueError(
-                    f"slab payload for {op.name!r} is {pk.dtype}"
-                    f"{pk.shape} but this engine's pool stores "
-                    f"{pool['k'].dtype}{pool['k'].shape[1:]}: fleet "
+                    f"slab payload for {op.name!r} is "
+                    f"{getattr(pk, 'dtype', None)}"
+                    f"{getattr(pk, 'shape', sorted(sub))} but this "
+                    f"engine's pool stores "
+                    f"{pool[first].dtype}{pool[first].shape[1:]}: fleet "
                     f"replicas must share kv_cache_dtype and pool "
                     f"geometry")
             if ("k_scale" in pool) != ("k_scale" in sub):

@@ -495,8 +495,7 @@ class ServingEngine:
         self._kv_bytes_per_token = (
             self._pool_bytes / (self.num_pages * self.page_size))
         self._bf16_bytes_per_token = sum(
-            op.num_kv_heads * (op.qk_head_dim + op.v_head_dim) * 2
-            for op in self.gen.attn_ops)
+            op.cache_bytes_per_token() for op in self.gen.attn_ops)
 
         # decode attention impl over the paged pool: the per-engine
         # override wins, else FFConfig.paged_attention_impl; resolved
@@ -524,14 +523,15 @@ class ServingEngine:
         # lookup too — the bench stamps it as proof the dtype-keyed
         # entry governed an 'auto' engine
         self._ktune_base = kernel_tune.stats()
-        if requested == "auto":
-            op0 = self.gen.attn_ops[0]
+        # an op whose paged kernels are not the autotuner's has no entry in
+        # its table and says so with None
+        op0 = self.gen.attn_ops[0]
+        tune_key = op0.paged_kernel_shape(self.kv.pool[op0.name])
+        if requested == "auto" and tune_key is not None:
             tuned = kernel_tune.lookup_paged_impl(
                 page_size=self.page_size,
                 pages_per_slot=self.pages_per_slot,
-                head_dim=op0.qk_head_dim,
-                dtype=self.kv.pool[op0.name]["k"].dtype,
-                batch=self.slots, heads=op0.num_heads)
+                batch=self.slots, **tune_key)
             if tuned is not None:
                 self.paged_attention_impl = tuned
         # prefill/append page-scatter impl (ISSUE 18): the same knob
@@ -544,14 +544,11 @@ class ServingEngine:
         # shape overrides the backend default, same as decode above.
         self.paged_prefill_impl = resolve_paged_attention_impl(
             requested, cfg)
-        if requested == "auto":
-            op0 = self.gen.attn_ops[0]
+        if requested == "auto" and tune_key is not None:
             tuned_pf = kernel_tune.lookup_paged_prefill_impl(
                 page_size=self.page_size,
                 pages_per_slot=self.pages_per_slot,
-                head_dim=op0.qk_head_dim,
-                dtype=self.kv.pool[op0.name]["k"].dtype,
-                batch=self.slots, heads=op0.num_heads)
+                batch=self.slots, **tune_key)
             if tuned_pf is not None:
                 self.paged_prefill_impl = tuned_pf
         fflogger.info(
@@ -732,6 +729,20 @@ class ServingEngine:
         # / prefill dispatches of programs whose calls all streamed
         self._moe_took: Dict = {}
         self._moe_streamed_dispatches = 0
+        # what the attention ops count of their own decode dispatches
+        # (`decode_span_counts`: a selecting attention's index bytes, kept
+        # and seen tokens; nothing for plain attention, whose engines then
+        # skip the count), and the prompt tokens admissions found cached /
+        # were asked to prefill
+        none_live = np.zeros((0, 1), np.int64)
+        self._counting_attn_ops = [
+            op for op in self.gen.attn_ops
+            if op.decode_span_counts(none_live)]
+        self._attn_counts: Dict[str, int] = {}
+        for op in self._counting_attn_ops:
+            self._attn_counts.update(op.decode_span_counts(none_live))
+        self._prefix_hit_tokens = 0
+        self._prefix_prompt_tokens = 0
 
         # ---- unified telemetry plane (ISSUE 13) ----
         # the engine's latency histograms (TTFT / inter-token / queue
@@ -1183,7 +1194,7 @@ class ServingEngine:
             g = op.gather_paged_kv(pool[op.name], prefix_pages)
             caches[op.name] = {
                 name: c[name].at[:, :p0].set(g[name].astype(c[name].dtype))
-                for name in ("k", "v")}
+                for name in c}
         return caches
 
     def _scatter_tail(self, gen, pool, caches, pages, p0: int = 0):
@@ -1196,9 +1207,8 @@ class ServingEngine:
         a perf knob — resolution happens at TRACE time inside the
         prefill builders, warm programs pay nothing."""
         return {
-            op.name: op.paged_prefill_write(
-                pool[op.name], caches[op.name]["k"][:, p0:],
-                caches[op.name]["v"][:, p0:], pages,
+            op.name: op.scatter_cache_tail(
+                pool[op.name], caches[op.name], p0, pages,
                 impl=self.paged_prefill_impl)
             for op in gen.attn_ops}
 
@@ -1839,7 +1849,11 @@ class ServingEngine:
                             kind="hit" if full else "cold",
                             bucket=req.bucket,
                             prompt_tokens=int(req.prompt.size),
-                            matched_pages=full) as psp:
+                            matched_pages=full,
+                            tail_tokens=int(req.prompt.size)
+                            - req.prefix_tokens) as psp:
+                self._prefix_hit_tokens += req.prefix_tokens
+                self._prefix_prompt_tokens += int(req.prompt.size)
                 self._seed_slot(slot, req, poison)
                 tok, ok, routed = self._run_prefill(
                     req.prompt, req.bucket, lease,
@@ -2273,6 +2287,16 @@ class ServingEngine:
                            + 1)[self.active].sum())
             kv_read = self._note_pages_touched(
                 write_pos[:, None] + np.arange(k), budget)
+            attn = collections.Counter()
+            if self._counting_attn_ops:
+                # per live row and step, the tokens its attention may see
+                # (`context` above is the first step's column, summed)
+                seen = (np.minimum(write_pos[:, None] + np.arange(k),
+                                   (budget - 1)[:, None]) + 1)[self.active]
+                for op in self._counting_attn_ops:
+                    attn.update(op.decode_span_counts(seen))
+                for name, v in attn.items():
+                    self._attn_counts[name] += v
             # per-slot draw counters: the next token's index is exactly
             # the count already emitted — slot- and replica-invariant,
             # so a failover replay reproduces the stream
@@ -2282,7 +2306,8 @@ class ServingEngine:
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
-                        context_tokens=context, kv_read_bytes=kv_read) as sp:
+                        context_tokens=context, kv_read_bytes=kv_read,
+                        **attn) as sp:
             key = ("decode", k)
             toks, oks, self.kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_decode(k, self._moe_took_list(key)),
@@ -2796,6 +2821,9 @@ class ServingEngine:
             # otherwise): experts_hit / (experts x MoE layers x
             # decode_steps) is the share of the expert weights a step
             # streams
+            "prefix_hit_tokens": self._prefix_hit_tokens,
+            "prefix_prompt_tokens": self._prefix_prompt_tokens,
+            **self._attn_counts,
             "moe_assignments": self._moe_assignments,
             "moe_experts_hit": self._moe_experts_hit,
             # decode and run-to-completion prefill dispatches whose

@@ -1,0 +1,476 @@
+"""DeepSeek-V3.2's decoder through the normal path (models/deepseek_v32.py ->
+compile() -> predict / generate / make_serving_engine) against the plain
+reference (tests/reference_deepseek_v32.py, the same text as
+benchmark/reference/deepseek_v32.py), at a tiny size that keeps every ratio
+(4 heads, latent 32 + rope 16, 4 index heads of 32, index_topk 16 against
+contexts of 8 to 96, 16 experts in 4 groups of which 2 are kept, top-4, 4
+held), in float32 on the CPU; and the mechanisms it forced, each alone: the
+latent attention op and its selection (ops/mla.py), the two decode kernels
+(ops/pallas_kernels.py, interpret mode), the router's form and the held
+experts (ops/moe.py).
+
+Logits are compared, never tokens: with random weights the largest logit
+changes on rounding. Every tolerance stands beside its reason.
+"""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import reference_deepseek_v32 as ref
+from flexflow_tpu import FFConfig, FFModel
+from flexflow_tpu.models.deepseek_v32 import YARN, deepseek_v32_lm
+from flexflow_tpu.ops import mla
+from flexflow_tpu.ops.mla import LatentAttention
+from flexflow_tpu.ops.moe import MoE
+
+VOCAB, SEQ, TOPK = 128, 96, 16
+HELD = (4, 4)
+SIZES = dict(num_hidden_layers=3, first_k_dense_replace=1, rms_norm_eps=1e-6,
+             rope_theta=1e4, rope_scaling=YARN, qk_nope_head_dim=32,
+             qk_rope_head_dim=16, kv_lora_rank=32, index_topk=TOPK,
+             num_experts_per_tok=4, n_group=4, topk_group=2,
+             routed_scaling_factor=2.5, norm_topk_prob=True,
+             experts_held=HELD)
+
+# float32 program against the float32 reference: both round every matmul to
+# 2^-24 relative in different orders (absorbed against expanded, grouped
+# against dense), logits of order 1. Measured 4e-6; bf16 compute lands near
+# 1e-2.
+LOGIT_ATOL = 5e-5
+
+
+def build(batch=2, seq=SEQ, seed=3, gain=1.0, held=HELD, topk=TOPK):
+    cfg = FFConfig(batch_size=batch, mesh_shape={"data": 1}, seed=seed)
+    ff = FFModel(cfg)
+    _, logits = deepseek_v32_lm(
+        ff, batch, seq_len=seq, hidden=64, layers=3, heads=4, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=32, qk_rope_head_dim=16,
+        v_head_dim=32, index_n_heads=4, index_head_dim=32, index_topk=topk,
+        dense_layers=1, ffn_hidden=128, num_experts=16, experts_per_token=4,
+        expert_hidden=32, n_group=4, topk_group=2, experts_held=held,
+        score_bias_std=0.05, uq_init_gain=gain, vocab_size=VOCAB)
+    ff.compile(final_tensor=logits)
+    # norm scales initialise to one and the index key's bias to zero, where
+    # a missing or misplaced one would pass: spread them
+    rs = np.random.RandomState(seed)
+    for op, ws in ff.params.items():
+        for w, v in ws.items():
+            if w in ("scale", "q_norm", "kv_norm", "ik_norm_scale"):
+                ff.set_weights(op, w, (1 + 0.3 * rs.randn(*v.shape))
+                               .astype(np.float32))
+            elif w == "ik_norm_bias":
+                ff.set_weights(op, w, (0.3 * rs.randn(*v.shape))
+                               .astype(np.float32))
+    return ff
+
+
+@pytest.fixture(scope="module")
+def ff():
+    return build()
+
+
+def margins(ff, req, n):
+    """How far below the reference's maximum each emitted token's reference
+    logit lies, over one full pass of prompt + emitted tokens."""
+    full = np.asarray(req.output, np.int32)
+    assert full.size == req.prompt.size + n
+    rows = np.asarray(ref.forward(ff.params, full, SIZES,
+                                  rows=(req.prompt.size - 1, full.size - 1)))
+    return rows.max(axis=-1) - rows[np.arange(n), full[req.prompt.size:]]
+
+
+def test_graph_has_both_kinds_of_block(ff):
+    names = {op.name for op in ff.ops}
+    assert {"attn_0", "ffn_gate_0", "moe_1", "moe_2", "ln_f"} <= names
+    assert "moe_0" not in names and "ffn_gate_1" not in names
+    attn, moe = ff.get_op_by_name("attn_1"), ff.get_op_by_name("moe_1")
+    assert isinstance(attn, LatentAttention) and attn.kv_cache_protocol
+    assert attn.lat_width == 128 and attn.index_topk == TOPK
+    assert (moe.scoring, moe.n_group, moe.topk_group, moe.routed_scaling) \
+        == ("sigmoid", 4, 2, 2.5)
+    assert (moe.held_first, moe.held_count) == HELD
+    assert ff.params["moe_1"]["router"].shape == (64, 16)
+    assert ff.params["moe_1"]["w_gate"].shape == (4, 64, 32)
+    assert ff.params["moe_1"]["shared_down"].shape == (32, 64)
+    assert float(jnp.std(ff.params["moe_1"]["score_bias"])) > 0.01
+
+
+def test_predict_logits_match_reference(ff):
+    """The EXPANDED program (forward) against the reference, over a context
+    six times index_topk: the selection is real for most rows."""
+    toks = np.random.RandomState(0).randint(1, VOCAB, (2, SEQ)) \
+        .astype(np.int32)
+    got = np.asarray(ff.predict({"input": toks}))
+    for b in range(2):
+        want = np.asarray(ref.forward(ff.params, toks[b], SIZES))
+        np.testing.assert_allclose(got[b], want, atol=LOGIT_ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("rows", [None, (40, 70), (95, 96)])
+def test_reference_does_not_depend_on_its_blocks_or_rows(ff, monkeypatch,
+                                                         rows):
+    """Three spans of query rows with a key bound each, blocks of 16 rows,
+    and a last layer that computes the asked rows' blocks only: the same
+    logits as one span of whole blocks."""
+    toks = np.random.RandomState(5).randint(1, VOCAB, (SEQ,)).astype(np.int32)
+    want = np.asarray(ref.forward(ff.params, toks, SIZES))
+    for name, value in (("QUERY_BLOCK", 16), ("KEY_BLOCK", 32),
+                        ("ROW_BLOCK", 32)):
+        monkeypatch.setattr(ref, name, value)
+    trace = {}
+    got = np.asarray(ref.forward(ff.params, toks, SIZES, rows=rows,
+                                 trace=trace))
+    lo, hi = rows or (0, SEQ)
+    np.testing.assert_allclose(got, want[lo:hi], atol=1e-5, rtol=0)
+    held = [len(t) for t in trace["selected"]]
+    assert held == [SEQ, SEQ, -(-hi // 16) * 16 - lo // 16 * 16]
+
+
+def test_generate_scores_match_reference(ff):
+    """Prefill + decode through the contiguous latent cache (the ABSORBED
+    form), across index_topk: 10 prompt tokens, 20 emitted."""
+    prompt = np.random.RandomState(2).randint(1, VOCAB, (2, 10)) \
+        .astype(np.int32)
+    out, scores = ff.generate(prompt, max_new_tokens=20, return_scores=True)
+    for b in range(2):
+        logp = jax.nn.log_softmax(ref.forward(ff.params, out[b], SIZES))
+        want = [float(logp[9 + j, out[b, 10 + j]]) for j in range(20)]
+        np.testing.assert_allclose(scores[b], want, atol=2 * LOGIT_ATOL,
+                                   rtol=0)
+
+
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_serving_engine_emits_the_reference_argmax(ff, impl):
+    """Prefill (whole and in chunks of 32), both paged pools and decode
+    against the reference's full pass, at the level of logits, with contexts
+    below (8 + 24) and above (40, 96, 23 + 24) index_topk; `pallas` runs the
+    two decode kernels (interpret mode), `einsum` their oracle."""
+    eng = ff.make_serving_engine(serve_slots=4, kv_page_size=8,
+                                 max_seq_len=160, prefill_chunk=32,
+                                 decode_chunk=4, prefix_cache=False,
+                                 paged_attention_impl=impl)
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(1, VOCAB, (n,)).astype(np.int32)
+               for n in (40, 8, 96, 23)]
+    reqs = eng.run(prompts, max_new_tokens=24)
+    assert [r.state for r in reqs] == ["done"] * 4
+    for r in reqs:
+        # both sides hold the logit to LOGIT_ATOL: a near-tie flips within
+        # twice that
+        assert margins(ff, r, 24).max() <= 2 * LOGIT_ATOL
+    st = eng.stats()
+    assert st["dsa_context_tokens"] > st["dsa_selected_tokens"] > 0
+    assert st["index_read_bytes"] == st["dsa_context_tokens"] * 32 * 2
+    # page bytes: (lat 128 + index key 32) x f32, 3 layers
+    assert st["kv_bytes_per_token"] == (128 + 32) * 4 * 3
+
+
+def test_prefix_hit_prefill_matches_cold_prefill(ff):
+    """The same 88-token prompt cold, then again as a hit of its 10 full
+    pages (the tail's 8 rows against the gathered pages): the same tokens,
+    each on the reference's maximum."""
+    eng = ff.make_serving_engine(serve_slots=2, kv_page_size=8,
+                                 max_seq_len=160, decode_chunk=4,
+                                 prefix_cache=True)
+    prompt = np.random.RandomState(9).randint(1, VOCAB, (88,)) \
+        .astype(np.int32)
+    cold = eng.run([prompt], max_new_tokens=12)[0]
+    hit = eng.run([prompt], max_new_tokens=12)[0]
+    st = eng.stats()
+    assert st["prefix_hits"] == 1 and hit.prefix_tokens == 80
+    assert st["prefix_hit_tokens"] == 80 and st["prefix_prompt_tokens"] == 176
+    assert hit.tokens == cold.tokens
+    assert margins(ff, hit, 12).max() <= 2 * LOGIT_ATOL
+
+
+# ---- the attention op alone ------------------------------------------------
+
+def attention_op(topk=TOPK, gain=1.0, seq=48, batch=2):
+    ff = FFModel(FFConfig(batch_size=batch, mesh_shape={"data": 1}))
+    x = ff.create_tensor([batch, seq, 64], name="x")
+    op = LatentAttention(ff, "attn", [x], 64, 4, 48, 32, 32, 16, 32, 4, 32,
+                         topk, rope_scaling=YARN, uq_init_gain=gain)
+    rs = np.random.RandomState(1)
+    params = {}
+    for w in op.weight_specs():
+        scale = {"one": 0.3, "zero": 0.3}.get(w.init, w.shape[0] ** -0.5)
+        params[w.name] = jnp.asarray(
+            (w.init == "one") + scale * rs.randn(*w.shape), jnp.float32)
+    return op, params, jnp.asarray(rs.randn(batch, seq, 64), jnp.float32)
+
+
+def test_absorbed_form_matches_expanded_form():
+    """`forward` builds K and V per head; `prefill_forward` moves W_UK into
+    the query and W_UV into the output and attends the cached rows: the same
+    numbers up to float32 rounding (outputs of order 1)."""
+    op, params, x = attention_op()
+    expanded = op.forward(params, [x])[0]
+    absorbed, cache = op.prefill_forward(params, [x],
+                                         op.init_cache(2, 48, jnp.float32))
+    np.testing.assert_allclose(absorbed, expanded, atol=2e-5, rtol=0)
+    assert cache["lat"].shape == (2, 48, 128) and cache["ki"].shape \
+        == (2, 48, 32)
+    # the padding lanes of a latent row stay zero
+    assert not np.asarray(cache["lat"][..., 48:]).any()
+
+
+@pytest.mark.parametrize("least", [1, 40, 10 ** 6])
+def test_a_chunk_given_dead_keys_attends_what_it_attended(monkeypatch, least):
+    """A prefill chunk sees the cache's rows past its own end when
+    `_MIN_CHUNK_KEYS` says so: they are dead under the live rule, so the
+    first two chunks of a prompt give what the whole prompt gives."""
+    op, params, x = attention_op()
+    whole, _ = op.prefill_forward(params, [x],
+                                  op.init_cache(2, 64, jnp.float32))
+    monkeypatch.setattr(mla, "_MIN_CHUNK_KEYS", least)
+    cache = op.init_cache(2, 64, jnp.float32)
+    for start in (0, 16):
+        out, cache = op.chunk_forward(params, [x[:, start:start + 16]],
+                                      cache, start)
+        np.testing.assert_allclose(out, whole[:, start:start + 16],
+                                   atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("length, k, ties", [
+    (40, 16, False), (40, 16, True), (12, 16, False), (129, 1, True),
+    (64, 64, True), (300, 37, True)])
+def test_threshold_selects_exactly_top_k_ties_to_the_lower_position(
+        length, k, ties):
+    """`dsa_threshold` + `dsa_chosen` against `jax.lax.top_k` (which breaks
+    ties towards the lower index), with dead positions and, with `ties`,
+    scores drawn from five values so that the cut falls inside a run."""
+    rs = np.random.RandomState(length + k)
+    sc = rs.randn(3, length).astype(np.float32)
+    if ties:
+        sc = rs.randint(-2, 3, (3, length)).astype(np.float32)
+    sc[rs.rand(3, length) < 0.2] = -np.inf
+    sc[0, 0] = 1.0       # a row always holds its own token
+    got = np.asarray(mla.dsa_chosen(sc, *mla.dsa_threshold(
+        jnp.asarray(sc), k)))
+    want = np.zeros_like(got)
+    idx = np.asarray(jax.lax.top_k(jnp.asarray(sc), min(k, length))[1])
+    want[np.arange(3)[:, None], idx] = True
+    want &= sc > -np.inf
+    np.testing.assert_array_equal(got, want)
+
+
+def paged_state(op, params, tie: bool):
+    """Three slots over a pool of pages of 8: contexts 43, 9 and an idle
+    slot; with `tie`, the index keys of slot 0's pages 1-3 are one row
+    repeated, so 24 scores are equal and the cut of 16 falls among them."""
+    rs = np.random.RandomState(4)
+    pool = op.init_paged_cache(24, 8, jnp.float32)
+    pool = {n: jnp.asarray(rs.randn(*a.shape), jnp.float32)
+            for n, a in pool.items()}
+    pool["lat"] = pool["lat"].at[..., 48:].set(0.0)
+    table = np.zeros((3, 8), np.int32)
+    table[0, :6] = [3, 7, 1, 12, 9, 20]
+    table[1, :2] = [5, 2]
+    if tie:
+        pool["ki"] = pool["ki"].at[jnp.asarray([7, 1, 12])].set(
+            pool["ki"][7, 0])
+    write_pos = np.asarray([42, 8, 0], np.int32)
+    row_len = np.asarray([30, 5, 0], np.int32)      # 30..31 and 5..7: padding
+    prompt_pad = np.asarray([32, 8, 0], np.int32)
+    x = jnp.asarray(rs.randn(3, 1, 64), jnp.float32)
+    return pool, jnp.asarray(table), write_pos, row_len, prompt_pad, x
+
+
+@pytest.mark.parametrize("tie", [False, True])
+def test_pallas_decode_kernels_match_the_einsum_oracle(tie):
+    """`dsa_index_scores` and `mla_paged_core` (interpret mode) read the
+    pools through the page tables; the oracle gathers the pages and runs the
+    blocked XLA attention. Same appended rows, same output, also when the
+    selection's cut falls inside a run of equal scores."""
+    op, params, _ = attention_op(seq=1, batch=3)
+    pool, table, wp, rl, pp, x = paged_state(op, params, tie)
+    args = (params, [x], pool, table, jnp.asarray(wp), jnp.asarray(wp - 2),
+            jnp.asarray(rl), jnp.asarray(pp))
+    want, pool_e = op.paged_decode_forward(*args, impl="einsum")
+    got, pool_p = op.paged_decode_forward(*args, impl="pallas")
+    for n in ("lat", "ki"):
+        np.testing.assert_array_equal(pool_p[n], pool_e[n])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    if tie:
+        from flexflow_tpu.ops.pallas_kernels import dsa_index_scores_pallas
+
+        pr = op._project(params, x, jnp.asarray(wp - 2)[:, None])
+        sc = np.asarray(dsa_index_scores_pallas(
+            pr["qi"][:, 0], pr["w"][:, 0], pool_p["ki"], table,
+            jnp.asarray(wp), jnp.asarray(rl), jnp.asarray(pp)))
+        assert len(set(sc[0, 8:30])) == 1            # the run of ties
+        assert np.isinf(sc[0, 30:32]).all() and np.isinf(sc[0, 43:]).all()
+        chosen = np.asarray(mla.dsa_chosen(
+            sc, *mla.dsa_threshold(jnp.asarray(sc), TOPK)))[0]
+        tied = np.flatnonzero(chosen[8:30])
+        # what the cut takes of the run is its lowest positions
+        assert chosen.sum() == TOPK and 0 < tied.size < 22
+        np.testing.assert_array_equal(tied, np.arange(tied.size))
+
+
+def test_export_import_moves_a_page_of_both_pools():
+    op, params, _ = attention_op()
+    rs = np.random.RandomState(6)
+    pool = {n: jnp.asarray(rs.randn(*a.shape), jnp.float32)
+            for n, a in op.init_paged_cache(6, 8, jnp.float32).items()}
+    payload = op.export_page(pool, jnp.asarray([4, 2]))
+    assert payload["lat"].shape == (2, 8, 128) \
+        and payload["ki"].shape == (2, 8, 32)
+    other = op.init_paged_cache(6, 8, jnp.float32)
+    other = op.import_page(other, jnp.asarray([1, 5]), payload)
+    for n in ("lat", "ki"):
+        np.testing.assert_array_equal(other[n][1], pool[n][4])
+        np.testing.assert_array_equal(other[n][5], pool[n][2])
+        assert not np.asarray(other[n][0]).any()
+    got = op.gather_paged_kv(other, jnp.asarray([5, 1]))
+    np.testing.assert_array_equal(got["lat"][0, :8], pool["lat"][2])
+    assert got["ki"].shape == (1, 16, 32)
+
+
+def test_slab_export_import_round_trips_through_the_engine(ff):
+    """A prompt's pages leave one engine as a slab and serve a hit in
+    another: both pools of every layer travel."""
+    kw = dict(serve_slots=2, kv_page_size=8, max_seq_len=96, decode_chunk=4,
+              prefix_cache=True)
+    a, b = ff.make_serving_engine(**kw), ff.make_serving_engine(**kw)
+    prompt = np.random.RandomState(11).randint(1, VOCAB, (40,)) \
+        .astype(np.int32)
+    want = a.run([prompt], max_new_tokens=8)[0].tokens
+    slab = a.export_prefix_slab(prompt)
+    assert set(slab["payload"][0][("t", "attn_0")]) == {"lat", "ki"}
+    assert b.import_prefix_slab(slab) == 5
+    got = b.run([prompt], max_new_tokens=8)[0]
+    assert got.prefix_tokens == 32 and got.tokens == want
+
+
+def test_speculative_verify_is_refused_clearly():
+    op, params, x = attention_op()
+    with pytest.raises(NotImplementedError, match="speculative verify"):
+        op.paged_verify_forward(params, [x], None, None, None, None, None,
+                                None)
+
+
+# ---- the router's form and the held experts ---------------------------------
+
+def moe_op(held=None, bias=0.05, n=24, d=16, f=24, e=16, k=4):
+    ff = FFModel(FFConfig(batch_size=n, mesh_shape={"data": 1}))
+    x = ff.create_tensor([n, d], name="x")
+    op = MoE(ff, "moe", [x], e, f, k, None, expert="swiglu",
+             scoring="sigmoid", score_bias=bias, n_group=4, topk_group=2,
+             routed_scaling=2.5, shared_hidden_dim=f, experts_held=held)
+    rs = np.random.RandomState(0)
+    p = {w.name: jnp.asarray(rs.randn(*w.shape) * (
+        0.5 if w.name == "score_bias" else w.shape[-2] ** -0.5 if len(
+            w.shape) > 1 else 1.0), jnp.float32) for w in op.weight_specs()}
+    return op, p, jnp.asarray(rs.randn(n, d), jnp.float32)
+
+
+def test_selection_bias_changes_the_chosen_set_and_not_the_gates():
+    """s' = s + b selects; the gates are s of the chosen experts,
+    renormalised and times 2.5: with b the chosen sets differ, and where a
+    token's set is the same so are its gates."""
+    op, p, x = moe_op()
+    s, g_b, e_b = op._route(p, x)
+    _, g_0, e_0 = op._route({**p, "score_bias": jnp.zeros(16)}, x)
+    same = (np.sort(e_b, -1) == np.sort(e_0, -1)).all(-1)
+    assert 0 < same.sum() < len(same)
+    want = np.take_along_axis(np.asarray(s), np.asarray(e_b), -1)
+    want = 2.5 * want / want.sum(-1, keepdims=True)
+    np.testing.assert_allclose(g_b, want, rtol=1e-6)
+    for t in np.flatnonzero(same):
+        np.testing.assert_allclose(np.sort(g_b[t]), np.sort(g_0[t]),
+                                   rtol=1e-6)
+    # group-limited: every chosen expert lies in one of two groups of four
+    assert all(len({int(e) // 4 for e in row}) <= 2 for row in np.asarray(e_b))
+
+
+def test_four_shares_sum_to_the_uncut_layer():
+    """The guide's share test: four layers holding experts 0-3, 4-7, 8-11
+    and 12-15 of the same weights, the shared expert counted once, give the
+    uncut layer's output."""
+    whole, p, x = moe_op()
+    full = whole.forward(p, [x])[0]
+    shared = whole._shared_expert(p, x)
+    routed = jnp.zeros_like(full)
+    for first in range(0, 16, 4):
+        part, pp, _ = moe_op(held=(first, 4))
+        pp = {n: (v[first:first + 4] if n in MoE._EXPERT_WEIGHTS else v)
+              for n, v in p.items()}
+        assert pp["w_gate"].shape == part.weight_specs()[1].shape
+        routing = []
+        routed = routed + part.forward(pp, [x], routing=routing)[0] - shared
+        # the share's counters count ITS experts' assignments only
+        assert int(routing[0][0]) <= 24 * 4 and int(routing[0][1]) <= 4
+    np.testing.assert_allclose(routed + shared, full, atol=2e-5, rtol=0)
+
+
+def test_streamed_lowering_of_held_experts_matches_grouped(monkeypatch):
+    """A decode-shaped call of a layer that holds 4 of 16 experts through
+    the expert-stream kernel (interpret mode) against its grouped
+    lowering."""
+    import flexflow_tpu.ops.moe as moe_mod
+
+    op, p, x = moe_op(held=(8, 4), n=8, d=128, f=128)
+    p = {n: (v[8:12] if n in MoE._EXPERT_WEIGHTS else v)
+         for n, v in moe_op(n=8, d=128, f=128)[1].items()}
+    mask = jnp.asarray([True] * 6 + [False] * 2)
+    want, took = op.forward(p, [x], row_mask=mask)[0], []
+    monkeypatch.setattr(moe_mod, "_backend", lambda: "tpu")
+    got = op.forward(p, [x], row_mask=mask, lowerings=took)[0]
+    assert took == ["streamed"]
+    # dead rows: the routed part is zero, the shared expert still runs
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def test_plain_softmax_op_takes_none_of_the_new_arguments():
+    with pytest.raises(ValueError, match="dropless"):
+        ff = FFModel(FFConfig(batch_size=4, mesh_shape={"data": 1}))
+        MoE(ff, "m", [ff.create_tensor([4, 16], name="x")], 8, 16, 2,
+            scoring="sigmoid")
+
+
+# ---- the check can see the selection ----------------------------------------
+
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs",
+    "deepseek-v3.2-serve.json")
+
+
+@pytest.mark.parametrize("fault", ["off", "shifted"])
+def test_check_a_fails_when_the_selection_is_off_or_shifted(monkeypatch,
+                                                            fault):
+    """With glorot weights attention is nearly uniform over what it selects
+    and a program that attended to every live token would pass check (a).
+    The configuration's seeded draw widens W_UQ (`seeded_w_uq_gain`) so that
+    attention is peaked: then a selection switched off, or shifted by one
+    page (8 here), moves the logits beyond the configuration's own
+    tolerance, while the intact program stays far inside it."""
+    cfg = json.load(open(CONFIG))
+    tol = cfg["tolerances"]["predict_rel_rms"]
+    ff = build(batch=1, gain=cfg["seeded_w_uq_gain"])
+    toks = np.random.RandomState(1).randint(1, VOCAB, (1, SEQ)) \
+        .astype(np.int32)
+    want = np.asarray(ref.forward(ff.params, toks[0], SIZES))
+
+    def rel(got):
+        return float(np.linalg.norm(np.asarray(got)[0] - want)
+                     / np.linalg.norm(want))
+
+    assert rel(ff.predict({"input": toks})) < tol / 100
+    chosen = mla.dsa_chosen
+
+    def faulty(scores, thr, cut):
+        live = scores > -jnp.inf
+        if fault == "off":
+            return live
+        return jnp.roll(chosen(scores, thr, cut), 8, axis=-1) & live | (
+            live & (jnp.cumsum(live, axis=-1) <= 1))
+
+    monkeypatch.setattr(mla, "dsa_chosen", faulty)
+    ff2 = build(batch=1, gain=cfg["seeded_w_uq_gain"])
+    assert rel(ff2.predict({"input": toks})) > tol
