@@ -31,8 +31,17 @@ same page ids.
 
 Selection is exact: `dsa_threshold` finds each row's index_topk-th largest
 score digit by digit on the scores' bit patterns (8 passes of 15 counts) and
-cuts ties by position (lowest first, 4 more passes); the same two numbers a row drive the
-XLA mask here and the Pallas core kernel's (ops/pallas_kernels.py).
+cuts ties by position (lowest first, 4 more passes); the same two numbers a
+row drive the XLA mask here (`dsa_chosen`) and both Pallas cores.
+
+What the paged decode core READS (`paged_decode_forward`, impl `pallas`):
+only the rows the selection kept. `dsa_selected` turns the two numbers into
+a list of pool rows per slot (index_topk of them, ascending, the first
+n_sel real), XLA gathers those rows of the latent pool into (slots,
+index_topk, LAT) and `mla_gathered_core_pallas` runs the online softmax
+over index_topk / 128 blocks a slot, whatever the context; a context under
+index_topk just has a short list. The gather is an XLA fusion, not part of
+the named kernel: a device trace times it apart (PERF.md section 6, PR 31).
 
 YaRN rotary (`rope_scaling`: factor, original_max_position_embeddings,
 beta_fast, beta_slow, mscale, mscale_all_dim): frequencies interpolated
@@ -175,6 +184,62 @@ def dsa_chosen(scores, thr, tie_cut):
     t = thr[..., None]
     return (scores > -jnp.inf) & (
         (scores > t) | ((scores == t) & (pos <= tie_cut[..., None])))
+
+
+def dsa_selected(scores, thr, tie_cut, k: int, ps: int, page_table):
+    """The selection as a list of pool rows: (rows (..., k) int32, n_sel
+    (...) int32). `scores` (..., L) is read as L // ps pages of ps
+    positions, `page_table` (..., L // ps) int32 (ids under 2^24) names each
+    page's place in a pool seen as (pages * ps, .). rows[..., :n_sel] are
+    page_table[..., pos // ps] * ps + pos % ps for the positions
+    `dsa_chosen(scores, thr, tie_cut)` marks, ascending by position, and the
+    rest position 0's row.
+
+    Dense passes, no sort, no scatter and no gather: with `within` a page's
+    running count of chosen positions and `incl` the running count of
+    whole pages, the r-th chosen position lies in the page after those
+    with incl <= r. That one comparison, `le` (..., k, pages), is a run of
+    ones, so ONE product of it with per-page DIFFERENCES brings each r the
+    count before its page (sum of the pages' counts), the page's `within`
+    row (the first page's plus the summed differences) and its id in the
+    table (the same, a byte at a time: every factor is an integer under
+    256, exact in bf16, summed in f32); the offset in the page is how many
+    of the row's running counts are <= r's rank in the page."""
+    if ps > 256:
+        raise ValueError(f"pages of {ps} positions: at most 256")
+    lead, L = scores.shape[:-1], scores.shape[-1]
+    p = L // ps
+    bf, f32 = jnp.bfloat16, jnp.float32
+    chosen = dsa_chosen(scores, thr, tie_cut).reshape(lead + (p, ps))
+    within = jnp.einsum("...pi,ij->...pj", chosen.astype(bf),
+                        jnp.triu(jnp.ones((ps, ps), bf)),      # [i <= j]
+                        preferred_element_type=f32)
+    cnt = within[..., -1:]                              # (..., p, 1)
+    incl = jnp.cumsum(cnt[..., 0].astype(jnp.int32), axis=-1)
+    # a pair that marks more than k (no `dsa_threshold` does) lists the
+    # first k: a reader of the list never runs past it
+    n_sel = jnp.minimum(incl[..., -1], k)
+
+    def steps(x):       # x[q + 1] - x[q] along the pages; 0 for the last
+        return jnp.concatenate([x[..., 1:, :] - x[..., :-1, :],
+                                jnp.zeros_like(x[..., :1, :])], axis=-2)
+
+    ids = jnp.stack([(page_table >> s) & 0xFF for s in (0, 8, 16)],
+                    axis=-1).astype(f32)                # (..., p, 3)
+    r = jnp.arange(k, dtype=jnp.int32)
+    le = incl[..., None, :] <= r[:, None]               # (..., k, p)
+    got = jnp.einsum("...kp,...pn->...kn", le.astype(bf),
+                     jnp.concatenate([steps(within), cnt, steps(ids)],
+                                     axis=-1).astype(bf),
+                     preferred_element_type=f32)
+    counts = within[..., :1, :] + got[..., :ps]         # the page's row
+    off = jnp.sum(counts <= (r - got[..., ps])[..., None], axis=-1,
+                  dtype=jnp.int32)
+    mine = ids[..., :1, :] + got[..., ps + 1:]          # (..., k, 3) bytes
+    page = jnp.sum(mine.astype(jnp.int32)
+                   << jnp.arange(0, 24, 8, dtype=jnp.int32), axis=-1)
+    return jnp.where(r < n_sel[..., None], page * ps + off,
+                     page_table[..., :1] * ps), n_sel
 
 
 class LatentAttention(Op):
@@ -538,9 +603,10 @@ class LatentAttention(Op):
         """One decode step of every slot over the paged pools: append the
         token's latent row and index key at (page_table[b, write_pos //
         ps], write_pos % ps), then score, select and attend through the
-        page tables. `pallas`: the two kernels read the pools in place;
-        `einsum`: the slots' pages gathered into contiguous rows and the
-        blocked XLA attention, the parity oracle."""
+        page tables. `pallas`: the index kernel reads its pool in place and
+        the core reads the selected latent rows, gathered; `einsum`: the
+        slots' pages gathered into contiguous rows and the blocked XLA
+        attention, the parity oracle."""
         ps = cache["lat"].shape[1]
         pr = self._project(params, xs[0], rope_pos[:, None])
         page_ids = jnp.take_along_axis(
@@ -557,16 +623,19 @@ class LatentAttention(Op):
                                        write_pos[:, None], row_len,
                                        prompt_pad), cache
         from flexflow_tpu.ops.pallas_kernels import (
-            dsa_index_scores_pallas, mla_paged_core_pallas)
+            dsa_index_scores_pallas, mla_gathered_core_pallas)
 
         scores = dsa_index_scores_pallas(
             pr["qi"][:, 0], pr["w"][:, 0], cache["ki"], page_table,
             write_pos, row_len, prompt_pad)
         thr, cut = dsa_threshold(scores, self.index_topk)
-        ctx = mla_paged_core_pallas(
-            self._absorb(params, pr["q_nope"][:, 0], pr["q_rope"][:, 0]),
-            scores, thr, cut, cache["lat"], page_table, write_pos, row_len,
-            prompt_pad, self.scale, self.kv_lora_rank)
+        q_lat = self._absorb(params, pr["q_nope"][:, 0], pr["q_rope"][:, 0])
+        # a table smaller than index_topk has no more rows to list
+        rows, n_sel = dsa_selected(
+            scores, thr, cut, min(self.index_topk, page_table.shape[1] * ps),
+            ps, page_table)
+        ctx = mla_gathered_core_pallas(q_lat, rows, n_sel, cache["lat"],
+                                       scale=self.scale, c=self.kv_lora_rank)
         o = jnp.einsum("bhc,chv->bhv", ctx, params["w_uv"])
         return self._out(params, o[:, None]), cache
 

@@ -1208,24 +1208,31 @@ def moe_expert_stream_pallas(x, gates, sizes, w_gate, w_up, w_down):
 #
 # The cached state of a token is ONE latent row shared by all heads
 # ([cKV ; kR ; zero pad], ops/mla.py) and one index key. A decode step reads
-# both pools in place, in two kernels built like `_paged_attn_kernel` (grid
-# over slots, a loop to the slot's last live page, page DMAs issued by hand
-# through a ring that runs ahead across slot boundaries):
+# the index pool in place and, of the latent pool, only the rows the
+# selection kept:
 #
-#   dsa_index_scores  a page's index keys (ps, dI) against the slot's J index
+#   dsa_index_scores  built like `_paged_attn_kernel` (grid over slots, a loop
+#                     to the slot's last live page, page DMAs issued by hand
+#                     through a ring that runs ahead across slot boundaries):
+#                     a page's index keys (ps, dI) against the slot's J index
 #                     queries: I_s = sum_j w_j ReLU(qI_j . kI_s), one f32 row
 #                     of scores per slot, -inf where the live rule excludes s.
-#   mla_paged_core    the H absorbed queries (H, W) against a page's latents
-#                     (ps, W) once: one matmul scores all heads, the selection
-#                     (score above the row's threshold, ties by position:
-#                     ops/mla.py `dsa_threshold`) and the live rule mask the
-#                     columns, and the online softmax accumulates p @ cKV.
+#   mla_paged_core_gathered
+#                     the H absorbed queries (H, W) against blocks of 128
+#                     SELECTED latent rows: one matmul scores all heads, the
+#                     online softmax accumulates p @ cKV; index_topk / 128
+#                     turns a slot whatever the context, the only mask
+#                     column < n_sel.
 #
-# Between them the k-th largest score of each row is found by XLA
-# (`dsa_threshold`: 12 passes over a (slots, context) f32 array). The
-# core streams EVERY live page under the mask: a page of 128 tokens nearly
-# always holds a selected one (2048 of up to 33 k), so skipping pages would
-# save little; its useful share is `mla_core_roofline_share`.
+# Between them XLA finds the k-th largest score of each row (ops/mla.py
+# `dsa_threshold`: 12 passes over a (slots, context) f32 array), turns the
+# selection into a list of pool rows (`dsa_selected`) and gathers the listed
+# rows into (slots, index_topk, W): Mosaic refuses a DMA of fewer than 8 rows
+# of the tiled pool, and a page of 128 tokens nearly always holds a selected
+# one (2048 of up to 33 k), so neither row DMAs nor skipped pages can do it
+# inside a kernel (PERF.md section 6, PR 31). The core's name starts with
+# `mla_paged_core`, which is what benchmark/dsa_trace.py counts as the core;
+# the gather in front of it is an XLA fusion that no name marks.
 
 
 def _page_stream(pt_ref, lp_ref, hbm, buf, sem, cur, nbuf):
@@ -1304,47 +1311,6 @@ def _dsa_index_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
     jax.lax.fori_loop(0, lp_ref[b] + 1, one_page, 0)
 
 
-def _mla_core_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, tc_ref, q_ref,
-                     thr_ref, sc_ref, lat_hbm, o_ref, lat_buf, sem, cur, *,
-                     ps: int, nbuf: int, scale: float, c: int):
-    b = pl.program_id(0)
-    prime, take = _page_stream(pt_ref, lp_ref, lat_hbm, lat_buf, sem, cur,
-                               nbuf)
-    prime()
-    q = q_ref[0]                                        # (H, W)
-    thr = thr_ref[0]                                    # (1, 1) f32
-    rl, pp, wp, tc = rl_ref[b], pp_ref[b], wp_ref[b], tc_ref[b]
-    h = q.shape[0]
-
-    def one_page(t, carry):
-        m_prev, l_prev, acc = carry
-        buf = take()
-        page = lat_buf[buf].astype(q.dtype)             # (ps, W)
-        s = jax.lax.dot_general(
-            q, page, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (H, ps)
-        isc = sc_ref[0, pl.ds(t, 1), :]                 # (1, ps)
-        j, live = _live_columns(t, ps, rl, pp, wp)
-        chosen = live & ((isc > thr) | ((isc == thr) & (j <= tc)))
-        s = jnp.where(chosen, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(p.astype(page.dtype), page[:, :c],
-                                    preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    _, l_fin, acc = jax.lax.fori_loop(
-        0, lp_ref[b] + 1, one_page,
-        (jnp.full((h, 1), NEG_INF, jnp.float32),
-         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, c), jnp.float32)))
-    # the row's own token is live and chosen (all live tokens are while
-    # they are at most top-k, the top-k otherwise), so l > 0; a page with
-    # no chosen column before the first chosen one is scaled away (alpha 0)
-    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
-
-
 def _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps):
     last = jnp.maximum(write_pos, row_len - 1) // ps
     return [page_table.astype(jnp.int32), last.astype(jnp.int32),
@@ -1387,41 +1353,73 @@ def dsa_index_scores_pallas(qi, w, ki_pages, page_table, write_pos, row_len,
     return out.reshape(b, p * ps)
 
 
-def mla_paged_core_pallas(q_lat, scores, thr, tie_cut, lat_pages, page_table,
-                          write_pos, row_len, prompt_pad, scale: float,
-                          c: int):
-    """The attention core of one decode step over the latent pool: q_lat
-    (B, H, W) absorbed queries, lat_pages (P_pool, ps, W), `scores`
-    (B, P * ps) the step's index scores with each row's threshold `thr`
-    (B,) f32 and `tie_cut` (B,) int32 -> (B, H, c): softmax over the live
-    positions whose score is above thr, or equal to it at a position <=
-    tie_cut, of q . latent * scale, times the latents' first c columns."""
+def _mla_gathered_kernel(ns_ref, q_ref, g_ref, o_ref, *, blk: int,
+                         scale: float, c: int):
+    n = ns_ref[pl.program_id(0)]
+    q = q_ref[0]                                        # (H, W)
+    h = q.shape[0]
+
+    def one_block(t, carry):
+        m_prev, l_prev, acc = carry
+        r0 = pl.multiple_of(t * blk, blk)
+        rows = g_ref[0, pl.ds(r0, blk), :].astype(q.dtype)      # (blk, W)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, blk)
+        col = r0 + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+        s = jnp.where(col < n, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(rows.dtype), rows[:, :c],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    # n >= 1 (a row's own token is live and chosen), so the first block
+    # holds a real column and l > 0
+    _, l_fin, acc = jax.lax.fori_loop(
+        0, (n + blk - 1) // blk, one_block,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, c), jnp.float32)))
+    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+
+
+# inline=True: as `moe_expert_stream_pallas`, so a program's layers share one
+# trace and one Mosaic lowering of the kernel
+@functools.partial(jax.jit, inline=True, static_argnames=("scale", "c"))
+def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
+                             c: int):
+    """The attention core of one decode step over the SELECTED rows of the
+    latent pool: q_lat (B, H, W) absorbed queries, lat_pages (P_pool, ps,
+    W), `rows` (B, K) int32 rows of the pool seen as (P_pool * ps, W), of
+    which each slot's first n_sel (B,) >= 1 are its selection (the rest
+    any valid row) -> (B, H, c): softmax over those n_sel rows of q .
+    latent * scale, times the latents' first c columns."""
     b, h, wdt = q_lat.shape
-    ps = lat_pages.shape[1]
-    p = page_table.shape[1]
-    nbuf = _stream_ring(ps, wdt, lat_pages.dtype)
+    k = rows.shape[1]
+    # rows a matmul turn takes: a lane tile, or a smaller list whole
+    blk = LANES if k >= LANES else -(-k // 16) * 16
+    kp = -(-k // blk) * blk
+    if kp != k:
+        rows = jnp.pad(rows, ((0, 0), (0, kp - k)))
+    # XLA's gather: Mosaic refuses a DMA of fewer than 8 rows of the tiled
+    # pool (PERF.md section 6, PR 31)
+    gathered = lat_pages.reshape(-1, wdt).at[rows].get(
+        mode="promise_in_bounds")                       # (B, kp, W)
 
     def slot_map(bi, *_):
         return (bi, 0, 0)
 
-    prefetch = _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps)
-    prefetch.append(tie_cut.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch), grid=(b,),
+        num_scalar_prefetch=1, grid=(b,),
         in_specs=[pl.BlockSpec((1, h, wdt), slot_map),
-                  pl.BlockSpec((1, 1, 1), slot_map),
-                  pl.BlockSpec((1, p, ps), slot_map),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, h, c), slot_map),
-        scratch_shapes=[pltpu.VMEM((nbuf, ps, wdt), lat_pages.dtype),
-                        pltpu.SemaphoreType.DMA((nbuf,)),
-                        pltpu.SMEM((3,), jnp.int32)])
+                  pl.BlockSpec((1, kp, wdt), slot_map)],
+        out_specs=pl.BlockSpec((1, h, c), slot_map))
     return pl.pallas_call(
-        functools.partial(_mla_core_kernel, ps=ps, nbuf=nbuf, scale=scale,
-                          c=c),
+        functools.partial(_mla_gathered_kernel, blk=blk, scale=scale, c=c),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
         compiler_params=_compiler_params(("arbitrary",)),
-        interpret=_interpret(), name="mla_paged_core",
-    )(*prefetch, q_lat, thr.astype(jnp.float32)[:, None, None],
-      scores.reshape(b, p, ps), lat_pages)
+        interpret=_interpret(), name="mla_paged_core_gathered",
+    )(n_sel.astype(jnp.int32), q_lat, gathered)

@@ -259,10 +259,16 @@ def test_threshold_selects_exactly_top_k_ties_to_the_lower_position(
     np.testing.assert_array_equal(got, want)
 
 
-def paged_state(op, params, tie: bool):
-    """Three slots over a pool of pages of 8: contexts 43, 9 and an idle
-    slot; with `tie`, the index keys of slot 0's pages 1-3 are one row
-    repeated, so 24 scores are equal and the cut of 16 falls among them."""
+def paged_state(op, params, case: str):
+    """Three slots over a pool of pages of 8 whose tables hold 64 tokens,
+    four times index_topk, so the Pallas path takes the gathered core.
+    `plain`: contexts 43 (several pages over index_topk, a hole of padding
+    at 30..31), 9 (under it: a short list) and an idle slot (a list of one).
+    `tie`: the index keys of slot 0's pages 1-3 are one row repeated, so 22
+    live scores are equal and the cut of 16 falls among them, across page
+    edges. `few_over`: contexts 18 and 17, one and two tokens over
+    index_topk. `shared_doc`: slots 0 and 1 read the same four pages of one
+    document and append to pages of their own."""
     rs = np.random.RandomState(4)
     pool = op.init_paged_cache(24, 8, jnp.float32)
     pool = {n: jnp.asarray(rs.randn(*a.shape), jnp.float32)
@@ -271,24 +277,36 @@ def paged_state(op, params, tie: bool):
     table = np.zeros((3, 8), np.int32)
     table[0, :6] = [3, 7, 1, 12, 9, 20]
     table[1, :2] = [5, 2]
-    if tie:
+    if case == "tie":
         pool["ki"] = pool["ki"].at[jnp.asarray([7, 1, 12])].set(
             pool["ki"][7, 0])
     write_pos = np.asarray([42, 8, 0], np.int32)
     row_len = np.asarray([30, 5, 0], np.int32)      # 30..31 and 5..7: padding
     prompt_pad = np.asarray([32, 8, 0], np.int32)
+    if case == "few_over":
+        table[1, :3] = [5, 2, 14]
+        write_pos = np.asarray([17, 18, 0], np.int32)
+        row_len = np.asarray([0, 12, 0], np.int32)  # 12..13: padding
+        prompt_pad = np.asarray([0, 14, 0], np.int32)
+    if case == "shared_doc":
+        table[1, :5] = [3, 7, 1, 12, 5]
+        write_pos = np.asarray([42, 35, 0], np.int32)
+        row_len = np.asarray([30, 30, 0], np.int32)
+        prompt_pad = np.asarray([32, 32, 0], np.int32)
     x = jnp.asarray(rs.randn(3, 1, 64), jnp.float32)
     return pool, jnp.asarray(table), write_pos, row_len, prompt_pad, x
 
 
-@pytest.mark.parametrize("tie", [False, True])
-def test_pallas_decode_kernels_match_the_einsum_oracle(tie):
-    """`dsa_index_scores` and `mla_paged_core` (interpret mode) read the
+@pytest.mark.parametrize("case", ["plain", "tie", "few_over", "shared_doc"])
+def test_pallas_decode_kernels_match_the_einsum_oracle(case):
+    """`dsa_index_scores` and the gathered core (interpret mode) read the
     pools through the page tables; the oracle gathers the pages and runs the
     blocked XLA attention. Same appended rows, same output, also when the
-    selection's cut falls inside a run of equal scores."""
+    selection's cut falls inside a run of equal scores, when a context is a
+    token or two over index_topk, and when two slots share a document."""
+    tie = case == "tie"
     op, params, _ = attention_op(seq=1, batch=3)
-    pool, table, wp, rl, pp, x = paged_state(op, params, tie)
+    pool, table, wp, rl, pp, x = paged_state(op, params, case)
     args = (params, [x], pool, table, jnp.asarray(wp), jnp.asarray(wp - 2),
             jnp.asarray(rl), jnp.asarray(pp))
     want, pool_e = op.paged_decode_forward(*args, impl="einsum")
@@ -311,6 +329,151 @@ def test_pallas_decode_kernels_match_the_einsum_oracle(tie):
         # what the cut takes of the run is its lowest positions
         assert chosen.sum() == TOPK and 0 < tied.size < 22
         np.testing.assert_array_equal(tied, np.arange(tied.size))
+
+
+def core_case(case: str):
+    """Hand-made index scores of two slots over tables of 8 pages of 8
+    (-inf = dead under the live rule) and their page tables, for the
+    gathered core alone; index_topk is 16."""
+    rs = np.random.RandomState(len(case))
+    sc = np.full((2, 64), -np.inf, np.float32)
+    table = np.stack([rs.permutation(23)[:8] + 1 for _ in range(2)])
+
+    def live(b, n):
+        sc[b, :n] = rs.permutation(n) * 0.25 - 3.0      # distinct scores
+
+    if case == "under_topk":
+        live(0, 9), live(1, 16)
+    elif case == "few_over":
+        live(0, 17), live(1, 19)
+    elif case == "pages_over":
+        live(0, 43), live(1, 64)
+    elif case == "tie_straddles_page_edge":
+        # twelve scores above a run of six equal ones at 13..18: the cut
+        # takes four of the run, 13..15 of page 1 and 16 of page 2
+        live(0, 40), live(1, 40)
+        for b in range(2):
+            sc[b, 13:19] = 20.0
+            sc[b, rs.permutation(np.r_[0:13, 19:40])[:12]] += 40.0
+    elif case == "prompt_pad_hole":
+        live(0, 43), live(1, 30)
+        sc[0, 30:32] = -np.inf          # row_len 30, prompt_pad 32
+        sc[1, 5:8] = -np.inf
+    elif case == "inactive_slot":
+        live(0, 43)
+        sc[1, 0], table[1] = 0.0, 0     # one live position of the scratch page
+    elif case == "shared_pages":
+        live(0, 40), live(1, 40)
+        table[1, :4] = table[0, :4]     # one document under both slots
+    return sc, table.astype(np.int32)
+
+
+@pytest.mark.parametrize("case", [
+    "under_topk", "few_over", "pages_over", "tie_straddles_page_edge",
+    "prompt_pad_hole", "inactive_slot", "shared_pages"])
+def test_gathered_core_attends_exactly_the_selected_rows(case):
+    """`dsa_selected` + `mla_gathered_core_pallas` (interpret mode) against
+    a dense softmax under `dsa_chosen`'s mask over the slots' gathered
+    pages: the same rows, so the same numbers up to float32 rounding."""
+    from flexflow_tpu.ops.pallas_kernels import mla_gathered_core_pallas
+
+    sc, table = core_case(case)
+    rs = np.random.RandomState(7)
+    lat = jnp.asarray(rs.randn(24, 8, 128), jnp.float32)
+    q = jnp.asarray(rs.randn(2, 4, 128), jnp.float32)
+    thr, cut = mla.dsa_threshold(jnp.asarray(sc), TOPK)
+    chosen = np.asarray(mla.dsa_chosen(sc, thr, cut))
+    if case == "tie_straddles_page_edge":
+        np.testing.assert_array_equal(np.flatnonzero(chosen[0, 13:19]),
+                                      np.arange(4))
+    rows, n_sel = mla.dsa_selected(jnp.asarray(sc), thr, cut, TOPK, 8,
+                                   jnp.asarray(table))
+    np.testing.assert_array_equal(n_sel, np.minimum(
+        (sc > -np.inf).sum(-1), TOPK))
+    got = mla_gathered_core_pallas(q, rows, n_sel, lat, scale=0.3, c=32)
+    mine = lat[table].reshape(2, 64, 128)
+    logits = jnp.where(chosen[:, None, :],
+                       jnp.einsum("bhw,blw->bhl", q, mine) * 0.3, -jnp.inf)
+    want = jnp.einsum("bhl,blc->bhc", jax.nn.softmax(logits, axis=-1),
+                      mine[..., :32])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("length, ps, k, ties", [
+    (64, 8, 16, False), (64, 8, 16, True), (48, 8, 64, False),
+    (1024, 128, 300, True), (512, 256, 100, True), (33280, 128, 2048, True)])
+def test_selected_list_is_the_chosen_set_in_order(length, ps, k, ties):
+    """`dsa_selected`'s first n_sel entries are the pool's rows of
+    `dsa_chosen`'s positions, ascending by position, ties at the cut
+    included (under an identity table the positions themselves); past n_sel
+    the list repeats a valid entry."""
+    rs = np.random.RandomState(length + k)
+    sc = rs.randn(3, length).astype(np.float32)
+    if ties:
+        sc = rs.randint(-2, 3, (3, length)).astype(np.float32)
+    sc[rs.rand(3, length) < 0.2] = -np.inf
+    sc[:, 0] = 1.0
+    sc[2, 1:] = -np.inf                 # an idle slot: its own token alone
+    table = rs.randint(0, 1 << 22, (3, length // ps)).astype(np.int32)
+    thr, cut = mla.dsa_threshold(jnp.asarray(sc), k)
+    chosen = np.asarray(mla.dsa_chosen(sc, thr, cut))
+    sel, n_sel = mla.dsa_selected(jnp.asarray(sc), thr, cut, k, ps,
+                                  np.tile(np.arange(length // ps), (3, 1)))
+    rows, n_rows = mla.dsa_selected(jnp.asarray(sc), thr, cut, k, ps,
+                                    jnp.asarray(table))
+    np.testing.assert_array_equal(n_sel, chosen.sum(-1))
+    np.testing.assert_array_equal(n_rows, n_sel)
+    for b, n in enumerate(np.asarray(n_sel)):
+        want = np.flatnonzero(chosen[b])
+        np.testing.assert_array_equal(sel[b, :n], want)
+        np.testing.assert_array_equal(
+            rows[b, :n], table[b, want // ps] * ps + want % ps)
+        assert (np.asarray(sel[b, n:]) == 0).all()
+        assert (np.asarray(rows[b, n:]) == table[b, 0] * ps).all()
+
+
+def test_a_pair_that_marks_more_than_k_lists_the_first_k():
+    """`dsa_threshold` never marks more than k positions; a planted fault
+    may (benchmark/dsa_controls.py switches the selection off with
+    (-inf, L)). The list then holds the first k marked and n_sel is k, so
+    the gathered core's loop stays inside its k rows."""
+    sc = np.random.RandomState(0).randn(2, 64).astype(np.float32)
+    sc[1, 40:] = -np.inf
+    thr = jnp.full((2,), -jnp.inf, jnp.float32)
+    cut = jnp.full((2,), 64, jnp.int32)
+    sel, n_sel = mla.dsa_selected(jnp.asarray(sc), thr, cut, TOPK, 8,
+                                  np.tile(np.arange(8), (2, 1)))
+    np.testing.assert_array_equal(n_sel, [TOPK, TOPK])
+    np.testing.assert_array_equal(sel, np.tile(np.arange(TOPK), (2, 1)))
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3])
+def test_a_table_no_larger_than_the_selection_takes_the_same_core(pages):
+    """One core whatever the table's shape: tables of 1 or 2 pages of 8
+    hold at most index_topk = 16 tokens, so every live token is selected
+    and the list is as long as the table; with 3 pages it is index_topk.
+    The decode program holds `mla_paged_core_gathered` once either way and
+    agrees with the oracle."""
+    import re
+
+    op, params, _ = attention_op(seq=1, batch=3)
+    pool, table, *_, x = paged_state(op, params, "plain")
+    table = table[:, :pages]
+    wp = jnp.asarray([min(13, pages * 8 - 1), min(8, pages * 8 - 1), 0],
+                     jnp.int32)
+    rl = jnp.asarray([min(9, pages * 8 - 3), 5, 0], jnp.int32)
+    pp = jnp.asarray([min(10, pages * 8 - 2), min(8, pages * 8 - 1), 0],
+                     jnp.int32)
+
+    def step(impl):
+        return op.paged_decode_forward(params, [x], pool, table, wp, wp, rl,
+                                       pp, impl=impl)[0]
+
+    text = str(jax.make_jaxpr(lambda: step("pallas"))())
+    assert re.findall(r"name=(mla_paged_core\w*)", text) == [
+        "mla_paged_core_gathered"]
+    np.testing.assert_allclose(step("pallas"), step("einsum"), atol=2e-5,
+                               rtol=0)
 
 
 def test_export_import_moves_a_page_of_both_pools():
