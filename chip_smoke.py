@@ -238,11 +238,13 @@ def kernel_cases(z: Sizes):
     # per-shard call under model=2 head sharding
     def flash_grads(fn):
         def run(q, k, v):
-            w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)
-                        ).reshape(q.shape)
-            return jax.grad(
-                lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
-                argnums=(0, 1, 2))(q, k, v)
+            def pulled(q, k, v):
+                out = fn(q, k, v).astype(jnp.float32)
+                w = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
+                            ).reshape(out.shape)
+                return jnp.sum(out * w)
+
+            return jax.grad(pulled, argnums=(0, 1, 2))(q, k, v)
         return run
 
     for heads in sorted({h, h // 2}, reverse=True):
@@ -255,6 +257,19 @@ def kernel_cases(z: Sizes):
                                                                True)),
             # gradients sum ~seq bf16 products per element
             4 * BF16_TOL))
+
+    # the same at key and value widths that differ (latent attention's
+    # expanded form: keys half as wide again as the values)
+    def qkv_apart(rs):
+        return tuple(jnp.asarray(rs.randn(1, z.seq, h, w) * 0.5, bf16)
+                     for w in (d + d // 2, d + d // 2, d))
+
+    cases.append(KernelCase(
+        f"flash_fwd+bwd train b1 s{z.seq} h{h} dqk{d + d // 2} dv{d} bf16",
+        qkv_apart,
+        flash_grads(lambda q, k, v: pk.flash_attention(q, k, v, True, None)),
+        flash_grads(lambda q, k, v: dense_attention_oracle(q, k, v, True)),
+        4 * BF16_TOL))
 
     # paged attention: decode (S=1) and the speculative-verify slab
     # (S=K+1), on the native pool and on both quantized pools

@@ -40,7 +40,8 @@ from flexflow_tpu.parallel.strategy import (load_strategies_from_file,
                                             save_strategies_to_file)
 from flexflow_tpu.runtime.executor import GraphExecutor
 from flexflow_tpu.runtime.loss import loss_type_from_name
-from flexflow_tpu.runtime.metrics import PerfMetrics, metrics_from_names
+from flexflow_tpu.runtime.metrics import (ROUTING_COUNTS, PerfMetrics,
+                                          metrics_from_names)
 from flexflow_tpu.tensor import Tensor
 
 # process-wide model ids: the HBM ledger's per-instance source name
@@ -224,10 +225,12 @@ class FFModel:
             score_bias: Optional[float] = None, n_group: int = 1,
             topk_group: int = 1, routed_scaling: float = 1.0,
             shared_hidden_dim: int = 0, experts_held=None,
+            aux_weight: float = 1e-2,
             name: Optional[str] = None) -> Tensor:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
-        aux loss is folded into the training loss automatically.
+        aux loss, times `aux_weight` (0: the training loss is the task's
+        alone), is folded into the training loss automatically.
         capacity_factor=None is the dropless op (no dropped token; lowered
         by the call's static shape, ops/moe.py; no `dispatch`). Otherwise
         dispatch: "auto" (dense einsums when experts are mesh-sharded, else
@@ -241,7 +244,8 @@ class FFModel:
         from flexflow_tpu.ops.moe import MoE
 
         op = MoE(self, self._name("moe", name), [input], num_experts,
-                 hidden_dim, k, capacity_factor, dispatch=dispatch,
+                 hidden_dim, k, capacity_factor, aux_weight=aux_weight,
+                 dispatch=dispatch,
                  expert=expert, renormalize=renormalize, scoring=scoring,
                  score_bias=score_bias, n_group=n_group,
                  topk_group=topk_group, routed_scaling=routed_scaling,
@@ -283,10 +287,12 @@ class FFModel:
             rope_theta=rope_theta, qk_norm=qk_norm, eps=eps))
 
     def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
-                         q_lora_rank: int, kv_lora_rank: int,
+                         q_lora_rank: Optional[int], kv_lora_rank: int,
                          qk_nope_head_dim: int, qk_rope_head_dim: int,
-                         v_head_dim: int, index_n_heads: int,
-                         index_head_dim: int, index_topk: int,
+                         v_head_dim: int,
+                         index_n_heads: Optional[int] = None,
+                         index_head_dim: Optional[int] = None,
+                         index_topk: Optional[int] = None,
                          rope_theta: float = 10000.0,
                          rope_scaling: Optional[dict] = None,
                          eps: float = 1e-6, uq_init_gain: float = 1.0,
@@ -294,7 +300,8 @@ class FFModel:
         """Causal multi-head latent self-attention with a learned top-k
         selection of the cached tokens (DeepSeek MLA + lightning indexer,
         ops/mla.py): the cache holds one latent row and one index key a
-        token instead of K and V per head."""
+        token instead of K and V per head. `q_lora_rank=None`: no query
+        compression; `index_topk=None`: no indexer, plain causal MLA."""
         from flexflow_tpu.ops.mla import LatentAttention
 
         return self._add(LatentAttention(
@@ -1103,15 +1110,16 @@ class FFModel:
                             chunk = self.config.scan_steps
                             t_c0 = time.perf_counter()
                             _, smets = self.train_scanned(chunk)
+                            ev = None
                             if tm_on:
                                 # dispatch time of one scanned chunk
                                 # (device completion is async; the
                                 # epoch_sync span carries the wait)
-                                _telemetry.tracer().complete(
+                                ev = _telemetry.tracer().complete(
                                     "train_scan_chunk", t_c0,
                                     time.perf_counter() - t_c0,
                                     track="train", steps=chunk)
-                            epoch_mets.append((smets, bs, chunk))
+                            epoch_mets.append((smets, bs, chunk, ev))
                         else:
                             # ragged epoch tail: n_steps is static to the
                             # scanned program, so a tail-sized scan would
@@ -1120,7 +1128,7 @@ class FFModel:
                             chunk = 1
                             _, smets = self._run_train_step(
                                 self._stage_batch())
-                            epoch_mets.append((smets, bs, 1))
+                            epoch_mets.append((smets, bs, 1, None))
                         total += bs * chunk
                         it += chunk
                         if warm is None:
@@ -1152,12 +1160,13 @@ class FFModel:
                         bd["h2d"] += t_s - t_h
                         bd["dispatch"] += t_d - t_s
                         bd["steps"] += 1
+                        ev = None
                         if tm_on:
                             sid = f"step-{self._step_count}"
                             tr = _telemetry.tracer()
-                            tr.complete("train_step", t_b, t_d - t_b,
-                                        trace_id=sid, track="train",
-                                        step=self._step_count)
+                            ev = tr.complete("train_step", t_b, t_d - t_b,
+                                             trace_id=sid, track="train",
+                                             step=self._step_count)
                             tr.complete("host_wait", t_b, t_h - t_b,
                                         trace_id=sid, track="train")
                             if t_s > t_h:
@@ -1171,7 +1180,7 @@ class FFModel:
                             # one is installed): one predicate + one
                             # time compare until a window has elapsed
                             _flightrec.slo_monitor().maybe_evaluate()
-                        epoch_mets.append((mets, bs, 1))
+                        epoch_mets.append((mets, bs, 1, ev))
                         total += bs
                         if warm is None:
                             _note_warm(loss)
@@ -1233,7 +1242,7 @@ class FFModel:
                 with (sup.watchdog.arm(f"epoch {epoch} metrics sync",
                                        scale=max(len(epoch_mets), 1))
                       if sup is not None else contextlib.nullcontext()):
-                    for mets, bs, n in epoch_mets:
+                    for mets, bs, n, ev in epoch_mets:
                         # per-step entries hold scalars (n=1); scanned
                         # chunks hold stacked (n,) arrays — np.asarray
                         # unifies both
@@ -1242,6 +1251,22 @@ class FFModel:
                             self._perf.update(
                                 {k: float(a[j] if a.ndim else a)
                                  for k, a in arrs.items()}, bs)
+                        # a routed model's step says where its (token,
+                        # expert) assignments went: read here with the
+                        # other metrics, summed into the breakdown and
+                        # written onto the step's (or the chunk's) span
+                        counts = {
+                            k: int(arrs[k].max() if k.endswith("_max")
+                                   else arrs[k].sum())
+                            for k in ROUTING_COUNTS if k in arrs}
+                        for k, v in counts.items():
+                            bd[k] = (max(bd.get(k, 0), v)
+                                     if k.endswith("_max")
+                                     else bd.get(k, 0) + v)
+                        if counts:
+                            bd["moe_steps"] = bd.get("moe_steps", 0) + n
+                            if ev is not None:
+                                ev.setdefault("args", {}).update(counts)
                 dt_sync = time.perf_counter() - t_sync
                 bd["device"] += dt_sync
                 if tm_on:

@@ -54,17 +54,22 @@ YARN = {"type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
 
 def deepseek_v32_lm(ff: FFModel, batch_size: int, seq_len: int = 4096,
                     hidden: int = 7168, layers: int = 61, heads: int = 128,
-                    q_lora_rank: int = 1536, kv_lora_rank: int = 512,
+                    q_lora_rank: Optional[int] = 1536,
+                    kv_lora_rank: int = 512,
                     qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
-                    v_head_dim: int = 128, index_n_heads: int = 64,
-                    index_head_dim: int = 128, index_topk: int = 2048,
+                    v_head_dim: int = 128,
+                    index_n_heads: Optional[int] = 64,
+                    index_head_dim: Optional[int] = 128,
+                    index_topk: Optional[int] = 2048,
                     dense_layers: int = 3, ffn_hidden: int = 18432,
                     num_experts: int = 256, experts_per_token: int = 8,
                     expert_hidden: int = 2048, shared_experts: int = 1,
                     n_group: int = 8, topk_group: int = 4,
                     routed_scaling: float = 2.5, norm_topk_prob: bool = True,
                     experts_held=None, score_bias_std: float = 0.0,
-                    uq_init_gain: float = 1.0, vocab_size: int = 129280,
+                    uq_init_gain: float = 1.0,
+                    aux_loss_weight: float = 1e-2,
+                    vocab_size: int = 129280,
                     rope_theta: float = 10000.0,
                     rope_scaling: Optional[dict] = YARN,
                     rms_norm_eps: float = 1e-6):
@@ -73,7 +78,11 @@ def deepseek_v32_lm(ff: FFModel, batch_size: int, seq_len: int = 4096,
     `ln2_{i}`, `ffn_*_{i}` in the dense layers), with `moe_{i}` in the
     expert layers. `score_bias_std` and `uq_init_gain` shape the SEEDED
     draw only (the router's selection bias, which a checkpoint trains from
-    zero, and the width of W_UQ): loaded weights ignore them."""
+    zero, and the width of W_UQ): loaded weights ignore them.
+    `aux_loss_weight` scales the balancing term `fit()` adds to the loss.
+    `q_lora_rank=None` (queries projected from `a` directly) and
+    `index_topk=None` (no indexer: plain causal latent attention) build the
+    DeepSeek-V3 family's members without them (models/kanana2.py)."""
     tokens = ff.create_tensor([batch_size, seq_len], dtype=DataType.DT_INT32,
                               name="input")
     t = ff.embedding(tokens, vocab_size, hidden, name="tok_embed")
@@ -96,7 +105,8 @@ def deepseek_v32_lm(ff: FFModel, batch_size: int, seq_len: int = 4096,
                        n_group=n_group, topk_group=topk_group,
                        routed_scaling=routed_scaling,
                        shared_hidden_dim=shared_experts * expert_hidden,
-                       experts_held=experts_held, name=f"moe_{i}")
+                       experts_held=experts_held,
+                       aux_weight=aux_loss_weight, name=f"moe_{i}")
         t = ff.add(t, f, name=f"res2_{i}")
     t = ff.rms_norm(t, eps=rms_norm_eps, name="ln_f")
     logits = ff.dense(t, vocab_size, use_bias=False, name="lm_head")

@@ -30,11 +30,12 @@ from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import Op, WeightSpec
 
 # past this sequence length, the non-flash dense path (CPU backend, attention
-# dropout, mismatched head dims) switches from the fused einsum to the
-# pure-JAX blockwise online-softmax scan — an einsum would materialize the
-# S x S probability tensor. The Pallas flash kernels themselves stream K/V
-# tiles through the grid (round-3 rework) and have NO sequence cap: VMEM use
-# is O(block^2) regardless of S.
+# dropout) switches from the fused einsum to the pure-JAX blockwise
+# online-softmax scan — an einsum would materialize the S x S probability
+# tensor (the scan takes one head size: key and value widths that differ
+# keep the einsum there). The Pallas flash kernels themselves stream K/V
+# tiles through the grid (round-3 rework), have NO sequence cap (VMEM use is
+# O(block^2) regardless of S) and take key and value widths that differ.
 BLOCKWISE_SEQ_THRESHOLD = 4096
 
 
@@ -144,6 +145,39 @@ def flash_seq_cap() -> int:
         return int(os.environ.get("FF_FLASH_MAX_SEQ", "0") or 0)
     except ValueError:
         return 0
+
+
+def flash_eligible(config, causal: bool, sq: int, sk: int) -> bool:
+    """Whether a dense attention of sq queries against sk keys takes the
+    Pallas flash kernels: a static rule of the backend, the configuration
+    and the two lengths (the head sizes do not enter: the kernels take key
+    and value widths that differ). One rule for every op that calls
+    `flash_attention` (MultiHeadAttention, ops/mla.py)."""
+    import os
+
+    if config is not None and not getattr(config, "use_flash_attention",
+                                          True):
+        return False
+    force = os.environ.get("FF_FORCE_FLASH_ATTENTION") == "1"
+    if jax.default_backend() != "tpu" and not force:
+        return False  # interpret mode is for tests only
+    if causal and sq > sk:
+        # more queries than keys under bottom-right-aligned causality
+        # leaves the first sq-sk rows with no live key (0/0 in the
+        # online softmax); the einsum path's uniform-softmax answer for
+        # such rows is equally meaningless, so don't pretend parity
+        return False
+    # escape hatch: the streaming kernels carry no architectural length
+    # cap, but if a deployment's Mosaic build rejects some long-sequence
+    # compile, FF_FLASH_MAX_SEQ routes those shapes to the blockwise
+    # fallback without a code change (unset/0 = unlimited)
+    cap = flash_seq_cap()
+    if cap and max(sq, sk) > cap:
+        return False
+    for s in (sq, sk):
+        if s % min(128, s) != 0:
+            return False
+    return True
 
 
 def _apply_rope(x, theta: float, offset=0):
@@ -788,36 +822,11 @@ class MultiHeadAttention(Op):
     def _flash_ok(self, qh, kh) -> bool:
         """Use the hand-tiled Pallas flash kernel (ops/pallas_kernels.py) on
         the dense path when the backend runs it natively and the block grid
-        divides the sequence. Role parity with the reference's tuned vendor
-        kernel (attention.cu:244 cudnnMultiHeadAttnForward)."""
-        import os
-
-        cfg = getattr(self.model, "config", None)
-        if cfg is not None and not getattr(cfg, "use_flash_attention", True):
-            return False
-        force = os.environ.get("FF_FORCE_FLASH_ATTENTION") == "1"
-        if jax.default_backend() != "tpu" and not force:
-            return False  # interpret mode is for tests only
-        sq, sk = qh.shape[1], kh.shape[1]
-        if self.qk_head_dim != self.v_head_dim:
-            return False
-        if self.causal and sq > sk:
-            # more queries than keys under bottom-right-aligned causality
-            # leaves the first sq-sk rows with no live key (0/0 in the
-            # online softmax); the einsum path's uniform-softmax answer for
-            # such rows is equally meaningless, so don't pretend parity
-            return False
-        # escape hatch: the streaming kernels carry no architectural length
-        # cap, but if a deployment's Mosaic build rejects some long-sequence
-        # compile, FF_FLASH_MAX_SEQ routes those shapes to the blockwise
-        # fallback without a code change (unset/0 = unlimited)
-        cap = flash_seq_cap()
-        if cap and max(sq, sk) > cap:
-            return False
-        for s in (sq, sk):
-            if s % min(128, s) != 0:
-                return False
-        return True
+        divides the sequence (`flash_eligible`). Role parity with the
+        reference's tuned vendor kernel (attention.cu:244
+        cudnnMultiHeadAttnForward)."""
+        return flash_eligible(getattr(self.model, "config", None),
+                              self.causal, qh.shape[1], kh.shape[1])
 
     def _dense_attention(self, qh, kh, vh, scale, training, rng,
                          shard_ctx=None):

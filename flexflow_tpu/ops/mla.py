@@ -13,8 +13,21 @@ by all heads, and one index key:
     S_t = the index_topk live positions of largest I_{t,s} (all, while fewer)
     o_{t,i} = sum_{s in S_t} softmax_{S_t}(q_{t,i} . k_{s,i} * scale) v_{s,i}
 
+The architecture is described by two constructor arguments, neither a
+performance selector. `q_lora_rank=None`: no query compression, q_i = a W_Q,i
+(one `w_q` (D, H, d_nope + d_R) in place of `w_dq`, `q_norm`, `w_uq`).
+`index_topk=None`: no indexer (no index weight, no `ki` rows, S_t = every
+live position: plain causal MLA, DeepSeek-V2/V3's and Kanana-2's).
+
 Two forms of the same numbers. EXPANDED (`forward`: predict, fit): K and V
-are built per head from the latents. ABSORBED (everything that reads a
+are built per head from the latents. Without a selection that is a dense
+causal attention with keys of d_nope + d_R and values of d_v, and it takes
+the Pallas flash kernels and their FlashAttention-2 backward
+(`pallas_kernels.flash_attention`, which takes the two widths apart; the one
+rotary key is broadcast to the heads to make the 192-wide operand) wherever
+`attention.flash_eligible` says a dense attention does, on one device; with a
+selection, or where the rule refuses (the CPU, a mesh), it is the blocked XLA
+form below, which is differentiable too. ABSORBED (everything that reads a
 cache): W_UK moves into the query and W_UV into the output,
 
     q_{t,i} . k_{s,i} = [q^nope_{t,i} W_UK,i^T ; q^rope_{t,i}] . [cKV_s ; kR_s]
@@ -255,9 +268,11 @@ class LatentAttention(Op):
     prefill_chunk_barrier = True
 
     def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
-                 q_lora_rank: int, kv_lora_rank: int, qk_nope_head_dim: int,
-                 qk_rope_head_dim: int, v_head_dim: int, index_n_heads: int,
-                 index_head_dim: int, index_topk: int,
+                 q_lora_rank: Optional[int], kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int,
+                 v_head_dim: int, index_n_heads: Optional[int] = None,
+                 index_head_dim: Optional[int] = None,
+                 index_topk: Optional[int] = None,
                  rope_theta: float = 10000.0,
                  rope_scaling: Optional[dict] = None, eps: float = 1e-6,
                  uq_init_gain: float = 1.0):
@@ -267,8 +282,19 @@ class LatentAttention(Op):
         self.d_nope, self.d_rope = qk_nope_head_dim, qk_rope_head_dim
         self.v_head_dim = v_head_dim
         self.index_n_heads, self.index_head_dim = index_n_heads, index_head_dim
-        self.index_topk = int(index_topk)
-        assert qk_rope_head_dim % 2 == 0 and index_head_dim >= qk_rope_head_dim
+        # None: no indexer, every live position is attended
+        self.index_topk = None if index_topk is None else int(index_topk)
+        self.indexed = self.index_topk is not None
+        assert qk_rope_head_dim % 2 == 0
+        if self.indexed:
+            assert index_n_heads and index_head_dim >= qk_rope_head_dim
+            if q_lora_rank is None:
+                raise ValueError(
+                    f"{name}: the indexer's queries are projected from the "
+                    f"compressed query (q_lora_rank), which this layer "
+                    f"does not have")
+        # the rows a token leaves in a cache
+        self._cached = ("lat", "ki") if self.indexed else ("lat",)
         self.rope_theta = float(rope_theta)
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         self.eps = eps
@@ -285,7 +311,8 @@ class LatentAttention(Op):
         self.rope_amp = yarn_mscale(factor, float(sc.get("mscale", 1.0))) \
             / all_dim if sc else 1.0
         self.scale = (self.d_nope + self.d_rope) ** -0.5 * all_dim * all_dim
-        self.index_scale = index_n_heads ** -0.5 * index_head_dim ** -0.5
+        if self.indexed:
+            self.index_scale = index_n_heads ** -0.5 * index_head_dim ** -0.5
         self.finalize()
 
     def output_shapes(self):
@@ -298,10 +325,20 @@ class LatentAttention(Op):
         dq = self.d_nope + self.d_rope
         J, dI, dv = self.index_n_heads, self.index_head_dim, self.v_head_dim
         g2 = self.uq_init_gain ** 2
-        return [
+        # a weight's seeded draw is keyed by its place in this list: the
+        # full form's order is fixed
+        query = [WeightSpec("w_q", (D, H, dq), fan=(D / g2, H * dq / g2))] \
+            if rq is None else [
             WeightSpec("w_dq", (D, rq)),
             WeightSpec("q_norm", (rq,), init="one"),
-            WeightSpec("w_uq", (rq, H, dq), fan=(rq / g2, H * dq / g2)),
+            WeightSpec("w_uq", (rq, H, dq), fan=(rq / g2, H * dq / g2))]
+        index = [] if not self.indexed else [
+            WeightSpec("w_iq", (rq, J, dI), fan=(rq, J * dI)),
+            WeightSpec("w_ik", (D, dI)),
+            WeightSpec("ik_norm_scale", (dI,), init="one"),
+            WeightSpec("ik_norm_bias", (dI,), init="zero"),
+            WeightSpec("w_iw", (D, J))]
+        return query + [
             WeightSpec("w_dkv", (D, c + self.d_rope)),
             WeightSpec("kv_norm", (c,), init="one"),
             WeightSpec("w_uk", (c, H, self.d_nope),
@@ -309,12 +346,7 @@ class LatentAttention(Op):
             WeightSpec("w_uv", (c, H, dv), fan=(c, H * dv)),
             WeightSpec("wo", (H, dv, self.embed_dim),
                        fan=(H * dv, self.embed_dim)),
-            WeightSpec("w_iq", (rq, J, dI), fan=(rq, J * dI)),
-            WeightSpec("w_ik", (D, dI)),
-            WeightSpec("ik_norm_scale", (dI,), init="one"),
-            WeightSpec("ik_norm_bias", (dI,), init="zero"),
-            WeightSpec("w_iw", (D, J)),
-        ]
+        ] + index
 
     # ---- projections -------------------------------------------------------
 
@@ -341,12 +373,16 @@ class LatentAttention(Op):
 
     def _project(self, params, a, pos):
         """Everything one slab of tokens a (B, S, D) at positions pos (B, S)
-        contributes: queries (`q_nope`, `q_rope` (B, S, H, .), `qi` (B, S, J,
-        dI), `w` (B, S, J) f32) and what is cached (`lat` (B, S, LAT) =
-        [cKV ; kR ; 0], `ki` (B, S, dI))."""
+        contributes: queries (`q_nope`, `q_rope` (B, S, H, .)) and what is
+        cached (`lat` (B, S, LAT) = [cKV ; kR ; 0]); with an indexer also
+        its queries (`qi` (B, S, J, dI), `w` (B, S, J) f32) and `ki`
+        (B, S, dI), cached too."""
         c = self.kv_lora_rank
-        cq = self._rms(a @ params["w_dq"], params["q_norm"])
-        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+        if self.q_lora_rank is None:
+            q = jnp.einsum("bsd,dhk->bshk", a, params["w_q"])
+        else:
+            cq = self._rms(a @ params["w_dq"], params["q_norm"])
+            q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
         kv = a @ params["w_dkv"]
         ckv = self._rms(kv[..., :c], params["kv_norm"])
         kr = rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp)
@@ -354,16 +390,19 @@ class LatentAttention(Op):
         lat = jnp.concatenate(
             [ckv, kr] + ([jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype)]
                          if pad else []), axis=-1)
-        qi = self._rope_head(
-            jnp.einsum("bsr,rjk->bsjk", cq, params["w_iq"]), pos)
-        ki = self._rope_head(self._layer_norm(
-            a @ params["w_ik"], params["ik_norm_scale"],
-            params["ik_norm_bias"]), pos)
-        w = (a @ params["w_iw"]).astype(jnp.float32) * self.index_scale
-        return {"q_nope": q[..., :self.d_nope],
-                "q_rope": rope_rotate(q[..., self.d_nope:], pos,
-                                      self.inv_freq, self.rope_amp),
-                "qi": qi, "w": w, "lat": lat, "ki": ki}
+        out = {"q_nope": q[..., :self.d_nope],
+               "q_rope": rope_rotate(q[..., self.d_nope:], pos,
+                                     self.inv_freq, self.rope_amp),
+               "lat": lat}
+        if self.indexed:
+            out["qi"] = self._rope_head(
+                jnp.einsum("bsr,rjk->bsjk", cq, params["w_iq"]), pos)
+            out["ki"] = self._rope_head(self._layer_norm(
+                a @ params["w_ik"], params["ik_norm_scale"],
+                params["ik_norm_bias"]), pos)
+            out["w"] = (a @ params["w_iw"]).astype(jnp.float32) \
+                * self.index_scale
+        return out
 
     def _absorb(self, params, q_nope, q_rope):
         """(..., H, LAT) queries against latent rows: [q_nope W_UK^T ;
@@ -385,18 +424,19 @@ class LatentAttention(Op):
         cap = max(1, _BLOCK_LOGIT_BYTES // (4 * self.num_heads * n_keys))
         return math.gcd(s, 1 << (cap.bit_length() - 1))
 
-    def _blocked(self, pr, frontier, row_len, prompt_pad, ki, attend):
-        """Selection and attention in blocks of query rows, so that neither
-        an (S, L) score matrix nor an (S, H, L) logit tensor ever exists
-        whole. Query row (b, s) may see key j iff  j < row_len[b]  or
-        prompt_pad[b] <= j <= frontier[b, s]; of those its index scores
-        keep index_topk. `attend(block, chosen)` -> (B, R, H, .) gets each
-        block's slices of `pr` and the (B, R, L) mask."""
+    def _blocked(self, pr, frontier, row_len, prompt_pad, L, ki, attend):
+        """Selection and attention in blocks of query rows against L keys,
+        so that neither an (S, L) score matrix nor an (S, H, L) logit
+        tensor ever exists whole. Query row (b, s) may see key j iff
+        j < row_len[b]  or  prompt_pad[b] <= j <= frontier[b, s]; of those
+        its index scores against `ki` (B, L, dI) keep index_topk (all of
+        them without an indexer). `attend(block, chosen)` -> (B, R, H, .)
+        gets each block's slices of `pr` and the (B, R, L) mask."""
         b, s = frontier.shape
-        L = ki.shape[1]
         r = self._row_block(s, L)
         j = jnp.arange(L, dtype=jnp.int32)
-        rows = {n: pr[n] for n in ("q_nope", "q_rope", "qi", "w")}
+        rows = {n: pr[n] for n in ("q_nope", "q_rope", "qi", "w")
+                if n in pr}
         rows["frontier"] = frontier
 
         def split(x):       # (B, S, ...) -> (S // r, B, r, ...)
@@ -407,6 +447,8 @@ class LatentAttention(Op):
             fr = blk["frontier"]                            # (B, r)
             live = (j < row_len[:, None, None]) | (
                 (j >= prompt_pad[:, None, None]) & (j <= fr[..., None]))
+            if not self.indexed:
+                return attend(blk, live)
             sc = jnp.einsum("brjd,bld->brjl", blk["qi"], ki.astype(
                 blk["qi"].dtype), preferred_element_type=jnp.float32)
             sc = jnp.einsum("brjl,brj->brl", jnp.maximum(sc, 0.0),
@@ -418,10 +460,11 @@ class LatentAttention(Op):
         out = jax.lax.map(one, {n: split(x) for n, x in rows.items()})
         return jnp.moveaxis(out, 0, 1).reshape((b, s) + out.shape[3:])
 
-    def _attend_latent(self, params, pr, lat, ki, frontier, row_len,
+    def _attend_latent(self, params, pr, cache, frontier, row_len,
                        prompt_pad):
-        """ABSORBED: pr's queries against cached rows lat (B, L, LAT), ki
-        (B, L, dI) -> (B, S, D)."""
+        """ABSORBED: pr's queries against cached rows `cache["lat"]` (B, L,
+        LAT) and, with an indexer, `cache["ki"]` (B, L, dI) -> (B, S, D)."""
+        lat, ki = cache["lat"], cache.get("ki")
         c = self.kv_lora_rank
         latc = lat.astype(pr["q_nope"].dtype)
 
@@ -437,7 +480,7 @@ class LatentAttention(Op):
             return jnp.einsum("brhc,chv->brhv", ctx, params["w_uv"])
 
         return self._out(params, self._blocked(
-            pr, frontier, row_len, prompt_pad, ki, attend))
+            pr, frontier, row_len, prompt_pad, lat.shape[1], ki, attend))
 
     def forward(self, params, xs, *, training=False, rng=None):
         """EXPANDED: per-head K and V from the slab's own latents, causal."""
@@ -453,6 +496,12 @@ class LatentAttention(Op):
              jnp.broadcast_to(kr[:, :, None, :], (b, s, self.num_heads,
                                                   self.d_rope))], axis=-1)
         v = jnp.einsum("blc,chv->blhv", ckv, params["w_uv"])
+        if self._takes_flash(s):
+            from flexflow_tpu.ops.pallas_kernels import flash_attention
+
+            q = jnp.concatenate([pr["q_nope"], pr["q_rope"]], axis=-1)
+            return [self._out(params, flash_attention(q, k, v, True,
+                                                      self.scale))]
 
         def attend(blk, chosen):
             q = jnp.concatenate([blk["q_nope"], blk["q_rope"]], axis=-1)
@@ -465,7 +514,19 @@ class LatentAttention(Op):
 
         zero = jnp.zeros((b,), jnp.int32)
         return [self._out(params, self._blocked(
-            pr, pos, zero, zero, pr["ki"], attend))]
+            pr, pos, zero, zero, s, pr.get("ki"), attend))]
+
+    def _takes_flash(self, s: int) -> bool:
+        """Whether `forward` over s tokens is the flash kernels: no
+        selection to apply (they know the causal mask alone), the dense
+        rule of ops/attention.py, and a program on one device (a Mosaic
+        call inside a GSPMD-partitioned program would run replicated)."""
+        from flexflow_tpu.ops.attention import flash_eligible
+
+        mesh = getattr(self.model, "mesh", None)
+        return (not self.indexed and (mesh is None or mesh.size == 1)
+                and flash_eligible(getattr(self.model, "config", None),
+                                   True, s, s))
 
     def selection(self, params, a):
         """(B, S, S) bool: the positions each row of a causal slab a
@@ -474,21 +535,22 @@ class LatentAttention(Op):
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         pr = self._project(params, a, pos)
         zero = jnp.zeros((b,), jnp.int32)
-        return self._blocked(pr, pos, zero, zero, pr["ki"],
+        return self._blocked(pr, pos, zero, zero, s, pr.get("ki"),
                              lambda blk, chosen: chosen[:, :, None])[:, :, 0]
 
     # ---- contiguous cache (generate(), and a request's prefill) ------------
 
     def init_cache(self, batch: int, max_len: int, dtype):
-        return {"lat": jnp.zeros((batch, max_len, self.lat_width), dtype),
-                "ki": jnp.zeros((batch, max_len, self.index_head_dim),
-                                dtype)}
+        cache = {"lat": jnp.zeros((batch, max_len, self.lat_width), dtype)}
+        if self.indexed:
+            cache["ki"] = jnp.zeros((batch, max_len, self.index_head_dim),
+                                    dtype)
+        return cache
 
-    @staticmethod
-    def _write(cache, pr, start):
+    def _write(self, cache, pr, start):
         return {n: jax.lax.dynamic_update_slice(
             cache[n], pr[n].astype(cache[n].dtype), (0, start, 0))
-            for n in ("lat", "ki")}
+            for n in self._cached}
 
     def chunk_forward(self, params, xs, cache, start):
         """Positions [start, start + C) of a prompt: write their rows,
@@ -503,8 +565,9 @@ class LatentAttention(Op):
         cache = self._write(cache, pr, start)
         keys = min(cache["lat"].shape[1], max(start + c, _MIN_CHUNK_KEYS))
         zero = jnp.zeros((b,), jnp.int32)
-        out = self._attend_latent(params, pr, cache["lat"][:, :keys],
-                                  cache["ki"][:, :keys], pos, zero, zero)
+        out = self._attend_latent(
+            params, pr, {n: cache[n][:, :keys] for n in self._cached}, pos,
+            zero, zero)
         return out, cache
 
     def prefill_forward(self, params, xs, cache):
@@ -516,8 +579,7 @@ class LatentAttention(Op):
         pr = self._project(params, xs[0], rope_pos[:, None])
         zero = jnp.zeros_like(row_lengths)
         out = self._attend_latent(
-            params, pr, cache["lat"], cache["ki"],
-            (row_lengths - 1)[:, None], zero, zero)
+            params, pr, cache, (row_lengths - 1)[:, None], zero, zero)
         return out, cache
 
     def decode_forward(self, params, xs, cache, pos, rope_pos=None,
@@ -531,7 +593,7 @@ class LatentAttention(Op):
         cache = self._write(cache, pr, pos)
         zero = jnp.zeros((b,), jnp.int32)
         out = self._attend_latent(
-            params, pr, cache["lat"], cache["ki"],
+            params, pr, cache,
             jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32),
             zero if row_lengths is None else row_lengths,
             zero if row_lengths is None else zero + prompt_len)
@@ -542,7 +604,8 @@ class LatentAttention(Op):
     def cache_bytes_per_token(self) -> int:
         """bf16 bytes one cached token takes in this op's pools, the
         latent row's padding lanes included."""
-        return (self.lat_width + self.index_head_dim) * 2
+        return (self.lat_width
+                + (self.index_head_dim if self.indexed else 0)) * 2
 
     def paged_kernel_shape(self, cache):
         """None: the kernel autotuner's table holds the K/V paged kernels,
@@ -561,6 +624,17 @@ class LatentAttention(Op):
 
     def init_paged_cache(self, num_pages: int, page_size: int, dtype,
                          kv_dtype=None):
+        if not self.indexed:
+            # the paged pool and its decode core are built around the
+            # selection (two pools on one page table, the selected rows as
+            # a list): refused here, where an engine first asks the op for
+            # anything, not somewhere inside a kernel
+            raise NotImplementedError(
+                f"{self.name}: a paged pool for a latent attention without "
+                f"an indexer (index_topk=None) is not built: the serving "
+                f"engine's paged decode reads the lightning indexer's "
+                f"selection; use generate() (contiguous cache) or "
+                f"predict()")
         sdtype, qmax = kv_storage_dtype(kv_dtype)
         if qmax is not None:
             raise NotImplementedError(
@@ -617,11 +691,10 @@ class LatentAttention(Op):
         if resolve_paged_attention_impl(
                 impl, getattr(self.model, "config", None)) != "pallas":
             b = page_table.shape[0]
-            lat = cache["lat"][page_table].reshape(b, -1, self.lat_width)
-            ki = cache["ki"][page_table].reshape(b, -1, self.index_head_dim)
-            return self._attend_latent(params, pr, lat, ki,
-                                       write_pos[:, None], row_len,
-                                       prompt_pad), cache
+            rows = {n: cache[n][page_table].reshape(
+                b, -1, cache[n].shape[-1]) for n in ("lat", "ki")}
+            return self._attend_latent(params, pr, rows, write_pos[:, None],
+                                       row_len, prompt_pad), cache
         from flexflow_tpu.ops.pallas_kernels import (
             dsa_index_scores_pallas, mla_gathered_core_pallas)
 
@@ -659,8 +732,11 @@ class LatentAttention(Op):
         proj = 2 * b * s * sum(
             int(np.prod(w.shape)) for w in self.weight_specs()
             if len(w.shape) > 1)
-        keys = min(s, self.index_topk)
+        # a causal row sees half the sequence on average, the selection
+        # at most index_topk of it
+        keys = min(s, self.index_topk) if self.indexed else s / 2
         core = 2 * b * s * self.num_heads * keys * (
             self.d_nope + self.d_rope + self.v_head_dim)
-        index = 2 * b * s * s * self.index_n_heads * self.index_head_dim
-        return proj + core + index
+        index = 2 * b * s * s * self.index_n_heads * self.index_head_dim \
+            if self.indexed else 0
+        return int(proj + core + index)

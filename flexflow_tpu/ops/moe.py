@@ -55,14 +55,29 @@ holds, and computes, only experts first .. first + count - 1, which is one
 chip's share of an expert-parallel layer (its output is the shared expert
 plus ITS experts' part of the routed sum; the exchange that adds the other
 chips' parts is not in this op).
+
+A held share under a gradient (`training=True`, fewer experts held than
+routed over): of the N*k assignments only about held / num_experts land
+here, and they sort to the front. The grouped lowering then gathers and
+multiplies `held_rows_cap` sorted rows a pass (HELD_ROWS_SLACK x the even
+share) instead of all N*k, and scatter-adds their gate-weighted results into
+the tokens, in as many passes as the held rows need (`MoE._held_passes`: a
+`while_loop` on the count, one pass under any routing within the slack of
+even). No token is dropped whatever the routing, and a step pays for the
+passes its rows fill. The backward runs the same passes with each pass's
+forward recomputed (a `custom_vjp`), so nothing of N*k rows, and nothing of a
+pass, is kept between the forward and the backward. Forward-only calls (a
+prefill chunk, a decode step) keep the all-rows form they had.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from flexflow_tpu.ffconst import DataType, OperatorType
@@ -98,6 +113,19 @@ def dropless_lowering(backend: str, expert: str, training: bool,
 
 def _backend() -> str:
     return jax.default_backend()
+
+
+# a held share's sorted rows kept under a gradient: this many times the rows
+# an even routing sends here, rounded up to whole tiles of rows
+HELD_ROWS_SLACK = 2.0
+HELD_ROWS_TILE = 256
+
+
+def held_rows_cap(n_tokens: int, k: int, held: int, num_experts: int) -> int:
+    """Rows of the compact form: at most all N*k of them."""
+    even = n_tokens * k * held / num_experts
+    tiles = -(-int(HELD_ROWS_SLACK * even) // HELD_ROWS_TILE)
+    return min(n_tokens * k, max(1, tiles) * HELD_ROWS_TILE)
 
 
 class MoE(Op):
@@ -217,7 +245,7 @@ class MoE(Op):
 
     def forward(self, params, xs, *, training=False, rng=None,
                 capacity=None, row_mask=None, routing=None,
-                lowerings=None):
+                lowerings=None, group_sizes=None):
         """Dropless op: `row_mask` (bool, the shape of x without its last
         dim; None = every row live) gives masked rows group size 0 and
         output 0, so the free slots of a decode batch stream no expert;
@@ -225,7 +253,9 @@ class MoE(Op):
         [assignments, experts hit] (traced values: the serving engine
         sums them inside its programs); `lowerings`, if a list, receives
         'streamed' or 'grouped', the lowering this call took (a static
-        fact, known while tracing).
+        fact, known while tracing); `group_sizes`, if a list, receives the
+        int32 (held experts,) rows each held expert got (a traced value:
+        the train step sums, counts and maxes it into its metrics).
 
         Capacity op: `capacity` overrides the build-time training capacity. The
         inference path (runtime/generation.py) passes N (the slab's token
@@ -243,7 +273,8 @@ class MoE(Op):
 
         if self.dropless:
             return self._forward_dropless(params, t, orig_shape, row_mask,
-                                          routing, training, lowerings)
+                                          routing, training, lowerings,
+                                          group_sizes)
         logits = t @ params["router"].astype(t.dtype)       # (N, E)
         gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         if self._use_sort_dispatch():
@@ -392,7 +423,7 @@ class MoE(Op):
         return (jax.nn.silu(t @ g) * (t @ u)) @ d
 
     def _forward_dropless(self, params, t, orig_shape, row_mask, routing,
-                          training, lowerings):
+                          training, lowerings, group_sizes=None):
         """No capacity, no dropped token: `_route`'s top-k, the held
         experts over exactly the (token, expert) pairs that chose them
         (grouped or streamed, see the module docstring), each token's
@@ -407,9 +438,13 @@ class MoE(Op):
         took = self.lowering(N, training, t.dtype)
         if lowerings is not None:
             lowerings.append(took)
-        experts = (self._experts_streamed if took == "streamed"
-                   else self._experts_grouped)
-        y, sizes = experts(params, t, top_g, top_e, live)
+        if took == "streamed":
+            y, sizes = self._experts_streamed(params, t, top_g, top_e, live)
+        else:
+            y, sizes = self._experts_grouped(params, t, top_g, top_e, live,
+                                             training)
+        if group_sizes is not None:
+            group_sizes.append(sizes)
         if routing is not None:
             routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
                            .astype(jnp.int32))
@@ -423,10 +458,13 @@ class MoE(Op):
             y = y + self._shared_expert(params, t)
         return [y.reshape(orig_shape), aux.astype(jnp.float32)]
 
-    def _experts_grouped(self, params, t, top_g, top_e, live):
+    def _experts_grouped(self, params, t, top_g, top_e, live,
+                         training=False):
         """(y (N, D) f32, sizes (E,) int32): the N*k assignments
         stable-sorted by expert, grouped matmuls over exactly those rows,
-        unsorted, gate-weighted and summed per token."""
+        unsorted, gate-weighted and summed per token. A held share under a
+        gradient works on `held_rows_cap` sorted rows a pass (module
+        docstring)."""
         lo, E, k = self.held_first, self.held_count, self.k
         N, D = t.shape
         flat_e = top_e.reshape(-1)                          # token-major
@@ -448,20 +486,113 @@ class MoE(Op):
             top_g = top_g * live[:, None]
         order = jnp.argsort(flat_e, stable=True)
         sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-        # unsort: where each (token, choice) landed in the sorted rows
-        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32))
-        rows = t[order // k]                                # (N*k, D)
-        out = self._expert_ffn(
-            params, rows, lambda x, w: jax.lax.ragged_dot(x, w, sizes))
-        if masked:
-            # rows past the last group belong to no expert; what a grouped
-            # matmul leaves there is unspecified
-            out = jnp.where((jnp.arange(N * k) < jnp.sum(sizes))[:, None],
-                            out, 0)
-        y = jnp.einsum("nk,nkd->nd", top_g,
-                       out[back].reshape(N, k, D).astype(jnp.float32))
+
+        def all_rows():
+            # unsort: where each (token, choice) landed in the sorted rows
+            back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+                jnp.arange(N * k, dtype=jnp.int32))
+            rows = t[order // k]                            # (N*k, D)
+            out = self._expert_ffn(
+                params, rows, lambda x, w: jax.lax.ragged_dot(x, w, sizes))
+            if masked:
+                # rows past the last group belong to no expert; what a
+                # grouped matmul leaves there is unspecified
+                out = jnp.where(
+                    (jnp.arange(N * k) < jnp.sum(sizes))[:, None], out, 0)
+            return jnp.einsum("nk,nkd->nd", top_g,
+                              out[back].reshape(N, k, D).astype(jnp.float32))
+
+        cap = N * k
+        if training and E != self.num_experts:
+            cap = held_rows_cap(N, k, E, self.num_experts)
+        if cap == N * k:
+            return all_rows(), sizes
+        # whole passes of `cap` sorted rows; a padding row is past every
+        # group, like a row held elsewhere
+        pad = -(N * k) % cap
+        order = jnp.concatenate([order, jnp.zeros((pad,), order.dtype)])
+        weights = tuple(params[n].astype(t.dtype)
+                        for n in ("w_gate", "w_up", "w_down"))
+        # `top_g` is already 0 where the assignment is not held here
+        y = self._held_passes(cap)(weights, t, top_g.reshape(-1), order,
+                                   sizes)
         return y, sizes
+
+    def _held_passes(self, cap: int):
+        """f(weights, t, gates (N*k,), order, sizes) -> (N, D) f32: the held
+        assignments' gate-weighted expert outputs summed into their tokens,
+        `cap` sorted rows a pass, as many passes as the held rows need (one
+        under an even routing; a `while_loop`, so a step that routes
+        everything here still drops nothing and a usual step pays for one
+        pass). Its gradient runs the same passes again, each pass's forward
+        recomputed, so nothing of N*k rows is kept between the two."""
+        k = self.k
+
+        def one_pass(weights, t, gates, order, sizes, p):
+            start = p * cap
+            front = jax.lax.dynamic_slice_in_dim(order, start, cap)
+            tok = front // k
+            ends = jnp.cumsum(sizes)
+            # the rows of each group that lie inside this pass's window
+            mine = jnp.clip(jnp.minimum(ends, start + cap)
+                            - jnp.maximum(ends - sizes, start), 0, None)
+            w_gate, w_up, w_down = weights
+            # rows past the pass's last group belong to no expert, and what
+            # a grouped matmul (or its transpose) leaves there is
+            # unspecified: on the chip, whatever the buffer held, NaN
+            # included. Every grouped product is masked where it leaves the
+            # matmul, and the rows where they enter, so that neither pass
+            # direction carries such a value on.
+            valid = (jnp.arange(cap) < jnp.sum(mine))[:, None]
+            out = self._expert_ffn(
+                {"w_gate": w_gate, "w_up": w_up, "w_down": w_down},
+                jnp.where(valid, t[tok], 0),
+                lambda x, w: jnp.where(
+                    valid, jax.lax.ragged_dot(x, w, mine), 0))
+            return jnp.zeros(t.shape, jnp.float32).at[tok].add(
+                out.astype(jnp.float32) * gates[front][:, None])
+
+        def passes(sizes):
+            return -(-jnp.sum(sizes) // cap)
+
+        @jax.custom_vjp
+        def held(weights, t, gates, order, sizes):
+            def body(c):
+                p, y = c
+                return p + 1, y + one_pass(weights, t, gates, order, sizes,
+                                           p)
+
+            return jax.lax.while_loop(
+                lambda c: c[0] < passes(sizes), body,
+                (jnp.int32(0), jnp.zeros(t.shape, jnp.float32)))[1]
+
+        def fwd(weights, t, gates, order, sizes):
+            return held(weights, t, gates, order, sizes), (
+                weights, t, gates, order, sizes)
+
+        def bwd(res, dy):
+            weights, t, gates, order, sizes = res
+            f32 = functools.partial(jnp.zeros_like, dtype=jnp.float32)
+
+            def body(c):
+                p, acc = c
+                _, pull = jax.vjp(
+                    lambda w, x, g: one_pass(w, x, g, order, sizes, p),
+                    weights, t, gates)
+                return p + 1, jax.tree.map(
+                    lambda a, d: a + d.astype(jnp.float32), acc, pull(dy))
+
+            _, (dw, dt, dg) = jax.lax.while_loop(
+                lambda c: c[0] < passes(sizes), body,
+                (jnp.int32(0), (jax.tree.map(f32, weights), f32(t),
+                                f32(gates))))
+            none = functools.partial(np.zeros, dtype=jax.dtypes.float0)
+            return (jax.tree.map(lambda d, w: d.astype(w.dtype), dw, weights),
+                    dt.astype(t.dtype), dg.astype(gates.dtype),
+                    none(order.shape), none(sizes.shape))
+
+        held.defvjp(fwd, bwd)
+        return held
 
     def _experts_streamed(self, params, t, top_g, top_e, live):
         """(y (N, D), sizes (E,) int32) through the expert-stream kernel:

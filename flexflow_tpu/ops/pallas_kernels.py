@@ -6,7 +6,11 @@ reference; role parity with the tuned cuDNN MHA kernel the reference calls
 at attention.cu:244). Currently: flash attention forward (online softmax)
 and the FlashAttention-2 style backward (logsumexp saved from the forward;
 per-tile recompute of the probs; separate dq and dk/dv kernels so each
-output tile is written once).
+output tile is written once). Keys and values may differ in width (q, k
+(.., d_qk), v and the output (.., d_v): latent attention's expanded form has
+keys of 192 and values of 128); the three calls carry stable names
+(`flash_attention_fwd`, `flash_attention_bwd_dq`, `flash_attention_bwd_dkv`)
+that a device trace shows.
 
 Streaming design (round-3 rework): the opposing sequence is NOT staged in
 VMEM. Every kernel runs on a 3-D grid (batch*heads, own-side blocks,
@@ -184,7 +188,10 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
                                need_lse: bool = True):
-    """q,k,v: (B, S, H, D) -> (out, lse|None).
+    """q, k: (B, S, H, d_qk), v: (B, S, H, d_v) -> (out (B*H, S_q, d_v),
+    lse|None). The key and value widths are independent (latent attention
+    has keys of 192 and values of 128): the logit contracts d_qk, the
+    accumulator and the output are d_v wide.
     Grid: (B*H, S_q/block_q, S_k/block_k) — K/V tiles stream through the
     innermost axis. block_q/block_k default to AUTO (the kernel_tune
     table, static 512-down heuristic cold); explicit values pin the tile
@@ -193,7 +200,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     only for the VJP and costs more HBM writes than the output itself at
     small head dims."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     block_q, block_k = _resolve_blocks("flash_fwd", sq, sk, d, q.dtype,
                                        block_q, block_k, batch=b,
                                        heads=h, causal=causal)
@@ -207,7 +214,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     # (B, S, H, D) -> (B*H, S, D)
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
 
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
                                block_k=block_k, causal=causal, scale=scale,
@@ -222,8 +229,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     else:
         def kv_map(i, j, t):
             return (i, t, 0)
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype)]
     if need_lse:
         out_specs.append(pl.BlockSpec((1, block_q, 8),
                                       lambda i, j, t: (i, j, 0)))
@@ -234,17 +241,17 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),      # output accumulator
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=_interpret(), name="flash_attention_fwd",
     )(qt, kt, vt)
     return (outs[0], outs[1]) if need_lse else (outs[0], None)
 
@@ -340,7 +347,7 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
                                block_k: Optional[int] = None, dlse=None,
                                delta_precomputed=None):
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     block_q, block_k = _resolve_blocks("flash_bwd", sq, sk, d, q.dtype,
                                        block_q, block_k, batch=b,
                                        heads=h, causal=causal)
@@ -350,9 +357,9 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
+    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
+    ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
     # delta_i = rowsum(do_i * o_i) — the softmax-normalization term of ds;
     # an lse cotangent (if the lse output is ever differentiated) folds in
     # as ds = p * (dp - delta + dlse), i.e. delta -= dlse. Loop callers
@@ -393,8 +400,8 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), kv_map),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
         ],
@@ -402,10 +409,10 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=_interpret(), name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dk, dvt = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
                           offset=offset),
@@ -413,25 +420,25 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), q_map),
             pl.BlockSpec((1, block_q, 8), q_map),
             pl.BlockSpec((1, block_q, 8), q_map),
         ],
         out_specs=[pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-                   pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0))],
+                   pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)],
+                   jax.ShapeDtypeStruct((b * h, sk, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=_interpret(), name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     def back(x, s):
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
-    return back(dq, sq), back(dk, sk), back(dv, sk)
+    return back(dq, sq), back(dk, sk), back(dvt, sk)
 
 
 # ----------------------------------------------------- fused add+layernorm
@@ -567,18 +574,19 @@ def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None):
     """Flash attention: Pallas forward + FlashAttention-2 Pallas backward
     (logsumexp residual; per-tile prob recompute; no S x S materialization
-    in either direction)."""
+    in either direction). q, k (B, S, H, d_qk) and v (B, S, H, d_v): the
+    two widths need not agree; the default scale is d_qk^-0.5."""
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = flash_attention_fwd_pallas(q, k, v, causal, s, need_lse=False)
-    b, sq, h, d = q.shape
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    b, sq, h, _ = q.shape
+    return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def _flash_fwd_rule(q, k, v, causal, scale):
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, lse = flash_attention_fwd_pallas(q, k, v, causal, s)
-    b, sq, h, d = q.shape
-    o = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    b, sq, h, _ = q.shape
+    o = out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
     return o, (q, k, v, o, lse)
 
 

@@ -27,7 +27,7 @@ from flexflow_tpu.parallel.mesh import mesh_shape_dict
 from flexflow_tpu.parallel.pconfig import ParallelConfig
 from flexflow_tpu.runtime.initializer import init_weight
 from flexflow_tpu.runtime.loss import compute_loss
-from flexflow_tpu.runtime.metrics import batch_metrics
+from flexflow_tpu.runtime.metrics import batch_metrics, routing_counts
 
 
 def resolve_axis_map(pc: ParallelConfig, mesh_shape: Dict[str, int],
@@ -270,9 +270,11 @@ class GraphExecutor:
     # ---- forward interpretation ---------------------------------------------
 
     def apply_graph(self, params, state, input_values: Dict[Any, jnp.ndarray],
-                    *, training: bool, rng) -> Tuple[Dict[Any, jnp.ndarray], Dict]:
+                    *, training: bool, rng,
+                    group_sizes=None) -> Tuple[Dict[Any, jnp.ndarray], Dict]:
         """Interpret the graph in topo order. Returns (tensor->value map,
-        new_state)."""
+        new_state). `group_sizes`, if a list, receives from each dropless
+        MoE op the rows each of its held experts got (ops/moe.py)."""
         vals: Dict[Any, jnp.ndarray] = dict(input_values)
         new_state: Dict[str, Dict] = {}
         # mixed precision: master params stay f32; compute runs in bf16 on the
@@ -308,6 +310,8 @@ class GraphExecutor:
                     "axis_map": self._op_axis_maps.get(op.name, {}),
                     "sp_mode": getattr(self.model.config, "sp_mode", "ring"),
                 }
+            if group_sizes is not None and getattr(op, "dropless", False):
+                kwargs["group_sizes"] = group_sizes
             # named_scope stamps the op name into the HLO metadata of every
             # instruction it traces, so a jax.profiler trace's device ops
             # of the PRODUCTION jitted program attribute back to graph ops — the
@@ -354,11 +358,14 @@ class GraphExecutor:
         builders."""
         input_ops = [op for op in self.model.ops if isinstance(op, InputOp)]
         aux_tensors = list(getattr(self.model, "_aux_tensors", ()))
+        routed = any(getattr(op, "dropless", False) for op in self.model.ops)
 
         def loss_fn(p, st, batch, rng):
             input_values = {op.outputs[0]: batch[op.name] for op in input_ops}
+            sizes = [] if routed else None
             vals, new_state = self.apply_graph(
-                p, st, input_values, training=True, rng=rng)
+                p, st, input_values, training=True, rng=rng,
+                group_sizes=sizes)
             logits = vals[final_tensor]
             loss = compute_loss(loss_type, logits, batch[label_key])
             for t in aux_tensors:  # e.g. MoE load-balancing losses
@@ -367,6 +374,8 @@ class GraphExecutor:
                 loss_type, metric_types, logits, batch[label_key],
                 ignore_index=getattr(self.model.config,
                                      "metrics_ignore_index", None))
+            if sizes:
+                mets.update(routing_counts(sizes))
             return loss, (new_state, mets)
 
         return loss_fn
@@ -450,6 +459,7 @@ class GraphExecutor:
             # counts and totals (accuracy_count/_total) sum across
             # microbatches; mean metrics average (equal sizes -> exact)
             mets = {k: (jnp.sum(v) if k.endswith(("_count", "_total"))
+                        else jnp.max(v) if k.endswith("_max")
                         else jnp.mean(v))
                     for k, v in mets.items()}
             new_params, new_opt_state = optimizer.update(params, grads,

@@ -68,6 +68,26 @@ class PerfMetrics:
                                         or self.train_all, 1)
 
 
+# what a train step says of its dropless MoE layers' routing, per step and
+# summed over the layers (the most rows any expert got: the max over them)
+ROUTING_COUNTS = ("moe_assignments_total", "moe_experts_hit_total",
+                  "moe_rows_max")
+
+
+def routing_counts(group_sizes) -> Dict[str, jnp.ndarray]:
+    """From each dropless MoE op's int32 (held experts,) rows per expert:
+    the (token, expert) assignments that landed on experts held here, the
+    held experts with at least one row, and the most rows any of them got.
+    Computed inside the jitted step and carried out with its metrics."""
+    return {
+        "moe_assignments_total": sum(jnp.sum(s) for s in group_sizes),
+        "moe_experts_hit_total": sum(jnp.sum(s > 0, dtype=jnp.int32)
+                                     for s in group_sizes),
+        "moe_rows_max": jnp.max(jnp.stack([jnp.max(s)
+                                           for s in group_sizes])),
+    }
+
+
 def batch_metrics(loss_type: LossType, metric_types: Sequence[MetricsType],
                   logits, labels,
                   ignore_index: int = None) -> Dict[str, jnp.ndarray]:

@@ -635,11 +635,14 @@ class Tracer:
                  track: Optional[str] = None, **args):
         """Record a span retrospectively from perf_counter() instants —
         for phases measured anyway (fit's host_wait/h2d/dispatch) where
-        a live span would double the clock reads."""
+        a live span would double the clock reads. Returns the ring's
+        event (None when telemetry is off): a caller whose counts are
+        still on the device (fit()'s routing counts, read at the epoch's
+        sync) adds them to its "args" when they arrive."""
         if not _enabled:
-            return
-        self._emit(name, "X", (t0_s - _EPOCH) * 1e6, dur_s * 1e6,
-                   trace_id, track, args)
+            return None
+        return self._emit(name, "X", (t0_s - _EPOCH) * 1e6, dur_s * 1e6,
+                          trace_id, track, args)
 
     def instant(self, name: str, trace_id: Optional[str] = None,
                 track: Optional[str] = None, **args):
