@@ -12,13 +12,20 @@ keys of 192 and values of 128); the three calls carry stable names
 (`flash_attention_fwd`, `flash_attention_bwd_dq`, `flash_attention_bwd_dkv`)
 that a device trace shows.
 
-Streaming design (round-3 rework): the opposing sequence is NOT staged in
-VMEM. Every kernel runs on a 3-D grid (batch*heads, own-side blocks,
-opposing-side blocks) whose innermost axis streams opposing-side tiles
-through VMEM while f32 scratch accumulators (persistent across the
-sequential inner grid axis) carry the online-softmax / gradient state.
-VMEM use is therefore O(block^2) regardless of sequence length — the 4k
-sequence cap of the staged round-2 kernels is gone.
+Streaming design: the opposing sequence is NOT staged in VMEM. Every flash
+kernel runs on a 3-D grid (batch*heads, own-side blocks, opposing-side
+blocks) whose innermost axis streams opposing-side tiles through VMEM while
+f32 scratch accumulators (persistent across the sequential inner grid axis)
+carry the online-softmax / gradient state, so VMEM use is O(block^2)
+whatever the sequence length. The tile is 1024 x 1024 wherever the sequence
+divides by it (`_OUTER_BLOCK`, `_pick_block` below it), and a step takes it
+256 rows of the kernel's own side at a time (`_CHUNK`). Under causality a
+tile is dead (skipped, its DMA clamped), interior (no mask is built) or
+crossed by the diagonal (masked; where tiles are square and the offset a
+whole number of them, each chunk multiplies only the part it can see):
+`flash_tile_counts` counts the three classes by the kernels' own rule. On a
+v5e the three kernels' steps are then bound by their MXU pushes (PERF.md
+section 6, PR 33).
 
 Kernels compile through Mosaic; interpret mode is an explicit request
 (FF_PALLAS_INTERPRET=1, see _interpret).
@@ -37,7 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# m/l scratch rows are stored broadcast across one f32 lane tile
+# one f32 lane tile: the width of the forward's m / l scratch rows
 LANES = 128
 
 
@@ -65,31 +72,35 @@ def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
 
 def _pick_block(seq: int, want: int) -> int:
     """Largest tile size <= want that divides seq (the guard in
-    attention._flash_ok only promises 128-divisibility, so a 512 default
-    must degrade for e.g. seq 640). This is the STATIC heuristic — the
-    cold fallback when the measured-cost autotune table
-    (search/kernel_tune.py) has no entry for the shape."""
-    for b in (want, 256, 128, 64, 32, 16, 8):
-        if b <= seq and seq % b == 0:
+    attention.flash_eligible only promises 128-divisibility, so a 512
+    default must degrade for e.g. seq 640). This is the STATIC heuristic:
+    the only one there is wherever the measured-cost autotune table
+    (search/kernel_tune.py) has no entry for the shape, which is every
+    machine that never ran the tuner."""
+    for b in (want, 512, 256, 128, 64, 32, 16, 8):
+        if b <= min(want, seq) and seq % b == 0:
             return b
     return seq
+
+
+# The tile a grid step DMAs, and the rows of the kernel's own side (queries
+# in the forward and dq, keys in dkv) its arithmetic takes at a time.
+_OUTER_BLOCK = 1024
+_CHUNK = 256
 
 
 def _resolve_blocks(kernel: str, sq: int, sk: int, d: int, dtype,
                     want_q, want_k, *, batch: int = 1, heads: int = 1,
                     causal: bool = True):
-    """(block_q, block_k) for a flash kernel call. want_q/want_k = None
-    (the public API's default) means AUTO: the measured-cost autotune
-    table (search/kernel_tune.py, keyed by kernel/shape incl. dtype,
-    batch, heads, causality/device kind/jax version) wins when it has a
-    legal entry for this exact configuration, else the static
-    _pick_block heuristic from the 512 default (legality and hit/miss
-    accounting live in lookup_blocks). Explicit wants (the tuner's own
-    candidate sweep, callers pinning a block) bypass the table
-    entirely. Round-5 context: the static 512 default lost to XLA at
-    h4096 — a tuned table turns that into a re-measurable decision
-    instead of a hardcoded loss. Resolution happens at TRACE time
-    (shapes are static), so a warm program pays nothing."""
+    """(block_q, block_k) of a flash kernel call: the tile one grid step
+    DMAs. want_q/want_k = None (the public API's default) means AUTO: the
+    measured-cost table (search/kernel_tune.py, keyed by kernel, shape
+    incl. dtype, batch, heads, causality, device kind and jax version) wins
+    where it has a legal entry for this exact call; everywhere else (a
+    machine that never ran the tuner has no table) the static rule:
+    `_pick_block` down from `_OUTER_BLOCK`. Explicit wants (the tuner's own
+    sweep, a caller pinning a block) bypass the table. Resolution happens at
+    TRACE time (shapes are static), so a warm program pays nothing."""
     if want_q is None and want_k is None:
         from flexflow_tpu.search import kernel_tune
 
@@ -99,38 +110,128 @@ def _resolve_blocks(kernel: str, sq: int, sk: int, d: int, dtype,
                                         causal=causal)
         if hit is not None:
             return hit
-        want_q = want_k = 512
-    return (_pick_block(sq, want_q if want_q is not None else 512),
-            _pick_block(sk, want_k if want_k is not None else 512))
+    return (_pick_block(sq, want_q if want_q is not None else _OUTER_BLOCK),
+            _pick_block(sk, want_k if want_k is not None else _OUTER_BLOCK))
 
 
-def _maybe_when(cond, fn):
-    """Run fn under pl.when(cond), or directly when the guard is statically
-    always-true (non-causal paths) — no branch emitted in the kernel."""
-    if cond is None:
-        fn()
-    else:
-        pl.when(cond)(fn)
+def _chunk_rows(block: int) -> int:
+    """Rows of its own side a kernel's step takes at a time: `_CHUNK` where
+    it divides the block, else the block whole (sequences under 128, blocks
+    of 8 in tests). The chunks of a step are independent chains in one
+    basic block (one's matmul beside another's exponentials), and the grain
+    at which an aligned diagonal tile's dead part is left out."""
+    return _CHUNK if block % _CHUNK == 0 else block
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, offset):
-    """Causal mask with the cross-attention diagonal offset: row q attends
-    k_pos <= q_pos + offset, offset = sk - sq (bottom-right alignment, the
-    same convention as the einsum path's tril(k=sk-sq) — reference vendor
-    kernel handled distinct q/kv lengths, attention.cu:533-570). offset is
-    a static python int; offset=0 is plain self-attention causality."""
-    bq, bk = s.shape
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
+def _diagonal_aligned(block_q: int, block_k: int, offset: int) -> bool:
+    """Whether every tile the diagonal crosses has it from its own first
+    corner to its last (square tiles, the offset a whole number of them):
+    then a diagonal tile's visible part is known when the kernel is traced,
+    and each chunk multiplies only the opposing rows it can see."""
+    return block_q == block_k and offset % block_k == 0
+
+
+def flash_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
+                      offset: int, causal: bool) -> dict:
+    """{"live", "masked", "dead"} tiles of ONE head's (sq, sk) logits cut
+    into (block_q, block_k) tiles, by the rule the kernels branch on: a
+    tile is dead when its last query row does not see its first key, masked
+    when it is live and its first query row does not see its last key (the
+    diagonal crosses it: the only tiles that build a mask), and `live`
+    counts masked and interior tiles together. Row q sees key k where
+    k <= q + offset."""
+    live = masked = 0
+    for qi in range(sq // block_q):
+        for ki in range(sk // block_k):
+            is_live, interior = _tile_classes(qi, ki, block_q, block_k,
+                                              offset) if causal \
+                else (True, True)
+            live += is_live
+            masked += is_live and not interior
+    return {"live": live, "masked": masked,
+            "dead": (sq // block_q) * (sk // block_k) - live}
+
+
+def _tile_classes(qi, ki, block_q: int, block_k: int, offset: int):
+    """(live, interior) of tile (qi, ki) under the causal rule: the ONE
+    predicate, on the kernels' traced grid indices and on
+    `flash_tile_counts`' python ints alike."""
+    live = (qi + 1) * block_q + offset > ki * block_k
+    interior = qi * block_q + offset >= (ki + 1) * block_k - 1
+    return live, interior
+
+
+def _run_tile(step, causal: bool, qi, ki, block_q, block_k, offset):
+    """Run a kernel's `step(masked)` on the grid step's tile: a causal
+    kernel holds two bodies, the interior tile's (no mask anywhere in it)
+    and the diagonal tile's, and a dead tile runs neither; a non-causal
+    kernel holds the unmasked body alone, under no branch."""
+    if not causal:
+        step(False)
+        return
+    live, interior = _tile_classes(qi, ki, block_q, block_k, offset)
+    pl.when(interior)(functools.partial(step, False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        functools.partial(step, True))
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T, contracting the minor dims
+
+
+def _dot_nt(a, b):
+    return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _row_minus_col(rows: int, cols: int):
+    """int32 (rows, cols) of row index minus column index: with queries down
+    the rows, the query `first` positions (offset added) past column 0's key
+    sees column c from row r where this is >= -first; with keys down the
+    rows (dkv), where it is <= first."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _reach(masked: bool, aligned: bool, r0: int, chunk: int, block_k: int,
+           tile_first):
+    """(width, first) for query rows r0 .. r0 + chunk of a tile: the leading
+    keys of the tile they are multiplied against, and how far the first of
+    these rows' queries is past the tile's first key (offset added). On an
+    aligned diagonal tile both are known when the kernel is traced and the
+    keys no row of the chunk sees are left out; elsewhere the width is the
+    tile's and `first` follows from `tile_first`, the traced position of
+    the tile's first query against its first key."""
+    if masked and aligned:
+        return min(block_k, r0 + chunk), r0
+    return block_k, tile_first + r0
+
+
+def _across(x, width: int):
+    """(rows, 128) whose lanes are equal -> (rows, width) without a lane
+    broadcast where the lanes divide the width."""
+    if width % LANES == 0:
+        return x if width == LANES else pltpu.repeat(x, width // LANES,
+                                                     axis=1)
+    return jnp.broadcast_to(x[:, 0:1], (x.shape[0], width))
 
 
 # ---------------------------------------------------------------- forward
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
-                      block_k: int, causal: bool, scale: float,
+                      block_k: int, chunk: int, causal: bool, scale: float,
                       need_lse: bool, offset: int = 0):
+    """One (block_q, block_k) tile a grid step, `chunk` query rows at a
+    time. The running max and sum live LANE-WIDE in their (block_q, 128)
+    scratch: every lane of m holds the row's max, so it meets the logits'
+    lane tiles and the output rows with no lane broadcast (a (rows, 1)
+    statistic read from one lane costs a cross-lane permute a vector
+    register wherever it meets a tile: PERF.md section 6, PR 33), and l
+    holds one partial sum a lane (over the keys that fell on that lane), so
+    a step adds lane tiles and only `_finish` reduces across lanes."""
     if need_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -138,6 +239,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    dv = acc_scr.shape[1]
+    aligned = _diagonal_aligned(block_q, block_k, offset)
+    # the tile's first query against its first key, offset added
+    tile_first = qi * block_q + offset - ki * block_k
+    # a block the lanes do not divide (sequences under 128, blocks of 8 in
+    # tests) keeps the whole row sum in every lane of l instead
+    tiled = block_k % LANES == 0
 
     @pl.when(ki == 0)
     def _init():
@@ -145,34 +253,40 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: a k tile strictly after the (offset-shifted) last row of this
-    # q tile is dead
-    live = (qi + 1) * block_q + offset > ki * block_k if causal else None
+    def step(masked: bool):
+        for r0 in range(0, block_q, chunk):
+            rows = pl.ds(r0, chunk)
+            width, first = _reach(masked, aligned, r0, chunk, block_k,
+                                  tile_first)
+            q = q_ref[0, rows, :]   # native dtype into the MXU (bf16 fast
+            # path; accumulation stays f32 via preferred_element_type)
+            k = k_ref[0, 0:width, :]
+            v = v_ref[0, 0:width, :]
+            s = _dot_nt(q, k) * scale                       # (chunk, width)
+            if masked:
+                s = jnp.where(_row_minus_col(chunk, width) >= -first, s,
+                              NEG_INF)
+            m_prev = m_scr[rows, :]                         # (chunk, 128)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _across(m_new, width))
+            if tiled:
+                l_add = p[:, 0:LANES]
+                for j in range(LANES, width, LANES):
+                    l_add = l_add + p[:, j:j + LANES]
+            else:
+                l_add = jnp.sum(p, axis=-1, keepdims=True)
+            l_scr[rows, :] = l_scr[rows, :] * alpha + l_add
+            acc_scr[rows, :] = acc_scr[rows, :] * _across(alpha, dv) \
+                + _dot(p.astype(v.dtype), v)
+            m_scr[rows, :] = m_new
 
-    def _step():
-        q = q_ref[0]  # (block_q, d) — native dtype into the MXU (bf16 fast
-        # path; accumulation stays f32 via preferred_element_type)
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        m_prev = m_scr[:, 0:1]                      # (bq, 1)
-        l_prev = l_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                      # (bq, bk)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    _maybe_when(live, _step)
+    _run_tile(step, causal, qi, ki, block_q, block_k, offset)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_scr[:, 0:1]
+        l = l_scr[...]
+        l = jnp.sum(l, axis=-1, keepdims=True) if tiled else l[:, 0:1]
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
         if need_lse:
             # lse lives in an 8-lane padded layout: Mosaic wants the last two
@@ -193,23 +307,41 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     has keys of 192 and values of 128): the logit contracts d_qk, the
     accumulator and the output are d_v wide.
     Grid: (B*H, S_q/block_q, S_k/block_k) — K/V tiles stream through the
-    innermost axis. block_q/block_k default to AUTO (the kernel_tune
-    table, static 512-down heuristic cold); explicit values pin the tile
-    (degraded to a divisor of seq) and skip the table. need_lse=False
-    (inference) skips materializing the logsumexp residual — it exists
-    only for the VJP and costs more HBM writes than the output itself at
-    small head dims."""
+    innermost axis. block_q/block_k default to AUTO (`_resolve_blocks`: the
+    kernel_tune table, else the static rule down from `_OUTER_BLOCK`);
+    explicit values pin the tile (degraded to a divisor of seq) and skip the
+    table. need_lse=False (inference) skips materializing the logsumexp
+    residual — it exists only for the VJP and costs more HBM writes than
+    the output itself at small head dims."""
     b, sq, h, d = q.shape
-    sk, dv = k.shape[1], v.shape[3]
+    sk = k.shape[1]
     block_q, block_k = _resolve_blocks("flash_fwd", sq, sk, d, q.dtype,
                                        block_q, block_k, batch=b,
                                        heads=h, causal=causal)
     assert sq % block_q == 0 and sk % block_k == 0
-    # cross-attention diagonal offset (bottom-right aligned causality);
     # sq > sk with causal would leave the first rows keyless (0/0 in the
-    # online softmax) — refused upstream in attention._flash_ok
+    # online softmax) — refused upstream in attention.flash_eligible
+    assert not (causal and sk < sq), "causal flash needs sq <= sk"
+    return _flash_fwd_call(q, k, v, causal=causal, scale=float(scale),
+                           block_q=block_q, block_k=block_k,
+                           need_lse=need_lse, interpret=_interpret())
+
+
+# inline=True: traced once per shape and re-emitted under each caller's
+# named scope, so a program's layers share one trace and one Mosaic lowering
+# of each kernel (as `moe_expert_stream_pallas`): a kernel body of two tile
+# classes times four chunks is some hundred jax calls to trace, and a
+# five-layer step holds it fifteen times. What a cached trace must not
+# freeze (the tune table's answer, interpret mode) is resolved by the
+# callers above and comes in as a static argument.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "need_lse", "interpret"))
+def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
+                    interpret):
+    b, sq, h, d = q.shape
+    sk, dv = k.shape[1], v.shape[3]
+    # cross-attention diagonal offset (bottom-right aligned causality)
     offset = sk - sq
-    assert not (causal and offset < 0), "causal flash needs sq <= sk"
 
     # (B, S, H, D) -> (B*H, S, D)
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
@@ -217,7 +349,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
 
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale,
+                               block_k=block_k, chunk=_chunk_rows(block_q),
+                               causal=causal, scale=scale,
                                need_lse=need_lse, offset=offset)
     if causal:
         # clamp dead (fully-masked) inner steps to the last live tile: the
@@ -251,7 +384,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
             pltpu.VMEM((block_q, dv), jnp.float32),      # output accumulator
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(), name="flash_attention_fwd",
+        interpret=interpret, name="flash_attention_fwd",
     )(qt, kt, vt)
     return (outs[0], outs[1]) if need_lse else (outs[0], None)
 
@@ -261,36 +394,39 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *, block_q: int, block_k: int,
-                         causal: bool, scale: float, offset: int = 0):
-    """One q tile, k/v tiles streaming: dq = scale * sum_j ds_j @ k_j,
-    ds = p * (do @ v^T - delta)."""
+                         chunk: int, causal: bool, scale: float,
+                         offset: int = 0):
+    """One q tile, k/v tiles streaming, `chunk` query rows at a time:
+    dq = scale * sum_j ds_j @ k_j, ds = p * (do @ v^T - delta)."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    aligned = _diagonal_aligned(block_q, block_k, offset)
+    # the tile's first query against its first key, offset added
+    tile_first = qi * block_q + offset - ki * block_k
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = (qi + 1) * block_q + offset > ki * block_k if causal else None
+    def step(masked: bool):
+        for r0 in range(0, block_q, chunk):
+            rows = pl.ds(r0, chunk)
+            width, first = _reach(masked, aligned, r0, chunk, block_k,
+                                  tile_first)
+            q = q_ref[0, rows, :]
+            do = do_ref[0, rows, :]
+            lse = lse_ref[0, rows, 0:1]     # (chunk, 1): 8-lane layout
+            delta = delta_ref[0, rows, 0:1]
+            k = k_ref[0, 0:width, :]
+            v = v_ref[0, 0:width, :]
+            p = jnp.exp(_dot_nt(q, k) * scale - lse)        # (chunk, width)
+            if masked:
+                p = jnp.where(_row_minus_col(chunk, width) >= -first, p, 0.0)
+            ds = (p * (_dot_nt(do, v) - delta)).astype(k.dtype)
+            dq_scr[rows, :] = dq_scr[rows, :] + _dot(ds, k)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0:1]     # (block_q, 1) — lane-padded layout
-        delta = delta_ref[0, :, 0:1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse)                                # (bq, bk)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        dq_scr[...] = dq_scr[...] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
-
-    _maybe_when(live, _step)
+    _run_tile(step, causal, qi, ki, block_q, block_k, offset)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -299,42 +435,49 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
-                          block_k: int, causal: bool, scale: float,
-                          offset: int = 0):
-    """One k tile, q/do tiles streaming:
-    dv = sum_i p_i^T @ do_i; dk = scale * sum_i ds_i^T @ q_i."""
+                          block_k: int, chunk: int, causal: bool,
+                          scale: float, offset: int = 0):
+    """One k tile, q/do tiles streaming, `chunk` keys at a time, on the
+    TRANSPOSED logits s^T = k q^T (keys down the rows, queries along the
+    lanes), so that neither product needs a transpose: dv = sum_i p_i^T @
+    do_i, dk = scale * sum_i ds_i^T @ q_i, with lse and delta laid along
+    lanes (lse_ref / delta_ref: (1, 1, block_q))."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
+    aligned = _diagonal_aligned(block_q, block_k, offset)
+    # the tile's first query against its first key, offset added
+    tile_first = qi * block_q + offset - ki * block_k
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # causal: a q tile strictly before the (offset-shifted) first row of
-    # this k tile sees nothing of it
-    live = (qi + 1) * block_q + offset > ki * block_k if causal else None
+    def step(masked: bool):
+        for r0 in range(0, block_k, chunk):
+            rows = pl.ds(r0, chunk)
+            # the queries that see these keys of a diagonal tile, where
+            # that is known
+            start = min(r0, block_q) if masked and aligned else 0
+            width = block_q - start
+            k = k_ref[0, rows, :]
+            v = v_ref[0, rows, :]
+            q = q_ref[0, start:block_q, :]
+            do = do_ref[0, start:block_q, :]
+            lse = lse_ref[0, :, start:block_q]              # (1, width)
+            delta = delta_ref[0, :, start:block_q]
+            p = jnp.exp(_dot_nt(k, q) * scale - lse)        # (chunk, width)
+            if masked:
+                # rows are keys here: the first streamed query is `first`
+                # positions (offset added) past the chunk's first key
+                first = start - r0 if aligned else tile_first - r0
+                p = jnp.where(_row_minus_col(chunk, width) <= first, p, 0.0)
+            dv_scr[rows, :] = dv_scr[rows, :] + _dot(p.astype(do.dtype), do)
+            ds = (p * (_dot_nt(v, do) - delta)).astype(q.dtype)
+            dk_scr[rows, :] = dk_scr[rows, :] + _dot(ds, q)
 
-    def _step():
-        k = k_ref[0]   # (block_k, d)
-        v = v_ref[0]
-        q = q_ref[0]   # (block_q, d)
-        do = do_ref[0]
-        lse = lse_ref[0, :, 0:1]
-        delta = delta_ref[0, :, 0:1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k, offset)
-        p = jnp.exp(s - lse)                               # (bq, bk)
-        dv_scr[...] = dv_scr[...] + jnp.dot(
-            p.astype(do.dtype).T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_scr[...] = dk_scr[...] + jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32)
-
-    _maybe_when(live, _step)
+    _run_tile(step, causal, qi, ki, block_q, block_k, offset)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -346,14 +489,31 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
                                scale: float, block_q: Optional[int] = None,
                                block_k: Optional[int] = None, dlse=None,
                                delta_precomputed=None):
+    """(dq, dk, dv) of `flash_attention_fwd_pallas`'s output o (B, S_q, H,
+    d_v) under the cotangent do, from its lse (B*H, S_q, 8): two calls,
+    `flash_attention_bwd_dq` and `flash_attention_bwd_dkv`. Blocks as the
+    forward's."""
     b, sq, h, d = q.shape
-    sk, dv = k.shape[1], v.shape[3]
+    sk = k.shape[1]
     block_q, block_k = _resolve_blocks("flash_bwd", sq, sk, d, q.dtype,
                                        block_q, block_k, batch=b,
                                        heads=h, causal=causal)
     assert sq % block_q == 0 and sk % block_k == 0
+    assert not (causal and sk < sq), "causal flash needs sq <= sk"
+    return _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed,
+                           causal=causal, scale=float(scale),
+                           block_q=block_q, block_k=block_k,
+                           interpret=_interpret())
+
+
+# inline=True: as `_flash_fwd_call`
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret"))
+def _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed, *, causal,
+                    scale, block_q, block_k, interpret):
+    b, sq, h, d = q.shape
+    sk, dv = k.shape[1], v.shape[3]
     offset = sk - sq
-    assert not (causal and offset < 0), "causal flash needs sq <= sk"
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -372,8 +532,6 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
                         axis=-1)
     if dlse is not None:
         delta = delta - dlse.reshape(b * h, sq).astype(jnp.float32)
-    # broadcast into the same 8-lane padded layout as lse
-    delta = jnp.broadcast_to(delta[..., None], (b * h, sq, 8))
 
     if causal:
         # dead-tile clamps (see forward): masked inner steps re-reference a
@@ -382,20 +540,24 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
             return (i, jnp.minimum(
                 t, ((j + 1) * block_q - 1 + offset) // block_k), 0)
 
-        def q_map(i, j, t):
+        def q_tile(j, t):
             # first q tile whose last row reaches this k tile: q_pos >=
             # j*block_k - offset (floor div handles the negative numerator)
-            return (i, jnp.maximum(t, (j * block_k - offset) // block_q), 0)
+            return jnp.maximum(t, (j * block_k - offset) // block_q)
     else:
         def kv_map(i, j, t):
             return (i, t, 0)
 
-        q_map = kv_map
+        def q_tile(j, t):
+            return t
+
+    def q_map(i, j, t):
+        return (i, q_tile(j, t), 0)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          offset=offset),
+                          block_k=block_k, chunk=_chunk_rows(block_q),
+                          causal=causal, scale=scale, offset=offset),
         grid=(b * h, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
@@ -409,21 +571,28 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(), name="flash_attention_bwd_dq",
-    )(qt, kt, vt, dot, lse, delta)
+        interpret=interpret, name="flash_attention_bwd_dq",
+    )(qt, kt, vt, dot, lse,
+      # delta in the same 8-lane padded layout as lse
+      jnp.broadcast_to(delta[..., None], (b * h, sq, 8)))
 
+    # the dkv kernel works on transposed logits, so its two per-query rows
+    # lie along lanes
+    rows = (b * h, 1, sq)
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda i, j, t: (i, 0, q_tile(j, t)))
     dk, dvt = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          offset=offset),
+                          block_k=block_k, chunk=_chunk_rows(block_k),
+                          causal=causal, scale=scale, offset=offset),
         grid=(b * h, sk // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
             pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((1, block_q, dv), q_map),
-            pl.BlockSpec((1, block_q, 8), q_map),
-            pl.BlockSpec((1, block_q, 8), q_map),
+            row_spec,
+            row_spec,
         ],
         out_specs=[pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
                    pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0))],
@@ -432,8 +601,8 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(), name="flash_attention_bwd_dkv",
-    )(qt, kt, vt, dot, lse, delta)
+        interpret=interpret, name="flash_attention_bwd_dkv",
+    )(qt, kt, vt, dot, lse[..., 0].reshape(rows), delta.reshape(rows))
 
     def back(x, s):
         return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)

@@ -3,10 +3,10 @@
 The original FlexFlow thesis (PAPERS.md, "Beyond Data and Model
 Parallelism") drives every placement decision from MEASURED on-device
 costs; the same discipline applies one level down, to kernel tile sizes.
-Round 5 showed why: the flash kernels' static 512-block default lost to
-XLA's fused einsum at hidden 4096 — a hardcoded heuristic cannot know
-where a given chip generation's MXU/VMEM balance tips. This module makes
-block choice a measurement:
+A hardcoded tile cannot know where a given chip generation's MXU/VMEM
+balance tips (the flash kernels' static default is what a v5e measured:
+``ops/pallas_kernels._OUTER_BLOCK``). This module makes block choice a
+measurement:
 
   * ``tune_flash_attention`` sweeps ``(block_q, block_k)`` candidates for
     one (seq, head_dim, dtype) shape through the dispatch-floor timing
@@ -19,7 +19,7 @@ block choice a measurement:
     silently serving stale tiles;
   * ``ops/pallas_kernels._resolve_blocks`` consults ``lookup_blocks`` at
     trace time, falling back to the static ``_pick_block`` heuristic on
-    a miss (cold behavior is byte-identical to the pre-tuner code).
+    a miss (a machine that never ran the tuner has no table).
 
 Re-run the tuner after a hardware/jax change::
 
@@ -43,7 +43,8 @@ from typing import Dict, Optional, Sequence, Tuple
 # sequence) are skipped per shape
 DEFAULT_CANDIDATES: Tuple[Tuple[int, int], ...] = (
     (128, 128), (128, 256), (256, 128), (256, 256),
-    (256, 512), (512, 256), (512, 512))
+    (256, 512), (512, 256), (512, 512),
+    (512, 1024), (1024, 512), (1024, 1024))
 
 # in-memory table cache: {path: (file_stat_sig, {key: entry})} — keyed by
 # the file's (mtime_ns, size) so an out-of-process re-tune (the documented
@@ -464,9 +465,9 @@ def tune_paged_prefill(*, page_size: int = 16, pages_per_slot: int = 8,
 def static_blocks(seq_q: int, seq_k: int) -> Tuple[int, int]:
     """What the cold fallback would pick — recorded next to tuned picks
     so benches/tests can state whether tuning CHANGED the decision."""
-    from flexflow_tpu.ops.pallas_kernels import _pick_block
+    from flexflow_tpu.ops.pallas_kernels import _OUTER_BLOCK, _pick_block
 
-    return _pick_block(seq_q, 512), _pick_block(seq_k, 512)
+    return _pick_block(seq_q, _OUTER_BLOCK), _pick_block(seq_k, _OUTER_BLOCK)
 
 
 def tune_flash_attention(seq_q: int, seq_k: Optional[int] = None, *,

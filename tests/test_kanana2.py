@@ -243,8 +243,11 @@ def _qkv(b, s, h, dqk, dv, seed=0):
 
 
 def _oracle(q, k, v, scale):
+    """Causal attention by a float32 einsum; with more keys than queries row
+    q sees k <= q + sk - sq."""
     logits = jnp.einsum("bqhk,bshk->bhqs", q, k) * scale
-    mask = jnp.tril(jnp.ones(logits.shape[-2:], bool))
+    sq, sk = logits.shape[-2:]
+    mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
     p = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
     return jnp.einsum("bhqs,bshv->bqhv", p, v)
 
@@ -278,6 +281,114 @@ def test_flash_kernels_take_key_and_value_widths_apart(flash_case, what):
     # float32 tiles against a float32 einsum, values of order 1: measured
     # 2e-6
     np.testing.assert_allclose(got[what], want[what], atol=3e-5, rtol=0)
+
+
+# ---- the classes of a causal tile: dead, interior, crossed by the diagonal ---
+
+
+# name: (queries, keys, pinned block or None for the static default). 640 is
+# no multiple of 512: tiles of 128, whole-tile chunks. 1024 under tiles of
+# 512 walks chunks of 256, and its diagonal tiles leave their dead part out;
+# 1024_of_1280 has tiles of 512 x 256, which the diagonal crosses anywhere.
+WALKED = {"640": (640, 640, None), "640_of_1280": (640, 1280, None),
+          "1024": (1024, 1024, 512), "1024_of_1536": (1024, 1536, 512),
+          "1024_of_1280": (1024, 1280, 512)}
+WIDTHS = {"128x128": (128, 128), "192x128": (192, 128)}
+
+
+@pytest.fixture(scope="module", params=[(n, w) for n in sorted(WALKED)
+                                        for w in sorted(WIDTHS)],
+                ids=lambda p: f"{p[0]}-{p[1]}")
+def walked_case(request):
+    from flexflow_tpu.ops.pallas_kernels import (_resolve_blocks,
+                                                 flash_tile_counts)
+
+    (sq, sk, block), (dqk, dv) = (WALKED[request.param[0]],
+                                  WIDTHS[request.param[1]])
+    b, h = 1, 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (b, sq, h, dqk))
+    k = jax.random.normal(ks[1], (b, sk, h, dqk))
+    v = jax.random.normal(ks[2], (b, sk, h, dv))
+    do = jax.random.normal(ks[3], (b, sq, h, dv))
+    scale = dqk ** -0.5
+    bq, bk = _resolve_blocks("flash_fwd", sq, sk, dqk, q.dtype, block, block)
+    counts = flash_tile_counts(sq, sk, bq, bk, sk - sq, True)
+    # every class of tile is there to be walked
+    assert min(counts["live"] - counts["masked"], counts["masked"],
+               counts["dead"]) >= 1, counts
+
+    def heads_last(x):
+        return x.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
+
+    out, lse = flash_attention_fwd_pallas(q, k, v, True, scale, block, block)
+    o = heads_last(out)
+    got = dict(zip(("dq", "dk", "dv"), flash_attention_bwd_pallas(
+        q, k, v, o, lse, do, True, scale, block, block)))
+    got["forward"] = o
+    out, none = flash_attention_fwd_pallas(q, k, v, True, scale, block,
+                                           block, need_lse=False)
+    assert none is None
+    got["forward_without_lse"] = heads_last(out)
+    want_o, vjp = jax.vjp(lambda q, k, v: _oracle(q, k, v, scale),
+                          q, k, v)
+    want = dict(zip(("dq", "dk", "dv"), vjp(do)))
+    want["forward"] = want["forward_without_lse"] = want_o
+    return got, want
+
+
+@pytest.mark.parametrize("what", ["forward", "forward_without_lse", "dq",
+                                  "dk", "dv"])
+def test_flash_kernels_walk_every_class_of_tile(walked_case, what):
+    got, want = walked_case
+    assert got[what].shape == want[what].shape
+    # float32 tiles against a float32 einsum, values of order 1, rows of up
+    # to 1536 keys: measured 2e-6
+    np.testing.assert_allclose(got[what], want[what], atol=5e-5, rtol=0)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_only_a_causal_kernel_builds_a_mask(causal):
+    """The non-causal kernels are traced without a mask (no iota anywhere in
+    the forward's or the backward's jaxpr) and without a branch on the
+    tile's class; the causal ones hold both."""
+    q, k, v, do = _qkv(1, 256, 1, 48, 32)
+    scale = 48 ** -0.5
+
+    def both(q, k, v, do):
+        out, lse = flash_attention_fwd_pallas(q, k, v, causal, scale)
+        o = out.reshape(1, 1, 256, 32).transpose(0, 2, 1, 3)
+        return flash_attention_bwd_pallas(q, k, v, o, lse, do, causal, scale)
+
+    text = str(jax.make_jaxpr(both)(q, k, v, do))
+    assert text.count("pallas_call") == 3
+    assert ("iota" in text) == causal
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,want", [
+    (4096, 4096, 512, 512, (36, 8, 28)),    # the issue's reading
+    (4096, 4096, 1024, 1024, (10, 4, 6)),   # the static default's tiles
+    (4096, 4096, 256, 256, (136, 16, 120)),
+    (1024, 1280, 512, 256, (8, 4, 2)),
+    (640, 1280, 128, 128, (40, 5, 10)),
+    (96, 136, 8, 8, (138, 12, 66)),
+])
+def test_tile_counts_match_the_mask(sq, sk, bq, bk, want):
+    """`flash_tile_counts` against a brute-force count over the mask itself:
+    a tile is live where any of its elements is visible, masked where some
+    but not all are."""
+    from flexflow_tpu.ops.pallas_kernels import flash_tile_counts
+
+    mask = np.tril(np.ones((sq, sk), bool), k=sk - sq)
+    tiles = mask.reshape(sq // bq, bq, sk // bk, bk).transpose(0, 2, 1, 3)
+    live = tiles.any(axis=(2, 3))
+    brute = {"live": int(live.sum()),
+             "masked": int((live & ~tiles.all(axis=(2, 3))).sum()),
+             "dead": int((~live).sum())}
+    assert flash_tile_counts(sq, sk, bq, bk, sk - sq, True) == brute
+    assert tuple(brute[c] for c in ("live", "masked", "dead")) == want
+    assert flash_tile_counts(sq, sk, bq, bk, sk - sq, False) == {
+        "live": live.size, "masked": 0, "dead": 0}
 
 
 def test_flash_attention_vjp_at_widths_apart():

@@ -51,6 +51,31 @@ def test_cold_fallback_is_static_heuristic(table):
         == (128, 128)
 
 
+def test_static_rule_is_legal_for_every_admitted_sequence(table):
+    """With no table (the driver's machine has none) the static rule alone
+    picks the tiles: for every sequence `flash_eligible` admits to the TPU
+    (128 to 8192 in steps of 128) the outer tile divides the sequence and is
+    whole lane tiles (what a (1, 1, block_q) row of lse needs), and the rows
+    a step takes at a time divide the tile and are whole lane tiles too."""
+    from flexflow_tpu.ops.pallas_kernels import (_OUTER_BLOCK, _chunk_rows,
+                                                 flash_tile_counts)
+
+    for seq in range(128, 8192 + 1, 128):
+        for kernel in ("flash_fwd", "flash_bwd"):
+            bq, bk = _resolve_blocks(kernel, seq, seq, 128, jnp.bfloat16,
+                                     None, None)
+            assert bq == bk == _pick_block(seq, _OUTER_BLOCK)
+            assert seq % bq == 0 and bq % 128 == 0 and bq <= _OUTER_BLOCK
+            chunk = _chunk_rows(bq)
+            assert bq % chunk == 0 and chunk % 128 == 0
+        counts = flash_tile_counts(seq, seq, bq, bk, 0, True)
+        assert counts["masked"] == seq // bq    # the diagonal's own tiles
+    assert kernel_tune.stats()["hits"] == 0
+    assert _pick_block(4096, _OUTER_BLOCK) == 1024
+    assert _pick_block(1536, _OUTER_BLOCK) == 512
+    assert _pick_block(640, _OUTER_BLOCK) == 128
+
+
 def test_record_roundtrip_and_resolve(table):
     sig = kernel_tune.shape_sig(seq_q=640, seq_k=640, head_dim=64,
                                 dtype=jnp.float32, batch=1, heads=1,
