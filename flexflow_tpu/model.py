@@ -38,6 +38,7 @@ from flexflow_tpu.ops.tensor_ops import (Concat, Gather, Pad, Reshape, Reverse,
 from flexflow_tpu.parallel.mesh import make_mesh
 from flexflow_tpu.parallel.strategy import (load_strategies_from_file,
                                             save_strategies_to_file)
+from flexflow_tpu.runtime import profiler
 from flexflow_tpu.runtime.executor import GraphExecutor
 from flexflow_tpu.runtime.loss import loss_type_from_name
 from flexflow_tpu.runtime.metrics import (ROUTING_COUNTS, PerfMetrics,
@@ -73,6 +74,9 @@ class FFModel:
         self._step_count = 0
         self._train_step = None
         self._train_scan = None
+        # name -> profiler.Program of a train program this model has run:
+        # the process-wide registry holds them weakly
+        self._registered = {}
         # divergence-guarded step + its device-resident guard carry
         # (runtime/resilience.py; built in compile() when
         # config.on_nonfinite != "none")
@@ -662,6 +666,7 @@ class FFModel:
             if getattr(cfg, "overlap_grad_sync", False):
                 self.optimizer = self._maybe_shard_optimizer(self.optimizer)
             self.opt_state = self.optimizer.init_state(self.params)
+            self._registered = {}   # programs of an earlier compile()
             self._train_step = self.executor.make_train_step(
                 self.optimizer, self.loss_type, self.metric_types,
                 self._loss_tensor)
@@ -838,22 +843,47 @@ class FFModel:
             # split, bitwise-identical trajectory while finite; non-finite
             # steps leave params/opt state untouched in-graph. inject_nan
             # is the FF_FAULT nan_loss hook (a traced arg — no recompile).
+            args = (self.params, self.opt_state, self.bn_state, sharded,
+                    step_key, self._guard_state,
+                    jnp.asarray(bool(inject_nan)))
+            self._register_program("train_step", self._guarded_step, args)
             (self.params, self.opt_state, self.bn_state, loss, mets,
-             self._guard_state) = self._guarded_step(
-                self.params, self.opt_state, self.bn_state, sharded,
-                step_key, self._guard_state, jnp.asarray(bool(inject_nan)))
+             self._guard_state) = self._guarded_step(*args)
         else:
             if inject_nan:
                 raise RuntimeError(
                     "nan_loss injection needs the in-graph divergence "
                     "guard: set FFConfig.on_nonfinite before compile()")
+            args = (self.params, self.opt_state, self.bn_state, sharded,
+                    step_key)
+            self._register_program("train_step", self._train_step, args)
             (self.params, self.opt_state, self.bn_state, loss, mets) = \
-                self._train_step(self.params, self.opt_state, self.bn_state,
-                                 sharded, step_key)
+                self._train_step(*args)
         self._step_count += 1
         self._last_loss = loss
         self._last_metrics = mets
         return loss, mets
+
+    def _register_program(self, name: str, fn, args):
+        """A call of a train program notes its abstract arguments in the
+        process-wide registry (runtime/profiler.py), where
+        ``program_scopes()`` can lower it again when asked, if the jitted
+        function has compiled since the last note: on a mesh the second
+        call's arguments carry the shardings the first call's outputs took
+        and compile a second executable, the one that runs from then on.
+        Every later call pays one dict lookup, the count of the function's
+        executables and the question whether a profiler trace is running.
+        Nothing for an executor that jits per placement group (no one
+        program to lower)."""
+        prog = self._registered.get(name)
+        if hasattr(fn, "lower") and (
+                prog is None
+                or prog.compiles != profiler.executables(fn)):
+            prog = self._registered[name] = profiler.register_program(
+                name, fn, args, profiler.graph_op_phases(self))
+        if prog is not None and profiler.tracing():
+            # a traced slice's tables are read after the window
+            profiler.note_traced(prog)
 
     def _scan_eligible(self) -> bool:
         """Scanned multi-step training needs one program over one mesh
@@ -892,9 +922,11 @@ class FFModel:
         start = (self._dataloaders[0].next_index
                  // self._dataloaders[0].batch_size) % nb
         self._rng, scan_key = jax.random.split(self._rng)
+        args = (self.params, self.opt_state, self.bn_state, staged, scan_key,
+                start, n_steps)
+        self._register_program("train_scan", self._train_scan, args)
         (self.params, self.opt_state, self.bn_state, losses, mets) = \
-            self._train_scan(self.params, self.opt_state, self.bn_state,
-                             staged, scan_key, start, n_steps)
+            self._train_scan(*args)
         for dl in self._dataloaders:  # keep per-step verbs in sync
             dl.next_index = ((start + n_steps) % nb) * dl.batch_size
         self._step_count += n_steps

@@ -213,6 +213,7 @@ class MultiHeadAttention(Op):
     # implements the cache protocol generate() and the serving engine drive
     # (init_cache ... gather_paged_kv); ops/mla.py is the other op that does
     kv_cache_protocol = True
+    kernel_phase = "core"   # profiler.scope_table: an unnamed Mosaic call
 
     def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
                  kdim: int = 0, vdim: int = 0, dropout: float = 0.0,
@@ -301,19 +302,20 @@ class MultiHeadAttention(Op):
         """Shared projection: (B,S,D) x (D,H,Hd) -> (B,S,H,Hd) for q and
         (B,S,KVH,Hd) for k/v, bias and RoPE applied, BEFORE any GQA
         broadcast — the KV cache stores this pre-broadcast layout."""
-        qh = jnp.einsum("bsd,dhk->bshk", q, params["wq"])
-        kh = jnp.einsum("bsd,dhk->bshk", k, params["wk"])
-        vh = jnp.einsum("bsd,dhk->bshk", v, params["wv"])
-        if self.bias:
-            qh = qh + params["bias_q"]
-            kh = kh + params["bias_k"]
-            vh = vh + params["bias_v"]
-        if self.qk_norm:
-            qh = self._whole_rms_norm(qh, params["q_norm"])
-            kh = self._whole_rms_norm(kh, params["k_norm"])
-        if self.rope:
-            qh = _apply_rope(qh, self.rope_theta, rope_offset)
-            kh = _apply_rope(kh, self.rope_theta, rope_offset)
+        with jax.named_scope("project"):
+            qh = jnp.einsum("bsd,dhk->bshk", q, params["wq"])
+            kh = jnp.einsum("bsd,dhk->bshk", k, params["wk"])
+            vh = jnp.einsum("bsd,dhk->bshk", v, params["wv"])
+            if self.bias:
+                qh = qh + params["bias_q"]
+                kh = kh + params["bias_k"]
+                vh = vh + params["bias_v"]
+            if self.qk_norm:
+                qh = self._whole_rms_norm(qh, params["q_norm"])
+                kh = self._whole_rms_norm(kh, params["k_norm"])
+            if self.rope:
+                qh = _apply_rope(qh, self.rope_theta, rope_offset)
+                kh = _apply_rope(kh, self.rope_theta, rope_offset)
         return qh, kh, vh
 
     def _whole_rms_norm(self, xh, scale):
@@ -330,14 +332,16 @@ class MultiHeadAttention(Op):
             # GQA: broadcast each kv head to its query group; downstream
             # paths (flash / ring / einsum) then see plain MHA shapes
             rep = self.num_heads // self.num_kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
+            with jax.named_scope("project"):
+                kh = jnp.repeat(kh, rep, axis=2)
+                vh = jnp.repeat(vh, rep, axis=2)
         return kh, vh
 
     def _out_proj(self, params, ctx):
-        out = jnp.einsum("bqhk,hkd->bqd", ctx, params["wo"])
-        if self.bias:
-            out = out + params["bias_o"]
+        with jax.named_scope("out"):
+            out = jnp.einsum("bqhk,hkd->bqd", ctx, params["wo"])
+            if self.bias:
+                out = out + params["bias_o"]
         return out
 
     def forward(self, params, xs, *, training=False, rng=None, shard_ctx=None):
@@ -351,8 +355,9 @@ class MultiHeadAttention(Op):
             seq_axes = [ax for ax, d in (shard_ctx.get("axis_map") or {}).items()
                         if d == 1 and shard_ctx["mesh"].shape[ax] > 1]
         if seq_axes:
-            ctx = self._sp_attention(qh, kh, vh, shard_ctx, seq_axes, scale,
-                                     training, rng)
+            with jax.named_scope("core"):
+                ctx = self._sp_attention(qh, kh, vh, shard_ctx, seq_axes,
+                                         scale, training, rng)
         else:
             ctx = self._dense_attention(qh, kh, vh, scale, training, rng,
                                         shard_ctx)
@@ -380,12 +385,13 @@ class MultiHeadAttention(Op):
         """Full-prompt forward that also fills cache[:, :S]. Reuses the
         dense attention path (flash on TPU) for the prompt itself."""
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2])
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(
-                cache["k"], kh.astype(cache["k"].dtype), (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                cache["v"], vh.astype(cache["v"].dtype), (0, 0, 0, 0)),
-        }
+        with jax.named_scope("project"):
+            new_cache = {
+                "k": jax.lax.dynamic_update_slice(
+                    cache["k"], kh.astype(cache["k"].dtype), (0, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(
+                    cache["v"], vh.astype(cache["v"].dtype), (0, 0, 0, 0)),
+            }
         kh, vh = self._broadcast_kv(kh, vh)
         scale = 1.0 / math.sqrt(self.qk_head_dim)
         ctx = self._dense_attention(qh, kh, vh, scale, False, None, None)
@@ -402,13 +408,15 @@ class MultiHeadAttention(Op):
         kvh = self.num_kv_heads
         grp = self.num_heads // kvh
         scale = 1.0 / math.sqrt(self.qk_head_dim)
-        qg = qh.reshape(b, c, kvh, grp, self.qk_head_dim)
-        logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck.astype(qh.dtype),
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(live, logits, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(logits, axis=-1).astype(qh.dtype)
-        ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs, cv.astype(qh.dtype))
-        return ctx.reshape(b, c, self.num_heads, self.v_head_dim)
+        with jax.named_scope("core"):
+            qg = qh.reshape(b, c, kvh, grp, self.qk_head_dim)
+            logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck.astype(qh.dtype),
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(live, logits, jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(logits, axis=-1).astype(qh.dtype)
+            ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs,
+                             cv.astype(qh.dtype))
+            return ctx.reshape(b, c, self.num_heads, self.v_head_dim)
 
     def chunk_forward(self, params, xs, cache, start):
         """Chunked prefill: a (B, C, D) slab of prompt positions
@@ -422,10 +430,11 @@ class MultiHeadAttention(Op):
         runtime/generation.py notes)."""
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=start)
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], kh.astype(cache["k"].dtype), (0, start, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], vh.astype(cache["v"].dtype), (0, start, 0, 0))
+        with jax.named_scope("project"):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], kh.astype(cache["k"].dtype), (0, start, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], vh.astype(cache["v"].dtype), (0, start, 0, 0))
         c = qh.shape[1]
         end = start + c  # python ints: a static slice of the live prefix
         live = (jnp.arange(end)[None, :]
@@ -482,10 +491,11 @@ class MultiHeadAttention(Op):
         qh, kh, vh = self._project_qkv(
             params, xs[0], xs[1], xs[2],
             rope_offset=pos if rope_pos is None else rope_pos)
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], kh.astype(cache["k"].dtype), (0, pos, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
+        with jax.named_scope("project"):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], kh.astype(cache["k"].dtype), (0, pos, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
         idx = jnp.arange(ck.shape[1])
         if row_lengths is None:
             live = (idx <= pos)[None, :]
@@ -551,6 +561,11 @@ class MultiHeadAttention(Op):
             from flexflow_tpu.ops.pallas_kernels import \
                 paged_prefill_write_pallas
             return paged_prefill_write_pallas(cache, kh, vh, pages)
+        with jax.named_scope("core"):
+            return self._prefill_write_xla(cache, kh, vh, pages)
+
+    def _prefill_write_xla(self, cache, kh, vh, pages):
+        """`paged_prefill_write`'s 'einsum' branch: one scatter a tensor."""
         page_size = cache["k"].shape[1]
         n_pages = pages.shape[0]
         pad = n_pages * page_size - kh.shape[1]
@@ -678,11 +693,12 @@ class MultiHeadAttention(Op):
         the borrower attends exactly the (lossy) values the donor's
         decode attention sees."""
         out = {}
-        for name in ("k", "v"):
-            x = cache[name][pages]                          # (n,ps,KVH,D)
-            if name + "_scale" in cache:
-                x = page_dequantize(x, cache[name + "_scale"][pages])
-            out[name] = x.reshape(1, -1, *x.shape[2:])
+        with jax.named_scope("gather"):
+            for name in ("k", "v"):
+                x = cache[name][pages]                      # (n,ps,KVH,D)
+                if name + "_scale" in cache:
+                    x = page_dequantize(x, cache[name + "_scale"][pages])
+                out[name] = x.reshape(1, -1, *x.shape[2:])
         return out
 
     def _paged_attention_ctx(self, qh, cache, page_table, write_pos,
@@ -716,22 +732,28 @@ class MultiHeadAttention(Op):
                 paged_attention_fwd_pallas
 
             scale = 1.0 / math.sqrt(self.qk_head_dim)
+            # directly under the op's scope: the device names an unnamed
+            # Mosaic call after its innermost scope, and the benchmark's
+            # readers know this one as `attn_<i>` (scope_table books it to
+            # `kernel_phase`)
             return paged_attention_fwd_pallas(
                 qh, ck, cv, page_table, write_pos, row_len, prompt_pad,
                 scale, k_scales=cache.get("k_scale"),
                 v_scales=cache.get("v_scale"))
         b = qh.shape[0]
         max_len = page_table.shape[1] * ck.shape[1]
-        gk, gv = ck[page_table], cv[page_table]     # (B, P, ps, KVH, D)
-        if "k_scale" in cache:
-            gk = page_dequantize(gk, cache["k_scale"][page_table])
-            gv = page_dequantize(gv, cache["v_scale"][page_table])
-        gk = gk.reshape(b, max_len, *gk.shape[3:])
-        gv = gv.reshape(b, max_len, *gv.shape[3:])
-        idx = jnp.arange(max_len)
-        live = (idx[None, None, :] < row_len[:, None, None]) \
-            | ((idx[None, None, :] >= prompt_pad[:, None, None])
-               & (idx[None, None, :] <= write_pos[:, :, None]))
+        with jax.named_scope("gather"):
+            gk, gv = ck[page_table], cv[page_table]  # (B, P, ps, KVH, D)
+            if "k_scale" in cache:
+                gk = page_dequantize(gk, cache["k_scale"][page_table])
+                gv = page_dequantize(gv, cache["v_scale"][page_table])
+            gk = gk.reshape(b, max_len, *gk.shape[3:])
+            gv = gv.reshape(b, max_len, *gv.shape[3:])
+        with jax.named_scope("core"):
+            idx = jnp.arange(max_len)
+            live = (idx[None, None, :] < row_len[:, None, None]) \
+                | ((idx[None, None, :] >= prompt_pad[:, None, None])
+                   & (idx[None, None, :] <= write_pos[:, :, None]))
         return self._grouped_cache_attention(
             qh, gk, gv, live[:, None, None, :, :])
 
@@ -757,11 +779,12 @@ class MultiHeadAttention(Op):
         page_size = cache["k"].shape[1]
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=rope_pos)
-        page_ids = jnp.take_along_axis(
-            page_table, (write_pos // page_size)[:, None], axis=1)[:, 0]
-        offs = write_pos % page_size
-        cache = self._paged_append(cache, kh[:, 0], vh[:, 0], page_ids,
-                                   offs)
+        with jax.named_scope("project"):
+            page_ids = jnp.take_along_axis(
+                page_table, (write_pos // page_size)[:, None], axis=1)[:, 0]
+            offs = write_pos % page_size
+            cache = self._paged_append(cache, kh[:, 0], vh[:, 0], page_ids,
+                                       offs)
         ctx = self._paged_attention_ctx(qh, cache, page_table,
                                         write_pos[:, None], row_len,
                                         prompt_pad, impl)
@@ -806,15 +829,17 @@ class MultiHeadAttention(Op):
             # when the slab stays in one page, but slab positions can
             # span pages — the per-position form is the one that is
             # correct for every (write_pos, page boundary) layout.
-            for i in range(kh.shape[1]):
-                cache = self._paged_append(cache, kh[:, i], vh[:, i],
-                                           page_ids[:, i], offs[:, i])
+            with jax.named_scope("project"):
+                for i in range(kh.shape[1]):
+                    cache = self._paged_append(cache, kh[:, i], vh[:, i],
+                                               page_ids[:, i], offs[:, i])
         else:
             cache = dict(cache)
-            cache["k"] = cache["k"].at[page_ids, offs].set(
-                kh.astype(cache["k"].dtype))
-            cache["v"] = cache["v"].at[page_ids, offs].set(
-                vh.astype(cache["v"].dtype))
+            with jax.named_scope("project"):
+                cache["k"] = cache["k"].at[page_ids, offs].set(
+                    kh.astype(cache["k"].dtype))
+                cache["v"] = cache["v"].at[page_ids, offs].set(
+                    vh.astype(cache["v"].dtype))
         ctx = self._paged_attention_ctx(qh, cache, page_table, write_pos,
                                         row_len, prompt_pad, impl)
         return self._out_proj(params, ctx), cache
@@ -833,6 +858,13 @@ class MultiHeadAttention(Op):
         use_dropout = training and self.dropout > 0.0 and rng is not None
         if not use_dropout and self._flash_ok(qh, kh):
             return self._flash_dense(qh, kh, vh, scale, shard_ctx)
+        with jax.named_scope("core"):
+            return self._xla_attention(qh, kh, vh, scale, training, rng,
+                                       use_dropout)
+
+    def _xla_attention(self, qh, kh, vh, scale, training, rng, use_dropout):
+        """`_dense_attention` where flash is refused: blockwise past
+        BLOCKWISE_SEQ_THRESHOLD, else the plain einsum."""
         sq, sk = qh.shape[1], kh.shape[1]
         if max(sq, sk) > BLOCKWISE_SEQ_THRESHOLD \
                 and self.qk_head_dim == self.v_head_dim:

@@ -267,6 +267,8 @@ class LatentAttention(Op):
     # them were live at once (runtime/generation.py `_prefill`)
     prefill_chunk_barrier = True
 
+    kernel_phase = "core"   # profiler.scope_table: an unnamed Mosaic call
+
     def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
                  q_lora_rank: Optional[int], kv_lora_rank: int,
                  qk_nope_head_dim: int, qk_rope_head_dim: int,
@@ -378,45 +380,49 @@ class LatentAttention(Op):
         its queries (`qi` (B, S, J, dI), `w` (B, S, J) f32) and `ki`
         (B, S, dI), cached too."""
         c = self.kv_lora_rank
-        if self.q_lora_rank is None:
-            q = jnp.einsum("bsd,dhk->bshk", a, params["w_q"])
-        else:
-            cq = self._rms(a @ params["w_dq"], params["q_norm"])
-            q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
-        kv = a @ params["w_dkv"]
-        ckv = self._rms(kv[..., :c], params["kv_norm"])
-        kr = rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp)
-        pad = self.lat_width - c - self.d_rope
-        lat = jnp.concatenate(
-            [ckv, kr] + ([jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype)]
-                         if pad else []), axis=-1)
-        out = {"q_nope": q[..., :self.d_nope],
-               "q_rope": rope_rotate(q[..., self.d_nope:], pos,
-                                     self.inv_freq, self.rope_amp),
-               "lat": lat}
+        with jax.named_scope("project"):
+            if self.q_lora_rank is None:
+                q = jnp.einsum("bsd,dhk->bshk", a, params["w_q"])
+            else:
+                cq = self._rms(a @ params["w_dq"], params["q_norm"])
+                q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+            kv = a @ params["w_dkv"]
+            ckv = self._rms(kv[..., :c], params["kv_norm"])
+            kr = rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp)
+            pad = self.lat_width - c - self.d_rope
+            lat = jnp.concatenate(
+                [ckv, kr] + ([jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype)]
+                             if pad else []), axis=-1)
+            out = {"q_nope": q[..., :self.d_nope],
+                   "q_rope": rope_rotate(q[..., self.d_nope:], pos,
+                                         self.inv_freq, self.rope_amp),
+                   "lat": lat}
         if self.indexed:
-            out["qi"] = self._rope_head(
-                jnp.einsum("bsr,rjk->bsjk", cq, params["w_iq"]), pos)
-            out["ki"] = self._rope_head(self._layer_norm(
-                a @ params["w_ik"], params["ik_norm_scale"],
-                params["ik_norm_bias"]), pos)
-            out["w"] = (a @ params["w_iw"]).astype(jnp.float32) \
-                * self.index_scale
+            with jax.named_scope("index"):
+                out["qi"] = self._rope_head(
+                    jnp.einsum("bsr,rjk->bsjk", cq, params["w_iq"]), pos)
+                out["ki"] = self._rope_head(self._layer_norm(
+                    a @ params["w_ik"], params["ik_norm_scale"],
+                    params["ik_norm_bias"]), pos)
+                out["w"] = (a @ params["w_iw"]).astype(jnp.float32) \
+                    * self.index_scale
         return out
 
     def _absorb(self, params, q_nope, q_rope):
         """(..., H, LAT) queries against latent rows: [q_nope W_UK^T ;
         q_rope ; 0]."""
-        qa = jnp.einsum("...hk,chk->...hc", q_nope, params["w_uk"])
-        pad = self.lat_width - self.kv_lora_rank - self.d_rope
-        parts = [qa, q_rope]
-        if pad:
-            parts.append(jnp.zeros(qa.shape[:-1] + (pad,), qa.dtype))
-        return jnp.concatenate(parts, axis=-1)
+        with jax.named_scope("project"):
+            qa = jnp.einsum("...hk,chk->...hc", q_nope, params["w_uk"])
+            pad = self.lat_width - self.kv_lora_rank - self.d_rope
+            parts = [qa, q_rope]
+            if pad:
+                parts.append(jnp.zeros(qa.shape[:-1] + (pad,), qa.dtype))
+            return jnp.concatenate(parts, axis=-1)
 
     def _out(self, params, o):
         """(B, S, H, d_v) head outputs -> (B, S, D)."""
-        return jnp.einsum("bshv,hvd->bsd", o, params["wo"])
+        with jax.named_scope("out"):
+            return jnp.einsum("bshv,hvd->bsd", o, params["wo"])
 
     # ---- the blocked attention both forms share ----------------------------
 
@@ -445,17 +451,21 @@ class LatentAttention(Op):
 
         def one(blk):
             fr = blk["frontier"]                            # (B, r)
-            live = (j < row_len[:, None, None]) | (
-                (j >= prompt_pad[:, None, None]) & (j <= fr[..., None]))
+            with jax.named_scope("select"):
+                live = (j < row_len[:, None, None]) | (
+                    (j >= prompt_pad[:, None, None]) & (j <= fr[..., None]))
             if not self.indexed:
                 return attend(blk, live)
-            sc = jnp.einsum("brjd,bld->brjl", blk["qi"], ki.astype(
-                blk["qi"].dtype), preferred_element_type=jnp.float32)
-            sc = jnp.einsum("brjl,brj->brl", jnp.maximum(sc, 0.0),
-                            blk["w"]) + 0.0
-            sc = jnp.where(live, sc, -jnp.inf)
-            thr, cut = dsa_threshold(sc, self.index_topk)
-            return attend(blk, dsa_chosen(sc, thr, cut))
+            with jax.named_scope("index"):
+                sc = jnp.einsum("brjd,bld->brjl", blk["qi"], ki.astype(
+                    blk["qi"].dtype), preferred_element_type=jnp.float32)
+                sc = jnp.einsum("brjl,brj->brl", jnp.maximum(sc, 0.0),
+                                blk["w"]) + 0.0
+            with jax.named_scope("select"):
+                sc = jnp.where(live, sc, -jnp.inf)
+                thr, cut = dsa_threshold(sc, self.index_topk)
+                chosen = dsa_chosen(sc, thr, cut)
+            return attend(blk, chosen)
 
         out = jax.lax.map(one, {n: split(x) for n, x in rows.items()})
         return jnp.moveaxis(out, 0, 1).reshape((b, s) + out.shape[3:])
@@ -471,13 +481,16 @@ class LatentAttention(Op):
         def attend(blk, chosen):
             # absorbed per block: a chunk's (S, H, LAT) queries never exist
             q_lat = self._absorb(params, blk["q_nope"], blk["q_rope"])
-            logits = jnp.einsum("brhc,blc->brhl", q_lat, latc,
-                                preferred_element_type=jnp.float32)
-            logits = jnp.where(chosen[:, :, None, :], logits * self.scale,
-                               jnp.finfo(jnp.float32).min)
-            p = jax.nn.softmax(logits, axis=-1).astype(latc.dtype)
-            ctx = jnp.einsum("brhl,blc->brhc", p, latc[..., :c])
-            return jnp.einsum("brhc,chv->brhv", ctx, params["w_uv"])
+            with jax.named_scope("core"):
+                logits = jnp.einsum("brhc,blc->brhl", q_lat, latc,
+                                    preferred_element_type=jnp.float32)
+                logits = jnp.where(chosen[:, :, None, :],
+                                   logits * self.scale,
+                                   jnp.finfo(jnp.float32).min)
+                p = jax.nn.softmax(logits, axis=-1).astype(latc.dtype)
+                ctx = jnp.einsum("brhl,blc->brhc", p, latc[..., :c])
+            with jax.named_scope("out"):
+                return jnp.einsum("brhc,chv->brhv", ctx, params["w_uv"])
 
         return self._out(params, self._blocked(
             pr, frontier, row_len, prompt_pad, lat.shape[1], ki, attend))
@@ -489,28 +502,35 @@ class LatentAttention(Op):
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         pr = self._project(params, a, pos)
         c = self.kv_lora_rank
-        ckv = pr["lat"][..., :c]
-        kr = pr["lat"][..., c:c + self.d_rope]
-        k = jnp.concatenate(
-            [jnp.einsum("blc,chk->blhk", ckv, params["w_uk"]),
-             jnp.broadcast_to(kr[:, :, None, :], (b, s, self.num_heads,
-                                                  self.d_rope))], axis=-1)
-        v = jnp.einsum("blc,chv->blhv", ckv, params["w_uv"])
+        with jax.named_scope("project"):
+            ckv = pr["lat"][..., :c]
+            kr = pr["lat"][..., c:c + self.d_rope]
+            k = jnp.concatenate(
+                [jnp.einsum("blc,chk->blhk", ckv, params["w_uk"]),
+                 jnp.broadcast_to(kr[:, :, None, :],
+                                  (b, s, self.num_heads, self.d_rope))],
+                axis=-1)
+            v = jnp.einsum("blc,chv->blhv", ckv, params["w_uv"])
         if self._takes_flash(s):
             from flexflow_tpu.ops.pallas_kernels import flash_attention
 
-            q = jnp.concatenate([pr["q_nope"], pr["q_rope"]], axis=-1)
+            with jax.named_scope("project"):
+                q = jnp.concatenate([pr["q_nope"], pr["q_rope"]], axis=-1)
+            # the flash calls say their own phases (layout `project`,
+            # kernels `core`)
             return [self._out(params, flash_attention(q, k, v, True,
                                                       self.scale))]
 
         def attend(blk, chosen):
-            q = jnp.concatenate([blk["q_nope"], blk["q_rope"]], axis=-1)
-            logits = jnp.einsum("brhk,blhk->brhl", q, k,
-                                preferred_element_type=jnp.float32)
-            logits = jnp.where(chosen[:, :, None, :], logits * self.scale,
-                               jnp.finfo(jnp.float32).min)
-            p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-            return jnp.einsum("brhl,blhv->brhv", p, v)
+            with jax.named_scope("core"):
+                q = jnp.concatenate([blk["q_nope"], blk["q_rope"]], axis=-1)
+                logits = jnp.einsum("brhk,blhk->brhl", q, k,
+                                    preferred_element_type=jnp.float32)
+                logits = jnp.where(chosen[:, :, None, :],
+                                   logits * self.scale,
+                                   jnp.finfo(jnp.float32).min)
+                p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+                return jnp.einsum("brhl,blhv->brhv", p, v)
 
         zero = jnp.zeros((b,), jnp.int32)
         return [self._out(params, self._blocked(
@@ -548,9 +568,10 @@ class LatentAttention(Op):
         return cache
 
     def _write(self, cache, pr, start):
-        return {n: jax.lax.dynamic_update_slice(
-            cache[n], pr[n].astype(cache[n].dtype), (0, start, 0))
-            for n in self._cached}
+        with jax.named_scope("project"):
+            return {n: jax.lax.dynamic_update_slice(
+                cache[n], pr[n].astype(cache[n].dtype), (0, start, 0))
+                for n in self._cached}
 
     def chunk_forward(self, params, xs, cache, start):
         """Positions [start, start + C) of a prompt: write their rows,
@@ -651,13 +672,14 @@ class LatentAttention(Op):
         fresh `pages` (whole pages: one update-slice a page)."""
         ps = pool["lat"].shape[1]
         out = {}
-        for n in ("lat", "ki"):
-            x = cache[n][0, p0:]
-            pad = pages.shape[0] * ps - x.shape[0]
-            if pad:
-                x = jnp.pad(x, ((0, pad), (0, 0)))
-            out[n] = pool[n].at[pages].set(
-                x.reshape(pages.shape[0], ps, -1).astype(pool[n].dtype))
+        with jax.named_scope("core"):
+            for n in ("lat", "ki"):
+                x = cache[n][0, p0:]
+                pad = pages.shape[0] * ps - x.shape[0]
+                if pad:
+                    x = jnp.pad(x, ((0, pad), (0, 0)))
+                out[n] = pool[n].at[pages].set(
+                    x.reshape(pages.shape[0], ps, -1).astype(pool[n].dtype))
         return out
 
     def export_page(self, cache, page):
@@ -669,8 +691,9 @@ class LatentAttention(Op):
             for n in ("lat", "ki")}
 
     def gather_paged_kv(self, cache, pages):
-        return {n: cache[n][pages].reshape(1, -1, cache[n].shape[-1])
-                for n in ("lat", "ki")}
+        with jax.named_scope("gather"):
+            return {n: cache[n][pages].reshape(1, -1, cache[n].shape[-1])
+                    for n in ("lat", "ki")}
 
     def paged_decode_forward(self, params, xs, cache, page_table, write_pos,
                              rope_pos, row_len, prompt_pad, impl=None):
@@ -683,33 +706,40 @@ class LatentAttention(Op):
         attention, the parity oracle."""
         ps = cache["lat"].shape[1]
         pr = self._project(params, xs[0], rope_pos[:, None])
-        page_ids = jnp.take_along_axis(
-            page_table, (write_pos // ps)[:, None], axis=1)[:, 0]
-        offs = write_pos % ps
-        cache = {n: cache[n].at[page_ids, offs].set(
-            pr[n][:, 0].astype(cache[n].dtype)) for n in ("lat", "ki")}
+        with jax.named_scope("project"):
+            page_ids = jnp.take_along_axis(
+                page_table, (write_pos // ps)[:, None], axis=1)[:, 0]
+            offs = write_pos % ps
+            cache = {n: cache[n].at[page_ids, offs].set(
+                pr[n][:, 0].astype(cache[n].dtype)) for n in ("lat", "ki")}
         if resolve_paged_attention_impl(
                 impl, getattr(self.model, "config", None)) != "pallas":
             b = page_table.shape[0]
-            rows = {n: cache[n][page_table].reshape(
-                b, -1, cache[n].shape[-1]) for n in ("lat", "ki")}
+            with jax.named_scope("gather"):
+                rows = {n: cache[n][page_table].reshape(
+                    b, -1, cache[n].shape[-1]) for n in ("lat", "ki")}
             return self._attend_latent(params, pr, rows, write_pos[:, None],
                                        row_len, prompt_pad), cache
         from flexflow_tpu.ops.pallas_kernels import (
             dsa_index_scores_pallas, mla_gathered_core_pallas)
 
-        scores = dsa_index_scores_pallas(
-            pr["qi"][:, 0], pr["w"][:, 0], cache["ki"], page_table,
-            write_pos, row_len, prompt_pad)
-        thr, cut = dsa_threshold(scores, self.index_topk)
+        with jax.named_scope("index"):
+            scores = dsa_index_scores_pallas(
+                pr["qi"][:, 0], pr["w"][:, 0], cache["ki"], page_table,
+                write_pos, row_len, prompt_pad)
         q_lat = self._absorb(params, pr["q_nope"][:, 0], pr["q_rope"][:, 0])
-        # a table smaller than index_topk has no more rows to list
-        rows, n_sel = dsa_selected(
-            scores, thr, cut, min(self.index_topk, page_table.shape[1] * ps),
-            ps, page_table)
+        with jax.named_scope("select"):
+            thr, cut = dsa_threshold(scores, self.index_topk)
+            # a table smaller than index_topk has no more rows to list
+            rows, n_sel = dsa_selected(
+                scores, thr, cut,
+                min(self.index_topk, page_table.shape[1] * ps), ps,
+                page_table)
+        # the call says its own phases: XLA's row `gather`, the kernel `core`
         ctx = mla_gathered_core_pallas(q_lat, rows, n_sel, cache["lat"],
                                        scale=self.scale, c=self.kv_lora_rank)
-        o = jnp.einsum("bhc,chv->bhv", ctx, params["w_uv"])
+        with jax.named_scope("out"):
+            o = jnp.einsum("bhc,chv->bhv", ctx, params["w_uv"])
         return self._out(params, o[:, None]), cache
 
     def paged_verify_forward(self, *args, **kw):

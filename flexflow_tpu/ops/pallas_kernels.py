@@ -343,10 +343,12 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
     # cross-attention diagonal offset (bottom-right aligned causality)
     offset = sk - sq
 
-    # (B, S, H, D) -> (B*H, S, D)
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
+    # (B, S, H, D) -> (B*H, S, D); the phase scopes here and below are the
+    # calling attention op's (runtime/profiler.py PHASES)
+    with jax.named_scope("project"):
+        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+        vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
 
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
                                block_k=block_k, chunk=_chunk_rows(block_q),
@@ -368,24 +370,25 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
         out_specs.append(pl.BlockSpec((1, block_q, 8),
                                       lambda i, j, t: (i, j, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b * h, sq, 8), jnp.float32))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq // block_q, sk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, dv), kv_map),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
-            pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, dv), jnp.float32),      # output accumulator
-        ],
-        compiler_params=_compiler_params(),
-        interpret=interpret, name="flash_attention_fwd",
-    )(qt, kt, vt)
+    with jax.named_scope("core"):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(b * h, sq // block_q, sk // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_k, dv), kv_map),
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, LANES), jnp.float32),   # running max
+                pltpu.VMEM((block_q, LANES), jnp.float32),   # running sum
+                pltpu.VMEM((block_q, dv), jnp.float32),      # accumulator
+            ],
+            compiler_params=_compiler_params(),
+            interpret=interpret, name="flash_attention_fwd",
+        )(qt, kt, vt)
     return (outs[0], outs[1]) if need_lse else (outs[0], None)
 
 
@@ -515,23 +518,25 @@ def _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed, *, causal,
     sk, dv = k.shape[1], v.shape[3]
     offset = sk - sq
 
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
-    dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
-    ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
+    with jax.named_scope("project"):
+        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+        kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+        vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
+        dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
+        ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
     # delta_i = rowsum(do_i * o_i) — the softmax-normalization term of ds;
     # an lse cotangent (if the lse output is ever differentiated) folds in
     # as ds = p * (dp - delta + dlse), i.e. delta -= dlse. Loop callers
     # (the ring backward) pass delta_precomputed to hoist this out of their
     # scan body.
-    if delta_precomputed is not None:
-        delta = delta_precomputed.reshape(b * h, sq).astype(jnp.float32)
-    else:
-        delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
-                        axis=-1)
-    if dlse is not None:
-        delta = delta - dlse.reshape(b * h, sq).astype(jnp.float32)
+    with jax.named_scope("core"):
+        if delta_precomputed is not None:
+            delta = delta_precomputed.reshape(b * h, sq).astype(jnp.float32)
+        else:
+            delta = jnp.sum(dot.astype(jnp.float32)
+                            * ot.astype(jnp.float32), axis=-1)
+        if dlse is not None:
+            delta = delta - dlse.reshape(b * h, sq).astype(jnp.float32)
 
     if causal:
         # dead-tile clamps (see forward): masked inner steps re-reference a
@@ -554,60 +559,65 @@ def _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed, *, causal,
     def q_map(i, j, t):
         return (i, q_tile(j, t), 0)
 
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, chunk=_chunk_rows(block_q),
-                          causal=causal, scale=scale, offset=offset),
-        grid=(b * h, sq // block_q, sk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, dv), kv_map),
-            pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret, name="flash_attention_bwd_dq",
-    )(qt, kt, vt, dot, lse,
-      # delta in the same 8-lane padded layout as lse
-      jnp.broadcast_to(delta[..., None], (b * h, sq, 8)))
+    with jax.named_scope("core"):
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
+                              block_k=block_k, chunk=_chunk_rows(block_q),
+                              causal=causal, scale=scale, offset=offset),
+            grid=(b * h, sq // block_q, sk // block_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_k, d), kv_map),
+                pl.BlockSpec((1, block_k, dv), kv_map),
+                pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda i, j, t: (i, j, 0)),
+            out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=_compiler_params(),
+            interpret=interpret, name="flash_attention_bwd_dq",
+        )(qt, kt, vt, dot, lse,
+          # delta in the same 8-lane padded layout as lse
+          jnp.broadcast_to(delta[..., None], (b * h, sq, 8)))
 
     # the dkv kernel works on transposed logits, so its two per-query rows
     # lie along lanes
     rows = (b * h, 1, sq)
     row_spec = pl.BlockSpec((1, 1, block_q),
                             lambda i, j, t: (i, 0, q_tile(j, t)))
-    dk, dvt = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, chunk=_chunk_rows(block_k),
-                          causal=causal, scale=scale, offset=offset),
-        grid=(b * h, sk // block_k, sq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_q, dv), q_map),
-            row_spec,
-            row_spec,
-        ],
-        out_specs=[pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-                   pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, dv), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, dv), jnp.float32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret, name="flash_attention_bwd_dkv",
-    )(qt, kt, vt, dot, lse[..., 0].reshape(rows), delta.reshape(rows))
+    with jax.named_scope("core"):
+        dk, dvt = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
+                              block_k=block_k, chunk=_chunk_rows(block_k),
+                              causal=causal, scale=scale, offset=offset),
+            grid=(b * h, sk // block_k, sq // block_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_map),
+                pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_q, dv), q_map),
+                row_spec,
+                row_spec,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
+                pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0))],
+            out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * h, sk, dv), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
+            compiler_params=_compiler_params(),
+            interpret=interpret, name="flash_attention_bwd_dkv",
+        )(qt, kt, vt, dot, lse[..., 0].reshape(rows), delta.reshape(rows))
 
     def back(x, s):
         return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
-    return back(dq, sq), back(dk, sk), back(dvt, sk)
+    with jax.named_scope("project"):
+        return back(dq, sq), back(dk, sk), back(dvt, sk)
 
 
 # ----------------------------------------------------- fused add+layernorm
@@ -748,14 +758,16 @@ def flash_attention(q, k, v, causal: bool = False,
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = flash_attention_fwd_pallas(q, k, v, causal, s, need_lse=False)
     b, sq, h, _ = q.shape
-    return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
+    with jax.named_scope("out"):
+        return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
 def _flash_fwd_rule(q, k, v, causal, scale):
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, lse = flash_attention_fwd_pallas(q, k, v, causal, s)
     b, sq, h, _ = q.shape
-    o = out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
+    with jax.named_scope("out"):
+        o = out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
     return o, (q, k, v, o, lse)
 
 
@@ -1582,8 +1594,9 @@ def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
         rows = jnp.pad(rows, ((0, 0), (0, kp - k)))
     # XLA's gather: Mosaic refuses a DMA of fewer than 8 rows of the tiled
     # pool (PERF.md section 6, PR 31)
-    gathered = lat_pages.reshape(-1, wdt).at[rows].get(
-        mode="promise_in_bounds")                       # (B, kp, W)
+    with jax.named_scope("gather"):
+        gathered = lat_pages.reshape(-1, wdt).at[rows].get(
+            mode="promise_in_bounds")                   # (B, kp, W)
 
     def slot_map(bi, *_):
         return (bi, 0, 0)
@@ -1593,10 +1606,12 @@ def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
         in_specs=[pl.BlockSpec((1, h, wdt), slot_map),
                   pl.BlockSpec((1, kp, wdt), slot_map)],
         out_specs=pl.BlockSpec((1, h, c), slot_map))
-    return pl.pallas_call(
-        functools.partial(_mla_gathered_kernel, blk=blk, scale=scale, c=c),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
-        compiler_params=_compiler_params(("arbitrary",)),
-        interpret=_interpret(), name="mla_paged_core_gathered",
-    )(n_sel.astype(jnp.int32), q_lat, gathered)
+    with jax.named_scope("core"):
+        return pl.pallas_call(
+            functools.partial(_mla_gathered_kernel, blk=blk, scale=scale,
+                              c=c),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
+            compiler_params=_compiler_params(("arbitrary",)),
+            interpret=_interpret(), name="mla_paged_core_gathered",
+        )(n_sel.astype(jnp.int32), q_lat, gathered)

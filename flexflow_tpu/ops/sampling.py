@@ -133,6 +133,7 @@ def _masked_warped(logits, temps, top_ps, top_ks):
     return jnp.where(keep, warped, -jnp.inf)
 
 
+@jax.named_scope("sampler")
 def sampling_probs(logits, temps, top_ps, top_ks):
     """The per-row sampling distribution as (B, V) f32 probabilities —
     the operand of the rejection-sampling accept rule (``p`` for the
@@ -147,6 +148,7 @@ def sampling_probs(logits, temps, top_ps, top_ks):
     return jnp.where((temps > 0.0)[:, None], probs, greedy)
 
 
+@jax.named_scope("sampler")
 def sample_tokens(logits, temps, top_ps, top_ks, seeds, counters,
                   tag: int = TAG_TARGET):
     """One token per row from the warped distribution; (B,) int32.
@@ -163,6 +165,7 @@ def sample_tokens(logits, temps, top_ps, top_ks, seeds, counters,
     return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
 
 
+@jax.named_scope("sampler")
 def accept_uniforms(seeds, counters, k: int):
     """(B, k) accept-rule uniforms: row b, proposal i draws from the
     ACCEPT stream at index counters[b] + i. The host compares
@@ -182,6 +185,7 @@ def accept_uniforms(seeds, counters, k: int):
     return jax.vmap(one)(seeds, counters)
 
 
+@jax.named_scope("sampler")
 def residual_sample(p, q, seeds, counters):
     """The in-graph rejection re-draw: sample from the residual
     distribution ``norm(max(p - q, 0))`` — what makes accept/resample

@@ -299,10 +299,17 @@ class GraphExecutor:
                 seed = getattr(op, "seed", 0)
                 if seed:
                     op_rng = jax.random.fold_in(op_rng, seed)
-            p = resolve_tied_params(self.model, params, op.name,
-                                    params.get(op.name, {}))
-            if bf16:
-                p = {k: to_compute(v) for k, v in p.items()}
+            # named_scope stamps the op name into the HLO metadata of every
+            # instruction it traces (the cast of its weights included), so
+            # a jax.profiler trace's device ops of the PRODUCTION jitted
+            # program attribute back to graph ops (runtime/profiler.py
+            # scope_table) — the in-situ analog of the reference's
+            # --profiling per-op events (linear.cu:526-553)
+            with jax.named_scope(op.name):
+                p = resolve_tied_params(self.model, params, op.name,
+                                        params.get(op.name, {}))
+                if bf16:
+                    p = {k: to_compute(v) for k, v in p.items()}
             kwargs = {}
             if getattr(op, "wants_shard_ctx", False):
                 kwargs["shard_ctx"] = {
@@ -312,12 +319,6 @@ class GraphExecutor:
                 }
             if group_sizes is not None and getattr(op, "dropless", False):
                 kwargs["group_sizes"] = group_sizes
-            # named_scope stamps the op name into the HLO metadata of every
-            # instruction it traces, so a jax.profiler trace's device ops
-            # of the PRODUCTION jitted program attribute back to graph ops — the
-            # in-situ analog of the reference's --profiling per-op events
-            # (linear.cu:526-553); profiler.profile_step stays the unfused
-            # wall-timer variant
             with jax.named_scope(op.name):
                 if op.stateful:
                     outs, ns = op.forward_stateful(
@@ -367,13 +368,14 @@ class GraphExecutor:
                 p, st, input_values, training=True, rng=rng,
                 group_sizes=sizes)
             logits = vals[final_tensor]
-            loss = compute_loss(loss_type, logits, batch[label_key])
-            for t in aux_tensors:  # e.g. MoE load-balancing losses
-                loss = loss + vals[t]
-            mets = batch_metrics(
-                loss_type, metric_types, logits, batch[label_key],
-                ignore_index=getattr(self.model.config,
-                                     "metrics_ignore_index", None))
+            with jax.named_scope("loss"):
+                loss = compute_loss(loss_type, logits, batch[label_key])
+                for t in aux_tensors:  # e.g. MoE load-balancing losses
+                    loss = loss + vals[t]
+                mets = batch_metrics(
+                    loss_type, metric_types, logits, batch[label_key],
+                    ignore_index=getattr(self.model.config,
+                                         "metrics_ignore_index", None))
             if sizes:
                 mets.update(routing_counts(sizes))
             return loss, (new_state, mets)
@@ -392,7 +394,9 @@ class GraphExecutor:
         def step(params, opt_state, state, batch, rng):
             (loss, (new_state, mets)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, state, batch, rng)
-            new_params, new_opt_state = optimizer.update(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = optimizer.update(
+                    params, grads, opt_state)
             return new_params, new_opt_state, new_state, loss, mets
 
         # in-graph grad-sync overlap (FFConfig.overlap_grad_sync): carry
@@ -428,8 +432,9 @@ class GraphExecutor:
                 from flexflow_tpu.runtime.optimizer import \
                     apply_tree_shardings
 
-                return apply_tree_shardings(
-                    tree, scatter, jax.lax.with_sharding_constraint)
+                with jax.named_scope("grad_sync"):
+                    return apply_tree_shardings(
+                        tree, scatter, jax.lax.with_sharding_constraint)
 
             def accum_zero(p):
                 # low-precision grads accumulate in f32: summing `accum`
@@ -446,15 +451,17 @@ class GraphExecutor:
                 (loss, (st, mets)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(
                         params, st, mb, jax.random.fold_in(rng, i))
-                g_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(a.dtype), g_acc, grads)
+                with jax.named_scope("grad_sync"):
+                    g_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(a.dtype), g_acc, grads)
                 return (constrain(g_acc), st), (loss, mets)
 
             zeros = constrain(jax.tree.map(accum_zero, params))
             (g_sum, new_state), (losses, mets) = jax.lax.scan(
                 body, (zeros, state),
                 (micro, jnp.arange(accum, dtype=jnp.int32)))
-            grads = jax.tree.map(lambda g: g / accum, g_sum)
+            with jax.named_scope("grad_sync"):
+                grads = jax.tree.map(lambda g: g / accum, g_sum)
             loss = jnp.mean(losses)
             # counts and totals (accuracy_count/_total) sum across
             # microbatches; mean metrics average (equal sizes -> exact)
@@ -462,8 +469,9 @@ class GraphExecutor:
                         else jnp.max(v) if k.endswith("_max")
                         else jnp.mean(v))
                     for k, v in mets.items()}
-            new_params, new_opt_state = optimizer.update(params, grads,
-                                                         opt_state)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = optimizer.update(params, grads,
+                                                             opt_state)
             return new_params, new_opt_state, new_state, loss, mets
 
         return accum_step if accum > 1 else step
@@ -525,8 +533,9 @@ class GraphExecutor:
                 gnorm_sq = gnorm_sq + jnp.sum(
                     jnp.square(g.astype(jnp.float32)))
             finite = jnp.isfinite(raw_loss) & jnp.isfinite(gnorm_sq)
-            new_params, new_opt_state = optimizer.update(params, grads,
-                                                         opt_state)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = optimizer.update(params, grads,
+                                                             opt_state)
 
             def sel(new, old):
                 return jax.tree_util.tree_map(

@@ -302,17 +302,21 @@ class Generator:
                         return jnp.take_along_axis(x, ix, axis=1)
 
                     xs = [take_last(x) for x in xs]
-            if self.quantize:
-                cdtype = self._compute_dtype()
-                deq = lambda v: self._deq(v, cdtype)
-                p = {k: deq(v) for k, v in params.get(op.name, {}).items()}
-                p = resolve_tied_params(self.model, params, op.name, p,
-                                        leaf=deq)
-            else:
-                p = resolve_tied_params(self.model, params, op.name,
-                                        params.get(op.name, {}))
-            if bf16:
-                p = {k: to_compute(v) for k, v in p.items()}
+            # the op's scope holds the reading of its weights too
+            # (dequantization, the cast): runtime/profiler.py scope_table
+            with jax.named_scope(op.name):
+                if self.quantize:
+                    cdtype = self._compute_dtype()
+                    deq = lambda v: self._deq(v, cdtype)
+                    p = {k: deq(v)
+                         for k, v in params.get(op.name, {}).items()}
+                    p = resolve_tied_params(self.model, params, op.name, p,
+                                            leaf=deq)
+                else:
+                    p = resolve_tied_params(self.model, params, op.name,
+                                            params.get(op.name, {}))
+                if bf16:
+                    p = {k: to_compute(v) for k, v in p.items()}
             with jax.named_scope(op.name):
                 if getattr(op, "kv_cache_protocol", False):
                     cache = caches[op.name]

@@ -141,7 +141,8 @@ from flexflow_tpu._env import (compilation_cache_dir,
                                compilation_cache_entries)
 from flexflow_tpu.logger import fflogger
 from flexflow_tpu.ops import sampling as sampling_ops
-from flexflow_tpu.runtime import faultinject, flightrec, locks, telemetry
+from flexflow_tpu.runtime import (faultinject, flightrec, locks, profiler,
+                                  telemetry)
 from flexflow_tpu.runtime.generation import Generator
 from flexflow_tpu.runtime.kv_pool import KVPagePool, Lease
 from flexflow_tpu.runtime.lora import LoraAdapterPool
@@ -242,6 +243,28 @@ def _pow2_bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+# a tick of ``ServingEngine.step()`` longer than this explains itself in one
+# warning built from the ring's events of that tick
+HELD_TICK_S = 1.0
+
+_KEY_FIELDS = {"prefill": "b", "prefill_hit": "bm", "draft_prefill": "b",
+               "draft_prefill_hit": "bm", "prefill_ichunk": "bs",
+               "prefill_ifinal": "b", "decode": "k", "draft_propose": "k",
+               "verify": "k", "spec_uniforms": "k"}
+
+
+def program_name(key) -> str:
+    """The short name of an engine program's key, as the registry
+    (runtime/profiler.py), the ``program`` count of the dispatch spans and
+    the benchmark's scope tables know it: ``("decode", 8)`` ->
+    ``decode_k8``, ``("prefill", 2048, 16, 0)`` -> ``prefill_b2048``,
+    ``("prefill_hit", 128, 255)`` -> ``prefill_hit_b128_m255``. Fields a
+    key's bucket already fixes (its page count, the engine's chunk) are
+    left out."""
+    fields = _KEY_FIELDS.get(key[0], "")
+    return "_".join([key[0]] + [f"{f}{v}" for f, v in zip(fields, key[1:])])
 
 
 class ServingEngine:
@@ -648,6 +671,13 @@ class ServingEngine:
         self.deploy_state = "serving"
         self._weight_swaps = 0
         self._programs: Dict = {}
+        # key -> profiler.Program: the registry holds these weakly, so a
+        # program is listed for as long as this engine lives
+        self._registered: Dict = {}
+        self._graph_ops = profiler.graph_op_phases(self.model)
+        if self.draft_model is not None:
+            self._graph_ops.update(
+                profiler.graph_op_phases(self.draft_model))
         # ffsan retrace sentinel: warmup() closes the program set;
         # armed + sanitize on, _compiled_call reports any further
         # jit cache miss with the argument signature that diverged
@@ -1153,6 +1183,9 @@ class ServingEngine:
         JAX_COMPILATION_CACHE_DIR) absorbed the compile. Every shape-affecting datum is part of `key`, so this
         counter is exactly the number of XLA compiles the engine caused."""
         fn = self._programs.get(key)
+        if profiler.tracing() and key in self._registered:
+            # a traced slice's tables are read after the window
+            profiler.note_traced(self._registered[key])
         if fn is not None:
             # armed sentinel: bracket the dispatch with the jitted
             # callable's trace-cache size — growth means a WARM
@@ -1161,10 +1194,15 @@ class ServingEngine:
         self._retrace.note_miss(key, args)
         fn = self._programs[key] = build()
         self.recompile_count += 1
+        # abstract arguments only, taken before the call donates them:
+        # nothing is lowered until profiler.program_scopes() asks
+        self._registered[key] = profiler.register_program(
+            program_name(key), fn, args, self._graph_ops)
         cache_dir = compilation_cache_dir()
         before = compilation_cache_entries(cache_dir) if cache_dir else 0
         t0 = time.perf_counter()
-        with self._span("compile", key=str(key)):
+        with self._span("compile", key=str(key),
+                        program=program_name(key)):
             out = fn(*args)
             with self._span("compile_fetch"):
                 jax.block_until_ready(out)
@@ -1190,11 +1228,13 @@ class ServingEngine:
         cdtype = gen._compute_dtype()
         caches = {}
         for op in gen.attn_ops:
-            c = op.init_cache(1, bucket, cdtype)
-            g = op.gather_paged_kv(pool[op.name], prefix_pages)
-            caches[op.name] = {
-                name: c[name].at[:, :p0].set(g[name].astype(c[name].dtype))
-                for name in c}
+            with jax.named_scope(op.name), jax.named_scope("gather"):
+                c = op.init_cache(1, bucket, cdtype)
+                g = op.gather_paged_kv(pool[op.name], prefix_pages)
+                caches[op.name] = {
+                    name: c[name].at[:, :p0].set(
+                        g[name].astype(c[name].dtype))
+                    for name in c}
         return caches
 
     def _scatter_tail(self, gen, pool, caches, pages, p0: int = 0):
@@ -1206,11 +1246,26 @@ class ServingEngine:
         (ISSUE 18); both are bitwise-identical so the choice is purely
         a perf knob — resolution happens at TRACE time inside the
         prefill builders, warm programs pay nothing."""
-        return {
-            op.name: op.scatter_cache_tail(
-                pool[op.name], caches[op.name], p0, pages,
-                impl=self.paged_prefill_impl)
-            for op in gen.attn_ops}
+        out = {}
+        for op in gen.attn_ops:
+            # the op's own scope (runtime/profiler.py scope_table): the
+            # write is its attention's work, as in the walk
+            with jax.named_scope(op.name):
+                out[op.name] = op.scatter_cache_tail(
+                    pool[op.name], caches[op.name], p0, pages,
+                    impl=self.paged_prefill_impl)
+        return out
+
+    @staticmethod
+    @jax.named_scope("sampler")
+    def _first_token(logits, poison, temps, top_ps, top_ks, seeds):
+        """(tok, ok) of a prefill program's last position: the request's
+        first emitted token is TARGET-stream draw 0. The poison and the
+        finite check belong to the sampler's scope."""
+        logits = logits[:, -1] + poison                # (1, V)
+        ok = jnp.isfinite(logits).all(axis=-1)
+        return sampling_ops.sample_tokens(
+            logits, temps, top_ps, top_ks, seeds, jnp.zeros_like(seeds)), ok
 
     @staticmethod
     def _routing_sum(routing):
@@ -1257,12 +1312,8 @@ class ServingEngine:
                                           length, self.prefill_chunk,
                                           lora=lora, routing=routing,
                                           lowerings=took)
-            logits = logits[:, -1] + poison            # (1, V)
-            ok = jnp.isfinite(logits).all(axis=-1)
-            # the request's first emitted token is TARGET-stream draw 0
-            tok = sampling_ops.sample_tokens(
-                logits, temps, top_ps, top_ks, seeds,
-                jnp.zeros_like(seeds))
+            tok, ok = self._first_token(logits, poison, temps, top_ps,
+                                        top_ks, seeds)
             return (tok, ok, self._scatter_tail(gen, pool, caches, pages),
                     *self._routing_sum(routing))
 
@@ -1301,11 +1352,8 @@ class ServingEngine:
                                        row_lengths=length,
                                        gather_last=True, lora=lora,
                                        routing=routing, lowerings=took)
-            logits = logits[:, -1] + poison            # (1, V)
-            ok = jnp.isfinite(logits).all(axis=-1)
-            tok = sampling_ops.sample_tokens(
-                logits, temps, top_ps, top_ks, seeds,
-                jnp.zeros_like(seeds))
+            tok, ok = self._first_token(logits, poison, temps, top_ps,
+                                        top_ks, seeds)
             return (tok, ok, self._scatter_tail(gen, pool, caches,
                                                 tail_pages, p0),
                     *self._routing_sum(routing))
@@ -1414,11 +1462,8 @@ class ServingEngine:
                                        None, last_only=True,
                                        row_lengths=length,
                                        gather_last=True, lora=lora)
-            logits = logits[:, -1] + poison                  # (1, V)
-            ok = jnp.isfinite(logits).all(axis=-1)
-            tok = sampling_ops.sample_tokens(
-                logits, temps, top_ps, top_ks, seeds,
-                jnp.zeros_like(seeds))
+            tok, ok = self._first_token(logits, poison, temps, top_ps,
+                                        top_ks, seeds)
             return tok, ok, self._scatter_tail(gen, pool, caches, pages)
 
         # donate the pool only: the chunk caches feed the scatter but
@@ -1451,15 +1496,16 @@ class ServingEngine:
                     if has_lora else None)
             logits, pool = gen._walk(params, state, slab, pool, None,
                                      paged=paged, lora=lora)
-            logits = logits.astype(jnp.float32) \
-                + poison[:, None, None]                # (B, K+1, V)
-            ok = jnp.isfinite(logits).all(axis=-1)     # (B, K+1)
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            b, s, v = logits.shape
-            probs = sampling_ops.sampling_probs(
-                logits.reshape(b * s, v),
-                jnp.repeat(temps, s), jnp.repeat(top_ps, s),
-                jnp.repeat(top_ks, s)).reshape(b, s, v)
+            with jax.named_scope("sampler"):
+                logits = logits.astype(jnp.float32) \
+                    + poison[:, None, None]            # (B, K+1, V)
+                ok = jnp.isfinite(logits).all(axis=-1)     # (B, K+1)
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                b, s, v = logits.shape
+                probs = sampling_ops.sampling_probs(
+                    logits.reshape(b * s, v),
+                    jnp.repeat(temps, s), jnp.repeat(top_ps, s),
+                    jnp.repeat(top_ks, s)).reshape(b, s, v)
             return toks, probs, ok, pool
 
         return jax.jit(decode_verify, donate_argnums=(2,))
@@ -1498,10 +1544,11 @@ class ServingEngine:
                                          pool, None, paged=paged,
                                          lora=lora, routing=routing,
                                          lowerings=took)
-                logits = logits[:, 0] + poison[:, None]  # (B_slots, V)
-                ok = jnp.isfinite(logits).all(axis=-1)
-                nxt = sampling_ops.sample_tokens(
-                    logits, temps, top_ps, top_ks, seeds, ctr0 + i)
+                with jax.named_scope("sampler"):
+                    logits = logits[:, 0] + poison[:, None]  # (B_slots, V)
+                    ok = jnp.isfinite(logits).all(axis=-1)
+                    nxt = sampling_ops.sample_tokens(
+                        logits, temps, top_ps, top_ks, seeds, ctr0 + i)
                 return (pool, nxt), (nxt, ok, *self._routing_sum(routing))
 
             (pool, _), (toks, oks, *routed) = jax.lax.scan(
@@ -1538,12 +1585,13 @@ class ServingEngine:
                     "impl": self.paged_attention_impl}
                 logits, pool = gen._walk(params, state, tok[:, None],
                                          pool, None, paged=paged)
-                logits = logits[:, 0].astype(jnp.float32)  # (B, V)
-                nxt = sampling_ops.sample_tokens(
-                    logits, temps, top_ps, top_ks, seeds, ctr0 + i,
-                    tag=sampling_ops.TAG_DRAFT)
-                probs = sampling_ops.sampling_probs(
-                    logits, temps, top_ps, top_ks)
+                with jax.named_scope("sampler"):
+                    logits = logits[:, 0].astype(jnp.float32)  # (B, V)
+                    nxt = sampling_ops.sample_tokens(
+                        logits, temps, top_ps, top_ks, seeds, ctr0 + i,
+                        tag=sampling_ops.TAG_DRAFT)
+                    probs = sampling_ops.sampling_probs(
+                        logits, temps, top_ps, top_ks)
                 return (pool, nxt), (nxt, probs)
 
             (pool, _), (toks, probs) = jax.lax.scan(
@@ -1695,6 +1743,7 @@ class ServingEngine:
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page))
+        span.annotate(program=program_name(key))
         self._note_moe_lowering(key, span)
         if self.draft_gen is not None:
             # the draft model's prefix KV rides the same page ids, so its
@@ -1914,7 +1963,9 @@ class ServingEngine:
         ps = self._partial[slot]
         req = ps["req"]
         st = ps["next"]
-        with self._span("prefill_chunk", slot=slot, bucket=req.bucket):
+        with self._span("prefill_chunk", slot=slot, bucket=req.bucket,
+                        program=program_name(
+                            ("prefill_ichunk", req.bucket, st))):
             if st == 0:
                 ps["caches"] = self._compiled_call(
                     ("prefill_ichunk", req.bucket, 0),
@@ -2305,10 +2356,10 @@ class ServingEngine:
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
+        key = ("decode", k)
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read,
-                        **attn) as sp:
-            key = ("decode", k)
+                        program=program_name(key), **attn) as sp:
             toks, oks, self.kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_decode(k, self._moe_took_list(key)),
                 *args)
@@ -2530,6 +2581,7 @@ class ServingEngine:
         try:
             with self._lock:
                 self._tick_seq += 1
+                t_tick, compiles = telemetry.now_us(), self.recompile_count
                 with self._span("engine_step", tick=self._tick_seq,
                                 queued=len(self._queue),
                                 active=int(self.active.sum())):
@@ -2552,6 +2604,10 @@ class ServingEngine:
                             or bool(self._partial)
                     else:
                         out = self.pending()
+                if telemetry.now_us() - t_tick > HELD_TICK_S * 1e6 \
+                        and self.recompile_count == compiles:
+                    # (a tick that compiled says so in its compile line)
+                    self._explain_held_tick(t_tick)
         except Exception as e:  # noqa: BLE001 — an uncaught engine
             #   exception is a flight-recorder trigger (the lock is
             #   released by the time we get here; trip() only schedules,
@@ -2568,6 +2624,24 @@ class ServingEngine:
             with self._span("slo_tick"):
                 flightrec.slo_monitor().maybe_evaluate()
         return out
+
+    def _explain_held_tick(self, t_tick: float):
+        """ONE warning for a tick that took over HELD_TICK_S: every span
+        the ring holds of it (this engine's track, since ``t_tick`` on the
+        ring's clock) with its duration and counts, ``program`` among
+        them, so the run in which a tick was held says whether the host
+        or the chip held it (a ``*_fetch`` span is the host waiting for
+        the chip, any other is host work)."""
+        spans = [e for e in telemetry.tracer().events()
+                 if e["ph"] == "X" and e["ts"] >= t_tick
+                 and e["pid"] == self._tm_track]
+        fflogger.warning(
+            "serving: tick %d took %.3f s (over %.1f s): %s",
+            self._tick_seq, (telemetry.now_us() - t_tick) / 1e6,
+            HELD_TICK_S, "; ".join(
+                f"{e['name']} {e['dur'] / 1e6:.3f} s {e.get('args') or ''}"
+                for e in spans) or "the ring holds no span of it "
+            "(telemetry off)")
 
     def run(self, prompts=None, max_new_tokens: int = 32,
             **submit_kw) -> List[Request]:

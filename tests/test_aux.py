@@ -150,14 +150,9 @@ def test_checkpoint_sharded_fused_cross_topology_refused(tmp_path):
 
 
 def test_profiler_per_op(tmp_path):
-    from flexflow_tpu.runtime.profiler import export_taskgraph, profile_step
+    from flexflow_tpu.runtime.profiler import export_taskgraph
 
     ff, _ = build_and_train(tmp_path, steps=1)
-    rs = np.random.RandomState(1)
-    rows = profile_step(ff, {"x": rs.randn(32, 16).astype(np.float32)})
-    assert {r["op"] for r in rows} == {"fc1", "out"}
-    assert all(r["ms"] >= 0 for r in rows)
-
     dot = export_taskgraph(ff, str(tmp_path / "graph.dot"))
     content = open(dot).read()
     assert "fc1" in content and "->" in content

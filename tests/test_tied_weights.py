@@ -129,13 +129,3 @@ def test_tie_validation():
         ff.set_weights("head", "kernel", np.zeros((HIDDEN, VOCAB), np.float32))
     with pytest.raises(ValueError, match="before compile"):
         ff.tie_weights("head2", "kernel", "embed", "kernel", "transpose")
-
-
-def test_profile_step_resolves_ties():
-    from flexflow_tpu.runtime.profiler import profile_step
-
-    ff = _tied_lm()
-    rs = np.random.RandomState(3)
-    rows = profile_step(ff, {"input": rs.randint(0, VOCAB, (4, 6))
-                             .astype(np.int32)})
-    assert any(r["op"] == "lm_head" for r in rows)
