@@ -14,6 +14,17 @@ post-hoc NCCL cost, simulator.cc:548-594), an HBM over-capacity penalty
 `iteration_time` is an exact Python mirror of the C++ scheduler in
 csrc/sim.cc — the native annealer and this objective must agree (tested in
 tests/test_csim.py), so neither can drift silently.
+
+What the prices follow (PERF.md, PR 36: fitted against hand-written
+strategies run on the four-chip host): the job's own `compute_dtype`
+(element size of activations, edges and gradient syncs, the MXU's peak),
+`master_dtype` and optimizer (a weight with its gradient and moments, WHOLE
+on every mesh axis that does not shard it); every edge is charged its
+reshard forward and the transpose of it backward; the optimizer's pass
+over the state a chip holds, FSDP's passes over the gathered weights and a
+sequence-sharded attention's key/value rotation are part of an op's
+compute time; the share of a gradient all-reduce that the backend cannot
+hide (`MachineModel.all_reduce_exposed`) holds the compute stream.
 """
 
 from __future__ import annotations
@@ -37,6 +48,20 @@ MEM_PENALTY_PER_BYTE = 1e-3 / 1e6  # 1 ms per MB over HBM (simulator.cc:612-617)
 # forward in backward instead of stashing activations; "offload" parks
 # grads + optimizer state host-side at host_bw streaming cost.
 MEM_MODES = ("none", "remat", "zero1", "zero3", "offload")
+
+
+_DTYPE_BYTES = {"bfloat16": 2, "float32": 4}   # what FFConfig admits
+
+
+def _optimizer_slots(optimizer) -> int:
+    """Moment arrays an optimizer keeps beside each weight: Adam's two, one
+    for momentum, none for plain SGD; one where nothing is known yet."""
+    optimizer = getattr(optimizer, "inner", optimizer)   # a fused wrapper
+    if optimizer is None:
+        return 1
+    if hasattr(optimizer, "beta2"):
+        return 2
+    return 1 if getattr(optimizer, "momentum", 1.0) > 0.0 else 0
 
 
 def _parts(axis_map: AxisMap, mesh_shape: Dict[str, int]) -> int:
@@ -80,10 +105,23 @@ class CostModel:
     def __init__(self, model, mesh_shape: Dict[str, int],
                  machine: Optional[MachineModel] = None,
                  measured: Optional[Dict] = None,
-                 dtype_bytes: int = 4,
+                 dtype_bytes: Optional[int] = None,
                  fsdp_axis: str = ""):
         self.model = model
         self.mesh_shape = dict(mesh_shape)
+        # what the job itself says of its arithmetic: activations, edges and
+        # matmuls at `compute_dtype` (bf16: 2 bytes and the MXU's bf16
+        # peak), weights, gradients and moments at `master_dtype`, as many
+        # moments as the optimizer keeps. An f32 job with no optimizer yet
+        # (a cost model built before compile()) reads 4, 4 and one moment:
+        # the "weights + grads + opt state (x3)" this model always counted.
+        cfg = getattr(model, "config", None)
+        if dtype_bytes is None:
+            dtype_bytes = _DTYPE_BYTES.get(
+                str(getattr(cfg, "compute_dtype", "float32")), 4)
+        self.master_bytes = _DTYPE_BYTES.get(
+            str(getattr(cfg, "master_dtype", "float32")), 4)
+        self.opt_slots = _optimizer_slots(getattr(model, "optimizer", None))
         if machine is None:
             # two-tier topology by default: when the model's config names
             # DCN-spanning axes (FFConfig.dcn_mesh_shape), EVERY cost
@@ -211,22 +249,65 @@ class CostModel:
             for ax in expert_axes:
                 t += 4.0 * self.machine.all_to_all_time(
                     out_bytes, self.mesh_shape[ax], ax)
+        return t + self._state_pass_time(op, axis_map) \
+            + self._ring_rotation_time(op, axis_map)
+
+    def _state_pass_time(self, op: Op, axis_map: AxisMap) -> float:
+        """HBM passes over the op's weights that no matmul's roofline holds
+        (on top of either cost tier: a measured shard time has neither).
+        The optimizer's update reads weight, moments and gradient and
+        writes weight and moments, over what THIS chip holds: priced once
+        the model has an optimizer (a cost model built before compile()
+        prices none, as before). FSDP writes and reads the gathered weight
+        at the compute dtype for the forward pass, again for the backward,
+        and the whole gradient once before its reduce-scatter."""
+        layout = self._weight_layout(op, axis_map)
+        if not layout:
+            return 0.0
+        nbytes = 0.0
+        if getattr(self.model, "optimizer", None) is not None:
+            held, _ = self._weight_held(op, axis_map, layout)
+            nbytes += held * self.master_bytes * (2 * (1 + self.opt_slots) + 1)
+        for elems, sharded_axes, fsdp in layout:
+            if fsdp:
+                deg = 1
+                for ax in sharded_axes:
+                    deg *= self.mesh_shape.get(ax, 1)
+                nbytes += 3 * 2 * elems / deg * self.dtype_bytes
+        return nbytes / self.machine.hbm_bw
+
+    def _ring_rotation_time(self, op: Op, axis_map: AxisMap) -> float:
+        """An op that shards a `single_axis_dims` dim (attention's
+        sequence) rotates its other inputs (keys, values) around that axis:
+        n - 1 hops forward, twice that backward (the blocks and their
+        gradients), each of one chip's share of those inputs."""
+        t = 0.0
+        for dim in op.single_axis_dims():
+            for ax, d in (axis_map or {}).items():
+                n = self.mesh_shape.get(ax, 1)
+                if d != dim or n <= 1:
+                    continue
+                share = (sum(t_.volume() for t_ in op.inputs[1:])
+                         * self.dtype_bytes
+                         / max(_parts_out(axis_map, self.mesh_shape), 1))
+                t += 3.0 * (n - 1) * self.machine.p2p_time(share)
         return t
 
-    def op_grad_sync_time(self, op: Op, axis_map: AxisMap) -> float:
-        """All-reduce of weight grads over mesh axes that parallelize the op
-        but do not shard the weight itself (pure replication axes). Priced
-        per axis so DCN-crossing axes get the two-tier cost."""
+    def _weight_layout(self, op: Op, axis_map: AxisMap):
+        """[(elements, mesh axes that shard it, fsdp?)] for each weight of
+        the op under `axis_map`: `weight_partition` says which axes shard a
+        weight, and FSDP adds its axis only where the executor would
+        (runtime._with_fsdp degrades an indivisible weight to unsharded,
+        which then pays the plain all-reduce and holds its whole state)."""
         specs = op.weight_specs()
         if not specs:
-            return 0.0
+            return []
         try:
             wp = op.weight_partition(axis_map or {})
         except Exception:
             wp = {}
-        total = 0.0
+        out = []
         for spec in specs:
-            wbytes = int(np.prod(spec.shape)) * self.dtype_bytes
             pspec = wp.get(spec.name)
             sharded_axes = set()
             if pspec is not None:
@@ -235,13 +316,6 @@ class CostModel:
                         continue
                     for ax in (entry if isinstance(entry, tuple) else (entry,)):
                         sharded_axes.add(ax)
-            shard_deg = 1
-            for ax in sharded_axes:
-                shard_deg *= self.mesh_shape.get(ax, 1)
-            # FSDP applies to THIS weight only if the executor would
-            # actually shard it: same rule as runtime._with_fsdp, which
-            # degrades indivisible weights to unsharded (they then pay
-            # the plain all-reduce, not reduce-scatter + gathers)
             fsdp = False
             if (self.fsdp_axis and self.fsdp_axis not in sharded_axes
                     and self.mesh_shape[self.fsdp_axis] > 1):
@@ -250,77 +324,113 @@ class CostModel:
                 base = pspec or ()
                 fsdp = _with_fsdp(base, spec.shape, self.fsdp_axis,
                                   self.mesh_shape[self.fsdp_axis]) is not base
+            out.append((int(np.prod(spec.shape)), sharded_axes, fsdp))
+        return out
+
+    def op_grad_sync_time(self, op: Op, axis_map: AxisMap) -> float:
+        """All-reduce of weight grads over mesh axes that parallelize the op
+        but do not shard the weight itself (pure replication axes). Priced
+        per axis so DCN-crossing axes get the two-tier cost."""
+        return sum(self._grad_sync_parts(op, axis_map))
+
+    def _grad_sync_parts(self, op: Op, axis_map: AxisMap):
+        """(reduced, beside) seconds of `op_grad_sync_time`: the
+        gradient's all-reduces over ICI axes, and everything else (FSDP's
+        reduce-scatter and weight all-gathers, an all-reduce over a DCN
+        axis). The schedule treats them differently: this backend runs an
+        ICI all-reduce synchronously (`MachineModel.all_reduce_exposed`,
+        measured on one host), the others beside the compute; the DCN
+        tier was not measured and is priced as it was."""
+        reduced = beside = 0.0
+        for elems, sharded_axes, fsdp in self._weight_layout(op, axis_map):
+            wbytes = elems * self.dtype_bytes
+            shard_deg = 1
+            for ax in sharded_axes:
+                shard_deg *= self.mesh_shape.get(ax, 1)
             for ax, d in (axis_map or {}).items():
                 if d is not None and ax not in sharded_axes:
                     if fsdp and ax == self.fsdp_axis:
                         # FSDP: the gradient over this axis reduce-scatters
                         # instead of all-reducing
-                        total += self.machine.reduce_scatter_time(
+                        beside += self.machine.reduce_scatter_time(
                             wbytes / shard_deg, self.mesh_shape[ax], ax)
                     else:
-                        total += self.machine.all_reduce_time(
+                        t = self.machine.all_reduce_time(
                             wbytes / shard_deg, self.mesh_shape[ax], ax)
+                        if ax in self.machine.dcn_axes:
+                            beside += t
+                        else:
+                            reduced += t
             if fsdp:
                 # per-step weight re-materialization: all-gather the
                 # fsdp-sharded weight at use in forward and again for
                 # backward (2x); per-chip resident bytes are
                 # wbytes / (shard_deg * fsdp_size)
                 n = self.mesh_shape[self.fsdp_axis]
-                total += 2.0 * self.machine.all_gather_time(
+                beside += 2.0 * self.machine.all_gather_time(
                     wbytes / shard_deg / n, n, self.fsdp_axis)
-        return total
+        return reduced, beside
 
-    def _relief_degree(self, axis_map: AxisMap) -> int:
-        """Product of mesh-axis sizes the op does NOT parallelize over —
-        the replication degree ZeRO-style relief modes shard weights /
-        optimizer state across (the real executor shards over the data
-        or fsdp axis; replicated axes are exactly where those live)."""
-        used = {ax for ax, d in (axis_map or {}).items() if d is not None}
-        n = 1
-        for ax, size in self.mesh_shape.items():
-            if ax not in used:
-                n *= size
-        return max(n, 1)
+    def op_exposed_sync_time(self, op: Op, axis_map: AxisMap) -> float:
+        """The part of `op_grad_sync_time` that also holds the chips'
+        compute stream after the op: the share of the gradient's ICI
+        all-reduces that this backend cannot hide behind other work."""
+        return (self.machine.all_reduce_exposed
+                * self._grad_sync_parts(op, axis_map)[0])
+
+    def _weight_held(self, op: Op, axis_map: AxisMap, layout=None):
+        """(held, scattered): the op's weight ELEMENTS one chip holds under
+        `axis_map`, and what it would hold were each weight also split over
+        every axis that replicates it (the axes ZeRO shards state over). A
+        weight is whole on every axis that does not shard it: a batch or
+        sequence axis parallelizes the op and leaves each chip its own copy
+        of the weight, its gradient and its moments."""
+        held = scattered = 0.0
+        if layout is None:
+            layout = self._weight_layout(op, axis_map)
+        for elems, sharded_axes, fsdp in layout:
+            deg = 1
+            for ax in sharded_axes:
+                deg *= self.mesh_shape.get(ax, 1)
+            if fsdp:
+                deg *= self.mesh_shape[self.fsdp_axis]
+            held += elems / deg
+            scattered += elems / self.num_devices
+        return held, scattered
 
     def op_mem_bytes(self, op: Op, axis_map: AxisMap,
                      mem_mode: str = "none") -> float:
-        """Per-device HBM bytes under this choice: weights + grads + opt
-        state (x3) plus activations, divided over the partition. CONTRACT
-        axes shard the weight but leave the output replicated.
+        """Per-device HBM bytes under this choice: each weight with its
+        gradient and the optimizer's moments at `master_dtype`, WHOLE on
+        every mesh axis that does not shard the weight (`_weight_held`;
+        `fsdp_axis` counts where the executor applies it), plus the output
+        activations at `compute_dtype` over the output's partition.
 
         ``mem_mode`` (one of MEM_MODES) applies the search-chosen relief:
           remat    — stash ~1/4 of activations, recompute the rest in bwd;
-          zero1    — optimizer state (2/3 of the x3) shards over the op's
-                     replication axes (overlap_grad_sync's ZeRO-1 update);
-          zero3    — weights + grads + opt state all shard over the
-                     replication axes (fsdp_axis / ZeRO-3);
-          offload  — grads + optimizer state live host-side (2/3 of the
-                     weight term leaves HBM), streamed per step.
-
-        Approximation note: dividing the weight term by the FULL partition
-        count credits per-shard weight slices even on pure replication
-        (DP) axes — per-shard task accounting in the reference's style
-        (simulator.cc:595-620). A consequence: plain fsdp_axis adds no
-        further division here (it would double-count) and shows up in the
-        TIME model instead; the explicit zero1/zero3 mem modes DO divide
-        further — they are the search's optimistic relief pricing, paid
-        for on the time side by mem_mode_time."""
-        parts = _parts(axis_map, self.mesh_shape)
-        w = op.weight_bytes()
-        weight_term = w * 3 / max(parts, 1)
-        act_term = (op.output_bytes()
+          zero1    — the moments shard over the axes that replicate the
+                     weight (overlap_grad_sync's ZeRO-1 update);
+          zero3    — weight, gradient and moments all shard over them
+                     (fsdp_axis / ZeRO-3);
+          offload  — gradient and moments live host-side, streamed per
+                     step.
+        The relief modes are the search's optimistic pricing, paid for on
+        the time side by mem_mode_time."""
+        held, scattered = self._weight_held(op, axis_map)
+        slots = self.opt_slots
+        if mem_mode == "zero1":
+            elems = 2.0 * held + slots * scattered
+        elif mem_mode == "zero3":
+            elems = (2.0 + slots) * scattered
+        elif mem_mode == "offload":
+            elems = held
+        else:
+            elems = (2.0 + slots) * held
+        act_term = (sum(t.volume() for t in op.outputs) * self.dtype_bytes
                     / max(_parts_out(axis_map, self.mesh_shape), 1))
         if mem_mode == "remat":
             act_term *= 0.25
-        elif mem_mode == "zero1":
-            r = self._relief_degree(axis_map)
-            weight_term = w * (1.0 + 2.0 / r) / max(parts, 1)
-        elif mem_mode == "zero3":
-            r = self._relief_degree(axis_map)
-            weight_term = w * 3 / max(parts, 1) / r
-        elif mem_mode == "offload":
-            weight_term = w / max(parts, 1)
-        return weight_term + act_term
+        return elems * self.master_bytes + act_term
 
     def mem_mode_time(self, op: Op, axis_map: AxisMap,
                       mem_mode: str = "none") -> float:
@@ -333,11 +443,11 @@ class CostModel:
           offload  — grads out + updated params back over host_bw."""
         if mem_mode in ("none", "") or mem_mode is None:
             return 0.0
-        parts = max(_parts(axis_map, self.mesh_shape), 1)
-        w = op.weight_bytes() / parts
         if mem_mode == "remat":
             return self.op_compute_time(op, axis_map) / 3.0
-        r = self._relief_degree(axis_map)
+        held, scattered = self._weight_held(op, axis_map)
+        w = held * self.master_bytes
+        r = int(round(held / scattered)) if scattered else 1
         if mem_mode == "zero1":
             return self.machine.all_gather_time(w / r, r) if r > 1 else 0.0
         if mem_mode == "zero3":
@@ -374,6 +484,15 @@ class CostModel:
             else:  # dynamic-slice, nearly free
                 cost += self.machine.ici_latency
         return cost
+
+    def edge_time(self, producer_map: AxisMap, consumer_map: AxisMap,
+                  tensor) -> float:
+        """One edge of a TRAINING step: the tensor's reshard forward and
+        its gradient's reshard back, the transpose of the first (an
+        all-gather returns as a reduce-scatter, a slice as an all-gather,
+        an all-to-all as itself), as CONTRACT's psum is charged twice."""
+        return (self.resharding_time(producer_map, consumer_map, tensor)
+                + self.resharding_time(consumer_map, producer_map, tensor))
 
     # ---- whole strategy ------------------------------------------------------
 
@@ -417,7 +536,7 @@ class CostModel:
                     want = op.input_axis_map(am, input_idx)
                 except Exception:
                     want = am
-                c = self.resharding_time(pam, want, t)
+                c = self.edge_time(pam, want, t)
                 ps, ns = blocks.get(src, (0, D))
                 if ps != pi:
                     c += (t.volume() * self.dtype_bytes / max(ns, 1)
@@ -440,8 +559,9 @@ class CostModel:
             for d in range(pi, pi + ni):
                 start = max(start, dev_compute[d])
             end = start + self.op_compute_time(op, am)
+            held = end + self.op_exposed_sync_time(op, am)
             for d in range(pi, pi + ni):
-                dev_compute[d] = end
+                dev_compute[d] = held
             finish[op.name] = end
             sync = self.op_grad_sync_time(op, am)
             if sync > 0.0:

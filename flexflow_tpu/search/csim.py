@@ -1,9 +1,11 @@
 """ctypes bridge to the native search core (csrc/sim.cc).
 
 Builds the cost tables the C++ simulator consumes: per-op choice lists
-(legal axis maps) with compute + grad-sync + per-device-memory costs and the
-device count each choice spans, plus per-edge resharding cost matrices and
-tensor sizes (for placement transfers). Compiles the simulator on first use
+(legal axis maps) with compute + grad-sync (and the part of it that holds
+the compute stream) + per-device-memory costs and the device count each
+choice spans, per-edge resharding cost matrices (forward and backward) and
+tensor sizes (for placement transfers), and the annealer's structured moves
+(tied groups, followers: `set_moves`). Compiles the simulator on first use
 (g++ through _native.build_native_lib — plain C ABI + ctypes).
 
 Strategies evaluated here are (choice, place) pairs per op: the axis map
@@ -42,7 +44,7 @@ def _load_lib():
                    np.ctypeslib.ndpointer(dtype=np.int64, flags="C"))
     cd = ctypes.c_double
     tables = [ctypes.c_int, ctypes.c_int, ctypes.c_int,  # ops, edges, devices
-              i64, d, d, d, i32,                         # op tables
+              i64, d, d, d, d, i32,                      # op tables
               i32, i32, i64, d, d]                       # edge tables
     lib.ff_simulate.restype = cd
     lib.ff_simulate.argtypes = tables + [i32, i32, cd, cd, cd, cd]
@@ -52,6 +54,8 @@ def _load_lib():
     lib.ff_mcmc.restype = cd
     lib.ff_mcmc.argtypes = tables + [i32, i32, cd, cd, cd, cd,
                                      ctypes.c_int,  # allow_place
+                                     ctypes.c_int, i32, i32,  # tied groups
+                                     i32, i64, i32,     # followers
                                      ctypes.c_int, cd, ctypes.c_uint64,
                                      i32, i32]
     _lib = lib
@@ -63,7 +67,8 @@ class CompiledSearchProblem:
 
     def __init__(self, model, cost, mesh_shape: Dict[str, int],
                  epp: bool = True, eap: bool = True):
-        from flexflow_tpu.search.driver import legal_axis_maps
+        from flexflow_tpu.search.driver import (follow_sources,
+                                                legal_axis_maps, tied_groups)
 
         self.ops = [op for op in model.ops if not isinstance(op, InputOp)]
         self.op_index = {op.name: i for i, op in enumerate(self.ops)}
@@ -77,11 +82,12 @@ class CompiledSearchProblem:
 
         # per-op cost tables
         offsets = [0]
-        compute, sync, mem, ndev = [], [], [], []
+        compute, sync, exposed, mem, ndev = [], [], [], [], []
         for op, maps in zip(self.ops, self.op_maps):
             for am in maps:
                 compute.append(cost.op_compute_time(op, am))
                 sync.append(cost.op_grad_sync_time(op, am))
+                exposed.append(cost.op_exposed_sync_time(op, am))
                 mem.append(cost.op_mem_bytes(op, am))
                 parts = 1
                 for ax, dd in am.items():
@@ -92,6 +98,7 @@ class CompiledSearchProblem:
         self.op_cost_offsets = np.asarray(offsets, np.int64)
         self.op_compute_costs = np.asarray(compute, np.float64)
         self.op_sync_costs = np.asarray(sync, np.float64)
+        self.op_exposed_costs = np.asarray(exposed, np.float64)
         self.op_mem_bytes = np.asarray(mem, np.float64)
         self.op_ndev = np.asarray(ndev, np.int32)
 
@@ -121,16 +128,44 @@ class CompiledSearchProblem:
                 pm_out = src_op.output_axis_map(pm)
                 for cm in dst_maps:
                     want = dst_op.input_axis_map(cm, input_idx)
-                    ecosts.append(cost.resharding_time(pm_out, want, t))
+                    ecosts.append(cost.edge_time(pm_out, want, t))
             eoffsets.append(len(ecosts))
         self.edge_cost_offsets = np.asarray(eoffsets, np.int64)
         self.edge_costs = np.asarray(ecosts, np.float64)
         self.num_edges = len(edges)
+        self.set_moves(tied_groups(model), follow_sources(model))
+
+    def set_moves(self, groups, follows):
+        """The annealer's structured moves as flat tables (sim.cc ff_mcmc):
+        tied groups (lists of op names whose members hold one choice list)
+        and, per follower, its producer and for each of the producer's
+        choices the follower's own choice that equals what it delivers."""
+        from flexflow_tpu.search.driver import follow_choice
+
+        for g in groups:
+            assert len({len(self.op_maps[self.op_index[n]]) for n in g}) == 1
+        self.groups = [list(g) for g in groups]
+        self.group_offsets = np.cumsum(
+            [0] + [len(g) for g in groups]).astype(np.int32)
+        self.group_members = np.asarray(
+            [self.op_index[n] for g in groups for n in g], np.int32)
+        src_arr, offsets, tbl = [], [0], []
+        for i, op in enumerate(self.ops):
+            src = self.op_index.get(follows.get(op.name), -1)
+            src_arr.append(src)
+            if src >= 0:
+                tbl += [follow_choice(op, self.ops[src], m, self.op_maps[i])
+                        for m in self.op_maps[src]]
+            offsets.append(len(tbl))
+        self.follow_src = np.asarray(src_arr, np.int32)
+        self.follow_offsets = np.asarray(offsets, np.int64)
+        self.follow_tbl = np.asarray(tbl or [-1], np.int32)
 
     def _table_args(self):
         return (len(self.ops), self.num_edges, self.num_devices,
                 self.op_cost_offsets, self.op_compute_costs,
-                self.op_sync_costs, self.op_mem_bytes, self.op_ndev,
+                self.op_sync_costs, self.op_exposed_costs, self.op_mem_bytes,
+                self.op_ndev,
                 self.edge_src, self.edge_dst, self.edge_cost_offsets,
                 self.edge_costs, self.edge_bytes)
 
@@ -226,7 +261,9 @@ class CompiledSearchProblem:
             p = np.zeros(len(self.ops), np.int32)
             cost = lib.ff_mcmc(
                 *self._table_args(), init, places, *self._machine_args(),
-                int(allow_place), budget, alpha, seed * 0x9E3779B1 + k, c, p)
+                int(allow_place), len(self.groups), self.group_offsets,
+                self.group_members, self.follow_src, self.follow_offsets,
+                self.follow_tbl, budget, alpha, seed * 0x9E3779B1 + k, c, p)
             return c, p, cost
 
         if K == 1:
@@ -292,42 +329,38 @@ def native_optimize(model, cost, mesh_shape: Dict[str, int], budget: int,
                     alpha: float, seed: int,
                     verbose: bool = False,
                     restarts: int = 4,
-                    warm_start=None) -> Dict[str, ParallelConfig]:
-    from flexflow_tpu.search.driver import (data_parallel_strategy,
-                                            hierarchical_strategy)
+                    warm_start=None, seeds=None):
+    """The native search: ``{op: ParallelConfig}``, with the seeds' prices,
+    the start and the winner left on ``model._search_report``. ``seeds``
+    ({name: strategy}, `driver.search_seeds`) are what
+    `optimize_strategies` hands over; a caller that has none gets the same
+    ones computed here (the tied groups and followers are the problem's
+    own: `CompiledSearchProblem.set_moves`). The chains
+    start from the cheapest seed, and every seed competes with the
+    annealed winner (a two-tier machine's hierarchical candidate and a
+    warm start among them, priced by the same C tables), so the result
+    is never priced above any seed. A seed this mesh's legal maps do not
+    hold (a stale warm start) is dropped, not fatal."""
+    from flexflow_tpu.search.driver import search_seeds
 
     cfg = getattr(model, "config", None)
     epp = getattr(cfg, "enable_parameter_parallel", True)
     eap = getattr(cfg, "enable_attribute_parallel", True)
     prob = get_search_problem(model, cost, mesh_shape, epp, eap)
-    init = prob.choices_for(data_parallel_strategy(model, mesh_shape))
-    dp_cost = prob.simulate(init)
-    init_cost = dp_cost
-    # two-tier machine: the hierarchical ICI/DCN candidate (data/STAGE on
-    # the DCN axes, CONTRACT/TP inside ICI) is a first-class move — it
-    # seeds the chains when it beats flat DP, and it competes with the
-    # annealed winner below either way (the C tables already price its
-    # grad syncs at the DCN tier through op_grad_sync_time)
-    hier_c = hier_cost = None
-    if getattr(cost.machine, "dcn_axes", None):
-        hier_c = prob.choices_for(hierarchical_strategy(
-            model, mesh_shape, cost.machine.dcn_axes, epp, eap))
-        hier_cost = prob.simulate(hier_c)
-        if hier_cost < init_cost:
-            init, init_cost = hier_c, hier_cost
-    # warm start (ISSUE 19d): a previous search's strategy — already
-    # normalized by driver.warm_start_seed to this mesh's legal maps —
-    # seeds the chains when cheaper and competes with the winner below,
-    # so an N-chip result can only help, never hurt, the M-chip search
-    warm_c = warm_cost = None
-    if warm_start is not None:
+    if seeds is None:
+        op_maps = {op.name: m for op, m in zip(prob.ops, prob.op_maps)}
+        seeds = search_seeds(model, mesh_shape, cost, op_maps, warm_start,
+                             epp, eap)
+    choices, seed_costs = {}, {}
+    for name, strat in seeds.items():
         try:
-            warm_c = prob.choices_for(warm_start)
-            warm_cost = prob.simulate(warm_c)
-            if warm_cost < init_cost:
-                init, init_cost = warm_c, warm_cost
+            choices[name] = prob.choices_for(strat)
         except ValueError:
-            warm_c = warm_cost = None  # stale strategy: ignore, not fatal
+            continue
+        seed_costs[name] = prob.simulate(choices[name])
+    started_from = min(seed_costs, key=seed_costs.get)
+    init, init_cost = choices[started_from], seed_costs[started_from]
+    dp_cost = seed_costs.get("data_parallel", init_cost)
     # FSDP shards every weight over the full fsdp mesh axis; a sub-mesh
     # placement cannot hold such a weight, so the annealer must not
     # propose device-block moves (compile would reject its own winner)
@@ -335,14 +368,11 @@ def native_optimize(model, cost, mesh_shape: Dict[str, int], budget: int,
     best_c, best_p, best_cost = prob.mcmc(init, budget, alpha, seed,
                                           restarts=restarts,
                                           allow_place=allow_place)
-    if hier_cost is not None and hier_cost < best_cost:
-        best_c, best_p, best_cost = (hier_c,
-                                     np.zeros(len(prob.ops), np.int32),
-                                     hier_cost)
-    if warm_cost is not None and warm_cost < best_cost:
-        best_c, best_p, best_cost = (warm_c,
-                                     np.zeros(len(prob.ops), np.int32),
-                                     warm_cost)
+    winner = "annealed"
+    if init_cost <= best_cost:
+        best_c, best_p, best_cost = (init, np.zeros(len(prob.ops), np.int32),
+                                     init_cost)
+        winner = started_from
     if verbose:
         print(f"[search/native] best {best_cost * 1e3:.3f} ms vs DP "
               f"{dp_cost * 1e3:.3f} ms "
@@ -359,6 +389,8 @@ def native_optimize(model, cost, mesh_shape: Dict[str, int], budget: int,
         pc.device_ids = tuple(range(start, start + ndev))
         out[op.name] = pc
     _snap_tied_blocks(model, out, prob.num_devices)
+    model._search_report = {"seed_costs": seed_costs,
+                            "started_from": started_from, "winner": winner}
     return out
 
 

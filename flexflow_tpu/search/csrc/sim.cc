@@ -7,7 +7,8 @@
 // Division of labor: Python (flexflow_tpu/search/cost_model.py) knows the
 // machine model and computes COST TABLES —
 //   * per op, per legal axis-map choice: compute seconds, gradient-sync comm
-//     seconds, per-device memory bytes, and the number of devices spanned,
+//     seconds (and the share of them that holds the compute stream: a
+//     synchronous all-reduce), per-device memory bytes, devices spanned,
 //   * per graph edge, per (producer choice, consumer choice) pair:
 //     resharding comm seconds (GSPMD collectives within a device block).
 // This library evaluates a strategy — a (choice, placement) pair per op —
@@ -37,6 +38,8 @@ struct Tables {
   const int64_t* op_cost_offsets;   // [num_ops+1]
   const double* op_compute_costs;   // per (op, choice)
   const double* op_sync_costs;      // per (op, choice)
+  const double* op_exposed_costs;   // per (op, choice): the part of the sync
+                                    // that also holds the compute stream
   const double* op_mem_bytes;       // per (op, choice): per-device HBM bytes
   const int32_t* op_ndev;           // per (op, choice): devices spanned
   const int32_t* edge_src;          // [num_edges], sorted by dst, src < dst
@@ -125,7 +128,11 @@ double schedule(const Tables& T, const int32_t* choices,
     double start = ready;
     for (int d = pi; d < pi + ni; ++d) start = std::max(start, dev_compute[d]);
     double end = start + comp;
-    for (int d = pi; d < pi + ni; ++d) dev_compute[d] = end;
+    // what of the gradient's reduction this backend cannot hide (a
+    // synchronous all-reduce) holds the compute stream after the op; the
+    // sync stream below still carries the whole sync
+    double held = end + T.op_exposed_costs[off + choices[i]];
+    for (int d = pi; d < pi + ni; ++d) dev_compute[d] = held;
     finish[i] = end;
     if (tl && tl->compute_start) { tl->compute_start[i] = start; tl->compute_finish[i] = end; }
     // gradient sync rides this block's sync streams after the compute
@@ -162,6 +169,7 @@ Tables make_tables(int num_ops, int num_edges, int num_devices,
                    const int64_t* op_cost_offsets,
                    const double* op_compute_costs,
                    const double* op_sync_costs,
+                   const double* op_exposed_costs,
                    const double* op_mem_bytes,
                    const int32_t* op_ndev,
                    const int32_t* edge_src, const int32_t* edge_dst,
@@ -176,6 +184,7 @@ Tables make_tables(int num_ops, int num_edges, int num_devices,
   T.op_cost_offsets = op_cost_offsets;
   T.op_compute_costs = op_compute_costs;
   T.op_sync_costs = op_sync_costs;
+  T.op_exposed_costs = op_exposed_costs;
   T.op_mem_bytes = op_mem_bytes;
   T.op_ndev = op_ndev;
   T.edge_src = edge_src; T.edge_dst = edge_dst;
@@ -201,6 +210,7 @@ double ff_simulate(int num_ops, int num_edges, int num_devices,
                    const int64_t* op_cost_offsets,
                    const double* op_compute_costs,
                    const double* op_sync_costs,
+                   const double* op_exposed_costs,
                    const double* op_mem_bytes,
                    const int32_t* op_ndev,
                    const int32_t* edge_src, const int32_t* edge_dst,
@@ -211,8 +221,8 @@ double ff_simulate(int num_ops, int num_edges, int num_devices,
                    double hbm_bytes, double ici_bw, double ici_latency,
                    double mem_penalty_per_byte) {
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
-                         op_compute_costs, op_sync_costs, op_mem_bytes,
-                         op_ndev, edge_src, edge_dst, edge_cost_offsets,
+                         op_compute_costs, op_sync_costs, op_exposed_costs,
+                         op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
                          edge_costs, edge_bytes, hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   return schedule(T, choices, places, nullptr);
@@ -222,6 +232,7 @@ double ff_simulate_timeline(int num_ops, int num_edges, int num_devices,
                             const int64_t* op_cost_offsets,
                             const double* op_compute_costs,
                             const double* op_sync_costs,
+                            const double* op_exposed_costs,
                             const double* op_mem_bytes,
                             const int32_t* op_ndev,
                             const int32_t* edge_src, const int32_t* edge_dst,
@@ -235,8 +246,8 @@ double ff_simulate_timeline(int num_ops, int num_edges, int num_devices,
                             double* comm_start, double* comm_finish,
                             double* sync_start, double* sync_finish) {
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
-                         op_compute_costs, op_sync_costs, op_mem_bytes,
-                         op_ndev, edge_src, edge_dst, edge_cost_offsets,
+                         op_compute_costs, op_sync_costs, op_exposed_costs,
+                         op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
                          edge_costs, edge_bytes, hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   Timeline tl{compute_start, compute_finish, comm_start, comm_finish,
@@ -244,15 +255,25 @@ double ff_simulate_timeline(int num_ops, int num_edges, int num_devices,
   return schedule(T, choices, places, &tl);
 }
 
-// MCMC simulated annealing (reference: model.cc:1663-1725). Proposals
-// re-randomize one op's axis-map choice or its device block (reference
-// rewrite model.cc:1652-1661 + random contiguous ranges model.cc:496-525).
+// MCMC simulated annealing (reference: model.cc:1663-1725). A proposal is
+// one of (mirror of the Python annealer in search/driver.py):
+//   * one op: re-randomize its axis-map choice or its device block
+//     (reference rewrite model.cc:1652-1661 + random contiguous ranges
+//     model.cc:496-525) -- one proposal in four;
+//   * one TIED GROUP (ops that play the same part in a repeated block:
+//     driver.tied_groups; every member holds the same choice list): all
+//     members take one new choice -- the rest, and in two of those three
+//     every FOLLOWER downstream (an op with no parameter dim and one
+//     producer shape: follow_src) takes the choice that equals what its
+//     producer now delivers (follow_tbl), in topological order, so a chain
+//     of elementwise ops moves with the matmul that feeds it.
 // Returns the best cost; best_choices/best_places filled with the best
 // strategy.
 double ff_mcmc(int num_ops, int num_edges, int num_devices,
                const int64_t* op_cost_offsets,
                const double* op_compute_costs,
                const double* op_sync_costs,
+               const double* op_exposed_costs,
                const double* op_mem_bytes,
                const int32_t* op_ndev,
                const int32_t* edge_src, const int32_t* edge_dst,
@@ -265,11 +286,18 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
                int allow_place,  // 0: never propose device-block moves
                                  // (FSDP shards weights over the FULL
                                  // mesh, incompatible with sub-meshes)
+               int num_groups,
+               const int32_t* group_offsets,   // [num_groups+1]
+               const int32_t* group_members,   // op indices, by group
+               const int32_t* follow_src,      // [num_ops]: producer or -1
+               const int64_t* follow_offsets,  // [num_ops+1] into follow_tbl
+               const int32_t* follow_tbl,      // per producer choice: own
+                                               // choice, or -1 (not legal)
                int budget, double alpha, uint64_t seed,
                int32_t* best_choices, int32_t* best_places) {
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
-                         op_compute_costs, op_sync_costs, op_mem_bytes,
-                         op_ndev, edge_src, edge_dst, edge_cost_offsets,
+                         op_compute_costs, op_sync_costs, op_exposed_costs,
+                         op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
                          edge_costs, edge_bytes, hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   const int D = T.num_devices;
@@ -285,6 +313,11 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
     return std::max(1, std::min(n, D));
   };
   auto eval = [&]() { return schedule(T, cur_c.data(), cur_p.data(), nullptr); };
+  auto set_choice = [&](int op, int c) {
+    cur_c[op] = c;
+    cur_p[op] = align_place(cur_p[op], ndev_of(op, c), D);
+  };
+  std::vector<char> moved(num_ops, 0);
 
   double cur_cost = eval();
   std::vector<int32_t> best_c = cur_c, best_p = cur_p;
@@ -299,24 +332,44 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
       cur_c = best_c; cur_p = best_p;
       cur_cost = best_cost;
     }
-    int op = (int)(rng() % (uint64_t)num_ops);
-    int n_choices = (int)(op_cost_offsets[op + 1] - op_cost_offsets[op]);
-    int old_c = cur_c[op], old_p = cur_p[op];
-    // half the proposals move the device block, half the axis map
-    // (reference re-randomizes both at once; splitting mixes faster)
-    bool move_place = allow_place && (rng() & 1) != 0;
-    int ndev = ndev_of(op, old_c);
-    int nblocks = (ndev < D && D % ndev == 0) ? D / ndev : 1;
-    if (move_place && nblocks > 1) {
-      cur_p[op] = (int)(rng() % (uint64_t)nblocks) * ndev;
-      if (cur_p[op] == old_p) continue;
+    const std::vector<int32_t> old_c = cur_c, old_p = cur_p;
+    if (rng() % 4 == 0) {
+      int op = (int)(rng() % (uint64_t)num_ops);
+      int n_choices = (int)(op_cost_offsets[op + 1] - op_cost_offsets[op]);
+      // half the proposals move the device block, half the axis map
+      // (reference re-randomizes both at once; splitting mixes faster)
+      bool move_place = allow_place && (rng() & 1) != 0;
+      int ndev = ndev_of(op, cur_c[op]);
+      int nblocks = (ndev < D && D % ndev == 0) ? D / ndev : 1;
+      if (move_place && nblocks > 1) {
+        cur_p[op] = (int)(rng() % (uint64_t)nblocks) * ndev;
+      } else {
+        if (n_choices <= 1) continue;
+        set_choice(op, (int)(rng() % (uint64_t)n_choices));
+      }
     } else {
-      if (n_choices <= 1) continue;
+      int g = (int)(rng() % (uint64_t)num_groups);
+      bool follow = rng() % 3 != 0;
+      int first = group_members[group_offsets[g]];
+      int n_choices = (int)(op_cost_offsets[first + 1] - op_cost_offsets[first]);
       int new_c = (int)(rng() % (uint64_t)n_choices);
-      if (new_c == old_c) continue;
-      cur_c[op] = new_c;
-      cur_p[op] = align_place(old_p, ndev_of(op, new_c), D);
+      std::fill(moved.begin(), moved.end(), 0);
+      for (int k = group_offsets[g]; k < group_offsets[g + 1]; ++k) {
+        set_choice(group_members[k], new_c);
+        moved[group_members[k]] = 1;
+      }
+      if (follow) {
+        for (int i = 0; i < num_ops; ++i) {
+          int src = follow_src[i];
+          if (src < 0 || !moved[src] || moved[i]) continue;
+          int c = follow_tbl[follow_offsets[i] + cur_c[src]];
+          if (c < 0) continue;
+          set_choice(i, c);
+          moved[i] = 1;
+        }
+      }
     }
+    if (cur_c == old_c && cur_p == old_p) continue;
     double new_cost = eval();
     double diff = new_cost - cur_cost;
     // reference accepts with prob exp(-alpha*diff) on simulated ms; our
@@ -328,7 +381,7 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
         best_c = cur_c; best_p = cur_p;
       }
     } else {
-      cur_c[op] = old_c; cur_p[op] = old_p;
+      cur_c = old_c; cur_p = old_p;
     }
   }
   std::memcpy(best_choices, best_c.data(), sizeof(int32_t) * num_ops);

@@ -1,16 +1,38 @@
 """MCMC strategy search driver.
 
 Reference: FFModel::optimize (model.cc:1663-1725) — simulated annealing over
-per-op ParallelConfigs: start from data-parallel (or imported), propose =
-re-randomize one op's config (rewrite, model.cc:1652-1661), accept if better
-else with prob exp(-alpha * diff), periodic reset-to-best every budget/100
-iterations (capped 1000).
+per-op ParallelConfigs: accept a proposal if better, else with prob
+exp(-alpha * diff), periodic reset-to-best every budget/100 iterations
+(capped 1000). The reference starts from data parallelism and re-randomizes
+one op's config per proposal (rewrite, model.cc:1652-1661). Here both are
+wider, because on a mesh of more than one axis neither finds what the
+simulator's own prices prefer (PERF.md, PR 36):
+
+* **Where it starts** (`search_seeds`). Every seed is priced and the chains
+  start from the cheapest; each competes with the annealed winner, so the
+  result is never priced above any of them. The seeds: flat data parallelism
+  over `data` (on a two-axis mesh it leaves the other axis replicated, holds
+  every weight whole on every chip and LOSES: it is listed so that its price
+  is on record); the uniform family (`uniform_seeds`: each mesh axis given
+  one role for the whole graph: batch, sequence, the ops' parameter dim with
+  CONTRACT on the matmul that consumes it, or none; all-batch, Megatron-style
+  tensor parallelism and sequence parallelism are members, by role and not
+  by model name); on a two-tier machine `hierarchical_strategy`; a warm
+  start that carries over.
+* **What a proposal rewrites**. One op, as in the reference (one in four:
+  a strategy that treats an embedding-side or head-side layer differently
+  stays reachable); or one TIED GROUP, the ops that play the same part in a
+  repeated block (`tied_groups`: found from the graph, a decoder's four
+  `ffn_gate`s), all to one map, and in two of three such moves every
+  follower downstream (`follow_sources`: an elementwise op or norm with one
+  producer shape) takes what its producer now delivers.
 
 TPU version: proposals are mesh-expressible axis maps (each mesh axis is
 assigned to one of the op's partitionable output dims or left replicated,
 subject to divisibility) — the GSPMD-constrained SOAP space. The objective is
 CostModel.iteration_time; when the C++ simulator library is built it replaces
-the Python loop wholesale (flexflow_tpu/search/csim.py).
+the Python loop wholesale (flexflow_tpu/search/csim.py, csrc/sim.cc ff_mcmc:
+the same seeds, groups and moves).
 """
 
 from __future__ import annotations
@@ -147,6 +169,188 @@ def hierarchical_strategy(model, mesh_shape: Dict[str, int],
     return out
 
 
+ROLES = ("batch", "sequence", "parameter", None)
+
+
+def _normalized(am: AxisMap) -> AxisMap:
+    return {ax: d for ax, d in (am or {}).items() if d is not None}
+
+
+def _role_dims(op):
+    """(parameter dim, sequence dim) of an op's primary output, read off the
+    op itself: the parameter dim is the one its weight contraction produces
+    (`_contracted_output_dims`: a Linear's or an Embedding's channels, an
+    attention's hidden, a convolution's out-channels), the sequence dim the
+    first partitionable dim that is neither the sample dim nor that one (a
+    decoder's positions, a convolution's rows). Either may be None."""
+    nd = op.outputs[0].num_dims
+    produced = [d % nd for d in op._contracted_output_dims]
+    dims = op.partitionable_output_dims()
+    pdim = next((d for d in produced if d in dims), None)
+    sdim = next((d for d in dims if d != 0 and d not in produced), None)
+    return pdim, sdim
+
+
+def uniform_strategy(model, mesh_shape: Dict[str, int],
+                     roles: Dict[str, Optional[str]],
+                     op_maps: Dict[str, list]) -> Dict[str, AxisMap]:
+    """ONE rule for every op: mesh axis -> role (`ROLES`). An axis on
+    "batch" shards every op's sample dim, on "sequence" every op's sequence
+    dim. On "parameter" it shards the parameter dim of each op that has one,
+    or that op's contraction (CONTRACT) when its input already arrives
+    sharded there over this axis (a column-parallel producer feeds a
+    row-parallel consumer: the Megatron pair); an op with no parameter dim
+    (elementwise, a norm) takes what its first producer delivers on the
+    axis. Whatever the op's `legal_axis_maps` (``op_maps``) do not hold is
+    dropped axis by axis, so the result always simulates and compiles."""
+    from flexflow_tpu.parallel.pconfig import CONTRACT
+
+    out: Dict[str, AxisMap] = {}
+    delivered: Dict[str, AxisMap] = {}
+    for op in model.ops:
+        if isinstance(op, InputOp):
+            continue
+        pdim, sdim = _role_dims(op)
+        src = next((t.owner_op for t in op.inputs if t.owner_op is not None
+                    and not isinstance(t.owner_op, InputOp)), None)
+        arrives = delivered.get(src.name, {}) if src is not None else {}
+        want: AxisMap = {}
+        for ax, role in roles.items():
+            if role == "batch":
+                want[ax] = 0
+            elif role == "sequence":
+                want[ax] = sdim
+            elif role == "parameter":
+                got = arrives.get(ax)
+                if pdim is None:
+                    want[ax] = got
+                elif (got is not None and op.contract_size() is not None
+                        and got == op.contract_input_dim(0)):
+                    want[ax] = CONTRACT
+                else:
+                    want[ax] = pdim
+        want = _normalized(want)
+        legal = [_normalized(m) for m in op_maps[op.name]]
+        for ax in reversed(list(want)):
+            if want in legal:
+                break
+            del want[ax]
+        out[op.name] = want
+        delivered[op.name] = op.output_axis_map(want)
+    return out
+
+
+def uniform_seeds(model, mesh_shape: Dict[str, int],
+                  op_maps: Dict[str, list]) -> Dict[str, Dict[str, AxisMap]]:
+    """The family the annealer starts from: every assignment of the mesh's
+    axes (size > 1) to a role, `uniform_strategy` of each, duplicates
+    dropped. {name: strategy}; a name reads ``data=batch,model=parameter``.
+    Two axes give 16 assignments (and about a dozen distinct strategies),
+    three 64; past four axes the replicated role is left out."""
+    import itertools
+
+    axes = [ax for ax, n in mesh_shape.items() if n > 1]
+    roles = ROLES if len(axes) <= 4 else ROLES[:-1]
+    seeds: Dict[str, Dict[str, AxisMap]] = {}
+    for combo in itertools.product(roles, repeat=len(axes)):
+        strat = uniform_strategy(model, mesh_shape, dict(zip(axes, combo)),
+                                 op_maps)
+        if strat not in seeds.values():
+            seeds[",".join(f"{ax}={r or 'none'}"
+                           for ax, r in zip(axes, combo))] = strat
+    return seeds
+
+
+def tied_groups(model) -> list:
+    """Ops that play the same part in a repeated block, as lists of op
+    names in graph order (every op is in exactly one list; most of a
+    non-repeating graph's lists hold one op). Found from the graph alone:
+    an op's signature is its class, operator type, input, output and weight
+    shapes and how far back each of its producers lies; a run of the op
+    list in which a block of signatures repeats back to back (a decoder's
+    layers) ties each position of the block across the repeats. The widest
+    such run is taken first, then what lies left and right of it."""
+    ops = [op for op in model.ops if not isinstance(op, InputOp)]
+    index = {op.name: i for i, op in enumerate(ops)}
+    sig = [(type(op).__name__, op.op_type,
+            tuple(tuple(t.dims) for t in op.inputs),
+            tuple(tuple(t.dims) for t in op.outputs),
+            tuple(tuple(w.shape) for w in op.weight_specs()),
+            tuple(i - index[t.owner_op.name] for t in op.inputs
+                  if t.owner_op is not None and t.owner_op.name in index))
+           for i, op in enumerate(ops)]
+    groups = []
+
+    def split(lo, hi):
+        best = None  # (covered, start, period)
+        for p in range(1, (hi - lo) // 2 + 1):
+            run = 0
+            for i in range(lo, hi - p):
+                run = run + 1 if sig[i] == sig[i + p] else 0
+                if run >= p:
+                    covered = (run // p + 1) * p
+                    if best is None or covered > best[0]:
+                        best = (covered, i - run + 1, p)
+        if best is None:
+            groups.extend([ops[i].name] for i in range(lo, hi))
+            return
+        covered, start, p = best
+        split(lo, start)
+        for k in range(p):
+            groups.append([ops[i].name
+                           for i in range(start + k, start + covered, p)])
+        split(start + covered, hi)
+
+    split(0, len(ops))
+    return groups
+
+
+def follow_sources(model) -> Dict[str, str]:
+    """{follower: producer}: an op with no parameter dim whose producers
+    all deliver its own output shape (an elementwise op, a norm) may take
+    its first producer's output map in the move that rewrites the
+    producer."""
+    out = {}
+    for op in model.ops:
+        if isinstance(op, InputOp) or _role_dims(op)[0] is not None:
+            continue
+        srcs = [t.owner_op for t in op.inputs if t.owner_op is not None
+                and not isinstance(t.owner_op, InputOp)]
+        if srcs and all(tuple(t.dims) == tuple(op.outputs[0].dims)
+                        for t in op.inputs):
+            out[op.name] = srcs[0].name
+    return out
+
+
+def follow_choice(op, src_op, src_map: AxisMap, maps: list) -> int:
+    """Index in ``maps`` (the follower's legal maps) of the map that
+    equals what ``src_op`` delivers under ``src_map``; -1 if none does."""
+    want = _normalized(src_op.output_axis_map(src_map))
+    return next((j for j, m in enumerate(maps) if _normalized(m) == want),
+                -1)
+
+
+def search_seeds(model, mesh_shape: Dict[str, int], cost, op_maps,
+                 warm=None, epp: bool = True, eap: bool = True
+                 ) -> Dict[str, Dict[str, AxisMap]]:
+    """Every strategy the annealer may start from and must not lose to,
+    by name: flat data parallelism over `data` (on a mesh of two axes it
+    leaves the other replicated, holds every weight whole and loses; on a
+    `data`-only mesh it is the family's batch member), the uniform family,
+    on a two-tier machine the hierarchical ICI/DCN candidate, and a warm
+    start that carries over."""
+    seeds = {"data_parallel": data_parallel_strategy(model, mesh_shape)}
+    for name, strat in uniform_seeds(model, mesh_shape, op_maps).items():
+        if strat not in seeds.values():
+            seeds[name] = strat
+    if cost.machine.dcn_axes:
+        seeds["hierarchical"] = hierarchical_strategy(
+            model, mesh_shape, cost.machine.dcn_axes, epp, eap)
+    if warm is not None:
+        seeds["warm_start"] = warm
+    return seeds
+
+
 def data_parallel_strategy(model, mesh_shape: Dict[str, int]) -> Dict[str, AxisMap]:
     out = {}
     for op in model.ops:
@@ -274,11 +478,20 @@ def optimize_strategies(model, budget: int = 1000, alpha: float = 0.05,
     epp = getattr(cfgflags, "enable_parameter_parallel", True)
     eap = getattr(cfgflags, "enable_attribute_parallel", True)
     warm = warm_start_seed(model, mesh_shape, warm_start, epp, eap)
+    ops = [op for op in model.ops if not isinstance(op, InputOp)]
+    # proposal distributions, precomputed once per op
+    op_maps = {op.name: legal_axis_maps(op, mesh_shape, epp, eap) for op in ops}
+    # the chains start from the CHEAPEST seed and `best` starts at that
+    # seed's cost, so best-of-chain can only improve on it: the result is
+    # never priced above any seed, however short or unlucky the chain
+    seeds = search_seeds(model, mesh_shape, cost, op_maps, warm, epp, eap)
+    groups = tied_groups(model)
 
     # which simulator priced the strategy rides the model (into
     # _search_summary) and the log: a search that quietly ran the Python
     # annealer is not the search the docs describe
     model._search_simulator = "python"
+    out = None
     if use_native:
         from flexflow_tpu.logger import fflogger
 
@@ -286,46 +499,63 @@ def optimize_strategies(model, budget: int = 1000, alpha: float = 0.05,
             from flexflow_tpu.search.csim import native_optimize
 
             out = native_optimize(model, cost, mesh_shape, budget, alpha,
-                                  seed, verbose=verbose, warm_start=warm)
+                                  seed, verbose=verbose, seeds=seeds)
             model._search_simulator = "native"
             fflogger.info("search: native C++ simulator ran (budget %d)",
                           budget)
-            return out
         except (ImportError, OSError) as e:
             fflogger.warning(
                 "search: native simulator unavailable (%s: %s) — running "
                 "the Python annealer", type(e).__name__, e)
+    if out is None:
+        out = _python_anneal(model, cost, mesh_shape, ops, op_maps, seeds,
+                             groups, budget, alpha, seed, verbose)
+    model._search_report = _edge_report(cost, ops, out,
+                                        model._search_report, groups)
+    return out
 
+
+def _python_anneal(model, cost, mesh_shape, ops, op_maps, seeds, groups,
+                   budget, alpha, seed, verbose):
+    """The annealer of `csrc/sim.cc` `ff_mcmc` in Python (no device-block
+    moves: the Python objective is asked without placements): the same
+    seeds, the same three kinds of proposal in the same shares, the same
+    acceptance rule and reset-to-best."""
     rng = random.Random(seed)
-    ops = [op for op in model.ops if not isinstance(op, InputOp)]
-    # proposal distributions, precomputed once per op
-    op_maps = {op.name: legal_axis_maps(op, mesh_shape, epp, eap) for op in ops}
-
-    # seed candidates: flat data-parallel always; on a two-tier machine
-    # also the hierarchical ICI/DCN candidate; plus the warm-start seed
-    # when a previous strategy carries over. The anneal starts from the
-    # CHEAPER seed, and `best` starts at that seed's cost — best-of-chain
-    # can only improve on it, so the hierarchical structure survives even
-    # a short or unlucky chain (the losing seed costs strictly more and
-    # can never win)
-    seeds = [data_parallel_strategy(model, mesh_shape)]
-    if cost.machine.dcn_axes:
-        seeds.append(hierarchical_strategy(model, mesh_shape,
-                                           cost.machine.dcn_axes, epp, eap))
-    if warm is not None:
-        seeds.append(warm)
-    scored = sorted(((cost.iteration_time(s), i, s)
-                     for i, s in enumerate(seeds)), key=lambda t: t[:2])
-    current, current_cost = dict(scored[0][2]), scored[0][0]
+    by_name = {op.name: op for op in ops}
+    follows = follow_sources(model)
+    seed_costs = {name: cost.iteration_time(s) for name, s in seeds.items()}
+    started_from = min(seed_costs, key=seed_costs.get)
+    current = dict(seeds[started_from])
+    current_cost = seed_costs[started_from]
     best, best_cost = dict(current), current_cost
     reset_span = min(max(budget // 100, 1), 1000)  # reference model.cc:1673-1677
 
     for it in range(budget):
         if it % reset_span == 0 and it > 0:
             current, current_cost = dict(best), best_cost
-        op = rng.choice(ops)
         proposal = dict(current)
-        proposal[op.name] = rng.choice(op_maps[op.name])
+        if rng.randrange(4) == 0:
+            op = rng.choice(ops)
+            proposal[op.name] = rng.choice(op_maps[op.name])
+        else:
+            group = rng.choice(groups)
+            follow = rng.randrange(3) != 0
+            pick = rng.randrange(len(op_maps[group[0]]))
+            moved = set(group)
+            for name in group:
+                proposal[name] = op_maps[name][pick]
+            if follow:
+                # in graph order, so a chain of followers moves as one
+                for op in ops:
+                    src = follows.get(op.name)
+                    if src not in moved or op.name in moved:
+                        continue
+                    j = follow_choice(op, by_name[src], proposal[src],
+                                      op_maps[op.name])
+                    if j >= 0:
+                        proposal[op.name] = op_maps[op.name][j]
+                        moved.add(op.name)
         new_cost = cost.iteration_time(proposal)
         diff = new_cost - current_cost
         if diff < 0 or rng.random() < math.exp(-alpha * diff * 1e3):
@@ -337,7 +567,7 @@ def optimize_strategies(model, budget: int = 1000, alpha: float = 0.05,
                   f"best {best_cost * 1e3:.3f} ms")
 
     if verbose:
-        dp_cost = cost.iteration_time(data_parallel_strategy(model, mesh_shape))
+        dp_cost = seed_costs["data_parallel"]
         print(f"[search] done: best {best_cost * 1e3:.3f} ms vs DP "
               f"{dp_cost * 1e3:.3f} ms ({dp_cost / max(best_cost, 1e-12):.2f}x)")
 
@@ -346,7 +576,31 @@ def optimize_strategies(model, budget: int = 1000, alpha: float = 0.05,
         am = best.get(op.name, {})
         out[op.name] = ParallelConfig.from_axis_map(
             op.outputs[0].num_dims, mesh_shape, am)
+    model._search_report = {
+        "seed_costs": seed_costs, "started_from": started_from,
+        "winner": ("annealed" if best_cost < seed_costs[started_from]
+                   else started_from)}
     return out
+
+
+def _edge_report(cost, ops, out, report, groups):
+    """The search's own account of its result, for `_search_summary`: the
+    seeds' prices, where the chains started, what won, and how many of the
+    graph's producer-consumer edges the result reshards (an edge counts
+    when its reshard is priced above zero)."""
+    edges = resharded = 0
+    maps = {n: (pc.axis_map or {}) for n, pc in out.items()}
+    for op in ops:
+        for idx, t in enumerate(op.inputs):
+            src = t.owner_op
+            if src is None or isinstance(src, InputOp):
+                continue
+            edges += 1
+            resharded += cost.edge_time(
+                src.output_axis_map(maps.get(src.name, {})),
+                op.input_axis_map(maps.get(op.name, {}), idx), t) > 0.0
+    return {**report, "edges": edges, "resharded_edges": resharded,
+            "tied_groups": len(groups)}
 
 
 def optimize_strategies_multi(model, budget: int = 1000, alpha: float = 0.05,
@@ -435,5 +689,6 @@ def optimize_strategies_multi(model, budget: int = 1000, alpha: float = 0.05,
         "hbm_cap_bytes": cap,
         "mem_modes": {n: m for n, m in modes.items() if m != "none"},
         "over_cap": peak > cap,
+        **getattr(model, "_search_report", {}),
     }
     return out

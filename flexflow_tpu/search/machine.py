@@ -28,14 +28,28 @@ class MachineModel:
     hbm_bw: float = 819e9  # bytes/s
     hbm_bytes: float = 16e9  # capacity per chip
     # interconnect
-    ici_bw: float = 4.5e10  # bytes/s per link per direction (v5e ~45 GB/s)
+    # bytes/s per link per direction. Fitted on the four-chip v5e host
+    # (PERF.md, PR 36): a 134 MB bf16 all-reduce over one axis of the 2 x 2
+    # takes 3.25 ms in the training step's trace, a 268 MB all-gather 6.5 ms
+    # alone: 41 GB/s a direction either way (45 is the published rate)
+    ici_bw: float = 4.1e10
     dcn_bw: float = 6.25e9  # bytes/s per host
     ici_latency: float = 1e-6  # seconds per hop
     dcn_latency: float = 1e-5  # seconds per hop (host NIC + switch)
     # host<->device (PCIe-class) bandwidth: prices the search's per-op
     # host-offload memory mode (cost_model.mem_mode_time, ISSUE 19)
     host_bw: float = 1.6e10  # bytes/s
-    mxu_efficiency: float = 0.5  # achievable fraction of peak on real shapes
+    # achievable fraction of peak on real shapes. Fitted: the four-chip
+    # step's bf16 matmuls, each with what XLA fused around it, run at 130
+    # (ffn_down forward) to 180 TFLOP/s (the head's weight gradient) of 197
+    # (PERF.md, PR 36)
+    mxu_efficiency: float = 0.7
+    # share of a gradient all-reduce's time that nothing hides: XLA:TPU runs
+    # an all-reduce synchronously (no -start/-done pair), and in the
+    # four-chip step's trace each gradient all-reduce's own time is its
+    # whole duration (PERF.md, PR 36); all-gathers run beside the compute,
+    # and so is an all-reduce over a DCN axis priced (not measured)
+    all_reduce_exposed: float = 1.0
     # mesh axis name -> number of hosts the axis spans (1 = pure ICI)
     dcn_axes: Dict[str, int] = dataclasses.field(default_factory=dict)
 
@@ -57,12 +71,20 @@ class MachineModel:
         return axis_size // hosts, hosts
 
     @staticmethod
-    def _ring(bytes_per_chip: float, size: int, bw: float, lat: float) -> float:
+    def _lanes(size: int) -> int:
+        """Directions a ring of `size` chips moves data in at once: two,
+        except that a ring of two is ONE link between two neighbours (both
+        "directions" of it are the same wire: measured, PERF.md PR 36)."""
+        return 2 if size > 2 else 1
+
+    @classmethod
+    def _ring(cls, bytes_per_chip: float, size: int, bw: float,
+              lat: float) -> float:
         """Bidirectional ring all-reduce over one tier."""
         if size <= 1:
             return 0.0
-        return (2.0 * (size - 1) / size * bytes_per_chip / (2 * bw)
-                + size * lat)
+        return (2.0 * (size - 1) / size * bytes_per_chip
+                / (cls._lanes(size) * bw) + size * lat)
 
     # ---- collectives --------------------------------------------------------
 
@@ -84,7 +106,8 @@ class MachineModel:
         t = 0.0
         if intra > 1:
             t += ((intra - 1) / intra * bytes_per_chip * intra
-                  / (2 * self.ici_bw) + intra * self.ici_latency)
+                  / (self._lanes(intra) * self.ici_bw)
+                  + intra * self.ici_latency)
         if hosts > 1:
             # each host gathers the other hosts' (already intra-gathered) parts
             t += ((hosts - 1) / hosts * bytes_per_chip * axis_size / hosts
@@ -103,10 +126,12 @@ class MachineModel:
         intra, hosts = self._tiers(axis_size, axis_name)
         t = 0.0
         if intra > 1:
-            t += ((intra - 1) / intra * bytes_per_chip / (2 * self.ici_bw)
+            t += ((intra - 1) / intra * bytes_per_chip
+                  / (self._lanes(intra) * self.ici_bw)
                   + intra * self.ici_latency)
         if hosts > 1:
-            t += ((hosts - 1) / hosts * bytes_per_chip / (2 * self.dcn_bw)
+            t += ((hosts - 1) / hosts * bytes_per_chip
+                  / (self._lanes(hosts) * self.dcn_bw)
                   + hosts * self.dcn_latency)
         return t
 
@@ -118,7 +143,8 @@ class MachineModel:
         t = 0.0
         if intra > 1:
             # each chip sends (size-1)/size of its shard, both ring dirs
-            t += (bytes_per_chip * (intra - 1) / intra / (2 * self.ici_bw)
+            t += (bytes_per_chip * (intra - 1) / intra
+                  / (self._lanes(intra) * self.ici_bw)
                   + intra * self.ici_latency)
         if hosts > 1:
             t += (bytes_per_chip * (hosts - 1) / hosts / self.dcn_bw

@@ -69,8 +69,9 @@ def test_fsdp_numerics_match_unsharded():
 def test_cost_model_prices_fsdp():
     """Search-side FSDP awareness (time model): grad sync over the fsdp
     axis becomes a reduce-scatter (~half an all-reduce) plus 2 per-step
-    weight all-gathers; memory is already per-shard-credited (see
-    op_mem_bytes approximation note), so it is unchanged."""
+    weight all-gathers; memory counts a weight whole on a batch axis and
+    1/axis-size of it (kernel and bias both divide here) once the axis is
+    the fsdp axis, the activations unchanged."""
     from flexflow_tpu.search.cost_model import CostModel
 
     cfg = FFConfig(batch_size=16, mesh_shape=dict(MESH))
@@ -83,7 +84,10 @@ def test_cost_model_prices_fsdp():
     fsdp = CostModel(ff, MESH, fsdp_axis="data")
     op = ff.get_op_by_name("big")
 
-    assert fsdp.op_mem_bytes(op, dp) == plain.op_mem_bytes(op, dp)
+    acts = 16 * 1024 * 4 / MESH["data"]
+    state = (256 * 1024 + 1024) * 4 * 3     # weight, gradient, one moment
+    assert plain.op_mem_bytes(op, dp) == state + acts
+    assert fsdp.op_mem_bytes(op, dp) == state / MESH["data"] + acts
 
     s_plain, s_fsdp = (c.op_grad_sync_time(op, dp) for c in (plain, fsdp))
     assert s_fsdp != s_plain

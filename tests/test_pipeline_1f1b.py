@@ -137,7 +137,10 @@ def test_simulator_prices_pp_above_dp_for_deep_thin_model():
                                             optimize_strategies)
 
     mesh_shape = {"data": 8}
-    ff, _ = _deep_stack_model(mesh_shape, L=8, B=8, S=16, D=128)
+    # wide enough that the layers' compute counts: at S=16, D=128 a step is
+    # latency alone and the all-replicated seed (no collective at all)
+    # beats both, which the search rightly returns
+    ff, _ = _deep_stack_model(mesh_shape, L=8, B=8, S=64, D=512)
     cost = CostModel(ff, mesh_shape)
     dp = data_parallel_strategy(ff, mesh_shape)
     pp = dict(dp)
@@ -145,6 +148,7 @@ def test_simulator_prices_pp_above_dp_for_deep_thin_model():
     t_dp = cost.iteration_time(dp)
     t_pp = cost.iteration_time(pp)
     assert t_pp < t_dp, f"PP {t_pp} not faster than DP {t_dp}"
+    assert t_pp < cost.iteration_time({name: {} for name in dp})
 
     best = optimize_strategies(ff, budget=3000, mesh_shape=mesh_shape,
                                seed=0)
