@@ -360,7 +360,7 @@ def kernel_cases(z: Sizes):
     # hidden / 2 (OLMoE's ratio), top-2, experts 6 and 7 hit by nobody; at
     # the real size also a hidden size between the multiples of 1024 whose
     # experts stream in two chunks of their width
-    def stream_args(n, d, f, experts=8, k=2):
+    def stream_args(n, d, f, matrices=3, experts=8, k=2):
         def make(rs):
             gates = np.full((n, experts), pk.MOE_NOT_CHOSEN, np.float32)
             for r in range(n):
@@ -368,36 +368,65 @@ def kernel_cases(z: Sizes):
                     rs.rand(k)
             sizes = (gates != pk.MOE_NOT_CHOSEN).sum(0).astype(np.int32)
             w = [jnp.asarray(rs.randn(experts, a, b) / math.sqrt(a), bf16)
-                 for a, b in ((d, f), (d, f), (f, d))]
+                 for a, b in ((d, f),) * (matrices - 1) + ((f, d),)]
             return (jnp.asarray(rs.randn(n, d), bf16),
                     jnp.asarray(gates), jnp.asarray(sizes), *w)
         return make
 
-    def stream_oracle(x, gates, sizes, wg, wu, wd):
+    def stream_oracle(x, gates, sizes, *w):
         xf = x.astype(jnp.float32)
         y = jnp.zeros(xf.shape, jnp.float32)
+        wd = w[-1]
         with jax.default_matmul_precision("highest"):
-            for e in range(wg.shape[0]):
-                hid = jax.nn.silu(xf @ wg[e].astype(jnp.float32)) \
-                    * (xf @ wu[e].astype(jnp.float32))
+            for e in range(wd.shape[0]):
+                up = xf @ w[-2][e].astype(jnp.float32)
+                hid = (jax.nn.silu(xf @ w[0][e].astype(jnp.float32)) * up
+                       if len(w) == 3 else jnp.square(jax.nn.relu(up)))
                 col = gates[:, e:e + 1]
                 y += jnp.where(col != pk.MOE_NOT_CHOSEN,
                                col * (hid @ wd[e].astype(jnp.float32)), 0.0)
         return y
 
-    stream_shapes = [(rows, z.hidden, max(128, z.hidden // 2))
+    stream_shapes = [(rows, z.hidden, max(128, z.hidden // 2), 3)
                      for rows in (32, 128)]
+    # two-matrix relu^2 experts (a latent narrower than their width)
+    stream_shapes.append((32, max(128, z.hidden // 2), z.hidden, 2))
     if z is FULL:
-        stream_shapes.append((32, 2560, 1024))
-    for rows, dim, width in stream_shapes:
+        stream_shapes += [(32, 2560, 1024, 3), (32, 1024, 2688, 2)]
+    for rows, dim, width, matrices in stream_shapes:
         cases.append(KernelCase(
-            f"moe_expert_stream n{rows} e8 k2 d{dim} f{width} bf16 chunk"
-            f"{pk.moe_stream_chunk(dim, width, bf16)}",
-            stream_args(rows, dim, width),
+            f"moe_expert_stream n{rows} e8 k2 d{dim} f{width} x{matrices} "
+            f"bf16 chunk{pk.moe_stream_chunk(dim, width, bf16, matrices)}",
+            stream_args(rows, dim, width, matrices),
             pk.moe_expert_stream_pallas, stream_oracle,
             # h rounds to bf16 before the down projection (2^-8 of
             # values of order 1), outputs of order 1
             BF16_TOL))
+
+    # the Mamba-2 decode update over a pool of float32 states, live slots
+    # only (dead ones scattered, leading and trailing), against XLA's loop
+    from flexflow_tpu.ops.mamba import mamba_state_update
+
+    def update_args(s, nh, p, n, g):
+        def make(rs):
+            live = np.ones((s,), bool)
+            live[[0, s // 2, s - 1]] = False
+            return (jnp.asarray(rs.randn(s, nh, p, n) * 0.1, jnp.float32),
+                    jnp.asarray(rs.rand(s, nh), jnp.float32),
+                    jnp.asarray(rs.randn(s, nh, p), jnp.float32),
+                    jnp.asarray(rs.randn(s, g, n), jnp.float32),
+                    jnp.asarray(rs.randn(s, g, n), jnp.float32),
+                    jnp.asarray(live))
+        return make
+
+    for shape in [(8, 16, 64, 128, 2)] + ([(32, 128, 64, 128, 8)]
+                                          if z is FULL else []):
+        cases.append(KernelCase(
+            "mamba_state_update slots{} h{} p{} n{} g{} f32".format(*shape),
+            update_args(*shape), pk.mamba_state_update_pallas,
+            mamba_state_update,
+            # float32 throughout; y sums n products of order 1
+            1e-4))
 
     # fused add+layernorm at the model's hidden, forward (inference) and
     # with the backward's saved statistics; plus the 4096 x 4096 width the

@@ -130,6 +130,7 @@ class OperatorType(enum.Enum):
     OP_MINIMUM = enum.auto()
     OP_SIGMOID_SILU_MULTI = enum.auto()
     OP_ROTARY_EMBEDDING = enum.auto()
+    OP_MAMBA2 = enum.auto()  # selective state-space mixer (ops/mamba.py)
 
 
 # --- dtype lowering ---------------------------------------------------------

@@ -229,7 +229,7 @@ class FFModel:
             score_bias: Optional[float] = None, n_group: int = 1,
             topk_group: int = 1, routed_scaling: float = 1.0,
             shared_hidden_dim: int = 0, experts_held=None,
-            aux_weight: float = 1e-2,
+            aux_weight: float = 1e-2, latent_dim: int = 0,
             name: Optional[str] = None) -> Tensor:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
@@ -239,9 +239,11 @@ class FFModel:
         by the call's static shape, ops/moe.py; no `dispatch`). Otherwise
         dispatch: "auto" (dense einsums when experts are mesh-sharded, else
         sort-based) | "dense" | "sort". expert: "gelu" (w_in, w_out) |
-        "swiglu" (w_gate, w_up, w_down); renormalize: kept gates rescaled
-        to sum to 1 per token. The dropless SwiGLU op also takes the
-        router's form (scoring, score_bias, n_group / topk_group,
+        "swiglu" (w_gate, w_up, w_down) | "relu2" (w_up, w_down; dropless
+        only); renormalize: kept gates rescaled to sum to 1 per token.
+        latent_dim: the routed experts work in a space that narrow, between
+        w_latent_in and w_latent_out (dropless only). The dropless op also
+        takes the router's form (scoring, score_bias, n_group / topk_group,
         routed_scaling), a shared expert (shared_hidden_dim) and
         experts_held=(first, count), one chip's share of an
         expert-parallel layer: ops/moe.py."""
@@ -254,7 +256,7 @@ class FFModel:
                  score_bias=score_bias, n_group=n_group,
                  topk_group=topk_group, routed_scaling=routed_scaling,
                  shared_hidden_dim=shared_hidden_dim,
-                 experts_held=experts_held)
+                 experts_held=experts_held, latent_dim=latent_dim)
         outs = self._add(op)
         self._aux_tensors.append(outs[1])
         return outs[0]
@@ -314,6 +316,21 @@ class FFModel:
             qk_rope_head_dim, v_head_dim, index_n_heads, index_head_dim,
             index_topk, rope_theta=rope_theta, rope_scaling=rope_scaling,
             eps=eps, uq_init_gain=uq_init_gain))
+
+    def mamba2(self, input: Tensor, num_heads: int, head_dim: int,
+               n_groups: int, state_size: int, conv_kernel: int = 4,
+               chunk_size: int = 128, eps: float = 1e-5,
+               name: Optional[str] = None) -> Tensor:
+        """Mamba-2 mixer (ops/mamba.py): a selective state-space layer of
+        `num_heads` heads of `head_dim`, B and C shared by `n_groups` groups
+        of heads, `state_size` state columns a head; its cache is one
+        fixed-size recurrent state a sequence."""
+        from flexflow_tpu.ops.mamba import Mamba2Mixer
+
+        return self._add(Mamba2Mixer(
+            self, self._name("mamba2", name), [input], num_heads, head_dim,
+            n_groups, state_size, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=eps))
 
     def transformer_pipeline_stack(self, input: Tensor, num_layers: int,
                                    num_heads: int, ffn_mult: int = 4,

@@ -56,6 +56,15 @@ chip's share of an expert-parallel layer (its output is the shared expert
 plus ITS experts' part of the routed sum; the exchange that adds the other
 chips' parts is not in this op).
 
+`expert="relu2"` is the two-matrix expert `relu(x w_up)^2 w_down` (Nemotron:
+no gate matrix), on the same dropless op and both of its lowerings; the
+shared expert then has that form too. `latent_dim` (LatentMoE) puts the
+routed experts into a narrower space: `l = x w_latent_in` (D, L), the experts
+are (L, F) / (F, L), and the gate-weighted sum goes back through
+`w_latent_out` (L, D). The router and the shared expert read the D-wide
+input. A held share projects ITS experts' sum, so the shares of a layer add
+up to the layer.
+
 A held share under a gradient (`training=True`, fewer experts held than
 routed over): of the N*k assignments only about held / num_experts land
 here, and they sort to the front. The grouped lowering then gathers and
@@ -104,11 +113,20 @@ def dropless_lowering(backend: str, expert: str, training: bool,
     from flexflow_tpu.ops.pallas_kernels import (MOE_STREAM_MAX_ROWS,
                                                  moe_stream_chunk)
 
-    if (backend == "tpu" and expert == "swiglu" and not training
+    if (backend == "tpu" and expert in _DROPLESS_EXPERTS and not training
             and devices == 1 and n_tokens <= MOE_STREAM_MAX_ROWS
-            and moe_stream_chunk(dim, hidden_dim, dtype) is not None):
+            and moe_stream_chunk(dim, hidden_dim, dtype,
+                                 len(_DROPLESS_EXPERTS[expert])) is not None):
         return "streamed"
     return "grouped"
+
+
+# the dropless expert forms (both of which the expert-stream kernel
+# computes): their (E, in, F) matrices, then the (E, F, in) one
+_DROPLESS_EXPERTS = {
+    "swiglu": ("w_gate", "w_up", "w_down"),
+    "relu2": ("w_up", "w_down"),
+}
 
 
 def _backend() -> str:
@@ -141,7 +159,8 @@ class MoE(Op):
                  scoring: str = "softmax",
                  score_bias: Optional[float] = None, n_group: int = 1,
                  topk_group: int = 1, routed_scaling: float = 1.0,
-                 shared_hidden_dim: int = 0, experts_held=None):
+                 shared_hidden_dim: int = 0, experts_held=None,
+                 latent_dim: int = 0):
         super().__init__(model, name, inputs)
         self.num_experts = num_experts
         self.hidden_dim = hidden_dim
@@ -150,8 +169,9 @@ class MoE(Op):
         self.aux_weight = aux_weight
         if dispatch not in ("auto", "dense", "sort"):
             raise ValueError(f"dispatch must be auto|dense|sort, got {dispatch!r}")
-        if expert not in ("gelu", "swiglu"):
-            raise ValueError(f"expert must be gelu|swiglu, got {expert!r}")
+        if expert not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(
+                f"expert must be gelu|swiglu|relu2, got {expert!r}")
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"scoring must be softmax|sigmoid, got {scoring!r}")
@@ -164,17 +184,21 @@ class MoE(Op):
         self.n_group, self.topk_group = int(n_group), int(topk_group)
         self.routed_scaling = float(routed_scaling)
         self.shared_hidden_dim = int(shared_hidden_dim)
+        self.latent_dim = int(latent_dim)
         self.held_first, self.held_count = (
             (0, num_experts) if experts_held is None
             else (int(experts_held[0]), int(experts_held[1])))
         plain = (scoring == "softmax" and score_bias is None
                  and self.n_group == 1 and self.routed_scaling == 1.0
-                 and not self.shared_hidden_dim and experts_held is None)
-        if not plain and (capacity_factor is not None or expert != "swiglu"):
+                 and not self.shared_hidden_dim and experts_held is None
+                 and not self.latent_dim and expert != "relu2")
+        if not plain and (capacity_factor is not None
+                          or expert not in _DROPLESS_EXPERTS):
             raise ValueError(
                 "scoring, score_bias, n_group, routed_scaling, "
-                "shared_hidden_dim and experts_held belong to the dropless "
-                "SwiGLU op (capacity_factor=None, expert='swiglu')")
+                "shared_hidden_dim, experts_held, latent_dim and "
+                "expert='relu2' belong to the dropless op "
+                "(capacity_factor=None, expert='swiglu' or 'relu2')")
         if num_experts % self.n_group or not (
                 1 <= self.topk_group <= self.n_group):
             raise ValueError(
@@ -186,6 +210,8 @@ class MoE(Op):
                 f"experts_held {experts_held}: (first, count) inside "
                 f"0..{num_experts}")
         self.dim = inputs[0].dims[-1]
+        # the width the routed experts read and write
+        self.expert_dim = self.latent_dim or self.dim
         self.capacity = None            # dropless: no buffer to size
         if capacity_factor is not None:
             ntokens = 1
@@ -205,20 +231,23 @@ class MoE(Op):
 
     def weights(self) -> List[WeightSpec]:
         E, D, F = self.num_experts, self.dim, self.hidden_dim
+        L = self.expert_dim
         H = self.held_count         # the expert matrices this layer holds
-        up = ("w_gate", "w_up") if self.expert == "swiglu" else ("w_in",)
-        down = "w_down" if self.expert == "swiglu" else "w_out"
+        *up, down = _DROPLESS_EXPERTS.get(self.expert, ("w_in", "w_out"))
         ws = [WeightSpec("router", (D, E), init="glorot", fan=(D, E))] + [
-            WeightSpec(w, (H, D, F), init="glorot", fan=(D, F)) for w in up
-        ] + [WeightSpec(down, (H, F, D), init="glorot", fan=(F, D))]
+            WeightSpec(w, (H, L, F), init="glorot", fan=(L, F)) for w in up
+        ] + [WeightSpec(down, (H, F, L), init="glorot", fan=(F, L))]
+        if self.latent_dim:
+            ws += [WeightSpec("w_latent_in", (D, L), init="glorot"),
+                   WeightSpec("w_latent_out", (L, D), init="glorot")]
         if self.score_bias is not None:
             ws.append(WeightSpec("score_bias", (E,), init="normal",
                                  init_args=(0.0, float(self.score_bias))))
         if self.shared_hidden_dim:
             Fs = self.shared_hidden_dim
-            ws += [WeightSpec("shared_gate", (D, Fs), init="glorot"),
-                   WeightSpec("shared_up", (D, Fs), init="glorot"),
-                   WeightSpec("shared_down", (Fs, D), init="glorot")]
+            ws += [WeightSpec(f"shared_{w[2:]}", (D, Fs), init="glorot")
+                   for w in up]
+            ws += [WeightSpec("shared_down", (Fs, D), init="glorot")]
         return ws
 
     _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down", "w_in", "w_out")
@@ -232,6 +261,8 @@ class MoE(Op):
         if self.expert == "swiglu":
             return mm(jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"]),
                       w["w_down"])
+        if self.expert == "relu2":
+            return mm(jnp.square(jax.nn.relu(mm(x, w["w_up"]))), w["w_down"])
         return mm(jax.nn.gelu(mm(x, w["w_in"])), w["w_out"])
 
     def _use_sort_dispatch(self) -> bool:
@@ -374,7 +405,7 @@ class MoE(Op):
         mesh = getattr(self.model, "mesh", None)
         return dropless_lowering(
             _backend(), self.expert, training,
-            1 if mesh is None else mesh.size, n_tokens, self.dim,
+            1 if mesh is None else mesh.size, n_tokens, self.expert_dim,
             self.hidden_dim, dtype)
 
     def _route(self, params, t):
@@ -423,7 +454,11 @@ class MoE(Op):
         return scores, top_g, top_e
 
     def _shared_expert(self, params, t):
-        """The SwiGLU expert every row passes through."""
+        """The expert every row passes through, of the op's own form."""
+        if self.expert == "relu2":
+            u, d = (params[n].astype(t.dtype)
+                    for n in ("shared_up", "shared_down"))
+            return jnp.square(jax.nn.relu(t @ u)) @ d
         g, u, d = (params[n].astype(t.dtype)
                    for n in ("shared_gate", "shared_up", "shared_down"))
         return (jax.nn.silu(t @ g) * (t @ u)) @ d
@@ -445,11 +480,18 @@ class MoE(Op):
         took = self.lowering(N, training, t.dtype)
         if lowerings is not None:
             lowerings.append(took)
+        x = t
+        if self.latent_dim:
+            with jax.named_scope("latent"):
+                x = t @ params["w_latent_in"].astype(t.dtype)
         if took == "streamed":
-            y, sizes = self._experts_streamed(params, t, top_g, top_e, live)
+            y, sizes = self._experts_streamed(params, x, top_g, top_e, live)
         else:
-            y, sizes = self._experts_grouped(params, t, top_g, top_e, live,
+            y, sizes = self._experts_grouped(params, x, top_g, top_e, live,
                                              training)
+        if self.latent_dim:
+            with jax.named_scope("latent"):
+                y = y.astype(t.dtype) @ params["w_latent_out"].astype(t.dtype)
         if group_sizes is not None:
             group_sizes.append(sizes)
         if routing is not None:
@@ -539,8 +581,8 @@ class MoE(Op):
         # group, like a row held elsewhere
         pad = -(N * k) % cap
         order = jnp.concatenate([order, jnp.zeros((pad,), order.dtype)])
-        weights = tuple(params[n].astype(t.dtype)
-                        for n in ("w_gate", "w_up", "w_down"))
+        names = _DROPLESS_EXPERTS[self.expert]
+        weights = tuple(params[n].astype(t.dtype) for n in names)
         # `top_g` is already 0 where the assignment is not held here
         return self._held_passes(cap)(weights, t, top_g.reshape(-1), order,
                                       sizes)
@@ -563,7 +605,6 @@ class MoE(Op):
             # the rows of each group that lie inside this pass's window
             mine = jnp.clip(jnp.minimum(ends, start + cap)
                             - jnp.maximum(ends - sizes, start), 0, None)
-            w_gate, w_up, w_down = weights
             # rows past the pass's last group belong to no expert, and what
             # a grouped matmul (or its transpose) leaves there is
             # unspecified: on the chip, whatever the buffer held, NaN
@@ -572,7 +613,7 @@ class MoE(Op):
             # direction carries such a value on.
             valid = (jnp.arange(cap) < jnp.sum(mine))[:, None]
             out = self._expert_ffn(
-                {"w_gate": w_gate, "w_up": w_up, "w_down": w_down},
+                dict(zip(_DROPLESS_EXPERTS[self.expert], weights)),
                 jnp.where(valid, t[tok], 0),
                 lambda x, w: jnp.where(
                     valid, jax.lax.ragged_dot(x, w, mine), 0))
@@ -646,7 +687,7 @@ class MoE(Op):
         # (scope_table books it to `kernel_phase`)
         y = moe_expert_stream_pallas(
             t, gmat, sizes, *(params[n].astype(t.dtype)
-                              for n in ("w_gate", "w_up", "w_down")))
+                              for n in _DROPLESS_EXPERTS[self.expert]))
         return y, sizes
 
     def partitionable_output_dims(self):
@@ -682,6 +723,11 @@ class MoE(Op):
         matmuls = 3 if self.expert == "swiglu" else 2
         # the share of a token's k picks that lands on the experts held here
         routed = ntokens * self.k * self.held_count / self.num_experts
+        if self.latent_dim:
+            return int(2 * matmuls * (routed * self.latent_dim
+                                      * self.hidden_dim + ntokens * self.dim
+                                      * self.shared_hidden_dim)
+                       + 4 * ntokens * self.dim * self.latent_dim)
         return int(2 * matmuls * self.dim
                    * (routed * self.hidden_dim
                       + ntokens * self.shared_hidden_dim))

@@ -1228,30 +1228,37 @@ MOE_STREAM_MAX_ROWS = 128
 _MOE_BUFFER_BUDGET = 24 << 20
 
 
-def moe_stream_chunk(d: int, f: int, dtype):
+def moe_stream_chunk(d: int, f: int, dtype, matrices: int = 3):
     """Columns of the experts' width `f` that one step of the stream takes:
     the widest chunk (F itself, or a multiple of the 128 lanes that divides
-    it) whose three matrices over hidden size `d` fit _MOE_BUFFER_BUDGET
-    twice. None where Mosaic could not tile the copies (D or F off the
-    lanes)."""
+    it) whose `matrices` matrices (three of a SwiGLU expert, two of a relu^2
+    one) over hidden size `d` fit _MOE_BUFFER_BUDGET twice. None where Mosaic
+    could not tile the copies (D or F off the lanes)."""
     if d % LANES or f % LANES:
         return None
-    one = 3 * d * jnp.dtype(dtype).itemsize     # bytes a column of F
+    one = matrices * d * jnp.dtype(dtype).itemsize  # bytes a column of F
     for chunk in range(f, 0, -LANES):
         if f % chunk == 0 and 2 * one * chunk <= _MOE_BUFFER_BUDGET:
             return chunk
     return None
 
 
-def _moe_stream_kernel(sizes_ref, x_ref, g_ref, wg_hbm, wu_hbm, wd_hbm,
-                       o_ref, gbuf, ubuf, dbuf, sem, acc_ref, ids_ref,
-                       *, nf: int, chunk: int):
-    """The whole call in one invocation. Scalar prefetch: sizes (E,), the
+def _moe_stream_kernel(sizes_ref, x_ref, g_ref, *refs, nf: int, chunk: int,
+                       gated: bool):
+    """The whole call in one invocation. `gated`: a SwiGLU expert's three
+    matrices (gate, up, down) and buffers; otherwise a relu^2 expert's two
+    (up, down): one ring, one loop, the matrix count and the activation the
+    only static differences. Scalar prefetch: sizes (E,), the
     rows that chose each expert; the experts with any are listed in
     ids_ref (SMEM) first. x (N, D) and the gate matrix g (N, E) f32 sit in
     VMEM; the expert matrices stay in HBM. Chunk c of the stream is columns
     [f0, f0 + chunk) of expert ids[c // nf] with f0 = (c % nf) * chunk, and
     lives in buffer c % 2."""
+    if gated:
+        (wg_hbm, wu_hbm, wd_hbm, o_ref, gbuf, ubuf, dbuf, sem, acc_ref,
+         ids_ref) = refs
+    else:
+        wu_hbm, wd_hbm, o_ref, ubuf, dbuf, sem, acc_ref, ids_ref = refs
 
     def list_hit(e, n_hit):
         @pl.when(sizes_ref[e] > 0)
@@ -1269,15 +1276,15 @@ def _moe_stream_kernel(sizes_ref, x_ref, g_ref, wg_hbm, wu_hbm, wd_hbm,
         return ids_ref[c] if nf == 1 else ids_ref[jax.lax.div(c, nf_)]
 
     def copies(e, f0, slot):
+        ups = (wg_hbm, wu_hbm) if gated else (wu_hbm,)
         if nf == 1:
-            srcs = (wg_hbm.at[e], wu_hbm.at[e], wd_hbm.at[e])
+            srcs = (*(w.at[e] for w in ups), wd_hbm.at[e])
         else:
-            srcs = (wg_hbm.at[e, :, pl.ds(f0, chunk)],
-                    wu_hbm.at[e, :, pl.ds(f0, chunk)],
+            srcs = (*(w.at[e, :, pl.ds(f0, chunk)] for w in ups),
                     wd_hbm.at[e, pl.ds(f0, chunk), :])
+        bufs = (gbuf, ubuf, dbuf) if gated else (ubuf, dbuf)
         return [pltpu.make_async_copy(src, buf.at[slot], sem.at[i, slot])
-                for i, (src, buf) in enumerate(zip(srcs,
-                                                   (gbuf, ubuf, dbuf)))]
+                for i, (src, buf) in enumerate(zip(srcs, bufs))]
 
     def fetch(c):
         @pl.when(c < n_chunks)
@@ -1302,9 +1309,13 @@ def _moe_stream_kernel(sizes_ref, x_ref, g_ref, wg_hbm, wu_hbm, wd_hbm,
         for cp in copies(0, 0, slot):     # a wait reads the size only
             cp.wait()
         x = x_ref[...]
-        gate = mm(x, gbuf[slot])
-        h = (gate * jax.lax.logistic(gate) * mm(x, ubuf[slot])
-             ).astype(x.dtype)
+        if gated:
+            gate = mm(x, gbuf[slot])
+            h = (gate * jax.lax.logistic(gate) * mm(x, ubuf[slot])
+                 ).astype(x.dtype)
+        else:
+            up = jax.lax.max(mm(x, ubuf[slot]), jnp.float32(0))
+            h = (up * up).astype(x.dtype)
         # this expert's column of the gate matrix, by select
         ge = jnp.sum(jax.lax.select(lane == expert(c), g_ref[...], no_gate),
                      axis=1, keepdims=True)                 # (N, 1)
@@ -1324,19 +1335,21 @@ def _moe_stream_kernel(sizes_ref, x_ref, g_ref, wg_hbm, wu_hbm, wd_hbm,
 # 10-layer program traced and lowered the kernel ten times: +5 s of warm
 # set-up in moe-chat-steady (PERF.md section 6, PR 28)
 @functools.partial(jax.jit, inline=True)
-def moe_expert_stream_pallas(x, gates, sizes, w_gate, w_up, w_down):
-    """Dropless SwiGLU experts over a few token rows, each hit expert's
-    matrices streamed once: x (N, D), N <= MOE_STREAM_MAX_ROWS; gates
-    (N, E) f32, a row's gate for each expert it chose and MOE_NOT_CHOSEN
-    elsewhere (every entry of a dead row); sizes (E,) int32, the rows
-    that chose each expert (an expert with none is not read); w_gate,
-    w_up (E, D, F), w_down (E, F, D) in x's dtype -> (N, D) in x's dtype:
-    sum over a row's chosen experts of gate * (silu(x w_gate) * (x w_up))
-    w_down, the three products and the sum in f32. A row that chose no
-    expert comes out zero. Inference-only: no VJP."""
+def moe_expert_stream_pallas(x, gates, sizes, *weights):
+    """Dropless experts over a few token rows, each hit expert's matrices
+    streamed once: x (N, D), N <= MOE_STREAM_MAX_ROWS; gates (N, E) f32, a
+    row's gate for each expert it chose and MOE_NOT_CHOSEN elsewhere (every
+    entry of a dead row); sizes (E,) int32, the rows that chose each expert
+    (an expert with none is not read); `weights` in x's dtype: w_gate, w_up
+    (E, D, F), w_down (E, F, D) of SwiGLU experts, or w_up, w_down of
+    relu^2 ones -> (N, D) in x's dtype: sum over a row's chosen experts of
+    gate * (silu(x w_gate) * (x w_up)) w_down, or of gate * relu(x w_up)^2
+    w_down, the products and the sum in f32. A row that chose no expert
+    comes out zero. Inference-only: no VJP."""
     n, d = x.shape
-    e, _, f = w_gate.shape
-    chunk = moe_stream_chunk(d, f, x.dtype)
+    gated = len(weights) == 3
+    e, _, f = weights[0].shape
+    chunk = moe_stream_chunk(d, f, x.dtype, len(weights))
     if n > MOE_STREAM_MAX_ROWS or chunk is None:
         raise ValueError(
             f"{n} rows of experts ({d}, {f}) {x.dtype}: at most "
@@ -1355,39 +1368,36 @@ def moe_expert_stream_pallas(x, gates, sizes, w_gate, w_up, w_down):
     # products and h over the chunk, the down product and its select over
     # D, in f32)
     resident = rows * (2 * (2 * d * itemsize + 4 * -(-e // LANES) * LANES)
-                       + 4 * d + 4 * (3 * chunk + 2 * d))
+                       + 4 * d + 4 * (len(weights) * chunk + 2 * d))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
             pl.BlockSpec((rows, e), lambda i, *_: (0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *(pl.BlockSpec(memory_space=pl.ANY) for _ in weights),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, d, chunk), w_gate.dtype),
-            pltpu.VMEM((2, d, chunk), w_up.dtype),
-            pltpu.VMEM((2, chunk, d), w_down.dtype),
-            pltpu.SemaphoreType.DMA((3, 2)),
+            *(pltpu.VMEM((2, d, chunk), w.dtype) for w in weights[:-1]),
+            pltpu.VMEM((2, chunk, d), weights[-1].dtype),
+            pltpu.SemaphoreType.DMA((len(weights), 2)),
             pltpu.VMEM((rows, d), jnp.float32),             # the sum
             pltpu.SMEM((e,), jnp.int32),                    # hit experts
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_moe_stream_kernel, nf=f // chunk, chunk=chunk),
+        functools.partial(_moe_stream_kernel, nf=f // chunk, chunk=chunk,
+                          gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # the buffers, the resident blocks, room for Mosaic's own
-            vmem_limit_bytes=int(2 * 3 * d * chunk * itemsize + resident
-                                 + (8 << 20))),
+            vmem_limit_bytes=int(2 * len(weights) * d * chunk * itemsize
+                                 + resident + (8 << 20))),
         interpret=_interpret(),
-    )(sizes.astype(jnp.int32), x, gates.astype(jnp.float32), w_gate, w_up,
-      w_down)
+    )(sizes.astype(jnp.int32), x, gates.astype(jnp.float32), *weights)
     return out[:n]
 
 
@@ -1615,3 +1625,107 @@ def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
             compiler_params=_compiler_params(("arbitrary",)),
             interpret=_interpret(), name="mla_paged_core_gathered",
         )(n_sel.astype(jnp.int32), q_lat, gathered)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 decode: one token of the recurrence over a pool of per-slot states
+# ---------------------------------------------------------------------------
+#
+# The pool's H (slots, heads, P, N) float32 stays in HBM and is the kernel's
+# output too (aliased): a grid step holds ONE slot's whole state in VMEM
+# (4.19 MB at 128 x 64 x 128, there and back through the pipeline's two
+# buffers each way), multiplies it by the step's decay, adds dt x (x) B and
+# reads y = H C out of the new state before it goes back. A DEAD slot is
+# neither read nor written: the scalar-prefetched `src` sends its grid step to
+# the block of the nearest live slot before it (the first live one after it
+# for the leading dead slots), and the pipeline neither fetches nor writes
+# back a block whose index did not change, while the body runs for live steps
+# only. Per head the work is elementwise over (P, N) with x along the
+# sublanes and B, C along the lanes, so x arrives transposed, (slots, groups,
+# P, heads a group): its column for a head is a static lane slice.
+
+
+def _mamba_update_kernel(src_ref, live_ref, h_ref, d_ref, x_ref, b_ref, c_ref,
+                         o_ref, y_ref, *, groups: int, per: int):
+    s = pl.program_id(0)
+
+    @pl.when(live_ref[s] > 0)
+    def _():
+        def group(g, carry):
+            d, x = d_ref[0, g], x_ref[0, g]         # (1, per), (P, per)
+            b, c = b_ref[0, g], c_ref[0, g]         # (1, N) each
+            cols = []
+            for i in range(per):
+                head = g * per + i
+                new = d[:, i:i + 1] * h_ref[0, head] + x[:, i:i + 1] * b
+                o_ref[0, head] = new
+                cols.append(jnp.sum(new * c, axis=1, keepdims=True))
+            y_ref[0, g] = jnp.concatenate(cols, axis=1)
+            return carry
+
+        jax.lax.fori_loop(0, groups, group, 0)
+
+    @pl.when(live_ref[s] == 0)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    # nothing live at all: every step sits on slot 0's block, which then
+    # goes back as it came
+    @pl.when(jnp.logical_and(s == 0, src_ref[0] < 0))
+    def _():
+        o_ref[...] = h_ref[...]
+
+
+@functools.partial(jax.jit, inline=True)
+def mamba_state_update_pallas(h, decay, dtx, bm, cm, live):
+    """One token of `H <- decay H + dtx B^T`, `y = H C` on a pool of states,
+    live slots only, in place: h (S, H, P, N) f32 (aliased to the output),
+    decay (S, H) f32, dtx (S, H, P) f32, bm, cm (S, G, N) f32, live (S,) bool
+    -> (y (S, H, P) f32, zero for a dead slot; h)."""
+    s, nh, p, n = h.shape
+    g = bm.shape[1]
+    per = nh // g
+    idx = jnp.arange(s, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, idx, -1))
+    first = jnp.min(jnp.where(live, idx, s))
+    any_live = first < s
+    src = jnp.where(before >= 0, before, first)
+    # `src[0] < 0` tells the kernel nothing is live; the index map clamps it
+    src = jnp.where(any_live, src, -1).astype(jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s,),
+        in_specs=[
+            pl.BlockSpec((1, nh, p, n),
+                         lambda i, src, live: (jnp.maximum(src[i], 0), 0, 0,
+                                               0)),
+            pl.BlockSpec((1, g, 1, per), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, g, p, per), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, g, 1, n), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, g, 1, n), lambda i, *_: (i, 0, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, nh, p, n),
+                         lambda i, src, live: (jnp.maximum(src[i], 0), 0, 0,
+                                               0)),
+            pl.BlockSpec((1, g, p, per), lambda i, *_: (i, 0, 0, 0)),
+        ],
+    )
+    block = nh * p * n * 4
+    new, yt = pl.pallas_call(
+        functools.partial(_mamba_update_kernel, groups=g, per=per),
+        name="mamba_state_update",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(h.shape, h.dtype),
+                   jax.ShapeDtypeStruct((s, g, p, per), jnp.float32)],
+        # operand 2 (after the two prefetched scalars) is the pool
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(4 * block + (16 << 20))),
+        interpret=_interpret(),
+    )(src, live.astype(jnp.int32), h,
+      decay.reshape(s, g, 1, per),
+      dtx.reshape(s, g, per, p).transpose(0, 1, 3, 2),
+      bm.reshape(s, g, 1, n), cm.reshape(s, g, 1, n))
+    return yt.transpose(0, 1, 3, 2).reshape(s, nh, p), new

@@ -132,10 +132,16 @@ class Generator:
                 f"integer token input; this graph has [{kinds}]")
         self.token_input = tok_inputs[0]
         self.attn_ops = []
+        # ops whose cache is ONE fixed-size recurrent state a sequence
+        # (`state_cache_protocol`, ops/mamba.py), beside the per-token rows
+        # of the attention ops
+        self.state_ops = []
         for op in model.ops:
             if isinstance(op, InputOp):
                 continue
-            if getattr(op, "kv_cache_protocol", False):
+            if getattr(op, "state_cache_protocol", False):
+                self.state_ops.append(op)
+            elif getattr(op, "kv_cache_protocol", False):
                 if not op.causal:
                     raise ValueError(
                         f"{op.name}: generate() requires causal attention")
@@ -155,17 +161,19 @@ class Generator:
                     f"{op.name} ({op.op_type.name}) mixes sequence "
                     "positions or is unsupported in the KV-cache decode "
                     "path; generate() supports transformer decoder graphs")
-        if not self.attn_ops:
+        cached = self.attn_ops + self.state_ops
+        if not cached:
             raise ValueError("graph has no attention ops; nothing to cache")
         # dropless MoE ops count their routing inside the serve programs
         self.dropless_moe_ops = [
             op for op in model.ops
             if op.op_type == OperatorType.OP_MOE and op.dropless]
-        # topo index of the last attention op: beyond it every op is
-        # per-position, so the prefill tail (lm_head included) can run on
-        # the final position only instead of the whole prompt
+        # topo index of the last attention (or recurrent-state) op: beyond
+        # it every op is per-position, so the prefill tail (lm_head
+        # included) can run on the final position only instead of the whole
+        # prompt
         self._last_attn_idx = max(i for i, op in enumerate(model.ops)
-                                  if op in self.attn_ops)
+                                  if op in cached)
 
     # ---- weight-only quantization (int8 / fp8) -----------------------------
 
@@ -318,7 +326,13 @@ class Generator:
                 if bf16:
                     p = {k: to_compute(v) for k, v in p.items()}
             with jax.named_scope(op.name):
-                if getattr(op, "kv_cache_protocol", False):
+                if getattr(op, "state_cache_protocol", False):
+                    out, nc = self._state_step(
+                        op, p, xs, caches[op.name], pos, paged, row_lengths,
+                        chunk_start, gather_last)
+                    new_caches[op.name] = nc
+                    outs = [out]
+                elif getattr(op, "kv_cache_protocol", False):
                     cache = caches[op.name]
                     if paged is not None:
                         # continuous-batching slot decode over the paged
@@ -400,6 +414,37 @@ class Generator:
             for i, t in enumerate(op.outputs):
                 vals[t] = outs[i]
         return vals[self.model._final_tensor], new_caches
+
+    @staticmethod
+    def _state_step(op, p, xs, state, pos, paged, row_lengths, chunk_start,
+                    gather_last):
+        """A recurrent-state op's part of a walk: the slab advances the
+        sequence's one state (a prefill slab from `chunk_start`, padding
+        rows past `row_lengths` leaving it alone; a decode token; the
+        engine's slots, live ones only), except in the ragged prefill's
+        gather pass, which re-reads the last live row's kept output."""
+        if paged is not None:
+            if xs[0].shape[1] > 1:
+                raise NotImplementedError(
+                    f"{op.name}: a recurrent state cannot be verified at "
+                    "several positions in one pass (speculative decoding)")
+            return op.paged_step_forward(p, xs, state, paged["row_len"] > 0,
+                                         impl=paged.get("impl"))
+        if pos is not None:
+            return op.step_forward(p, xs, state)
+        if gather_last:
+            return op.last_forward(p, xs, state)
+        return op.scan_forward(p, xs, state, chunk_start or 0, row_lengths)
+
+    def init_caches(self, batch: int, max_len: int, dtype):
+        """The contiguous per-request caches of one prefill (and of
+        generate()'s decode): per-token rows for the attention ops, one
+        state for the recurrent ones."""
+        caches = {op.name: op.init_cache(batch, max_len, dtype)
+                  for op in self.attn_ops}
+        caches.update({op.name: op.init_state(batch, dtype)
+                       for op in self.state_ops})
+        return caches
 
     @staticmethod
     def _live_rows(shape, paged, row_lengths, chunk_start, gather_last):
@@ -529,8 +574,7 @@ class Generator:
             b, s0 = tokens.shape
             max_len = s0 + max_new_tokens
             row_lengths = lengths if ragged else None
-            caches = {op.name: op.init_cache(b, max_len, cdtype)
-                      for op in self.attn_ops}
+            caches = self.init_caches(b, max_len, cdtype)
             logits, caches = self._prefill(params, state, tokens, caches,
                                            row_lengths, prefill_chunk)
             key, sub = jax.random.split(key)
@@ -635,8 +679,7 @@ class Generator:
             b, s0 = tokens.shape
             max_len = s0 + max_new_tokens
             row_lengths = lengths if ragged else None
-            caches = {op.name: op.init_cache(b, max_len, cdtype)
-                      for op in self.attn_ops}
+            caches = self.init_caches(b, max_len, cdtype)
             logits, caches = self._prefill(params, state, tokens, caches,
                                            row_lengths, prefill_chunk)
             logp = jax.nn.log_softmax(logits[:, -1].astype(jnp.float32),

@@ -701,8 +701,9 @@ class KVPagePool:
 
     def __init__(self, gen, draft_gen, num_pages: int, page_size: int,
                  pages_per_slot: int, kv_dtype, prefix_cache: bool,
-                 host_pages: int, page_import):
+                 host_pages: int, page_import, slots: int = 0):
         self.gen = gen
+        self.slots = int(slots)
         self.draft_gen = draft_gen
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
@@ -731,12 +732,20 @@ class KVPagePool:
         # that the recompile counter could not see
         repl = NamedSharding(gen.model.mesh, PartitionSpec())
         cdtype = gen._compute_dtype()
-        return {
+        pool = {
             op.name: jax.tree.map(
                 lambda a: jax.device_put(a, repl),
                 op.init_paged_cache(self.num_pages, self.page_size,
                                     cdtype, kv_dtype=kv_dtype))
             for op in gen.attn_ops}
+        # beside the pages: one recurrent state a slot for each op that
+        # keeps one (no page table, no per-token bytes; the page movers
+        # below walk the attention ops only)
+        pool.update({
+            op.name: jax.tree.map(lambda a: jax.device_put(a, repl),
+                                  op.init_state_pool(self.slots, cdtype))
+            for op in getattr(gen, "state_ops", ())})
+        return pool
 
     @property
     def free_pages(self) -> int:

@@ -42,7 +42,8 @@ from jax._src import profiler as _jax_profiler  # the session of start_trace
 # the graph (benchmark/scope_reduce.py).
 
 PHASES = ("project", "index", "select", "gather", "core", "out",
-          "route", "experts", "shared")
+          "route", "experts", "shared", "latent",
+          "conv", "scan", "update", "gate_norm", "seat")
 OUTSIDE_OPS = ("sampler", "loss", "optimizer", "grad_sync")
 
 _WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")

@@ -476,6 +476,27 @@ class ServingEngine:
         if self.speculate_k < 0:
             raise ValueError(
                 f"speculate_k={self.speculate_k}: must be >= 0")
+        if not self.gen.attn_ops:
+            raise NotImplementedError(
+                "the serving engine pages per-token K/V rows: a graph of "
+                "recurrent-state ops alone has none (generate() runs it)")
+        if self.gen.state_ops:
+            # a recurrent state is one array a slot, overwritten every
+            # step: a trie edge holds no snapshot of it to share, and one
+            # verify pass cannot score several positions of it
+            named = self.gen.state_ops[0].name
+            if enable_prefix:
+                raise ValueError(
+                    f"{named} keeps a recurrent state: the radix prefix "
+                    "cache shares pages of per-token rows and has no "
+                    "snapshot of a state to share; build the engine with "
+                    "prefix_cache=False")
+            if self.speculate_k > 0:
+                raise ValueError(
+                    f"{named} keeps a recurrent state: speculative "
+                    "verification scores K+1 positions in one pass, which "
+                    "a state advanced in place cannot undo; speculate_k "
+                    "must be 0")
         self.draft_gen = None
         if self.speculate_k > 0:
             if self.draft_model is None:
@@ -503,7 +524,7 @@ class ServingEngine:
             self.gen, self.draft_gen, self.num_pages, self.page_size,
             self.pages_per_slot, self._kv_dtype_arg, enable_prefix, hp,
             lambda build, *args: self._compiled_call(
-                ("page_import",), build, *args))
+                ("page_import",), build, *args), slots=self.slots)
         # the trie, for the router and stats() to READ: pages enter and
         # leave it only through self.kv
         self.prefix_cache = self.kv.prefix_cache
@@ -514,7 +535,13 @@ class ServingEngine:
         # geometry at 2 bytes/element, so kv_capacity_vs_bf16 is exactly
         # the capacity multiplier a quantized pool buys at equal HBM.
         self._pool_bytes = sum(
-            int(a.nbytes) for a in jax.tree_util.tree_leaves(self.kv.pool))
+            int(a.nbytes) for op in self.gen.attn_ops
+            for a in jax.tree_util.tree_leaves(self.kv.pool[op.name]))
+        # the recurrent ops' state: fixed bytes a slot, no per-token part
+        self._state_pool_bytes = sum(
+            int(a.nbytes) for op in self.gen.state_ops
+            for a in jax.tree_util.tree_leaves(self.kv.pool[op.name]))
+        self._state_bytes_per_slot = self._state_pool_bytes // self.slots
         self._kv_bytes_per_token = (
             self._pool_bytes / (self.num_pages * self.page_size))
         self._bf16_bytes_per_token = sum(
@@ -1237,7 +1264,8 @@ class ServingEngine:
                     for name in c}
         return caches
 
-    def _scatter_tail(self, gen, pool, caches, pages, p0: int = 0):
+    def _scatter_tail(self, gen, pool, caches, pages, p0: int = 0,
+                      slot=None):
         """COW scatter: write the contiguous cache's positions past
         ``p0`` into ``pages`` — the request's own fresh pages, never the
         shared ones. ``p0=0`` is the cold (whole-bucket) case. Routed
@@ -1254,6 +1282,11 @@ class ServingEngine:
                 out[op.name] = op.scatter_cache_tail(
                     pool[op.name], caches[op.name], p0, pages,
                     impl=self.paged_prefill_impl)
+        for op in gen.state_ops:
+            # the prefilled state takes the request's slot in the pool
+            with jax.named_scope(op.name), jax.named_scope("seat"):
+                out[op.name] = op.seat_state(pool[op.name], caches[op.name],
+                                             slot[0])
         return out
 
     @staticmethod
@@ -1302,9 +1335,11 @@ class ServingEngine:
         has_lora = self.lora_pool is not None
 
         def prefill(params, state, tokens, length, pool, pages, poison,
-                    temps, top_ps, top_ks, seeds, lora_pool, lora_pages):
-            caches = {op.name: op.init_cache(1, bucket, cdtype)
-                      for op in gen.attn_ops}
+                    temps, top_ps, top_ks, seeds, lora_pool, lora_pages,
+                    *slot):
+            # `slot`: one more argument of a model with recurrent-state
+            # ops, the pool row its prefilled state is seated in
+            caches = gen.init_caches(1, bucket, cdtype)
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             routing = [] if gen.dropless_moe_ops else None
@@ -1314,7 +1349,8 @@ class ServingEngine:
                                           lowerings=took)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
-            return (tok, ok, self._scatter_tail(gen, pool, caches, pages),
+            return (tok, ok, self._scatter_tail(gen, pool, caches, pages,
+                                                slot=slot),
                     *self._routing_sum(routing))
 
         return jax.jit(prefill, donate_argnums=(4,))
@@ -1369,8 +1405,7 @@ class ServingEngine:
         cdtype = gen._compute_dtype()
 
         def prefill(params, state, tokens, pool, pages):
-            caches = {op.name: op.init_cache(1, bucket, cdtype)
-                      for op in gen.attn_ops}
+            caches = gen.init_caches(1, bucket, cdtype)
             _, caches = gen._walk(params, state, tokens, caches, None,
                                   skip_tail=True)
             return self._scatter_tail(gen, pool, caches, pages)
@@ -1415,27 +1450,30 @@ class ServingEngine:
         has_lora = self.lora_pool is not None
         chunk = self.prefill_chunk
 
+        # `length`: one more argument of a model with recurrent-state ops,
+        # whose state the prompt's padding rows must not reach
         if st == 0:
             def prefill_chunk0(params, state, tokens, lora_pool,
-                               lora_pages):
-                caches = {op.name: op.init_cache(1, bucket, cdtype)
-                          for op in gen.attn_ops}
+                               lora_pages, *length):
+                caches = gen.init_caches(1, bucket, cdtype)
                 lora = ({"pool": lora_pool, "pages": lora_pages}
                         if has_lora else None)
                 _, caches = gen._walk(
                     params, state, tokens[:, :chunk], caches, None,
-                    chunk_start=0, skip_tail=True, lora=lora)
+                    chunk_start=0, skip_tail=True, lora=lora,
+                    row_lengths=length[0] if length else None)
                 return caches
 
             return jax.jit(prefill_chunk0)
 
         def prefill_chunk(params, state, tokens, caches, lora_pool,
-                          lora_pages):
+                          lora_pages, *length):
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             _, caches = gen._walk(
                 params, state, tokens[:, st:st + chunk], caches, None,
-                chunk_start=st, skip_tail=True, lora=lora)
+                chunk_start=st, skip_tail=True, lora=lora,
+                row_lengths=length[0] if length else None)
             return caches
 
         return jax.jit(prefill_chunk, donate_argnums=(3,))
@@ -1453,7 +1491,7 @@ class ServingEngine:
 
         def prefill_final(params, state, tokens, length, caches, pool,
                           pages, poison, temps, top_ps, top_ks, seeds,
-                          lora_pool, lora_pages):
+                          lora_pool, lora_pages, *slot):
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             tok_last = jnp.take_along_axis(
@@ -1464,7 +1502,8 @@ class ServingEngine:
                                        gather_last=True, lora=lora)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
-            return tok, ok, self._scatter_tail(gen, pool, caches, pages)
+            return tok, ok, self._scatter_tail(gen, pool, caches, pages,
+                                               slot=slot)
 
         # donate the pool only: the chunk caches feed the scatter but
         # back no output (tok/ok are tiny, pool aliases the pool input),
@@ -1704,8 +1743,37 @@ class ServingEngine:
         version bit-identical to the bare adapter key."""
         return version_ns(self.weight_version, adapter)
 
+    def _refuse_state_pages(self, what: str):
+        """Page slabs carry per-token rows only: for a model whose ops keep
+        a recurrent state they would move a prefix without the state that
+        goes with it."""
+        if self.gen.state_ops:
+            raise NotImplementedError(
+                f"{what}: {self.gen.state_ops[0].name} keeps a recurrent "
+                "state, which no page slab carries (export, import and "
+                "evacuation move pages of per-token rows only)")
+
+    def _state_slot_args(self, slot):
+        """The trailing `slot` argument of the prefill programs of a model
+        with recurrent-state ops; nothing for any other model."""
+        if not self.gen.state_ops:
+            return ()
+        return (np.int32(slot),)
+
+    def slot_state(self, slot: int) -> dict:
+        """Host copies of one slot's recurrent state, {op name: the op's
+        state arrays for that slot}; empty for a model without such ops.
+        For checks that hold what prefill seated and decode advanced to a
+        reference: the state covers the prompt and every emitted token but
+        the last (which no step has read yet)."""
+        with self._lock:
+            return {op.name: jax.device_get(jax.tree_util.tree_map(
+                lambda a: a[slot], self.kv.pool[op.name]))
+                for op in self.gen.state_ops}
+
     def _run_prefill(self, prompt, bucket: int, lease, sampling,
-                     adapter_page: int, poison, span=telemetry.NULL_SPAN):
+                     adapter_page: int, poison, span=telemetry.NULL_SPAN,
+                     slot: int = 0):
         """Dispatch one run-to-completion prefill of ``prompt`` into
         ``lease``'s pages, target then draft; returns the device values
         ``(tok, ok, routed)`` and notes the target program's MoE lowering
@@ -1742,7 +1810,8 @@ class ServingEngine:
                     bucket, n_prefill, self._moe_took_list(key)),
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
-                *self._lora_args_1(adapter_page))
+                *self._lora_args_1(adapter_page),
+                *self._state_slot_args(slot))
         span.annotate(program=program_name(key))
         self._note_moe_lowering(key, span)
         if self.draft_gen is not None:
@@ -1761,6 +1830,14 @@ class ServingEngine:
                     self.draft_gen._params(), self.draft_model.bn_state,
                     padded, kv.draft_pool, tail_pages)
         return tok, ok, routed
+
+    def _scan_rows(self, bucket: int) -> Dict:
+        """`scan_rows` of a prefill span: the rows the recurrent ops' chunked
+        scans walk (the bucket, padding included, times those ops); nothing
+        for a model without them."""
+        if not self.gen.state_ops:
+            return {}
+        return {"scan_rows": bucket * len(self.gen.state_ops)}
 
     def _admit(self) -> int:
         """Move queued requests into free slots: look up the longest
@@ -1900,13 +1977,15 @@ class ServingEngine:
                             prompt_tokens=int(req.prompt.size),
                             matched_pages=full,
                             tail_tokens=int(req.prompt.size)
-                            - req.prefix_tokens) as psp:
+                            - req.prefix_tokens,
+                            **self._scan_rows(req.bucket)) as psp:
                 self._prefix_hit_tokens += req.prefix_tokens
                 self._prefix_prompt_tokens += int(req.prompt.size)
                 self._seed_slot(slot, req, poison)
                 tok, ok, routed = self._run_prefill(
                     req.prompt, req.bucket, lease,
-                    self._sampling_args_1(req), adapter_page, poison, psp)
+                    self._sampling_args_1(req), adapter_page, poison, psp,
+                    slot)
                 with self._span("prefill_fetch"):
                     # ONE device_get: the copies back start together
                     ok, tok, *routed = jax.device_get((ok, tok, *routed))
@@ -1966,18 +2045,21 @@ class ServingEngine:
         with self._span("prefill_chunk", slot=slot, bucket=req.bucket,
                         program=program_name(
                             ("prefill_ichunk", req.bucket, st))):
+            length = ((np.asarray([req.prompt.size], np.int32),)
+                      if self.gen.state_ops else ())
             if st == 0:
                 ps["caches"] = self._compiled_call(
                     ("prefill_ichunk", req.bucket, 0),
                     lambda: self._build_prefill_ichunk(req.bucket, 0),
                     self.gen._params(), self.model.bn_state, ps["padded"],
-                    *self._lora_args_1(ps["adapter_page"]))
+                    *self._lora_args_1(ps["adapter_page"]), *length)
             else:
                 ps["caches"] = self._compiled_call(
                     ("prefill_ichunk", req.bucket, st),
                     lambda: self._build_prefill_ichunk(req.bucket, st),
                     self.gen._params(), self.model.bn_state, ps["padded"],
-                    ps["caches"], *self._lora_args_1(ps["adapter_page"]))
+                    ps["caches"], *self._lora_args_1(ps["adapter_page"]),
+                    *length)
             ps["next"] = st + self.prefill_chunk
             self._prefill_chunks_interleaved += 1
             if ps["next"] >= req.bucket:
@@ -1999,7 +2081,8 @@ class ServingEngine:
             self.gen._params(), self.model.bn_state, ps["padded"],
             np.asarray([req.prompt.size], np.int32), ps["caches"],
             self.kv.pool, pages, ps["poison"], *self._sampling_args_1(req),
-            *self._lora_args_1(ps["adapter_page"]))
+            *self._lora_args_1(ps["adapter_page"]),
+            *self._state_slot_args(slot))
         if self.draft_gen is not None:
             # the draft pool rides the same page ids; its cold prefill
             # program (shared with run-to-completion admission) fills
@@ -2065,6 +2148,7 @@ class ServingEngine:
         pages now cached for this prompt, or None when pool pressure or
         a non-finite prefill prevented publishing — the caller falls
         back to the cold path."""
+        self._refuse_state_pages("prefill_into_cache")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -2149,6 +2233,7 @@ class ServingEngine:
         the exact key they were cached under. None when the path's pages
         were evicted since the manifest walk — the entry simply drops
         out of the evacuation."""
+        self._refuse_state_pages("export_prefix_slab")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         with self._lock:
             slab = self.kv.export_slab(prompt, ns, start_page)
@@ -2171,6 +2256,7 @@ class ServingEngine:
         in order. A slab whose predecessors have not merged yet is
         refused (return 0, no pages written): publishing pages past a
         gap would cache a prefix whose middle was never written."""
+        self._refuse_state_pages("import_prefix_slab")
         with self._lock:
             imported = self.kv.import_slab(slab)
             if imported:
@@ -2356,6 +2442,10 @@ class ServingEngine:
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
+            if self.gen.state_ops:
+                # each step reads and writes every live slot's state once
+                attn["state_bytes"] = (2 * k * live
+                                       * self._state_bytes_per_slot)
         key = ("decode", k)
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read,
@@ -2920,6 +3010,13 @@ class ServingEngine:
             "kv_cache_dtype": self.kv_cache_dtype,
             "weight_dtype": self.weight_dtype,
             "kv_pool_bytes": self._pool_bytes,
+            # the recurrent-state ops' pool beside the pages (0 for a
+            # model without them): its bytes, the fixed bytes a slot holds
+            # whatever its context, and the slots whose state is live
+            "state_pool_bytes": self._state_pool_bytes,
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+            "state_slots_live": (int((self.row_len > 0).sum())
+                                 if self.gen.state_ops else 0),
             "kv_bytes_per_token": round(self._kv_bytes_per_token, 3),
             "tokens_per_pool_gb": int((1 << 30)
                                       / self._kv_bytes_per_token),
