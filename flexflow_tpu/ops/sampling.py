@@ -27,6 +27,15 @@ program: rows with temperature 0 return ``argmax(logits)`` computed
 exactly as the pre-sampling greedy path did (f32 cast then argmax), so
 greedy streams are bitwise-identical to a greedy-only engine.
 
+The gate: the warp and the draw of ``sample_tokens`` and
+``sampling_probs`` run under ONE ``lax.cond`` on ``any(temps > 0)``, so
+a dispatch whose rows are all greedy sorts no vocabulary and draws
+nothing; its other branch is that same argmax (a one-hot at it for
+``sampling_probs``). A dispatch that holds one sampled row takes the
+ungated computation whole, its greedy rows included. What the callers
+do around these functions (the poison add, the finite check) stays
+outside the gate and runs every step.
+
 Warping semantics (shared by the sampler and ``sampling_probs`` — the
 rejection-sampling accept rule depends on the two agreeing): logits are
 divided by temperature and ranked in descending order, ties by
@@ -133,19 +142,33 @@ def _masked_warped(logits, temps, top_ps, top_ks):
     return jnp.where(keep, warped, -jnp.inf)
 
 
+def _any_sampled(temps):
+    """The gate's predicate: does any row of this dispatch use the warp?
+    A released slot's temperature is 0, so free slots never hold it open."""
+    return jnp.any(temps > 0.0)
+
+
 @jax.named_scope("sampler")
 def sampling_probs(logits, temps, top_ps, top_ks):
     """The per-row sampling distribution as (B, V) f32 probabilities —
     the operand of the rejection-sampling accept rule (``p`` for the
     target, ``q`` for the draft). Rows with temperature 0 are the
     degenerate one-hot at argmax (their "distribution" is the greedy
-    choice)."""
+    choice); a dispatch of such rows only takes the gate's greedy
+    branch."""
     logits = logits.astype(jnp.float32)
-    masked = _masked_warped(logits, temps, top_ps, top_ks)
-    probs = jax.nn.softmax(masked, axis=-1)
-    greedy = jax.nn.one_hot(jnp.argmax(logits, axis=-1),
-                            logits.shape[-1], dtype=jnp.float32)
-    return jnp.where((temps > 0.0)[:, None], probs, greedy)
+    temps = jnp.asarray(temps, jnp.float32)
+
+    def greedy():
+        return jax.nn.one_hot(jnp.argmax(logits, axis=-1),
+                              logits.shape[-1], dtype=jnp.float32)
+
+    def warped():
+        masked = _masked_warped(logits, temps, top_ps, top_ks)
+        probs = jax.nn.softmax(masked, axis=-1)
+        return jnp.where((temps > 0.0)[:, None], probs, greedy())
+
+    return jax.lax.cond(_any_sampled(temps), warped, greedy)
 
 
 @jax.named_scope("sampler")
@@ -153,16 +176,23 @@ def sample_tokens(logits, temps, top_ps, top_ks, seeds, counters,
                   tag: int = TAG_TARGET):
     """One token per row from the warped distribution; (B,) int32.
     temperature-0 rows take ``argmax(f32(logits))`` — bitwise the
-    pre-sampling greedy decode. Draw b is a pure function of
+    pre-sampling greedy decode — and a dispatch of such rows only takes
+    the gate's greedy branch. Draw b is a pure function of
     (seeds[b], tag, counters[b]): slot- and replica-invariant."""
     logits = logits.astype(jnp.float32)
     temps = jnp.asarray(temps, jnp.float32)
-    masked = _masked_warped(logits, temps, top_ps, top_ks)
-    keys = slot_keys(seeds, counters, tag)
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row))(keys, masked)
-    greedy = jnp.argmax(logits, axis=-1)
-    return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
+
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def warped():
+        masked = _masked_warped(logits, temps, top_ps, top_ks)
+        keys = slot_keys(seeds, counters, tag)
+        sampled = jax.vmap(
+            lambda k, row: jax.random.categorical(k, row))(keys, masked)
+        return jnp.where(temps > 0.0, sampled, greedy()).astype(jnp.int32)
+
+    return jax.lax.cond(_any_sampled(temps), warped, greedy)
 
 
 @jax.named_scope("sampler")

@@ -60,6 +60,7 @@ _NO_CHAIN = re.compile(
 _BLOCK = re.compile(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \(.*\{\s*\n)")
 _TUPLE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .* tuple\((.*?)\)"
                     r"(?:, |$)", re.M)
+_ROOT_TUPLE = re.compile(r"^\s*ROOT %?([\w.\-]+) = .* tuple\(", re.M)
 _WHILE = re.compile(r" while\(%?([\w.\-]+)\), condition=%?[\w.\-]+, "
                     r"body=%?([\w.\-]+)")
 _ELEMENT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .* get-tuple-element\("
@@ -110,10 +111,12 @@ def _operands(body: str) -> List[str]:
 
 
 def _loop_links(hlo_text: str) -> Dict[str, List[str]]:
-    """{instruction that feeds a ``while``: the loop body's
+    """{instruction that feeds a ``while``'s state: the loop body's
     get-tuple-elements of that position}: a weight's layout copy hoisted
     in front of a decode loop reaches its consumer only through the
-    loop's state."""
+    loop's state, and so does what one turn of the body hands the next
+    through its ROOT tuple (the slices that prefetch the next turn's
+    first weight)."""
     blocks = {}
     for block in _BLOCK.split(hlo_text):
         head = _COMPUTATION.match(block)
@@ -126,8 +129,10 @@ def _loop_links(hlo_text: str) -> Dict[str, List[str]]:
         elements: Dict[int, List[str]] = {}
         for name, index in _ELEMENT.findall(blocks.get(body, "")):
             elements.setdefault(int(index), []).append(name)
-        for i, fed in enumerate(tuples.get(state, ())):
-            links.setdefault(fed, []).extend(elements.get(i, ()))
+        root = _ROOT_TUPLE.search(blocks.get(body, ""))
+        for fed_by in (state, root.group(1) if root else None):
+            for i, fed in enumerate(tuples.get(fed_by, ())):
+                links.setdefault(fed, []).extend(elements.get(i, ()))
     return links
 
 

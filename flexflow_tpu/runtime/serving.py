@@ -774,6 +774,7 @@ class ServingEngine:
         self._adapter_spec: Dict[str, List[int]] = {}
         self._sampled_requests = 0
         self._sampled_slot_steps = 0
+        self._sampler_gated_steps = 0
         # dropless MoE routing of the DECODE dispatches, summed over
         # steps and MoE layers (counted inside the program, live rows
         # only): assignments, experts with at least one row
@@ -2646,9 +2647,9 @@ class ServingEngine:
         # shows each replica's chunk cadence without per-token events
         toks0 = self._tokens_emitted
         steps0 = self.decode_steps
-        # live slots whose sampler's warp is USED this dispatch (the
-        # program computes it for every row; temperature-0 rows discard
-        # it): the engage share of a future all-greedy gate
+        # live slots whose sampler's warp is USED this dispatch; with
+        # none, the program's gate (ops/sampling.py) skips the warp and
+        # the draw for every step of it (a free slot's temperature is 0)
         sampled = int(np.count_nonzero(self.temps[self.active] > 0.0))
         with self._span("decode_chunk",
                         slots=int(self.active.sum())) as sp:
@@ -2658,6 +2659,8 @@ class ServingEngine:
                 self._decode_step(sampled)
             sp.annotate(tokens=self._tokens_emitted - toks0)
         self._sampled_slot_steps += sampled * (self.decode_steps - steps0)
+        if sampled == 0:
+            self._sampler_gated_steps += self.decode_steps - steps0
 
     def step(self) -> bool:
         """One scheduler tick: admit what fits (unless draining), then one
@@ -2981,6 +2984,10 @@ class ServingEngine:
             # slots x the dispatch's steps, counted at dispatch: a slot
             # that retires mid-chunk still counts its whole chunk)
             "sampled_slot_steps": self._sampled_slot_steps,
+            # decode steps of dispatches in which no live slot samples:
+            # over decode_steps, the share of steps whose sampler took
+            # the gate's greedy branch
+            "sampler_gated_steps": self._sampler_gated_steps,
             # decode dispatches of a model with dropless MoE ops (0
             # otherwise): experts_hit / (experts x MoE layers x
             # decode_steps) is the share of the expert weights a step

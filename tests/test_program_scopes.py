@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import flexflow_tpu as fft
+import hlo_text
 from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.models.deepseek_v32 import deepseek_v32_lm
 from flexflow_tpu.models.kanana2 import kanana2_lm
@@ -78,8 +79,11 @@ HLO = '''HloModule jit_decode, is_scheduled=true
   %st = (s32[], /*index=1*/f32[8]{0}) parameter(0)
   %gte.0 = s32[] get-tuple-element(%st), index=0
   %gte.1 = f32[8]{0} get-tuple-element(%st), index=1
+  %gte.2 = f32[8]{0} get-tuple-element(%st), index=2
   %mul.3 = f32[8]{0} multiply(%gte.1, %gte.1), metadata={op_name="jit(decode)/jit(main)/while/body/ffn_up_1/mul"}
-  ROOT %tuple.6 = (s32[], f32[8]{0}) tuple(%gte.0, %mul.3)
+  %add.4 = f32[8]{0} add(%gte.2, %gte.2), metadata={op_name="jit(decode)/jit(main)/while/body/attn_3/out/add"}
+  %copy.7 = f32[8]{0} copy(%gte.1)
+  ROOT %tuple.6 = (s32[], f32[8]{0}, f32[8]{0}) tuple(%gte.0, %mul.3, %copy.7)
 }
 
 ENTRY %main.9 (p: f32[8]) -> f32[8] {
@@ -123,8 +127,11 @@ def test_scope_table_books_each_instruction_to_its_op_and_phase():
         "mul.3": ("ffn_up_1", ""),
         # what XLA made without a scope: computed FROM the sort (a chain)...
         "rw.1": ("sampler", ""), "copy.5": ("sampler", ""),
-        # ... or FOR the loop body's reader of that position of its state
-        "copy.9": ("ffn_up_1", "")}
+        # ... or FOR the loop body's reader of that position of its state,
+        # be it fed in front of the loop or by the turn before (a prefetch
+        # of the next turn's weight)
+        "copy.9": ("ffn_up_1", ""),
+        "add.4": ("attn_3", "out"), "copy.7": ("attn_3", "out")}
     # a source is no link (%p feeds every op here), nor is control flow
     assert not {"p", "c0", "gte.1", "tuple.5", "while.1"} & set(table)
     # names alone (no kernel phases) work too; nothing else is in the table
@@ -204,6 +211,43 @@ def test_decode_table_books_the_sort_to_the_sampler_and_leaves_little_out():
     a_layer = len(rows) - len(rows1)
     assert a_layer > 0
     assert len(missing) < 0.03 * (len(rows1) + 23 * a_layer)
+
+
+def test_the_samplers_gate_and_both_its_branches_are_booked_to_the_sampler():
+    """The conditional around the warp (ops/sampling.py) and every device
+    op of its two branch computations, with the loops a draw runs on the
+    CPU, read `sampler`: the gate moves seconds inside that row and none
+    into the unscoped share."""
+    eng = llama_engine()
+    eng.run(prompts(1, (5, 9)), max_new_tokens=6)
+    prog = eng._registered[("decode", eng.decode_chunk)]
+    text = prog.text()
+    table = profiler.scope_table(text, prog.graph_ops)
+    rows, calls = hlo_text.computations(text)
+    gates = [(name, body) for ins in rows.values() for name, _, body in ins
+             if hlo_text.opcode(body) == "conditional"]
+    assert len(gates) == 1
+    assert table[gates[0][0]] == ("sampler", "")
+    branches = hlo_text.branches_of(gates[0][1])
+    assert len(branches) == 2
+    # a loop's body and condition (and the call the CPU wraps a small loop
+    # in) hold device ops of their own; what a fusion or a reduction
+    # applies is part of that ONE op
+    seen = hlo_text.reach(calls, branches, through=hlo_text.CONTROL_FLOW)
+    ops = collections.Counter()
+    for comp in seen:
+        for name, _, body in rows[comp]:
+            opcode = hlo_text.opcode(body)
+            # (but for the copies the CPU makes into the state of a loop it
+            # wraps in a call: they feed a tuple, which hands no scope on)
+            if opcode not in NO_DEVICE_OP \
+                    and not (opcode == "copy" and name not in table):
+                assert table.get(name, ("", ""))[0] == "sampler", (comp, name)
+                ops[opcode] += 1
+    assert ops["sort"] == 1 and len(seen) > 2      # the draw's loops too
+    # outside the gate and still the sampler's: the finite check
+    finite = [n for n, p in traced(text) if p.endswith("/is_finite")]
+    assert finite and all(table[n] == ("sampler", "") for n in finite)
 
 
 # ---- (b) the sparse latent attention's phases -------------------------------
