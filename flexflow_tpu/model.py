@@ -284,13 +284,18 @@ class FFModel:
                             add_zero_attn: bool = False, causal: bool = False,
                             num_kv_heads: int = 0, rope: bool = False,
                             rope_theta: float = 10000.0,
-                            qk_norm: bool = False, eps: float = 1e-6,
+                            qk_norm=False, eps: float = 1e-6,
+                            window: int = 0, flash_chunks: bool = False,
                             name: Optional[str] = None, **kw) -> Tensor:
+        """`window` > 0: a sliding-window layer (position i sees keys
+        i - window < j <= i); `qk_norm`: True over all heads of a position,
+        "head" over each head's entries (ops/attention.py)."""
         return self._add(MultiHeadAttention(
             self, self._name("multihead_attention", name), [query, key, value],
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, num_kv_heads=num_kv_heads, rope=rope,
-            rope_theta=rope_theta, qk_norm=qk_norm, eps=eps))
+            rope_theta=rope_theta, qk_norm=qk_norm, eps=eps, window=window,
+            flash_chunks=flash_chunks))
 
     def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
                          q_lora_rank: Optional[int], kv_lora_rank: int,

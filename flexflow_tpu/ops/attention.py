@@ -207,6 +207,16 @@ def _apply_rope(x, theta: float, offset=0):
 
 
 class MultiHeadAttention(Op):
+    """Multi-head / grouped-query attention, global or windowed.
+
+    A GLOBAL layer (`window=0`) sees every earlier key: its flash forward
+    costs sq * sk / 2 logits a head under causality and its cache keeps
+    every token (`cache_bytes_per_token` a token). A WINDOW layer sees the
+    last `window` keys: `flops()` prices sq * window logits, the flash
+    forward runs two key tiles a query tile whatever sk is, and a cache
+    need keep `cache_tokens_kept` = min(context, window) tokens, which is
+    what the serving pool gives it (`kv_keep`, runtime/kv_pool.py)."""
+
     op_type = OperatorType.OP_MULTIHEAD_ATTENTION
     needs_rng = True
     wants_shard_ctx = True  # executor passes (mesh, axis_map) for SP lowering
@@ -220,8 +230,9 @@ class MultiHeadAttention(Op):
                  bias: bool = True, add_bias_kv: bool = False,
                  add_zero_attn: bool = False, causal: bool = False,
                  num_kv_heads: int = 0, rope: bool = False,
-                 rope_theta: float = 10000.0, qk_norm: bool = False,
-                 eps: float = 1e-6):
+                 rope_theta: float = 10000.0, qk_norm=False,
+                 eps: float = 1e-6, window: int = 0,
+                 flash_chunks: bool = False):
         super().__init__(model, name, inputs)
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
@@ -248,8 +259,31 @@ class MultiHeadAttention(Op):
         # projection and before the head split's RoPE. It lives in
         # _project_qkv, the one place every lowering shares, so the KV
         # cache holds k already normed and no attention kernel knows of it
+        # "head" (EXAONE, Qwen3): the same norm over each head's own
+        # entries, one learned vector of the head size for q and one for k
+        if qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"qk_norm={qk_norm!r}: False, True (over all heads of a "
+                "position) or 'head' (over each head's entries)")
         self.qk_norm = qk_norm
         self.eps = eps
+        # a WINDOW layer (sliding-window attention): position i sees keys
+        # i - window < j <= i, the position itself included; 0 = a global
+        # layer, which sees every earlier key. `_sees` is the one
+        # definition every path masks by; the flash forward and the paged
+        # kernel take the window as a static parameter. A window layer
+        # keeps only its window in the serving engine's pool (`kv_keep`):
+        # its page table there is a ring indexed by SEQUENCE position.
+        if window < 0 or (window and not causal):
+            raise ValueError(
+                f"{name}: window={window} needs causal attention and a "
+                "positive size")
+        self.window = int(window)
+        # a prefill chunk attends its prefix through the flash forward
+        # (bottom-right aligned) instead of the einsum whose f32 logits
+        # are chunk x prefix x heads; off, `chunk_forward` is what the
+        # prefix-hit prefill of every other model compiles
+        self.flash_chunks = bool(flash_chunks)
         # kdim/vdim are total projection sizes (reference kProjSize*num_heads
         # semantics via cudnnSetAttnDescriptor, attention.cu:533-570)
         self.kdim = kdim if kdim > 0 else embed_dim
@@ -287,7 +321,10 @@ class MultiHeadAttention(Op):
             WeightSpec("wo", (self.num_heads, self.v_head_dim, self.embed_dim),
                        init="glorot", fan=(self.vdim, self.embed_dim)),
         ]
-        if self.qk_norm:
+        if self.qk_norm == "head":
+            ws += [WeightSpec("q_norm", (self.qk_head_dim,), init="one"),
+                   WeightSpec("k_norm", (self.qk_head_dim,), init="one")]
+        elif self.qk_norm:
             ws += [WeightSpec("q_norm", (self.kdim,), init="one"),
                    WeightSpec("k_norm", (kvh * self.qk_head_dim,),
                               init="one")]
@@ -310,7 +347,10 @@ class MultiHeadAttention(Op):
                 qh = qh + params["bias_q"]
                 kh = kh + params["bias_k"]
                 vh = vh + params["bias_v"]
-            if self.qk_norm:
+            if self.qk_norm == "head":
+                qh = self._head_rms_norm(qh, params["q_norm"])
+                kh = self._head_rms_norm(kh, params["k_norm"])
+            elif self.qk_norm:
                 qh = self._whole_rms_norm(qh, params["q_norm"])
                 kh = self._whole_rms_norm(kh, params["k_norm"])
             if self.rope:
@@ -326,6 +366,29 @@ class MultiHeadAttention(Op):
         x = x * jax.lax.rsqrt(ms + self.eps) \
             * scale.astype(jnp.float32).reshape(xh.shape[-2:])
         return x.astype(xh.dtype)
+
+    def _head_rms_norm(self, xh, scale):
+        """RMSNorm of a (B, S, H, Hd) projection over each head's own Hd
+        entries, statistics in f32."""
+        x = xh.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        x = x * jax.lax.rsqrt(ms + self.eps) * scale.astype(jnp.float32)
+        return x.astype(xh.dtype)
+
+    def _sees(self, q_pos, k_pos):
+        """Whether the query at sequence position `q_pos` sees the key at
+        `k_pos` (broadcast against each other): causal, and inside the
+        window where the layer has one."""
+        seen = k_pos <= q_pos
+        if self.window:
+            seen = seen & (k_pos > q_pos - self.window)
+        return seen
+
+    def kv_keep(self):
+        """What of a sequence's keys and values a cache must keep for this
+        op: None = all of them, else the last `window` positions (the
+        serving pool groups its ops by this: runtime/kv_pool.py)."""
+        return self.window or None
 
     def _broadcast_kv(self, kh, vh):
         if self.num_kv_heads != self.num_heads:
@@ -355,6 +418,10 @@ class MultiHeadAttention(Op):
             seq_axes = [ax for ax, d in (shard_ctx.get("axis_map") or {}).items()
                         if d == 1 and shard_ctx["mesh"].shape[ax] > 1]
         if seq_axes:
+            if self.window:
+                raise NotImplementedError(
+                    f"{self.name}: ring / Ulysses attention has no window; "
+                    "a window layer's sequence dim cannot be sharded")
             with jax.named_scope("core"):
                 ctx = self._sp_attention(qh, kh, vh, shard_ctx, seq_axes,
                                          scale, training, rng)
@@ -427,7 +494,10 @@ class MultiHeadAttention(Op):
         and positions as the whole-prompt pass; logits are bitwise-equal
         to it on the einsum path (a flash-prefill backend accumulates in
         a different order, so there equality is within kernel tolerance —
-        runtime/generation.py notes)."""
+        runtime/generation.py notes). A window layer attends only the
+        slice that holds the chunk and the window before it. With
+        `flash_chunks` the chunk takes the flash forward, bottom-right
+        aligned, where the backend runs it (`flash_eligible`)."""
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=start)
         with jax.named_scope("project"):
@@ -437,10 +507,25 @@ class MultiHeadAttention(Op):
                 cache["v"], vh.astype(cache["v"].dtype), (0, start, 0, 0))
         c = qh.shape[1]
         end = start + c  # python ints: a static slice of the live prefix
-        live = (jnp.arange(end)[None, :]
-                <= (start + jnp.arange(c))[:, None])        # (C, end)
-        ctx = self._grouped_cache_attention(
-            qh, ck[:, :end], cv[:, :end], live[None, None, None, :, :])
+        lo = 0
+        if self.window:
+            # whole flash tiles back from the chunk, the window at least
+            from flexflow_tpu.ops.pallas_kernels import _WINDOW_BLOCK
+
+            lo = max(0, start - -(-self.window // _WINDOW_BLOCK)
+                     * _WINDOW_BLOCK)
+        ks, vs = ck[:, lo:end], cv[:, lo:end]
+        if self.flash_chunks and flash_eligible(
+                getattr(self.model, "config", None), True, c, end - lo):
+            kb, vb = self._broadcast_kv(ks.astype(qh.dtype),
+                                        vs.astype(qh.dtype))
+            ctx = self._flash_dense(qh, kb, vb,
+                                    1.0 / math.sqrt(self.qk_head_dim), None)
+        else:
+            live = self._sees((start + jnp.arange(c))[:, None],
+                              jnp.arange(lo, end)[None, :])
+            ctx = self._grouped_cache_attention(
+                qh, ks, vs, live[None, None, None, :, :])
         return self._out_proj(params, ctx), {"k": ck, "v": cv}
 
     def encode_kv(self, params, enc):
@@ -473,6 +558,8 @@ class MultiHeadAttention(Op):
                                      rope_offset=rope_pos)
         idx = jnp.arange(cache["k"].shape[1])
         live = idx[None, :] < row_lengths[:, None]
+        if self.window:
+            live = live & self._sees(rope_pos[:, None], idx[None, :])
         ctx = self._grouped_cache_attention(
             qh, cache["k"], cache["v"], live[:, None, None, None, :])
         return self._out_proj(params, ctx), cache
@@ -498,10 +585,17 @@ class MultiHeadAttention(Op):
                 cache["v"], vh.astype(cache["v"].dtype), (0, pos, 0, 0))
         idx = jnp.arange(ck.shape[1])
         if row_lengths is None:
-            live = (idx <= pos)[None, :]
+            live = self._sees(pos, idx)[None, :]
         else:
             live = (idx[None, :] < row_lengths[:, None]) \
                 | ((idx[None, :] >= prompt_len) & (idx[None, :] <= pos))
+            if self.window:
+                # a slot past the pad holds the position its token has in
+                # the sequence, not its index in the cache
+                at = jnp.where(idx[None, :] < prompt_len, idx[None, :],
+                               idx[None, :] - prompt_len
+                               + row_lengths[:, None])
+                live = live & self._sees(rope_pos[:, None], at)
         ctx = self._grouped_cache_attention(
             qh, ck, cv, live[:, None, None, None, :])
         return self._out_proj(params, ctx), {"k": ck, "v": cv}
@@ -637,8 +731,15 @@ class MultiHeadAttention(Op):
             impl=impl)
 
     def cache_bytes_per_token(self) -> int:
-        """bf16 bytes one cached token takes in this op's pool."""
+        """bf16 bytes one cached token takes in this op's pool. A window
+        layer holds that for at most `cache_tokens_kept` tokens a
+        sequence, whatever its length."""
         return self.num_kv_heads * (self.qk_head_dim + self.v_head_dim) * 2
+
+    def cache_tokens_kept(self, context: int) -> int:
+        """How many of a sequence's `context` tokens this op's cache has
+        to hold: all of them, or the window."""
+        return min(context, self.window) if self.window else context
 
     def paged_kernel_shape(self, cache):
         """What the kernel autotuner's table is keyed by (None from an op
@@ -779,6 +880,12 @@ class MultiHeadAttention(Op):
         page_size = cache["k"].shape[1]
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=rope_pos)
+        if self.window:
+            # a window layer's table is the slot's ring, and its rows are
+            # addressed by SEQUENCE position: no bucket pad lies between
+            # the prompt and the emitted tokens, the window is one run
+            return self._paged_window_decode(params, qh, kh, vh, cache,
+                                             page_table, rope_pos, impl)
         with jax.named_scope("project"):
             page_ids = jnp.take_along_axis(
                 page_table, (write_pos // page_size)[:, None], axis=1)[:, 0]
@@ -789,6 +896,80 @@ class MultiHeadAttention(Op):
                                         write_pos[:, None], row_len,
                                         prompt_pad, impl)
         return self._out_proj(params, ctx), cache
+
+    def _paged_window_decode(self, params, qh, kh, vh, cache, ring, pos,
+                             impl):
+        """`paged_decode_forward` of a window layer. `ring` (B, R) int32:
+        the slot's pages, the page of sequence positions [t * page_size,
+        (t + 1) * page_size) in column t % R; `pos` (B,) the token's
+        sequence position, where it is written and up to where it sees.
+        A free slot (pos 0, a zeroed ring) writes and reads scratch page
+        0, as on a global layer."""
+        page_size, r = cache["k"].shape[1], ring.shape[1]
+        with jax.named_scope("project"):
+            page_ids = jnp.take_along_axis(
+                ring, ((pos // page_size) % r)[:, None], axis=1)[:, 0]
+            cache = self._paged_append(cache, kh[:, 0], vh[:, 0], page_ids,
+                                       pos % page_size)
+        resolved = resolve_paged_attention_impl(
+            impl, getattr(self.model, "config", None))
+        if resolved == "pallas":
+            from flexflow_tpu.ops.pallas_kernels import \
+                paged_attention_fwd_pallas
+
+            zero = jnp.zeros_like(pos)
+            ctx = paged_attention_fwd_pallas(
+                qh, cache["k"], cache["v"], ring, pos[:, None], zero, zero,
+                1.0 / math.sqrt(self.qk_head_dim),
+                k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
+                window=self.window)
+            return self._out_proj(params, ctx), cache
+        b = qh.shape[0]
+        with jax.named_scope("gather"):
+            gk, gv = cache["k"][ring], cache["v"][ring]  # (B, R, ps, KVH, D)
+            if "k_scale" in cache:
+                gk = page_dequantize(gk, cache["k_scale"][ring])
+                gv = page_dequantize(gv, cache["v_scale"][ring])
+            gk = gk.reshape(b, r * page_size, *gk.shape[3:])
+            gv = gv.reshape(b, r * page_size, *gv.shape[3:])
+        with jax.named_scope("core"):
+            # column c holds the newest logical page congruent to it
+            last = (pos // page_size)[:, None]                    # (B, 1)
+            col = jnp.arange(r)[None, :]
+            page = last - (last - col) % r                        # (B, R)
+            at = (page[:, :, None] * page_size
+                  + jnp.arange(page_size)[None, None, :]).reshape(b, -1)
+            live = (at >= 0) & self._sees(pos[:, None], at)
+        ctx = self._grouped_cache_attention(
+            qh, gk, gv, live[:, None, None, None, :])
+        return self._out_proj(params, ctx), cache
+
+    def scatter_window_tail(self, cache, contiguous, length, ring,
+                            impl="einsum"):
+        """What a prefill leaves of a window layer in the pool: the pages
+        of the contiguous cache that hold the last window of the prompt's
+        `length` (1,) positions, each into its column of the slot's `ring`
+        (R,). The rest of the prompt's keys travelled in the program and
+        are dropped with it."""
+        page_size, r = cache["k"].shape[1], ring.shape[0]
+        k, v = contiguous["k"], contiguous["v"]
+        pad = -k.shape[1] % page_size
+        if pad:
+            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        last = (length[0] - 1) // page_size
+        for back in range(min(r, k.shape[1] // page_size)):
+            # a page before the sequence's first is the first again: the
+            # same rows into the same column
+            t = jnp.maximum(last - back, 0)
+            with jax.named_scope("project"):
+                ks = jax.lax.dynamic_slice_in_dim(k, t * page_size,
+                                                  page_size, axis=1)
+                vs = jax.lax.dynamic_slice_in_dim(v, t * page_size,
+                                                  page_size, axis=1)
+                page = jax.lax.dynamic_slice_in_dim(ring, t % r, 1)
+            cache = self.paged_prefill_write(cache, ks, vs, page, impl=impl)
+        return cache
 
     def paged_verify_forward(self, params, xs, cache, page_table, write_pos,
                              rope_pos0, row_len, prompt_pad, impl=None):
@@ -815,6 +996,11 @@ class MultiHeadAttention(Op):
         just requantized; the running-max scale must see them in order),
         so the final pool state is identical across impls — the
         bitwise-pool contract the parity tests pin."""
+        if self.window:
+            raise NotImplementedError(
+                f"{self.name}: a window layer's ring of pages cannot take "
+                "back the rows of rejected draft positions; speculative "
+                "verification over window layers is not built")
         page_size = cache["k"].shape[1]
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=rope_pos0)
@@ -856,7 +1042,10 @@ class MultiHeadAttention(Op):
     def _dense_attention(self, qh, kh, vh, scale, training, rng,
                          shard_ctx=None):
         use_dropout = training and self.dropout > 0.0 and rng is not None
-        if not use_dropout and self._flash_ok(qh, kh):
+        # a window under a gradient is XLA's masked attention: the flash
+        # backward kernels carry no window
+        if not use_dropout and not (self.window and training) \
+                and self._flash_ok(qh, kh):
             return self._flash_dense(qh, kh, vh, scale, shard_ctx)
         with jax.named_scope("core"):
             return self._xla_attention(qh, kh, vh, scale, training, rng,
@@ -866,7 +1055,7 @@ class MultiHeadAttention(Op):
         """`_dense_attention` where flash is refused: blockwise past
         BLOCKWISE_SEQ_THRESHOLD, else the plain einsum."""
         sq, sk = qh.shape[1], kh.shape[1]
-        if max(sq, sk) > BLOCKWISE_SEQ_THRESHOLD \
+        if max(sq, sk) > BLOCKWISE_SEQ_THRESHOLD and not self.window \
                 and self.qk_head_dim == self.v_head_dim:
             # long-context dense fallback for flash-refused shapes (CPU
             # backend, dropout, causal with sq > sk): pure-JAX blockwise
@@ -889,6 +1078,9 @@ class MultiHeadAttention(Op):
         if self.causal:
             sq, sk = logits.shape[-2], logits.shape[-1]
             mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+            if self.window:
+                mask = self._sees((sk - sq + jnp.arange(sq))[:, None],
+                                  jnp.arange(sk)[None, :])
             logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
         probs = jax.nn.softmax(logits, axis=-1).astype(qh.dtype)
         if training and self.dropout > 0.0 and rng is not None:
@@ -905,11 +1097,17 @@ class MultiHeadAttention(Op):
         strategy shards the batch or head dim over a >1 mesh axis, run the
         kernel per-shard inside shard_map (embarrassingly parallel — no
         collectives), the same pattern the ring path uses for seq."""
-        from flexflow_tpu.ops.pallas_kernels import flash_attention
+        from flexflow_tpu.ops.pallas_kernels import (flash_attention,
+                                                     flash_attention_window)
+
+        def flash(q, k, v):
+            if self.window:   # forward only: `_dense_attention` saw to it
+                return flash_attention_window(q, k, v, self.window, scale)
+            return flash_attention(q, k, v, self.causal, scale)
 
         mesh = (shard_ctx or {}).get("mesh")
         if mesh is None:
-            return flash_attention(qh, kh, vh, self.causal, scale)
+            return flash(qh, kh, vh)
         from flexflow_tpu.parallel import shard_entries
 
         axis_map = (shard_ctx or {}).get("axis_map") or {}
@@ -917,14 +1115,10 @@ class MultiHeadAttention(Op):
         # keeping whatever parallelism remains valid
         ent = shard_entries(mesh, axis_map, qh.shape, (0, 2))
         if ent[0] is None and ent[2] is None:
-            return flash_attention(qh, kh, vh, self.causal, scale)
+            return flash(qh, kh, vh)
 
         spec = P(ent[0], None, ent[2], None)
-
-        def inner(q, k, v):
-            return flash_attention(q, k, v, self.causal, scale)
-
-        return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+        return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
                              out_specs=spec, check_vma=False)(qh, kh, vh)
 
     def _sp_attention(self, qh, kh, vh, shard_ctx, seq_axes, scale,
@@ -1030,10 +1224,13 @@ class MultiHeadAttention(Op):
     def flops(self):
         b, sq = self.inputs[0].dims[0], self.inputs[0].dims[1]
         sk = self.inputs[1].dims[1]
-        d = self.embed_dim
         kv_frac = self.num_kv_heads / self.num_heads  # GQA shrinks k/v proj
-        proj = 2 * b * sq * self.q_in * d \
-            + int(2 * b * sk * (self.k_in + self.v_in) * d * kv_frac) \
-            + 2 * b * sq * d * d
-        attn = 2 * b * self.num_heads * sq * sk * self.head_dim * 2
+        proj = 2 * b * sq * self.q_in * self.kdim \
+            + int(2 * b * sk * (self.k_in * self.kdim
+                                + self.v_in * self.vdim) * kv_frac) \
+            + 2 * b * sq * self.vdim * self.embed_dim
+        # a window layer's query meets `window` keys at most, not all sk
+        seen = min(sk, self.window) if self.window else sk
+        attn = 2 * b * self.num_heads * sq * seen \
+            * (self.qk_head_dim + self.v_head_dim)
         return proj + attn

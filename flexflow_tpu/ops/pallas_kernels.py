@@ -87,6 +87,8 @@ def _pick_block(seq: int, want: int) -> int:
 # in the forward and dq, keys in dkv) its arithmetic takes at a time.
 _OUTER_BLOCK = 1024
 _CHUNK = 256
+# a window layer's forward tile, where the window is no larger
+_WINDOW_BLOCK = 512
 
 
 def _resolve_blocks(kernel: str, sq: int, sk: int, d: int, dtype,
@@ -132,44 +134,84 @@ def _diagonal_aligned(block_q: int, block_k: int, offset: int) -> bool:
 
 
 def flash_tile_counts(sq: int, sk: int, block_q: int, block_k: int,
-                      offset: int, causal: bool) -> dict:
+                      offset: int, causal: bool,
+                      window: Optional[int] = None) -> dict:
     """{"live", "masked", "dead"} tiles of ONE head's (sq, sk) logits cut
     into (block_q, block_k) tiles, by the rule the kernels branch on: a
-    tile is dead when its last query row does not see its first key, masked
-    when it is live and its first query row does not see its last key (the
-    diagonal crosses it: the only tiles that build a mask), and `live`
-    counts masked and interior tiles together. Row q sees key k where
-    k <= q + offset."""
+    tile is dead when no query row of it sees a key of it, masked when it
+    is live and some row does not see some key (the diagonal crosses it,
+    or a window's lower edge does: the only tiles that build a mask), and
+    `live` counts masked and interior tiles together. Row q sees key k
+    where k <= q + offset and, under a `window`, q + offset - window < k.
+    A windowed forward has no grid step for a tile wholly below the
+    window (`_window_tiles`): `dead` still counts it, `steps` says how many
+    steps the grid has."""
     live = masked = 0
     for qi in range(sq // block_q):
         for ki in range(sk // block_k):
             is_live, interior = _tile_classes(qi, ki, block_q, block_k,
-                                              offset) if causal \
+                                              offset, window) if causal \
                 else (True, True)
             live += is_live
             masked += is_live and not interior
-    return {"live": live, "masked": masked,
-            "dead": (sq // block_q) * (sk // block_k) - live}
+    counts = {"live": live, "masked": masked,
+              "dead": (sq // block_q) * (sk // block_k) - live}
+    if causal and window is not None:
+        counts["steps"] = (sq // block_q) * _window_tiles(
+            sq, sk, block_q, block_k, window)
+    return counts
 
 
-def _tile_classes(qi, ki, block_q: int, block_k: int, offset: int):
-    """(live, interior) of tile (qi, ki) under the causal rule: the ONE
-    predicate, on the kernels' traced grid indices and on
-    `flash_tile_counts`' python ints alike."""
+def _tile_classes(qi, ki, block_q: int, block_k: int, offset: int,
+                  window: Optional[int] = None):
+    """(live, interior) of tile (qi, ki) under the causal rule and, where
+    the layer has one, the window's lower edge: the ONE predicate, on the
+    kernels' traced grid indices and on `flash_tile_counts`' python ints
+    alike."""
     live = (qi + 1) * block_q + offset > ki * block_k
     interior = qi * block_q + offset >= (ki + 1) * block_k - 1
+    if window is not None:
+        # the tile's first query still sees its last key / its last query
+        # already sees its first key
+        low_live = (ki + 1) * block_k - 1 > qi * block_q + offset - window
+        low_interior = ki * block_k > (qi + 1) * block_q - 1 + offset - window
+        if isinstance(live, bool):
+            return live and low_live, interior and low_interior
+        return (jnp.logical_and(live, low_live),
+                jnp.logical_and(interior, low_interior))
     return live, interior
 
 
-def _run_tile(step, causal: bool, qi, ki, block_q, block_k, offset):
+def _window_first_tile(qi, block_q: int, block_k: int, offset: int,
+                       window: int):
+    """The first key tile query tile `qi` of a window layer can see."""
+    first_key = qi * block_q + offset - window + 1
+    if isinstance(qi, int):
+        return max(first_key, 0) // block_k
+    return jnp.maximum(first_key, 0) // block_k
+
+
+def _window_tiles(sq: int, sk: int, block_q: int, block_k: int,
+                  window: int) -> int:
+    """Key tiles a windowed forward's grid walks for each query tile: from
+    the first tile the window reaches to the diagonal's, at most."""
+    offset = sk - sq
+    return max(((qi + 1) * block_q - 1 + offset) // block_k
+               - _window_first_tile(qi, block_q, block_k, offset, window) + 1
+               for qi in range(sq // block_q))
+
+
+def _run_tile(step, causal: bool, qi, ki, block_q, block_k, offset,
+              window=None):
     """Run a kernel's `step(masked)` on the grid step's tile: a causal
     kernel holds two bodies, the interior tile's (no mask anywhere in it)
-    and the diagonal tile's, and a dead tile runs neither; a non-causal
-    kernel holds the unmasked body alone, under no branch."""
+    and the masked tile's (the diagonal or a window's lower edge crosses
+    it), and a dead tile runs neither; a non-causal kernel holds the
+    unmasked body alone, under no branch."""
     if not causal:
         step(False)
         return
-    live, interior = _tile_classes(qi, ki, block_q, block_k, offset)
+    live, interior = _tile_classes(qi, ki, block_q, block_k, offset, window)
     pl.when(interior)(functools.partial(step, False))
     pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
         functools.partial(step, True))
@@ -223,7 +265,8 @@ def _across(x, width: int):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                       block_k: int, chunk: int, causal: bool, scale: float,
-                      need_lse: bool, offset: int = 0):
+                      need_lse: bool, offset: int = 0,
+                      window: Optional[int] = None):
     """One (block_q, block_k) tile a grid step, `chunk` query rows at a
     time. The running max and sum live LANE-WIDE in their (block_q, 128)
     scratch: every lane of m holds the row's max, so it meets the logits'
@@ -237,17 +280,20 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     else:
         m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    kt = ki = pl.program_id(2)      # the grid's step, and its key tile
     nk = pl.num_programs(2)
     dv = acc_scr.shape[1]
-    aligned = _diagonal_aligned(block_q, block_k, offset)
+    aligned = window is None and _diagonal_aligned(block_q, block_k, offset)
+    if window is not None:
+        # a window layer's grid starts at the first tile the window reaches
+        ki = kt + _window_first_tile(qi, block_q, block_k, offset, window)
     # the tile's first query against its first key, offset added
     tile_first = qi * block_q + offset - ki * block_k
     # a block the lanes do not divide (sequences under 128, blocks of 8 in
     # tests) keeps the whole row sum in every lane of l instead
     tiled = block_k % LANES == 0
 
-    @pl.when(ki == 0)
+    @pl.when(kt == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -264,8 +310,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
             v = v_ref[0, 0:width, :]
             s = _dot_nt(q, k) * scale                       # (chunk, width)
             if masked:
-                s = jnp.where(_row_minus_col(chunk, width) >= -first, s,
-                              NEG_INF)
+                ahead = _row_minus_col(chunk, width)
+                seen = ahead >= -first
+                if window is not None:
+                    seen = jnp.logical_and(seen, ahead < window - first)
+                s = jnp.where(seen, s, NEG_INF)
             m_prev = m_scr[rows, :]                         # (chunk, 128)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -281,9 +330,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
                 + _dot(p.astype(v.dtype), v)
             m_scr[rows, :] = m_new
 
-    _run_tile(step, causal, qi, ki, block_q, block_k, offset)
+    _run_tile(step, causal, qi, ki, block_q, block_k, offset, window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(kt == nk - 1)
     def _finish():
         l = l_scr[...]
         l = jnp.sum(l, axis=-1, keepdims=True) if tiled else l[:, 0:1]
@@ -301,7 +350,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
 def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
-                               need_lse: bool = True):
+                               need_lse: bool = True,
+                               window: Optional[int] = None):
     """q, k: (B, S, H, d_qk), v: (B, S, H, d_v) -> (out (B*H, S_q, d_v),
     lse|None). The key and value widths are independent (latent attention
     has keys of 192 and values of 128): the logit contracts d_qk, the
@@ -312,19 +362,34 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     explicit values pin the tile (degraded to a divisor of seq) and skip the
     table. need_lse=False (inference) skips materializing the logsumexp
     residual — it exists only for the VJP and costs more HBM writes than
-    the output itself at small head dims."""
+    the output itself at small head dims.
+
+    `window` (causal only): query i sees keys i - window < j <= i. A key
+    tile wholly below the window has no grid step, the tile the lower edge
+    crosses is masked like the diagonal's, the rest run as they do without
+    one; with None the call is the one it always was."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q, block_k = _resolve_blocks("flash_fwd", sq, sk, d, q.dtype,
-                                       block_q, block_k, batch=b,
-                                       heads=h, causal=causal)
+    if window is not None:
+        assert causal, "a window is a causal layer's"
+        if block_q is None and block_k is None:
+            # tiles near the window's size: a query tile reads its own
+            # keys and the window before them, two tiles in all
+            block_q = block_k = _WINDOW_BLOCK if window <= _WINDOW_BLOCK \
+                else _OUTER_BLOCK
+        block_q, block_k = _pick_block(sq, block_q), _pick_block(sk, block_k)
+    else:
+        block_q, block_k = _resolve_blocks("flash_fwd", sq, sk, d, q.dtype,
+                                           block_q, block_k, batch=b,
+                                           heads=h, causal=causal)
     assert sq % block_q == 0 and sk % block_k == 0
     # sq > sk with causal would leave the first rows keyless (0/0 in the
     # online softmax) — refused upstream in attention.flash_eligible
     assert not (causal and sk < sq), "causal flash needs sq <= sk"
     return _flash_fwd_call(q, k, v, causal=causal, scale=float(scale),
                            block_q=block_q, block_k=block_k,
-                           need_lse=need_lse, interpret=_interpret())
+                           need_lse=need_lse, interpret=_interpret(),
+                           window=window)
 
 
 # inline=True: traced once per shape and re-emitted under each caller's
@@ -335,9 +400,10 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
 # freeze (the tune table's answer, interpret mode) is resolved by the
 # callers above and comes in as a static argument.
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "need_lse", "interpret"))
+    "causal", "scale", "block_q", "block_k", "need_lse", "interpret",
+    "window"))
 def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
-                    interpret):
+                    interpret, window=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[3]
     # cross-attention diagonal offset (bottom-right aligned causality)
@@ -353,8 +419,19 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
                                block_k=block_k, chunk=_chunk_rows(block_q),
                                causal=causal, scale=scale,
-                               need_lse=need_lse, offset=offset)
-    if causal:
+                               need_lse=need_lse, offset=offset,
+                               window=window)
+    nk = sk // block_k
+    if window is not None:
+        # the grid's key axis starts at the first tile the window reaches
+        # and is as long as the longest such run
+        nk = _window_tiles(sq, sk, block_q, block_k, window)
+
+        def kv_map(i, j, t):
+            return (i, jnp.minimum(
+                t + _window_first_tile(j, block_q, block_k, offset, window),
+                ((j + 1) * block_q - 1 + offset) // block_k), 0)
+    elif causal:
         # clamp dead (fully-masked) inner steps to the last live tile: the
         # revisited block is already VMEM-resident, so masked steps cost no
         # DMA (pl.when(live) already skips their compute)
@@ -373,7 +450,7 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
     with jax.named_scope("core"):
         outs = pl.pallas_call(
             kernel,
-            grid=(b * h, sq // block_q, sk // block_k),
+            grid=(b * h, sq // block_q, nk),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
                 pl.BlockSpec((1, block_k, d), kv_map),
@@ -762,6 +839,20 @@ def flash_attention(q, k, v, causal: bool = False,
         return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
+def flash_attention_window(q, k, v, window: int,
+                           scale: Optional[float] = None):
+    """The causal flash forward of a window layer (query i sees keys
+    i - window < j <= i, bottom-right aligned where sq < sk): the forward
+    kernel alone, no VJP. A window under a gradient takes XLA's masked
+    attention (ops/attention.py), not this."""
+    s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    out, _ = flash_attention_fwd_pallas(q, k, v, True, s, need_lse=False,
+                                        window=window)
+    b, sq, h, _ = q.shape
+    with jax.named_scope("out"):
+        return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
+
+
 def _flash_fwd_rule(q, k, v, causal, scale):
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, lse = flash_attention_fwd_pallas(q, k, v, causal, s)
@@ -850,7 +941,8 @@ def _paged_ring(ps: int, kvh: int, dqk: int, dv: int, dtype) -> int:
 
 def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
                        s: int, h: int, kvh: int, ps: int, nbuf: int,
-                       scale: float, quantized: bool = False):
+                       scale: float, quantized: bool = False,
+                       window: Optional[int] = None):
     """One slot per grid step: score the slot's (S*H, Dqk) query rows
     against each of its live pages in turn and fold into the running
     online softmax (f32 m, l, acc carried by the loop). Scalar-prefetch
@@ -866,7 +958,16 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     page to fetch and [2] how many pages were consumed; page n of the
     stream lives in buffer n % nbuf. Fetches run nbuf - 1 pages ahead:
     the page fetched at the top of an iteration lands in the buffer the
-    previous iteration finished reading."""
+    previous iteration finished reading.
+
+    A `window` layer (one more scalar-prefetch ref, each slot's first live
+    page) keeps its pages in a RING: the table is as wide as the ring and
+    logical page t lives in column t % width. The slot's loop and the
+    stream's cursor start at the window's first page, and a key at or
+    below `frontier - window` is dead."""
+    if window is not None:
+        fp_ref, rest = rest[0], rest[1:]
+        ring = pt_ref.shape[1]
     if quantized:
         ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref, \
             k_buf, v_buf, sem, cur = rest
@@ -888,16 +989,21 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
 
         @pl.when(fs < nb)
         def _():
-            for c in page_copies(pt_ref[fs, fp], buf):
+            col = fp if window is None else fp % ring
+            for c in page_copies(pt_ref[fs, col], buf):
                 c.start()
             more = fp < lp_ref[fs]
             cur[0] = jnp.where(more, fs, fs + 1)
-            cur[1] = jnp.where(more, fp + 1, 0)
+            first = 0 if window is None \
+                else fp_ref[jnp.minimum(fs + 1, nb - 1)]
+            cur[1] = jnp.where(more, fp + 1, first)
 
     @pl.when(b == 0)
     def _prime():
         for i in range(3):
             cur[i] = 0
+        if window is not None:
+            cur[1] = fp_ref[0]
         for i in range(nbuf - 1):
             fetch_next(i)
 
@@ -935,7 +1041,7 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
         k = k_buf[buf]                                  # (ps, KVH, Dqk)
         v = v_buf[buf]                                  # (ps, KVH, Dv)
         if quantized:
-            page = pt_ref[b, t]
+            page = pt_ref[b, t if window is None else t % ring]
             k = k.astype(jnp.float32) * head_scales(ks_ref, page,
                                                     k.shape[-1])
             v = v.astype(jnp.float32) * head_scales(vs_ref, page,
@@ -950,6 +1056,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
             preferred_element_type=jnp.float32) * scale  # (rows, cols)
         j = t * ps + tok
         live = (j < rl) | ((j >= pp) & (j <= wp))
+        if window is not None:
+            live = live & (j > wp - window)
         sc = jnp.where(live & own_head, sc, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -960,7 +1068,7 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
         return m_new, l_new, acc
 
     _, l_fin, acc = jax.lax.fori_loop(
-        0, lp_ref[b] + 1, one_page,
+        0 if window is None else fp_ref[b], lp_ref[b] + 1, one_page,
         (jnp.full((rows, 1), NEG_INF, jnp.float32),
          jnp.zeros((rows, 1), jnp.float32),
          jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)))
@@ -974,7 +1082,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
 
 def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
                                row_len, prompt_pad, scale: float,
-                               k_scales=None, v_scales=None):
+                               k_scales=None, v_scales=None,
+                               window: Optional[int] = None):
     """Paged-pool attention: q (B, S, H, Dqk) against k_pages/v_pages
     ((P_pool, page_size, KVH, D)) through per-slot page tables
     ((B, pages_per_slot) int32) -> (B, S, H, Dv) context.
@@ -999,7 +1108,13 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     the score/context matmuls — per-page HBM traffic is the quantized
     bytes, and the full-width KV is never materialized anywhere. The
     einsum page-gather path applies the same dequant after its gather,
-    staying the parity oracle."""
+    staying the parity oracle.
+
+    ``window`` marks a window layer: position j is live only where
+    frontier - window < j as well, the page table is the slot's RING
+    (logical page t in column t % width: width pages hold any window of at
+    most (width - 1) * page_size + 1 positions) and the slot's pages are
+    streamed from the window's first, not from page 0."""
     b, s, h, dqk = q.shape
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[3]
@@ -1025,6 +1140,12 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     prefetch = [page_table.astype(jnp.int32), last_page,
                 write_pos.astype(jnp.int32), row_len.astype(jnp.int32),
                 prompt_pad.astype(jnp.int32)]
+    if window is not None:
+        assert (page_table.shape[1] - 1) * ps + 1 >= window, \
+            f"a ring of {page_table.shape[1]} pages of {ps} cannot hold a " \
+            f"window of {window}"
+        first_idx = jnp.maximum(jnp.min(write_pos, axis=1) - window + 1, 0)
+        prefetch.append((first_idx // ps).astype(jnp.int32))
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
@@ -1046,7 +1167,8 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     )
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
-                          nbuf=nbuf, scale=scale, quantized=quantized),
+                          nbuf=nbuf, scale=scale, quantized=quantized,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s * h, dv), q.dtype),
         # sequential: the cursor and the ring carry over from slot to slot

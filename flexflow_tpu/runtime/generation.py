@@ -36,6 +36,7 @@ import numpy as np
 from flexflow_tpu.ffconst import DataType, OperatorType
 from flexflow_tpu.ops.base import InputOp
 from flexflow_tpu.runtime.executor import resolve_tied_params
+from flexflow_tpu.runtime.kv_pool import op_keeps
 
 # ops whose forward treats every (batch, position) independently — safe to
 # run on a (B, 1, ...) decode slice exactly as on the full sequence
@@ -342,15 +343,20 @@ class Generator:
                         # pass: write_pos is (B, S) per-position. "impl"
                         # routes the attention body (einsum page-gather
                         # oracle vs the Pallas paged kernel) per engine.
+                        # an op that keeps a window has its group's ring
+                        # of pages for a table (runtime/kv_pool.py)
+                        keep = op_keeps(op)
+                        table = paged["page_table"] if keep is None \
+                            else paged["window_tables"][keep]
                         if tokens.shape[1] > 1:
                             out, nc = op.paged_verify_forward(
-                                p, xs, cache, paged["page_table"],
+                                p, xs, cache, table,
                                 paged["write_pos"], paged["rope_pos"],
                                 paged["row_len"], paged["prompt_pad"],
                                 impl=paged.get("impl"))
                         else:
                             out, nc = op.paged_decode_forward(
-                                p, xs, cache, paged["page_table"],
+                                p, xs, cache, table,
                                 paged["write_pos"], paged["rope_pos"],
                                 paged["row_len"], paged["prompt_pad"],
                                 impl=paged.get("impl"))

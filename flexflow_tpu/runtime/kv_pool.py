@@ -15,8 +15,13 @@ The engine asks the pool for pages and gives them back; it never names
 the free list or mutates the trie. The pool has no lock of its own:
 every method runs under the caller's engine lock (the trie keeps its
 ``prefix-cache`` condition for the tier publisher thread). One pool
-geometry, one kind of state (attention pages): a configuration whose
-layers keep different state says so here, not in the scheduler.
+geometry, and two kinds of attention page: an op says what of a sequence
+it keeps (`kv_keep()`: everything, or a window), and the pool groups its
+ops by that. The ops that keep everything share the one page table the
+free list, the leases and the trie are about; each window size has a
+`WindowPageGroup` beside it, a ring of pages a slot, with pool arrays of
+its own size. A configuration whose layers keep different state says so
+here, not in the scheduler.
 """
 
 from __future__ import annotations
@@ -683,6 +688,89 @@ class Lease:
         self.pages: List[int] = []
 
 
+def op_keeps(op):
+    """What of a sequence's rows attention op `op` keeps in the pool: None
+    for all of them, else its window (ops/attention.py `kv_keep`)."""
+    keep = getattr(op, "kv_keep", None)
+    return keep() if keep is not None else None
+
+
+class WindowPageGroup:
+    """The pages of the attention ops that keep a window of ``window``
+    positions: a RING of ``ring`` = ceil(window / page_size) + 1 pages a
+    slot, the page of sequence positions [t * page_size, (t + 1) *
+    page_size) in column ``t % ring`` of the slot's row of ``tables``.
+    That is the bound: whatever the context length, a slot holds at most
+    ``ring`` pages here, and the group's pool arrays are ``1 + slots *
+    ring`` pages (page 0 the scratch page of idle slots), so seating never
+    waits on this group and admission reserves by length for the global
+    group alone.
+
+    A slot takes pages as its context first reaches them (``seat`` for a
+    prefilled prompt, ``reach`` before each decode dispatch) and, once it
+    holds ``ring``, RECYCLES: the page whose rows have all left the window
+    becomes the page of the next ``page_size`` positions. The column keeps
+    its pool page through that (the device overwrites it row by row, and a
+    row is overwritten ``ring * page_size`` >= ``window`` positions after
+    it was written: only after it left the window), so a decode dispatch
+    of several steps may be handed the table of its last step. ``release``
+    returns everything with the slot. Invariant: ``free_pages + held_pages
+    == num_pages - 1``."""
+
+    def __init__(self, window: int, page_size: int, slots: int):
+        self.window = int(window)
+        self.page_size = int(page_size)
+        self.ring = -(-self.window // self.page_size) + 1
+        self.num_pages = 1 + int(slots) * self.ring
+        self._free = list(range(self.num_pages - 1, 0, -1))
+        self.tables = np.zeros((int(slots), self.ring), np.int32)
+        self._held = [0] * int(slots)
+        self._last = [-1] * int(slots)     # newest logical page a slot has
+        self.recycled = 0
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def held_pages(self) -> int:
+        return sum(self._held)
+
+    def held(self, slot: int) -> int:
+        return self._held[slot]
+
+    def seat(self, slot: int, length: int) -> None:
+        """Give ``slot`` the pages a prefill of ``length`` positions
+        leaves rows in: the page of the last position and the ``ring - 1``
+        before it, as far as the sequence has them."""
+        assert self._held[slot] == 0, f"window slot {slot} seated twice"
+        last = (int(length) - 1) // self.page_size
+        for t in range(max(0, last - self.ring + 1), last + 1):
+            self.tables[slot, t % self.ring] = self._free.pop()
+            self._held[slot] += 1
+        self._last[slot] = last
+
+    def reach(self, slot: int, position: int) -> None:
+        """Make ``slot``'s ring hold the page of sequence ``position``
+        (and of every position before it that a window still sees)."""
+        last = int(position) // self.page_size
+        while self._last[slot] < last:
+            self._last[slot] += 1
+            if self._held[slot] < self.ring:
+                self.tables[slot, self._last[slot] % self.ring] = \
+                    self._free.pop()
+                self._held[slot] += 1
+            else:
+                self.recycled += 1
+
+    def release(self, slot: int) -> None:
+        row = self.tables[slot]
+        self._free.extend(int(p) for p in row if p)
+        row[:] = 0
+        self._held[slot] = 0
+        self._last[slot] = -1
+
+
 class KVPagePool:
     """The one owner of the paged KV pool: the free list, the device
     arrays of the target (and draft) pool, the page movers and the trie.
@@ -709,6 +797,16 @@ class KVPagePool:
         self.page_size = int(page_size)
         self.pages_per_slot = int(pages_per_slot)
         self._page_import = page_import
+        # beside the one table of the ops that keep everything: a ring of
+        # pages a slot for each window size some op keeps
+        self.window_groups: Dict[int, WindowPageGroup] = {
+            w: WindowPageGroup(w, self.page_size, self.slots)
+            for w in sorted({op_keeps(op) for op in gen.attn_ops} - {None})}
+        if self.window_groups and (draft_gen is not None or prefix_cache):
+            raise ValueError(
+                "window layers keep a ring of pages a slot: no trie edge "
+                "and no draft pool shares it (prefix_cache=False, no "
+                "draft model)")
         self.pool = self._init_arrays(gen, kv_dtype)
         # the draft pool mirrors the target pool's page GEOMETRY, page
         # IDS and storage dtype (its own KVH/Dh): one allocator, one page
@@ -735,7 +833,7 @@ class KVPagePool:
         pool = {
             op.name: jax.tree.map(
                 lambda a: jax.device_put(a, repl),
-                op.init_paged_cache(self.num_pages, self.page_size,
+                op.init_paged_cache(self._op_pages(op), self.page_size,
                                     cdtype, kv_dtype=kv_dtype))
             for op in gen.attn_ops}
         # beside the pages: one recurrent state a slot for each op that
@@ -747,9 +845,36 @@ class KVPagePool:
             for op in getattr(gen, "state_ops", ())})
         return pool
 
+    def _op_pages(self, op) -> int:
+        """Pages in `op`'s pool arrays: the pool's, or its window group's."""
+        keep = op_keeps(op)
+        return self.num_pages if keep is None \
+            else self.window_groups[keep].num_pages
+
     @property
     def free_pages(self) -> int:
         return len(self._free_pages)
+
+    # ---- the window groups' rings ------------------------------------------
+
+    def seat_windows(self, slot: int, length: int) -> None:
+        for g in self.window_groups.values():
+            g.seat(slot, length)
+
+    def reach_windows(self, slot: int, position: int) -> None:
+        for g in self.window_groups.values():
+            g.reach(slot, position)
+
+    def release_windows(self, slot: int) -> None:
+        for g in self.window_groups.values():
+            g.release(slot)
+
+    def window_tables(self, slot=None) -> Dict[int, np.ndarray]:
+        """{window: ring table}: every slot's (slots, ring), or one slot's
+        (ring,) row; what a program of a model with window layers takes
+        beside the global page table."""
+        return {w: (g.tables if slot is None else g.tables[slot])
+                for w, g in self.window_groups.items()}
 
     # ---- reserve / commit / publish / release ----------------------------
 

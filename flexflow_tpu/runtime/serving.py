@@ -144,7 +144,7 @@ from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import (faultinject, flightrec, locks, profiler,
                                   telemetry)
 from flexflow_tpu.runtime.generation import Generator
-from flexflow_tpu.runtime.kv_pool import KVPagePool, Lease
+from flexflow_tpu.runtime.kv_pool import KVPagePool, Lease, op_keeps
 from flexflow_tpu.runtime.lora import LoraAdapterPool
 
 # process-wide engine ids: the default telemetry `replica` label when no
@@ -497,6 +497,30 @@ class ServingEngine:
                     "verification scores K+1 positions in one pass, which "
                     "a state advanced in place cannot undo; speculate_k "
                     "must be 0")
+        windowed = sorted({w for w in map(op_keeps, self.gen.attn_ops)
+                           if w is not None})
+        if windowed:
+            # a window layer keeps a ring of pages a slot (runtime/
+            # kv_pool.py WindowPageGroup): a prefix hit would need the
+            # window layers' last rows at the match point, a rejected
+            # draft position cannot be taken back out of a ring, and an
+            # interleaved chunk program does not seat one
+            named = next(op.name for op in self.gen.attn_ops
+                         if op_keeps(op) is not None)
+            for bad, what in (
+                    (enable_prefix, "prefix_cache must be False (a hit "
+                     "needs the window layers' rows at the match point, "
+                     "which no trie page holds)"),
+                    (self.speculate_k > 0, "speculate_k must be 0 (a "
+                     "ring of pages cannot take back rejected draft "
+                     "positions)"),
+                    (self.prefill_interleave_chunks > 0,
+                     "prefill_interleave_chunks must be 0 (the chunk "
+                     "programs do not seat a window layer's ring)")):
+                if bad:
+                    raise ValueError(
+                        f"{named} keeps a window of {windowed[0]} "
+                        f"positions: {what}")
         self.draft_gen = None
         if self.speculate_k > 0:
             if self.draft_model is None:
@@ -542,10 +566,18 @@ class ServingEngine:
             int(a.nbytes) for op in self.gen.state_ops
             for a in jax.tree_util.tree_leaves(self.kv.pool[op.name]))
         self._state_bytes_per_slot = self._state_pool_bytes // self.slots
+        # bytes a token of context takes: in the table of the ops that
+        # keep everything (a window group's arrays are a fixed size a slot)
+        self._window_pool_bytes = sum(
+            int(a.nbytes) for op in self.gen.attn_ops
+            if op_keeps(op) is not None
+            for a in jax.tree_util.tree_leaves(self.kv.pool[op.name]))
         self._kv_bytes_per_token = (
-            self._pool_bytes / (self.num_pages * self.page_size))
+            (self._pool_bytes - self._window_pool_bytes)
+            / (self.num_pages * self.page_size))
         self._bf16_bytes_per_token = sum(
-            op.cache_bytes_per_token() for op in self.gen.attn_ops)
+            op.cache_bytes_per_token() for op in self.gen.attn_ops
+            if op_keeps(op) is None)
 
         # decode attention impl over the paged pool: the per-engine
         # override wins, else FFConfig.paged_attention_impl; resolved
@@ -762,6 +794,9 @@ class ServingEngine:
         self._pages_touched = 0
         self._last_pages_touched = 0
         self._kv_read_bytes = 0
+        # pages held, summed over decode steps, by kind of table (a model
+        # with window layers: `_reach_windows`)
+        self._page_steps = {"global": 0, "window": 0}
         self._tick_seq = 0
         # (the kernel-tune counter baseline _ktune_base is snapshotted
         # in the impl-resolution block above, before the construction-
@@ -1135,6 +1170,7 @@ class ServingEngine:
         self.active[slot] = False
         self.poison[slot] = 0.0
         self.page_tables[slot, :] = 0   # scratch page: dead writes land there
+        self.kv.release_windows(slot)
         self.row_len[slot] = 0
         self.prompt_pad[slot] = 0
         self.emitted[slot] = 0
@@ -1159,6 +1195,7 @@ class ServingEngine:
         table = np.zeros((self.pages_per_slot,), np.int32)
         table[:len(req.lease.pages)] = req.lease.pages
         self.page_tables[slot] = table
+        self.kv.seat_windows(slot, req.prompt.size)
         self.row_len[slot] = req.prompt.size
         self.prompt_pad[slot] = req.bucket
         self.emitted[slot] = 0
@@ -1266,7 +1303,7 @@ class ServingEngine:
         return caches
 
     def _scatter_tail(self, gen, pool, caches, pages, p0: int = 0,
-                      slot=None):
+                      slot=None, length=None, rings=None):
         """COW scatter: write the contiguous cache's positions past
         ``p0`` into ``pages`` — the request's own fresh pages, never the
         shared ones. ``p0=0`` is the cold (whole-bucket) case. Routed
@@ -1280,6 +1317,15 @@ class ServingEngine:
             # the op's own scope (runtime/profiler.py scope_table): the
             # write is its attention's work, as in the walk
             with jax.named_scope(op.name):
+                keep = op_keeps(op)
+                if keep is not None:
+                    # a window layer: the pages that hold the prompt's
+                    # last window, into the slot's ring; the rest of its
+                    # rows travelled in the program and end with it
+                    out[op.name] = op.scatter_window_tail(
+                        pool[op.name], caches[op.name], length, rings[keep],
+                        impl=self.paged_prefill_impl)
+                    continue
                 out[op.name] = op.scatter_cache_tail(
                     pool[op.name], caches[op.name], p0, pages,
                     impl=self.paged_prefill_impl)
@@ -1337,9 +1383,12 @@ class ServingEngine:
 
         def prefill(params, state, tokens, length, pool, pages, poison,
                     temps, top_ps, top_ks, seeds, lora_pool, lora_pages,
-                    *slot):
-            # `slot`: one more argument of a model with recurrent-state
-            # ops, the pool row its prefilled state is seated in
+                    *seat):
+            # `seat` (`_seat_args`): for a model with recurrent-state ops
+            # the pool row its prefilled state is seated in, then for one
+            # with window layers the slot's ring tables
+            slot = seat[:1] if gen.state_ops else ()
+            rings = seat[-1] if self.kv.window_groups else None
             caches = gen.init_caches(1, bucket, cdtype)
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
@@ -1351,7 +1400,8 @@ class ServingEngine:
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
             return (tok, ok, self._scatter_tail(gen, pool, caches, pages,
-                                                slot=slot),
+                                                slot=slot, length=length,
+                                                rings=rings),
                     *self._routing_sum(routing))
 
         return jax.jit(prefill, donate_argnums=(4,))
@@ -1557,8 +1607,10 @@ class ServingEngine:
         def decode(params, state, pool, page_table, last_tok, write_pos0,
                    rope_pos0, row_len, prompt_pad, budget, poison,
                    temps, top_ps, top_ks, seeds, ctr0,
-                   lora_pool, lora_pages):
-            """`n_steps` slot-decode steps as ONE in-graph scan. Past a
+                   lora_pool, lora_pages, *rings):
+            """`n_steps` slot-decode steps as ONE in-graph scan. `rings`:
+            one more argument of a model with window layers, its groups'
+            ring tables as they will stand at the dispatch's last step. Past a
             slot's own budget (prompt_pad + its max_new_tokens) the write
             position and RoPE clamp to the final allocated slot — those
             steps only produce tokens the host truncates, and the
@@ -1579,6 +1631,8 @@ class ServingEngine:
                     "rope_pos": jnp.minimum(rope_pos0 + i, rope_cap),
                     "row_len": row_len, "prompt_pad": prompt_pad,
                     "impl": self.paged_attention_impl}
+                if rings:
+                    paged["window_tables"] = rings[0]
                 routing = [] if gen.dropless_moe_ops else None
                 logits, pool = gen._walk(params, state, tok[:, None],
                                          pool, None, paged=paged,
@@ -1753,6 +1807,11 @@ class ServingEngine:
                 f"{what}: {self.gen.state_ops[0].name} keeps a recurrent "
                 "state, which no page slab carries (export, import and "
                 "evacuation move pages of per-token rows only)")
+        if self.kv.window_groups:
+            raise NotImplementedError(
+                f"{what}: a window layer's rows live in a ring of pages a "
+                "slot, which no page slab carries (export, import and "
+                "evacuation move the global table's pages only)")
 
     def _state_slot_args(self, slot):
         """The trailing `slot` argument of the prefill programs of a model
@@ -1760,6 +1819,14 @@ class ServingEngine:
         if not self.gen.state_ops:
             return ()
         return (np.int32(slot),)
+
+    def _seat_args(self, slot):
+        """What a cold prefill program takes to seat a request beyond the
+        global table's pages: the state's pool row, then the window
+        groups' ring tables of the slot; nothing for most models."""
+        if not self.kv.window_groups:
+            return self._state_slot_args(slot)
+        return (*self._state_slot_args(slot), self.kv.window_tables(slot))
 
     def slot_state(self, slot: int) -> dict:
         """Host copies of one slot's recurrent state, {op name: the op's
@@ -1812,7 +1879,7 @@ class ServingEngine:
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page),
-                *self._state_slot_args(slot))
+                *self._seat_args(slot))
         span.annotate(program=program_name(key))
         self._note_moe_lowering(key, span)
         if self.draft_gen is not None:
@@ -2413,6 +2480,31 @@ class ServingEngine:
         self._kv_read_bytes += kv_read
         return kv_read
 
+    def _reach_windows(self, rope_pos, budget, k: int) -> Dict:
+        """Before a decode dispatch of `k` steps over window layers: every
+        live slot's rings are made to hold the page of its last step's
+        position (the program is handed the tables as they will stand
+        then: WindowPageGroup), and the dispatch's span gets the context
+        tokens ONE layer of each kind reads over its steps, summed over
+        the live slots: all of a sequence on a global layer, the window at
+        most on a window layer."""
+        cap = budget - self.prompt_pad + self.row_len - 1
+        # (slots, k): the sequence position each step's token takes
+        pos = np.minimum(rope_pos[:, None] + np.arange(k), cap[:, None])
+        for slot in np.flatnonzero(self.active):
+            self.kv.reach_windows(int(slot), int(pos[slot, -1]))
+        self._page_steps["global"] += k * (self.num_pages - 1
+                                           - self.kv.free_pages)
+        self._page_steps["window"] += k * sum(
+            g.held_pages for g in self.kv.window_groups.values())
+        seen = pos[self.active] + 1
+        counts = {"context_tokens_global": int(seen.sum())}
+        # one number for the window layers: the engine's cells have one
+        # window size (each further size adds its own tokens here)
+        counts["context_tokens_window"] = int(sum(
+            np.minimum(seen, w).sum() for w in self.kv.window_groups))
+        return counts
+
     def _decode_step(self, sampled: int):
         k = self.decode_chunk
         with self._span("decode_prepare"):
@@ -2443,6 +2535,9 @@ class ServingEngine:
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
+            if self.kv.window_groups:
+                attn.update(self._reach_windows(rope_pos, budget, k))
+                args += (self.kv.window_tables(),)
             if self.gen.state_ops:
                 # each step reads and writes every live slot's state once
                 attn["state_bytes"] = (2 * k * live
@@ -2935,6 +3030,30 @@ class ServingEngine:
         with self._lock:
             return self.kv.flush()
 
+    def _window_stats(self) -> Dict:
+        """What `stats()` says of a model with window layers: the pages
+        the live requests hold in the global table and in the window
+        groups' rings (page ids: each backs every op of its group), now
+        and summed over the decode steps so far, the rings' bound, and how
+        many times a ring's page went on to the next page_size positions
+        instead of a new page being taken."""
+        groups = self.kv.window_groups
+        if not groups:
+            return {}
+        return {
+            "kv_pages_held_global": self.num_pages - 1 - self.kv.free_pages,
+            "kv_pages_held_window": sum(g.held_pages
+                                        for g in groups.values()),
+            "kv_window_pages_recycled": sum(g.recycled
+                                            for g in groups.values()),
+            # the two gauges above summed over every decode step so far: a
+            # window's average holding is a delta of these over its steps
+            "kv_page_steps_global": self._page_steps["global"],
+            "kv_page_steps_window": self._page_steps["window"],
+            "kv_window_ring_pages": max(g.ring for g in groups.values()),
+            "kv_window_pool_bytes": self._window_pool_bytes,
+        }
+
     def stats(self) -> Dict:
         with self._lock:
             return self._stats_locked()
@@ -3038,6 +3157,7 @@ class ServingEngine:
             # reclaimable at refcount 0), shared those mounted by >1
             # live request right now
             "pages_in_use": self.num_pages - 1 - self.kv.free_pages,
+            **self._window_stats(),
             "kv_pages_cached": pc.pages if pc else 0,
             "kv_pages_shared": pc.shared_pages() if pc else 0,
             # tiered-cache observability (ISSUE 12): pages by tier (hbm

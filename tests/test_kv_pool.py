@@ -362,3 +362,110 @@ def test_flush_forget_and_namespace_flush_return_pages_to_the_free_list():
     pool.release(held)
     assert pool.flush() == 2 and pool.free_pages == PAGES - 1
     conserved(pool)
+
+
+# ---- window groups: a ring of pages a slot beside the global table --------
+
+from flexflow_tpu.runtime.kv_pool import WindowPageGroup  # noqa: E402
+
+
+class _WindowOp:
+    """An attention op as the pool sees it: a name, what it keeps, and
+    pool arrays of as many pages as it is given."""
+
+    def __init__(self, name, keep):
+        self.name, self._keep = name, keep
+
+    def kv_keep(self):
+        return self._keep
+
+    def init_paged_cache(self, num_pages, page_size, cdtype, kv_dtype=None):
+        return {"k": np.zeros((num_pages, page_size, 1, 2), np.float32)}
+
+
+def window_pool(prefix_cache=False, draft=None, slots=3):
+    gen = SimpleNamespace(
+        attn_ops=[_WindowOp("attn_global_0", None),
+                  _WindowOp("attn_window_1", 5),
+                  _WindowOp("attn_window_2", 5)],
+        _compute_dtype=lambda: np.float32, model=_NO_OPS.model)
+    return KVPagePool(gen, draft, PAGES, PS, pages_per_slot=4,
+                      kv_dtype=None, prefix_cache=prefix_cache,
+                      host_pages=0, page_import=None, slots=slots)
+
+
+@pytest.mark.parametrize("window,page,ring", [(5, 2, 4), (4, 4, 2),
+                                              (3, 8, 2), (128, 128, 2),
+                                              (9, 4, 4)])
+def test_a_window_group_never_holds_more_than_its_ring(window, page, ring):
+    """Whatever the context length: a prefill of any length seats at most
+    `ring` = ceil(window / page) + 1 pages, decoding on recycles them, and
+    release returns all of them."""
+    g = WindowPageGroup(window, page, slots=2)
+    assert g.ring == ring and g.num_pages == 1 + 2 * ring
+    for length in (1, page - 1, page, page + 1, window, 7 * page + 3, 1000):
+        g.seat(0, length)
+        assert g.held(0) == min(ring, (length - 1) // page + 1)
+        assert g.free_pages + g.held_pages == g.num_pages - 1
+        before = g.recycled
+        for position in range(length, length + 5 * ring * page):
+            g.reach(0, position)
+            assert g.held(0) <= ring
+            # the page of every position the window still sees is in its
+            # column, and no two columns share a pool page
+            row = g.tables[0]
+            held = row[row > 0]
+            assert len(set(held.tolist())) == held.size == g.held(0)
+            for at in range(max(0, position - window + 1), position + 1):
+                assert row[(at // page) % ring] > 0
+        turned = (length + 5 * ring * page - 1) // page - (length - 1) // page
+        took = g.held(0) - min(ring, (length - 1) // page + 1)
+        assert g.recycled - before == turned - took
+        g.release(0)
+        assert g.held(0) == 0 and not g.tables[0].any()
+        assert g.free_pages == g.num_pages - 1
+
+
+def test_window_groups_slots_do_not_share_pages():
+    g = WindowPageGroup(5, 2, slots=3)
+    for slot, length in enumerate((3, 40, 7)):
+        g.seat(slot, length)
+    for step in range(30):
+        for slot, length in enumerate((3, 40, 7)):
+            g.reach(slot, length + step)
+    pages = g.tables[g.tables > 0]
+    assert len(set(pages.tolist())) == pages.size == g.held_pages == 12
+    g.release(1)
+    assert g.held_pages == 8 and g.free_pages == 4
+    with pytest.raises(AssertionError, match="seated twice"):
+        g.seat(0, 3)
+
+
+def test_the_pool_groups_its_ops_by_what_they_keep():
+    """The global op's arrays are the pool's size and answer to the free
+    list; the two window ops share ONE group, whose arrays are a ring a
+    slot; seating the rings takes nothing from the free list."""
+    pool = window_pool()
+    assert sorted(pool.window_groups) == [5]
+    g = pool.window_groups[5]
+    assert pool.pool["attn_global_0"]["k"].shape[0] == PAGES
+    assert pool.pool["attn_window_1"]["k"].shape[0] \
+        == pool.pool["attn_window_2"]["k"].shape[0] == g.num_pages \
+        == 1 + pool.slots * 4
+    free = pool.free_pages
+    pool.seat_windows(1, 9)
+    pool.reach_windows(1, 30)
+    assert pool.free_pages == free and g.held(1) == 4
+    assert pool.window_tables()[5].shape == (pool.slots, 4)
+    assert (pool.window_tables(1)[5] > 0).all()
+    pool.release_windows(1)
+    assert g.held_pages == 0
+
+
+@pytest.mark.parametrize("kw,what", [
+    (dict(prefix_cache=True), "prefix_cache=False"),
+    (dict(draft=SimpleNamespace(attn_ops=[])), "no\\s+draft model"),
+])
+def test_a_pool_with_window_groups_refuses_a_trie_and_a_draft_pool(kw, what):
+    with pytest.raises(ValueError, match=what):
+        window_pool(**kw)

@@ -589,3 +589,160 @@ def test_prefill_tune_table_roundtrip(tmp_path, ff):
             os.environ.pop("FF_KERNEL_TUNE_TABLE", None)
         else:
             os.environ["FF_KERNEL_TUNE_TABLE"] = old
+
+
+# ---- a window layer's ring of pages ---------------------------------------
+#
+# A window layer keeps a RING of ceil(window / page) + 1 pages a slot: the
+# page of sequence positions [t * page, (t + 1) * page) in column t % ring,
+# rows addressed by sequence position (no bucket pad). The kernel starts a
+# slot's loop and its page stream at the window's first page.
+
+WINDOW_CASES = {
+    # window: (ring, the slots' sequence positions), page size 4
+    3: (2, [0, 2, 3, 4, 9, 23]),            # smaller than a page
+    4: (2, [3, 4, 7, 8, 21, 0]),            # a page exactly
+    9: (4, [0, 5, 8, 12, 30, 47]),          # two pages and a row
+}
+
+
+def _ring_positions(pos, ring, page):
+    """(B, ring * page) the sequence position each row of the gathered ring
+    holds: column c holds the newest logical page congruent to c."""
+    last = (pos // page)[:, None]
+    col = np.arange(ring)[None, :]
+    logical = last - (last - col) % ring
+    return (logical[:, :, None] * page
+            + np.arange(page)[None, None, :]).reshape(pos.size, -1)
+
+
+def _window_oracle(q, pool, table, pos, window, scale):
+    from flexflow_tpu.ops.attention import page_dequantize
+
+    b, s, h, d = q.shape
+    page, kvh = pool["k"].shape[1], pool["k"].shape[2]
+    gk, gv = pool["k"][table], pool["v"][table]
+    if "k_scale" in pool:
+        gk = page_dequantize(gk, pool["k_scale"][table])
+        gv = page_dequantize(gv, pool["v_scale"][table])
+    gk = gk.reshape(b, -1, kvh, gk.shape[-1])
+    gv = gv.reshape(b, -1, kvh, gv.shape[-1])
+    at = _ring_positions(pos, table.shape[1], page)
+    live = (at >= 0) & (at <= pos[:, None]) & (at > pos[:, None] - window)
+    qg = q.reshape(b, s, kvh, h // kvh, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(jnp.asarray(live)[:, None, None, None, :], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, gv).reshape(b, s, h, -1)
+
+
+def _window_inputs(window, seed, quantized=False):
+    ring, positions = WINDOW_CASES[window]
+    rs = np.random.RandomState(seed)
+    page, h, kvh, d = 4, 4, 2, 16
+    b = len(positions)
+    n_pages = 1 + b * ring
+    kf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
+    vf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
+    if quantized:
+        from flexflow_tpu.ops.attention import page_quantize, page_scale
+
+        ks, vs = page_scale(kf, 127.0), page_scale(vf, 127.0)
+        pool = {"k": page_quantize(kf, ks, 127.0, jnp.int8),
+                "v": page_quantize(vf, vs, 127.0, jnp.int8),
+                "k_scale": ks, "v_scale": vs}
+    else:
+        pool = {"k": jnp.asarray(kf), "v": jnp.asarray(vf)}
+    table = rs.permutation(np.arange(1, n_pages)).reshape(b, ring) \
+        .astype(np.int32)
+    pos = np.asarray(positions, np.int32)
+    q = jnp.asarray(rs.randn(b, 1, h, d), jnp.float32)
+    return q, pool, table, pos
+
+
+def _run_window_kernel(q, pool, table, pos, window, scale=0.29):
+    zero = jnp.zeros_like(jnp.asarray(pos))
+    return paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table),
+        jnp.asarray(pos)[:, None], zero, zero, scale,
+        k_scales=pool.get("k_scale"), v_scales=pool.get("v_scale"),
+        window=window)
+
+
+@pytest.mark.parametrize("depth", [2, 8])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("window", sorted(WINDOW_CASES))
+def test_window_kernel_matches_the_oracle(monkeypatch, window, quantized,
+                                          depth):
+    """Windows smaller than, equal to and larger than a page; slots at
+    position 0, inside the first window, on a page edge, one past it, and
+    after the ring has wrapped many times; the stream's buffers at two
+    depths."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_PAGED_RING_MAX", depth)
+    q, pool, table, pos = _window_inputs(window, 41, quantized)
+    out = _run_window_kernel(q, pool, table, pos, window)
+    want = _window_oracle(q, pool, table, pos, window, 0.29)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+@pytest.mark.parametrize("window", sorted(WINDOW_CASES))
+def test_window_kernel_never_reads_a_page_below_the_window(window):
+    """Poison: a column of the ring whose page lies wholly below the window
+    (or was never written: a sequence shorter than its ring) is NaN, and
+    the output is the oracle's on the clean pool."""
+    q, pool, table, pos = _window_inputs(window, 43)
+    want = _window_oracle(q, pool, table, pos, window, 0.29)
+    ring, page = table.shape[1], 4
+    at = _ring_positions(pos, ring, page).reshape(pos.size, ring, page)
+    seen = ((at >= 0) & (at <= pos[:, None, None])
+            & (at > pos[:, None, None] - window)).any(-1)
+    dead = table[~seen]
+    assert dead.size > 0
+    poisoned = {n: pool[n].at[jnp.asarray(dead)].set(jnp.nan)
+                for n in ("k", "v")}
+    out = _run_window_kernel(q, poisoned, table, pos, window)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+def test_window_kernel_idle_slots_read_the_scratch_page():
+    q, pool, table, pos = _window_inputs(3, 45)
+    table[:], pos[:] = 0, 0
+    out = _run_window_kernel(q, pool, table, pos, 3)
+    want = _window_oracle(q, pool, table, pos, 3, 0.29)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+@pytest.mark.parametrize("name", ["ragged-live-pages", "one-live-page",
+                                  "quantized"])
+def test_a_window_that_sees_everything_is_the_global_kernel_bit_for_bit(
+        name):
+    """With `window=None` the kernel takes no new operand (a static
+    parameter: the call it was). A window wider than any context over the same table (a ring as wide
+    as the table: column t % width is column t) walks the same pages from
+    page 0 and masks nothing more: the same bits."""
+    q, pool, table, wp, row_len, pad, _ = _stream_inputs(name, 29)
+    # sequence positions without a bucket pad: the window path's addressing
+    zero = np.zeros_like(row_len)
+    assert wp.max() < (table.shape[1] - 1) * 4 + 1
+    plain = paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table), jnp.asarray(wp),
+        jnp.asarray(zero), jnp.asarray(zero), 0.29,
+        k_scales=pool.get("k_scale"), v_scales=pool.get("v_scale"))
+    wide = paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table), jnp.asarray(wp),
+        jnp.asarray(zero), jnp.asarray(zero), 0.29,
+        k_scales=pool.get("k_scale"), v_scales=pool.get("v_scale"),
+        window=(table.shape[1] - 1) * 4 + 1)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(wide))
+
+
+def test_a_ring_too_narrow_for_its_window_is_refused():
+    q, pool, table, pos = _window_inputs(3, 47)
+    with pytest.raises(AssertionError, match="cannot hold a window"):
+        _run_window_kernel(q, pool, table, pos, 9)
