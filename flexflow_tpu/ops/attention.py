@@ -747,7 +747,7 @@ class MultiHeadAttention(Op):
         return {"head_dim": self.qk_head_dim, "dtype": cache["k"].dtype,
                 "heads": self.num_heads}
 
-    def decode_span_counts(self, context):
+    def decode_span_counts(self, context, page_size=None):
         """Counts a decode dispatch adds to its span beside the engine's
         own: none for plain attention."""
         return {}

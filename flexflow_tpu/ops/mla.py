@@ -633,15 +633,26 @@ class LatentAttention(Op):
         which this op does not run."""
         return None
 
-    def decode_span_counts(self, context):
+    def decode_span_counts(self, context, page_size=None):
         """Host-side counts of one decode dispatch from the live rows'
         context lengths (an int array, one entry a row and step): bytes of
-        index keys read, tokens the selection keeps, tokens it saw."""
+        index keys read, tokens the selection keeps, tokens it saw; and,
+        given the pool's page size, the bytes the index kernel's block
+        stream moves for them (each row's pages rounded up to whole
+        blocks: over `index_read_bytes` it is the stream's over-fetch)."""
         ctx = np.asarray(context, np.int64)
-        return {"index_read_bytes": int(ctx.sum()) * self.index_head_dim * 2,
-                "dsa_selected_tokens": int(np.minimum(
-                    ctx, self.index_topk).sum()),
-                "dsa_context_tokens": int(ctx.sum())}
+        key = self.index_head_dim * 2
+        counts = {"index_read_bytes": int(ctx.sum()) * key,
+                  "dsa_selected_tokens": int(np.minimum(
+                      ctx, self.index_topk).sum()),
+                  "dsa_context_tokens": int(ctx.sum())}
+        if page_size is not None:
+            from flexflow_tpu.ops.pallas_kernels import dsa_index_block_tokens
+
+            block = dsa_index_block_tokens(page_size)
+            counts["index_streamed_bytes"] = int(
+                (-(-ctx // block)).sum()) * block * key
+        return counts
 
     def init_paged_cache(self, num_pages: int, page_size: int, dtype,
                          kv_dtype=None):
@@ -700,7 +711,9 @@ class LatentAttention(Op):
         """One decode step of every slot over the paged pools: append the
         token's latent row and index key at (page_table[b, write_pos //
         ps], write_pos % ps), then score, select and attend through the
-        page tables. `pallas`: the index kernel reads its pool in place and
+        page tables. `pallas`: the index kernel reads its pool in place, a
+        block of 8 pages a turn (so a slot's context is streamed rounded up
+        to whole blocks: `decode_span_counts`' `index_streamed_bytes`), and
         the core reads the selected latent rows, gathered; `einsum`: the
         slots' pages gathered into contiguous rows and the blocked XLA
         attention, the parity oracle."""

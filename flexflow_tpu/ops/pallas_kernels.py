@@ -1532,12 +1532,24 @@ def moe_expert_stream_pallas(x, gates, sizes, *weights):
 # the index pool in place and, of the latent pool, only the rows the
 # selection kept:
 #
-#   dsa_index_scores  built like `_paged_attn_kernel` (grid over slots, a loop
-#                     to the slot's last live page, page DMAs issued by hand
-#                     through a ring that runs ahead across slot boundaries):
-#                     a page's index keys (ps, dI) against the slot's J index
-#                     queries: I_s = sum_j w_j ReLU(qI_j . kI_s), one f32 row
-#                     of scores per slot, -inf where the live rule excludes s.
+#   dsa_index_scores  the grid is the slots, as in `_paged_attn_kernel`, but
+#                     a turn of the slot's loop takes a BLOCK of
+#                     _INDEX_BLOCK_PAGES = 8 pages, not one: one wait for the
+#                     block's 8 page copies (one semaphore a ring buffer,
+#                     counting bytes), 8 products of a page's index keys
+#                     (ps, dI), stationary in an MXU, with the slot's J index
+#                     queries pushed through, ReLU x w and the sum over J in
+#                     f32 (w broadcast along the lanes once a slot), one
+#                     (8, ps) store of I_s = sum_j w_j ReLU(qI_j . kI_s), -inf
+#                     where the live rule excludes s; then the 8 copies of the
+#                     block a ring's depth ahead, into the buffer just read.
+#                     The loop runs to the block of the slot's last live page;
+#                     what that block fetches past it (the table's scratch
+#                     page 0) is dead by the live rule. The ring holds whole
+#                     blocks and runs ahead across slot boundaries. The other
+#                     orientation (queries stationary, keys pushed) leaves J
+#                     along the lanes, and the sum over J becomes lane
+#                     reductions and a relayout (PERF.md section 6, PR 40).
 #   mla_paged_core_gathered
 #                     the H absorbed queries (H, W) against blocks of 128
 #                     SELECTED latent rows: one matmul scores all heads, the
@@ -1556,122 +1568,155 @@ def moe_expert_stream_pallas(x, gates, sizes, *weights):
 # the gather in front of it is an XLA fusion that no name marks.
 
 
-def _page_stream(pt_ref, lp_ref, hbm, buf, sem, cur, nbuf):
-    """The hand-issued page stream of one pool array: (prime, take). `cur`
-    (SMEM, kept across grid steps) holds [slot, page] of the next page to
-    fetch and [2] the pages consumed; page n of the stream lives in buffer
-    n % nbuf and fetches run nbuf - 1 pages ahead, across slots."""
+# pages of one index turn: their (1, ps) f32 score rows fill one sublane
+# tile of the output, so a turn ends in ONE unmasked store
+_INDEX_BLOCK_PAGES = 8
+
+
+def dsa_index_block_tokens(page_size: int) -> int:
+    """Tokens one turn of `dsa_index_scores` fetches and scores: a slot's
+    context is streamed in whole blocks of this many."""
+    return _INDEX_BLOCK_PAGES * page_size
+
+
+def _page_stream(pt_ref, lb_ref, hbm, buf, sem, cur, nbuf, g):
+    """The hand-issued page stream of one pool array, in blocks of g pages
+    (table columns [block * g, block * g + g)): (prime, take, refill). `cur`
+    (SMEM, kept across grid steps) holds [slot, block] of the next block to
+    fetch and [2] the blocks consumed; block n of the stream lives in buffer
+    n % nbuf, its g page copies signal that buffer's one semaphore, and
+    fetches run nbuf blocks ahead, across slots."""
     nb = pl.num_programs(0)
 
-    def copy(page, b):
-        return pltpu.make_async_copy(hbm.at[page], buf.at[b], sem.at[b])
-
     def fetch_next(b):
-        fs, fp = cur[0], cur[1]
+        fs, fb = cur[0], cur[1]
 
         @pl.when(fs < nb)
         def _():
-            copy(pt_ref[fs, fp], b).start()
-            more = fp < lp_ref[fs]
+            for i in range(g):
+                pltpu.make_async_copy(hbm.at[pt_ref[fs, fb * g + i]],
+                                      buf.at[b, i], sem.at[b]).start()
+            more = fb < lb_ref[fs]
             cur[0] = jnp.where(more, fs, fs + 1)
-            cur[1] = jnp.where(more, fp + 1, 0)
+            cur[1] = jnp.where(more, fb + 1, 0)
 
     def prime():
         @pl.when(pl.program_id(0) == 0)
         def _():
             for i in range(3):
                 cur[i] = 0
-            for i in range(nbuf - 1):
+            for i in range(nbuf):
                 fetch_next(i)
 
     def take():
-        """The next page of the stream, waited for: its ring buffer."""
+        """The next block of the stream, all g pages waited for at once (the
+        semaphore counts bytes): its ring buffer."""
         n = cur[2]
         cur[2] = n + 1
-        fetch_next((n + nbuf - 1) % nbuf)
-        b = n % nbuf
-        copy(0, b).wait()
+        b = jax.lax.rem(n, nbuf)
+        pltpu.make_async_copy(buf.at[b], buf.at[b], sem.at[b]).wait()
         return b
 
-    return prime, take
+    # refill(b) = fetch_next(b), called once the turn has read buffer b: the
+    # block a ring's depth ahead goes into the buffer just given back
+    return prime, take, fetch_next
 
 
-def _stream_ring(ps: int, width: int, dtype) -> int:
-    """Page buffers of a (ps, width) page stream: 2 .. _PAGED_RING_MAX."""
-    page = ps * -(-width // LANES) * LANES * jnp.dtype(dtype).itemsize
-    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // page)))
+def _stream_ring(g: int, ps: int, width: int, dtype) -> int:
+    """Block buffers of a stream of g (ps, width) pages a block: 2 ..
+    _PAGED_RING_MAX."""
+    block = g * ps * -(-width // LANES) * LANES * jnp.dtype(dtype).itemsize
+    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // block)))
 
 
-def _live_columns(t, ps, rl, pp, wp):
-    """(1, ps) token index of page t's columns and the live rule on them."""
-    j = t * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    return j, (j < rl) | ((j >= pp) & (j <= wp))
+def _live_columns(page0, g, ps, rl, pp, wp):
+    """(g, ps): the live rule on the columns (tokens) of pages [page0,
+    page0 + g)."""
+    j = (page0 + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 0)) * ps \
+        + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
+    return (j < rl) | ((j >= pp) & (j <= wp))
 
 
-def _dsa_index_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
-                      k_hbm, o_ref, k_buf, sem, cur, *, ps: int, nbuf: int):
+def _dsa_index_kernel(pt_ref, lb_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
+                      k_hbm, o_ref, k_buf, sem, cur, *, ps: int, nbuf: int,
+                      g: int):
     b = pl.program_id(0)
-    prime, take = _page_stream(pt_ref, lp_ref, k_hbm, k_buf, sem, cur, nbuf)
+    prime, take, refill = _page_stream(pt_ref, lb_ref, k_hbm, k_buf, sem, cur,
+                                       nbuf, g)
     prime()
     q = q_ref[0]                                        # (J, dI)
-    w = w_ref[0]                                        # (J, 1) f32
+    # across the lanes once a slot, not once a page
+    w = jnp.broadcast_to(w_ref[0], (q.shape[0], ps))    # (J, ps) f32
     rl, pp, wp = rl_ref[b], pp_ref[b], wp_ref[b]
     o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (g, ps), 0)
 
-    def one_page(t, carry):
+    def one_block(t, carry):
         buf = take()
-        k = k_buf[buf].astype(q.dtype)                  # (ps, dI)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        sc = jnp.zeros((g, ps), jnp.float32)
+        # one product a page, each the same code: a column's arithmetic
+        # does not depend on where in the block its page sits
+        for i in range(g):
+            k = k_buf[buf, i].astype(q.dtype)           # (ps, dI)
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32)
+            sc = jnp.where(row == i, jnp.sum(jnp.maximum(s, 0.0) * w, axis=0,
+                                             keepdims=True), sc)
+        page0 = pl.multiple_of(t * g, g)
+        # a page the block fetched past the slot's last live one (scratch
+        # page 0 in the table) is dead by the same rule as a dead column.
         # + 0.0: a -0.0 would order below +0.0 in the threshold's key
-        sc = jnp.sum(jnp.maximum(s, 0.0) * w, axis=0, keepdims=True) + 0.0
-        _, live = _live_columns(t, ps, rl, pp, wp)
-        o_ref[0, pl.ds(t, 1), :] = jnp.where(live, sc, -jnp.inf)
+        live = _live_columns(page0, g, ps, rl, pp, wp)
+        o_ref[0, pl.ds(page0, g), :] = jnp.where(live, sc + 0.0, -jnp.inf)
+        refill(buf)
         return carry
 
-    jax.lax.fori_loop(0, lp_ref[b] + 1, one_page, 0)
+    jax.lax.fori_loop(0, lb_ref[b] + 1, one_block, 0)
 
 
-def _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps):
-    last = jnp.maximum(write_pos, row_len - 1) // ps
-    return [page_table.astype(jnp.int32), last.astype(jnp.int32),
-            write_pos.astype(jnp.int32), row_len.astype(jnp.int32),
-            prompt_pad.astype(jnp.int32)]
-
-
+# inline=True: as `moe_expert_stream_pallas`, so a program's layers share one
+# trace and one Mosaic lowering of the kernel
+@functools.partial(jax.jit, inline=True)
 def dsa_index_scores_pallas(qi, w, ki_pages, page_table, write_pos, row_len,
                             prompt_pad):
     """Index scores of one decode step, read from the pool in place: qi
     (B, J, dI), w (B, J) f32, ki_pages (P_pool, ps, dI), page tables
     (B, P) -> (B, P * ps) f32, I_s = sum_j w_j ReLU(qi_j . kI_s) at the
     slot's live positions (j < row_len or prompt_pad <= j <= write_pos)
-    and -inf elsewhere."""
+    and -inf elsewhere. A turn of the kernel takes a block of
+    _INDEX_BLOCK_PAGES pages; a table that is no whole number of blocks is
+    padded with the scratch page 0 and the output cut back."""
     b, jn, di = qi.shape
     ps = ki_pages.shape[1]
     p = page_table.shape[1]
-    nbuf = _stream_ring(ps, di, ki_pages.dtype)
+    g = _INDEX_BLOCK_PAGES
+    p_pad = -(-p // g) * g
+    nbuf = _stream_ring(g, ps, di, ki_pages.dtype)
+    last = jnp.maximum(write_pos, row_len - 1) // ps
+    prefetch = [jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, p_pad - p))),
+                (last // g).astype(jnp.int32), write_pos.astype(jnp.int32),
+                row_len.astype(jnp.int32), prompt_pad.astype(jnp.int32)]
 
     def slot_map(bi, *_):
         return (bi, 0, 0)
 
-    prefetch = _dsa_prefetch(page_table, write_pos, row_len, prompt_pad, ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=(b,),
         in_specs=[pl.BlockSpec((1, jn, di), slot_map),
                   pl.BlockSpec((1, jn, 1), slot_map),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, p, ps), slot_map),
-        scratch_shapes=[pltpu.VMEM((nbuf, ps, di), ki_pages.dtype),
+        out_specs=pl.BlockSpec((1, p_pad, ps), slot_map),
+        scratch_shapes=[pltpu.VMEM((nbuf, g, ps, di), ki_pages.dtype),
                         pltpu.SemaphoreType.DMA((nbuf,)),
                         pltpu.SMEM((3,), jnp.int32)])
     out = pl.pallas_call(
-        functools.partial(_dsa_index_kernel, ps=ps, nbuf=nbuf),
+        functools.partial(_dsa_index_kernel, ps=ps, nbuf=nbuf, g=g),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, p, ps), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, p_pad, ps), jnp.float32),
         compiler_params=_compiler_params(("arbitrary",)),
         interpret=_interpret(), name="dsa_index_scores",
     )(*prefetch, qi, w.astype(jnp.float32)[:, :, None], ki_pages)
-    return out.reshape(b, p * ps)
+    return out[:, :p].reshape(b, p * ps)
 
 
 def _mla_gathered_kernel(ns_ref, q_ref, g_ref, o_ref, *, blk: int,

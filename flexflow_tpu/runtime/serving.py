@@ -830,10 +830,11 @@ class ServingEngine:
         none_live = np.zeros((0, 1), np.int64)
         self._counting_attn_ops = [
             op for op in self.gen.attn_ops
-            if op.decode_span_counts(none_live)]
+            if op.decode_span_counts(none_live, self.page_size)]
         self._attn_counts: Dict[str, int] = {}
         for op in self._counting_attn_ops:
-            self._attn_counts.update(op.decode_span_counts(none_live))
+            self._attn_counts.update(
+                op.decode_span_counts(none_live, self.page_size))
         self._prefix_hit_tokens = 0
         self._prefix_prompt_tokens = 0
 
@@ -2524,7 +2525,7 @@ class ServingEngine:
                 seen = (np.minimum(write_pos[:, None] + np.arange(k),
                                    (budget - 1)[:, None]) + 1)[self.active]
                 for op in self._counting_attn_ops:
-                    attn.update(op.decode_span_counts(seen))
+                    attn.update(op.decode_span_counts(seen, self.page_size))
                 for name, v in attn.items():
                     self._attn_counts[name] += v
             # per-slot draw counters: the next token's index is exactly

@@ -166,6 +166,9 @@ def test_serving_engine_emits_the_reference_argmax(ff, impl):
     st = eng.stats()
     assert st["dsa_context_tokens"] > st["dsa_selected_tokens"] > 0
     assert st["index_read_bytes"] == st["dsa_context_tokens"] * 32 * 2
+    # what the index kernel's stream moved: whole blocks of 8 pages of 8
+    assert st["index_streamed_bytes"] > st["index_read_bytes"]
+    assert st["index_streamed_bytes"] % (64 * 32 * 2) == 0
     # page bytes: (lat 128 + index key 32) x f32, 3 layers
     assert st["kv_bytes_per_token"] == (128 + 32) * 4 * 3
 
@@ -329,6 +332,118 @@ def test_pallas_decode_kernels_match_the_einsum_oracle(case):
         # what the cut takes of the run is its lowest positions
         assert chosen.sum() == TOPK and 0 < tied.size < 22
         np.testing.assert_array_equal(tied, np.arange(tied.size))
+
+
+# ---- the index kernel's block turn (ops/pallas_kernels.py) ------------------
+
+G = 8       # pallas_kernels._INDEX_BLOCK_PAGES: pages a turn fetches and scores
+
+# name -> (page size, table width, [(row_len, prompt_pad, write_pos)] a slot;
+# an idle slot is (0, 0, 0)). Contexts end inside their last page unless the
+# name says otherwise, and prompts leave a hole of padding before prompt_pad.
+INDEX_CASES = {
+    "one_page": (8, 20, [(3, 4, 6)]),
+    "g_minus_1_pages": (8, 20, [(40, 48, 8 * (G - 1) - 3), (0, 0, 2)]),
+    "g_pages_to_the_last_column": (8, 20, [(40, 48, 8 * G - 1), (5, 8, 9)]),
+    "g_plus_1_pages": (8, 20, [(0, 0, 8 * G), (61, 64, 8 * G + 5)]),
+    "two_g_plus_3_pages": (8, 20, [(100, 104, 8 * (2 * G + 3) - 2)]),
+    "idle_slot_between_live_ones": (8, 20, [(100, 104, 8 * (2 * G + 3) - 2),
+                                            (0, 0, 0),
+                                            (0, 0, 8 * G + 1),
+                                            (0, 0, 0)]),
+    # the cell's table width (260 pages, no whole number of blocks), a slot
+    # that fills it to its last column beside a short one
+    "full_table_of_260_pages": (4, 260, [(1000, 1024, 4 * 260 - 1),
+                                         (9, 12, 13)]),
+}
+
+
+def index_case(name, tie_pages=()):
+    """Random index keys and queries (float32, 4 heads of 16) for
+    INDEX_CASES[name]: every slot's live pages are pages of its own, every
+    table entry past them and the pad are the scratch page 0, which holds
+    NaN (fetched by a block's tail, never to be scored); `tie_pages` of
+    slot 0 hold one key row repeated."""
+    from flexflow_tpu.ops.pallas_kernels import dsa_index_scores_pallas
+
+    ps, width, slots = INDEX_CASES[name]
+    rs = np.random.RandomState(len(name))
+    rl, pp, wp = (np.asarray(c, np.int32) for c in zip(*slots))
+    pages = np.maximum(wp, rl - 1) // ps + 1
+    pool = rs.randn(1 + pages.sum(), ps, 16).astype(np.float32)
+    pool[0] = np.nan
+    table = np.zeros((len(slots), width), np.int32)
+    ids = rs.permutation(np.arange(1, 1 + pages.sum()))
+    for b, n in enumerate(pages):
+        table[b, :n], ids = ids[:n], ids[n:]
+    for t in tie_pages:
+        pool[table[0, t]] = pool[table[0, tie_pages[0]], 0]
+    qi = rs.randn(len(slots), 4, 16).astype(np.float32)
+    w = rs.randn(len(slots), 4).astype(np.float32)
+    got = np.asarray(dsa_index_scores_pallas(
+        *(jnp.asarray(a) for a in (qi, w, pool, table, wp, rl, pp))))
+    # the oracle: I_s = sum_j w_j ReLU(qI_j . kI_s) over the gathered pages,
+    # -inf off the live rule
+    keys = pool[table].reshape(len(slots), width * ps, 16)
+    sc = np.einsum("bj,bjs->bs", w, np.maximum(
+        np.einsum("bjd,bsd->bjs", qi, np.nan_to_num(keys)), 0.0))
+    j = np.arange(width * ps)[None]
+    live = (j < rl[:, None]) | ((j >= pp[:, None]) & (j <= wp[:, None]))
+    return got, np.where(live, sc, -np.inf), live
+
+
+@pytest.mark.parametrize("name", sorted(INDEX_CASES))
+def test_index_kernel_scores_whole_blocks_like_the_oracle(name):
+    """`dsa_index_scores` (interpret mode) fetches and scores a slot's
+    context in blocks of G pages: contexts of 1, G - 1, G, G + 1 and
+    2G + 3 pages, idle slots the stream runs ahead across, a table that is
+    no whole number of blocks filled to its last column. Finite exactly
+    where the oracle is, also in the pages a block fetched past the slot's
+    last live one (scratch page 0, here NaN) and in the table's pad."""
+    got, want, live = index_case(name)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(np.isneginf(got), ~live)
+    np.testing.assert_array_equal(np.isfinite(got), live)
+    # float32 both sides, sums of 16 and 4 products of order 1
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5, rtol=0)
+
+
+def test_index_kernel_ties_are_one_value_across_a_block_boundary():
+    """Pages G - 2 .. G + 1 of slot 0 hold one key row repeated: their
+    live columns, the last two pages of the first block and the first two
+    of the second, read ONE value bit for bit (the threshold's cut inside a
+    run of ties counts on it)."""
+    got, want, live = index_case("two_g_plus_3_pages",
+                                 tie_pages=(G - 2, G - 1, G, G + 1))
+    run = got[0, 8 * (G - 2):8 * (G + 2)]
+    assert live[0, 8 * (G - 2):8 * (G + 2)].all() and len(set(run)) == 1
+    np.testing.assert_allclose(run, want[0, 8 * (G - 2):8 * (G + 2)],
+                               atol=1e-5, rtol=0)
+    assert len(set(got[0, :8 * (G - 2)])) > 8
+
+
+@pytest.mark.parametrize("contexts, whole", [
+    ([[1, 2], [8 * 5 * G - 1, 8 * 5 * G]], False),
+    ([[8 * G, 8 * 3 * G]], True)])
+def test_streamed_index_bytes_round_contexts_up_to_whole_blocks(contexts,
+                                                                whole):
+    """`decode_span_counts` with the pool's page size: what the block
+    stream moves is every (row, step)'s context rounded up to G pages,
+    never under `index_read_bytes` and equal to it at whole blocks; without
+    a page size the count is left out."""
+    from flexflow_tpu.ops.pallas_kernels import (_INDEX_BLOCK_PAGES,
+                                                 dsa_index_block_tokens)
+
+    assert _INDEX_BLOCK_PAGES == G and dsa_index_block_tokens(8) == 8 * G
+    op, _, _ = attention_op()
+    ctx = np.asarray(contexts)
+    got = op.decode_span_counts(ctx, page_size=8)
+    blocks = sum(-(-int(c) // (8 * G)) for c in ctx.ravel())
+    assert got["index_streamed_bytes"] == blocks * 8 * G * 32 * 2
+    assert got["index_read_bytes"] == ctx.sum() * 32 * 2
+    assert (got["index_streamed_bytes"] == got["index_read_bytes"]) == whole
+    assert got["index_streamed_bytes"] >= got["index_read_bytes"]
+    assert "index_streamed_bytes" not in op.decode_span_counts(ctx)
 
 
 def core_case(case: str):
