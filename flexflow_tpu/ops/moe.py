@@ -65,18 +65,23 @@ are (L, F) / (F, L), and the gate-weighted sum goes back through
 input. A held share projects ITS experts' sum, so the shares of a layer add
 up to the layer.
 
-A held share under a gradient (`training=True`, fewer experts held than
-routed over): of the N*k assignments only about held / num_experts land
-here, and they sort to the front. The grouped lowering then gathers and
-multiplies `held_rows_cap` sorted rows a pass (HELD_ROWS_SLACK x the even
-share) instead of all N*k, and scatter-adds their gate-weighted results into
-the tokens, in as many passes as the held rows need (`MoE._held_passes`: a
-`while_loop` on the count, one pass under any routing within the slack of
-even). No token is dropped whatever the routing, and a step pays for the
-passes its rows fill. The backward runs the same passes with each pass's
-forward recomputed (a `custom_vjp`), so nothing of N*k rows, and nothing of a
-pass, is kept between the forward and the backward. Forward-only calls (a
-prefill chunk, a decode step) keep the all-rows form they had.
+A held share (fewer experts held than routed over) in the grouped lowering,
+with a gradient or without (fit(), predict, a prefill chunk of more than
+MOE_STREAM_MAX_ROWS rows): of the N*k assignments only about held /
+num_experts land here, and they sort to the front. The grouped lowering then
+gathers and multiplies `held_rows_cap` sorted rows a pass (HELD_ROWS_SLACK x
+the even share) instead of all N*k, and scatter-adds their gate-weighted
+results into the tokens, in as many passes as the held rows need
+(`MoE._held_passes`: a `while_loop` on the count, one pass under any routing
+within the slack of even). No token is dropped whatever the routing, a call
+pays for the passes its rows fill, and no array of N*k rows is in such a
+program. The backward runs the same passes with each pass's forward
+recomputed (a `custom_vjp`), so nothing of N*k rows, and nothing of a pass, is
+kept between the forward and the backward. A layer that holds every expert
+keeps the all-rows form (there the compact form's rows ARE all N*k), and the
+streamed lowering (a decode step, a bucket of few rows) sorts nothing.
+`expert_rows` counts what the grouped products were given: passes x
+`held_rows_cap` for a held share, N*k for the all-rows form.
 """
 
 from __future__ import annotations
@@ -133,8 +138,8 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-# a held share's sorted rows kept under a gradient: this many times the rows
-# an even routing sends here, rounded up to whole tiles of rows
+# a held share's sorted rows a pass: this many times the rows an even routing
+# sends here, rounded up to whole tiles of rows
 HELD_ROWS_SLACK = 2.0
 HELD_ROWS_TILE = 256
 
@@ -278,7 +283,7 @@ class MoE(Op):
 
     def forward(self, params, xs, *, training=False, rng=None,
                 capacity=None, row_mask=None, routing=None,
-                lowerings=None, group_sizes=None):
+                lowerings=None, group_sizes=None, expert_rows=None):
         """Dropless op: `row_mask` (bool, the shape of x without its last
         dim; None = every row live) gives masked rows group size 0 and
         output 0, so the free slots of a decode batch stream no expert;
@@ -288,7 +293,11 @@ class MoE(Op):
         'streamed' or 'grouped', the lowering this call took (a static
         fact, known while tracing); `group_sizes`, if a list, receives the
         int32 (held experts,) rows each held expert got (a traced value:
-        the train step sums, counts and maxes it into its metrics).
+        the train step sums, counts and maxes it into its metrics);
+        `expert_rows`, if a list, receives the rows this call's grouped
+        products were given: the static N*k of the all-rows form, a held
+        share's passes x `held_rows_cap` (a traced int32), 0 where the
+        call streamed.
 
         Capacity op: `capacity` overrides the build-time training capacity. The
         inference path (runtime/generation.py) passes N (the slab's token
@@ -307,7 +316,7 @@ class MoE(Op):
         if self.dropless:
             return self._forward_dropless(params, t, orig_shape, row_mask,
                                           routing, training, lowerings,
-                                          group_sizes)
+                                          group_sizes, expert_rows)
         with jax.named_scope("route"):
             logits = t @ params["router"].astype(t.dtype)   # (N, E)
             gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -464,7 +473,8 @@ class MoE(Op):
         return (jax.nn.silu(t @ g) * (t @ u)) @ d
 
     def _forward_dropless(self, params, t, orig_shape, row_mask, routing,
-                          training, lowerings, group_sizes=None):
+                          training, lowerings, group_sizes=None,
+                          expert_rows=None):
         """No capacity, no dropped token: `_route`'s top-k, the held
         experts over exactly the (token, expert) pairs that chose them
         (grouped or streamed, see the module docstring), each token's
@@ -487,8 +497,12 @@ class MoE(Op):
         if took == "streamed":
             y, sizes = self._experts_streamed(params, x, top_g, top_e, live)
         else:
-            y, sizes = self._experts_grouped(params, x, top_g, top_e, live,
-                                             training)
+            y, sizes = self._experts_grouped(params, x, top_g, top_e, live)
+        if expert_rows is not None:
+            cap = 0 if took == "streamed" else self._rows_a_pass(N)
+            # whole passes (`_held_passes`), counted where the sizes are
+            expert_rows.append(cap if cap in (0, N * k)
+                               else cap * -(-jnp.sum(sizes) // cap))
         if self.latent_dim:
             with jax.named_scope("latent"):
                 y = y.astype(t.dtype) @ params["w_latent_out"].astype(t.dtype)
@@ -509,13 +523,11 @@ class MoE(Op):
                 y = y + self._shared_expert(params, t)
         return [y.reshape(orig_shape), aux.astype(jnp.float32)]
 
-    def _experts_grouped(self, params, t, top_g, top_e, live,
-                         training=False):
+    def _experts_grouped(self, params, t, top_g, top_e, live):
         """(y (N, D) f32, sizes (E,) int32): the N*k assignments
         stable-sorted by expert, grouped matmuls over exactly those rows,
-        unsorted, gate-weighted and summed per token. A held share under a
-        gradient works on `held_rows_cap` sorted rows a pass (module
-        docstring)."""
+        unsorted, gate-weighted and summed per token. A held share works on
+        `held_rows_cap` sorted rows a pass (module docstring)."""
         with jax.named_scope("route"):
             flat_e, top_g, masked = self._held_assignments(top_g, top_e,
                                                            live)
@@ -524,7 +536,7 @@ class MoE(Op):
                 flat_e, length=self.held_count).astype(jnp.int32)
         with jax.named_scope("experts"):
             return self._grouped_rows(params, t, top_g, order, sizes,
-                                      masked, training), sizes
+                                      masked), sizes
 
     def _held_assignments(self, top_g, top_e, live):
         """(flat_e (N*k,), top_g, masked): each assignment's expert as this
@@ -551,10 +563,18 @@ class MoE(Op):
             top_g = top_g * live[:, None]
         return flat_e, top_g, masked
 
-    def _grouped_rows(self, params, t, top_g, order, sizes, masked,
-                      training):
+    def _rows_a_pass(self, n_tokens: int) -> int:
+        """Sorted rows the grouped lowering works on at a time: all N*k
+        where the layer holds every expert, a held share's
+        `held_rows_cap`."""
+        if self.held_count == self.num_experts:
+            return n_tokens * self.k
+        return held_rows_cap(n_tokens, self.k, self.held_count,
+                             self.num_experts)
+
+    def _grouped_rows(self, params, t, top_g, order, sizes, masked):
         """y (N, D) f32 of `_experts_grouped` from the sorted order."""
-        E, k = self.held_count, self.k
+        k = self.k
         N, D = t.shape
 
         def all_rows():
@@ -572,9 +592,7 @@ class MoE(Op):
             return jnp.einsum("nk,nkd->nd", top_g,
                               out[back].reshape(N, k, D).astype(jnp.float32))
 
-        cap = N * k
-        if training and E != self.num_experts:
-            cap = held_rows_cap(N, k, E, self.num_experts)
+        cap = self._rows_a_pass(N)
         if cap == N * k:
             return all_rows()
         # whole passes of `cap` sorted rows; a padding row is past every

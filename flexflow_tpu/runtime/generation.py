@@ -262,7 +262,8 @@ class Generator:
     def _walk(self, params, state, tokens, caches, pos, last_only=False,
               rope_pos=None, row_lengths=None, prompt_len=None,
               chunk_start=None, skip_tail=False, gather_last=False,
-              paged=None, lora=None, routing=None, lowerings=None):
+              paged=None, lora=None, routing=None, lowerings=None,
+              expert_rows=None):
         """Interpret the graph on a (B, S) token slab. pos=None means
         prefill (positions 0..S-1, fills cache); otherwise S == 1 and pos
         is the traced cache slot of the token. last_only=True narrows the
@@ -274,8 +275,9 @@ class Generator:
         column -1, and decode steps get per-row RoPE positions + a pad-
         slot cache mask (see MultiHeadAttention.decode_forward).
         `routing`, if a list, collects each dropless MoE op's (2,) routing
-        counts of this walk, and `lowerings` the lowering each of those
-        calls took (ops/moe.py)."""
+        counts of this walk, `lowerings` the lowering each of those calls
+        took and `expert_rows` the rows its grouped products were given
+        (ops/moe.py)."""
         bf16 = self._compute_dtype() == jnp.bfloat16
 
         def to_compute(a):
@@ -403,6 +405,7 @@ class Generator:
                                 chunk_start, gather_last)
                             kwargs["routing"] = routing
                             kwargs["lowerings"] = lowerings
+                            kwargs["expert_rows"] = expert_rows
                         else:
                             # a capacity op drops nothing at inference
                             # when its buffer holds the whole slab (see
@@ -466,7 +469,8 @@ class Generator:
         return at[None, :] < row_lengths[:, None]
 
     def _prefill(self, params, state, tokens, caches, row_lengths,
-                 prefill_chunk, lora=None, routing=None, lowerings=None):
+                 prefill_chunk, lora=None, routing=None, lowerings=None,
+                 expert_rows=None):
         """Whole-prompt prefill, or chunked (`prefill_chunk` > 0 and the
         prompt longer than it): each chunk writes its k/v and attends the
         static prefix slice under the same causal rule — score memory is
@@ -498,7 +502,7 @@ class Generator:
             return self._walk(params, state, tokens, caches, None,
                               last_only=True, row_lengths=row_lengths,
                               prompt_len=s0, lora=lora, routing=routing,
-                              lowerings=lowerings)
+                              lowerings=lowerings, expert_rows=expert_rows)
         starts = list(range(0, s0, prefill_chunk))
         if row_lengths is not None:
             for st in starts:
@@ -508,24 +512,26 @@ class Generator:
                     params, state, tokens[:, st:st + prefill_chunk],
                     caches, None, chunk_start=st, skip_tail=True, lora=lora,
                     row_lengths=row_lengths, routing=routing,
-                    lowerings=lowerings)
+                    lowerings=lowerings, expert_rows=expert_rows)
                 caches, tokens = close(caches, tokens)
             tok_last = jnp.take_along_axis(
                 tokens, (row_lengths - 1)[:, None], axis=1)      # (B, 1)
             return self._walk(params, state, tok_last, caches, None,
                               last_only=True, row_lengths=row_lengths,
                               gather_last=True, lora=lora, routing=routing,
-                              lowerings=lowerings)
+                              lowerings=lowerings, expert_rows=expert_rows)
         for st in starts[:-1]:
             _, caches = self._walk(
                 params, state, tokens[:, st:st + prefill_chunk], caches,
                 None, chunk_start=st, skip_tail=True, lora=lora,
-                routing=routing, lowerings=lowerings)
+                routing=routing, lowerings=lowerings,
+                expert_rows=expert_rows)
             caches, tokens = close(caches, tokens)
         st = starts[-1]
         return self._walk(params, state, tokens[:, st:], caches, None,
                           last_only=True, chunk_start=st, lora=lora,
-                          routing=routing, lowerings=lowerings)
+                          routing=routing, lowerings=lowerings,
+                          expert_rows=expert_rows)
 
     # ---- sampling ----------------------------------------------------------
 

@@ -822,6 +822,16 @@ class ServingEngine:
         # / prefill dispatches of programs whose calls all streamed
         self._moe_took: Dict = {}
         self._moe_streamed_dispatches = 0
+        # the rows the grouped expert products of the run-to-completion
+        # prefills were given (ops/moe.py `expert_rows`: a held share's
+        # passes x its rows a pass, counted inside the program; the N*k of
+        # a layer that holds every expert, a static fact its program's own
+        # list {program key: [rows of each such call]} collects while it is
+        # traced), and those prefills' assignments: rows / assignments is
+        # what the products are handed for each row that needs them
+        self._moe_static_rows: Dict = {}
+        self._moe_expert_rows = 0
+        self._moe_prefill_assignments = 0
         # what the attention ops count of their own decode dispatches
         # (`decode_span_counts`: a selecting attention's index bytes, kept
         # and seen tokens; nothing for plain attention, whose engines then
@@ -1349,12 +1359,26 @@ class ServingEngine:
             logits, temps, top_ps, top_ks, seeds, jnp.zeros_like(seeds)), ok
 
     @staticmethod
-    def _routing_sum(routing):
+    def _routing_sum(routing, expert_rows=(), static_rows=None):
         """The extra trailing output of a serve program whose model has
         dropless MoE ops: their int32 (2,) routing counts [assignments,
         experts hit] summed over the ops one walk ran.
-        Nothing for any other model, whose programs are unchanged."""
-        return (sum(routing),) if routing else ()
+        Nothing for any other model, whose programs are unchanged.
+        A prefill also collects `expert_rows`: what is counted on the
+        device (a held share's passes) rides as a third entry, what is
+        static goes to `static_rows`, the program's own list, so the
+        program of a model that holds every expert is the one it was."""
+        if not routing:
+            return ()
+        counts = sum(routing)
+        static = [r for r in expert_rows if isinstance(r, int)]
+        if static_rows is not None:
+            static_rows[:] = static     # the same again if traced again
+        if len(static) < len(expert_rows):
+            counted = sum(r for r in expert_rows if not isinstance(r, int))
+            counts = jnp.concatenate(
+                [counts, jnp.reshape(counted, (1,)).astype(counts.dtype)])
+        return (counts,)
 
     def _moe_took_list(self, key):
         """The list in which program `key` collects the lowering of each
@@ -1364,6 +1388,15 @@ class ServingEngine:
             return None
         took = self._moe_took[key] = []
         return took
+
+    def _moe_rows_list(self, key):
+        """The list in which prefill program `key` collects the static
+        `expert_rows` of its MoE calls as it is traced; None for a model
+        without such an op."""
+        if not self.gen.dropless_moe_ops:
+            return None
+        rows = self._moe_static_rows[key] = []
+        return rows
 
     def _note_moe_lowering(self, key, span):
         """A dispatch of an expert model's program `key` says on its span
@@ -1377,7 +1410,8 @@ class ServingEngine:
             self._moe_streamed_dispatches += streamed
             span.annotate(moe_streamed=streamed)
 
-    def _build_prefill(self, bucket: int, n_pages: int, took=None):
+    def _build_prefill(self, bucket: int, n_pages: int, took=None,
+                       static_rows=None):
         gen = self.gen
         cdtype = gen._compute_dtype()
         has_lora = self.lora_pool is not None
@@ -1394,20 +1428,22 @@ class ServingEngine:
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             routing = [] if gen.dropless_moe_ops else None
+            rows = []
             logits, caches = gen._prefill(params, state, tokens, caches,
                                           length, self.prefill_chunk,
                                           lora=lora, routing=routing,
-                                          lowerings=took)
+                                          lowerings=took, expert_rows=rows)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
             return (tok, ok, self._scatter_tail(gen, pool, caches, pages,
                                                 slot=slot, length=length,
                                                 rings=rings),
-                    *self._routing_sum(routing))
+                    *self._routing_sum(routing, rows, static_rows))
 
         return jax.jit(prefill, donate_argnums=(4,))
 
-    def _build_prefill_hit(self, bucket: int, full: int, took=None):
+    def _build_prefill_hit(self, bucket: int, full: int, took=None,
+                           static_rows=None):
         """Prefix-hit prefill: ``full`` cached pages are gathered
         READ-ONLY into the front of a contiguous per-request cache, the
         tail slab [full*ps, bucket) runs as one chunk_forward pass (each
@@ -1429,22 +1465,25 @@ class ServingEngine:
             caches = self._seed_prefix_caches(gen, bucket, p0, pool,
                                               prefix_pages)
             routing = [] if gen.dropless_moe_ops else None
+            rows = []
             # row_lengths on the tail walk: its attention does not read
             # it, a dropless MoE masks the padding rows by it
             _, caches = gen._walk(params, state, tokens_tail, caches,
                                   None, chunk_start=p0, skip_tail=True,
                                   lora=lora, row_lengths=length,
-                                  routing=routing, lowerings=took)
+                                  routing=routing, lowerings=took,
+                                  expert_rows=rows)
             logits, caches = gen._walk(params, state, tok_last, caches,
                                        None, last_only=True,
                                        row_lengths=length,
                                        gather_last=True, lora=lora,
-                                       routing=routing, lowerings=took)
+                                       routing=routing, lowerings=took,
+                                       expert_rows=rows)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
             return (tok, ok, self._scatter_tail(gen, pool, caches,
                                                 tail_pages, p0),
-                    *self._routing_sum(routing))
+                    *self._routing_sum(routing, rows, static_rows))
 
         return jax.jit(prefill, donate_argnums=(5,))
 
@@ -1845,8 +1884,9 @@ class ServingEngine:
                      slot: int = 0):
         """Dispatch one run-to-completion prefill of ``prompt`` into
         ``lease``'s pages, target then draft; returns the device values
-        ``(tok, ok, routed)`` and notes the target program's MoE lowering
-        on ``span``. A prefix hit gathers the matched pages
+        ``(tok, ok, routed)``, the program's static ``expert_rows``, and
+        notes the target program's MoE lowering on ``span``. A prefix hit
+        gathers the matched pages
         read-only and prefills only the tail slab [full*ps, bucket) into
         FRESH pages — the matched prefix's partial last page (tokens
         past full*ps) is re-materialized into the lease's own first tail
@@ -1867,7 +1907,8 @@ class ServingEngine:
             key = ("prefill_hit", bucket, full)
             tok, ok, kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_prefill_hit(
-                    bucket, full, self._moe_took_list(key)),
+                    bucket, full, self._moe_took_list(key),
+                    self._moe_rows_list(key)),
                 self.gen._params(), self.model.bn_state, padded,
                 np.asarray([[prompt[-1]]], np.int32), length, kv.pool,
                 prefix_pages, tail_pages, poison, *sampling,
@@ -1876,7 +1917,8 @@ class ServingEngine:
             key = ("prefill", bucket, n_prefill, self.prefill_chunk)
             tok, ok, kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_prefill(
-                    bucket, n_prefill, self._moe_took_list(key)),
+                    bucket, n_prefill, self._moe_took_list(key),
+                    self._moe_rows_list(key)),
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page),
@@ -1898,7 +1940,7 @@ class ServingEngine:
                     lambda: self._build_draft_prefill(bucket, n_prefill),
                     self.draft_gen._params(), self.draft_model.bn_state,
                     padded, kv.draft_pool, tail_pages)
-        return tok, ok, routed
+        return tok, ok, routed, sum(self._moe_static_rows.get(key, ()))
 
     def _scan_rows(self, bucket: int) -> Dict:
         """`scan_rows` of a prefill span: the rows the recurrent ops' chunked
@@ -2051,7 +2093,7 @@ class ServingEngine:
                 self._prefix_hit_tokens += req.prefix_tokens
                 self._prefix_prompt_tokens += int(req.prompt.size)
                 self._seed_slot(slot, req, poison)
-                tok, ok, routed = self._run_prefill(
+                tok, ok, routed, rows = self._run_prefill(
                     req.prompt, req.bucket, lease,
                     self._sampling_args_1(req), adapter_page, poison, psp,
                     slot)
@@ -2061,8 +2103,14 @@ class ServingEngine:
                     ok_host, tok_host = bool(ok[0]), int(tok[0])
                 psp.annotate(ok=ok_host)
                 if routed:
-                    psp.annotate(assignments=int(routed[0][0]),
-                                 experts_hit=int(routed[0][1]))
+                    # a held share's passes are counted on the device
+                    assigned, hit, *counted = (int(v) for v in routed[0])
+                    rows += sum(counted)
+                    psp.annotate(assignments=assigned, experts_hit=hit,
+                                 expert_rows=rows)
+                    if rows:
+                        self._moe_expert_rows += rows
+                        self._moe_prefill_assignments += assigned
                 if self._tm_on:
                     req.decode_span = telemetry.tracer().begin(
                         "decode", trace_id=req.trace_id,
@@ -2257,7 +2305,7 @@ class ServingEngine:
                 if not lease.need:
                     return last             # already fully published
                 self.kv.commit(lease)
-                _, ok, _ = self._run_prefill(
+                _, ok, *_ = self._run_prefill(
                     prompt, bucket, lease, self._sampling_args_greedy(),
                     apage, np.float32(0.0))
                 ok = bool(np.asarray(ok)[0])
@@ -3120,6 +3168,13 @@ class ServingEngine:
             # decode and run-to-completion prefill dispatches whose
             # program's MoE calls all took the expert-stream kernel
             "moe_streamed_dispatches": self._moe_streamed_dispatches,
+            # over the run-to-completion prefills whose experts took the
+            # grouped lowering: the rows its products were given and the
+            # assignments that needed them (a held share: about its slack,
+            # 2, where every pass is the first; a layer that holds every
+            # expert: 1 but for bucket padding)
+            "moe_expert_rows": self._moe_expert_rows,
+            "moe_prefill_assignments": self._moe_prefill_assignments,
             "ttft_p50_ms": round(pct(0.50) * 1e3, 3),
             "ttft_p99_ms": round(pct(0.99) * 1e3, 3),
             "free_pages": self.kv.free_pages,

@@ -566,31 +566,46 @@ def test_a_share_has_the_uncut_layers_gradients_of_its_experts(first):
     assert not np.asarray(got["score_bias"]).any()
 
 
+def _only(p, first, count):
+    """The uncut layer's weights with every expert outside first .. first +
+    count - 1 silenced (`w_down` zero): the layer that holds every expert
+    (the all-rows form, never `_held_passes`) then gives that share's
+    output, and its gradients of the share's own weights."""
+    keep = (jnp.arange(p["w_down"].shape[0]) >= first) \
+        & (jnp.arange(p["w_down"].shape[0]) < first + count)
+    return {**p, "w_down": p["w_down"] * keep[:, None, None]}
+
+
 @pytest.mark.parametrize("slack,passes", [(2.0, "one pass"),
                                           (0.25, "several passes")])
 def test_held_share_under_training_equals_the_all_rows_form(monkeypatch,
                                                             slack, passes):
     """training=True works on `held_rows_cap` sorted rows a pass; a step
     whose held assignments outnumber the cap (slack 0.25) takes more passes
-    and drops nothing: both give the forward-only (all-rows) form's output
-    and gradients."""
+    and drops nothing: both give the output and the gradients of the uncut
+    layer with the other experts silenced, whose all-rows form passes
+    through no `_held_passes` (a forward-only call of the share itself
+    does, since PR 41)."""
     monkeypatch.setattr(moe_mod, "HELD_ROWS_TILE", 8)
     monkeypatch.setattr(moe_mod, "HELD_ROWS_SLACK", slack)
     op, _, x = moe_op(held=(8, 4))
-    p = _share(moe_op()[1], 8, 4)
+    whole, uncut, _ = moe_op()
+    p = _share(uncut, 8, 4)
     cap = moe_mod.held_rows_cap(96, 4, 4, 32)
     sizes = []
 
-    def pulled(params, training):
-        out = op.forward(params, [x], training=training,
-                         group_sizes=sizes)[0]
+    def pulled(params, layer, training):
+        out = layer.forward(params, [x], training=training,
+                            group_sizes=sizes)[0]
         return jnp.sum(out * jnp.cos(out)), out
 
-    (_, want), want_g = jax.value_and_grad(pulled, has_aux=True)(p, False)
-    (_, got), got_g = jax.value_and_grad(pulled, has_aux=True)(p, True)
-    assert cap < 96 * 4
-    assert (int(sizes[0].sum()) <= cap) == (passes == "one pass")
-    assert int(sizes[0].sum()) > 2 * cap or passes == "one pass"
+    (_, want), uncut_g = jax.value_and_grad(pulled, has_aux=True)(
+        _only(uncut, 8, 4), whole, False)
+    want_g = _share(uncut_g, 8, 4)
+    (_, got), got_g = jax.value_and_grad(pulled, has_aux=True)(p, op, True)
+    assert cap < 96 * 4 and sizes[0].shape == (32,)
+    assert (int(sizes[1].sum()) <= cap) == (passes == "one pass")
+    assert int(sizes[1].sum()) > 2 * cap or passes == "one pass"
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
     for w, g in want_g.items():
         np.testing.assert_allclose(
