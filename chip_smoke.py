@@ -46,8 +46,7 @@ REHEARSAL_PASSED = 64
 
 # environment switches that reroute around kernels or change what `auto`
 # resolves to: the smoke runs with none of them
-REROUTING_ENV = ("FF_FLASH_MAX_SEQ", "FF_FORCE_FLASH_ATTENTION",
-                 "FF_KERNEL_TUNE_TABLE")
+REROUTING_ENV = ("FF_FLASH_MAX_SEQ", "FF_FORCE_FLASH_ATTENTION")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -729,7 +728,7 @@ def main(argv=None):
         import jax
 
         from flexflow_tpu import _env
-        from flexflow_tpu.search import cost_db, kernel_tune
+        from flexflow_tpu.search import cost_db
 
         if rehearsal:
             _env.force_cpu_devices(4)
@@ -752,10 +751,7 @@ def main(argv=None):
         cache_dir = _env.resolve_compilation_cache()
         entries0 = _env.compilation_cache_entries(cache_dir)
         log(f"compile cache {cache_dir}: {entries0} entries at start")
-        table = kernel_tune.default_table_path()
-        log(f"kernel tune table {table}: "
-            f"{'found' if os.path.exists(table) else 'none'}; cost DB: "
-            f"{cost_db.resolve_path() or 'off'}")
+        log(f"cost DB: {cost_db.resolve_path() or 'off'}")
 
     entries = functools.partial(_env.compilation_cache_entries, cache_dir)
     with phase("build", entries):

@@ -3,8 +3,8 @@ tests and dry runs, and the one resolver that places jax's persistent
 compilation cache.
 
 One implementation of each serves the package import hook
-(FLEXFLOW_FORCE_CPU_DEVICES), the repo-root entry scripts (chip_smoke.py,
-bench.py, __graft_entry__.py), the launcher, and the C API
+(FLEXFLOW_FORCE_CPU_DEVICES), the entry scripts (chip_smoke.py,
+benchmark/run.py, __graft_entry__.py), the launcher, and the C API
 (FFT_JAX_PLATFORMS/FFT_NUM_CPU_DEVICES).
 """
 

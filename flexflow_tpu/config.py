@@ -270,22 +270,6 @@ class FFConfig:
     # fuses into each consuming matmul, so the HBM weight read per decode
     # step — the decode bottleneck — is the quantized bytes.
     serve_weight_dtype: str = "native"
-    # decode/verify attention over the paged KV pool:
-    #   "auto"   — Pallas paged-attention kernel on a TPU backend (page-
-    #              table lookup inside the kernel, only a slot's live
-    #              pages stream through VMEM), einsum page-gather
-    #              elsewhere; a measured winner persisted by
-    #              search/kernel_tune.py tune_paged_attention for this
-    #              engine's exact shape+dtype overrides the backend
-    #              default (measured costs beat heuristics)
-    #   "pallas" — force the kernel everywhere (off-TPU that needs
-    #              FF_PALLAS_INTERPRET=1, which the CPU suite and CI set
-    #              to execute the real kernel code path)
-    #   "einsum" — force the page-gather oracle (bitwise the dense-cache
-    #              attention) — the parity baseline
-    # Greedy serving streams are token-identical under either impl
-    # (tests/test_pallas_paged.py pins it).
-    paged_attention_impl: str = "auto"
     # ---- disaggregated fleet + tiered prefix cache (ISSUE 12) ----
     # pinned host-memory second tier under the radix prefix cache
     # (runtime/serving.py): refcount-0 KV pages evicted under pool
@@ -350,7 +334,7 @@ class FFConfig:
     # and the trace ring records per-request / per-step spans — the
     # substrate stats()/health() export through. "off": span creation
     # returns a shared no-op and every observe/inc short-circuits at one
-    # predicate (the bench's telemetry_overhead_pct control arm).
+    # predicate.
     telemetry: str = "on"
     # serve a Prometheus text endpoint (/metrics), a JSON snapshot
     # (/metrics.json) and the Chrome trace ring (/trace.json) on
@@ -622,10 +606,6 @@ class FFConfig:
             raise ValueError(
                 f"preempt_deadline_s={self.preempt_deadline_s}: must be "
                 f"> 0 (the evacuation race needs a budget)")
-        if self.paged_attention_impl not in ("auto", "pallas", "einsum"):
-            raise ValueError(
-                f"paged_attention_impl={self.paged_attention_impl!r}: "
-                f"must be 'auto', 'pallas' or 'einsum'")
         if self.kv_cache_dtype not in ("native", "bf16", "int8", "fp8"):
             raise ValueError(
                 f"kv_cache_dtype={self.kv_cache_dtype!r}: must be "
@@ -778,11 +758,6 @@ class FFConfig:
                             "prompt's prefix into this many contiguous "
                             "shards across the prefill tier (0 = off, "
                             ">= 2 = shard count)")
-        p.add_argument("--paged-attention-impl", type=str, default="auto",
-                       choices=("auto", "pallas", "einsum"),
-                       help="decode attention over the paged pool: "
-                            "Pallas kernel vs einsum page-gather "
-                            "(auto = pallas on TPU)")
         p.add_argument("--kv-cache-dtype", type=str, default="native",
                        choices=("native", "bf16", "int8", "fp8"),
                        help="paged KV pool storage dtype (int8/fp8: "
@@ -927,7 +902,6 @@ class FFConfig:
             serve_replica_roles=args.serve_replica_roles,
             prefill_interleave_chunks=args.prefill_interleave_chunks,
             seq_parallel_shards=args.seq_parallel_shards,
-            paged_attention_impl=args.paged_attention_impl,
             kv_cache_dtype=args.kv_cache_dtype,
             serve_weight_dtype=args.serve_weight_dtype,
             telemetry=args.telemetry,

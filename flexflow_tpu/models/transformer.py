@@ -6,7 +6,7 @@ MHA + residual + 2xdense blocks, defaults hidden 512 / 16 heads / 12 layers /
 seq 128, MSE regression head, SGD 0.01).
 
 `build_encoder_classifier` is the modern variant (pre-LN, GELU FFN, causal
-option) used as the flagship bench model.
+option) used as the flagship training model.
 """
 
 from __future__ import annotations

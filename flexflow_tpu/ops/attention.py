@@ -39,10 +39,10 @@ from flexflow_tpu.ops.base import Op, WeightSpec
 BLOCKWISE_SEQ_THRESHOLD = 4096
 
 
-def resolve_paged_attention_impl(impl=None, config=None) -> str:
-    """Resolve an ``auto|pallas|einsum`` request (per-engine override
-    first, then FFConfig.paged_attention_impl) to the concrete decode
-    attention path:
+def resolve_paged_attention_impl(impl=None) -> str:
+    """Resolve an ``auto|pallas|einsum`` request (the engine's
+    ``paged_attention_impl`` argument; None is ``auto``) to the concrete
+    path of the paged decode attention and of the prefill page write:
 
       * ``pallas`` — the paged-attention kernel (ops/pallas_kernels.py
         paged_attention_fwd_pallas): page-table lookup inside the grid,
@@ -55,8 +55,6 @@ def resolve_paged_attention_impl(impl=None, config=None) -> str:
       * ``auto`` — pallas on a TPU backend, einsum elsewhere.
     """
     if impl in (None, "", "auto"):
-        impl = getattr(config, "paged_attention_impl", "auto") or "auto"
-    if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "einsum"
     if impl not in ("pallas", "einsum"):
         raise ValueError(
@@ -741,12 +739,6 @@ class MultiHeadAttention(Op):
         to hold: all of them, or the window."""
         return min(context, self.window) if self.window else context
 
-    def paged_kernel_shape(self, cache):
-        """What the kernel autotuner's table is keyed by (None from an op
-        whose paged kernels it does not hold)."""
-        return {"head_dim": self.qk_head_dim, "dtype": cache["k"].dtype,
-                "heads": self.num_heads}
-
     def decode_span_counts(self, context, page_size=None):
         """Counts a decode dispatch adds to its span beside the engine's
         own: none for plain attention."""
@@ -806,8 +798,8 @@ class MultiHeadAttention(Op):
                              row_len, prompt_pad, impl):
         """Shared attention body of the paged decode/verify paths: q
         (B, S, H, Dh) against the updated pool through the per-slot page
-        tables, write_pos (B, S) per-position frontiers. Two impls behind
-        FFConfig.paged_attention_impl (resolve_paged_attention_impl):
+        tables, write_pos (B, S) per-position frontiers. Two impls
+        (resolve_paged_attention_impl):
 
           * ``einsum`` — gather the slot's pages into a logical
             (B, L_max, KVH, Dh) cache and run _grouped_cache_attention:
@@ -825,8 +817,7 @@ class MultiHeadAttention(Op):
             the einsum path to kernel tolerance (accumulation order
             differs); greedy token streams are pinned identical by
             tests/test_pallas_paged.py and test_quantized_serving.py."""
-        resolved = resolve_paged_attention_impl(
-            impl, getattr(self.model, "config", None))
+        resolved = resolve_paged_attention_impl(impl)
         ck, cv = cache["k"], cache["v"]
         if resolved == "pallas":
             from flexflow_tpu.ops.pallas_kernels import \
@@ -911,8 +902,7 @@ class MultiHeadAttention(Op):
                 ring, ((pos // page_size) % r)[:, None], axis=1)[:, 0]
             cache = self._paged_append(cache, kh[:, 0], vh[:, 0], page_ids,
                                        pos % page_size)
-        resolved = resolve_paged_attention_impl(
-            impl, getattr(self.model, "config", None))
+        resolved = resolve_paged_attention_impl(impl)
         if resolved == "pallas":
             from flexflow_tpu.ops.pallas_kernels import \
                 paged_attention_fwd_pallas

@@ -628,11 +628,6 @@ class LatentAttention(Op):
         return (self.lat_width
                 + (self.index_head_dim if self.indexed else 0)) * 2
 
-    def paged_kernel_shape(self, cache):
-        """None: the kernel autotuner's table holds the K/V paged kernels,
-        which this op does not run."""
-        return None
-
     def decode_span_counts(self, context, page_size=None):
         """Host-side counts of one decode dispatch from the live rows'
         context lengths (an int array, one entry a row and step): bytes of
@@ -725,8 +720,7 @@ class LatentAttention(Op):
             offs = write_pos % ps
             cache = {n: cache[n].at[page_ids, offs].set(
                 pr[n][:, 0].astype(cache[n].dtype)) for n in ("lat", "ki")}
-        if resolve_paged_attention_impl(
-                impl, getattr(self.model, "config", None)) != "pallas":
+        if resolve_paged_attention_impl(impl) != "pallas":
             b = page_table.shape[0]
             with jax.named_scope("gather"):
                 rows = {n: cache[n][page_table].reshape(

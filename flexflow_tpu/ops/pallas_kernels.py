@@ -73,10 +73,7 @@ def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
 def _pick_block(seq: int, want: int) -> int:
     """Largest tile size <= want that divides seq (the guard in
     attention.flash_eligible only promises 128-divisibility, so a 512
-    default must degrade for e.g. seq 640). This is the STATIC heuristic:
-    the only one there is wherever the measured-cost autotune table
-    (search/kernel_tune.py) has no entry for the shape, which is every
-    machine that never ran the tuner."""
+    default must degrade for e.g. seq 640)."""
     for b in (want, 512, 256, 128, 64, 32, 16, 8):
         if b <= min(want, seq) and seq % b == 0:
             return b
@@ -91,27 +88,12 @@ _CHUNK = 256
 _WINDOW_BLOCK = 512
 
 
-def _resolve_blocks(kernel: str, sq: int, sk: int, d: int, dtype,
-                    want_q, want_k, *, batch: int = 1, heads: int = 1,
-                    causal: bool = True):
+def _resolve_blocks(sq: int, sk: int, want_q, want_k):
     """(block_q, block_k) of a flash kernel call: the tile one grid step
-    DMAs. want_q/want_k = None (the public API's default) means AUTO: the
-    measured-cost table (search/kernel_tune.py, keyed by kernel, shape
-    incl. dtype, batch, heads, causality, device kind and jax version) wins
-    where it has a legal entry for this exact call; everywhere else (a
-    machine that never ran the tuner has no table) the static rule:
-    `_pick_block` down from `_OUTER_BLOCK`. Explicit wants (the tuner's own
-    sweep, a caller pinning a block) bypass the table. Resolution happens at
-    TRACE time (shapes are static), so a warm program pays nothing."""
-    if want_q is None and want_k is None:
-        from flexflow_tpu.search import kernel_tune
-
-        hit = kernel_tune.lookup_blocks(kernel, seq_q=sq, seq_k=sk,
-                                        head_dim=d, dtype=dtype,
-                                        batch=batch, heads=heads,
-                                        causal=causal)
-        if hit is not None:
-            return hit
+    DMAs, a pure function of the call. `_pick_block` down from
+    `_OUTER_BLOCK` for each side the caller left None, down from the
+    caller's own value for a side it pinned. Shapes are static, so this is
+    resolved as the program traces and a warm program pays nothing."""
     return (_pick_block(sq, want_q if want_q is not None else _OUTER_BLOCK),
             _pick_block(sk, want_k if want_k is not None else _OUTER_BLOCK))
 
@@ -357,19 +339,17 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     has keys of 192 and values of 128): the logit contracts d_qk, the
     accumulator and the output are d_v wide.
     Grid: (B*H, S_q/block_q, S_k/block_k) — K/V tiles stream through the
-    innermost axis. block_q/block_k default to AUTO (`_resolve_blocks`: the
-    kernel_tune table, else the static rule down from `_OUTER_BLOCK`);
-    explicit values pin the tile (degraded to a divisor of seq) and skip the
-    table. need_lse=False (inference) skips materializing the logsumexp
-    residual — it exists only for the VJP and costs more HBM writes than
-    the output itself at small head dims.
+    innermost axis. block_q/block_k default to `_resolve_blocks`' static
+    rule down from `_OUTER_BLOCK`; explicit values pin the tile (degraded
+    to a divisor of seq). need_lse=False (inference) skips materializing
+    the logsumexp residual — it exists only for the VJP and costs more HBM
+    writes than the output itself at small head dims.
 
     `window` (causal only): query i sees keys i - window < j <= i. A key
     tile wholly below the window has no grid step, the tile the lower edge
     crosses is masked like the diagonal's, the rest run as they do without
     one; with None the call is the one it always was."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sq, sk = q.shape[1], k.shape[1]
     if window is not None:
         assert causal, "a window is a causal layer's"
         if block_q is None and block_k is None:
@@ -379,9 +359,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
                 else _OUTER_BLOCK
         block_q, block_k = _pick_block(sq, block_q), _pick_block(sk, block_k)
     else:
-        block_q, block_k = _resolve_blocks("flash_fwd", sq, sk, d, q.dtype,
-                                           block_q, block_k, batch=b,
-                                           heads=h, causal=causal)
+        block_q, block_k = _resolve_blocks(sq, sk, block_q, block_k)
     assert sq % block_q == 0 and sk % block_k == 0
     # sq > sk with causal would leave the first rows keyless (0/0 in the
     # online softmax) — refused upstream in attention.flash_eligible
@@ -397,8 +375,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
 # of each kernel (as `moe_expert_stream_pallas`): a kernel body of two tile
 # classes times four chunks is some hundred jax calls to trace, and a
 # five-layer step holds it fifteen times. What a cached trace must not
-# freeze (the tune table's answer, interpret mode) is resolved by the
-# callers above and comes in as a static argument.
+# freeze (interpret mode) is resolved by the callers above and comes in as
+# a static argument.
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "causal", "scale", "block_q", "block_k", "need_lse", "interpret",
     "window"))
@@ -573,11 +551,8 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
     d_v) under the cotangent do, from its lse (B*H, S_q, 8): two calls,
     `flash_attention_bwd_dq` and `flash_attention_bwd_dkv`. Blocks as the
     forward's."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    block_q, block_k = _resolve_blocks("flash_bwd", sq, sk, d, q.dtype,
-                                       block_q, block_k, batch=b,
-                                       heads=h, causal=causal)
+    sq, sk = q.shape[1], k.shape[1]
+    block_q, block_k = _resolve_blocks(sq, sk, block_q, block_k)
     assert sq % block_q == 0 and sk % block_k == 0
     assert not (causal and sk < sq), "causal flash needs sq <= sk"
     return _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed,

@@ -25,8 +25,8 @@ hysteresis controller, not a forecaster:
     counters, never replicas.
 
 Drive it with ``start()`` (a daemon thread ticking every policy window)
-or call ``tick()`` directly for deterministic stepping (what the tests
-and the elastic_serve smoke do). The policy registers itself on the
+or call ``tick()`` directly for deterministic stepping (what
+tests/test_elastic_serve.py does). The policy registers itself on the
 ``/healthz`` rollup (controller state is operational state) and exports
 ``ff_autoscale_*`` series at scrape time.
 
@@ -157,8 +157,8 @@ class AutoscalePolicy:
         """One policy evaluation: fold the current SLO verdict and fleet
         load into the streaks, then act if a threshold crossed. Returns
         the action taken (``"scale_out"``/``"scale_in"``) or None.
-        Deterministic given the monitor's window state — the smoke and
-        tests call this directly instead of racing the loop thread."""
+        Deterministic given the monitor's window state — tests call
+        this directly instead of racing the loop thread."""
         slo = flightrec.slo_monitor()
         slo.maybe_evaluate()
         breaches = [b for b in slo.breaches()
@@ -300,8 +300,8 @@ class AutoscalePolicy:
     # ---- observability ---------------------------------------------------
 
     def state(self) -> Dict:
-        """Controller state (keys pinned — the /healthz row and the
-        smoke's assertion surface)."""
+        """Controller state (keys pinned — the /healthz row and what
+        tests/test_elastic_serve.py asserts on)."""
         with self._lock:
             cooldown_left = 0.0
             if self._last_action_t:

@@ -899,7 +899,8 @@ def _orbax_restore_as_numpy(path):
     import orbax.checkpoint as ocp
 
     ckptr = _checkpointer()
-    structure = ckptr.metadata(path)
+    # the step's metadata wraps the saved tree's own (orbax 0.11)
+    structure = ckptr.metadata(path).item_metadata.tree
     restore_args = jax.tree_util.tree_map(
         lambda _m: ocp.RestoreArgs(restore_type=np.ndarray), structure)
     return ckptr.restore(path, restore_args=restore_args)

@@ -370,7 +370,7 @@ class RollingDeployer:
         (None override when the prior is the construction version), dump
         ONE flight-recorder bundle naming the cause (and the offending
         SLO for a canary breach), and stamp the breach->fleet-on-prior
-        latency the bench reports."""
+        latency (the report's ``rollback_s``)."""
         r = self.router
         t0 = time.monotonic()
         for i in swapped:

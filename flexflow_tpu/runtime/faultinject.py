@@ -98,9 +98,9 @@ def _annotate(kind: str, site: str, index: int,
     """Report a FIRED injection to the telemetry plane (an instant
     ``fault`` trace annotation + ``ff_fault_fired_total`` counter), so
     every drill's trace shows exactly where the fault landed — asserted
-    by router_smoke/disagg_smoke/obs_smoke. Deferred import (telemetry
-    never imports this module back) and best-effort: injection must
-    work even if telemetry is torn down mid-test."""
+    by tests/test_telemetry.py. Deferred import (telemetry never imports
+    this module back) and best-effort: injection must work even if
+    telemetry is torn down mid-test."""
     try:
         from flexflow_tpu.runtime import telemetry
 

@@ -46,10 +46,10 @@ forensics, three pieces on one switch:
     memory-objective search will consume.
 
 ``FFConfig.telemetry="off"`` (or ``telemetry.set_enabled(False)``, or
-this module's own ``set_enabled(False)`` — the bench's overhead control
-arm) short-circuits every piece at the same single predicate as every
-other telemetry emit: the log ring stops growing, ``trip()`` returns at
-one check, the SLO evaluator never judges.
+this module's own ``set_enabled(False)``) short-circuits every piece at
+the same single predicate as every other telemetry emit: the log ring
+stops growing, ``trip()`` returns at one check, the SLO evaluator never
+judges.
 """
 
 from __future__ import annotations
@@ -81,7 +81,7 @@ BUNDLE_PREFIX = "bundle_"
 _TMP_PREFIX = "tmp-bundle-"
 LOG_RING_CAP = 2048
 
-# module gate (the bench's recorder-off control arm): AND'ed with the
+# module gate (the recorder alone off, telemetry on): AND'ed with the
 # process-wide telemetry switch and the configured FFConfig.telemetry —
 # one predicate guards every emit in this module
 _enabled = True
@@ -89,8 +89,8 @@ _enabled = True
 
 def set_enabled(on: bool) -> bool:
     """Flip the recorder/SLO/ledger gate; returns the previous value.
-    Telemetry itself keeps running — this is the marginal-overhead
-    control arm (bench ``flightrec_overhead_pct``)."""
+    Telemetry itself keeps running (tests/test_flightrec.py holds the
+    contract)."""
     global _enabled
     prev = _enabled
     _enabled = bool(on)
@@ -715,8 +715,8 @@ class SLOMonitor:
         """Re-snapshot every known series and restart the window clock.
         ``ServingEngine.warmup()``/``ServingRouter.warmup()`` call this
         when they finish, so compile-inflated warmup TTFTs can never be
-        judged as a breach — the same discipline the bench's timed
-        windows use."""
+        judged as a breach — as the benchmark's window starts after its
+        warm-up."""
         if not self.specs:
             return
         with self._lock:

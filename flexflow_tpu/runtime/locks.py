@@ -440,7 +440,7 @@ class RetraceSentinel:
     def arm(self) -> None:
         """Close the program set — warmup is done; every later miss is
         a violation. Arming is unconditional; the mode gates at call
-        time so a bench can toggle the sentinel without rebuilding."""
+        time so a test can toggle the sentinel without rebuilding."""
         self.armed = True
 
     def call(self, key, fn, args):
@@ -502,7 +502,7 @@ def retrace_log() -> List[Dict]:
 
 
 def reset() -> None:
-    """Drop recorded evidence (tests/bench); live locks stay tracked."""
+    """Drop recorded evidence (tests); live locks stay tracked."""
     with _evidence_lock:
         _violations.clear()
         _violation_pairs.clear()

@@ -34,8 +34,8 @@ level up: this router routes, sheds and fails over on the live
     returns immediately with state ``"rejected"``: excess load fails in
     microseconds at the front door, so ACCEPTED requests keep a bounded
     queue wait and the fleet's p99 TTFT stays flat instead of every
-    request sharing an ever-growing backlog (bench `router_serving`
-    measures exactly this).
+    request sharing an ever-growing backlog (tests/test_router.py
+    ``test_shedding_accepted_work_unaffected_and_fleet_drains``).
   * HEALTH-DRIVEN PLACEMENT — dispatch picks the least-loaded live
     replica by the same counters ``health()`` exports (active slots +
     queued work, read via the router's own outstanding ledger plus the
@@ -128,7 +128,8 @@ _ROUTER_IDS = iter(range(1 << 30))
 
 def _slab_nbytes(slab: Dict) -> int:
     """Host bytes a page slab's payload actually moves (the evacuation
-    cost the bench stamps and the placement advisor prices)."""
+    cost ``stats()["evacuation_bytes"]`` reports and the placement advisor
+    prices)."""
     total = 0
     stack = [slab.get("payload")]
     while stack:
@@ -247,8 +248,8 @@ class ServingRouter:
         self.model = model
         self.n = int(replicas)
         # replica roles (ISSUE 12): default "mixed" for every replica —
-        # bit-identical to the pre-role fleet, so existing tests, benches
-        # and smokes measure the same machine. A per-replica list (or
+        # bit-identical to the pre-role fleet, so existing tests and the
+        # benchmark measure the same machine. A per-replica list (or
         # FFConfig.serve_replica_roles as "prefill,decode,decode") turns
         # on the disaggregated placement + handoff below.
         raw = (roles if roles is not None
@@ -629,8 +630,8 @@ class ServingRouter:
         the set can reach (two passes: publish, then saturated repeat),
         the decode/verify programs, and (for role-split or tiered
         fleets) the shared page-import writer — so failover AND handoff
-        traffic later hits only warm programs: the smoke asserts zero
-        survivor recompiles through a mid-flight crash of the prefill
+        traffic later hits only warm programs: tests/test_disagg.py asserts
+        zero survivor recompiles through a mid-flight crash of the prefill
         replica. Call while the fleet is quiet (before routed
         traffic)."""
         plist = [np.asarray(p, np.int32).reshape(-1) for p in prompts]
@@ -1010,8 +1011,8 @@ class ServingRouter:
             self._retired[r] = True
             self._drop_affinity_locked(r)
             margin = deadline_t - time.perf_counter()
-            # last drill's deadline headroom (negative = starved) — the
-            # bench stamps it next to evacuation_bytes
+            # last drill's deadline headroom (negative = starved):
+            # stats()["preempt_margin_s"], beside evacuation_bytes
             self._preempt_margin_s = round(margin, 4)
         if self._tm_on:
             flightrec.trip(

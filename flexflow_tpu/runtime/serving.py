@@ -173,12 +173,6 @@ def version_ns(version, adapter=None):
     return (version, adapter)
 
 
-def _ktune_stats():
-    from flexflow_tpu.search import kernel_tune
-
-    return kernel_tune.stats()
-
-
 @dataclass
 class Request:
     """One serving request and its full lifecycle record."""
@@ -553,8 +547,8 @@ class ServingEngine:
         # leave it only through self.kv
         self.prefix_cache = self.kv.prefix_cache
 
-        # pool-capacity observability (the router/bench signals ROADMAP
-        # item 1 calls for), computed once — the pool's geometry is fixed
+        # pool-capacity observability (the router's placement signals),
+        # computed once — the pool's geometry is fixed
         # for the engine's life. The bf16 reference prices the SAME
         # geometry at 2 bytes/element, so kv_capacity_vs_bf16 is exactly
         # the capacity multiplier a quantized pool buys at equal HBM.
@@ -579,66 +573,22 @@ class ServingEngine:
             op.cache_bytes_per_token() for op in self.gen.attn_ops
             if op_keeps(op) is None)
 
-        # decode attention impl over the paged pool: the per-engine
-        # override wins, else FFConfig.paged_attention_impl; resolved
-        # ONCE here ("auto" -> the backend's concrete choice) so every
-        # program this engine builds, and stats(), agree on it. Under
-        # "auto" a MEASURED winner persisted by search/kernel_tune.py's
-        # tune_paged_attention for this engine's exact (page geometry,
-        # heads, pool dtype) overrides the backend heuristic — the
-        # paper's measured-costs-over-heuristics rule applied to impl
-        # choice. The einsum page-gather stays the parity oracle —
-        # greedy streams are token-identical either way
-        # (tests/test_pallas_paged.py).
+        # the paged pool's decode attention and its prefill/append page
+        # write, one choice for both, resolved ONCE here ("auto" -> the
+        # backend's: the kernels on a TPU, the einsum page-gather and the
+        # whole-slab scatter elsewhere) so every program this engine
+        # builds, and stats(), agree on it. The einsum paths stay the
+        # parity oracle: greedy streams are token-identical and prefill
+        # writes bitwise identical either way (tests/test_pallas_paged.py)
         from flexflow_tpu.ops.attention import resolve_paged_attention_impl
 
-        requested = (paged_attention_impl
-                     if paged_attention_impl not in (None, "")
-                     else getattr(cfg, "paged_attention_impl", "auto")
-                     or "auto")
         self.paged_attention_impl = resolve_paged_attention_impl(
-            requested, cfg)
-        from flexflow_tpu.search import kernel_tune
-
-        # snapshot the autotune-table counter baseline BEFORE the
-        # construction-time impl lookup below, so stats() shows that
-        # lookup too — the bench stamps it as proof the dtype-keyed
-        # entry governed an 'auto' engine
-        self._ktune_base = kernel_tune.stats()
-        # an op whose paged kernels are not the autotuner's has no entry in
-        # its table and says so with None
-        op0 = self.gen.attn_ops[0]
-        tune_key = op0.paged_kernel_shape(self.kv.pool[op0.name])
-        if requested == "auto" and tune_key is not None:
-            tuned = kernel_tune.lookup_paged_impl(
-                page_size=self.page_size,
-                pages_per_slot=self.pages_per_slot,
-                batch=self.slots, **tune_key)
-            if tuned is not None:
-                self.paged_attention_impl = tuned
-        # prefill/append page-scatter impl (ISSUE 18): the same knob
-        # routes the KV WRITE path — "pallas" scatters pages to the pool
-        # from VMEM one page at a time (ops/pallas_kernels.py
-        # paged_prefill_write_pallas), "einsum" is the whole-slab
-        # dynamic-update scatter and stays the parity oracle (prefill
-        # writes are bitwise identical either way; tests pin it). Under
-        # "auto" a measured tune_paged_prefill winner for this engine's
-        # shape overrides the backend default, same as decode above.
-        self.paged_prefill_impl = resolve_paged_attention_impl(
-            requested, cfg)
-        if requested == "auto" and tune_key is not None:
-            tuned_pf = kernel_tune.lookup_paged_prefill_impl(
-                page_size=self.page_size,
-                pages_per_slot=self.pages_per_slot,
-                batch=self.slots, **tune_key)
-            if tuned_pf is not None:
-                self.paged_prefill_impl = tuned_pf
+            paged_attention_impl)
         fflogger.info(
-            "serving: paged decode attention impl=%s prefill impl=%s "
+            "serving: paged decode attention and prefill write impl=%s "
             "kv_cache_dtype=%s "
             "weight_dtype=%s (%.1f KV bytes/token, %.2fx bf16 capacity)",
-            self.paged_attention_impl, self.paged_prefill_impl,
-            self.kv_cache_dtype,
+            self.paged_attention_impl, self.kv_cache_dtype,
             self.weight_dtype, self._kv_bytes_per_token,
             self._bf16_bytes_per_token / self._kv_bytes_per_token)
 
@@ -798,9 +748,6 @@ class ServingEngine:
         # with window layers: `_reach_windows`)
         self._page_steps = {"global": 0, "window": 0}
         self._tick_seq = 0
-        # (the kernel-tune counter baseline _ktune_base is snapshotted
-        # in the impl-resolution block above, before the construction-
-        # time table lookup)
         self._ttfts = collections.deque(maxlen=4096)
         # per-adapter ledgers (ISSUE 14 telemetry satellite): requests,
         # spec proposals/accepts — keyed by adapter label ("none" for
@@ -1335,11 +1282,11 @@ class ServingEngine:
                     # rows travelled in the program and end with it
                     out[op.name] = op.scatter_window_tail(
                         pool[op.name], caches[op.name], length, rings[keep],
-                        impl=self.paged_prefill_impl)
+                        impl=self.paged_attention_impl)
                     continue
                 out[op.name] = op.scatter_cache_tail(
                     pool[op.name], caches[op.name], p0, pages,
-                    impl=self.paged_prefill_impl)
+                    impl=self.paged_attention_impl)
         for op in gen.state_ops:
             # the prefilled state takes the request's slot in the pool
             with jax.named_scope(op.name), jax.named_scope("seat"):
@@ -2418,8 +2365,8 @@ class ServingEngine:
             return self.import_prefix_slab(slab) > 0
 
     def warmup(self, prompts, max_new_tokens: int = 4) -> Dict:
-        """Warm EVERY program this prompt set can reach — the bench
-        gotcha relearned in PRs 7, 8 and 10, promoted to an API: a
+        """Warm EVERY program this prompt set can reach — a gotcha
+        relearned in PRs 7, 8 and 10, promoted to an API: a
         prompt REPEATED after its first run reaches (bucket,
         matched_pages) hit-prefill variants the first pass never
         compiled, so any timed window that repeats prompts (best-of-N
@@ -2471,8 +2418,8 @@ class ServingEngine:
         if self._tm_on:
             # restart the SLO window clock past the warmup: a
             # compile-inflated warmup TTFT must never be judged as a
-            # breach (the bench's warm-window discipline, applied to
-            # the health plane)
+            # breach (the benchmark's window starts after its warm-up;
+            # the same rule for the health plane)
             flightrec.slo_monitor().rebaseline()
         self._retrace.arm()
         return {"programs": self.recompile_count - before,
@@ -3132,7 +3079,7 @@ class ServingEngine:
             "recompiles": self.recompile_count,
             # post-warmup jit cache misses the ffsan sentinel saw
             # (0 unless FF_SANITIZE is on and a warm program
-            # retraced — the smokes assert this stays 0)
+            # retraced — tests/test_router.py asserts this stays 0)
             "sanitizer_retraces": self._retrace.hits,
             # mean fraction of computed positions doing USEFUL work per
             # decode step (mid-chunk retirements stop counting) — the
@@ -3187,7 +3134,7 @@ class ServingEngine:
             # the capacity multiplier vs a bf16 pool of the same
             # geometry — effective page capacity = kv_page_size x that
             # multiplier in bf16-equivalent tokens per page's bytes.
-            # These are the router/bench placement signals: a quantized
+            # These are the router's placement signals: a quantized
             # replica advertises more tokens per byte, not more bytes.
             "kv_cache_dtype": self.kv_cache_dtype,
             "weight_dtype": self.weight_dtype,
@@ -3219,7 +3166,7 @@ class ServingEngine:
             # tiered-cache observability (ISSUE 12): pages by tier (hbm
             # = trie-cached pool pages, host = pinned host copies incl.
             # publishes still in flight), the migration counters the
-            # bench/router steer by, and the handoff ledger (prefill-
+            # router steers by, and the handoff ledger (prefill-
             # only admissions run for the role split, slabs moved)
             "host_kv_pages": pc.host_pages if pc else 0,
             "kv_pages_hbm": pc.pages if pc else 0,
@@ -3283,17 +3230,14 @@ class ServingEngine:
                 for name, v in self._adapter_spec.items()},
             "requests_by_adapter": dict(self._adapter_requests),
             # decode-attention hot-path observability (ISSUE 7): which
-            # impl this engine's programs trace, how many pool pages the
-            # last dispatch's attention read (vs the table-width gather
-            # the einsum path always re-materializes), and the kernel
-            # autotune table's process-wide hit/miss deltas since engine
-            # construction (see the baseline note in __init__)
+            # impl this engine's programs trace (one choice, under the
+            # two names its readers ask for: the decode attention's and
+            # the prefill page write's) and how many pool pages the last
+            # dispatch's attention read (vs the table-width gather the
+            # einsum path always re-materializes)
             "paged_attention_impl": self.paged_attention_impl,
-            "paged_prefill_impl": self.paged_prefill_impl,
+            "paged_prefill_impl": self.paged_attention_impl,
             "pages_touched": self._pages_touched,
             "kv_read_bytes": self._kv_read_bytes,
             "last_pages_touched": self._last_pages_touched,
-            **{f"kernel_tune_{k}": v - self._ktune_base.get(k, 0)
-               for k, v in _ktune_stats().items()
-               if k in ("hits", "misses")},
         }

@@ -3,7 +3,7 @@
 The paper's whole premise is choosing strategies from MEASURED costs, and
 the fleet's runtime signals were scattered across ad-hoc dicts
 (``ServingEngine.stats()``, ``ServingRouter.stats()``,
-``model.last_step_breakdown``, ``kernel_tune.stats()``) with no time
+``model.last_step_breakdown``) with no time
 dimension, no export format, and no way to reconstruct what happened to
 ONE request as it crossed router -> prefill replica -> KV-page handoff ->
 decode replica -> retirement. TensorFlow's system paper made timeline
@@ -18,12 +18,12 @@ Three pieces, one process-wide substrate:
     dtype, impl). Export as Prometheus text exposition
     (``registry().to_prometheus()``, served by ``start_http_server`` /
     ``FFConfig.metrics_port`` on ``/metrics``) or a JSON snapshot
-    (``registry().snapshot()``, ``/metrics.json``). Engines, routers and
-    the kernel-tune table register *collectors* — weakly-referenced
+    (``registry().snapshot()``, ``/metrics.json``). Engines and routers
+    register *collectors* — weakly-referenced
     callbacks that publish their ``stats()`` dicts as gauges at scrape
     time — so every counter the ad-hoc dicts already carried (hit rates,
     handoffs, demotions/promotions, fenced/resubmitted/timeouts/rejected,
-    recompile_count, kernel_tune hits) is a first-class series without a
+    recompile_count) is a first-class series without a
     second bookkeeping path: the dict IS the collector's source, the
     registry is the export plane both share.
 
@@ -46,10 +46,9 @@ Three pieces, one process-wide substrate:
   * **Fault annotations** — ``runtime/faultinject.py`` reports every
     fired FF_FAULT event here (``annotate("fault", ...)``), so a fault
     drill's trace shows exactly where the fault landed
-    (``fault_events()``; asserted by router_smoke/disagg_smoke).
+    (``fault_events()``; asserted by tests/test_telemetry.py).
 
-Overhead discipline (the budget the bench stamps as
-``telemetry_overhead_pct``): every hot-path emit is one lock-cheap
+Overhead discipline: every hot-path emit is one lock-cheap
 dict/deque op and a ``perf_counter()`` call; histograms are fixed arrays
 (no per-observation allocation); ``set_enabled(False)`` (or
 ``FFConfig.telemetry="off"``) turns ``span()`` into a shared no-op and
@@ -370,17 +369,6 @@ class Registry:
                                     if r not in dead]
 
     # ---- export ---------------------------------------------------------
-
-    def describe(self) -> Dict[str, int]:
-        """Registry shape for bench honesty stamps: how many families /
-        labeled series / histogram series exist right now."""
-        with self._lock:
-            fams = list(self._families.values())
-        series = sum(len(f.children()) for f in fams)
-        hists = sum(len(f.children()) for f in fams
-                    if f.kind == "histogram")
-        return {"families": len(fams), "series": series,
-                "histograms": hists}
 
     def to_prometheus(self) -> str:
         """Prometheus text exposition (format 0.0.4)."""
@@ -771,8 +759,8 @@ def annotate(name: str, trace_id: Optional[str] = None,
 
 
 def fault_events() -> List[Dict]:
-    """Every FF_FAULT annotation currently in the trace ring (the
-    router/disagg smoke assertion surface)."""
+    """Every FF_FAULT annotation currently in the trace ring (read by
+    tests/test_telemetry.py's failover drill)."""
     return _tracer.events(name="fault")
 
 

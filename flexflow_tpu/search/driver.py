@@ -626,7 +626,7 @@ def optimize_strategies_multi(model, budget: int = 1000, alpha: float = 0.05,
 
     Stashes ``model._predicted_step_time`` (base + relief overhead) and
     ``model._search_summary`` for telemetry calibration
-    (``cost_db.export_calibration``) and the bench tier."""
+    (``cost_db.export_calibration``)."""
     from flexflow_tpu.search.cost_model import MEM_MODES
 
     mesh_shape = mesh_shape or model.config.mesh_shape

@@ -42,8 +42,7 @@ class MeasuredTable(dict):
     """The cost table measure_op_costs returns: a plain {key: seconds}
     dict (drop-in for every CostModel consumer) that also records how
     many DISTINCT signatures back its keys — twins share one timing, so
-    len(table) >= signatures_timed. scripts/northstar_search.py reports
-    both for cost-table provenance."""
+    len(table) >= signatures_timed."""
 
     signatures_timed: int = 0
 
@@ -98,9 +97,9 @@ _ENV_SIG: Optional[Tuple] = None
 def _env_signature() -> Tuple:
     """(backend, device kind, jax version) stamped into every cost
     signature. Within one process it is constant — but these signatures
-    are the keys the persistent cost tables (kernel_tune today, the
-    ROADMAP-3 cross-session cost DB next) are built from, and a timing
-    taken on one backend/jax build must never be served on another."""
+    are the keys the persistent cost DB (search/cost_db.py) is built
+    from, and a timing taken on one backend/jax build must never be
+    served on another."""
     global _ENV_SIG
     if _ENV_SIG is None:
         import jax
@@ -238,14 +237,13 @@ def _dispatch_floor(calls: int = 3) -> float:
 
 def time_scalar_program(step, *args, warmup: int = 1, iters: int = 5,
                         loop: int = 1) -> float:
-    """THE timing primitive (exposed for the kernel autotuner,
-    search/kernel_tune.py, and any future microbench): time a jitted
-    callable that returns ONE scalar, the way measure_one documents —
-    compile excluded, each call forced by a 4-byte float() fetch, the
-    null-dispatch floor sampled just before and subtracted, best-of-iters
-    so one host stall cannot inflate the result. ``loop`` divides the result when
-    the program repeats its body in-graph (lax.scan amortization).
-    Returns seconds, clamped positive."""
+    """THE timing primitive: time a jitted callable that returns ONE
+    scalar, the way measure_one documents — compile excluded, each call
+    forced by a 4-byte float() fetch, the null-dispatch floor sampled just
+    before and subtracted, best-of-iters so one host stall cannot inflate
+    the result. ``loop`` divides the result when the program repeats its
+    body in-graph (lax.scan amortization). Returns seconds, clamped
+    positive."""
     import time as _time
 
     float(step(*args))  # compile + first warmup

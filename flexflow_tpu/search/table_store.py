@@ -1,8 +1,6 @@
 """Shared atomic-JSON table persistence for measured-cost stores.
 
-One implementation of the on-disk discipline that `search/kernel_tune.py`
-proved out and the op-cost database (`search/cost_db.py`) now shares —
-the ISSUE 19 satellite that forbids a second divergent persistence stack:
+The on-disk discipline of the op-cost database (`search/cost_db.py`):
 
   * atomic publish: write ``<path>.tmp`` then ``os.replace`` so a reader
     (or a crash mid-write) can never observe a torn table;
@@ -27,8 +25,7 @@ import os
 from typing import Dict, Optional, Tuple
 
 # {path: (file_stat_sig, entries)} — shared by every table on disk; keys
-# are file paths so distinct tables (kernel_tune.json, cost_db.json)
-# never collide. kernel_tune aliases this as its legacy `_TABLES` name.
+# are file paths so distinct tables never collide.
 _CACHE: Dict[str, Tuple] = {}
 
 

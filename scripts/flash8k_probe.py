@@ -25,7 +25,7 @@ k = jnp.asarray(rs.randn(b, s, h, d), jnp.bfloat16)
 v = jnp.asarray(rs.randn(b, s, h, d), jnp.bfloat16)
 
 f = jax.jit(lambda q, k, v: flash_attention(q, k, v, True, 0.088))
-# scalar fetch = the sync (same as bench.py's timed loop); warmed OUTSIDE
+# scalar fetch = the sync; warmed OUTSIDE
 # the timed window so its compile doesn't pollute the ms/iter
 sync = jax.jit(lambda a: a.astype(jnp.float32).sum())
 o = f(q, k, v)

@@ -1,5 +1,5 @@
-"""On-chip MFU probe: time the bench `full` transformer config under one
-configuration knob per run, via the scanned multi-step trainer (so the
+"""On-chip MFU probe: time the encoder classifier (hidden 1024, 8 layers,
+seq 512 by default) under one configuration knob per run, via the scanned multi-step trainer (so the
 numbers are free of per-dispatch latency).
 
 Usage (one jax process per chip):
@@ -8,7 +8,8 @@ Usage (one jax process per chip):
     python scripts/mfu_probe.py --master bfloat16
     python scripts/mfu_probe.py --seq 1024 --layers 4
 
-Prints one JSON line comparable with the bench full_scan tier.
+Prints one JSON line. A probe of levers, not a benchmark: the cells of
+benchmark/run.py are what PERF.md counts.
 """
 
 import argparse
@@ -38,7 +39,7 @@ def main():
     import jax
     import numpy as np
 
-    from bench import _peak_flops_per_chip
+    from benchmark.peaks import peaks_for
     from flexflow_tpu._env import resolve_compilation_cache
 
     resolve_compilation_cache()
@@ -49,9 +50,9 @@ def main():
     from flexflow_tpu.ops.base import InputOp
 
     dev = jax.devices()[0]
-    # same roofline denominator as the bench rows this probe is compared
-    # against; raises here, before any work, on a device outside the table
-    peak, _ = _peak_flops_per_chip(dev)
+    # the benchmark's roofline denominator; raises here, before any work,
+    # on a device outside the table
+    peak = peaks_for(dev.device_kind)["bf16_flops"]
     cfg = FFConfig(batch_size=args.batch, mesh_shape={"data": 1},
                    compute_dtype=args.dtype, master_dtype=args.master,
                    use_fused_ln=args.fused_ln,

@@ -74,7 +74,7 @@ def build(args, strategies=None, mesh=None):
 
 
 def real_time_s(ff, steps: int, scan: bool = False) -> float:
-    """Best-of-3 whole-program step time (fetch-synced, like bench.py).
+    """Best-of-3 whole-program step time (fetch-synced).
     scan=True runs the steps as ONE lax.scan device program — the
     dispatch-free number, required where per-step host dispatch would
     otherwise dominate small models (the simulator prices compute, not

@@ -1,5 +1,6 @@
-"""Worker script for the elastic-recovery smoke (scripts/elastic_smoke.py,
-ci/run_ci.sh `elastic` tier), launched through flexflow_tpu.launcher.
+"""Worker script of the changed-topology drill (tests/test_multihost.py
+`test_two_process_run_resumes_on_one_survivor_resharded`), launched through
+flexflow_tpu.launcher.
 
 Phase 1 runs it on TWO controller processes (4 virtual CPU devices each,
 8-device global data mesh) with FF_FAULT=sigterm@step:<k>: both

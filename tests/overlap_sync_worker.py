@@ -1,7 +1,7 @@
-"""Worker for the collective-overlap CI drill
-(scripts/collective_overlap_smoke.py, ci/run_ci.sh `overlap` tier),
-launched through flexflow_tpu.launcher on one OR two controller
-processes.
+"""Worker of the overlapped-sync preempt/resume drill
+(tests/test_multihost.py
+`test_two_process_overlapped_sync_preempt_resumes_bitwise`), launched
+through flexflow_tpu.launcher on one OR two controller processes.
 
 Trains with FFConfig.overlap_grad_sync on (bucketed in-scan grad
 reduce-scatter + ZeRO-1 sharded optimizer update) and
@@ -9,7 +9,7 @@ async_checkpointing on — single-process that publishes checkpoints from
 the background thread; on two controllers the collective multihost save
 falls back to synchronous with a warning (the documented contract) —
 under a TrainSupervisor. FF_FAULT=sigterm@step:<k> preempts phase 1; a
-relaunch resumes and must continue BITWISE (the smoke compares the
+relaunch resumes and must continue BITWISE (the test compares the
 resumed loss tail against an uninterrupted reference run).
 
 Prints one machine-checkable line per process:

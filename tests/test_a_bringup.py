@@ -7,7 +7,6 @@ The chip side of the same contracts is `python chip_smoke.py`.
 import os
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -79,31 +78,22 @@ def test_library_code_places_no_cache():
 
 
 def test_peak_table_raises_on_unknown_device_kind():
-    import bench
+    from benchmark.peaks import peaks_for
 
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    assert bench._peak_flops_per_chip(v5e) == (197e12, "spec")
+    assert peaks_for("TPU v5 lite")["bf16_flops"] == 197e12
     for kind in ("TPU v99", "cpu", ""):
-        with pytest.raises(ValueError, match="TPU_PEAK_BF16"):
-            bench._peak_flops_per_chip(
-                types.SimpleNamespace(device_kind=kind))
+        with pytest.raises(KeyError, match="benchmark/peaks.py"):
+            peaks_for(kind)
 
 
 def test_entry_scripts_refuse_a_non_tpu_platform():
-    """bench.py and chip_smoke.py name the platform and stop — no CPU tier,
-    no fallback row, no JSON verdict — and place no cache on the way."""
-    import bench
+    """chip_smoke.py names the platform and stops — no CPU tier, no
+    fallback row, no JSON verdict — and places no cache on the way."""
     import chip_smoke
 
-    with pytest.raises(SystemExit, match="'cpu'"):
-        bench.main()
     with pytest.raises(RuntimeError, match="'cpu', not a TPU"):
         chip_smoke.main([])
     assert not _env.compilation_cache_dir()
-    for gone in ("_Child", "_run_attempt", "_probe_backend", "_pick_non_tpu",
-                 "_promote_history", "_measured_matmul_peak", "TIER_COST_S",
-                 "child", "probe"):
-        assert not hasattr(bench, gone), gone
 
 
 def test_interpret_mode_is_asked_for(monkeypatch):

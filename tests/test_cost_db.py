@@ -411,3 +411,33 @@ def test_export_calibration_absent_without_signals(tmp_path):
         assert cost_db.export_calibration(ff) is None  # no observations
     finally:
         telemetry.reset()
+
+
+def test_time_scalar_program_primitive():
+    import jax
+    import jax.numpy as jnp
+
+    fn = jax.jit(lambda x: jnp.sum(x * 2.0))
+    dt = measure.time_scalar_program(fn, jnp.ones((64, 64)), warmup=1,
+                                     iters=2)
+    assert dt > 0.0
+
+
+def test_measure_signature_records_dtype_and_env():
+    """ISSUE-7 bugfix: the cost-table signature must carry input dtypes
+    and the (backend, device kind, jax version) environment — shapes
+    alone let a bf16 timing serve an fp32 query across version bumps."""
+    import jax
+
+    cfg = FFConfig(batch_size=2, mesh_shape={"data": 1})
+    ff = FFModel(cfg)
+    x = ff.create_tensor([2, 8], name="x")
+    ff.dense(x, 4, ActiMode.AC_MODE_RELU, name="d0")
+    op = next(o for o in ff.ops if o.name == "d0")
+    sig = measure._op_signature(op, [(2, 8)], [(8, 4)])
+    env = measure._env_signature()
+    assert env == (jax.default_backend(),) + env[1:]
+    assert env[2] == jax.__version__
+    assert sig[-1] == env, "environment signature missing from cost key"
+    dtypes = sig[-2]
+    assert len(dtypes) == len(op.inputs) and "FLOAT" in dtypes[0].upper()

@@ -16,11 +16,15 @@ Correctness anchors:
   * swap_weights refuses an engine with live slots (a mid-stream weight
     change would corrupt in-flight decodes).
 
-The canary-breach -> automatic-rollback drill (slow@canary under live
-flood) lives in scripts/deploy_smoke.py, where real traffic feeds the
-SLO windows; these tests cover every deploy state machine edge that
-does not need a flood.
+The last test rolls a fleet under a closed-loop flood, where real traffic
+feeds the SLO windows: a clean roll, then the canary-breach -> automatic
+rollback drill (slow@canary).
 """
+
+import json
+import os
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -233,10 +237,10 @@ def test_drain_reopen_gate_without_decode(ff):
     assert eng.stats()["deploy_state"] == "serving"
 
 
-# ---- decode-carrying paths (deploy CI tier runs these) --------------------
+# ---- decode-carrying paths ------------------------------------------------
 
 
-@pytest.mark.slow  # 20 s; deploy CI tier runs the full file
+@pytest.mark.slow  # 20 s
 def test_drain_reopen_serve_token_identity(ff):
     """drain -> reopen -> serve: the reopened engine serves again and
     its tokens still equal solo generate (ISSUE 17 satellite — drain
@@ -258,7 +262,7 @@ def test_drain_reopen_serve_token_identity(ff):
     assert eng.stats()["completed"] == 5
 
 
-@pytest.mark.slow  # 15 s; deploy CI tier runs the full file
+@pytest.mark.slow  # 15 s
 def test_swap_weights_refuses_live_slots(ff):
     """A mid-stream weight change corrupts in-flight decodes: swapping
     with live slots must raise, and the engine must finish serving the
@@ -279,7 +283,7 @@ def test_swap_weights_refuses_live_slots(ff):
                                   solo[0, req.prompt.size:])
 
 
-@pytest.mark.slow  # 40 s; deploy CI tier runs the full file
+@pytest.mark.slow  # 40 s
 def test_version_salt_isolates_prefix_cache(ff, tmp_path):
     """The stale-KV kill shot: a prompt whose prefix is HOT under v0
     admits COLD after the swap to v1 (zero cross-version hits — new
@@ -326,7 +330,7 @@ def test_version_salt_isolates_prefix_cache(ff, tmp_path):
     assert eng.stats()["prefix_hits"] == after["prefix_hits"] + 1
 
 
-@pytest.mark.slow  # 45 s; deploy CI tier runs the full file
+@pytest.mark.slow  # 45 s
 def test_ab_fleet_per_version_hit_accounting(ff, tmp_path):
     """Mid-roll A/B window: replica 0 on v1, replica 1 still on v0
     behind one router. Identical prompts route to a consistent home via
@@ -378,10 +382,10 @@ def test_ab_fleet_per_version_hit_accounting(ff, tmp_path):
         router.close()
 
 
-@pytest.mark.slow  # 35 s; deploy CI tier runs the full file
+@pytest.mark.slow  # 35 s
 def test_rolling_deploy_on_live_fleet(ff, tmp_path):
-    """End-to-end roll on a STARTED fleet (no flood — deploy_smoke owns
-    that): warmup re-runs under the new weights, both replicas end on
+    """End-to-end roll on a STARTED fleet (no flood: the last test has
+    one): warmup re-runs under the new weights, both replicas end on
     v1, zero recompiles during the swaps (same-geometry override), and
     post-deploy traffic matches the v1 reference."""
     reg, v1 = _publish_bumped(ff, tmp_path, step=1)
@@ -418,3 +422,171 @@ def test_rolling_deploy_on_live_fleet(ff, tmp_path):
         assert router.stats()["swaps_completed"] == 2
     finally:
         router.close()
+
+
+class _Flood(threading.Thread):
+    """Closed-loop skewed flood: keeps up to `max_inflight` requests open
+    (80 % share a system prompt) until stopped, sampling how many replicas
+    are suspended at once: the capacity >= N - 1 witness."""
+
+    def __init__(self, router, rs, system, max_new, max_inflight=12):
+        super().__init__(daemon=True)
+        self.router, self.rs, self.system = router, rs, system
+        self.max_new, self.max_inflight = max_new, max_inflight
+        self.reqs, self.max_suspended = [], 0
+        self._halt = threading.Event()
+        self._done_before = self._engines_done()
+
+    def _engines_done(self):
+        return sum(e.stats()["completed"] for e in self.router.engines)
+
+    def run(self):
+        rs = self.rs
+        while not self._halt.is_set():
+            self.max_suspended = max(self.max_suspended,
+                                     sum(self.router._suspended))
+            if sum(not r.settled for r in self.reqs) >= self.max_inflight:
+                time.sleep(0.004)
+                continue
+            if rs.randint(5) < 4:
+                prompt = np.concatenate([self.system, rs.randint(
+                    1, VOCAB, (int(rs.randint(1, 8)),)).astype(np.int32)])
+            else:
+                prompt = rs.randint(
+                    1, VOCAB, (int(rs.randint(3, 25)),)).astype(np.int32)
+            self.reqs.append(self.router.submit(prompt, self.max_new))
+
+    def settle(self, warmups_since):
+        """Stop, wait everything out, and hold the exactly-once ledger:
+        every request done on its first attempt, and the engines'
+        completions since the flood was made = the flood + the deploy's
+        own warm-up passes."""
+        self._halt.set()
+        self.join(timeout=60)
+        self.router.wait(self.reqs, timeout=600)
+        n = len(self.reqs)
+        assert [r.state for r in self.reqs] == ["done"] * n
+        assert all(r.attempts == 1 for r in self.reqs)
+        assert self._engines_done() - self._done_before \
+            == n + warmups_since, "duplicated or dropped work"
+
+
+@pytest.mark.slow  # ~1 min: two deploys under a flood, canary soaks of 1 s
+def test_flood_roll_then_canary_breach_rolls_back(ff, tmp_path,
+                                                  monkeypatch):
+    """A 2-replica fleet under a closed-loop skewed flood.
+    Leg 1, a version published mid-flood rolls through: every request
+    served exactly once, at most one replica suspended at any sampled
+    instant and none fenced, zero recompiles (a same-geometry swap keeps
+    every program valid), the tries salted with the new version, post-roll
+    streams those of a model holding the new weights.
+    Leg 2, `slow@canary` stalls the freshly swapped canary's admissions and
+    breaches its rebaselined TTFT SLO: the deployer rolls the fleet BACK,
+    traffic still exactly-once and token-identical to the prior version,
+    and exactly ONE manifest-intact flight bundle names the breached SLO."""
+    from flexflow_tpu.runtime import flightrec
+
+    max_new = 12
+    monkeypatch.setattr(ff.config, "slo_window_s", 1.0)
+    reg = WeightArtifactRegistry(str(tmp_path / "watch"))
+    flight = tmp_path / "flight"
+    flight.mkdir()
+    rs = np.random.RandomState(0)
+    system = rs.randint(1, VOCAB, (32,)).astype(np.int32)   # 4 full pages
+    router = ff.make_serving_router(
+        replicas=2, serve_slots=4, kv_page_size=8, max_seq_len=80,
+        decode_buckets=[32, 64], start=False)
+
+    def publish(step, scale):
+        keep = ff.params
+        ff.params = ff.executor.reshard_params(_bumped(keep, scale))
+        try:
+            return reg.publish(ff, step=step)
+        finally:
+            ff.params = keep
+
+    def assert_serves(version, probe):
+        keep = ff.params
+        ff.params = ff.executor.reshard_params(reg.load_params(version))
+        try:
+            solo = ff.generate(probe[None, :], max_new_tokens=max_new)
+        finally:
+            ff.params = keep
+        got = router.run([probe], max_new_tokens=max_new, timeout=600)[0]
+        np.testing.assert_array_equal(np.asarray(got.tokens, np.int32),
+                                      solo[0, probe.size:])
+
+    try:
+        tail = rs.randint(1, VOCAB, (3,)).astype(np.int32)
+        warm = [rs.randint(1, VOCAB, (10,)).astype(np.int32),
+                np.concatenate([system, tail]),
+                np.concatenate([system, tail + 1])]
+        router.warmup(warm, max_new_tokens=4)
+        warm_compiles = [e.recompile_count for e in router.engines]
+        router.start()
+        dep = RollingDeployer(router, reg, canary_windows=2)
+
+        # ---- leg 1: a clean roll under load
+        v1 = publish(1, 1.25)
+        flood = _Flood(router, rs, system, max_new)
+        flood.start()
+        while len(flood.reqs) < 8:                     # the flood is live
+            time.sleep(0.01)
+        report = dep.deploy(v1, warmup_prompts=warm, max_new_tokens=4)
+        while len(flood.reqs) < 40:                    # post-roll traffic
+            time.sleep(0.01)
+        # each swapped engine's warm-up drives 2 passes over the prompts
+        flood.settle(warmups_since=2 * 2 * len(warm))
+        assert report["state"] == "completed", report
+        assert report["swapped"] == [0, 1] and report["canary"] == 0
+        st = router.stats()
+        assert st["fenced"] == 0 and flood.max_suspended <= 1
+        assert (st["swaps_completed"], st["rollbacks"]) == (2, 0)
+        assert not st["deploying"]
+        assert [row["weight_version"] for row in st["per_replica"]] \
+            == router.health()["weight_versions"] == [v1, v1]
+        for eng, warm_count in zip(router.engines, warm_compiles):
+            assert eng._cache_ns(None) == (v1, None)
+            assert eng.recompile_count == warm_count
+        assert_serves(v1, np.concatenate(
+            [system, rs.randint(1, VOCAB, (4,)).astype(np.int32)]))
+
+        # ---- leg 2: the canary breaches, the fleet rolls back
+        v2 = publish(2, 1.5)
+        # a tight TTFT ceiling over 1 s windows; the debounce parked high,
+        # so the ONLY bundle is the rollback's own synchronous dump
+        flightrec.configure(FFConfig(
+            batch_size=2, mesh_shape={"data": 1}, slo_ttft_p99_s=0.25,
+            slo_window_s=1.0, flight_recorder_dir=str(flight),
+            flight_debounce_s=600.0))
+        _arm_fault(monkeypatch, "slow(600)@canary:1-400")
+        flood = _Flood(router, rs, system, max_new)
+        flood.start()
+        while len(flood.reqs) < 8:
+            time.sleep(0.01)
+        try:
+            report = dep.deploy(v2, warmup_prompts=warm, max_new_tokens=4)
+        finally:
+            monkeypatch.delenv("FF_FAULT")
+            faultinject.reset()
+        # only the canary's warm-up ran engine-side; the rollback swap
+        # rebaselines without warming again
+        flood.settle(warmups_since=2 * len(warm))
+        assert report["state"] == "rolled_back", report
+        assert report["breach"]["slo"] == "ttft_p99", report["breach"]
+        assert str(report["breach"]["replica"]) == str(report["canary"])
+        assert report["rollback_s"] > 0
+        assert [e.weight_version for e in router.engines] == [v1, v1]
+        st = router.stats()
+        assert (st["rollbacks"], st["fenced"]) == (1, 0)
+        bundle, = [str(flight / d) for d in os.listdir(flight)]
+        assert report["bundle"] == bundle
+        flightrec.verify_bundle(bundle)
+        with open(os.path.join(bundle, "trigger.json")) as f:
+            blob = json.dumps(json.load(f))
+        assert "canary_rollback" in blob and "ttft_p99" in blob
+        assert_serves(v1, np.concatenate(
+            [system, rs.randint(1, VOCAB, (5,)).astype(np.int32)]))
+    finally:
+        router.close()
+        flightrec.reset()

@@ -209,7 +209,7 @@ def test_slab_roundtrip_bitwise_and_token_identity(ff):
     assert imp.stats()["prefix_hits"] >= 1
 
 
-@pytest.mark.slow  # ~30 s; disagg CI tier runs the full file — the
+@pytest.mark.slow  # ~30 s — the
 # quantized leg: slabs carry scales, so int8 pages round-trip bitwise
 def test_quantized_slab_handoff_is_bitwise(ff):
     prompts = _mixed_prompts(23)
@@ -285,7 +285,7 @@ def test_import_refuses_dtype_mismatch_and_host_tail(ff):
 # ---- role-split fleet ------------------------------------------------------
 
 
-@pytest.mark.slow  # ~35 s; disagg CI tier runs the full file
+@pytest.mark.slow  # ~35 s
 def test_role_split_fleet_token_identity_and_handoff(ff):
     """1 prefill + 2 decode: long prompts route through the prefill
     replica (prefill-only, no completions there), hand off as slabs,
@@ -323,7 +323,7 @@ def test_role_split_fleet_token_identity_and_handoff(ff):
         router.close()
 
 
-@pytest.mark.slow  # ~35 s; disagg CI tier runs the full file — the
+@pytest.mark.slow  # ~35 s — the
 # drill: the prefill tier dies mid-handoff, work falls back cold
 def test_prefill_replica_crash_cold_path_fallback(ff, monkeypatch):
     prompts = _mixed_prompts(27, n=10)
@@ -414,8 +414,8 @@ def test_tier_faults_fall_back_token_identical(ff, monkeypatch):
     assert st["completed"] == 12 and st["failed"] == 0
 
 
-@pytest.mark.slow  # ~25 s; disagg CI tier runs the full file — the
-# thrice-relearned bench gotcha as an API contract
+# a gotcha learned three times over, now an API contract: warmup() drives
+# every (bucket, matched_pages) variant the same traffic will reach
 def test_warmup_drives_every_variant_zero_recompiles_after(ff):
     prompts = _mixed_prompts(35, n=8)
     eng = ff.make_serving_engine(serve_slots=2, kv_page_size=PS,
@@ -434,7 +434,7 @@ def test_warmup_drives_every_variant_zero_recompiles_after(ff):
         f"the (bucket, matched_pages) variant sweep missed one")
 
 
-@pytest.mark.slow  # ~25 s; disagg CI tier runs the full file
+@pytest.mark.slow  # ~25 s
 def test_warmup_learns_interleaved_prefill_variants(ff):
     """ISSUE 18: chunk-interleaved admission adds the prefill_ichunk /
     prefill_ifinal program families. warmup() must drive them too — an

@@ -291,7 +291,7 @@ def test_engine_reclaim_queued_drains_parked_queue(ff):
 # ---- live membership + preemption drills (model fixture: slow) -----------
 
 
-@pytest.mark.slow  # 20 s; elastic_serve CI tier runs the full file
+@pytest.mark.slow  # 20 s
 def test_scale_out_serves_token_identical(ff):
     """add_replica() on a live, mid-flood fleet: the newcomer is warmed
     before admission, takes real work, and every stream stays
@@ -323,7 +323,7 @@ def test_scale_out_serves_token_identical(ff):
         router.close()
 
 
-@pytest.mark.slow  # 30 s; elastic_serve CI tier runs the full file
+@pytest.mark.slow  # 30 s
 def test_scale_in_requeues_and_survivor_inherits(ff):
     """remove_replica() racing fresh submissions strands nothing: parked
     never-admitted work is requeued automatically and completes
@@ -372,7 +372,6 @@ def test_scale_in_requeues_and_survivor_inherits(ff):
         router.close()
 
 
-@pytest.mark.slow  # 15 s; elastic_serve CI tier runs the full file
 def test_scale_in_inherits_adapters_no_reregister(ff):
     """After the adapter-holding replica retires, the tenant keeps
     serving from survivors with NO caller re-register; a later
@@ -401,7 +400,7 @@ def test_scale_in_inherits_adapters_no_reregister(ff):
         router.close()
 
 
-@pytest.mark.slow  # 30 s; elastic_serve CI tier runs the full file
+@pytest.mark.slow  # 30 s
 def test_preempt_exactly_once_and_prefix_evacuation(ff, monkeypatch):
     """FF_FAULT preempt(800)@replica:0 mid-flood: the replica evacuates
     queued + in-flight work and its hot prefix pages inside the
@@ -454,7 +453,7 @@ def test_preempt_exactly_once_and_prefix_evacuation(ff, monkeypatch):
         router.close()
 
 
-@pytest.mark.slow  # 25 s; elastic_serve CI tier runs the full file
+@pytest.mark.slow  # 25 s
 def test_preempt_deadline_starved_degrades_to_clean_fence(ff, monkeypatch):
     """slow_evac stalls the first slab export past a tiny preemption
     deadline: evacuation aborts, the replica is FENCED (this one IS a
@@ -484,7 +483,7 @@ def test_preempt_deadline_starved_degrades_to_clean_fence(ff, monkeypatch):
         router.close()
 
 
-@pytest.mark.slow  # 15 s; elastic_serve CI tier runs the full file
+@pytest.mark.slow  # 15 s
 def test_autoscaler_drives_real_router(ff, monkeypatch):
     """The policy wired to a REAL fleet: a scripted breach grows it via
     add_replica (newcomer serves token-identical), scripted idleness

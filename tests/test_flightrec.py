@@ -377,7 +377,7 @@ def test_telemetry_off_short_circuits_everything(tmp_path):
         assert flightrec.dump() is None
     finally:
         telemetry.set_enabled(prev)
-    # the module's own gate (the bench control arm) behaves identically
+    # the module's own gate (the recorder alone off) behaves identically
     flightrec.set_enabled(False)
     try:
         flightrec.trip("fence")
@@ -478,7 +478,6 @@ def test_healthz_and_slo_json_endpoints(tmp_path):
 # -------------------------------------------------- engine integration
 
 
-@pytest.mark.slow  # model-fixture-heavy; the obs CI tier runs it
 def test_engine_sources_ride_the_bundle(ff, tmp_path):
     prev = ff.config.flight_recorder_dir
     ff.config.flight_recorder_dir = str(tmp_path)
@@ -508,7 +507,6 @@ def test_engine_sources_ride_the_bundle(ff, tmp_path):
         ff.config.flight_recorder_dir = prev
 
 
-@pytest.mark.slow  # model-fixture-heavy; the obs CI tier runs it
 def test_model_dump_flight_record_and_off_contract(ff, tmp_path):
     path = ff.dump_flight_record(directory=str(tmp_path), note="drill")
     assert path and os.path.isdir(path)

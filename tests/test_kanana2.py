@@ -312,7 +312,7 @@ def walked_case(request):
     v = jax.random.normal(ks[2], (b, sk, h, dv))
     do = jax.random.normal(ks[3], (b, sq, h, dv))
     scale = dqk ** -0.5
-    bq, bk = _resolve_blocks("flash_fwd", sq, sk, dqk, q.dtype, block, block)
+    bq, bk = _resolve_blocks(sq, sk, block, block)
     counts = flash_tile_counts(sq, sk, bq, bk, sk - sq, True)
     # every class of tile is there to be walked
     assert min(counts["live"] - counts["masked"], counts["masked"],

@@ -73,7 +73,7 @@ def test_longctx_knob_validation():
     assert cfg.seq_parallel_shards == 2
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
+@pytest.mark.slow  # model fixture
 def test_longctx_engine_router_validation(ff):
     # the chunk is the interleave quantum: interleaving without chunked
     # prefill has no unit of work to schedule
@@ -87,7 +87,7 @@ def test_longctx_engine_router_validation(ff):
                                start=False)
 
 
-@pytest.mark.slow  # builds 4 engines; longctx CI tier runs the full file
+@pytest.mark.slow  # builds 4 engines
 def test_kv_pages_default_derive_leaves_prefix_slack(ff):
     """The PR 11 finding, fixed: the derived pool must leave slack
     beyond the slots' own pages, or every published prefix page fights
@@ -113,7 +113,6 @@ def test_kv_pages_default_derive_leaves_prefix_slack(ff):
     assert wide.num_pages == 1 + 32 + 16
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
 def test_longctx_stats_keys_pinned(ff):
     eng = ff.make_serving_engine(serve_slots=1, kv_page_size=PS,
                                  max_seq_len=32, prefill_chunk=PS,
@@ -166,7 +165,7 @@ def test_interleaved_prefill_token_identical(ff):
     assert got_s == want_s, "interleaving changed a sampled stream"
 
 
-@pytest.mark.slow  # ~15 s; longctx CI tier runs the full file
+@pytest.mark.slow  # ~15 s
 def test_interleaved_prefill_identity_int8_and_pallas(ff):
     """The same identity under an int8 pool and the pallas write impl:
     the interleaved final scatter must land bitwise the pages the
@@ -189,10 +188,47 @@ def test_interleaved_prefill_identity_int8_and_pallas(ff):
         assert got == want, f"interleave changed a stream under {kw}"
 
 
+@pytest.mark.parametrize("budget", [1, 0], ids=["interleaved",
+                                                "run_to_completion"])
+def test_decode_stream_and_a_long_prompts_prefill(ff, budget):
+    """The head-of-line contract, in ticks, not seconds: a stream is
+    mid-decode when a long prompt arrives. Interleaved admission spends
+    one chunk a tick, so the stream emits a token on every tick the long
+    prompt's slot is mid-prefill; run-to-completion admission never
+    leaves a slot mid-prefill between ticks (the whole prefill sits in
+    one tick, in front of the stream). The streams are the same."""
+    stream, long = _prompts(37, [5, 14])
+    eng = ff.make_serving_engine(serve_slots=2, kv_page_size=PS,
+                                 max_seq_len=32, prefill_chunk=PS,
+                                 prefill_interleave_chunks=budget,
+                                 prefix_cache=False, decode_chunk=1)
+    fr = eng.submit(stream, max_new_tokens=12)
+    while len(fr.tokens) < 2:          # a live stream, not a cold start
+        eng.step()
+    mr = eng.submit(long, max_new_tokens=3)
+    mid_prefill_ticks = stream_tokens_meanwhile = 0
+    while {fr.state, mr.state} - {"done", "failed", "timeout"}:
+        before = len(fr.tokens)
+        eng.step()
+        if eng.stats()["prefill_partial_slots"]:
+            mid_prefill_ticks += 1
+            stream_tokens_meanwhile += len(fr.tokens) - before
+    assert (fr.state, mr.state) == ("done", "done")
+    if budget:
+        assert mid_prefill_ticks >= 16 // PS - 1   # bucket 16, a chunk a tick
+        assert stream_tokens_meanwhile >= mid_prefill_ticks
+    else:
+        assert mid_prefill_ticks == 0
+    solo = [list(r.tokens) for r in ff.make_serving_engine(
+        serve_slots=2, kv_page_size=PS, max_seq_len=32, prefill_chunk=PS,
+        prefix_cache=False).run([stream, long], max_new_tokens=12)]
+    assert list(fr.tokens) == solo[0] and list(mr.tokens) == solo[1][:3]
+
+
 # ---- mid-prefill deadline / fault / drain legs ----------------------------
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
+@pytest.mark.slow  # model fixture
 def test_mid_prefill_deadline_expires(ff):
     eng = ff.make_serving_engine(serve_slots=1, kv_page_size=PS,
                                  max_seq_len=32, prefill_chunk=PS,
@@ -211,7 +247,7 @@ def test_mid_prefill_deadline_expires(ff):
     assert [r.state for r in done] == ["done", "done"]
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
+@pytest.mark.slow  # model fixture
 def test_mid_prefill_nan_poison_fails_request(ff, monkeypatch):
     """The nan_loss drill must catch an interleaved admission too: the
     poison rides the slot-resident partial state into the FINAL chunk's
@@ -230,7 +266,7 @@ def test_mid_prefill_nan_poison_fails_request(ff, monkeypatch):
     assert eng.stats()["failed"] == 1
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
+@pytest.mark.slow  # model fixture
 def test_drain_completes_mid_prefill_slots(ff):
     """An admitted request is never cancelled: drain() must keep
     spending prefill quanta until mid-prefill slots finish and decode
@@ -246,7 +282,6 @@ def test_drain_completes_mid_prefill_slots(ff):
     assert st["drained"] and eng.stats()["prefill_partial_slots"] == 0
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
 def test_interleave_emits_intertoken_histogram(ff):
     """The inter-token histogram (the head-of-line metric this ISSUE
     exists to flatten) must keep counting under interleaved admission."""

@@ -1,7 +1,5 @@
 """MFU levers (VERDICT r2 #4): bf16 master weights and the fused
-residual-add + layernorm op. Numerics verified on the CPU mesh; the
-bench ablates them on hardware via FF_BENCH_MASTER_DTYPE /
-FF_BENCH_FUSED_LN."""
+residual-add + layernorm op. Numerics verified on the CPU mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -314,7 +312,7 @@ def test_fused_grad_dtype_mismatch_buckets_by_param_dtype():
 @pytest.mark.parametrize("opt_kind", ["sgd", "adam"])
 @pytest.mark.parametrize("master", ["float32", "bfloat16"])
 def test_fused_optimizer_scanned_training_bitwise(opt_kind, master):
-    """train_scanned + FusedUpdate (the bench's chip-ablation path): the
+    """train_scanned + FusedUpdate: the
     scanned multi-step program with the fused update must be bit-identical
     to the per-leaf update — a break here would burn the TPU ablation
     window."""

@@ -1,6 +1,6 @@
 """Pallas paged-attention decode kernel (ops/pallas_kernels.py
-paged_attention_fwd_pallas) and its routing knob
-(FFConfig.paged_attention_impl).
+paged_attention_fwd_pallas) and its routing (the engine's
+paged_attention_impl argument).
 
 Correctness anchors:
   * kernel vs the einsum page-gather oracle (bitwise the dense-cache
@@ -18,7 +18,7 @@ Correctness anchors:
     a dead page never reaches the arithmetic.
 
 On CPU the kernel runs in interpret mode — the REAL kernel code path,
-executed by every CI tier (the ISSUE-7 routing requirement).
+executed by the suite (the ISSUE-7 routing requirement).
 """
 
 import jax
@@ -231,25 +231,127 @@ def test_kernel_raw_entrypoint_gqa_rows(ff, attn):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
 
 
-def test_resolve_impl_knob(ff):
-    """auto resolves per backend; bad values are rejected; the FFConfig
-    knob validates."""
+def test_resolve_impl_knob():
+    """auto resolves per backend; bad values are rejected; FFConfig has no
+    field or flag for it (the engine's argument is the one way to ask)."""
     want_auto = "pallas" if jax.default_backend() == "tpu" else "einsum"
-    assert resolve_paged_attention_impl(None, ff.config) == want_auto
-    assert resolve_paged_attention_impl("auto", None) == want_auto
-    assert resolve_paged_attention_impl("pallas", ff.config) == "pallas"
-    assert resolve_paged_attention_impl("einsum", None) == "einsum"
+    assert resolve_paged_attention_impl(None) == want_auto
+    assert resolve_paged_attention_impl("auto") == want_auto
+    assert resolve_paged_attention_impl("pallas") == "pallas"
+    assert resolve_paged_attention_impl("einsum") == "einsum"
     with pytest.raises(ValueError, match="paged_attention_impl"):
-        resolve_paged_attention_impl("cuda", None)
-    with pytest.raises(ValueError, match="paged_attention_impl"):
+        resolve_paged_attention_impl("cuda")
+    with pytest.raises(TypeError, match="paged_attention_impl"):
         FFConfig(batch_size=2, mesh_shape={"data": 1},
-                 paged_attention_impl="einsums")
+                 paged_attention_impl="einsum")
     cfg = FFConfig.parse_args(["--batch-size", "2",
                                "--paged-attention-impl", "pallas"])
-    assert cfg.paged_attention_impl == "pallas"
+    assert not hasattr(cfg, "paged_attention_impl")  # an unknown flag
 
 
-@pytest.mark.slow  # ~40 s: two engines, interpret-mode kernel; kernels CI tier
+def _engine(ff, impl):
+    return ff.make_serving_engine(serve_slots=2, kv_page_size=4,
+                                  max_seq_len=32, paged_attention_impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["auto", "pallas", "einsum"])
+def test_stats_report_one_impl_under_both_names(ff, impl):
+    """An engine makes ONE choice for the decode attention and the prefill
+    page write; stats() gives it under the two names its readers ask for
+    (the benchmark's generator and chip_smoke.py fail a run unless both
+    say pallas), and carries nothing of a tune table."""
+    st = _engine(ff, impl).stats()
+    assert st["paged_attention_impl"] == st["paged_prefill_impl"] \
+        == resolve_paged_attention_impl(impl)
+    assert not [k for k in st if k.startswith("kernel_tune")]
+
+
+FLASH_SEQ, FLASH_HEADS, FLASH_DIM = 256, 2, 16
+
+
+@pytest.fixture
+def planted_table(ff, tmp_path, monkeypatch):
+    """The file a kernel autotuner this repo once had would have read, at
+    both places it looked (`FF_KERNEL_TUNE_TABLE`, and
+    `~/.cache/flexflow_tpu/kernel_tune.json`), keyed as it keyed them: for
+    the engine's exact shape the impl the backend does NOT choose, for a
+    flash call's exact shape tiles the static rule does not give."""
+    from flexflow_tpu.search import table_store
+
+    eng = _engine(ff, "einsum")
+    op0 = eng.gen.attn_ops[0]
+    wrong = ("einsum" if resolve_paged_attention_impl("auto") == "pallas"
+             else "pallas")
+    dtype = np.dtype(eng.kv.pool[op0.name]["k"].dtype).name
+    ctx = eng.pages_per_slot * eng.page_size
+    env = table_store.env_key()
+
+    def shape(sq, sk, d, b, h, causal, dt):
+        return (f"sq{sq}|sk{sk}|d{d}|b{b}|h{h}"
+                f"|{'causal' if causal else 'full'}|{dt}")
+
+    paged = (op0.qk_head_dim, eng.slots, op0.num_heads)
+    flash = shape(FLASH_SEQ, FLASH_SEQ, FLASH_DIM, 1, FLASH_HEADS, True,
+                  "float32")
+    entries = {
+        f"paged_fwd|{env}|{shape(1, ctx, *paged, True, dtype)}":
+            {"impl": wrong},
+        f"paged_prefill|{env}|"
+        f"{shape(eng.page_size, ctx, *paged, False, dtype)}":
+            {"impl": wrong},
+        f"flash_fwd|{env}|{flash}": {"blocks": [128, 128]},
+        f"flash_bwd|{env}|{flash}": {"blocks": [128, 128]},
+    }
+    home = tmp_path / "home"
+    at_home = home / ".cache" / "flexflow_tpu" / "kernel_tune.json"
+    at_home.parent.mkdir(parents=True)
+    named = tmp_path / "named.json"
+    for path in (at_home, named):
+        table_store.publish(str(path), entries)
+    monkeypatch.setenv("HOME", str(home))
+    monkeypatch.setenv("FF_KERNEL_TUNE_TABLE", str(named))
+    return wrong
+
+
+@pytest.mark.parametrize("what", ["decode_impl", "prefill_impl",
+                                  "flash_forward", "flash_backward"])
+def test_a_planted_tune_table_is_ignored(ff, planted_table, monkeypatch,
+                                         what):
+    """What chooses a kernel is the backend, and what chooses a tile is the
+    call's shape: no file on the machine changes what a program compiles
+    to. With a table planted that names the other impl and other tiles for
+    these exact shapes, the engine on `auto` and the flash kernels' entry
+    points give what they give with no file."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    if what.endswith("_impl"):
+        key = {"decode_impl": "paged_attention_impl",
+               "prefill_impl": "paged_prefill_impl"}[what]
+        got = _engine(ff, "auto").stats()[key]
+        assert got == resolve_paged_attention_impl("auto") != planted_table
+        return
+    seen = {}
+
+    def capture(*args, block_q, block_k, **kw):
+        seen["blocks"] = (block_q, block_k)
+        raise StopIteration  # the tiles are chosen; nothing need run
+
+    call = {"flash_forward": "_flash_fwd_call",
+            "flash_backward": "_flash_bwd_call"}[what]
+    monkeypatch.setattr(pk, call, capture)
+    q = jnp.zeros((1, FLASH_SEQ, FLASH_HEADS, FLASH_DIM), jnp.float32)
+    with pytest.raises(StopIteration):
+        if what == "flash_forward":
+            pk.flash_attention_fwd_pallas(q, q, q, True, 0.25)
+        else:
+            lse = jnp.zeros((FLASH_HEADS, FLASH_SEQ, 8), jnp.float32)
+            pk.flash_attention_bwd_pallas(q, q, q, q, lse, q, True, 0.25)
+    assert seen["blocks"] == (FLASH_SEQ, FLASH_SEQ)
+    assert seen["blocks"] == pk._resolve_blocks(FLASH_SEQ, FLASH_SEQ,
+                                                None, None)
+
+
+@pytest.mark.slow  # ~40 s: two engines, interpret-mode kernel
 def test_serving_token_identity_pallas_vs_einsum(ff):
     """THE acceptance pin: a full greedy serving run — prefix cache ON,
     speculative decoding ON (self-draft: the accept path genuinely
@@ -279,7 +381,7 @@ def test_serving_token_identity_pallas_vs_einsum(ff):
                           "token stream (must be a pure perf mechanism)")
 
 
-@pytest.mark.slow  # ~20 s; kernels CI tier
+@pytest.mark.slow  # ~20 s
 def test_recompile_flat_with_pallas_impl(ff):
     """The one-program serving contract survives the kernel path: after
     bucket warmup, mixed same-bucket traffic through the pallas impl
@@ -481,7 +583,7 @@ def _quant_pool(rs, attn, n_pages=10, page=4):
     }
 
 
-@pytest.mark.slow  # interpret-mode kernel; kernels CI tier
+@pytest.mark.slow  # interpret-mode kernel
 @pytest.mark.parametrize("length", [5, 13, 16])
 def test_prefill_write_kernel_bitwise_full_width(ff, attn, length):
     """The page-at-a-time VMEM scatter vs the einsum big-scatter oracle:
@@ -515,7 +617,7 @@ def test_prefill_write_kernel_bitwise_full_width(ff, attn, length):
         np.asarray(pool["k"][np.asarray(untouched)]))
 
 
-@pytest.mark.slow  # interpret-mode kernel; kernels CI tier
+@pytest.mark.slow  # interpret-mode kernel
 @pytest.mark.parametrize("length", [6, 16])
 def test_prefill_write_kernel_bitwise_quantized(ff, attn, length):
     """Quantized pools: the kernel computes page_scale/page_quantize
@@ -538,57 +640,6 @@ def test_prefill_write_kernel_bitwise_quantized(ff, attn, length):
         assert out_p[n].dtype == pool[n].dtype
         np.testing.assert_array_equal(np.asarray(out_e[n]),
                                       np.asarray(out_p[n]))
-
-
-@pytest.mark.slow  # builds engines; kernels CI tier
-def test_prefill_tune_table_roundtrip(tmp_path, ff):
-    """tune_paged_prefill persists a measured write-impl winner under
-    the 'paged_prefill' kernel key; an 'auto' engine consults it at
-    construction (lookup_paged_prefill_impl), keyed by the pool
-    STORAGE dtype so int8 and full-width entries never shadow each
-    other."""
-    import os
-
-    from flexflow_tpu.search import kernel_tune
-
-    table = str(tmp_path / "ktune.json")
-    eng = ff.make_serving_engine(serve_slots=2, kv_page_size=4,
-                                 max_seq_len=32)
-    op0 = eng.gen.attn_ops[0]
-    rec = kernel_tune.tune_paged_prefill(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, kv_heads=op0.num_kv_heads,
-        heads=op0.num_heads, slots=eng.slots, iters=1, path=table)
-    assert rec["kernel"] == "paged_prefill"
-    assert rec["impl"] in ("pallas", "einsum")
-    got = kernel_tune.lookup_paged_prefill_impl(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, dtype=jnp.float32, batch=eng.slots,
-        heads=op0.num_heads, path=table)
-    assert got == rec["impl"]
-    # dtype is in the key: the full-width entry must MISS for int8
-    assert kernel_tune.lookup_paged_prefill_impl(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, dtype=jnp.int8, batch=eng.slots,
-        heads=op0.num_heads, path=table) is None
-    old = os.environ.get("FF_KERNEL_TUNE_TABLE")
-    os.environ["FF_KERNEL_TUNE_TABLE"] = table
-    try:
-        kernel_tune.reload(table)
-        eng2 = ff.make_serving_engine(serve_slots=2, kv_page_size=4,
-                                      max_seq_len=32,
-                                      paged_attention_impl="auto")
-        assert eng2.paged_prefill_impl == rec["impl"]
-        # an explicit impl request bypasses the table
-        eng3 = ff.make_serving_engine(serve_slots=2, kv_page_size=4,
-                                      max_seq_len=32,
-                                      paged_attention_impl="pallas")
-        assert eng3.paged_prefill_impl == "pallas"
-    finally:
-        if old is None:
-            os.environ.pop("FF_KERNEL_TUNE_TABLE", None)
-        else:
-            os.environ["FF_KERNEL_TUNE_TABLE"] = old
 
 
 # ---- a window layer's ring of pages ---------------------------------------

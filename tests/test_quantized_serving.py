@@ -25,8 +25,6 @@ All quantized paths run on CPU: the Pallas kernel in interpret mode is
 the REAL kernel code path (the ISSUE-7 routing rule).
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -276,7 +274,7 @@ def test_quantized_decode_and_verify_pallas_matches_einsum(ff, attn):
 # ---- engine-level contracts ------------------------------------------------
 
 
-@pytest.mark.slow  # ~40 s: two engines; quant CI tier runs the file
+@pytest.mark.slow  # ~40 s: two engines
 def test_engine_token_identity_pallas_vs_einsum_quantized(ff):
     """THE parity pin: a greedy serving run on an int8 pool with int8
     weights, prefix cache ON and speculation ON emits exactly the same
@@ -308,7 +306,7 @@ def test_engine_token_identity_pallas_vs_einsum_quantized(ff):
                           "stream vs the einsum oracle")
 
 
-@pytest.mark.slow  # ~35 s; quant CI tier
+@pytest.mark.slow  # ~35 s
 def test_divergence_budget_vs_full_width(ff):
     """Quantized KV (+ weights) is lossy by design: greedy streams may
     diverge from the full-width path. The documented per-dtype budget
@@ -338,7 +336,7 @@ def test_divergence_budget_vs_full_width(ff):
             f"budget {DIVERGENCE_BUDGET[dt]}")
 
 
-@pytest.mark.slow  # ~15 s; quant CI tier
+@pytest.mark.slow  # ~15 s
 def test_cow_isolation_quantized(ff):
     """Copy-on-write survives quantization: borrowers mounting a cached
     prefix write tails/decodes into their OWN pages — the donor's
@@ -378,7 +376,6 @@ def test_cow_isolation_quantized(ff):
     assert st["prefix_refs_live"] == 0
 
 
-@pytest.mark.slow  # ~20 s; quant CI tier
 def test_recompile_flat_quantized(ff):
     """The one-program contract survives the quantized tier: after
     bucket warmup, mixed same-bucket traffic on an int8 pool with int8
@@ -398,8 +395,35 @@ def test_recompile_flat_quantized(ff):
         "warm quantized traffic must not recompile"
 
 
+def test_prefix_sharing_is_blind_to_the_pool_dtype(ff):
+    """The radix trie, the COW rule and the allocator never look inside a
+    page: the same skewed shared-prefix traffic finds the same hits on a
+    bf16 pool and on an int8 pool with int8 weights, and both leave the
+    pool whole after drain and flush."""
+    rs = np.random.RandomState(41)
+    system = rs.randint(1, VOCAB, (12,)).astype(np.int32)   # 3 full pages
+    prompts = [np.concatenate([system, rs.randint(
+        1, VOCAB, (n,)).astype(np.int32)]) for n in (2, 5, 1, 4, 3)] \
+        + [rs.randint(1, VOCAB, (7,)).astype(np.int32)]
+    seen = []
+    for kw in (dict(kv_cache_dtype="bf16"),
+               dict(kv_cache_dtype="int8", weight_dtype="int8")):
+        eng = ff.make_serving_engine(serve_slots=2, kv_page_size=4,
+                                     max_seq_len=64, **kw)
+        reqs = eng.run(prompts, max_new_tokens=3)
+        assert [r.state for r in reqs] == ["done"] * len(prompts)
+        st = eng.drain()
+        assert st["prefix_refs_live"] == 0
+        assert st["free_pages"] + st["kv_pages_cached"] \
+            == st["kv_pages"] - 1
+        seen.append((st["prefix_hits"], st["prefix_lookups"]))
+        eng.flush_prefix_cache()
+        assert eng.stats()["free_pages"] == st["kv_pages"] - 1
+    assert seen[0] == seen[1] and seen[0][0] >= 4, seen
+
+
 def test_stats_observability(ff):
-    """The router/bench signals: dtypes, bytes-per-token (scales
+    """The router's signals: dtypes, bytes-per-token (scales
     included), tokens-per-pool-GB and the capacity multiplier — and the
     bf16 pool halves an f32 pool without any scale machinery."""
     e8 = ff.make_serving_engine(serve_slots=1, kv_page_size=8,
@@ -442,55 +466,7 @@ def test_weight_dtype_conflict_and_validation(ff):
     assert eng.stats()["weight_dtype"] == "int8"
 
 
-def test_paged_impl_tuning_table(tmp_path, ff):
-    """tune_paged_attention persists a measured impl winner keyed by the
-    POOL dtype; an 'auto' engine consults it at construction, and an
-    entry tuned on int8 pages can never govern a full-width pool."""
-    from flexflow_tpu.search import kernel_tune
-
-    table = str(tmp_path / "ktune.json")
-    eng = ff.make_serving_engine(serve_slots=2, kv_page_size=4,
-                                 max_seq_len=32, kv_cache_dtype="int8")
-    op0 = eng.gen.attn_ops[0]
-    rec = kernel_tune.tune_paged_attention(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, kv_heads=op0.num_kv_heads,
-        heads=op0.num_heads, slots=eng.slots, kv_dtype="int8",
-        iters=1, path=table)
-    assert rec["impl"] in ("pallas", "einsum")
-    assert rec["kv_dtype"] == "int8"
-    got = kernel_tune.lookup_paged_impl(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, dtype=jnp.int8, batch=eng.slots,
-        heads=op0.num_heads, path=table)
-    assert got == rec["impl"]
-    # dtype is in the key: the int8 entry must MISS for a float32 pool
-    assert kernel_tune.lookup_paged_impl(
-        page_size=eng.page_size, pages_per_slot=eng.pages_per_slot,
-        head_dim=op0.qk_head_dim, dtype=jnp.float32, batch=eng.slots,
-        heads=op0.num_heads, path=table) is None
-    # an 'auto' engine picks the tuned winner up through the env table
-    old = os.environ.get("FF_KERNEL_TUNE_TABLE")
-    os.environ["FF_KERNEL_TUNE_TABLE"] = table
-    try:
-        kernel_tune.reload(table)
-        eng2 = ff.make_serving_engine(
-            serve_slots=2, kv_page_size=4, max_seq_len=32,
-            kv_cache_dtype="int8", paged_attention_impl="auto")
-        assert eng2.paged_attention_impl == rec["impl"]
-        # an explicit impl request bypasses the table
-        eng3 = ff.make_serving_engine(
-            serve_slots=2, kv_page_size=4, max_seq_len=32,
-            kv_cache_dtype="int8", paged_attention_impl="pallas")
-        assert eng3.paged_attention_impl == "pallas"
-    finally:
-        if old is None:
-            os.environ.pop("FF_KERNEL_TUNE_TABLE", None)
-        else:
-            os.environ["FF_KERNEL_TUNE_TABLE"] = old
-
-
-@pytest.mark.slow  # ~15 s; quant CI tier
+@pytest.mark.slow  # ~15 s
 def test_bf16_pool_serves(ff):
     """kv_cache_dtype='bf16' under f32 compute: a plain-cast pool (no
     scales) that halves pool bytes; streams complete and the pool

@@ -259,3 +259,37 @@ def test_flash_bwd_dlse_term():
     np.testing.assert_allclose(np.asarray(dk), np.asarray(gd_k), rtol=2e-4,
                                atol=2e-5)
     assert np.abs(np.asarray(dv)).max() == 0  # lse has no v dependence
+
+
+def test_cold_fallback_is_static_heuristic():
+    from flexflow_tpu.ops.pallas_kernels import _pick_block, _resolve_blocks
+
+    assert _resolve_blocks(640, 640, None, None) \
+        == (_pick_block(640, 512), _pick_block(640, 512)) == (128, 128)
+    # a side the caller pins is degraded to a divisor, the other is free
+    assert _resolve_blocks(640, 640, 640, 128) == (640, 128)
+    assert _resolve_blocks(640, 1024, 256, None) == (128, 1024)
+
+
+def test_static_rule_is_legal_for_every_admitted_sequence():
+    """The static rule alone picks the tiles: for every sequence
+    `flash_eligible` admits to the TPU (128 to 8192 in steps of 128) the
+    outer tile divides the sequence and is whole lane tiles (what a
+    (1, 1, block_q) row of lse needs), and the rows a step takes at a time
+    divide the tile and are whole lane tiles too."""
+    from flexflow_tpu.ops.pallas_kernels import (_OUTER_BLOCK, _chunk_rows,
+                                                 _pick_block,
+                                                 _resolve_blocks,
+                                                 flash_tile_counts)
+
+    for seq in range(128, 8192 + 1, 128):
+        bq, bk = _resolve_blocks(seq, seq, None, None)
+        assert bq == bk == _pick_block(seq, _OUTER_BLOCK)
+        assert seq % bq == 0 and bq % 128 == 0 and bq <= _OUTER_BLOCK
+        chunk = _chunk_rows(bq)
+        assert bq % chunk == 0 and chunk % 128 == 0
+        counts = flash_tile_counts(seq, seq, bq, bk, 0, True)
+        assert counts["masked"] == seq // bq    # the diagonal's own tiles
+    assert _pick_block(4096, _OUTER_BLOCK) == 1024
+    assert _pick_block(1536, _OUTER_BLOCK) == 512
+    assert _pick_block(640, _OUTER_BLOCK) == 128

@@ -185,7 +185,7 @@ def test_router_stats_and_health_keys(ff):
 # ---- fleet semantics (decode on both replicas) ----------------------------
 
 
-@pytest.mark.slow  # 25 s; the router CI tier runs the full file
+@pytest.mark.slow  # 25 s
 def test_fleet_token_identity_and_both_replicas_serve(ff):
     """More requests than one replica's capacity, mixed lengths: every
     stream equals its solo generate run, and least-loaded dispatch
@@ -209,17 +209,27 @@ def test_fleet_token_identity_and_both_replicas_serve(ff):
         router.close()
 
 
-@pytest.mark.slow  # 25 s; router CI tier runs the full file
-def test_crash_failover_exactly_once_token_identity(ff, monkeypatch):
+@pytest.mark.parametrize("sanitize", ["off", "on"])
+def test_crash_failover_exactly_once_token_identity(ff, monkeypatch,
+                                                    sanitize):
     """FF_FAULT crash@replica:0 mid-flight: the replica is fenced, its
     in-flight and queued work resubmits to the survivor exactly once,
-    every request completes with its solo tokens, none is duplicated."""
+    every request completes with its solo tokens, none is duplicated.
+    Under the sanitizer (every runtime lock an order-asserting proxy, every
+    engine's retrace sentinel armed by a warmup over the run's own
+    buckets) the same drill leaves no lock-order violation and no
+    post-warmup retrace."""
+    from flexflow_tpu.runtime import locks
+
     prompts = _prompts(5, [5, 9, 3, 12, 7, 6])
+    prev = locks.set_mode(sanitize)
+    locks.reset()
     router = ff.make_serving_router(replicas=2, serve_slots=2,
                                     kv_page_size=4, max_seq_len=64,
                                     decode_chunk=2, start=False)
     try:
-        router.warmup(_prompts(6, [5, 9]), max_new_tokens=2)
+        router.warmup(prompts if sanitize == "on" else _prompts(6, [5, 9]),
+                      max_new_tokens=12 if sanitize == "on" else 2)
         warm_done = router.engines[1].stats()["completed"]
         _arm_fault(monkeypatch, "crash(3)@replica:0")
         reqs = router.run(prompts, max_new_tokens=12, timeout=300)
@@ -237,12 +247,22 @@ def test_crash_failover_exactly_once_token_identity(ff, monkeypatch):
         assert router.engines[1].stats()["completed"] - warm_done == sum(
             1 for r in reqs if r.replica == 1)
         assert router.health()["alive"] == 1
+        if sanitize == "on":
+            assert hasattr(router.engines[1]._lock, "rank"), "no proxy"
+            assert locks.violations() == [], [
+                (v["outer"], v["inner"]) for v in locks.violations()]
+            assert locks.retrace_log() == [], [
+                (r["program"], r["signature"]) for r in locks.retrace_log()]
+            assert [e.stats()["sanitizer_retraces"]
+                    for e in router.engines] == [0, 0]
     finally:
         _disarm_fault(monkeypatch)
         router.close()
+        locks.set_mode(prev)
+        locks.reset()
 
 
-@pytest.mark.slow  # 45 s; router CI tier runs the full file — the
+@pytest.mark.slow  # 45 s — the
 # satellite pin: failover token identity with prefix cache AND
 # speculation live on both replicas
 def test_requeue_after_crash_token_identity_with_prefix_and_spec(
@@ -288,7 +308,6 @@ def test_requeue_after_crash_token_identity_with_prefix_and_spec(
         router.close()
 
 
-@pytest.mark.slow  # 20 s; router CI tier runs the full file
 def test_hang_detected_fenced_and_survivor_completes(ff, monkeypatch):
     """FF_FAULT hang@replica:1: the wedged driver stops heartbeating,
     the health sweep fences it within health_timeout_s, its work moves
@@ -317,7 +336,6 @@ def test_hang_detected_fenced_and_survivor_completes(ff, monkeypatch):
         router.close()
 
 
-@pytest.mark.slow  # 20 s; router CI tier runs the full file
 def test_slow_replica_expired_inflight_not_resubmitted(ff, monkeypatch):
     """FF_FAULT slow(400)@serve:1 stalls replica 0's first admission past
     the request's 150 ms deadline; when the replica is then crashed, the
@@ -358,7 +376,6 @@ def test_slow_replica_expired_inflight_not_resubmitted(ff, monkeypatch):
         router.close()
 
 
-@pytest.mark.slow  # 20 s; router CI tier runs the full file
 def test_prefix_affinity_concentrates_shared_prompts(ff):
     """Shared-prefix traffic lands on the replica that already holds the
     prefix pages: after the first shared-prompt request homes, the rest
@@ -389,7 +406,6 @@ def test_prefix_affinity_concentrates_shared_prompts(ff):
         router.close()
 
 
-@pytest.mark.slow  # 20 s; router CI tier runs the full file
 def test_shedding_accepted_work_unaffected_and_fleet_drains(ff):
     """With a bounded queue, shed load never touches accepted work:
     accepted requests all complete solo-identical; drain() settles the
@@ -415,7 +431,7 @@ def test_shedding_accepted_work_unaffected_and_fleet_drains(ff):
         router.close()
 
 
-@pytest.mark.slow  # 15 s; router CI tier runs the full file
+@pytest.mark.slow  # 15 s
 def test_serve_fleet_api(ff):
     """FFModel.serve_fleet: the one-shot fleet surface returns outputs
     aligned with prompts (None for shed/expired) plus the fleet ledger."""

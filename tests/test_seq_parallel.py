@@ -118,7 +118,7 @@ def test_shard_bounds_cover_contiguously():
             assert sorted(sizes, reverse=True) == sizes
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
+@pytest.mark.slow  # model fixture
 @pytest.mark.parametrize("shards", [2, 3])
 def test_sharded_merge_bitwise_full_width(ff, shards):
     prompt = _prompt(41, 24)        # 6 full pages: bounds 3+3 / 2+2+2
@@ -134,7 +134,6 @@ def test_sharded_merge_bitwise_full_width(ff, shards):
     assert dec.stats()["partial_slab_imports"] == shards - 1
 
 
-@pytest.mark.slow  # model fixture; longctx CI tier runs the full file
 @pytest.mark.parametrize("shards", [2, 3])
 def test_sharded_merge_bitwise_int8(ff, shards):
     """The quantized published-state contract (PR 11) must survive the
@@ -196,7 +195,6 @@ def test_partial_export_bounds_validated(ff):
     assert whole["start_page"] == 0 and len(whole["payload"]) == 6
 
 
-@pytest.mark.slow  # ~35 s; longctx CI tier runs the full file
 def test_router_seq_parallel_token_identity(ff):
     """Fleet leg: a disaggregated router with seq_parallel_shards=2
     must emit exactly the single-engine greedy streams for prompts long

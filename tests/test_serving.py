@@ -46,7 +46,6 @@ def _prompts(seed, lengths):
     return [rs.randint(1, VOCAB, (L,)).astype(np.int32) for L in lengths]
 
 
-@pytest.mark.slow  # 17 s; the serving CI tier + serve_smoke drive continuous batching
 def test_continuous_batching_token_identical_to_sequential(ff):
     """More requests than slots, mixed lengths spanning several buckets:
     every request's emitted tokens equal its SOLO (one-request-at-a-time)
@@ -74,7 +73,7 @@ def test_continuous_batching_token_identical_to_sequential(ff):
     assert 0.0 < st["occupancy"] <= 1.0
 
 
-@pytest.mark.slow  # 8 s; serving CI tier runs the full file
+@pytest.mark.slow  # 8 s
 def test_serve_api_and_eos_retirement(ff):
     """FFModel.serve: eos retires a slot early (freeing it for the queue)
     and outputs match per-request generate with the same eos."""
@@ -139,7 +138,7 @@ def test_paged_gather_matches_dense_cache_bitwise(ff):
         np.testing.assert_array_equal(np.asarray(cache_d[name]), gathered)
 
 
-@pytest.mark.slow  # 11 s; serving CI tier runs the full file
+@pytest.mark.slow  # 11 s
 def test_early_exit_identical_to_full_scan(ff):
     """The while_loop early-exit path: identical tokens (and scores) to
     the full-length scan, with and without eos; without eos_id it simply
@@ -265,7 +264,7 @@ def test_serving_validation(ff):
                  decode_buckets=[16, 8])
 
 
-@pytest.mark.slow  # 17 s; serving CI tier runs the full file
+@pytest.mark.slow  # 17 s
 def test_decode_chunk_invariance(ff):
     """decode_chunk trades dispatch overhead for retirement granularity
     ONLY: any chunk size produces identical tokens — including requests
@@ -521,7 +520,7 @@ def draft(ff):
     return model
 
 
-@pytest.mark.slow  # 35 s; serving CI tier runs the full file
+@pytest.mark.slow  # 35 s
 def test_speculative_greedy_token_identity(ff, draft):
     """Speculative decoding at several K — including K larger than
     max_new_tokens — emits exactly the non-speculative greedy stream.
@@ -555,7 +554,7 @@ def test_speculative_greedy_token_identity(ff, draft):
                 == st["kv_pages"] - 1
 
 
-@pytest.mark.slow  # 12 s; serving CI tier runs the full file
+@pytest.mark.slow  # 12 s
 def test_speculative_with_eos_and_prefix_cache(ff, draft):
     """eos retirement mid-verify-window truncates cleanly, and the prefix
     cache + speculation compose: identical tokens to the plain engine
@@ -580,7 +579,6 @@ def test_speculative_with_eos_and_prefix_cache(ff, draft):
     assert eng.stats()["prefix_hits"] >= len(prompts) - 1
 
 
-@pytest.mark.slow  # 25 s; serving CI tier runs the full file
 def test_recompile_flat_with_prefix_and_speculation(ff, draft):
     """Warm-window flatness with BOTH features on: after one pass has
     warmed the buckets (cold + hit prefills, draft mirrors, draft decode
@@ -669,11 +667,9 @@ def test_stats_and_health_expose_pool_observability(ff):
                 "prefix_refs_live", "spec_accept_rate", "spec_proposed",
                 "spec_accepted", "speculate_k",
                 # decode-attention hot path (ISSUE 7): impl routing,
-                # pages the last dispatch's attention read, autotune
-                # table consultations
+                # pages the last dispatch's attention read
                 "paged_attention_impl", "pages_touched",
-                "last_pages_touched", "kernel_tune_hits",
-                "kernel_tune_misses"):
+                "last_pages_touched"):
         assert key in st, f"stats() missing {key}"
     assert st["pages_in_use"] == 0 and st["prefix_hit_rate"] == 0.0
     assert st["paged_attention_impl"] in ("pallas", "einsum")
@@ -712,8 +708,8 @@ def test_engine_deadline_expires_in_queue_without_dispatch(ff):
     assert eng.load() == {"active_slots": 0, "queued": 1}
 
 
-@pytest.mark.slow  # 18 s; the router drives each replica from its own
-# thread — this pins the one-engine-lock contract under real contention
+# the router drives each replica from its own thread — this pins the
+# one-engine-lock contract under real contention
 def test_engine_thread_safe_under_concurrent_submit(ff):
     """Concurrent-submit stress: four threads submit while the main
     thread drives step() — every request completes exactly once, the
@@ -779,7 +775,7 @@ def test_engine_thread_safe_under_concurrent_submit(ff):
                                       solo[0, r.prompt.size:])
 
 
-@pytest.mark.slow  # 7 s; serving CI tier runs the full file
+@pytest.mark.slow  # 7 s
 def test_explicit_buckets_and_per_request_max_new(ff):
     """Pinned decode_buckets honor their boundaries; per-request
     max_new_tokens mixes freely in one batch."""

@@ -17,7 +17,7 @@ Correctness anchors:
     (``telemetry.fault_events()``) — a drill's trace shows where the
     fault landed;
   * ``FFConfig.telemetry="off"`` / ``set_enabled(False)`` short-circuit
-    every emit (the bench's overhead control arm).
+    every emit.
 """
 
 import json
@@ -370,7 +370,6 @@ ENGINE_STATS_KEYS = {
     "prefix_evictions", "prefix_refs_live", "speculate_k",
     "spec_proposed", "spec_accepted", "spec_accept_rate",
     "paged_attention_impl", "pages_touched", "last_pages_touched",
-    "kernel_tune_hits", "kernel_tune_misses",
 }
 ENGINE_HEALTH_KEYS = {
     "status", "admitting", "active_slots", "queued", "serve_slots",
@@ -528,16 +527,33 @@ def test_router_trace_tree_complete(ff):
         router.close()
 
 
-def test_failover_span_continuity(ff, monkeypatch):
+@pytest.mark.parametrize("read", ["the live ring", "scrape and bundle"])
+def test_failover_span_continuity(ff, monkeypatch, tmp_path, read):
     """A crash-failover request keeps ONE trace: spans on both replicas
     under the same root, a resubmit annotation in between, and the
-    fault annotation marks where the drill landed."""
+    fault annotation marks where the drill landed. Read from the live
+    ring; and read as an operator would after the fact: the scrape
+    carries the latency histograms and the failover counters as labeled
+    series over both replicas, and the drill's trigger storm (crash fault,
+    replica fence) leaves exactly ONE manifest-intact flight bundle whose
+    own trace holds the failed-over requests' whole span trees."""
+    from flexflow_tpu.runtime import flightrec
+
     # crash at the 2nd busy tick: tick 1 genuinely ADMITTED work on
     # replica 0 (prefills ran), so failed-over traces carry spans from
     # both replicas; enough requests that work is still queued/in-flight
     # when the crash lands
     monkeypatch.setenv("FF_FAULT", "crash(2)@replica:0")
     faultinject.reset()
+    bundled = read == "scrape and bundle"
+    if bundled:
+        # a debounce this long keeps the storm ONE pending record, which
+        # flush() publishes after the fleet has settled
+        for knob, value in (("flight_recorder_dir", str(tmp_path)),
+                            ("flight_debounce_s", 600.0),
+                            ("flight_cooldown_s", 600.0),
+                            ("flight_window_s", 600.0)):
+            monkeypatch.setattr(ff.config, knob, value)
     try:
         # decode_chunk=2: a request takes 4+ ticks, so tick-2 work is
         # genuinely mid-decode when the replica dies
@@ -552,6 +568,10 @@ def test_failover_span_continuity(ff, monkeypatch):
         assert st["fenced"] == 1 and st["resubmitted"] >= 1
         resub = [r for r in reqs if r.attempts == 2]
         assert resub, "the crash was supposed to catch work in flight"
+        if bundled:
+            _scrape_and_bundle(router, resub, str(tmp_path))
+            router.close()
+            return
         for r in resub:
             assert r.state == "done"
             tree = telemetry.trace_tree(r.trace_id)
@@ -575,9 +595,53 @@ def test_failover_span_continuity(ff, monkeypatch):
     finally:
         monkeypatch.delenv("FF_FAULT", raising=False)
         faultinject.reset()
+        if bundled:
+            flightrec.reset()
 
 
-@pytest.mark.slow
+def _scrape_and_bundle(router, failed_over, flight_dir):
+    import os
+
+    from flexflow_tpu.runtime import flightrec
+
+    text = telemetry.registry().to_prometheus()
+    for needle in ("ff_serving_ttft_seconds_bucket",
+                   "ff_serving_intertoken_seconds_bucket",
+                   "ff_serving_queue_wait_seconds_bucket",
+                   "ff_router_ttft_seconds_bucket", "ff_router_fenced",
+                   "ff_router_resubmitted", "ff_router_timeouts",
+                   "ff_router_rejected", "ff_router_replica_up",
+                   "ff_hbm_bytes"):
+        assert needle in text, f"scrape missing {needle}"
+    for r in range(2):
+        assert f'replica="{r}",role="mixed"' in text, \
+            f"scrape has no series for replica {r}"
+    path = flightrec.recorder().flush()
+    assert path and flightrec.list_bundles(flight_dir) == [path]
+    flightrec.verify_bundle(path)
+
+    def read(name):
+        with open(os.path.join(path, name)) as f:
+            return json.load(f)
+
+    trig = read("trigger.json")
+    assert (trig["cause"], trig["args"]["kind"]) == ("fault", "crash")
+    assert "replica_fence" in [m["cause"] for m in trig["merged_triggers"]]
+    assert trig["stack"]
+    evs = read("trace.json")["traceEvents"]
+    for r in failed_over:
+        mine = [e for e in evs if e["ph"] == "X"
+                and e.get("args", {}).get("trace_id") == r.trace_id]
+        root = max((e for e in mine if e["name"] == "request"),
+                   key=lambda e: e.get("dur", 0.0))
+        t0, t1 = root["ts"], root["ts"] + root.get("dur", 0.0)
+        assert all(t0 - 1.0 <= e["ts"] <= t1 + 1.0 for e in mine), \
+            f"bundle trace incomplete for failed-over {r.trace_id}"
+    assert read("engines.json")["router"]["stats"]["fenced"] == 1
+    assert any(src.get("kv_pool", 0) > 0
+               for src in read("hbm.json")["sources"].values())
+
+
 def test_handoff_span_continuity(ff):
     """A prefill->decode handoff request keeps ONE trace: handoff_export
     on the prefill replica, handoff_import + hit prefill + decode on the
