@@ -131,6 +131,7 @@ class OperatorType(enum.Enum):
     OP_SIGMOID_SILU_MULTI = enum.auto()
     OP_ROTARY_EMBEDDING = enum.auto()
     OP_MAMBA2 = enum.auto()  # selective state-space mixer (ops/mamba.py)
+    OP_GATED_MLP = enum.auto()  # SwiGLU feed-forward as one op (ops/dense.py)
 
 
 # --- dtype lowering ---------------------------------------------------------

@@ -286,16 +286,18 @@ class FFModel:
                             rope_theta: float = 10000.0,
                             qk_norm=False, eps: float = 1e-6,
                             window: int = 0, flash_chunks: bool = False,
+                            softmax_scale: Optional[float] = None,
                             name: Optional[str] = None, **kw) -> Tensor:
         """`window` > 0: a sliding-window layer (position i sees keys
         i - window < j <= i); `qk_norm`: True over all heads of a position,
-        "head" over each head's entries (ops/attention.py)."""
+        "head" over each head's entries; `softmax_scale`: what q k^T is
+        multiplied by, None = 1 / sqrt(head size) (ops/attention.py)."""
         return self._add(MultiHeadAttention(
             self, self._name("multihead_attention", name), [query, key, value],
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, num_kv_heads=num_kv_heads, rope=rope,
             rope_theta=rope_theta, qk_norm=qk_norm, eps=eps, window=window,
-            flash_chunks=flash_chunks))
+            flash_chunks=flash_chunks, softmax_scale=softmax_scale))
 
     def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
                          q_lora_rank: Optional[int], kv_lora_rank: int,
@@ -336,6 +338,16 @@ class FFModel:
             self, self._name("mamba2", name), [input], num_heads, head_dim,
             n_groups, state_size, conv_kernel=conv_kernel,
             chunk_size=chunk_size, eps=eps))
+
+    def gated_mlp(self, input: Tensor, hidden_dim: int,
+                  name: Optional[str] = None) -> Tensor:
+        """SwiGLU feed-forward as one op (ops/dense.py `GatedMLP`): one
+        in-projection to [gate | up] of 2 x `hidden_dim`, silu(gate) * up,
+        one out-projection back."""
+        from flexflow_tpu.ops.dense import GatedMLP
+
+        return self._add(GatedMLP(self, self._name("gated_mlp", name),
+                                  [input], hidden_dim))
 
     def transformer_pipeline_stack(self, input: Tensor, num_layers: int,
                                    num_heads: int, ffn_mult: int = 4,

@@ -221,6 +221,8 @@ class MultiHeadAttention(Op):
     # implements the cache protocol generate() and the serving engine drive
     # (init_cache ... gather_paged_kv); ops/mla.py is the other op that does
     kv_cache_protocol = True
+    # `chunk_forward` takes a traced start (a chunk loop's body)
+    traced_chunk_start = True
     kernel_phase = "core"   # profiler.scope_table: an unnamed Mosaic call
 
     def __init__(self, model, name, inputs, embed_dim: int, num_heads: int,
@@ -230,7 +232,7 @@ class MultiHeadAttention(Op):
                  num_kv_heads: int = 0, rope: bool = False,
                  rope_theta: float = 10000.0, qk_norm=False,
                  eps: float = 1e-6, window: int = 0,
-                 flash_chunks: bool = False):
+                 flash_chunks: bool = False, softmax_scale=None):
         super().__init__(model, name, inputs)
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
@@ -294,6 +296,13 @@ class MultiHeadAttention(Op):
         self.head_dim = embed_dim // num_heads
         self.qk_head_dim = self.kdim // num_heads
         self.v_head_dim = self.vdim // num_heads
+        # what the logits q k^T are multiplied by before the softmax, in
+        # every path (dense, flash, chunk, decode, both paged impls): the
+        # convention 1 / sqrt(head size) unless the model states another
+        # (Granite's `attention_multiplier`)
+        self.softmax_scale = (1.0 / math.sqrt(self.qk_head_dim)
+                              if softmax_scale is None
+                              else float(softmax_scale))
         if rope:
             assert self.qk_head_dim % 2 == 0, "RoPE needs an even head dim"
         self.q_in = inputs[0].dims[-1]
@@ -409,7 +418,7 @@ class MultiHeadAttention(Op):
         q, k, v = xs[0], xs[1], xs[2]
         qh, kh, vh = self._project_qkv(params, q, k, v)
         kh, vh = self._broadcast_kv(kh, vh)
-        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        scale = self.softmax_scale
 
         seq_axes = []
         if shard_ctx is not None:
@@ -458,7 +467,7 @@ class MultiHeadAttention(Op):
                     cache["v"], vh.astype(cache["v"].dtype), (0, 0, 0, 0)),
             }
         kh, vh = self._broadcast_kv(kh, vh)
-        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        scale = self.softmax_scale
         ctx = self._dense_attention(qh, kh, vh, scale, False, None, None)
         return self._out_proj(params, ctx), new_cache
 
@@ -472,7 +481,7 @@ class MultiHeadAttention(Op):
         b, c = qh.shape[0], qh.shape[1]
         kvh = self.num_kv_heads
         grp = self.num_heads // kvh
-        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        scale = self.softmax_scale
         with jax.named_scope("core"):
             qg = qh.reshape(b, c, kvh, grp, self.qk_head_dim)
             logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck.astype(qh.dtype),
@@ -504,6 +513,20 @@ class MultiHeadAttention(Op):
             cv = jax.lax.dynamic_update_slice(
                 cache["v"], vh.astype(cache["v"].dtype), (0, start, 0, 0))
         c = qh.shape[1]
+        if not isinstance(start, int):
+            # a TRACED start: the body of a chunk loop compiled once
+            # (runtime/generation.py `_prefill`, `loop`). No static slice
+            # of the prefix exists, so the chunk attends the whole cache
+            # under the same rule; the rows behind it are not seen
+            if self.window or self.flash_chunks:
+                raise NotImplementedError(
+                    f"{self.name}: a chunk loop's traced start has no "
+                    "window slice and no flash tile alignment")
+            live = self._sees((start + jnp.arange(c))[:, None],
+                              jnp.arange(ck.shape[1])[None, :])
+            ctx = self._grouped_cache_attention(
+                qh, ck, cv, live[None, None, None, :, :])
+            return self._out_proj(params, ctx), {"k": ck, "v": cv}
         end = start + c  # python ints: a static slice of the live prefix
         lo = 0
         if self.window:
@@ -518,7 +541,7 @@ class MultiHeadAttention(Op):
             kb, vb = self._broadcast_kv(ks.astype(qh.dtype),
                                         vs.astype(qh.dtype))
             ctx = self._flash_dense(qh, kb, vb,
-                                    1.0 / math.sqrt(self.qk_head_dim), None)
+                                    self.softmax_scale, None)
         else:
             live = self._sees((start + jnp.arange(c))[:, None],
                               jnp.arange(lo, end)[None, :])
@@ -621,11 +644,14 @@ class MultiHeadAttention(Op):
         payload, so the allocator/trie/COW machinery is untouched."""
         sdtype, qmax = kv_storage_dtype(kv_dtype)
         store = sdtype if sdtype is not None else dtype
+        # heads narrower than the 128 lanes: `pack` neighbouring KV heads
+        # share a row (`pool_pack`); at pack 1 the rows are (KVH, D)
+        pack = self.pool_pack(quantized=qmax is not None)
         pool = {
-            "k": jnp.zeros((num_pages, page_size, self.num_kv_heads,
-                            self.qk_head_dim), store),
-            "v": jnp.zeros((num_pages, page_size, self.num_kv_heads,
-                            self.v_head_dim), store),
+            "k": jnp.zeros((num_pages, page_size, self.num_kv_heads // pack,
+                            self.qk_head_dim * pack), store),
+            "v": jnp.zeros((num_pages, page_size, self.num_kv_heads // pack,
+                            self.v_head_dim * pack), store),
         }
         if qmax is not None:
             pool["k_scale"] = jnp.zeros(
@@ -633,6 +659,36 @@ class MultiHeadAttention(Op):
             pool["v_scale"] = jnp.zeros(
                 (num_pages, self.num_kv_heads), jnp.float32)
         return pool
+
+    def pool_pack(self, quantized: bool = False) -> int:
+        """How many neighbouring KV heads share one row of a pool page.
+        A head narrower than the chip's 128 lanes (64) cannot be the minor
+        dim of an array in HBM without padding: the device then stores the
+        pool transposed, every reader and writer pays a copy of it, and
+        Mosaic refuses to slice a page out of it. So the pool of such an op
+        is (pages, page_size, KVH / pack, pack x D) with pack x D = 128: the
+        same bytes in the same order as (pages, page_size, KVH, D), only
+        the trailing dims merged, so whatever is gathered from it or
+        scattered to it is reshaped at the boundary (`_pool_rows`,
+        `_pool_heads`) and the pool itself never is. 1 (the shape every
+        head of 128 has) for a quantized pool, whose scales are per KV
+        head, and for a window layer's ring."""
+        d = self.qk_head_dim
+        if quantized or self.window or d != self.v_head_dim \
+                or d >= 128 or 128 % d:
+            return 1
+        pack = 128 // d
+        return pack if self.num_kv_heads % pack == 0 else 1
+
+    @staticmethod
+    def _pool_rows(x, pool):
+        """Rows (.., KVH, D) in the pool's own trailing dims."""
+        return x.reshape(*x.shape[:-2], *pool.shape[2:])
+
+    def _pool_heads(self, x, d):
+        """What was read from a pool (.., KVH / pack, pack x D) as
+        (.., KVH, D)."""
+        return x.reshape(*x.shape[:-2], self.num_kv_heads, d)
 
     def paged_prefill_write(self, cache, kh, vh, pages, impl="einsum"):
         """Scatter a slot's contiguous prefill k/v (1, L, KVH, Dh) into
@@ -673,7 +729,8 @@ class MultiHeadAttention(Op):
         for name, x in (("k", kh), ("v", vh)):
             pool = cache[name]
             if not quantized:
-                out[name] = pool.at[pages].set(paged(x).astype(pool.dtype))
+                out[name] = pool.at[pages].set(
+                    self._pool_rows(paged(x), pool).astype(pool.dtype))
                 continue
             qmax = storage_qmax(pool.dtype)
             pf = paged(x).astype(jnp.float32)
@@ -705,7 +762,7 @@ class MultiHeadAttention(Op):
             pool = cache[name]
             if not quantized:
                 out[name] = pool.at[page_ids, offs].set(
-                    x.astype(pool.dtype))
+                    self._pool_rows(x, pool).astype(pool.dtype))
                 continue
             qmax = storage_qmax(pool.dtype)
             sc = cache[name + "_scale"]
@@ -791,6 +848,8 @@ class MultiHeadAttention(Op):
                 x = cache[name][pages]                      # (n,ps,KVH,D)
                 if name + "_scale" in cache:
                     x = page_dequantize(x, cache[name + "_scale"][pages])
+                x = self._pool_heads(x, self.qk_head_dim if name == "k"
+                                     else self.v_head_dim)
                 out[name] = x.reshape(1, -1, *x.shape[2:])
         return out
 
@@ -823,7 +882,7 @@ class MultiHeadAttention(Op):
             from flexflow_tpu.ops.pallas_kernels import \
                 paged_attention_fwd_pallas
 
-            scale = 1.0 / math.sqrt(self.qk_head_dim)
+            scale = self.softmax_scale
             # directly under the op's scope: the device names an unnamed
             # Mosaic call after its innermost scope, and the benchmark's
             # readers know this one as `attn_<i>` (scope_table books it to
@@ -839,6 +898,8 @@ class MultiHeadAttention(Op):
             if "k_scale" in cache:
                 gk = page_dequantize(gk, cache["k_scale"][page_table])
                 gv = page_dequantize(gv, cache["v_scale"][page_table])
+            gk = self._pool_heads(gk, self.qk_head_dim)
+            gv = self._pool_heads(gv, self.v_head_dim)
             gk = gk.reshape(b, max_len, *gk.shape[3:])
             gv = gv.reshape(b, max_len, *gv.shape[3:])
         with jax.named_scope("core"):
@@ -910,7 +971,7 @@ class MultiHeadAttention(Op):
             zero = jnp.zeros_like(pos)
             ctx = paged_attention_fwd_pallas(
                 qh, cache["k"], cache["v"], ring, pos[:, None], zero, zero,
-                1.0 / math.sqrt(self.qk_head_dim),
+                self.softmax_scale,
                 k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
                 window=self.window)
             return self._out_proj(params, ctx), cache
@@ -1013,9 +1074,11 @@ class MultiHeadAttention(Op):
             cache = dict(cache)
             with jax.named_scope("project"):
                 cache["k"] = cache["k"].at[page_ids, offs].set(
-                    kh.astype(cache["k"].dtype))
+                    self._pool_rows(kh, cache["k"]).astype(
+                        cache["k"].dtype))
                 cache["v"] = cache["v"].at[page_ids, offs].set(
-                    vh.astype(cache["v"].dtype))
+                    self._pool_rows(vh, cache["v"]).astype(
+                        cache["v"].dtype))
         ctx = self._paged_attention_ctx(qh, cache, page_table, write_pos,
                                         row_len, prompt_pad, impl)
         return self._out_proj(params, ctx), cache

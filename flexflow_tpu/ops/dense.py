@@ -117,6 +117,54 @@ class Linear(Op):
         return 2 * batch * self.in_dim * self.out_dim
 
 
+class GatedMLP(Op):
+    """A gated feed-forward block as ONE graph op (HF `GraniteMoeHybridMLP`:
+    `input_linear`, chunk, `output_linear`):
+
+        [g | u] = x W_in          # D -> 2 F, one matmul
+        y = (silu(g) * u) W_out   # F -> D
+
+    One op, so one scope: a trace books the whole block to its name
+    (`mlp_<i>`), where `models/llama.py` `swiglu` spreads the same block
+    over five ops. No bias. Every position is independent of the others
+    (decode-safe, runtime/generation.py)."""
+
+    op_type = OperatorType.OP_GATED_MLP
+
+    def __init__(self, model, name, inputs, hidden_dim: int):
+        super().__init__(model, name, inputs)
+        self.dim = inputs[0].dims[-1]
+        self.hidden_dim = int(hidden_dim)
+        self.finalize()
+
+    def output_shapes(self):
+        return [self.inputs[0].dims], [self.inputs[0].dtype]
+
+    def weights(self) -> List[WeightSpec]:
+        d, f = self.dim, self.hidden_dim
+        # each half of w_in drawn as the (D, F) Linear it replaces
+        return [WeightSpec("w_in", (d, 2 * f), init="glorot", fan=(d, f)),
+                WeightSpec("w_out", (f, d), init="glorot")]
+
+    def forward(self, params, xs, *, training=False, rng=None):
+        import jax
+
+        x = xs[0]
+        gu = jnp.einsum("...i,io->...o", x, params["w_in"].astype(x.dtype),
+                        preferred_element_type=x.dtype)
+        h = jax.nn.silu(gu[..., :self.hidden_dim]) * gu[..., self.hidden_dim:]
+        return [jnp.einsum("...i,io->...o", h,
+                           params["w_out"].astype(x.dtype),
+                           preferred_element_type=x.dtype)]
+
+    def partitionable_output_dims(self):
+        return list(range(self.outputs[0].num_dims - 1))    # the rows
+
+    def flops(self):
+        rows = int(np.prod(self.outputs[0].dims[:-1]))
+        return 6 * rows * self.dim * self.hidden_dim
+
+
 class Embedding(Op):
     op_type = OperatorType.OP_EMBEDDING
 

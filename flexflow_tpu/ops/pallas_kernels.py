@@ -917,7 +917,7 @@ def _paged_ring(ps: int, kvh: int, dqk: int, dv: int, dtype) -> int:
 def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
                        s: int, h: int, kvh: int, ps: int, nbuf: int,
                        scale: float, quantized: bool = False,
-                       window: Optional[int] = None):
+                       window: Optional[int] = None, pack: int = 1):
     """One slot per grid step: score the slot's (S*H, Dqk) query rows
     against each of its live pages in turn and fold into the running
     online softmax (f32 m, l, acc carried by the loop). Scalar-prefetch
@@ -939,7 +939,15 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     page) keeps its pages in a RING: the table is as wide as the ring and
     logical page t lives in column t % width. The slot's loop and the
     stream's cursor start at the window's first page, and a key at or
-    below `frontier - window` is dead."""
+    below `frontier - window` is dead.
+
+    `pack` > 1 (heads narrower than a lane tile, ops/attention.py
+    `pool_pack`): a row of a page holds `pack` neighbouring KV heads side by
+    side in its 128 lanes, `kvh / pack` such rows a token, and a query row
+    carries its head's entries in the lanes of ITS KV head and zeros in the
+    others. A column is then a (token, row of `pack` KV heads), a query row
+    owns the column its KV head lies in, and its zeros keep the neighbours
+    out of its scores; its context comes out in its own lanes of the 128."""
     if window is not None:
         fp_ref, rest = rest[0], rest[1:]
         ring = pt_ref.shape[1]
@@ -951,6 +959,7 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     grp = h // kvh
+    kvh = kvh // pack           # rows a token takes in a page
     rows, cols = s * h, ps * kvh
 
     def page_copies(page, buf):
@@ -986,7 +995,7 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     # belong to a row's KV head, each column's token, each row's frontier
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-    own_head = (row % h) // grp == col % kvh            # (rows, cols)
+    own_head = (row % h) // (grp * pack) == col % kvh   # (rows, cols)
     tok = col // kvh                                    # (1, cols)
     wp = jnp.full((rows, 1), wp_ref[b, 0], jnp.int32)
     for i in range(1, s):
@@ -1093,11 +1102,24 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     b, s, h, dqk = q.shape
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[3]
+    # a pool whose rows hold `pack` neighbouring KV heads of `dqk` side by
+    # side (ops/attention.py `pool_pack`: heads narrower than the lanes)
+    pack, d0 = (dv // dqk if k_pages.shape[3] != dqk else 1), dqk
+    if pack > 1:
+        assert k_pages.shape[3] == dv == LANES, (k_pages.shape, dqk)
+        kvh = kvh * pack
+        # query head i reads KV head i // grp: its entries go to that
+        # head's lanes of the row, zeros to the others
+        lane_of = (jnp.arange(h) // (h // kvh)) % pack              # (H,)
+        q = (q[:, :, :, None, :]
+             * (lane_of[:, None] == jnp.arange(pack))[None, None, :, :, None]
+             .astype(q.dtype)).reshape(b, s, h, dv)
+        dqk = dv
     assert h % kvh == 0, f"heads {h} not a multiple of kv heads {kvh}"
     assert (k_scales is None) == (v_scales is None), \
         "quantized pools carry BOTH k and v scales"
     quantized = k_scales is not None
-    nbuf = _paged_ring(ps, kvh, dqk, dv, k_pages.dtype)
+    nbuf = _paged_ring(ps, kvh // pack, dqk, dv, k_pages.dtype)
     # last live page per slot: the live rule's bound is max(write
     # frontier, prompt tail) — a serving dispatch always has write_pos
     # >= prompt_pad >= row_len, but the kernel honors the FULL rule so
@@ -1134,8 +1156,8 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
         ],
         out_specs=pl.BlockSpec((1, s * h, dv), slot_map),
         scratch_shapes=[
-            pltpu.VMEM((nbuf, ps, kvh, dqk), k_pages.dtype),
-            pltpu.VMEM((nbuf, ps, kvh, dv), v_pages.dtype),
+            pltpu.VMEM((nbuf, ps, kvh // pack, dqk), k_pages.dtype),
+            pltpu.VMEM((nbuf, ps, kvh // pack, dv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, nbuf)),
             pltpu.SMEM((3,), jnp.int32),               # the stream's state
         ],
@@ -1143,13 +1165,19 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
                           nbuf=nbuf, scale=scale, quantized=quantized,
-                          window=window),
+                          window=window, pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s * h, dv), q.dtype),
         # sequential: the cursor and the ring carry over from slot to slot
         compiler_params=_compiler_params(("arbitrary",)),
         interpret=_interpret(),
     )(*prefetch, q.reshape(b, s * h, dqk), k_pages, v_pages)
+    if pack > 1:
+        # each head's context lies in the lanes of its KV head
+        out = jnp.take_along_axis(
+            out.reshape(b, s, h, pack, d0),
+            lane_of[None, None, :, None, None], axis=3)
+        return out.reshape(b, s, h, d0)
     return out.reshape(b, s, h, dv)
 
 

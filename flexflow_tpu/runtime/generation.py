@@ -71,6 +71,7 @@ _DECODE_SAFE = {
     # ZERO drops (a token never picks the same expert twice): either
     # way the row independence that decode promises
     OperatorType.OP_MOE,
+    OperatorType.OP_GATED_MLP,
 }
 
 
@@ -443,7 +444,9 @@ class Generator:
             return op.step_forward(p, xs, state)
         if gather_last:
             return op.last_forward(p, xs, state)
-        return op.scan_forward(p, xs, state, chunk_start or 0, row_lengths)
+        return op.scan_forward(p, xs, state,
+                               0 if chunk_start is None else chunk_start,
+                               row_lengths)
 
     def init_caches(self, batch: int, max_len: int, dtype):
         """The contiguous per-request caches of one prefill (and of
@@ -465,12 +468,13 @@ class Generator:
             return jnp.broadcast_to((paged["row_len"] > 0)[:, None], shape)
         if row_lengths is None or gather_last:
             return None
-        at = (chunk_start or 0) + jnp.arange(shape[1])
+        at = (0 if chunk_start is None else chunk_start) \
+            + jnp.arange(shape[1])
         return at[None, :] < row_lengths[:, None]
 
     def _prefill(self, params, state, tokens, caches, row_lengths,
                  prefill_chunk, lora=None, routing=None, lowerings=None,
-                 expert_rows=None):
+                 expert_rows=None, loop=False):
         """Whole-prompt prefill, or chunked (`prefill_chunk` > 0 and the
         prompt longer than it): each chunk writes its k/v and attends the
         static prefix slice under the same causal rule — score memory is
@@ -503,6 +507,9 @@ class Generator:
                               last_only=True, row_lengths=row_lengths,
                               prompt_len=s0, lora=lora, routing=routing,
                               lowerings=lowerings, expert_rows=expert_rows)
+        if loop:
+            return self._prefill_loop(params, state, tokens, caches,
+                                      row_lengths, prefill_chunk, lora)
         starts = list(range(0, s0, prefill_chunk))
         if row_lengths is not None:
             for st in starts:
@@ -532,6 +539,49 @@ class Generator:
                           last_only=True, chunk_start=st, lora=lora,
                           routing=routing, lowerings=lowerings,
                           expert_rows=expert_rows)
+
+    def chunk_loop_refusal(self) -> Optional[str]:
+        """Why this graph's chunks cannot run as one loop body, or None."""
+        for op in self.attn_ops:
+            if not getattr(op, "traced_chunk_start", False) \
+                    or getattr(op, "window", 0) \
+                    or getattr(op, "flash_chunks", False):
+                return (f"{op.name} attends a static slice of its prefix "
+                        "(a window, a flash tile, a selection)")
+        if self.dropless_moe_ops:
+            return (f"{self.dropless_moe_ops[0].name} counts its routing "
+                    "walk by walk")
+        return None
+
+    def _prefill_loop(self, params, state, tokens, caches, row_lengths,
+                      chunk, lora=None):
+        """The ragged chunked prefill as ONE loop: a `fori_loop` over the
+        chunk starts whose body (one walk of `chunk` rows from a TRACED
+        start) is compiled once, where `_prefill` unrolls a body a chunk
+        (a 40-layer graph's 16 k bucket in 16 chunks is 640 layer bodies:
+        minutes of compile). It runs only the chunks that hold live rows
+        (`row_lengths`), so one bucket's program costs a short prompt
+        little. The attention ops attend the whole contiguous cache under
+        the causal rule (no static slice of the prefix exists); the state
+        ops carry their state from chunk to chunk as ever. Ends with the
+        gather pass of the ragged chunked prefill."""
+        assert tokens.shape[1] % chunk == 0, (tokens.shape, chunk)
+
+        def body(i, caches):
+            st = i * chunk
+            toks = jax.lax.dynamic_slice_in_dim(tokens, st, chunk, axis=1)
+            _, new = self._walk(params, state, toks, caches, None,
+                                chunk_start=st, skip_tail=True, lora=lora,
+                                row_lengths=row_lengths)
+            return new
+
+        n = (jnp.max(row_lengths) + chunk - 1) // chunk
+        caches = jax.lax.fori_loop(0, n, body, caches)
+        tok_last = jnp.take_along_axis(
+            tokens, (row_lengths - 1)[:, None], axis=1)          # (B, 1)
+        return self._walk(params, state, tok_last, caches, None,
+                          last_only=True, row_lengths=row_lengths,
+                          gather_last=True, lora=lora)
 
     # ---- sampling ----------------------------------------------------------
 

@@ -54,7 +54,7 @@ class _TrieNode:
     ordered publisher instead of resurrecting a reused node."""
 
     __slots__ = ("chunk", "page", "parent", "children", "ref", "last_use",
-                 "tier", "hostdata", "gen")
+                 "tier", "hostdata", "gen", "snap")
 
     def __init__(self, chunk, page, parent):
         self.chunk = chunk
@@ -66,6 +66,10 @@ class _TrieNode:
         self.tier = "hbm"
         self.hostdata = None
         self.gen = 0
+        # a model with recurrent-state ops: the row of the pool's snapshot
+        # arrays that holds every such op's state after EXACTLY the tokens
+        # of the path that ends here; 0 = none
+        self.snap = 0
 
 
 class RadixPrefixCache:
@@ -115,10 +119,24 @@ class RadixPrefixCache:
     ``evict()`` walks the whole trie per pressure call, which is fine at
     the pool sizes this engine runs (hundreds of pages) — a
     persistently-maintained ref-0-leaf LRU makes reclaim O(need) if
-    pool sizes grow by orders of magnitude."""
+    pool sizes grow by orders of magnitude.
+
+    SNAPSHOTS (``snapshots`` > 0: the model keeps a recurrent state beside
+    its pages, ops/mamba.py). The pages of a prefix are only half of what a
+    borrower needs: the state ops' state AFTER the prefix is the other, and
+    it cannot be computed from the pages. So a node may carry a snapshot id
+    (``node.snap``, a row of the pool's snapshot arrays, ids 1..snapshots;
+    row 0 is the scratch row a program that takes no snapshot writes), and
+    the trie keeps ONE invariant: no page is cached that no snapshot makes
+    reachable, i.e. every leaf carries a snapshot. ``match`` returns the
+    path to the deepest node that carries one; ``insert_snapshot`` publishes
+    a path only together with the snapshot on its last node; a snapshot
+    leaves with its node (eviction, flush, forget: ``_kill_subtree`` hands
+    the id back), and the ancestors it alone made reachable leave with it.
+    The trie owns ids, free list and counts; the arrays are the pool's."""
 
     def __init__(self, page_size: int, host_pages: int = 0,
-                 d2h=None, h2d=None):
+                 d2h=None, h2d=None, snapshots: int = 0):
         self.page_size = int(page_size)
         self.root = _TrieNode(None, -1, None)
         self.pages = 0          # HBM-page-holding nodes currently cached
@@ -144,6 +162,17 @@ class RadixPrefixCache:
         self.host_pages = int(host_pages)
         if self.host_pages < 0:
             raise ValueError(f"host_pages={host_pages}: must be >= 0")
+        # ---- snapshots of a recurrent state (ids 1..snapshots) ----
+        self.snapshots = int(snapshots)
+        if self.snapshots and self.host_pages:
+            raise ValueError(
+                "the host tier moves pages only: a demoted prefix would "
+                "leave its snapshot behind (snapshots > 0 needs "
+                "host_pages == 0)")
+        self._free_snaps = list(range(self.snapshots, 0, -1))
+        self.snapshots_taken = 0
+        self.snapshot_hits = 0
+        self.snapshots_evicted = 0  # left with their node, for any reason
         if self.host_pages and (d2h is None or h2d is None):
             raise ValueError("host_pages > 0 needs d2h and h2d callables")
         self.d2h = d2h
@@ -214,6 +243,11 @@ class RadixPrefixCache:
             node = child
         for n in path:
             n.last_use = self._tick
+        if self.snapshots:
+            # a borrower resumes from a state: the match ends at the
+            # deepest node that carries one, deeper pages are not leased
+            while path and not path[-1].snap:
+                path.pop()
         return path
 
     def note_admitted(self, matched_pages: int):
@@ -223,6 +257,8 @@ class RadixPrefixCache:
         if matched_pages:
             self.hits += 1
             self.tokens_saved += matched_pages * self.page_size
+            if self.snapshots:
+                self.snapshot_hits += 1
 
     def acquire(self, nodes):
         for n in nodes:
@@ -270,6 +306,80 @@ class RadixPrefixCache:
             created.append(child)
             self.pages += 1
         return created
+
+    # ---- snapshots --------------------------------------------------------
+
+    def insert_snapshot(self, prompt, matched, start: int, pages: List[int],
+                        snap: int, ns=None):
+        """Publish a finished prefill of a model with recurrent-state ops:
+        ``pages[j]`` holds chunk ``start + j`` of ``prompt`` and ``snap``
+        the state after the LAST of them. Walks down from the ``matched``
+        path: a chunk that is cached already is passed through (the
+        caller's duplicate page stays private), a missing one gets the
+        caller's page (ref 1, as ``insert``). The snapshot goes on the
+        last node unless it has one. Returns (created nodes, whether the
+        trie took ``snap``); an id it did not take is the caller's to
+        hand back (``release_snapshot_id``)."""
+        node = matched[-1] if matched else self.root
+        created = []
+        for j, page in enumerate(pages):
+            chunk = self._chunk(prompt, start + j, ns)
+            child = node.children.get(chunk)
+            if child is None:
+                child = _TrieNode(chunk, page, node)
+                child.ref = 1
+                self._live_refs += 1
+                node.children[chunk] = child
+                created.append(child)
+                self.pages += 1
+            child.last_use = self._tick
+            node = child
+        took = bool(pages) and not node.snap
+        if took:
+            node.snap = int(snap)
+            self.snapshots_taken += 1
+        return created, took
+
+    def snapshot_id(self) -> int:
+        """Pop a free snapshot id; 0 when every one is on a node."""
+        return self._free_snaps.pop() if self._free_snaps else 0
+
+    def release_snapshot_id(self, snap: int) -> None:
+        if snap:
+            self._free_snaps.append(int(snap))
+
+    def evict_snapshot(self, protect=()) -> List[int]:
+        """Pressure on the snapshot ids: the least recently used unmounted
+        leaf outside ``protect`` leaves, with its snapshot and the
+        ancestors it alone made reachable. Returns the pages freed
+        (empty: every leaf is mounted or protected)."""
+        keep = set(id(n) for n in protect)
+        leaves = [n for n in self._iter_nodes()
+                  if not n.children and n.ref == 0 and id(n) not in keep]
+        if not leaves:
+            return []
+        self.evictions += 1
+        return self._kill_reachable(min(leaves, key=lambda n: n.last_use),
+                                    keep)
+
+    def _kill_reachable(self, node, keep=()) -> List[int]:
+        """``_kill_subtree(node)`` and, where the trie holds snapshots, the
+        chain of ancestors left without child and snapshot (pages that no
+        snapshot makes reachable any more), as far as they are unmounted
+        and outside ``keep``."""
+        parent = node.parent
+        freed = self._kill_subtree(node)
+        while (self.snapshots and parent is not None
+               and parent is not self.root and not parent.children
+               and not parent.snap and parent.ref == 0
+               and id(parent) not in keep):
+            node, parent = parent, parent.parent
+            freed.extend(self._kill_subtree(node))
+        return freed
+
+    @property
+    def snapshots_held(self) -> int:
+        return self.snapshots - len(self._free_snaps)
 
     def _iter_nodes(self):
         stack = list(self.root.children.values())
@@ -374,10 +484,13 @@ class RadixPrefixCache:
                     selected.append(n)
                 self.evictions += 1
             else:
-                freed.extend(self._kill_subtree(n))
+                freed.extend(self._kill_reachable(n, keep))
                 if pressure:
                     self.evictions += 1
-            if parent is not self.root and reclaimable(parent):
+            while parent is not None and parent.tier == "reaped":
+                parent = parent.parent  # the chain left with its snapshot
+            if parent is not None and parent is not self.root \
+                    and reclaimable(parent):
                 heapq.heappush(heap, (parent.last_use, id(parent), parent))
         # a failed-demotion kill (d2h_fail on a parent) may have reaped
         # an already-selected descendant — its page was freed by the
@@ -430,6 +543,10 @@ class RadixPrefixCache:
                     # selected-for-demotion but not yet snapshot: its
                     # pool page is still allocated — free it too
                     freed.append(n.page)
+            if n.snap:
+                self._free_snaps.append(n.snap)
+                self.snapshots_evicted += 1
+                n.snap = 0
             n.tier = "reaped"
             n.page = -1
             n.hostdata = None
@@ -622,6 +739,9 @@ class RadixPrefixCache:
             if n.children or n.ref:
                 break
             freed.extend(self._kill_subtree(n))
+            if self.snapshots and n.parent is not self.root \
+                    and n.parent.snap:
+                break       # the rest of the path is another snapshot's
         return freed
 
     def flush_namespace(self, ns) -> List[int]:
@@ -677,15 +797,28 @@ class Lease:
     fresh ones. ``hold`` tells a request's lease, which lives until
     ``release``, from a publisher's, which ends at ``publish``."""
 
-    __slots__ = ("matched", "need", "hold", "nodes", "private", "pages")
+    __slots__ = ("matched", "need", "hold", "nodes", "private", "pages",
+                 "snap", "wants_snap")
 
-    def __init__(self, matched, need: int, hold: bool):
+    def __init__(self, matched, need: int, hold: bool,
+                 wants_snap: bool = False):
         self.matched = matched
         self.need = need
         self.hold = hold
         self.nodes: List[_TrieNode] = []
         self.private: List[int] = []
         self.pages: List[int] = []
+        # a model with recurrent-state ops: whether this prefill ends on a
+        # page boundary (its final state IS the state after its last page:
+        # ``reserve``), and from ``commit`` the snapshot row it writes
+        # (0 = the scratch row: nothing will be published)
+        self.wants_snap = wants_snap
+        self.snap = 0
+
+    @property
+    def snap_from(self) -> int:
+        """The snapshot row a hit resumes from (0 on a cold prefill)."""
+        return self.matched[-1].snap if self.matched else 0
 
 
 def op_keeps(op):
@@ -789,7 +922,8 @@ class KVPagePool:
 
     def __init__(self, gen, draft_gen, num_pages: int, page_size: int,
                  pages_per_slot: int, kv_dtype, prefix_cache: bool,
-                 host_pages: int, page_import, slots: int = 0):
+                 host_pages: int, page_import, slots: int = 0,
+                 snapshots: int = 0):
         self.gen = gen
         self.slots = int(slots)
         self.draft_gen = draft_gen
@@ -817,9 +951,25 @@ class KVPagePool:
         self._free_pages = list(range(self.num_pages - 1, 0, -1))
         # host_pages > 0 gives the trie a pinned host-memory second tier
         # whose D2H/H2D are this pool's own movers
+        state_ops = list(getattr(gen, "state_ops", ()))
+        snapshots = int(snapshots) if (prefix_cache and state_ops) else 0
         self.prefix_cache = (RadixPrefixCache(
             self.page_size, host_pages=host_pages,
-            d2h=self.d2h, h2d=self.h2d) if prefix_cache else None)
+            d2h=self.d2h, h2d=self.h2d, snapshots=snapshots)
+            if prefix_cache else None)
+        # beside the slots' states: the snapshots the trie's nodes carry,
+        # one row an id and row 0 the scratch row, in arrays of the ops'
+        # own pool format. Written by the prefill programs only (a prefill
+        # that ends on a page boundary), read by the hit prefills; None
+        # for every engine without a prefix cache or without state ops.
+        self.snapshots = None
+        if snapshots:
+            repl = NamedSharding(gen.model.mesh, PartitionSpec())
+            self.snapshots = {
+                op.name: jax.tree.map(
+                    lambda a: jax.device_put(a, repl),
+                    op.init_state_pool(snapshots + 1, gen._compute_dtype()))
+                for op in state_ops}
 
     def _init_arrays(self, gen, kv_dtype):
         # COMMITTED (replicated on the model's mesh) up front: an
@@ -909,6 +1059,11 @@ class KVPagePool:
                    if trie is not None else [])
         if not hold and len(matched) >= len(prompt) // self.page_size:
             return Lease(matched, 0, hold)
+        # where the trie holds snapshots, a prefill publishes only if its
+        # last live row is the last row of a page: then the state it ends
+        # with is the state after that path
+        wants = bool(self.snapshots) and len(prompt) >= self.page_size \
+            and len(prompt) % self.page_size == 0
         host = [n for n in matched if n.tier != "hbm"]
         if not self.make_room(n_pages - len(matched) + len(host), matched):
             return None
@@ -919,7 +1074,7 @@ class KVPagePool:
             matched = matched[:len(matched) - len(host) + k]
             if len(self._free_pages) < n_pages - len(matched):
                 return None     # raced shortfall after a failed promotion
-        return Lease(matched, n_pages - len(matched), hold)
+        return Lease(matched, n_pages - len(matched), hold, wants)
 
     def commit(self, lease: Lease) -> None:
         """Take what ``reserve`` found: pop the fresh pages and mount the
@@ -933,6 +1088,14 @@ class KVPagePool:
         lease.nodes = list(lease.matched)
         lease.private = fresh
         lease.pages = [n.page for n in lease.matched] + fresh
+        if lease.wants_snap:
+            # the matched path is mounted by now: pressure on the ids
+            # cannot take the snapshot this prefill resumes from
+            trie = self.prefix_cache
+            lease.snap = trie.snapshot_id()
+            if not lease.snap:
+                self._free_pages.extend(trie.evict_snapshot())
+                lease.snap = trie.snapshot_id()
 
     def publish(self, lease: Lease, prompt, ns, ok: bool) -> int:
         """A finished prefill offers ``prompt``'s FULL pages past the
@@ -945,11 +1108,26 @@ class KVPagePool:
         what the trie did not adopt returns to the free list. Returns
         the pages published."""
         created = []
-        if ok and self.prefix_cache is not None:
+        trie = self.prefix_cache
+        if trie is not None and trie.snapshots:
+            # pages and the snapshot on the last of them, or nothing: no
+            # page is published that no snapshot makes reachable
+            took = False
+            if ok and lease.snap:
+                full = len(lease.matched)
+                created, took = trie.insert_snapshot(
+                    prompt, lease.matched, full,
+                    lease.pages[full:len(prompt) // self.page_size],
+                    lease.snap, ns=ns)
+            if not took:
+                trie.release_snapshot_id(lease.snap)
+            lease.snap = 0
+        elif ok and trie is not None:
             full = len(lease.matched)
-            created = self.prefix_cache.insert(
+            created = trie.insert(
                 prompt, lease.matched, full,
                 lease.pages[full:len(prompt) // self.page_size], ns=ns)
+        if created:
             adopted = {n.page for n in created}
             lease.nodes.extend(created)
             lease.private = [p for p in lease.private if p not in adopted]
@@ -967,6 +1145,9 @@ class KVPagePool:
             lease.nodes = []
         self._free_pages.extend(lease.private)
         lease.private = []
+        if lease.snap:      # committed and never published (a retired slot)
+            self.prefix_cache.release_snapshot_id(lease.snap)
+            lease.snap = 0
 
     def flush(self) -> int:
         """Evict EVERY refcount-0 cached page, both tiers, back to the

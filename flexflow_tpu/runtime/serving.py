@@ -290,7 +290,9 @@ class ServingEngine:
                  adapter_pool_pages: Optional[int] = None,
                  lora_rank: Optional[int] = None,
                  lora_targets: Optional[List[str]] = None,
-                 prefill_interleave_chunks: Optional[int] = None):
+                 prefill_interleave_chunks: Optional[int] = None,
+                 state_snapshots: Optional[int] = None,
+                 prefill_chunk_loop: bool = False):
         cfg = model.config
         # sanitize mode is read at LOCK CREATION time: adopt
         # FFConfig.sanitize before this engine (or its pools)
@@ -347,6 +349,15 @@ class ServingEngine:
             raise ValueError(
                 f"prefill_interleave_chunks="
                 f"{self.prefill_interleave_chunks}: must be >= 0")
+        # a cold prefill's chunks as ONE loop whose body compiles once
+        # (runtime/generation.py `_prefill_loop`), not a body a chunk
+        self.prefill_chunk_loop = bool(prefill_chunk_loop)
+        if self.prefill_chunk_loop and (
+                self.prefill_chunk <= 0 or self.prefill_interleave_chunks):
+            raise ValueError(
+                "prefill_chunk_loop loops over chunks of prefill_chunk "
+                "rows inside one program: it needs prefill_chunk > 0 and "
+                "prefill_interleave_chunks == 0")
         if self.prefill_interleave_chunks and self.prefill_chunk <= 0:
             raise ValueError(
                 "prefill_interleave_chunks > 0 needs prefill_chunk > 0: "
@@ -433,6 +444,9 @@ class ServingEngine:
                              eos_id=eos_id, pad_id=pad_id, quantize=quantize)
         self.eos_id = eos_id
         self.pad_id = pad_id
+        refusal = self.prefill_chunk_loop and self.gen.chunk_loop_refusal()
+        if refusal:
+            raise ValueError(f"prefill_chunk_loop: {refusal}")
         cdtype = self.gen._compute_dtype()
         if self._kv_dtype_arg is None:
             self.kv_cache_dtype = jnp.dtype(cdtype).name
@@ -474,17 +488,31 @@ class ServingEngine:
             raise NotImplementedError(
                 "the serving engine pages per-token K/V rows: a graph of "
                 "recurrent-state ops alone has none (generate() runs it)")
+        # snapshots of the recurrent state on the trie's nodes (runtime/
+        # kv_pool.py): how many the pool holds, for a model with state
+        # ops under a prefix cache; nothing is allocated for any other
+        self.state_snapshots = 0
         if self.gen.state_ops:
             # a recurrent state is one array a slot, overwritten every
-            # step: a trie edge holds no snapshot of it to share, and one
-            # verify pass cannot score several positions of it
+            # step: a prefix hit resumes from a SNAPSHOT of it that a
+            # trie node carries, which the host tier does not move, and
+            # one verify pass cannot score several positions of it
             named = self.gen.state_ops[0].name
             if enable_prefix:
-                raise ValueError(
-                    f"{named} keeps a recurrent state: the radix prefix "
-                    "cache shares pages of per-token rows and has no "
-                    "snapshot of a state to share; build the engine with "
-                    "prefix_cache=False")
+                if hp:
+                    raise ValueError(
+                        f"{named} keeps a recurrent state: the host tier "
+                        "moves pages of per-token rows only, a demoted "
+                        "prefix would leave its snapshot behind; "
+                        "host_kv_pages must be 0")
+                self.state_snapshots = int(
+                    self.slots if state_snapshots is None
+                    else state_snapshots)
+                if self.state_snapshots < 1:
+                    raise ValueError(
+                        f"state_snapshots={state_snapshots}: a prefix "
+                        f"cache over {named}'s recurrent state needs at "
+                        "least one snapshot (or prefix_cache=False)")
             if self.speculate_k > 0:
                 raise ValueError(
                     f"{named} keeps a recurrent state: speculative "
@@ -542,7 +570,8 @@ class ServingEngine:
             self.gen, self.draft_gen, self.num_pages, self.page_size,
             self.pages_per_slot, self._kv_dtype_arg, enable_prefix, hp,
             lambda build, *args: self._compiled_call(
-                ("page_import",), build, *args), slots=self.slots)
+                ("page_import",), build, *args), slots=self.slots,
+            snapshots=self.state_snapshots)
         # the trie, for the router and stats() to READ: pages enter and
         # leave it only through self.kv
         self.prefix_cache = self.kv.prefix_cache
@@ -560,6 +589,11 @@ class ServingEngine:
             int(a.nbytes) for op in self.gen.state_ops
             for a in jax.tree_util.tree_leaves(self.kv.pool[op.name]))
         self._state_bytes_per_slot = self._state_pool_bytes // self.slots
+        self._snapshot_pool_bytes = sum(
+            int(a.nbytes) for a in jax.tree_util.tree_leaves(
+                self.kv.snapshots or {}))
+        self._snapshot_bytes = (self._snapshot_pool_bytes
+                                // (self.state_snapshots + 1))
         # bytes a token of context takes: in the table of the ops that
         # keep everything (a window group's arrays are a fixed size a slot)
         self._window_pool_bytes = sum(
@@ -1290,9 +1324,43 @@ class ServingEngine:
         for op in gen.state_ops:
             # the prefilled state takes the request's slot in the pool
             with jax.named_scope(op.name), jax.named_scope("seat"):
-                out[op.name] = op.seat_state(pool[op.name], caches[op.name],
-                                             slot[0])
+                state, at = caches[op.name], slot[0]
+                if self.kv.snapshots:
+                    # a publisher (`prefill_into_cache`) holds no slot and
+                    # says -1: row 0 keeps what it holds
+                    at = jnp.maximum(slot[0], 0)
+                    state = {k: jnp.where(
+                        slot[0] >= 0, state[k],
+                        pool[op.name][k][at][None].astype(state[k].dtype))
+                        for k in pool[op.name]}
+                out[op.name] = op.seat_state(pool[op.name], state, at)
         return out
+
+    @staticmethod
+    def _take_snapshot(gen, snaps, caches, snap):
+        """Write the state every recurrent op ends a prefill with into row
+        ``snap`` of the snapshot arrays (row 0, the scratch row, where the
+        prefill publishes nothing)."""
+        out = {}
+        for op in gen.state_ops:
+            with jax.named_scope(op.name), jax.named_scope("seat"):
+                out[op.name] = op.seat_state(snaps[op.name],
+                                             caches[op.name], snap)
+        return out
+
+    @staticmethod
+    def _seed_state_caches(gen, snaps, snap, dtype):
+        """The state caches of a hit prefill: every recurrent op's state
+        read from row ``snap`` of the snapshot arrays, READ-ONLY (the
+        copy-on-write rule: the tail advances a copy)."""
+        caches = {}
+        for op in gen.state_ops:
+            with jax.named_scope(op.name), jax.named_scope("seat"):
+                st = op.init_state(1, dtype)
+                caches[op.name] = {
+                    **st, **{k: snaps[op.name][k][snap][None].astype(
+                        st[k].dtype) for k in snaps[op.name]}}
+        return caches
 
     @staticmethod
     @jax.named_scope("sampler")
@@ -1368,8 +1436,13 @@ class ServingEngine:
                     *seat):
             # `seat` (`_seat_args`): for a model with recurrent-state ops
             # the pool row its prefilled state is seated in, then for one
-            # with window layers the slot's ring tables
+            # with window layers the slot's ring tables; under a prefix
+            # cache the state ops' snapshot arrays and the row to write
+            # come last
             slot = seat[:1] if gen.state_ops else ()
+            snaps, snap = seat[-2:] if self.kv.snapshots else (None, None)
+            # (window layers and snapshots never meet: the prefix cache is
+            # refused over a ring)
             rings = seat[-1] if self.kv.window_groups else None
             caches = gen.init_caches(1, bucket, cdtype)
             lora = ({"pool": lora_pool, "pages": lora_pages}
@@ -1379,15 +1452,20 @@ class ServingEngine:
             logits, caches = gen._prefill(params, state, tokens, caches,
                                           length, self.prefill_chunk,
                                           lora=lora, routing=routing,
-                                          lowerings=took, expert_rows=rows)
+                                          lowerings=took, expert_rows=rows,
+                                          loop=self.prefill_chunk_loop)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
+            taken = (() if snaps is None else
+                     (self._take_snapshot(gen, snaps, caches, snap),))
             return (tok, ok, self._scatter_tail(gen, pool, caches, pages,
                                                 slot=slot, length=length,
                                                 rings=rings),
-                    *self._routing_sum(routing, rows, static_rows))
+                    *self._routing_sum(routing, rows, static_rows), *taken)
 
-        return jax.jit(prefill, donate_argnums=(4,))
+        # the snapshot arrays are the 15th argument: donated where present
+        return jax.jit(prefill, donate_argnums=(
+            (4, 14) if self.kv.snapshots else (4,)))
 
     def _build_prefill_hit(self, bucket: int, full: int, took=None,
                            static_rows=None):
@@ -1406,11 +1484,19 @@ class ServingEngine:
 
         def prefill(params, state, tokens_tail, tok_last, length, pool,
                     prefix_pages, tail_pages, poison,
-                    temps, top_ps, top_ks, seeds, lora_pool, lora_pages):
+                    temps, top_ps, top_ks, seeds, lora_pool, lora_pages,
+                    *resume):
+            # `resume`, for a model with recurrent-state ops: the pool row
+            # the request is seated in, the snapshot arrays, the row the
+            # tail resumes FROM and the row it writes (0 = scratch)
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             caches = self._seed_prefix_caches(gen, bucket, p0, pool,
                                               prefix_pages)
+            if resume:
+                slot, snaps, snap_from, snap = resume
+                caches.update(self._seed_state_caches(
+                    gen, snaps, snap_from, gen._compute_dtype()))
             routing = [] if gen.dropless_moe_ops else None
             rows = []
             # row_lengths on the tail walk: its attention does not read
@@ -1428,11 +1514,15 @@ class ServingEngine:
                                        expert_rows=rows)
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
-            return (tok, ok, self._scatter_tail(gen, pool, caches,
-                                                tail_pages, p0),
-                    *self._routing_sum(routing, rows, static_rows))
+            taken = (() if not resume else
+                     (self._take_snapshot(gen, snaps, caches, snap),))
+            return (tok, ok, self._scatter_tail(
+                        gen, pool, caches, tail_pages, p0,
+                        slot=(slot,) if resume else None),
+                    *self._routing_sum(routing, rows, static_rows), *taken)
 
-        return jax.jit(prefill, donate_argnums=(5,))
+        return jax.jit(prefill, donate_argnums=(
+            (5, 16) if self.kv.snapshots else (5,)))
 
     def _build_draft_prefill(self, bucket: int, n_pages: int):
         """Cold draft prefill: fill the draft pool's pages for the whole
@@ -1785,11 +1875,13 @@ class ServingEngine:
         version bit-identical to the bare adapter key."""
         return version_ns(self.weight_version, adapter)
 
-    def _refuse_state_pages(self, what: str):
+    def _refuse_state_pages(self, what: str, with_snapshot: bool = False):
         """Page slabs carry per-token rows only: for a model whose ops keep
-        a recurrent state they would move a prefix without the state that
-        goes with it."""
-        if self.gen.state_ops:
+        a recurrent state they would move a prefix without the snapshot
+        that goes with it (`with_snapshot`: `prefill_into_cache` publishes
+        pages AND snapshot through the normal programs, and is refused for
+        a window layer's ring alone)."""
+        if self.gen.state_ops and not with_snapshot:
             raise NotImplementedError(
                 f"{what}: {self.gen.state_ops[0].name} keeps a recurrent "
                 "state, which no page slab carries (export, import and "
@@ -1814,6 +1906,16 @@ class ServingEngine:
         if not self.kv.window_groups:
             return self._state_slot_args(slot)
         return (*self._state_slot_args(slot), self.kv.window_tables(slot))
+
+    def _snapshot_args(self, lease, hit: bool):
+        """What a prefill program of an engine that holds snapshots takes
+        last: the arrays (donated), for a hit the row it resumes from,
+        and the row it writes; nothing for any other engine."""
+        if not self.kv.snapshots:
+            return ()
+        return (self.kv.snapshots,
+                *((np.int32(lease.snap_from),) if hit else ()),
+                np.int32(lease.snap))
 
     def slot_state(self, slot: int) -> dict:
         """Host copies of one slot's recurrent state, {op name: the op's
@@ -1859,8 +1961,22 @@ class ServingEngine:
                 self.gen._params(), self.model.bn_state, padded,
                 np.asarray([[prompt[-1]]], np.int32), length, kv.pool,
                 prefix_pages, tail_pages, poison, *sampling,
-                *self._lora_args_1(adapter_page))
+                *self._lora_args_1(adapter_page),
+                *(self._state_slot_args(slot) if kv.snapshots else ()),
+                *self._snapshot_args(lease, True))
         else:
+            if self.prefill_chunk_loop and self.buckets:
+                # the chunk loop runs only the chunks that hold live rows,
+                # so ONE program, the largest pinned bucket's, serves every
+                # bucket: the rows behind the prompt's own bucket are never
+                # walked and the pages behind its own are the scratch page
+                bucket = self.buckets[-1]
+                n_prefill = math.ceil(bucket / self.page_size)
+                padded = np.concatenate([padded, np.full(
+                    (1, bucket - padded.shape[1]), self.pad_id, np.int32)],
+                    axis=1)
+                tail_pages = np.concatenate([tail_pages, np.zeros(
+                    n_prefill - tail_pages.size, np.int32)])
             key = ("prefill", bucket, n_prefill, self.prefill_chunk)
             tok, ok, kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_prefill(
@@ -1869,7 +1985,13 @@ class ServingEngine:
                 self.gen._params(), self.model.bn_state, padded, length,
                 kv.pool, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page),
-                *self._seat_args(slot))
+                *self._seat_args(slot), *self._snapshot_args(lease, False))
+        if kv.snapshots:
+            # the arrays come back last, behind the routing counts
+            *routed, kv.snapshots = routed
+            span.annotate(snapshot=int(bool(full)),
+                          snapshot_bytes=self._snapshot_bytes
+                          * (bool(full) + bool(lease.snap)))
         span.annotate(program=program_name(key))
         self._note_moe_lowering(key, span)
         if self.draft_gen is not None:
@@ -2212,7 +2334,7 @@ class ServingEngine:
         pages now cached for this prompt, or None when pool pressure or
         a non-finite prefill prevented publishing — the caller falls
         back to the cold path."""
-        self._refuse_state_pages("prefill_into_cache")
+        self._refuse_state_pages("prefill_into_cache", with_snapshot=True)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("empty prompt")
@@ -2252,9 +2374,10 @@ class ServingEngine:
                 if not lease.need:
                     return last             # already fully published
                 self.kv.commit(lease)
+                # no slot is held: -1 seats the state nowhere
                 _, ok, *_ = self._run_prefill(
                     prompt, bucket, lease, self._sampling_args_greedy(),
-                    apage, np.float32(0.0))
+                    apage, np.float32(0.0), slot=-1)
                 ok = bool(np.asarray(ok)[0])
                 self.kv.publish(lease, prompt, ns, ok)
                 if not ok:
@@ -3146,6 +3269,16 @@ class ServingEngine:
             "state_bytes_per_slot": self._state_bytes_per_slot,
             "state_slots_live": (int((self.row_len > 0).sum())
                                  if self.gen.state_ops else 0),
+            # the snapshots of that state on the trie's nodes (all 0 for
+            # an engine without state ops or without a prefix cache):
+            # rows held now, the arrays' bytes (scratch row included),
+            # admissions that resumed from one, snapshots published, and
+            # snapshots that left with their node (eviction, flush, forget)
+            "state_snapshots_held": (pc.snapshots_held if pc else 0),
+            "state_snapshot_pool_bytes": self._snapshot_pool_bytes,
+            "state_snapshot_hits": (pc.snapshot_hits if pc else 0),
+            "state_snapshots_taken": (pc.snapshots_taken if pc else 0),
+            "state_snapshots_evicted": (pc.snapshots_evicted if pc else 0),
             "kv_bytes_per_token": round(self._kv_bytes_per_token, 3),
             "tokens_per_pool_gb": int((1 << 30)
                                       / self._kv_bytes_per_token),

@@ -241,18 +241,21 @@ def test_one_slots_state_in_anothers_place_fails_the_check(ff):
 
 def test_engine_refuses_what_a_recurrent_state_cannot_do(ff):
     kw = dict(serve_slots=2, kv_page_size=8, kv_pages=24, max_seq_len=48)
-    with pytest.raises(ValueError, match="prefix_cache=False"):
-        ff.make_serving_engine(prefix_cache=True, **kw)
+    # a prefix cache is no longer refused (tests/test_state_snapshots.py:
+    # a hit resumes from a snapshot); its host tier still is
+    with pytest.raises(ValueError, match="host_kv_pages must be 0"):
+        ff.make_serving_engine(prefix_cache=True, host_kv_pages=8, **kw)
     with pytest.raises(ValueError, match="speculate_k must be 0"):
         ff.make_serving_engine(prefix_cache=False, draft_model=ff,
                                speculate_k=2, **kw)
     eng = ff.make_serving_engine(prefix_cache=False, **kw)
     p = prompts([16])[0]
     for call in (lambda: eng.export_prefix_slab(p),
-                 lambda: eng.import_prefix_slab({}),
-                 lambda: eng.prefill_into_cache(p)):
+                 lambda: eng.import_prefix_slab({})):
         with pytest.raises(NotImplementedError, match="recurrent state"):
             call()
+    with pytest.raises(RuntimeError, match="needs the radix prefix cache"):
+        eng.prefill_into_cache(p)
     assert eng.flush_prefix_cache() == 0        # the generator's call
 
 
