@@ -410,7 +410,10 @@ def kernel_cases(z: Sizes):
         def make(rs):
             live = np.ones((s,), bool)
             live[[0, s // 2, s - 1]] = False
-            return (jnp.asarray(rs.randn(s, nh, p, n) * 0.1, jnp.float32),
+            # the pool in the layout the op holds: (slots, groups, N,
+            # heads a group x P)
+            return (jnp.asarray(rs.randn(s, g, n, nh // g * p) * 0.1,
+                                jnp.float32),
                     jnp.asarray(rs.rand(s, nh), jnp.float32),
                     jnp.asarray(rs.randn(s, nh, p), jnp.float32),
                     jnp.asarray(rs.randn(s, g, n), jnp.float32),
@@ -418,7 +421,8 @@ def kernel_cases(z: Sizes):
                     jnp.asarray(live))
         return make
 
-    for shape in [(8, 16, 64, 128, 2)] + ([(32, 128, 64, 128, 8)]
+    for shape in [(8, 16, 64, 128, 2)] + ([(32, 128, 64, 128, 8),
+                                           (16, 64, 64, 128, 1)]
                                           if z is FULL else []):
         cases.append(KernelCase(
             "mamba_state_update slots{} h{} p{} n{} g{} f32".format(*shape),

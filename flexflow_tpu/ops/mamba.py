@@ -20,9 +20,21 @@ The state protocol (`state_cache_protocol`, beside attention's
 `kv_cache_protocol`; runtime/generation.py asks the op):
 
   * a sequence's state is `{"conv": (B, K - 1, C) compute dtype, "h":
-    (B, H, P, N) float32}`: the last K - 1 pre-convolution rows and the
-    recurrent state. float32 because a recurrence accumulates its rounding
-    over every token of the sequence.
+    (B, G, N, H / G x P) float32}`: the last K - 1 pre-convolution rows and
+    the recurrent state. float32 because a recurrence accumulates its
+    rounding over every token of the sequence.
+  * THE LAYOUT OF "h" is this module's own (and the kernel's,
+    `pallas_kernels.mamba_state_update_pallas`): a group's heads lie side by
+    side, the state dimension N along the sublanes and (head, p) dense along
+    the lanes, `h[b, g, n, i * P + p]` = the equations' `H[b, g * H / G + i,
+    p, n]`. Why: a decode step's per-(head, p) operands (the decay, dt x)
+    are then ROWS, which is how `_split` hands x over; B and C are columns
+    of N that a whole group shares; and `y = H C` sums along the sublanes,
+    vector adds with no reduction across lanes. Prefill, the pool, the
+    snapshot rows, the kernel and XLA's loop all hold it so, and
+    `ssd_chunked`'s einsums emit and read it as an index order. Nothing
+    outside takes "h" apart; a check that wants the equations' (.., H, P, N)
+    asks `logical_state` (a view for the host, off every timed path).
   * `init_state(batch, dtype)` is the zero state; `scan_forward(params, xs,
     state, start, row_lengths)` advances it over a slab of rows that begins at
     position `start` and returns the slab's outputs: rows at or past a
@@ -50,6 +62,7 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flexflow_tpu.ffconst import OperatorType
 from flexflow_tpu.ops.base import Op, WeightSpec
@@ -58,11 +71,12 @@ from flexflow_tpu.ops.base import Op, WeightSpec
 def ssd_chunked(x, dt, a, bm, cm, h0, chunk: int):
     """The recurrence over a slab, chunked. x (B, S, H, P); dt (B, S, H) f32,
     0 on rows that must not move the state; a (H,) f32 (negative); bm, cm
-    (B, S, G, N); h0 (B, H, P, N) f32 -> (y (B, S, H, P) f32 without the D
-    term, h (B, H, P, N) f32 after the slab's last row). S is padded to a
-    multiple of `chunk` with dt = 0 rows."""
+    (B, S, G, N); h0 (B, G, N, H / G x P) f32, the held layout -> (y (B, S,
+    H, P) f32 without the D term, h like h0 after the slab's last row). S is
+    padded to a multiple of `chunk` with dt = 0 rows."""
     b, s, nh, p = x.shape
     g, n = bm.shape[2:]
+    q = nh // g * p                 # a group's (head, p) lanes
     pad = -s % chunk
     if pad:
         x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
@@ -88,36 +102,47 @@ def ssd_chunked(x, dt, a, bm, cm, h0, chunk: int):
     # 2. what each chunk adds to the state: sum_s exp(A_last - A_s) dt_s x_s B_s^T
     to_end = jnp.exp(acum[..., -1:] - acum).transpose(0, 2, 3, 1)  # (B,nc,Q,H)
     xw = (x * (dt * to_end)[..., None]).astype(cd)
-    bh = jnp.repeat(bm, nh // g, axis=3)                    # (B, nc, Q, H, N)
-    add = jnp.einsum("bcshp,bcshn->bchpn", xw, bh,
+    add = jnp.einsum("bcsgn,bcsgq->bcgnq", bm,
+                     xw.reshape(b, nc, chunk, g, q),
                      preferred_element_type=jnp.float32)
     # 3. between chunks: h_{c+1} = exp(A_last of c) h_c + add_c
-    across = jnp.exp(acum[..., -1]).transpose(2, 0, 1)      # (nc, B, H)
+    across = jnp.repeat(jnp.exp(acum[..., -1]).transpose(2, 0, 1), p,
+                        axis=-1).reshape(nc, b, g, 1, q)
 
     def carry(h, ca):
         d, add_c = ca
-        return d[..., None, None] * h + add_c, h
+        return d * h + add_c, h
 
     h, h_in = jax.lax.scan(carry, h0.astype(jnp.float32),
                            (across, add.transpose(1, 0, 2, 3, 4)))
     # 4. the state entering a chunk, read by its rows: C_l . h_in * exp(A_l)
-    ch = jnp.repeat(cm, nh // g, axis=3)
-    off = jnp.einsum("bclhn,cbhpn->bclhp", ch, h_in.astype(cd),
-                     preferred_element_type=jnp.float32)
+    off = jnp.einsum("bclgn,cbgnq->bclgq", cm, h_in.astype(cd),
+                     preferred_element_type=jnp.float32
+                     ).reshape(b, nc, chunk, nh, p)
     y = y + off * jnp.exp(acum).transpose(0, 2, 3, 1)[..., None]
     return y.reshape(b, nc * chunk, nh, p)[:, :s], h
 
 
+def lane_rows(decay, dtx, groups: int):
+    """A step's per-head decay (S, H) and dt x (S, H, P) as rows over a
+    group's (head, p) lanes, (S, G, 1, H / G x P) each: what multiplies a
+    state in the held layout."""
+    s, nh, p = dtx.shape
+    return (jnp.repeat(decay, p, axis=-1).reshape(s, groups, 1, -1),
+            dtx.reshape(s, groups, 1, -1))
+
+
 def mamba_state_update(h, decay, dtx, bm, cm, live):
     """One token of the recurrence on a pool of states, LIVE rows only, in
-    place: h (S, H, P, N) f32, decay (S, H) f32 = exp(dt A), dtx (S, H, P) f32
-    = dt x, bm, cm (S, G, N) f32, live (S,) bool -> (y (S, H, P) f32 = H_t C_t,
-    h). A loop over the live rows whose carry is the pool: each turn reads one
-    row's state, writes it back through a dynamic-update-slice that XLA does
-    in place, and a dead row's state is never touched (a `where` over the
-    whole pool would stream every row, live or not, there and back)."""
-    s, nh, p, n = h.shape
-    g = bm.shape[1]
+    place: h (S, G, N, H / G x P) f32 (the held layout), decay (S, H) f32 =
+    exp(dt A), dtx (S, H, P) f32 = dt x, bm, cm (S, G, N) f32, live (S,) bool
+    -> (y (S, H, P) f32 = H_t C_t, h). A loop over the live rows whose carry
+    is the pool: each turn reads one row's state, writes it back through a
+    dynamic-update-slice that XLA does in place, and a dead row's state is
+    never touched (a `where` over the whole pool would stream every row, live
+    or not, there and back)."""
+    s, g = bm.shape[:2]
+    dq, xq = lane_rows(decay, dtx, g)
     order = jnp.argsort(~live, stable=True).astype(jnp.int32)   # live first
 
     def one(i, carry):
@@ -126,18 +151,15 @@ def mamba_state_update(h, decay, dtx, bm, cm, live):
         row = jax.lax.dynamic_index_in_dim(h, r, 0, keepdims=False)
         pick = functools.partial(jax.lax.dynamic_index_in_dim, index=r,
                                  axis=0, keepdims=False)
-        bh = jnp.repeat(pick(bm), nh // g, axis=0)              # (H, N)
-        chd = jnp.repeat(pick(cm), nh // g, axis=0)
-        new = (pick(decay)[:, None, None] * row
-               + pick(dtx)[:, :, None] * bh[:, None, :])
-        yr = jnp.sum(new * chd[:, None, :], axis=-1)            # (H, P)
+        new = pick(dq) * row + pick(bm)[:, :, None] * pick(xq)  # (G, N, Q)
+        yr = jnp.sum(new * pick(cm)[:, :, None], axis=1)        # (G, Q)
         return (jax.lax.dynamic_update_index_in_dim(h, new, r, 0),
                 jax.lax.dynamic_update_index_in_dim(y, yr, r, 0))
 
     h, y = jax.lax.fori_loop(
         0, jnp.sum(live, dtype=jnp.int32), one,
-        (h, jnp.zeros((s, nh, p), jnp.float32)))
-    return y, h
+        (h, jnp.zeros((s, g, h.shape[-1]), jnp.float32)))
+    return y.reshape(dtx.shape), h
 
 
 class Mamba2Mixer(Op):
@@ -296,8 +318,8 @@ class Mamba2Mixer(Op):
         per-request state; `out_last` only where a gather pass may follow)."""
         st = {"conv": jnp.zeros((batch, self.conv_kernel - 1, self.conv_dim),
                                 dtype),
-              "h": jnp.zeros((batch, self.num_heads, self.head_dim,
-                              self.state_size), jnp.float32)}
+              "h": jnp.zeros((batch, self.n_groups, self.state_size,
+                              self.d_inner // self.n_groups), jnp.float32)}
         if out_last:
             st["out_last"] = jnp.zeros((batch, 1, self.dim), dtype)
         return st
@@ -313,15 +335,25 @@ class Mamba2Mixer(Op):
     def step_forward(self, params, xs, state):
         """One decode token for every sequence of a contiguous state."""
         def update(decay, dtx, bm, cm):
-            g = self.num_heads // self.n_groups
-            h = (decay[:, :, None, None] * state["h"]
-                 + dtx[..., None] * jnp.repeat(bm, g, axis=1)[:, :, None, :])
-            return jnp.einsum("bhpn,bhn->bhp", h,
-                              jnp.repeat(cm, g, axis=1)), h
+            dq, xq = lane_rows(decay, dtx, self.n_groups)
+            h = dq * state["h"] + bm[..., None] * xq
+            return jnp.einsum("bgnq,bgn->bgq", h, cm).reshape(dtx.shape), h
 
         out, conv, h = self._step(params, xs[0], state["conv"], update)
         return out, {**state, "conv": conv.astype(state["conv"].dtype),
                      "h": h}
+
+    def logical_state(self, state):
+        """A state's arrays with "h" as the equations write it, (.., H, P,
+        N), out of the held layout (.., G, N, H / G x P): for a host-side
+        check against a reference (`ServingEngine.slot_state`), never on a
+        timed path."""
+        h = np.asarray(state["h"])
+        lead, n = h.shape[:-3], self.state_size
+        h = np.moveaxis(h.reshape(*lead, self.n_groups, n, -1,
+                                  self.head_dim), -3, -1)
+        return {**state, "h": h.reshape(*lead, self.num_heads,
+                                        self.head_dim, n)}
 
     def state_bytes_per_slot(self, dtype) -> int:
         return (4 * self.num_heads * self.head_dim * self.state_size
@@ -338,15 +370,22 @@ class Mamba2Mixer(Op):
             pool[k], state[k][0].astype(pool[k].dtype), slot, 0)
             for k in pool}
 
+    def _kernel_takes_layout(self) -> bool:
+        from flexflow_tpu.ops.pallas_kernels import LANES, MAMBA_STATE_ROWS
+        return (self.d_inner // self.n_groups % LANES == 0
+                and self.state_size % MAMBA_STATE_ROWS == 0)
+
     def paged_step_forward(self, params, xs, pool, live, impl="einsum"):
         """One decode token for the serving engine's slots: xs[0] (slots, 1,
         D), live (slots,) bool. Only live slots' states are read and written,
         each once and in place (`impl` "pallas": the kernel that streams a
-        live slot's state through VMEM; otherwise XLA's loop over the live
+        live slot's state through VMEM, where the held layout fills whole
+        vector registers: a group's lanes a multiple of 128, N of the
+        kernel's `MAMBA_STATE_ROWS`; otherwise XLA's loop over the live
         rows, the parity oracle); the conv rows (1.5 % of the state's bytes)
         move under a select."""
         update = mamba_state_update
-        if impl == "pallas" and self.state_size % 128 == 0:
+        if impl == "pallas" and self._kernel_takes_layout():
             from flexflow_tpu.ops.pallas_kernels import (
                 mamba_state_update_pallas as update)
         out, conv, h = self._step(

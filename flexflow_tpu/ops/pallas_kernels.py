@@ -1801,37 +1801,57 @@ def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
 # Mamba-2 decode: one token of the recurrence over a pool of per-slot states
 # ---------------------------------------------------------------------------
 #
-# The pool's H (slots, heads, P, N) float32 stays in HBM and is the kernel's
-# output too (aliased): a grid step holds ONE slot's whole state in VMEM
-# (4.19 MB at 128 x 64 x 128, there and back through the pipeline's two
-# buffers each way), multiplies it by the step's decay, adds dt x (x) B and
-# reads y = H C out of the new state before it goes back. A DEAD slot is
-# neither read nor written: the scalar-prefetched `src` sends its grid step to
-# the block of the nearest live slot before it (the first live one after it
-# for the leading dead slots), and the pipeline neither fetches nor writes
-# back a block whose index did not change, while the body runs for live steps
-# only. Per head the work is elementwise over (P, N) with x along the
-# sublanes and B, C along the lanes, so x arrives transposed, (slots, groups,
-# P, heads a group): its column for a head is a static lane slice.
+# The pool's H stays in HBM and is the kernel's output too (aliased). It is
+# held (slots, groups, N, heads a group x P) float32 (ops/mamba.py says why
+# and gives the logical view, `Mamba2Mixer.logical_state`): per group a tile
+# with the state dimension N along the SUBLANES and (head, p) dense along the
+# LANES. A grid step holds ONE slot's whole state in VMEM (4.19 MB at 8 x 128
+# x 1024, 2.10 MB at 1 x 128 x 4096; there and back through the pipeline's
+# two buffers each way) and works through it a 128-lane block at a time:
+#
+#   * the step's decay and dt x are ROWS over the group's (head, p) lanes
+#     (the wrapper's `lane_rows`), spread along the sublanes as they load;
+#   * B and C are columns of N, spread across the lanes ONCE a group (their
+#     row spread along the sublanes and transposed: 16 registers each at N =
+#     128) and reused by every lane block of the group, not once a head;
+#   * `new = d * h + b * x` goes back in place, and `y = H C` adds `new * c`
+#     over the N / 8 registers of the block with vector adds and ends in one
+#     8 -> 1 sublane reduce a block: nothing a head crosses the lanes (N on
+#     the lanes costs a 128-lane reduction a register and a one-lane column
+#     of y a head, 52-56 bundles a head against 21: PERF.md section 6, PR 44);
+#   * y leaves as a lane-dense row, (slots, d_inner) as the gate reads it.
+#
+# A DEAD slot is neither read nor written: the scalar-prefetched `src` sends
+# its grid step to the block of the nearest live slot before it (the first
+# live one after it for the leading dead slots), and the pipeline neither
+# fetches nor writes back a block whose index did not change, while the body
+# runs for live steps only.
+
+# the state dimension's granule: a float32 register's sublanes
+MAMBA_STATE_ROWS = 8
 
 
 def _mamba_update_kernel(src_ref, live_ref, h_ref, d_ref, x_ref, b_ref, c_ref,
-                         o_ref, y_ref, *, groups: int, per: int):
+                         o_ref, y_ref):
     s = pl.program_id(0)
+    groups, n, q = h_ref.shape[1:]
 
     @pl.when(live_ref[s] > 0)
     def _():
         def group(g, carry):
-            d, x = d_ref[0, g], x_ref[0, g]         # (1, per), (P, per)
-            b, c = b_ref[0, g], c_ref[0, g]         # (1, N) each
-            cols = []
-            for i in range(per):
-                head = g * per + i
-                new = d[:, i:i + 1] * h_ref[0, head] + x[:, i:i + 1] * b
-                o_ref[0, head] = new
-                cols.append(jnp.sum(new * c, axis=1, keepdims=True))
-            y_ref[0, g] = jnp.concatenate(cols, axis=1)
-            return carry
+            # (1, N) rows -> (N, LANES) columns spread across the lanes
+            b = jnp.broadcast_to(b_ref[0, g], (LANES, n)).T
+            c = jnp.broadcast_to(c_ref[0, g], (LANES, n)).T
+
+            def block(j, carry):
+                at = (0, g, slice(None),
+                      pl.ds(pl.multiple_of(j * LANES, LANES), LANES))
+                new = d_ref[at] * h_ref[at] + b * x_ref[at]
+                o_ref[at] = new
+                y_ref[at] = jnp.sum(new * c, axis=0, keepdims=True)
+                return carry
+
+            return jax.lax.fori_loop(0, q // LANES, block, carry)
 
         jax.lax.fori_loop(0, groups, group, 0)
 
@@ -1849,12 +1869,13 @@ def _mamba_update_kernel(src_ref, live_ref, h_ref, d_ref, x_ref, b_ref, c_ref,
 @functools.partial(jax.jit, inline=True)
 def mamba_state_update_pallas(h, decay, dtx, bm, cm, live):
     """One token of `H <- decay H + dtx B^T`, `y = H C` on a pool of states,
-    live slots only, in place: h (S, H, P, N) f32 (aliased to the output),
-    decay (S, H) f32, dtx (S, H, P) f32, bm, cm (S, G, N) f32, live (S,) bool
-    -> (y (S, H, P) f32, zero for a dead slot; h)."""
-    s, nh, p, n = h.shape
-    g = bm.shape[1]
-    per = nh // g
+    live slots only, in place: h (S, G, N, H / G x P) f32 (the held layout;
+    aliased to the output), decay (S, H) f32, dtx (S, H, P) f32, bm, cm (S,
+    G, N) f32, live (S,) bool -> (y (S, H, P) f32, zero for a dead slot; h).
+    A group's lanes are a multiple of 128 and N of `MAMBA_STATE_ROWS`."""
+    from flexflow_tpu.ops.mamba import lane_rows
+
+    s, g, n, q = h.shape
     idx = jnp.arange(s, dtype=jnp.int32)
     before = jax.lax.cummax(jnp.where(live, idx, -1))
     first = jnp.min(jnp.where(live, idx, s))
@@ -1862,40 +1883,33 @@ def mamba_state_update_pallas(h, decay, dtx, bm, cm, live):
     src = jnp.where(before >= 0, before, first)
     # `src[0] < 0` tells the kernel nothing is live; the index map clamps it
     src = jnp.where(any_live, src, -1).astype(jnp.int32)
+
+    def state(i, src, live):
+        return (jnp.maximum(src[i], 0), 0, 0, 0)
+
+    def own(i, *_):
+        return (i, 0, 0, 0)
+
+    row, col = pl.BlockSpec((1, g, 1, q), own), pl.BlockSpec((1, g, 1, n), own)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, nh, p, n),
-                         lambda i, src, live: (jnp.maximum(src[i], 0), 0, 0,
-                                               0)),
-            pl.BlockSpec((1, g, 1, per), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, g, p, per), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, g, 1, n), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, g, 1, n), lambda i, *_: (i, 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nh, p, n),
-                         lambda i, src, live: (jnp.maximum(src[i], 0), 0, 0,
-                                               0)),
-            pl.BlockSpec((1, g, p, per), lambda i, *_: (i, 0, 0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, g, n, q), state), row, row, col, col],
+        out_specs=[pl.BlockSpec((1, g, n, q), state), row],
     )
-    block = nh * p * n * 4
-    new, yt = pl.pallas_call(
-        functools.partial(_mamba_update_kernel, groups=g, per=per),
+    block = g * n * q * 4
+    new, y = pl.pallas_call(
+        _mamba_update_kernel,
         name="mamba_state_update",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(h.shape, h.dtype),
-                   jax.ShapeDtypeStruct((s, g, p, per), jnp.float32)],
+                   jax.ShapeDtypeStruct((s, g, 1, q), jnp.float32)],
         # operand 2 (after the two prefetched scalars) is the pool
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=int(4 * block + (16 << 20))),
         interpret=_interpret(),
-    )(src, live.astype(jnp.int32), h,
-      decay.reshape(s, g, 1, per),
-      dtx.reshape(s, g, per, p).transpose(0, 1, 3, 2),
+    )(src, live.astype(jnp.int32), h, *lane_rows(decay, dtx, g),
       bm.reshape(s, g, 1, n), cm.reshape(s, g, 1, n))
-    return yt.transpose(0, 1, 3, 2).reshape(s, nh, p), new
+    return y.reshape(dtx.shape), new

@@ -1919,13 +1919,16 @@ class ServingEngine:
 
     def slot_state(self, slot: int) -> dict:
         """Host copies of one slot's recurrent state, {op name: the op's
-        state arrays for that slot}; empty for a model without such ops.
-        For checks that hold what prefill seated and decode advanced to a
-        reference: the state covers the prompt and every emitted token but
-        the last (which no step has read yet)."""
+        state arrays for that slot, as its equations write them
+        (`logical_state`: the pool's own layout is the op's business)};
+        empty for a model without such ops. For checks that hold what
+        prefill seated and decode advanced to a reference: the state covers
+        the prompt and every emitted token but the last (which no step has
+        read yet)."""
         with self._lock:
-            return {op.name: jax.device_get(jax.tree_util.tree_map(
-                lambda a: a[slot], self.kv.pool[op.name]))
+            return {op.name: op.logical_state(jax.device_get(
+                jax.tree_util.tree_map(lambda a: a[slot],
+                                       self.kv.pool[op.name])))
                 for op in self.gen.state_ops}
 
     def _run_prefill(self, prompt, bucket: int, lease, sampling,

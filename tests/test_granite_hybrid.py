@@ -243,12 +243,13 @@ def test_gated_mlp_is_gate_times_up_through_one_in_projection():
 def test_state_kernel_at_one_group_matches_the_xla_oracle(live):
     """The Pallas state update (interpreted) at G = 1, where every head of a
     slot reads the SAME B and C row (the layout it had run at was 8 groups of
-    16 heads), against XLA's loop over the live rows."""
+    16 heads), against XLA's loop over the live rows; the pool in the held
+    layout, (slots, 1, N, heads x P)."""
     from flexflow_tpu.ops.pallas_kernels import mamba_state_update_pallas
 
     slots, heads, p, n = 4, 8, 16, 128
     rs = np.random.RandomState(9)
-    h = jnp.asarray(rs.randn(slots, heads, p, n).astype(np.float32))
+    h = jnp.asarray(rs.randn(slots, 1, n, heads * p).astype(np.float32))
     decay = jnp.asarray(rs.rand(slots, heads).astype(np.float32))
     dtx = jnp.asarray(rs.randn(slots, heads, p).astype(np.float32))
     bm = jnp.asarray(rs.randn(slots, 1, n).astype(np.float32))

@@ -4,7 +4,10 @@ its forward against the plain reference's token-by-token recurrence
 benchmark/reference/nemotron_h.py), its gradient by autodiff against the
 reference's, and the state protocol the engine drives: a bucket's padding
 never reaches the state, chunks of a prefill carry it, a decode step advances
-it, and the pool's update touches live rows only.
+it, and the pool's update touches live rows only. The recurrent state is held
+(.., G, N, H / G x P) (ops/mamba.py says why); the equations and the
+reference write (.., H, P, N), and `held` / `logical` here go between the two
+without the op's help.
 
 Every tolerance stands beside its reason.
 """
@@ -28,11 +31,25 @@ HIDDEN, HEADS, P, G, N = 48, 8, 16, 2, 16
 ATOL = 2e-5
 
 
-def build(seq, chunk=16, batch=2, seed=1):
+def held(h, g):
+    """(.., H, P, N) as the equations write it -> the layout the op holds."""
+    *lead, nh, p, n = np.shape(h)
+    h = np.asarray(h).reshape(*lead, g, nh // g, p, n)
+    return np.moveaxis(h, -1, -3).reshape(*lead, g, n, nh // g * p)
+
+
+def logical(h, p):
+    """The layout the op holds -> (.., H, P, N)."""
+    *lead, g, n, q = np.shape(h)
+    h = np.moveaxis(np.asarray(h).reshape(*lead, g, n, q // p, p), -3, -1)
+    return h.reshape(*lead, g * (q // p), p, n)
+
+
+def build(seq, chunk=16, batch=2, seed=1, p=P):
     cfg = FFConfig(batch_size=batch, mesh_shape={"data": 1}, seed=seed)
     ff = FFModel(cfg)
     x = ff.create_tensor([batch, seq, HIDDEN], name="x")
-    y = ff.mamba2(x, HEADS, P, G, N, chunk_size=chunk, name="mamba")
+    y = ff.mamba2(x, HEADS, p, G, N, chunk_size=chunk, name="mamba")
     ff.compile(final_tensor=y)
     rs = np.random.RandomState(seed)
     for w in ("norm_w", "D"):
@@ -42,19 +59,20 @@ def build(seq, chunk=16, batch=2, seed=1):
     return ff, ff.get_op_by_name("mamba")
 
 
-def reference(params, x):
+def reference(params, x, rows=None, head_dim=P):
     """The reference's layer on x (S, D) without its pre-norm and residual:
-    a unit norm scale with the row scaled back, and the input taken off."""
+    a unit norm scale with the row scaled back, and the input taken off.
+    With `rows`, beside it the reference's state after that many rows: H
+    (HEADS, head_dim, N) and the conv tail."""
     p = params
     # ref.mamba computes h + mamba(RMSNorm(h; norm)); feed it rows whose RMS
     # is 1 so that the norm with scale one is the identity up to eps
-    rms = jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-5)
-    out = ref.mamba(x, jnp.ones((HIDDEN,)) * 1.0, p["w_in"], p["conv_w"],
-                    p["conv_b"], p["dt_bias"], p["A_log"], p["D"],
-                    p["norm_w"], p["w_out"], x.shape[0], heads=HEADS,
-                    head_dim=P, groups=G, state=N, eps=1e-5)[0]
-    del rms
-    return out - x
+    out, h, tail = ref.mamba(
+        x, jnp.ones((HIDDEN,)) * 1.0, p["w_in"], p["conv_w"], p["conv_b"],
+        p["dt_bias"], p["A_log"], p["D"], p["norm_w"], p["w_out"],
+        x.shape[0] if rows is None else rows, heads=HEADS,
+        head_dim=head_dim, groups=G, state=N, eps=1e-5)
+    return out - x if rows is None else (out - x, h, tail)
 
 
 def unit_rows(rs, *shape):
@@ -169,42 +187,95 @@ def test_decode_steps_continue_the_prefill():
                                    atol=ATOL, rtol=0)
 
 
+# (heads, P, groups, N): a tiny one whose group fills one 128-lane block, and
+# the two cells' own (`ssm-latentmoe-chat-saturated` 8 groups of 16 heads,
+# `hybrid-ssm-docqa-saturated` one group of 64)
+POOL_SHAPES = {"tiny": (8, 32, 2, 16), "nemotron": (128, 64, 8, 128),
+               "granite": (64, 64, 1, 128)}
+
+
 @pytest.mark.parametrize("live", [
     [True, False, True, True, False], [False, False, True, False, False],
     [True] * 5, [False] * 5, [False, False, False, False, True]])
+@pytest.mark.parametrize("shape", list(POOL_SHAPES))
 @pytest.mark.parametrize("impl", ["loop", "pallas"])
-def test_pool_update_reads_and_writes_live_rows_only(impl, live):
-    """XLA's loop over the live rows and the Pallas kernel (interpreted; the
-    state's columns at the lanes' 128): the recurrence's one step on live
-    rows, and not one bit of a dead row moves, wherever the dead rows lie."""
+def test_pool_update_reads_and_writes_live_rows_only(impl, shape, live):
+    """XLA's loop over the live rows and the Pallas kernel (interpreted) on
+    a pool in the held layout, at a tiny shape and at both cells': the
+    recurrence's one step on live rows as the equations write it, the kernel
+    the loop's equal, and not one bit of a dead row moves, wherever the dead
+    rows lie."""
     from flexflow_tpu.ops.pallas_kernels import mamba_state_update_pallas
 
-    update = mamba_state_update if impl == "loop" \
-        else mamba_state_update_pallas
-    N = 128 if impl == "pallas" else 16
+    heads, p, g, n = POOL_SHAPES[shape]
     rs = np.random.RandomState(8)
-    h = jnp.asarray(rs.randn(5, HEADS, P, N).astype(np.float32))
-    decay = jnp.asarray(rs.rand(5, HEADS).astype(np.float32))
-    dtx = jnp.asarray(rs.randn(5, HEADS, P).astype(np.float32))
-    bm = jnp.asarray(rs.randn(5, G, N).astype(np.float32))
-    cm = jnp.asarray(rs.randn(5, G, N).astype(np.float32))
-    live = jnp.asarray(live)
-    y, new = jax.jit(update)(h, decay, dtx, bm, cm, live)
-    bh, ch = (np.repeat(np.asarray(v), HEADS // G, axis=1) for v in (bm, cm))
-    want = (np.asarray(decay)[:, :, None, None] * np.asarray(h)
-            + np.asarray(dtx)[..., None] * bh[:, :, None, :])
+    h = rs.randn(5, heads, p, n).astype(np.float32)
+    decay = rs.rand(5, heads).astype(np.float32)
+    dtx = rs.randn(5, heads, p).astype(np.float32)
+    bm = rs.randn(5, g, n).astype(np.float32)
+    cm = rs.randn(5, g, n).astype(np.float32)
+    args = tuple(map(jnp.asarray, (held(h, g), decay, dtx, bm, cm, live)))
+    y, new = jax.jit(mamba_state_update)(*args)
+    if impl == "pallas":
+        y0, new0 = y, new
+        y, new = jax.jit(mamba_state_update_pallas)(*args)
+        # the same products; y's N terms summed in another order
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(new0))
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
+                                   atol=1e-5 * n / 16, rtol=0)
+    assert new.shape == (5, g, n, heads // g * p) and y.shape == dtx.shape
+    got = logical(new, p)
+    bh, ch = (np.repeat(v, heads // g, axis=1) for v in (bm, cm))
+    want = (decay[:, :, None, None] * h + dtx[..., None] * bh[:, :, None, :])
     for r in range(5):
-        if bool(live[r]):
-            np.testing.assert_allclose(np.asarray(new[r]), want[r],
-                                       atol=1e-6, rtol=0)
+        if live[r]:
+            np.testing.assert_allclose(got[r], want[r], atol=1e-6, rtol=0)
             # a sum of N products of order 1
             np.testing.assert_allclose(
                 np.asarray(y[r]), (want[r] * ch[r][:, None, :]).sum(-1),
-                atol=1e-5 * N / 16, rtol=0)
+                atol=1e-5 * n / 16, rtol=0)
         else:       # not one bit of a dead row moves
-            np.testing.assert_array_equal(np.asarray(new[r]),
-                                          np.asarray(h[r]))
+            np.testing.assert_array_equal(got[r], h[r])
             assert not np.asarray(y[r]).any()
+
+
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_a_seated_state_advanced_in_the_pool_is_the_recurrences(impl):
+    """A prompt of 21 rows in a bucket of 32 prefilled by the chunked scan,
+    seated into a pool whose other slots hold garbage (one of them live),
+    advanced by six in-place decode steps (XLA's loop, the interpreted
+    kernel: two groups of 128 lanes) and read back as the equations write it
+    (`logical_state`): the reference's token-by-token state after 27 rows,
+    and the six outputs are the reference's rows 21..26."""
+    ff, op = build(32, batch=1, p=32)
+    assert op._kernel_takes_layout()
+    params = ff.params["mamba"]
+    x = unit_rows(np.random.RandomState(11), 1, 32, HIDDEN)
+    want_out, want_h, want_tail = reference(params, jnp.asarray(x[0, :27]),
+                                            rows=27, head_dim=32)
+    _, state = op.scan_forward(params, [jnp.asarray(x)], op.init_state(1), 0,
+                               jnp.asarray([21], jnp.int32))
+    pool = jax.tree.map(lambda v: jnp.full_like(v, 3.0),
+                        op.init_state_pool(4, jnp.float32))
+    pool = op.seat_state(pool, state, 2)
+    live = jnp.asarray([False, True, True, False])
+    step = jax.jit(lambda pool, u: op.paged_step_forward(
+        params, [u], pool, live, impl=impl))
+    for t in range(21, 27):
+        u = jnp.zeros((4, 1, HIDDEN)).at[2, 0].set(x[0, t])
+        out, pool = step(pool, u)
+        np.testing.assert_allclose(np.asarray(out[2, 0]),
+                                   np.asarray(want_out[t]), atol=ATOL, rtol=0)
+    got = op.logical_state(jax.device_get(jax.tree.map(lambda v: v[2], pool)))
+    assert got["h"].shape == (HEADS, 32, N)
+    np.testing.assert_allclose(got["h"], np.asarray(want_h), atol=ATOL,
+                               rtol=0)
+    np.testing.assert_allclose(got["conv"], np.asarray(want_tail), atol=ATOL,
+                               rtol=0)
+    assert float(np.abs(got["h"]).max()) > 0.01
+    # the dead slots kept their garbage to the bit
+    for r in (0, 3):
+        assert float(pool["h"][r].min()) == float(pool["h"][r].max()) == 3.0
 
 
 def test_ssd_with_an_entering_state_and_dead_rows():
@@ -217,9 +288,9 @@ def test_ssd_with_an_entering_state_and_dead_rows():
     a = -jnp.asarray(rs.rand(HEADS).astype(np.float32) * 4 - 0.5) - 1.0
     bm = jnp.asarray(rs.randn(1, 20, G, N).astype(np.float32))
     cm = jnp.asarray(rs.randn(1, 20, G, N).astype(np.float32))
-    h0 = jnp.asarray(rs.randn(1, HEADS, P, N).astype(np.float32))
-    y, h = ssd_chunked(x, dt, a, bm, cm, h0, 8)
-    hs = np.asarray(h0[0])
+    h0 = rs.randn(1, HEADS, P, N).astype(np.float32)
+    y, h = ssd_chunked(x, dt, a, bm, cm, jnp.asarray(held(h0, G)), 8)
+    hs = h0[0]
     for t in range(12):
         d = np.exp(np.asarray(dt[0, t]) * np.asarray(a))
         bh = np.repeat(np.asarray(bm[0, t]), HEADS // G, axis=0)
@@ -230,15 +301,18 @@ def test_ssd_with_an_entering_state_and_dead_rows():
         np.testing.assert_allclose(np.asarray(y[0, t]),
                                    (hs * ch[:, None, :]).sum(-1), atol=1e-4,
                                    rtol=0)
-    np.testing.assert_allclose(np.asarray(h[0]), hs, atol=1e-4, rtol=0)
+    np.testing.assert_allclose(logical(h[0], P), hs, atol=1e-4, rtol=0)
 
 
 def test_op_states_its_state_and_its_cost():
     ff, op = build(16)
     assert isinstance(op, Mamba2Mixer) and op.state_cache_protocol
     pool = op.init_state_pool(3, jnp.bfloat16)
-    assert pool["h"].shape == (3, HEADS, P, N) \
+    # N along the sublanes, a group's (head, p) along the lanes; the bytes
+    # a slot are what (HEADS, P, N) float32 takes
+    assert pool["h"].shape == (3, G, N, HEADS // G * P) \
         and pool["h"].dtype == jnp.float32
+    assert pool["h"][0].nbytes == 4 * HEADS * P * N
     assert pool["conv"].shape == (3, 3, HEADS * P + 2 * G * N) \
         and pool["conv"].dtype == jnp.bfloat16
     assert op.state_bytes_per_slot(jnp.bfloat16) == sum(
@@ -249,3 +323,12 @@ def test_op_states_its_state_and_its_cost():
     seated = op.seat_state(pool, jax.tree.map(jnp.ones_like, st), 1)
     assert float(seated["h"][1].min()) == 1 and not seated["h"][0].any()
     assert not hasattr(op, "reset_state")       # seating overwrites a slot
+    # the logical view is the inverse of the held layout, for a slot's
+    # arrays and for a batch's
+    for lead in ((), (2,)):
+        eq = np.random.RandomState(2).randn(*lead, HEADS, P, N)
+        view = op.logical_state({"h": held(eq, G), "conv": None})
+        np.testing.assert_array_equal(view["h"], eq)
+        assert view["conv"] is None
+    # the kernel takes a group of whole 128-lane blocks; this op's is 64
+    assert not op._kernel_takes_layout()

@@ -92,11 +92,17 @@ def test_a_hit_through_a_snapshot_is_the_request_served_cold(impl):
     leak_free(eng)
 
 
-def test_the_seated_state_after_a_hit_is_the_references(ff):
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_the_seated_state_after_a_hit_is_the_references(ff, impl):
     """The hit's state against the reference's recurrence from token 0 over
-    document + question + emitted tokens (check (c) of the benchmark cell)."""
+    document + question + emitted tokens (check (c) of the benchmark cell):
+    the snapshot taken, seated and resumed from in the layout the op holds,
+    the decode steps by XLA's loop or the interpreted kernel (one group of
+    128 lanes, N = 16), and `slot_state` handing out (H, P, N) as the
+    reference writes it."""
     doc, q = tokens(4 * PS, 3), tokens(3, 4)
-    eng = engine(ff)
+    eng = engine(ff, paged_attention_impl=impl)
+    assert eng.kv.snapshots["mamba_0"]["h"].shape[1:] == (1, 16, 8 * 16)
     eng.prefill_into_cache(doc)
     req, (state, n) = serve(eng, np.concatenate([doc, q]), new=8,
                             read_state=True)
