@@ -539,15 +539,15 @@ def build_model(z: Sizes, batch, mesh_shape, rehearsal, **cfg_kw):
 def mosaic_calls(ff, batch):
     """{kernel name: count} of Mosaic custom calls in the lowered train
     step. Lowering only (no second compile): a pallas_call lowers to a
-    `tpu_custom_call` whose backend config names the kernel function."""
+    `tpu_custom_call` whose backend config carries the call's `name=`."""
     import jax
 
     sharded = ff.executor.shard_batch(batch)
     key = jax.random.split(ff._rng)[1]
     text = ff._train_step.lower(ff.params, ff.opt_state, ff.bn_state,
                                 sharded, key).as_text()
-    names = ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
-             "_flash_bwd_dkv_kernel")
+    names = ("flash_attention_fwd", "flash_attention_bwd_dq",
+             "flash_attention_bwd_dkv")
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     return {n: sum(n in ln for ln in calls) for n in names}, len(calls)
 

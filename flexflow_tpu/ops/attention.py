@@ -801,6 +801,16 @@ class MultiHeadAttention(Op):
         own: none for plain attention."""
         return {}
 
+    @staticmethod
+    def paged_turn_pages(cache, width: int) -> int:
+        """Pages a turn of the paged kernel takes on the pool `cache` under
+        a page table `width` pages wide: a static of the shapes the kernel
+        sees, for the engine's counters."""
+        from flexflow_tpu.ops.pallas_kernels import paged_turn_pages
+
+        ps, rows = cache["k"].shape[1:3]
+        return paged_turn_pages(ps, rows, width)
+
     def export_page(self, cache, page):
         """Slice pool page(s) out as the serializable migration payload
         — the unit both the prefill->decode fleet handoff and the

@@ -649,6 +649,13 @@ class LatentAttention(Op):
                 (-(-ctx // block)).sum()) * block * key
         return counts
 
+    @staticmethod
+    def paged_turn_pages(cache, width: int) -> int:
+        """`MultiHeadAttention.paged_turn_pages`: 1, the core reads gathered
+        rows and no page stream (the index kernel's blocks are counted by
+        `decode_span_counts`)."""
+        return 1
+
     def init_paged_cache(self, num_pages: int, page_size: int, dtype,
                          kv_dtype=None):
         if not self.indexed:

@@ -858,30 +858,48 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # follows the pages it streams.
 #
 #   * The grid is the SLOTS (sequential). Inside a grid step a fori_loop
-#     runs over the slot's live pages, 0 .. last_page[b], so no step of
-#     any kind exists for a dead page. (Until PR 26 the grid was (slots,
-#     table width) with dead steps clamped and pl.when-skipped, on the
-#     assumption that a dead step costs nothing. It costs 0.13-0.15 us:
-#     with 16 x 66 steps of which 5 were live, that was nearly all of the
-#     157 us a call took in chat-steady — PERF.md section 6, PR 26.)
-#   * The pages of ALL slots form one stream, slot by slot, page by page.
-#     A cursor in SMEM walks it `_paged_ring(...) - 1` pages ahead of the
-#     arithmetic and issues one DMA per page and tensor (a page of the
-#     pool is one contiguous copy, all KV heads in it) into a ring of
-#     VMEM buffers; because the cursor crosses slot boundaries, the DMA
-#     queue never drains between slots, which matters when most slots
-#     hold one or two pages. Every slot has >= 1 live page (the live rule
-#     admits j = 0 even for an inactive slot, whose zeroed table row
-#     points at scratch page 0), so the cursor's advance is O(1).
-#   * A page's arithmetic is one pass for all heads: the page is viewed
-#     as (page_size * KVH, D) rows in the pool's own layout, all S * H
+#     runs over the slot's live pages, 0 .. last_page[b], a TURN at a time,
+#     so no step of any kind exists for a dead page. (Until PR 26 the grid
+#     was (slots, table width) with dead steps clamped and pl.when-skipped,
+#     on the assumption that a dead step costs nothing. It costs 0.13-0.15
+#     us: with 16 x 66 steps of which 5 were live, that was nearly all of
+#     the 157 us a call took in chat-steady — PERF.md section 6, PR 26.)
+#   * A turn takes a BLOCK of g consecutive live pages of the slot, with g
+#     from the shapes the call sees (`paged_turn_pages`): the smallest power
+#     of two whose block has the 1024 columns (tokens x rows a token) of the
+#     turn the kernel reaches its roof on, so ONE page from 8 rows a token
+#     of 128 lanes up (heads of 128, 8 or 16 KV heads: the turn it always
+#     was), two where two heads of 64 share a row of four a token, four at 2
+#     KV heads of 128. The pages past a slot's last WHOLE block go one a
+#     turn: nothing past the last live page is ever fetched (a dead page may
+#     hold anything, and 0 x NaN in p v is NaN), and one body serves both.
+#   * The turns of ALL slots form one stream, slot by slot, block by block
+#     (`_page_stream`, shared with `dsa_index_scores`). A cursor in SMEM
+#     walks it `_paged_ring(...) - 1` turns ahead of the arithmetic and
+#     issues one DMA per page and tensor (a page of the pool is one
+#     contiguous copy, all KV heads in it) into a ring of VMEM buffers, a
+#     turn's copies onto one semaphore a tensor; because the cursor crosses
+#     slot boundaries, the DMA queue never drains between slots, which
+#     matters when most slots hold one or two pages. Every slot has >= 1
+#     live page (the live rule admits j = 0 even for an inactive slot, whose
+#     zeroed table row points at scratch page 0), so the cursor's advance is
+#     O(1).
+#   * A block of g > 1 pages LANDS DENSE: the wrapper views the pools as
+#     (pages, page_size x rows, D), the same bytes in the same order, so the
+#     turn's buffer is (g x page_size x rows, D) rows with no padding. Pages
+#     of fewer rows a token than the dtype's sublane tile land padded to it
+#     otherwise, and re-packing them costs the body more than their copy
+#     does (4 rows of bf16: 0.22 us a page beside 0.32 us of DMA, whatever
+#     the turn — PERF.md section 6, PR 45).
+#   * A turn's arithmetic is one pass for all heads: the block is viewed
+#     as (g * page_size * KVH, D) rows in the pool's own layout, all S * H
 #     query rows are scored against it in one matmul, and a column whose
 #     KV head is not the row's (h // G != c % KVH) is masked together
-#     with the live rule. That spends KVH x the FLOPs, on an MXU whose
-#     cost here is loading the page as weights — the same whether 1 or
-#     S * H rows stream through — and it removes the per-head strided
-#     slices of the page, which were what a live page cost (1.5-1.9 us
-#     against 0.64 us for its DMA; now 0.69).
+#     with the live rule; one online-softmax update, one p v. That spends
+#     KVH x the FLOPs, on an MXU whose cost here is loading the block as
+#     weights — the same whether 1 or S * H rows stream through — and it
+#     removes the per-head strided slices of the page, which were what a
+#     live page cost (1.5-1.9 us against 0.64 us for its DMA; now 0.69).
 #
 # The Flex-TPU analogue (PAPERS.md 2407.08700): keep the data resident in
 # the compute unit; don't materialize the logical view in HBM.
@@ -897,30 +915,133 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # VMEM the page ring may take: half the 16 MiB a kernel gets by default,
 # the rest is for the scores, a dequantized page and Mosaic's own use
 _PAGED_RING_BUDGET = 8 << 20
-# pages in flight beyond which a deeper ring hides no more DMA latency
+# turns in flight beyond which a deeper ring hides no more DMA latency
 _PAGED_RING_MAX = 4
+# columns (tokens x rows a token) of the turn the paged kernel reaches its
+# roof on: a page of 128 tokens x 8 rows of 128 lanes (PERF.md section 6,
+# PR 26 and PR 45). A narrower page is taken `paged_turn_pages` at a time
+_PAGED_TURN_COLS = 1024
+# pages of one index turn, and the most any turn takes: their (1, ps) f32
+# score rows fill one sublane tile of the index kernel's output, so its turn
+# ends in ONE unmasked store
+_INDEX_BLOCK_PAGES = 8
 
 
-def _paged_ring(ps: int, kvh: int, dqk: int, dv: int, dtype) -> int:
-    """Number of page buffers per tensor, from the shapes the kernel sees:
-    as many as fit _PAGED_RING_BUDGET at the size a (page_size, KVH, D)
-    page takes in VMEM (minor dims padded to the dtype's tile), between 2
-    (fetch one page while another is read) and _PAGED_RING_MAX."""
+def paged_turn_pages(ps: int, rows: int, width: int) -> int:
+    """Pages one turn of the paged kernel takes, from the shapes the call
+    sees: the smallest power of two whose block has `_PAGED_TURN_COLS`
+    columns (a page holds ps tokens x `rows` rows a token), at most the index
+    kernel's block and the table's `width`; 1 from that many columns a page
+    up."""
+    g = 1
+    while g * ps * rows < _PAGED_TURN_COLS \
+            and 2 * g <= min(_INDEX_BLOCK_PAGES, width):
+        g *= 2
+    return g
+
+
+def _paged_ring(g: int, dtype, *pages) -> int:
+    """Ring buffers of a stream whose turn takes g pages of each of the
+    shapes `pages` ((ps, KVH, D) of K and of V, (ps, dI) of the index keys):
+    as many as fit _PAGED_RING_BUDGET at the size a page takes in VMEM (minor
+    dims padded to the dtype's tile), between 2 (fetch one turn while another
+    is read) and _PAGED_RING_MAX."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * max(1, 4 // itemsize)
-    rows = -(-kvh // sublanes) * sublanes
-    page = sum(ps * rows * -(-d // LANES) * LANES * itemsize
-               for d in (dqk, dv))
-    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // page)))
+    block = g * sum(
+        math.prod(lead) * -(-r // sublanes) * sublanes
+        * -(-d // LANES) * LANES * itemsize for *lead, r, d in pages)
+    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // block)))
+
+
+def _page_stream(pt_ref, last_ref, pools, bufs, sem, cur, nbuf, g, *,
+                 tail=False, first_ref=None, ring=None):
+    """The hand-issued page stream of the pool arrays `pools` (HBM; K and V,
+    or the index keys alone) that share one page table, a TURN at a time:
+    (prime, take, fetch_next). A turn is a block of g consecutive logical
+    pages of a slot, starting at the slot's first live page (0, or
+    first_ref[slot] of a window layer, whose table is a ring: logical page t
+    in column t % ring) and stepping by g up to last_ref[slot]. With `tail`
+    the pages past the slot's last WHOLE block are turns of one page each, so
+    no page past last_ref[slot] is ever fetched; without it the last block is
+    fetched whole (the caller pads the table, and what lies past the last
+    live page is dead by its own rule). `cur` (SMEM, kept across grid steps)
+    holds [slot, page] where the next turn to fetch starts and [2] the turns
+    consumed; turn n of the stream lives in ring buffer n % nbuf (bufs[i]:
+    (nbuf, g, *page)), its page copies signal that buffer's one semaphore a
+    pool, and the cursor runs ahead across slots, so the DMA queue never
+    drains between them."""
+    nb = pl.num_programs(0)
+    first_of = (lambda s: 0) if first_ref is None else (lambda s: first_ref[s])
+
+    def start(fs, fp, b, pages):
+        for i in range(pages):
+            col = fp + i if ring is None else (fp + i) % ring
+            page = pt_ref[fs, col]
+            for j, (hbm, buf) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(hbm.at[page], buf.at[b, i],
+                                      sem.at[j, b]).start()
+
+    def fetch_next(b):
+        fs, fp = cur[0], cur[1]
+
+        @pl.when(fs < nb)
+        def _():
+            last = last_ref[fs]
+            if tail and g > 1:
+                whole = fp + (g - 1) <= last
+
+                @pl.when(whole)
+                def _():
+                    start(fs, fp, b, g)
+
+                @pl.when(jnp.logical_not(whole))
+                def _():
+                    start(fs, fp, b, 1)
+
+                nxt = fp + jnp.where(whole, g, 1)
+            else:
+                start(fs, fp, b, g)
+                nxt = fp + g
+            more = nxt <= last
+            cur[0] = jnp.where(more, fs, fs + 1)
+            cur[1] = jnp.where(more, nxt,
+                               first_of(jnp.minimum(fs + 1, nb - 1)))
+
+    def prime(ahead):
+        """At the first grid step: the cursor to the stream's start and the
+        first `ahead` turns fetched."""
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            cur[0] = 0
+            cur[1] = first_of(0)
+            cur[2] = 0
+            for i in range(ahead):
+                fetch_next(i)
+
+    def take(pages=g):
+        """The next turn of the stream, a block (or, of a tail, one page),
+        all its copies waited for at once (the semaphore counts bytes): its
+        ring buffer."""
+        n = cur[2]
+        cur[2] = n + 1
+        b = jax.lax.rem(n, nbuf)
+        for j, buf in enumerate(bufs):
+            got = buf.at[b] if pages == g else buf.at[b, pl.ds(0, pages)]
+            pltpu.make_async_copy(got, got, sem.at[j, b]).wait()
+        return b
+
+    return prime, take, fetch_next
 
 
 def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
-                       s: int, h: int, kvh: int, ps: int, nbuf: int,
+                       s: int, h: int, kvh: int, ps: int, nbuf: int, g: int,
                        scale: float, quantized: bool = False,
                        window: Optional[int] = None, pack: int = 1):
     """One slot per grid step: score the slot's (S*H, Dqk) query rows
-    against each of its live pages in turn and fold into the running
-    online softmax (f32 m, l, acc carried by the loop). Scalar-prefetch
+    against its live pages, a turn of g pages at a time (the pages past the
+    last whole block one at a time), and fold each turn into the running
+    online softmax (f32 m, l, acc carried by the loops). Scalar-prefetch
     refs: page table (B, P), last live page (B,), per-position write
     frontier (B, S), row_len (B,), prompt_pad (B,) — and, for a quantized
     pool, the per-(pool page, kv head) f32 k/v scales (P_pool, KVH): the
@@ -928,16 +1049,14 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     VMEM, against the scales of the pool page it came from — the
     full-width KV never exists in HBM.
 
-    k_hbm / v_hbm are the whole pools, left in HBM. `cur` (SMEM, kept
-    across grid steps) is the stream's state: [slot, page] of the next
-    page to fetch and [2] how many pages were consumed; page n of the
-    stream lives in buffer n % nbuf. Fetches run nbuf - 1 pages ahead:
-    the page fetched at the top of an iteration lands in the buffer the
-    previous iteration finished reading.
+    k_hbm / v_hbm are the whole pools, left in HBM; `_page_stream` brings
+    their pages in, nbuf - 1 turns ahead of the arithmetic: the turn fetched
+    at the top of an iteration lands in the buffer the previous iteration
+    finished reading.
 
     A `window` layer (one more scalar-prefetch ref, each slot's first live
     page) keeps its pages in a RING: the table is as wide as the ring and
-    logical page t lives in column t % width. The slot's loop and the
+    logical page t lives in column t % width. The slot's loops and the
     stream's cursor start at the window's first page, and a key at or
     below `frontier - window` is dead.
 
@@ -948,55 +1067,27 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     others. A column is then a (token, row of `pack` KV heads), a query row
     owns the column its KV head lies in, and its zeros keep the neighbours
     out of its scores; its context comes out in its own lanes of the 128."""
+    fp_ref = None
     if window is not None:
         fp_ref, rest = rest[0], rest[1:]
-        ring = pt_ref.shape[1]
     if quantized:
         ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref, \
             k_buf, v_buf, sem, cur = rest
     else:
         q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur = rest
     b = pl.program_id(0)
-    nb = pl.num_programs(0)
+    ring = pt_ref.shape[1] if window is not None else None
     grp = h // kvh
     kvh = kvh // pack           # rows a token takes in a page
-    rows, cols = s * h, ps * kvh
+    rows = s * h
+    prime, take, fetch_next = _page_stream(
+        pt_ref, lp_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem, cur, nbuf, g,
+        tail=True, first_ref=fp_ref, ring=ring)
+    prime(nbuf - 1)
 
-    def page_copies(page, buf):
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf],
-                                      sem.at[1, buf]))
-
-    def fetch_next(buf):
-        fs, fp = cur[0], cur[1]
-
-        @pl.when(fs < nb)
-        def _():
-            col = fp if window is None else fp % ring
-            for c in page_copies(pt_ref[fs, col], buf):
-                c.start()
-            more = fp < lp_ref[fs]
-            cur[0] = jnp.where(more, fs, fs + 1)
-            first = 0 if window is None \
-                else fp_ref[jnp.minimum(fs + 1, nb - 1)]
-            cur[1] = jnp.where(more, fp + 1, first)
-
-    @pl.when(b == 0)
-    def _prime():
-        for i in range(3):
-            cur[i] = 0
-        if window is not None:
-            cur[1] = fp_ref[0]
-        for i in range(nbuf - 1):
-            fetch_next(i)
-
-    # what does not change from page to page: which columns of a page
-    # belong to a row's KV head, each column's token, each row's frontier
+    # what does not change from turn to turn: each row's frontier; which
+    # columns of a turn belong to a row's KV head, and each column's token
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-    own_head = (row % h) // (grp * pack) == col % kvh   # (rows, cols)
-    tok = col // kvh                                    # (1, cols)
     wp = jnp.full((rows, 1), wp_ref[b, 0], jnp.int32)
     for i in range(1, s):
         # slab position i attends at its OWN frontier wp[b, i]: in-slab
@@ -1006,6 +1097,11 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     pp = pp_ref[b]
     q = q_ref[0]                                        # (S*H, Dqk)
 
+    def columns(pages):
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, pages * ps * kvh), 1)
+        own_head = (row % h) // (grp * pack) == col % kvh   # (rows, cols)
+        return own_head, col // kvh                         # tok (1, cols)
+
     def head_scales(sc_ref, page, d):
         # (KVH, d) tile of the page's per-head scales, from SMEM scalars
         kh = jax.lax.broadcasted_iota(jnp.int32, (kvh, d), 0)
@@ -1014,51 +1110,68 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
             tile = jnp.where(kh == i, sc_ref[page, i], tile)
         return tile
 
-    def one_page(t, carry):
-        m_prev, l_prev, acc = carry
-        n = cur[2]
-        cur[2] = n + 1
-        fetch_next((n + nbuf - 1) % nbuf)
-        buf = n % nbuf
-        for c in page_copies(0, buf):
-            c.wait()
-        k = k_buf[buf]                                  # (ps, KVH, Dqk)
-        v = v_buf[buf]                                  # (ps, KVH, Dv)
-        if quantized:
-            page = pt_ref[b, t if window is None else t % ring]
-            k = k.astype(jnp.float32) * head_scales(ks_ref, page,
-                                                    k.shape[-1])
-            v = v.astype(jnp.float32) * head_scales(vs_ref, page,
-                                                    v.shape[-1])
-        # mixed-width pool (kv_cache_dtype='bf16' under f32 compute) and
-        # the dequantized page: both matmuls run at query precision,
-        # matching the einsum oracle's cast
-        k = k.reshape(cols, -1).astype(q.dtype)
-        v = v.reshape(cols, -1).astype(q.dtype)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (rows, cols)
-        j = t * ps + tok
-        live = (j < rl) | ((j >= pp) & (j <= wp))
-        if window is not None:
-            live = live & (j > wp - window)
-        sc = jnp.where(live & own_head, sc, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
-                                    preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+    def dequantized(x, sc_ref, t):
+        # each page of the turn against its own pool page's scales
+        tiles = [head_scales(sc_ref,
+                             pt_ref[b, t + i if ring is None
+                                    else (t + i) % ring], x.shape[-1])
+                 for i in range(x.shape[0])]
+        return x.astype(jnp.float32) * jnp.stack(tiles)[:, None]
 
-    _, l_fin, acc = jax.lax.fori_loop(
-        0 if window is None else fp_ref[b], lp_ref[b] + 1, one_page,
-        (jnp.full((rows, 1), NEG_INF, jnp.float32),
-         jnp.zeros((rows, 1), jnp.float32),
-         jnp.zeros((rows, v_buf.shape[-1]), jnp.float32)))
+    def turn(pages):
+        """The arithmetic of a turn of `pages` pages from logical page t."""
+        cols = pages * ps * kvh
+        own_head, tok = columns(pages)
+
+        def body(t, carry):
+            m_prev, l_prev, acc = carry
+            fetch_next(jax.lax.rem(cur[2] + nbuf - 1, nbuf))
+            buf = take(pages)
+            # (pages, ps, KVH, D), or dense (pages, ps x KVH, D)
+            k = k_buf[buf, :pages]
+            v = v_buf[buf, :pages]
+            if quantized:
+                k = dequantized(k, ks_ref, t)
+                v = dequantized(v, vs_ref, t)
+            # mixed-width pool (kv_cache_dtype='bf16' under f32 compute) and
+            # the dequantized page: both matmuls run at query precision,
+            # matching the einsum oracle's cast
+            k = k.reshape(cols, -1).astype(q.dtype)
+            v = v.reshape(cols, -1).astype(q.dtype)
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, cols)
+            j = t * ps + tok
+            live = (j < rl) | ((j >= pp) & (j <= wp))
+            if window is not None:
+                live = live & (j > wp - window)
+            sc = jnp.where(live & own_head, sc, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                        preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
+
+        return body
+
+    first = 0 if window is None else fp_ref[b]
+    carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, v_buf.shape[-1]), jnp.float32))
+    if g > 1:
+        block, blocks = turn(g), (lp_ref[b] + 1 - first) // g
+        carry = jax.lax.fori_loop(
+            0, blocks, lambda i, c, t0=first: block(t0 + i * g, c), carry)
+        first += blocks * g
+    # at g = 1 every live page; else the pages past the slot's last whole
+    # block, one a turn: a dead page is never fetched (its 0 x NaN in p v
+    # would be NaN)
+    _, l_fin, acc = jax.lax.fori_loop(first, lp_ref[b] + 1, turn(1), carry)
     # every row has >= 1 live position (its own write frontier:
     # prompt_pad <= write_pos always holds, and the inactive-slot zeros
-    # satisfy j == 0 <= write_pos == 0), so l > 0 — no guard. A page in
+    # satisfy j == 0 <= write_pos == 0), so l > 0 — no guard. A turn in
     # which a row has NO live column leaves p = 1 everywhere while m is
     # still NEG_INF; the first live score then scales that away (alpha = 0)
     o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
@@ -1075,11 +1188,14 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     write_pos (B, S) int32 is each slab position's logical write
     frontier (host-clamped, nondecreasing over S); row_len / prompt_pad
     (B,) the ragged-prompt live-rule bounds. The grid is the slots; each
-    grid step loops over its slot's live pages only, whose DMAs were
-    issued by hand some pages earlier (possibly during the previous
-    slot) into a ring of VMEM buffers as deep as _paged_ring says for
-    these shapes. HBM traffic and time both follow the LIVE pages, not
-    the pool and not the table's width. A slot that is not active
+    grid step loops over its slot's live pages only, a turn of
+    `paged_turn_pages` pages at a time (one where a page has the columns,
+    a block of 2, 4 or 8 of narrower pages, which then lands dense; the
+    pages past the last whole block one a turn), whose DMAs were issued
+    by hand some turns earlier (possibly during the previous slot) into a
+    ring of VMEM buffers as deep as _paged_ring says for these shapes.
+    HBM traffic and time both follow the LIVE pages, not the pool and not
+    the table's width. A slot that is not active
     (write_pos == row_len == prompt_pad == 0) is indistinguishable from
     a one-token context and streams its one page (scratch page 0), as
     the oracle reads it. Inference-only: no VJP (the serving engine never
@@ -1119,7 +1235,23 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     assert (k_scales is None) == (v_scales is None), \
         "quantized pools carry BOTH k and v scales"
     quantized = k_scales is not None
-    nbuf = _paged_ring(ps, kvh // pack, dqk, dv, k_pages.dtype)
+    rows = kvh // pack          # rows a token takes in a page
+    g = paged_turn_pages(ps, rows, page_table.shape[1])
+    k_page, v_page = (ps, rows, dqk), (ps, rows, dv)
+    if g > 1 and not quantized:
+        # a block LANDS DENSE: the pools seen as (pages, ps x rows, D), the
+        # same bytes in the same order (XLA makes the reshape a bitcast of
+        # the tiled pool), so a turn's g pages are one (g x ps x rows, D)
+        # buffer with no sublane padding. A page of fewer rows a token than
+        # the dtype's sublane tile (4 or 2 against bf16's 16) lands padded
+        # to the tile otherwise and is re-packed by the body, 128 registers
+        # a page and tensor whatever the turn: 0.22 us a page of 4 rows
+        # against its 0.32 us copy (PERF.md section 6, PR 45). A quantized
+        # pool keeps its rows apart: its scales are a head's
+        k_page, v_page = (ps * rows, dqk), (ps * rows, dv)
+        k_pages = k_pages.reshape(-1, *k_page)
+        v_pages = v_pages.reshape(-1, *v_page)
+    nbuf = _paged_ring(g, k_pages.dtype, k_page, v_page)
     # last live page per slot: the live rule's bound is max(write
     # frontier, prompt tail) — a serving dispatch always has write_pos
     # >= prompt_pad >= row_len, but the kernel honors the FULL rule so
@@ -1156,15 +1288,15 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
         ],
         out_specs=pl.BlockSpec((1, s * h, dv), slot_map),
         scratch_shapes=[
-            pltpu.VMEM((nbuf, ps, kvh // pack, dqk), k_pages.dtype),
-            pltpu.VMEM((nbuf, ps, kvh // pack, dv), v_pages.dtype),
+            pltpu.VMEM((nbuf, g, *k_page), k_pages.dtype),
+            pltpu.VMEM((nbuf, g, *v_page), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, nbuf)),
             pltpu.SMEM((3,), jnp.int32),               # the stream's state
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
-                          nbuf=nbuf, scale=scale, quantized=quantized,
+                          nbuf=nbuf, g=g, scale=scale, quantized=quantized,
                           window=window, pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s * h, dv), q.dtype),
@@ -1535,9 +1667,9 @@ def moe_expert_stream_pallas(x, gates, sizes, *weights):
 # the index pool in place and, of the latent pool, only the rows the
 # selection kept:
 #
-#   dsa_index_scores  the grid is the slots, as in `_paged_attn_kernel`, but
-#                     a turn of the slot's loop takes a BLOCK of
-#                     _INDEX_BLOCK_PAGES = 8 pages, not one: one wait for the
+#   dsa_index_scores  the grid is the slots and the stream `_page_stream`, as
+#                     in `_paged_attn_kernel`; a turn of the slot's loop
+#                     takes a BLOCK of _INDEX_BLOCK_PAGES = 8 pages: one wait for the
 #                     block's 8 page copies (one semaphore a ring buffer,
 #                     counting bytes), 8 products of a page's index keys
 #                     (ps, dI), stationary in an MXU, with the slot's J index
@@ -1571,65 +1703,10 @@ def moe_expert_stream_pallas(x, gates, sizes, *weights):
 # the gather in front of it is an XLA fusion that no name marks.
 
 
-# pages of one index turn: their (1, ps) f32 score rows fill one sublane
-# tile of the output, so a turn ends in ONE unmasked store
-_INDEX_BLOCK_PAGES = 8
-
-
 def dsa_index_block_tokens(page_size: int) -> int:
     """Tokens one turn of `dsa_index_scores` fetches and scores: a slot's
     context is streamed in whole blocks of this many."""
     return _INDEX_BLOCK_PAGES * page_size
-
-
-def _page_stream(pt_ref, lb_ref, hbm, buf, sem, cur, nbuf, g):
-    """The hand-issued page stream of one pool array, in blocks of g pages
-    (table columns [block * g, block * g + g)): (prime, take, refill). `cur`
-    (SMEM, kept across grid steps) holds [slot, block] of the next block to
-    fetch and [2] the blocks consumed; block n of the stream lives in buffer
-    n % nbuf, its g page copies signal that buffer's one semaphore, and
-    fetches run nbuf blocks ahead, across slots."""
-    nb = pl.num_programs(0)
-
-    def fetch_next(b):
-        fs, fb = cur[0], cur[1]
-
-        @pl.when(fs < nb)
-        def _():
-            for i in range(g):
-                pltpu.make_async_copy(hbm.at[pt_ref[fs, fb * g + i]],
-                                      buf.at[b, i], sem.at[b]).start()
-            more = fb < lb_ref[fs]
-            cur[0] = jnp.where(more, fs, fs + 1)
-            cur[1] = jnp.where(more, fb + 1, 0)
-
-    def prime():
-        @pl.when(pl.program_id(0) == 0)
-        def _():
-            for i in range(3):
-                cur[i] = 0
-            for i in range(nbuf):
-                fetch_next(i)
-
-    def take():
-        """The next block of the stream, all g pages waited for at once (the
-        semaphore counts bytes): its ring buffer."""
-        n = cur[2]
-        cur[2] = n + 1
-        b = jax.lax.rem(n, nbuf)
-        pltpu.make_async_copy(buf.at[b], buf.at[b], sem.at[b]).wait()
-        return b
-
-    # refill(b) = fetch_next(b), called once the turn has read buffer b: the
-    # block a ring's depth ahead goes into the buffer just given back
-    return prime, take, fetch_next
-
-
-def _stream_ring(g: int, ps: int, width: int, dtype) -> int:
-    """Block buffers of a stream of g (ps, width) pages a block: 2 ..
-    _PAGED_RING_MAX."""
-    block = g * ps * -(-width // LANES) * LANES * jnp.dtype(dtype).itemsize
-    return int(max(2, min(_PAGED_RING_MAX, _PAGED_RING_BUDGET // block)))
 
 
 def _live_columns(page0, g, ps, rl, pp, wp):
@@ -1640,13 +1717,16 @@ def _live_columns(page0, g, ps, rl, pp, wp):
     return (j < rl) | ((j >= pp) & (j <= wp))
 
 
-def _dsa_index_kernel(pt_ref, lb_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
+def _dsa_index_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
                       k_hbm, o_ref, k_buf, sem, cur, *, ps: int, nbuf: int,
                       g: int):
     b = pl.program_id(0)
-    prime, take, refill = _page_stream(pt_ref, lb_ref, k_hbm, k_buf, sem, cur,
-                                       nbuf, g)
-    prime()
+    # refill(buf) = the stream's fetch, called once the turn has read buffer
+    # buf: the block a ring's depth ahead goes into the buffer just given
+    # back
+    prime, take, refill = _page_stream(pt_ref, lp_ref, (k_hbm,), (k_buf,),
+                                       sem, cur, nbuf, g)
+    prime(nbuf)
     q = q_ref[0]                                        # (J, dI)
     # across the lanes once a slot, not once a page
     w = jnp.broadcast_to(w_ref[0], (q.shape[0], ps))    # (J, ps) f32
@@ -1674,7 +1754,7 @@ def _dsa_index_kernel(pt_ref, lb_ref, wp_ref, rl_ref, pp_ref, q_ref, w_ref,
         refill(buf)
         return carry
 
-    jax.lax.fori_loop(0, lb_ref[b] + 1, one_block, 0)
+    jax.lax.fori_loop(0, lp_ref[b] // g + 1, one_block, 0)
 
 
 # inline=True: as `moe_expert_stream_pallas`, so a program's layers share one
@@ -1694,10 +1774,10 @@ def dsa_index_scores_pallas(qi, w, ki_pages, page_table, write_pos, row_len,
     p = page_table.shape[1]
     g = _INDEX_BLOCK_PAGES
     p_pad = -(-p // g) * g
-    nbuf = _stream_ring(g, ps, di, ki_pages.dtype)
+    nbuf = _paged_ring(g, ki_pages.dtype, (ps, di))
     last = jnp.maximum(write_pos, row_len - 1) // ps
     prefetch = [jnp.pad(page_table.astype(jnp.int32), ((0, 0), (0, p_pad - p))),
-                (last // g).astype(jnp.int32), write_pos.astype(jnp.int32),
+                last.astype(jnp.int32), write_pos.astype(jnp.int32),
                 row_len.astype(jnp.int32), prompt_pad.astype(jnp.int32)]
 
     def slot_map(bi, *_):
@@ -1710,7 +1790,7 @@ def dsa_index_scores_pallas(qi, w, ki_pages, page_table, write_pos, row_len,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, p_pad, ps), slot_map),
         scratch_shapes=[pltpu.VMEM((nbuf, g, ps, di), ki_pages.dtype),
-                        pltpu.SemaphoreType.DMA((nbuf,)),
+                        pltpu.SemaphoreType.DMA((1, nbuf)),
                         pltpu.SMEM((3,), jnp.int32)])
     out = pl.pallas_call(
         functools.partial(_dsa_index_kernel, ps=ps, nbuf=nbuf, g=g),

@@ -618,6 +618,16 @@ class ServingEngine:
 
         self.paged_attention_impl = resolve_paged_attention_impl(
             paged_attention_impl)
+        # pages a turn of the paged kernel takes on the ops that keep
+        # everything (a static of their pools' shapes and the table's width:
+        # ops/pallas_kernels.py `paged_turn_pages`); the gather has no turns
+        self._paged_turn_pages = 1
+        if self.paged_attention_impl == "pallas":
+            self._paged_turn_pages = max(
+                (op.paged_turn_pages(self.kv.pool[op.name],
+                                     self.pages_per_slot)
+                 for op in self.gen.attn_ops if op_keeps(op) is None),
+                default=1)
         fflogger.info(
             "serving: paged decode attention and prefill write impl=%s "
             "kv_cache_dtype=%s "
@@ -778,6 +788,7 @@ class ServingEngine:
         self._pages_touched = 0
         self._last_pages_touched = 0
         self._kv_read_bytes = 0
+        self._kv_streamed_bytes = 0
         # pages held, summed over decode steps, by kind of table (a model
         # with window layers: `_reach_windows`)
         self._page_steps = {"global": 0, "window": 0}
@@ -2582,25 +2593,33 @@ class ServingEngine:
                 budget[slot] = req.bucket + req.max_new_tokens
         return write_pos, rope_pos, budget
 
-    def _note_pages_touched(self, frontier, budget) -> int:
+    def _note_pages_touched(self, frontier, budget):
         """Record the pool pages this dispatch's attention READS: per
         active slot, pages up to its final-step write frontier (what the
         pallas kernel streams through VMEM — the einsum path gathers the
         whole table width regardless, which is exactly the delta the
         kernel exists to remove). ``frontier`` is (slots, steps): the
         write position of each attention pass the dispatch makes.
-        Returns the KV bytes those passes stream for the active slots
+        Returns the KV bytes those passes read for the active slots
         (live pages x page_size x kv_bytes_per_token, summed over
-        passes), which ``kv_read_bytes`` accumulates."""
+        passes), which ``kv_read_bytes`` accumulates, and the bytes the
+        kernel's turns FETCH for them, which ``kv_streamed_bytes`` does:
+        a slot's whole blocks of `paged_turn_pages` pages and, one a turn,
+        the pages past its last whole block, so no page past the last live
+        one: the same bytes, whatever a turn takes (the index kernel's
+        stream rounds a slot up to whole blocks and reads more than it
+        needs: ``index_streamed_bytes``; a tail that fetched whole blocks
+        would round `pages` up to `paged_turn_pages` here)."""
         fr = np.minimum(frontier, (budget - 1)[:, None])
         pages = (fr // self.page_size + 1)[self.active]  # (active, steps)
         touched = int(pages[:, -1].sum())
         self._last_pages_touched = touched
         self._pages_touched += touched
-        kv_read = int(int(pages.sum()) * self.page_size
-                      * self._kv_bytes_per_token)
+        kv_read = kv_streamed = int(int(pages.sum()) * self.page_size
+                                    * self._kv_bytes_per_token)
         self._kv_read_bytes += kv_read
-        return kv_read
+        self._kv_streamed_bytes += kv_streamed
+        return kv_read, kv_streamed
 
     def _reach_windows(self, rope_pos, budget, k: int) -> Dict:
         """Before a decode dispatch of `k` steps over window layers: every
@@ -2637,7 +2656,7 @@ class ServingEngine:
             # at dispatch (the bytes roofline of the kernel reads them)
             context = int((np.minimum(write_pos, budget - 1)
                            + 1)[self.active].sum())
-            kv_read = self._note_pages_touched(
+            kv_read, kv_streamed = self._note_pages_touched(
                 write_pos[:, None] + np.arange(k), budget)
             attn = collections.Counter()
             if self._counting_attn_ops:
@@ -2667,6 +2686,8 @@ class ServingEngine:
         key = ("decode", k)
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
                         context_tokens=context, kv_read_bytes=kv_read,
+                        kv_streamed_bytes=kv_streamed,
+                        paged_turn_pages=self._paged_turn_pages,
                         program=program_name(key), **attn) as sp:
             toks, oks, self.kv.pool, *routed = self._compiled_call(
                 key, lambda: self._build_decode(k, self._moe_took_list(key)),
@@ -3375,5 +3396,9 @@ class ServingEngine:
             "paged_prefill_impl": self.paged_attention_impl,
             "pages_touched": self._pages_touched,
             "kv_read_bytes": self._kv_read_bytes,
+            # what the kernel's turns fetched for them, and the pages a
+            # turn takes (`_note_pages_touched`)
+            "kv_streamed_bytes": self._kv_streamed_bytes,
+            "paged_turn_pages": self._paged_turn_pages,
             "last_pages_touched": self._last_pages_touched,
         }

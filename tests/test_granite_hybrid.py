@@ -298,6 +298,12 @@ def test_heads_of_a_part_of_a_lane_tile_share_a_row_of_the_pool():
         assert [r.prefix_tokens for r in reqs] == [24, 24, 0, 0]
         for r in reqs:
             assert margins(ff, r, sizes).max() <= 2 * LOGIT_ATOL
+        # a page of 8 tokens x 1 row: the kernel's turn takes a block of as
+        # many pages as the table is wide, and fetches no page past a slot's
+        # last live one (the pages past its last whole block go one a turn)
+        st = eng.stats()
+        assert st["paged_turn_pages"] == (8 if impl == "pallas" else 1)
+        assert st["kv_streamed_bytes"] == st["kv_read_bytes"] > 0
 
 
 def test_a_chunk_loop_is_the_unrolled_chunks(ff):

@@ -92,9 +92,30 @@ def test_kernel_matches_einsum_decode_ragged_scrambled(ff, attn):
                                       np.asarray(cache_p[n]))
 
 
-def test_kernel_matches_einsum_verify_slab(ff, attn):
+TURNS = [1, 2, 4]
+
+
+def _set_turn(monkeypatch, page, rows, width, turn):
+    """Make a turn of the kernel take `turn` pages of `page` tokens x `rows`
+    rows (fewer under a narrower table) the way the rule itself would: by
+    the columns its one constant asks of a turn, never through an argument
+    of the kernel."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_PAGED_TURN_COLS",
+                        turn * page * rows)
+    want = max(g for g in TURNS if g <= min(turn, width))
+    assert pallas_kernels.paged_turn_pages(page, rows, width) == want
+    return want
+
+
+@pytest.mark.parametrize("turn", TURNS)
+def test_kernel_matches_einsum_verify_slab(ff, attn, monkeypatch, turn):
     """S=4 speculative-verify slab: per-position write frontiers give
-    in-slab causality; every position's context must match the oracle."""
+    in-slab causality; every position's context must match the oracle. The
+    second slot's frontiers 11..13 straddle the edge of a block of 2 pages,
+    the first's 9..12 end in the third page: a block and a tail page."""
+    _set_turn(monkeypatch, 4, attn.num_kv_heads, 4, turn)
     rs = np.random.RandomState(5)
     pool = _pool(rs, attn)
     params = _params(ff, attn)
@@ -116,11 +137,14 @@ def test_kernel_matches_einsum_verify_slab(ff, attn):
     np.testing.assert_allclose(np.asarray(out_e), np.asarray(out_p), **TOL)
 
 
-def test_kernel_inactive_slot_scratch_page(ff, attn):
+@pytest.mark.parametrize("turn", TURNS)
+def test_kernel_inactive_slot_scratch_page(ff, attn, monkeypatch, turn):
     """The serving engine's inactive-slot state (zeroed table -> every
     write lands in scratch page 0, write_pos=row_len=prompt_pad=0): the
     kernel must produce the same finite output as the oracle — its live
-    rule admits j=0, so the online softmax never divides by zero."""
+    rule admits j=0, so the online softmax never divides by zero. A turn of
+    several pages takes the idle slot's one page as a tail."""
+    _set_turn(monkeypatch, 4, attn.num_kv_heads, 4, turn)
     rs = np.random.RandomState(7)
     pool = _pool(rs, attn)
     params = _params(ff, attn)
@@ -264,6 +288,31 @@ def test_stats_report_one_impl_under_both_names(ff, impl):
     assert st["paged_attention_impl"] == st["paged_prefill_impl"] \
         == resolve_paged_attention_impl(impl)
     assert not [k for k in st if k.startswith("kernel_tune")]
+
+
+@pytest.mark.parametrize("turn", TURNS)
+def test_engine_counts_what_the_turns_fetch(ff, monkeypatch, turn):
+    """Host only: beside `kv_read_bytes` (the live pages of each slot and
+    step) the engine counts `kv_streamed_bytes`, what the kernel's turns
+    fetch for them, from `paged_turn_pages`, the pages a turn takes on its
+    pools (a static of their shapes and the table's width). A slot's whole
+    blocks and, one a turn, the pages past them: never less than is read,
+    the same at one page a turn, and under this tail the same at any."""
+    g = _set_turn(monkeypatch, 4, 2, 8, turn)
+    eng = _engine(ff, "pallas")
+    assert eng.stats()["paged_turn_pages"] == g
+    assert _engine(ff, "einsum").stats()["paged_turn_pages"] == 1
+    eng.active[:] = True
+    budget = np.asarray([32, 32])
+    # two steps a slot: 2 and 3 live pages, 6 and 8 (the budget's clamp)
+    frontier = np.asarray([[7, 8], [22, 40]])
+    read, streamed = eng._note_pages_touched(frontier, budget)
+    per_page = eng.page_size * eng.stats()["kv_bytes_per_token"]
+    assert read == (2 + 3 + 6 + 8) * per_page
+    assert streamed == read
+    st = eng.stats()
+    assert (st["kv_read_bytes"], st["kv_streamed_bytes"]) == (read, streamed)
+    assert st["pages_touched"] == st["last_pages_touched"] == 3 + 8
 
 
 FLASH_SEQ, FLASH_HEADS, FLASH_DIM = 256, 2, 16
@@ -420,7 +469,9 @@ def _oracle(q, pool, table, wp, row_len, pad, scale):
     from flexflow_tpu.ops.attention import page_dequantize
 
     b, s, h, d = q.shape
-    page, kvh = pool["k"].shape[1], pool["k"].shape[2]
+    page = pool["k"].shape[1]
+    # a packed pool's row holds 128 / d neighbouring heads: the same bytes
+    kvh = pool["k"].shape[2] * pool["k"].shape[3] // d
     max_len = table.shape[1] * page
     gk, gv = pool["k"][table], pool["v"][table]
     if "k_scale" in pool:
@@ -464,13 +515,30 @@ def _stream_case(name):
         "quantized-verify": (4, 2, 5, [(3, 4, 6), (10, 12, 14)], 3, True),
         # the engine's idle state in every slot: zeroed table rows
         "all-inactive": (4, 2, 4, [(0, 0, 0)] * 4, 1, False),
+        # live pages 1, 2, 3, 4, 5: at a turn of g = 2 or 4 pages a slot of
+        # one page, of g - 1, g and g + 1
+        "block-edges": (4, 2, 6, [(1, 2, 2), (3, 4, 6), (9, 9, 10),
+                                  (2, 4, 14), (7, 8, 18)], 1, False),
+        # granite's: 8 KV heads of 64, two a 128-lane row of the pool
+        # (ops/attention.py `pool_pack`), 4 rows a token
+        "packed-heads-of-64": (32, 8, 6, [(3, 4, 9), (1, 2, 3), (7, 8, 19),
+                                          (2, 4, 14)], 1, False),
+        # Nemotron's: 2 KV heads under 8 query heads, 2 rows a token
+        "two-kv-heads": (8, 2, 6, [(3, 4, 9), (1, 2, 3), (7, 8, 19),
+                                   (9, 12, 15)], 1, False),
     }[name] + (page,)
+
+
+STREAM_CASES = ["ragged-live-pages", "one-live-page", "narrow-table",
+                "gqa-g2", "mha-g1", "verify-straddle", "quantized",
+                "quantized-verify", "all-inactive", "block-edges",
+                "packed-heads-of-64", "two-kv-heads"]
 
 
 def _stream_inputs(name, seed):
     h, kvh, pps, slots, s, quantized, page = _stream_case(name)
     rs = np.random.RandomState(seed)
-    b, d = len(slots), 16
+    b, d = len(slots), 64 if name == "packed-heads-of-64" else 16
     n_pages = 1 + b * pps
     kf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
     vf = rs.randn(n_pages, page, kvh, d).astype(np.float32)
@@ -483,6 +551,10 @@ def _stream_inputs(name, seed):
                 "k_scale": ks, "v_scale": vs}
     else:
         pool = {"k": jnp.asarray(kf), "v": jnp.asarray(vf)}
+    if d == 64:
+        # the pool as `init_paged_cache` holds it: (.., KVH / 2, 128)
+        pool = {n: x.reshape(n_pages, page, kvh // 2, 128)
+                for n, x in pool.items()}
     row_len = np.asarray([r for r, _, _ in slots], np.int32)
     pad = np.asarray([p for _, p, _ in slots], np.int32)
     wp = (np.asarray([w for _, _, w in slots], np.int32)[:, None]
@@ -504,17 +576,18 @@ def _run_kernel(q, pool, table, wp, row_len, pad, scale=0.29):
         k_scales=pool.get("k_scale"), v_scales=pool.get("v_scale"))
 
 
-@pytest.mark.parametrize("ring", [2, 3, 4])
-@pytest.mark.parametrize("name", [
-    "ragged-live-pages", "one-live-page", "narrow-table", "gqa-g2",
-    "mha-g1", "verify-straddle", "quantized", "quantized-verify",
-    "all-inactive"])
-def test_kernel_page_stream_matches_oracle(monkeypatch, name, ring):
-    """Every seam of the page stream against the einsum oracle."""
+@pytest.mark.parametrize("ring,turn", [(2, 1), (3, 1), (4, 1), (2, 2),
+                                       (3, 4)])
+@pytest.mark.parametrize("name", STREAM_CASES)
+def test_kernel_page_stream_matches_oracle(monkeypatch, name, ring, turn):
+    """Every seam of the page stream against the einsum oracle: each ring
+    depth at one page a turn, and each number of pages the rule can give a
+    turn under a ring that wraps inside a block's run of turns."""
     from flexflow_tpu.ops import pallas_kernels
 
     monkeypatch.setattr(pallas_kernels, "_PAGED_RING_MAX", ring)
     q, pool, table, wp, row_len, pad, _ = _stream_inputs(name, 29)
+    _set_turn(monkeypatch, *pool["k"].shape[1:3], table.shape[1], turn)
     out = _run_kernel(q, pool, table, wp, row_len, pad)
     want = _oracle(q, pool, jnp.asarray(table), jnp.asarray(wp),
                    jnp.asarray(row_len), jnp.asarray(pad), 0.29)
@@ -522,15 +595,20 @@ def test_kernel_page_stream_matches_oracle(monkeypatch, name, ring):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
 
 
-@pytest.mark.parametrize("name", ["ragged-live-pages", "verify-straddle",
-                                  "all-inactive"])
-def test_kernel_never_reads_a_dead_page(name):
+@pytest.mark.parametrize("name,turn", [
+    (name, turn) for name in ("ragged-live-pages", "verify-straddle",
+                              "all-inactive") for turn in TURNS]
+    + [(name, turn) for name in ("block-edges", "packed-heads-of-64",
+                                 "two-kv-heads") for turn in TURNS[1:]])
+def test_kernel_never_reads_a_dead_page(monkeypatch, name, turn):
     """Poison: every pool page that is live for NO slot is NaN — the
     table's entries past each slot's last live page point at such pages
     too — and the output still equals the oracle's on the clean pool:
     a dead page is neither fetched into the arithmetic nor multiplied by
-    a zero probability."""
+    a zero probability, whether a turn takes one page or a block of them
+    (a slot's pages past its last whole block go one a turn)."""
     q, pool, table, wp, row_len, pad, last = _stream_inputs(name, 31)
+    _set_turn(monkeypatch, *pool["k"].shape[1:3], table.shape[1], turn)
     want = _oracle(q, pool, jnp.asarray(table), jnp.asarray(wp),
                    jnp.asarray(row_len), jnp.asarray(pad), 0.29)
     live_pages = {int(table[b, t]) for b in range(table.shape[0])
@@ -553,16 +631,49 @@ def test_ring_depth_follows_the_shapes():
     from flexflow_tpu.ops.pallas_kernels import (_PAGED_RING_BUDGET,
                                                  _paged_ring)
 
-    assert _paged_ring(128, 8, 128, 128, jnp.bfloat16) == 4    # InternLM2
-    assert _paged_ring(128, 16, 128, 128, jnp.bfloat16) == 4   # OLMoE
-    assert _paged_ring(128, 8, 128, 128, jnp.int8) == 4
-    wide = _paged_ring(256, 32, 128, 128, jnp.bfloat16)
+    def ring(ps, kvh, dqk, dv, dtype, g=1):
+        return _paged_ring(g, dtype, (ps, kvh, dqk), (ps, kvh, dv))
+
+    assert ring(128, 8, 128, 128, jnp.bfloat16) == 4    # InternLM2
+    assert ring(128, 16, 128, 128, jnp.bfloat16) == 4   # OLMoE
+    assert ring(128, 8, 128, 128, jnp.int8) == 4
+    wide = ring(256, 32, 128, 128, jnp.bfloat16)
     assert wide == 2
     assert 2 * wide * 256 * 32 * 128 * 2 <= _PAGED_RING_BUDGET
-    assert _paged_ring(256, 16, 128, 128, jnp.bfloat16) == 4
-    assert _paged_ring(256, 16, 256, 128, jnp.bfloat16) == 2
-    assert _paged_ring(512, 64, 256, 256, jnp.float32) == 2    # the floor
-    assert _paged_ring(4, 2, 16, 16, jnp.float32) == 4         # the tests'
+    assert ring(256, 16, 128, 128, jnp.bfloat16) == 4
+    assert ring(256, 16, 256, 128, jnp.bfloat16) == 2
+    assert ring(512, 64, 256, 256, jnp.float32) == 2    # the floor
+    assert ring(4, 2, 16, 16, jnp.float32) == 4         # the tests'
+    # a turn of g pages is g pages of buffer: granite's block of 2 (4 rows
+    # a token, padded to the 16 of a bf16 tile), Nemotron's of 4 (2 rows)
+    assert ring(128, 4, 128, 128, jnp.bfloat16, g=2) == 4
+    assert ring(128, 2, 128, 128, jnp.bfloat16, g=4) == 2
+    # the index kernel's stream: blocks of 8 pages of (128, 128) keys
+    assert _paged_ring(8, jnp.bfloat16, (128, 128)) == 4
+
+
+def test_turn_pages_follow_the_shapes():
+    """A turn takes the smallest power-of-two block of pages that has the
+    columns (tokens x rows a token) of the turn the kernel reaches its roof
+    on, from the shapes the call sees, never from a model's name: one page
+    where a page has them already, never more than the index kernel's 8 nor
+    than the table is wide."""
+    from flexflow_tpu.ops.pallas_kernels import (dsa_index_block_tokens,
+                                                 paged_turn_pages)
+
+    assert paged_turn_pages(128, 8, 66) == 1      # InternLM2: 8 KV heads
+    assert paged_turn_pages(128, 16, 32) == 1     # OLMoE: 16
+    assert paged_turn_pages(128, 8, 264) == 1     # K-EXAONE, a global layer
+    assert paged_turn_pages(128, 8, 2) == 1       # and a window layer's ring
+    assert paged_turn_pages(128, 4, 134) == 2     # granite: 8 heads of 64
+    assert paged_turn_pages(128, 2, 32) == 4      # Nemotron: 2 heads of 128
+    assert paged_turn_pages(128, 8, 66) == 1      # an int8 pool of 8 heads
+    assert paged_turn_pages(256, 2, 64) == 2 and paged_turn_pages(64, 8, 64) \
+        == 2
+    assert paged_turn_pages(4, 2, 100) == 8       # the tests': the cap
+    assert [paged_turn_pages(4, 2, w) for w in (1, 2, 3, 4, 7, 8)] \
+        == [1, 2, 2, 4, 4, 8]                     # and the table's width
+    assert dsa_index_block_tokens(128) == 1024
 
 
 # ---- paged prefill/append write kernel (ISSUE 18) -------------------------

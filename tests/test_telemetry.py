@@ -749,7 +749,8 @@ def _run_warm(eng, seed, lengths, max_new):
     st1 = eng.stats()
     return reqs, t0, {k: st1[k] - st0[k] for k in
                       ("decode_steps", "pages_touched", "kv_read_bytes",
-                       "tokens_generated", "completed")}
+                       "kv_streamed_bytes", "tokens_generated",
+                       "completed")}
 
 
 @pytest.fixture(scope="module")
@@ -821,7 +822,8 @@ def profiled(ff, tmp_path_factory):
     return {"under": under, "eng": eng, "reqs": reqs, "off_reqs": off_reqs,
             "ring_since": t0, "ring_grew_off": ring_after - ring_before,
             "delta": {k: st1[k] - st0[k] for k in
-                      ("decode_steps", "kv_read_bytes", "pages_touched")}}
+                      ("decode_steps", "kv_read_bytes", "kv_streamed_bytes",
+                       "pages_touched")}}
 
 
 @pytest.mark.parametrize("case", ["count at entry", "count from annotate()",
@@ -867,6 +869,12 @@ def test_ring_and_profiler_hold_the_same_tick_spans(profiled):
     assert sum(d["k"] for d in disp) == profiled["delta"]["decode_steps"]
     assert sum(d["kv_read_bytes"] for d in disp) \
         == profiled["delta"]["kv_read_bytes"] > 0
+    # what the kernel's turns fetch for them: never less, and the same at
+    # one page a turn
+    assert {d["paged_turn_pages"] for d in disp} == {1}
+    assert sum(d["kv_streamed_bytes"] for d in disp) \
+        == profiled["delta"]["kv_streamed_bytes"] \
+        == profiled["delta"]["kv_read_bytes"]
 
 
 def test_telemetry_off_writes_neither_ring_event_nor_annotation(profiled):
@@ -912,6 +920,9 @@ def test_tick_span_tree_and_dispatch_counts(ff):
             if e["name"] == "decode_dispatch"]
     assert sum(d["k"] for d in disp) == delta["decode_steps"]
     assert sum(d["kv_read_bytes"] for d in disp) == delta["kv_read_bytes"]
+    assert sum(d["kv_streamed_bytes"] for d in disp) \
+        == delta["kv_streamed_bytes"] >= delta["kv_read_bytes"]
+    assert eng.stats()["paged_turn_pages"] == 1     # the gather has no turns
     # pages_touched counts the final step's frontier once per dispatch: k
     # steps of it bound what the k steps stream
     st = eng.stats()
