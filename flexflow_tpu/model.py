@@ -287,17 +287,24 @@ class FFModel:
                             qk_norm=False, eps: float = 1e-6,
                             window: int = 0, flash_chunks: bool = False,
                             softmax_scale: Optional[float] = None,
+                            rope_dim: int = 0, sink: Optional[float] = None,
+                            value_scale: float = 1.0,
                             name: Optional[str] = None, **kw) -> Tensor:
         """`window` > 0: a sliding-window layer (position i sees keys
         i - window < j <= i); `qk_norm`: True over all heads of a position,
         "head" over each head's entries; `softmax_scale`: what q k^T is
-        multiplied by, None = 1 / sqrt(head size) (ops/attention.py)."""
+        multiplied by, None = 1 / sqrt(head size); `rope_dim` > 0: rotary
+        over a head's first `rope_dim` entries only; `sink`: a learned logit
+        a query head in the softmax's denominator (the value is the seeded
+        draw's standard deviation); `value_scale`: v = value_scale * x Wv
+        (ops/attention.py)."""
         return self._add(MultiHeadAttention(
             self, self._name("multihead_attention", name), [query, key, value],
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, causal, num_kv_heads=num_kv_heads, rope=rope,
             rope_theta=rope_theta, qk_norm=qk_norm, eps=eps, window=window,
-            flash_chunks=flash_chunks, softmax_scale=softmax_scale))
+            flash_chunks=flash_chunks, softmax_scale=softmax_scale,
+            rope_dim=rope_dim, sink=sink, value_scale=value_scale))
 
     def latent_attention(self, input: Tensor, embed_dim: int, num_heads: int,
                          q_lora_rank: Optional[int], kv_lora_rank: int,

@@ -62,13 +62,46 @@ def exaone_moe_lm(ff: FFModel, batch_size: int, seq_len: int = 4096,
     zero): loaded weights ignore it."""
     layer_types = list(layer_types or
                        [PATTERN[i % len(PATTERN)] for i in range(layers)])
-    mlp_layer_types = list(mlp_layer_types or
-                           ["dense"] + ["sparse"] * (layers - 1))
-    sliding_windows = list(sliding_windows or [
-        sliding_window if t == "sliding_attention" else 0
-        for t in layer_types])
-    if not len(layer_types) == len(mlp_layer_types) \
-            == len(sliding_windows) == layers:
+    attention = dict(
+        kdim=heads * head_dim, vdim=heads * head_dim, num_kv_heads=kv_heads,
+        rope_theta=rope_theta, qk_norm="head")
+    return window_global_lm(
+        ff, batch_size, seq_len, hidden, heads, layer_types,
+        sliding_windows or [sliding_window if t == "sliding_attention" else 0
+                            for t in layer_types],
+        mlp_layer_types or ["dense"] + ["sparse"] * (layers - 1),
+        attention={"sliding_attention": dict(attention, rope=True),
+                   "full_attention": dict(attention, rope=False)},
+        ffn_hidden=ffn_hidden,
+        moe=dict(num_experts=num_experts, hidden_dim=expert_hidden,
+                 k=experts_per_token, renormalize=norm_topk_prob,
+                 score_bias=score_bias_std, routed_scaling=routed_scaling,
+                 shared_hidden_dim=shared_experts * expert_hidden,
+                 experts_held=experts_held),
+        vocab_size=vocab_size, rms_norm_eps=rms_norm_eps,
+        flash_chunks=flash_chunks)
+
+
+def window_global_lm(ff: FFModel, batch_size: int, seq_len: int, hidden: int,
+                     heads: int, layer_types: Sequence[str],
+                     sliding_windows: Sequence[int],
+                     mlp_layer_types: Sequence[str], attention: dict,
+                     ffn_hidden: int, moe: dict, vocab_size: int,
+                     rms_norm_eps: float = 1e-5, flash_chunks: bool = True):
+    """The walk this family of decoders shares (K-EXAONE above,
+    models/mimo_v2.py): pre-norm blocks whose attention is by
+    `layer_types[i]` a window layer (`attn_window_{i}`, the last
+    `sliding_windows[i]` keys) or a global one (`attn_global_{i}`), each
+    built with `attention[kind]`, the arguments of `ff.multihead_attention`
+    that depend on the layer's kind (KV heads, key and value widths, rotary
+    and its base, a QK norm, a sink, a value scale); a dense SwiGLU
+    (`ffn_*_{i}`) where `mlp_layer_types[i]` is "dense", else sigmoid-routed
+    dropless SwiGLU experts `moe_{i}` built with `moe`; a final norm and an
+    untied head."""
+    layer_types, mlp_layer_types = list(layer_types), list(mlp_layer_types)
+    sliding_windows = list(sliding_windows)
+    layers = len(layer_types)
+    if not len(mlp_layer_types) == len(sliding_windows) == layers:
         raise ValueError(
             f"layer_types, mlp_layer_types and sliding_windows must each "
             f"name {layers} layers")
@@ -83,24 +116,17 @@ def exaone_moe_lm(ff: FFModel, batch_size: int, seq_len: int = 4096,
                 f"layer {i}: layer_types {kind!r} with window {window}")
         a = ff.rms_norm(t, eps=rms_norm_eps, name=f"ln1_{i}")
         a = ff.multihead_attention(
-            a, a, a, hidden, heads, kdim=heads * head_dim,
-            vdim=heads * head_dim, causal=True, bias=False,
-            num_kv_heads=kv_heads, rope=bool(window), rope_theta=rope_theta,
-            qk_norm="head", eps=rms_norm_eps, window=int(window),
-            flash_chunks=flash_chunks,
-            name=f"attn_window_{i}" if window else f"attn_global_{i}")
+            a, a, a, hidden, heads, causal=True, bias=False,
+            eps=rms_norm_eps, window=int(window), flash_chunks=flash_chunks,
+            name=f"attn_window_{i}" if window else f"attn_global_{i}",
+            **attention[kind])
         t = ff.add(t, a, name=f"res1_{i}")
         m = ff.rms_norm(t, eps=rms_norm_eps, name=f"ln2_{i}")
         if mlp == "dense":
             f = swiglu(ff, m, hidden, ffn_hidden, i)
         else:
-            f = ff.moe(m, num_experts=num_experts, hidden_dim=expert_hidden,
-                       k=experts_per_token, capacity_factor=None,
-                       expert="swiglu", renormalize=norm_topk_prob,
-                       scoring="sigmoid", score_bias=score_bias_std,
-                       routed_scaling=routed_scaling,
-                       shared_hidden_dim=shared_experts * expert_hidden,
-                       experts_held=experts_held, name=f"moe_{i}")
+            f = ff.moe(m, capacity_factor=None, expert="swiglu",
+                       scoring="sigmoid", name=f"moe_{i}", **moe)
         t = ff.add(t, f, name=f"res2_{i}")
     t = ff.rms_norm(t, eps=rms_norm_eps, name="ln_f")
     logits = ff.dense(t, vocab_size, use_bias=False, name="lm_head")

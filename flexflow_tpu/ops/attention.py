@@ -178,8 +178,10 @@ def flash_eligible(config, causal: bool, sq: int, sk: int) -> bool:
     return True
 
 
-def _apply_rope(x, theta: float, offset=0):
+def _apply_rope(x, theta: float, offset=0, rope_dim: int = 0):
     """Rotary position embedding (rotate-half convention) on (B,S,H,Dh).
+    `rope_dim` > 0 turns the first `rope_dim` entries of a head only
+    (rotate-half within them) and leaves the rest as they are.
     Angles are computed from absolute positions in f32 and the rotation is
     applied in f32 regardless of compute dtype (bf16 angles at position
     ~1000+ would lose the low-order bits that distinguish neighbors).
@@ -187,6 +189,10 @@ def _apply_rope(x, theta: float, offset=0):
     rotates a new token at its true position. Scalar (python int or
     traced) applies to every row; a (B,) array gives per-row offsets
     (ragged right-padded prompts)."""
+    if rope_dim and rope_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [_apply_rope(x[..., :rope_dim], theta, offset),
+             x[..., rope_dim:]], axis=-1)
     s, d = x.shape[1], x.shape[-1]
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -232,7 +238,8 @@ class MultiHeadAttention(Op):
                  num_kv_heads: int = 0, rope: bool = False,
                  rope_theta: float = 10000.0, qk_norm=False,
                  eps: float = 1e-6, window: int = 0,
-                 flash_chunks: bool = False, softmax_scale=None):
+                 flash_chunks: bool = False, softmax_scale=None,
+                 rope_dim: int = 0, sink=None, value_scale: float = 1.0):
         super().__init__(model, name, inputs)
         if add_bias_kv or add_zero_attn:
             raise NotImplementedError(
@@ -303,8 +310,23 @@ class MultiHeadAttention(Op):
         self.softmax_scale = (1.0 / math.sqrt(self.qk_head_dim)
                               if softmax_scale is None
                               else float(softmax_scale))
+        # rotary over the first `rope_dim` entries of a head (0 = all of
+        # them): a partial rotary factor
+        self.rope_dim = int(rope_dim)
         if rope:
-            assert self.qk_head_dim % 2 == 0, "RoPE needs an even head dim"
+            assert (self.rope_dim or self.qk_head_dim) % 2 == 0 \
+                and self.rope_dim <= self.qk_head_dim, \
+                "RoPE needs an even number of a head's entries"
+        # a SINK: a learned logit a query head that joins the softmax's
+        # denominator and adds no value, p_ij = exp(s_ij) / (exp(b_h) +
+        # sum_j' exp(s_ij')), in every path that takes a softmax (`_softmax`,
+        # the flash forward, the paged kernel). The argument is the standard
+        # deviation of the seeded draw (a checkpoint's values replace it);
+        # None = no sink and no weight, and every kernel is the one it was.
+        self.sink = None if sink is None else float(sink)
+        # v = value_scale * x Wv, applied where v is projected, so a cache
+        # holds the scaled value and no attention path knows of it
+        self.value_scale = float(value_scale)
         self.q_in = inputs[0].dims[-1]
         self.k_in = inputs[1].dims[-1]
         self.v_in = inputs[2].dims[-1]
@@ -335,6 +357,9 @@ class MultiHeadAttention(Op):
             ws += [WeightSpec("q_norm", (self.kdim,), init="one"),
                    WeightSpec("k_norm", (kvh * self.qk_head_dim,),
                               init="one")]
+        if self.sink is not None:
+            ws.append(WeightSpec("sink", (self.num_heads,), init="normal",
+                                 init_args=(0.0, self.sink)))
         if self.bias:
             ws += [WeightSpec("bias_q", (self.num_heads, self.qk_head_dim), init="zero"),
                    WeightSpec("bias_k", (kvh, self.qk_head_dim), init="zero"),
@@ -360,10 +385,31 @@ class MultiHeadAttention(Op):
             elif self.qk_norm:
                 qh = self._whole_rms_norm(qh, params["q_norm"])
                 kh = self._whole_rms_norm(kh, params["k_norm"])
+            if self.value_scale != 1.0:
+                vh = vh * jnp.asarray(self.value_scale, vh.dtype)
             if self.rope:
-                qh = _apply_rope(qh, self.rope_theta, rope_offset)
-                kh = _apply_rope(kh, self.rope_theta, rope_offset)
+                qh = _apply_rope(qh, self.rope_theta, rope_offset,
+                                 self.rope_dim)
+                kh = _apply_rope(kh, self.rope_theta, rope_offset,
+                                 self.rope_dim)
         return qh, kh, vh
+
+    def _sink_of(self, params):
+        """The (H,) sink logits in float32, or None for a layer without."""
+        return (None if self.sink is None
+                else params["sink"].astype(jnp.float32))
+
+    @staticmethod
+    def _softmax(logits, sink=None):
+        """Softmax over the last axis of f32 `logits`; `sink` (broadcastable
+        to logits[..., :1]) joins the denominator as one more logit whose
+        probability is dropped."""
+        if sink is None:
+            return jax.nn.softmax(logits, axis=-1)
+        sink = jnp.broadcast_to(sink, logits.shape[:-1] + (1,))
+        return jax.nn.softmax(
+            jnp.concatenate([logits, sink.astype(logits.dtype)], axis=-1),
+            axis=-1)[..., :-1]
 
     def _whole_rms_norm(self, xh, scale):
         """RMSNorm of a (B, S, H, Hd) projection over all H*Hd entries of
@@ -429,12 +475,17 @@ class MultiHeadAttention(Op):
                 raise NotImplementedError(
                     f"{self.name}: ring / Ulysses attention has no window; "
                     "a window layer's sequence dim cannot be sharded")
+            if self.sink is not None:
+                raise NotImplementedError(
+                    f"{self.name}: ring / Ulysses attention has no sink; a "
+                    "layer with one cannot shard its sequence dim")
             with jax.named_scope("core"):
                 ctx = self._sp_attention(qh, kh, vh, shard_ctx, seq_axes,
                                          scale, training, rng)
         else:
             ctx = self._dense_attention(qh, kh, vh, scale, training, rng,
-                                        shard_ctx)
+                                        shard_ctx,
+                                        sink=self._sink_of(params))
         return [self._out_proj(params, ctx)]
 
     # ---- KV-cache inference path (runtime/generation.py) -------------------
@@ -468,13 +519,15 @@ class MultiHeadAttention(Op):
             }
         kh, vh = self._broadcast_kv(kh, vh)
         scale = self.softmax_scale
-        ctx = self._dense_attention(qh, kh, vh, scale, False, None, None)
+        ctx = self._dense_attention(qh, kh, vh, scale, False, None, None,
+                                    sink=self._sink_of(params))
         return self._out_proj(params, ctx), new_cache
 
-    def _grouped_cache_attention(self, qh, ck, cv, live):
+    def _grouped_cache_attention(self, qh, ck, cv, live, sink=None):
         """Shared cache-attention body for the decode and chunked-prefill
         paths: q (B, C, H, Dh) against cached k/v (B, L, KVH, Dh) with a
-        `live` mask broadcastable to (B, KVH, G, C, L). The GQA grouping
+        `live` mask broadcastable to (B, KVH, G, C, L) and the layer's
+        `sink` logits ((H,) f32 or None). The GQA grouping
         reshapes q to (KVH, G) groups — consecutive query heads share a
         kv head, matching _broadcast_kv's jnp.repeat layout — so the
         broadcast is never materialized. f32 scores/softmax."""
@@ -487,7 +540,9 @@ class MultiHeadAttention(Op):
             logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck.astype(qh.dtype),
                                 preferred_element_type=jnp.float32) * scale
             logits = jnp.where(live, logits, jnp.finfo(jnp.float32).min)
-            probs = jax.nn.softmax(logits, axis=-1).astype(qh.dtype)
+            if sink is not None:
+                sink = sink.reshape(1, kvh, grp, 1, 1)
+            probs = self._softmax(logits, sink).astype(qh.dtype)
             ctx = jnp.einsum("bkgqs,bskd->bqkgd", probs,
                              cv.astype(qh.dtype))
             return ctx.reshape(b, c, self.num_heads, self.v_head_dim)
@@ -525,7 +580,8 @@ class MultiHeadAttention(Op):
             live = self._sees((start + jnp.arange(c))[:, None],
                               jnp.arange(ck.shape[1])[None, :])
             ctx = self._grouped_cache_attention(
-                qh, ck, cv, live[None, None, None, :, :])
+                qh, ck, cv, live[None, None, None, :, :],
+                sink=self._sink_of(params))
             return self._out_proj(params, ctx), {"k": ck, "v": cv}
         end = start + c  # python ints: a static slice of the live prefix
         lo = 0
@@ -540,13 +596,14 @@ class MultiHeadAttention(Op):
                 getattr(self.model, "config", None), True, c, end - lo):
             kb, vb = self._broadcast_kv(ks.astype(qh.dtype),
                                         vs.astype(qh.dtype))
-            ctx = self._flash_dense(qh, kb, vb,
-                                    self.softmax_scale, None)
+            ctx = self._flash_dense(qh, kb, vb, self.softmax_scale, None,
+                                    sink=self._sink_of(params))
         else:
             live = self._sees((start + jnp.arange(c))[:, None],
                               jnp.arange(lo, end)[None, :])
             ctx = self._grouped_cache_attention(
-                qh, ks, vs, live[None, None, None, :, :])
+                qh, ks, vs, live[None, None, None, :, :],
+                sink=self._sink_of(params))
         return self._out_proj(params, ctx), {"k": ck, "v": cv}
 
     def encode_kv(self, params, enc):
@@ -564,7 +621,8 @@ class MultiHeadAttention(Op):
         step. Non-causal: every query attends the whole source."""
         qh, _, _ = self._project_qkv(params, xs[0], xs[0], xs[0])
         live = jnp.ones((1, 1, 1, 1, kv["k"].shape[1]), bool)
-        ctx = self._grouped_cache_attention(qh, kv["k"], kv["v"], live)
+        ctx = self._grouped_cache_attention(qh, kv["k"], kv["v"], live,
+                                            sink=self._sink_of(params))
         return self._out_proj(params, ctx)
 
     def query_forward(self, params, xs, cache, rope_pos, row_lengths):
@@ -582,7 +640,8 @@ class MultiHeadAttention(Op):
         if self.window:
             live = live & self._sees(rope_pos[:, None], idx[None, :])
         ctx = self._grouped_cache_attention(
-            qh, cache["k"], cache["v"], live[:, None, None, None, :])
+            qh, cache["k"], cache["v"], live[:, None, None, None, :],
+            sink=self._sink_of(params))
         return self._out_proj(params, ctx), cache
 
     def decode_forward(self, params, xs, cache, pos, rope_pos=None,
@@ -618,7 +677,8 @@ class MultiHeadAttention(Op):
                                + row_lengths[:, None])
                 live = live & self._sees(rope_pos[:, None], at)
         ctx = self._grouped_cache_attention(
-            qh, ck, cv, live[:, None, None, None, :])
+            qh, ck, cv, live[:, None, None, None, :],
+            sink=self._sink_of(params))
         return self._out_proj(params, ctx), {"k": ck, "v": cv}
 
     # ---- paged KV cache (runtime/serving.py) ------------------------------
@@ -647,10 +707,15 @@ class MultiHeadAttention(Op):
         # heads narrower than the 128 lanes: `pack` neighbouring KV heads
         # share a row (`pool_pack`); at pack 1 the rows are (KVH, D)
         pack = self.pool_pack(quantized=qmax is not None)
+        rows = (self.num_kv_heads // pack,)
+        if self._flat_pack(pack):
+            # ONE row a token: no row dim at all (a dim of one before the
+            # lanes is stored padded to two sublanes)
+            rows = ()
         pool = {
-            "k": jnp.zeros((num_pages, page_size, self.num_kv_heads // pack,
+            "k": jnp.zeros((num_pages, page_size, *rows,
                             self.qk_head_dim * pack), store),
-            "v": jnp.zeros((num_pages, page_size, self.num_kv_heads // pack,
+            "v": jnp.zeros((num_pages, page_size, *rows,
                             self.v_head_dim * pack), store),
         }
         if qmax is not None:
@@ -672,13 +737,33 @@ class MultiHeadAttention(Op):
         scattered to it is reshaped at the boundary (`_pool_rows`,
         `_pool_heads`) and the pool itself never is. 1 (the shape every
         head of 128 has) for a quantized pool, whose scales are per KV
-        head, and for a window layer's ring."""
-        d = self.qk_head_dim
-        if quantized or self.window or d != self.v_head_dim \
-                or d >= 128 or 128 % d:
+        head, and for a window layer's ring.
+
+        A head whose width the lanes do not divide (keys of 192 beside
+        values of 128) has the same trouble and one more: Mosaic refuses to
+        copy a 192-lane slice of a page. Its pool is FLAT: pack = KVH, one
+        row a token that holds every KV head side by side, (pages,
+        page_size, KVH x D) with KVH x D whole lane tiles, for keys and
+        values alike and for a window layer's ring too; the paged kernel
+        then contracts a token's whole row against a query row that is zero
+        outside its own head's lanes (`paged_attention_fwd_pallas`,
+        `kv_heads`)."""
+        d, dv, kvh = self.qk_head_dim, self.v_head_dim, self.num_kv_heads
+        if quantized:
+            return 1
+        if (d % 128 or dv % 128) and d >= 128 and kvh > 1 \
+                and not (kvh * d) % 128 and not (kvh * dv) % 128:
+            return kvh
+        if self.window or d != dv or d >= 128 or 128 % d:
             return 1
         pack = 128 // d
-        return pack if self.num_kv_heads % pack == 0 else 1
+        return pack if kvh % pack == 0 else 1
+
+    def _flat_pack(self, pack: int) -> bool:
+        """Whether `pool_pack`'s answer is the flat layout (not the narrow
+        heads' rows of 128 lanes)."""
+        return pack > 1 and pack == self.num_kv_heads \
+            and self.qk_head_dim >= 128
 
     @staticmethod
     def _pool_rows(x, pool):
@@ -686,9 +771,11 @@ class MultiHeadAttention(Op):
         return x.reshape(*x.shape[:-2], *pool.shape[2:])
 
     def _pool_heads(self, x, d):
-        """What was read from a pool (.., KVH / pack, pack x D) as
-        (.., KVH, D)."""
-        return x.reshape(*x.shape[:-2], self.num_kv_heads, d)
+        """What was read from a pool (.., KVH / pack, pack x D), or from a
+        flat one (.., KVH x D), as (.., KVH, D)."""
+        lead = x.shape[:-1] if self._flat_pack(self.pool_pack()) \
+            else x.shape[:-2]
+        return x.reshape(*lead, self.num_kv_heads, d)
 
     def paged_prefill_write(self, cache, kh, vh, pages, impl="einsum"):
         """Scatter a slot's contiguous prefill k/v (1, L, KVH, Dh) into
@@ -808,8 +895,8 @@ class MultiHeadAttention(Op):
         sees, for the engine's counters."""
         from flexflow_tpu.ops.pallas_kernels import paged_turn_pages
 
-        ps, rows = cache["k"].shape[1:3]
-        return paged_turn_pages(ps, rows, width)
+        ps, *rows, lanes = cache["k"].shape[1:]
+        return paged_turn_pages(ps, rows[0] if rows else 1, width, lanes)
 
     def export_page(self, cache, page):
         """Slice pool page(s) out as the serializable migration payload
@@ -864,7 +951,7 @@ class MultiHeadAttention(Op):
         return out
 
     def _paged_attention_ctx(self, qh, cache, page_table, write_pos,
-                             row_len, prompt_pad, impl):
+                             row_len, prompt_pad, impl, sink=None):
         """Shared attention body of the paged decode/verify paths: q
         (B, S, H, Dh) against the updated pool through the per-slot page
         tables, write_pos (B, S) per-position frontiers. Two impls
@@ -900,7 +987,8 @@ class MultiHeadAttention(Op):
             return paged_attention_fwd_pallas(
                 qh, ck, cv, page_table, write_pos, row_len, prompt_pad,
                 scale, k_scales=cache.get("k_scale"),
-                v_scales=cache.get("v_scale"))
+                v_scales=cache.get("v_scale"), sink=sink,
+                kv_heads=self.num_kv_heads)
         b = qh.shape[0]
         max_len = page_table.shape[1] * ck.shape[1]
         with jax.named_scope("gather"):
@@ -918,7 +1006,7 @@ class MultiHeadAttention(Op):
                 | ((idx[None, None, :] >= prompt_pad[:, None, None])
                    & (idx[None, None, :] <= write_pos[:, :, None]))
         return self._grouped_cache_attention(
-            qh, gk, gv, live[:, None, None, :, :])
+            qh, gk, gv, live[:, None, None, :, :], sink=sink)
 
     def paged_decode_forward(self, params, xs, cache, page_table, write_pos,
                              rope_pos, row_len, prompt_pad, impl=None):
@@ -956,7 +1044,8 @@ class MultiHeadAttention(Op):
                                        offs)
         ctx = self._paged_attention_ctx(qh, cache, page_table,
                                         write_pos[:, None], row_len,
-                                        prompt_pad, impl)
+                                        prompt_pad, impl,
+                                        sink=self._sink_of(params))
         return self._out_proj(params, ctx), cache
 
     def _paged_window_decode(self, params, qh, kh, vh, cache, ring, pos,
@@ -983,7 +1072,8 @@ class MultiHeadAttention(Op):
                 qh, cache["k"], cache["v"], ring, pos[:, None], zero, zero,
                 self.softmax_scale,
                 k_scales=cache.get("k_scale"), v_scales=cache.get("v_scale"),
-                window=self.window)
+                window=self.window, sink=self._sink_of(params),
+                kv_heads=self.num_kv_heads)
             return self._out_proj(params, ctx), cache
         b = qh.shape[0]
         with jax.named_scope("gather"):
@@ -991,6 +1081,8 @@ class MultiHeadAttention(Op):
             if "k_scale" in cache:
                 gk = page_dequantize(gk, cache["k_scale"][ring])
                 gv = page_dequantize(gv, cache["v_scale"][ring])
+            gk = self._pool_heads(gk, self.qk_head_dim)
+            gv = self._pool_heads(gv, self.v_head_dim)
             gk = gk.reshape(b, r * page_size, *gk.shape[3:])
             gv = gv.reshape(b, r * page_size, *gv.shape[3:])
         with jax.named_scope("core"):
@@ -1002,7 +1094,8 @@ class MultiHeadAttention(Op):
                   + jnp.arange(page_size)[None, None, :]).reshape(b, -1)
             live = (at >= 0) & self._sees(pos[:, None], at)
         ctx = self._grouped_cache_attention(
-            qh, gk, gv, live[:, None, None, None, :])
+            qh, gk, gv, live[:, None, None, None, :],
+            sink=self._sink_of(params))
         return self._out_proj(params, ctx), cache
 
     def scatter_window_tail(self, cache, contiguous, length, ring,
@@ -1012,25 +1105,76 @@ class MultiHeadAttention(Op):
         `length` (1,) positions, each into its column of the slot's `ring`
         (R,). The rest of the prompt's keys travelled in the program and
         are dropped with it."""
-        page_size, r = cache["k"].shape[1], ring.shape[0]
+        r = ring.shape[0]
+        return self._write_last_pages(
+            cache, contiguous, length, r,
+            lambda back, t: jax.lax.dynamic_slice_in_dim(ring, t % r, 1),
+            impl)
+
+    def _write_last_pages(self, cache, contiguous, length, count, page_of,
+                          impl):
+        """The `count` pages of the contiguous cache that end at the page of
+        position `length` - 1 (as far as the cache has them), each written
+        to pool page `page_of(back, t)` ((1,) int32; `back` pages before the
+        last, logical page `t`) of `cache`. A page before the sequence's
+        first is the first again: the same rows to wherever `page_of` says."""
+        page_size = cache["k"].shape[1]
         k, v = contiguous["k"], contiguous["v"]
         pad = -k.shape[1] % page_size
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         last = (length[0] - 1) // page_size
-        for back in range(min(r, k.shape[1] // page_size)):
-            # a page before the sequence's first is the first again: the
-            # same rows into the same column
+        for back in range(min(count, k.shape[1] // page_size)):
             t = jnp.maximum(last - back, 0)
             with jax.named_scope("project"):
                 ks = jax.lax.dynamic_slice_in_dim(k, t * page_size,
                                                   page_size, axis=1)
                 vs = jax.lax.dynamic_slice_in_dim(v, t * page_size,
                                                   page_size, axis=1)
-                page = jax.lax.dynamic_slice_in_dim(ring, t % r, 1)
+                page = page_of(back, t)
             cache = self.paged_prefill_write(cache, ks, vs, page, impl=impl)
         return cache
+
+    def window_snapshot_pages(self, page_size: int) -> int:
+        """Pages of `page_size` positions that hold the window before a
+        page-aligned position: what a snapshot of this layer is."""
+        return -(-self.window // page_size)
+
+    def take_window_snapshot(self, snaps, contiguous, length, snap,
+                             impl="einsum"):
+        """A window layer's SNAPSHOT at the end of a prefill of `length`
+        (1,) positions: the pages of the contiguous cache that end at the
+        prompt's last page, into pages [snap * n, (snap + 1) * n) of the
+        snapshot arrays `snaps` (the pool's own page format, n =
+        `window_snapshot_pages`). Where `length` is a whole number of pages
+        that is the window before position `length`, all a later request
+        that matches the prompt needs of this layer; elsewhere `snap` is the
+        scratch row 0 and nothing reads it."""
+        n = self.window_snapshot_pages(snaps["k"].shape[1])
+        return self._write_last_pages(
+            snaps, contiguous, length, n,
+            lambda back, t: jnp.reshape(snap * n + (n - 1 - back),
+                                        (1,)).astype(jnp.int32), impl)
+
+    def seed_window_cache(self, contiguous, snaps, snap, p0: int):
+        """`take_window_snapshot`'s inverse for a prefix hit at the
+        page-aligned match point `p0` (static): the pages of snapshot `snap`
+        at positions [p0 - n * page_size, p0) of the contiguous cache, whose
+        other rows stay zeros (no tail position's window reaches them)."""
+        page_size = snaps["k"].shape[1]
+        n = self.window_snapshot_pages(page_size)
+        out = dict(contiguous)
+        for back in range(n):
+            t = max(p0 // page_size - 1 - back, 0)
+            got = self.gather_paged_kv(
+                snaps, jnp.reshape(snap * n + (n - 1 - back),
+                                   (1,)).astype(jnp.int32))
+            for name in ("k", "v"):
+                out[name] = jax.lax.dynamic_update_slice_in_dim(
+                    out[name], got[name].astype(out[name].dtype),
+                    t * page_size, axis=1)
+        return out
 
     def paged_verify_forward(self, params, xs, cache, page_table, write_pos,
                              rope_pos0, row_len, prompt_pad, impl=None):
@@ -1090,7 +1234,8 @@ class MultiHeadAttention(Op):
                     self._pool_rows(vh, cache["v"]).astype(
                         cache["v"].dtype))
         ctx = self._paged_attention_ctx(qh, cache, page_table, write_pos,
-                                        row_len, prompt_pad, impl)
+                                        row_len, prompt_pad, impl,
+                                        sink=self._sink_of(params))
         return self._out_proj(params, ctx), cache
 
     def _flash_ok(self, qh, kh) -> bool:
@@ -1103,23 +1248,26 @@ class MultiHeadAttention(Op):
                               self.causal, qh.shape[1], kh.shape[1])
 
     def _dense_attention(self, qh, kh, vh, scale, training, rng,
-                         shard_ctx=None):
+                         shard_ctx=None, sink=None):
         use_dropout = training and self.dropout > 0.0 and rng is not None
-        # a window under a gradient is XLA's masked attention: the flash
-        # backward kernels carry no window
-        if not use_dropout and not (self.window and training) \
+        # a window or a sink under a gradient is XLA's masked attention: the
+        # flash backward kernels carry neither
+        if not use_dropout \
+                and not ((self.window or sink is not None) and training) \
+                and (sink is None or self.causal) \
                 and self._flash_ok(qh, kh):
-            return self._flash_dense(qh, kh, vh, scale, shard_ctx)
+            return self._flash_dense(qh, kh, vh, scale, shard_ctx, sink)
         with jax.named_scope("core"):
             return self._xla_attention(qh, kh, vh, scale, training, rng,
-                                       use_dropout)
+                                       use_dropout, sink)
 
-    def _xla_attention(self, qh, kh, vh, scale, training, rng, use_dropout):
+    def _xla_attention(self, qh, kh, vh, scale, training, rng, use_dropout,
+                       sink=None):
         """`_dense_attention` where flash is refused: blockwise past
         BLOCKWISE_SEQ_THRESHOLD, else the plain einsum."""
         sq, sk = qh.shape[1], kh.shape[1]
         if max(sq, sk) > BLOCKWISE_SEQ_THRESHOLD and not self.window \
-                and self.qk_head_dim == self.v_head_dim:
+                and sink is None and self.qk_head_dim == self.v_head_dim:
             # long-context dense fallback for flash-refused shapes (CPU
             # backend, dropout, causal with sq > sk): pure-JAX blockwise
             # online-softmax scan (O(block) working set) with rematerialized
@@ -1145,14 +1293,16 @@ class MultiHeadAttention(Op):
                 mask = self._sees((sk - sq + jnp.arange(sq))[:, None],
                                   jnp.arange(sk)[None, :])
             logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
-        probs = jax.nn.softmax(logits, axis=-1).astype(qh.dtype)
+        probs = self._softmax(
+            logits, None if sink is None else sink[None, :, None, None]
+        ).astype(qh.dtype)
         if training and self.dropout > 0.0 and rng is not None:
             keep = 1.0 - self.dropout
             probs = jnp.where(jax.random.bernoulli(rng, keep, probs.shape),
                               probs / keep, 0.0)
         return jnp.einsum("bhqs,bshk->bqhk", probs, vh)
 
-    def _flash_dense(self, qh, kh, vh, scale, shard_ctx):
+    def _flash_dense(self, qh, kh, vh, scale, shard_ctx, sink=None):
         """Dense flash with multi-chip awareness. A pallas_call is a Mosaic
         custom call the XLA SPMD partitioner cannot split: left inside the
         GSPMD-partitioned program it would be replicated (all-gathers around
@@ -1164,8 +1314,11 @@ class MultiHeadAttention(Op):
                                                      flash_attention_window)
 
         def flash(q, k, v):
-            if self.window:   # forward only: `_dense_attention` saw to it
-                return flash_attention_window(q, k, v, self.window, scale)
+            # forward only with a window or a sink: `_dense_attention` saw
+            # to it
+            if self.window or sink is not None:
+                return flash_attention_window(q, k, v, self.window or None,
+                                              scale, sink=sink)
             return flash_attention(q, k, v, self.causal, scale)
 
         mesh = (shard_ctx or {}).get("mesh")
@@ -1179,6 +1332,10 @@ class MultiHeadAttention(Op):
         ent = shard_entries(mesh, axis_map, qh.shape, (0, 2))
         if ent[0] is None and ent[2] is None:
             return flash(qh, kh, vh)
+        if sink is not None:
+            raise NotImplementedError(
+                f"{self.name}: the flash forward with a sink runs on one "
+                "device's heads; a sharded batch or head dim is not built")
 
         spec = P(ent[0], None, ent[2], None)
         return jax.shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),

@@ -245,10 +245,10 @@ def _across(x, width: int):
 # ---------------------------------------------------------------- forward
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
                       block_k: int, chunk: int, causal: bool, scale: float,
                       need_lse: bool, offset: int = 0,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None, sink: bool = False):
     """One (block_q, block_k) tile a grid step, `chunk` query rows at a
     time. The running max and sum live LANE-WIDE in their (block_q, 128)
     scratch: every lane of m holds the row's max, so it meets the logits'
@@ -256,11 +256,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
     statistic read from one lane costs a cross-lane permute a vector
     register wherever it meets a tile: PERF.md section 6, PR 33), and l
     holds one partial sum a lane (over the keys that fell on that lane), so
-    a step adds lane tiles and only `_finish` reduces across lanes."""
+    a step adds lane tiles and only `_finish` reduces across lanes.
+
+    With `sink` (static) one more input, the (B*H,) sink logits in SMEM: a
+    row's running max starts at its head's sink and its sum at exp(sink -
+    max) = 1, so the sink takes its share of the denominator and adds no
+    value; without it the kernel is the one it always was."""
+    sink_ref = None
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
+    o_ref, rest = rest[0], rest[1:]
     if need_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         m_scr, l_scr, acc_scr = rest
+    bh = pl.program_id(0)
     qi = pl.program_id(1)
     kt = ki = pl.program_id(2)      # the grid's step, and its key tile
     nk = pl.num_programs(2)
@@ -277,8 +287,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q: int,
 
     @pl.when(kt == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink_ref is None:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, sink_ref[bh])
+            # the sink's 1 in ONE lane's partial sum where the lanes are
+            # partial sums, in every lane where each holds the whole sum
+            lane = jax.lax.broadcasted_iota(jnp.int32, l_scr.shape, 1)
+            l_scr[...] = jnp.where(jnp.logical_or(lane == 0, not tiled),
+                                   1.0, 0.0).astype(l_scr.dtype)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def step(masked: bool):
@@ -333,7 +351,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
                                need_lse: bool = True,
-                               window: Optional[int] = None):
+                               window: Optional[int] = None, sink=None):
     """q, k: (B, S, H, d_qk), v: (B, S, H, d_v) -> (out (B*H, S_q, d_v),
     lse|None). The key and value widths are independent (latent attention
     has keys of 192 and values of 128): the logit contracts d_qk, the
@@ -348,7 +366,10 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     `window` (causal only): query i sees keys i - window < j <= i. A key
     tile wholly below the window has no grid step, the tile the lower edge
     crosses is masked like the diagonal's, the rest run as they do without
-    one; with None the call is the one it always was."""
+    one; with None the call is the one it always was.
+
+    `sink` ((H,) f32, forward only): a logit a head that joins every row's
+    softmax denominator and adds no value."""
     sq, sk = q.shape[1], k.shape[1]
     if window is not None:
         assert causal, "a window is a causal layer's"
@@ -364,7 +385,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
     # sq > sk with causal would leave the first rows keyless (0/0 in the
     # online softmax) — refused upstream in attention.flash_eligible
     assert not (causal and sk < sq), "causal flash needs sq <= sk"
-    return _flash_fwd_call(q, k, v, causal=causal, scale=float(scale),
+    return _flash_fwd_call(q, k, v, sink, causal=causal, scale=float(scale),
                            block_q=block_q, block_k=block_k,
                            need_lse=need_lse, interpret=_interpret(),
                            window=window)
@@ -380,8 +401,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "causal", "scale", "block_q", "block_k", "need_lse", "interpret",
     "window"))
-def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
-                    interpret, window=None):
+def _flash_fwd_call(q, k, v, sink=None, *, causal, scale, block_q, block_k,
+                    need_lse, interpret, window=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[3]
     # cross-attention diagonal offset (bottom-right aligned causality)
@@ -398,7 +419,7 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
                                block_k=block_k, chunk=_chunk_rows(block_q),
                                causal=causal, scale=scale,
                                need_lse=need_lse, offset=offset,
-                               window=window)
+                               window=window, sink=sink is not None)
     nk = sk // block_k
     if window is not None:
         # the grid's key axis starts at the first tile the window reaches
@@ -425,15 +446,21 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
         out_specs.append(pl.BlockSpec((1, block_q, 8),
                                       lambda i, j, t: (i, j, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b * h, sq, 8), jnp.float32))
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, dv), kv_map),
+    ]
+    operands = [qt, kt, vt]
+    if sink is not None:
+        # one logit a (batch, head) grid row, read as a scalar
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(jnp.tile(sink.astype(jnp.float32), b))
     with jax.named_scope("core"):
         outs = pl.pallas_call(
             kernel,
             grid=(b * h, sq // block_q, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), kv_map),
-                pl.BlockSpec((1, block_k, dv), kv_map),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[
@@ -443,7 +470,7 @@ def _flash_fwd_call(q, k, v, *, causal, scale, block_q, block_k, need_lse,
             ],
             compiler_params=_compiler_params(),
             interpret=interpret, name="flash_attention_fwd",
-        )(qt, kt, vt)
+        )(*operands)
     return (outs[0], outs[1]) if need_lse else (outs[0], None)
 
 
@@ -814,15 +841,16 @@ def flash_attention(q, k, v, causal: bool = False,
         return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
 
 
-def flash_attention_window(q, k, v, window: int,
-                           scale: Optional[float] = None):
+def flash_attention_window(q, k, v, window: Optional[int],
+                           scale: Optional[float] = None, sink=None):
     """The causal flash forward of a window layer (query i sees keys
-    i - window < j <= i, bottom-right aligned where sq < sk): the forward
-    kernel alone, no VJP. A window under a gradient takes XLA's masked
-    attention (ops/attention.py), not this."""
+    i - window < j <= i, bottom-right aligned where sq < sk; None: every
+    earlier key) and of a layer with a `sink` ((H,) logits in the softmax's
+    denominator): the forward kernel alone, no VJP. A window or a sink under
+    a gradient takes XLA's masked attention (ops/attention.py), not this."""
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = flash_attention_fwd_pallas(q, k, v, True, s, need_lse=False,
-                                        window=window)
+                                        window=window, sink=sink)
     b, sq, h, _ = q.shape
     with jax.named_scope("out"):
         return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
@@ -927,12 +955,16 @@ _PAGED_TURN_COLS = 1024
 _INDEX_BLOCK_PAGES = 8
 
 
-def paged_turn_pages(ps: int, rows: int, width: int) -> int:
+def paged_turn_pages(ps: int, rows: int, width: int,
+                     lanes: int = LANES) -> int:
     """Pages one turn of the paged kernel takes, from the shapes the call
     sees: the smallest power of two whose block has `_PAGED_TURN_COLS`
     columns (a page holds ps tokens x `rows` rows a token), at most the index
     kernel's block and the table's `width`; 1 from that many columns a page
-    up."""
+    up. A FLAT pool (one row a token, `lanes` wide: every KV head side by
+    side) counts a row as its lane tiles."""
+    if rows == 1 and lanes > LANES:
+        rows = lanes // LANES
     g = 1
     while g * ps * rows < _PAGED_TURN_COLS \
             and 2 * g <= min(_INDEX_BLOCK_PAGES, width):
@@ -1037,7 +1069,8 @@ def _page_stream(pt_ref, last_ref, pools, bufs, sem, cur, nbuf, g, *,
 def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
                        s: int, h: int, kvh: int, ps: int, nbuf: int, g: int,
                        scale: float, quantized: bool = False,
-                       window: Optional[int] = None, pack: int = 1):
+                       window: Optional[int] = None, pack: int = 1,
+                       sink: bool = False, flat: bool = False):
     """One slot per grid step: score the slot's (S*H, Dqk) query rows
     against its live pages, a turn of g pages at a time (the pages past the
     last whole block one at a time), and fold each turn into the running
@@ -1066,19 +1099,36 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     carries its head's entries in the lanes of ITS KV head and zeros in the
     others. A column is then a (token, row of `pack` KV heads), a query row
     owns the column its KV head lies in, and its zeros keep the neighbours
-    out of its scores; its context comes out in its own lanes of the 128."""
+    out of its scores; its context comes out in its own lanes of the 128.
+
+    `flat` (heads whose width the lanes do not divide: keys of 192,
+    ops/attention.py `pool_pack`): a token is ONE row of a page, all its KV
+    heads side by side (KVH x D lanes, whole lane tiles), and a query row
+    carries its head's entries in the lanes of its KV head and zeros in the
+    others, so the contraction over the whole row IS the row's own head's
+    logit: a column is a token, no column belongs to another head, and the
+    context comes out KVH x Dv wide with the row's own head's lanes picked
+    at the end.
+
+    With `sink` (static) one more input after the queries, the (S*H, 1)
+    sink logit of each query row: the running maximum starts there and the
+    denominator at exp(sink - max) = 1, so the sink takes its share and adds
+    no value; without it the kernel is the one it always was."""
     fp_ref = None
     if window is not None:
         fp_ref, rest = rest[0], rest[1:]
+    ks_ref = vs_ref = sink_ref = None
     if quantized:
-        ks_ref, vs_ref, q_ref, k_hbm, v_hbm, o_ref, \
-            k_buf, v_buf, sem, cur = rest
-    else:
-        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur = rest
+        ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
+    q_ref, rest = rest[0], rest[1:]
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur = rest
     b = pl.program_id(0)
     ring = pt_ref.shape[1] if window is not None else None
     grp = h // kvh
-    kvh = kvh // pack           # rows a token takes in a page
+    heads_kv = kvh
+    kvh = 1 if flat else kvh // pack    # rows a token takes in a page
     rows = s * h
     prime, take, fetch_next = _page_stream(
         pt_ref, lp_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem, cur, nbuf, g,
@@ -1099,6 +1149,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
 
     def columns(pages):
         col = jax.lax.broadcasted_iota(jnp.int32, (1, pages * ps * kvh), 1)
+        if flat:    # a column is a token: every one is the row's own head's
+            return True, col
         own_head = (row % h) // (grp * pack) == col % kvh   # (rows, cols)
         return own_head, col // kvh                         # tok (1, cols)
 
@@ -1160,6 +1212,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
              jnp.zeros((rows, v_buf.shape[-1]), jnp.float32))
+    if sink_ref is not None:
+        carry = (sink_ref[...], jnp.ones((rows, 1), jnp.float32), carry[2])
     if g > 1:
         block, blocks = turn(g), (lp_ref[b] + 1 - first) // g
         carry = jax.lax.fori_loop(
@@ -1174,13 +1228,23 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     # satisfy j == 0 <= write_pos == 0), so l > 0 — no guard. A turn in
     # which a row has NO live column leaves p = 1 everywhere while m is
     # still NEG_INF; the first live score then scales that away (alpha = 0)
-    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+    out = acc / l_fin
+    if flat:
+        # (rows, KVH x Dv): the row's own KV head's lane tiles
+        dv = out.shape[1] // heads_kv
+        own = (row % h) // grp
+        picked = out[:, 0:dv]
+        for i in range(1, heads_kv):
+            picked = jnp.where(own == i, out[:, i * dv:(i + 1) * dv], picked)
+        out = picked
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
                                row_len, prompt_pad, scale: float,
                                k_scales=None, v_scales=None,
-                               window: Optional[int] = None):
+                               window: Optional[int] = None, sink=None,
+                               kv_heads: Optional[int] = None):
     """Paged-pool attention: q (B, S, H, Dqk) against k_pages/v_pages
     ((P_pool, page_size, KVH, D)) through per-slot page tables
     ((B, pages_per_slot) int32) -> (B, S, H, Dv) context.
@@ -1214,14 +1278,37 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     frontier - window < j as well, the page table is the slot's RING
     (logical page t in column t % width: width pages hold any window of at
     most (width - 1) * page_size + 1 positions) and the slot's pages are
-    streamed from the window's first, not from page 0."""
+    streamed from the window's first, not from page 0.
+
+    ``sink`` ((H,) f32) marks a layer whose softmax carries a sink: a logit
+    a query head in the denominator, no value.
+
+    ``kv_heads`` marks a FLAT pool (ops/attention.py `pool_pack` == the KV
+    heads): k_pages (P_pool, page_size, KVH x Dqk), v_pages (.., KVH x Dv),
+    one row a token."""
     b, s, h, dqk = q.shape
+    flat = k_pages.ndim == 3
+    if flat:
+        # one row a token: seen as one "KV head" row of KVH x D lanes, the
+        # page a dense (ps, lanes) block whatever the turn
+        assert kv_heads and k_pages.shape[2] == kv_heads * dqk \
+            and v_pages.shape[2] % kv_heads == 0 and k_scales is None, \
+            (k_pages.shape, v_pages.shape, kv_heads)
+        k_pages, v_pages = k_pages[:, :, None], v_pages[:, :, None]
     ps, kvh = k_pages.shape[1], k_pages.shape[2]
     dv = v_pages.shape[3]
     # a pool whose rows hold `pack` neighbouring KV heads of `dqk` side by
     # side (ops/attention.py `pool_pack`: heads narrower than the lanes)
     pack, d0 = (dv // dqk if k_pages.shape[3] != dqk else 1), dqk
-    if pack > 1:
+    if flat:
+        pack, kvh, d0 = 1, kv_heads, dv // kv_heads
+        # query head i reads KV head i // grp: its entries go to that
+        # head's lanes of the row, zeros to the others
+        own = (jnp.arange(h) // (h // kvh))[:, None] == jnp.arange(kvh)
+        q = (q[:, :, :, None, :] * own[None, None, :, :, None]
+             .astype(q.dtype)).reshape(b, s, h, kvh * dqk)
+        dqk = kvh * dqk
+    elif pack > 1:
         assert k_pages.shape[3] == dv == LANES, (k_pages.shape, dqk)
         kvh = kvh * pack
         # query head i reads KV head i // grp: its entries go to that
@@ -1235,10 +1322,10 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     assert (k_scales is None) == (v_scales is None), \
         "quantized pools carry BOTH k and v scales"
     quantized = k_scales is not None
-    rows = kvh // pack          # rows a token takes in a page
-    g = paged_turn_pages(ps, rows, page_table.shape[1])
+    rows = 1 if flat else kvh // pack   # rows a token takes in a page
+    g = paged_turn_pages(ps, rows, page_table.shape[1], dqk)
     k_page, v_page = (ps, rows, dqk), (ps, rows, dv)
-    if g > 1 and not quantized:
+    if flat or (g > 1 and not quantized):
         # a block LANDS DENSE: the pools seen as (pages, ps x rows, D), the
         # same bytes in the same order (XLA makes the reshape a bitcast of
         # the tiled pool), so a turn's g pages are one (g x ps x rows, D)
@@ -1278,15 +1365,20 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
+    operands = [q.reshape(b, s * h, dqk)]
+    in_specs = [pl.BlockSpec((1, s * h, dqk), slot_map)]
+    if sink is not None:
+        # one logit a query row, the same block at every slot
+        operands.append(jnp.tile(sink.astype(jnp.float32), s)[:, None])
+        in_specs.append(pl.BlockSpec((s * h, 1), lambda bi, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, s * h, dqk), slot_map),
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, s * h, dv), slot_map),
+        out_specs=pl.BlockSpec((1, s * h, d0 if flat else dv), slot_map),
         scratch_shapes=[
             pltpu.VMEM((nbuf, g, *k_page), k_pages.dtype),
             pltpu.VMEM((nbuf, g, *v_page), v_pages.dtype),
@@ -1297,20 +1389,22 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
                           nbuf=nbuf, g=g, scale=scale, quantized=quantized,
-                          window=window, pack=pack),
+                          window=window, pack=pack, sink=sink is not None,
+                          flat=flat),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s * h, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, s * h, d0 if flat else dv), q.dtype),
         # sequential: the cursor and the ring carry over from slot to slot
         compiler_params=_compiler_params(("arbitrary",)),
         interpret=_interpret(),
-    )(*prefetch, q.reshape(b, s * h, dqk), k_pages, v_pages)
+    )(*prefetch, *operands, k_pages, v_pages)
     if pack > 1:
         # each head's context lies in the lanes of its KV head
         out = jnp.take_along_axis(
             out.reshape(b, s, h, pack, d0),
             lane_of[None, None, :, None, None], axis=3)
         return out.reshape(b, s, h, d0)
-    return out.reshape(b, s, h, dv)
+    return out.reshape(b, s, h, d0 if flat else dv)
 
 
 def paged_prefill_write_pallas(cache, kh, vh, pages):
@@ -1345,13 +1439,16 @@ def paged_prefill_write_pallas(cache, kh, vh, pages):
                                             storage_qmax)
 
     pool_k, pool_v = cache["k"], cache["v"]
-    ps, kvh = pool_k.shape[1], pool_k.shape[2]
-    dk, dv = pool_k.shape[3], pool_v.shape[3]
+    ps, kvh = pool_k.shape[1], pool_k.shape[2]     # kvh: a quantized pool's
+    # a page's trailing dims: (rows a token, lanes), or of a flat pool the
+    # lanes alone
+    tail_k, tail_v = pool_k.shape[2:], pool_v.shape[2:]
+    zeros = (0,) * len(tail_k)
     n_pages = len(pages)
     quantized = "k_scale" in cache
     qmax = storage_qmax(pool_k.dtype) if quantized else 0.0
 
-    def paged(x, d):
+    def paged(x, tail):
         # identical host-side prep to the einsum oracle: pad the slab
         # tail to a page boundary, reshape to page-major tiles
         s = x.shape[1]
@@ -1359,17 +1456,17 @@ def paged_prefill_write_pallas(cache, kh, vh, pages):
         x = x[0]
         if pad:
             x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
-        return x.reshape(n_pages, ps, kvh, d)
+        return x.reshape(n_pages, ps, *tail)
 
-    kp = paged(kh, dk)
-    vp = paged(vh, dv)
+    kp = paged(kh, tail_k)
+    vp = paged(vh, tail_v)
     pages = jnp.asarray(pages, jnp.int32)
 
     def slab_map(t, pages_ref):
-        return (t, 0, 0, 0)
+        return (t, 0, *zeros)
 
     def pool_map(t, pages_ref):
-        return (pages_ref[t], 0, 0, 0)
+        return (pages_ref[t], 0, *zeros)
 
     def scale_map(t, pages_ref):
         return (pages_ref[t], 0, 0)
@@ -1390,14 +1487,14 @@ def paged_prefill_write_pallas(cache, kh, vh, pages):
             pv_out[...] = vp_ref[...].astype(pv_out.dtype)
 
     in_specs = [
-        pl.BlockSpec((1, ps, kvh, dk), slab_map),
-        pl.BlockSpec((1, ps, kvh, dv), slab_map),
-        pl.BlockSpec((1, ps, kvh, dk), pool_map),
-        pl.BlockSpec((1, ps, kvh, dv), pool_map),
+        pl.BlockSpec((1, ps, *tail_k), slab_map),
+        pl.BlockSpec((1, ps, *tail_v), slab_map),
+        pl.BlockSpec((1, ps, *tail_k), pool_map),
+        pl.BlockSpec((1, ps, *tail_v), pool_map),
     ]
     out_specs = [
-        pl.BlockSpec((1, ps, kvh, dk), pool_map),
-        pl.BlockSpec((1, ps, kvh, dv), pool_map),
+        pl.BlockSpec((1, ps, *tail_k), pool_map),
+        pl.BlockSpec((1, ps, *tail_v), pool_map),
     ]
     out_shape = [jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
                  jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype)]

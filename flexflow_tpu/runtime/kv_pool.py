@@ -936,11 +936,10 @@ class KVPagePool:
         self.window_groups: Dict[int, WindowPageGroup] = {
             w: WindowPageGroup(w, self.page_size, self.slots)
             for w in sorted({op_keeps(op) for op in gen.attn_ops} - {None})}
-        if self.window_groups and (draft_gen is not None or prefix_cache):
+        if self.window_groups and draft_gen is not None:
             raise ValueError(
-                "window layers keep a ring of pages a slot: no trie edge "
-                "and no draft pool shares it (prefix_cache=False, no "
-                "draft model)")
+                "window layers keep a ring of pages a slot: no draft pool "
+                "shares it (no draft model)")
         self.pool = self._init_arrays(gen, kv_dtype)
         # the draft pool mirrors the target pool's page GEOMETRY, page
         # IDS and storage dtype (its own KVH/Dh): one allocator, one page
@@ -952,24 +951,38 @@ class KVPagePool:
         # host_pages > 0 gives the trie a pinned host-memory second tier
         # whose D2H/H2D are this pool's own movers
         state_ops = list(getattr(gen, "state_ops", ()))
-        snapshots = int(snapshots) if (prefix_cache and state_ops) else 0
+        window_ops = [op for op in gen.attn_ops if op_keeps(op) is not None]
+        snapshots = int(snapshots) if (
+            prefix_cache and (state_ops or window_ops)) else 0
         self.prefix_cache = (RadixPrefixCache(
             self.page_size, host_pages=host_pages,
             d2h=self.d2h, h2d=self.h2d, snapshots=snapshots)
             if prefix_cache else None)
         # beside the slots' states: the snapshots the trie's nodes carry,
         # one row an id and row 0 the scratch row, in arrays of the ops'
-        # own pool format. Written by the prefill programs only (a prefill
+        # own pool format: a recurrent op's state, and of a window layer
+        # the pages of the window before the node's last position (ring - 1
+        # pages an id: at a page-aligned match point that is all such a
+        # layer knows). Written by the prefill programs only (a prefill
         # that ends on a page boundary), read by the hit prefills; None
-        # for every engine without a prefix cache or without state ops.
+        # for every engine without a prefix cache or with neither kind of op.
         self.snapshots = None
         if snapshots:
             repl = NamedSharding(gen.model.mesh, PartitionSpec())
+            cdtype = gen._compute_dtype()
             self.snapshots = {
                 op.name: jax.tree.map(
                     lambda a: jax.device_put(a, repl),
-                    op.init_state_pool(snapshots + 1, gen._compute_dtype()))
+                    op.init_state_pool(snapshots + 1, cdtype))
                 for op in state_ops}
+            self.snapshots.update({
+                op.name: jax.tree.map(
+                    lambda a: jax.device_put(a, repl),
+                    op.init_paged_cache(
+                        (snapshots + 1)
+                        * (self.window_groups[op_keeps(op)].ring - 1),
+                        self.page_size, cdtype, kv_dtype=kv_dtype))
+                for op in window_ops})
 
     def _init_arrays(self, gen, kv_dtype):
         # COMMITTED (replicated on the model's mesh) up front: an

@@ -498,21 +498,12 @@ class ServingEngine:
             # trie node carries, which the host tier does not move, and
             # one verify pass cannot score several positions of it
             named = self.gen.state_ops[0].name
-            if enable_prefix:
-                if hp:
-                    raise ValueError(
-                        f"{named} keeps a recurrent state: the host tier "
-                        "moves pages of per-token rows only, a demoted "
-                        "prefix would leave its snapshot behind; "
-                        "host_kv_pages must be 0")
-                self.state_snapshots = int(
-                    self.slots if state_snapshots is None
-                    else state_snapshots)
-                if self.state_snapshots < 1:
-                    raise ValueError(
-                        f"state_snapshots={state_snapshots}: a prefix "
-                        f"cache over {named}'s recurrent state needs at "
-                        "least one snapshot (or prefix_cache=False)")
+            if enable_prefix and hp:
+                raise ValueError(
+                    f"{named} keeps a recurrent state: the host tier "
+                    "moves pages of per-token rows only, a demoted "
+                    "prefix would leave its snapshot behind; "
+                    "host_kv_pages must be 0")
             if self.speculate_k > 0:
                 raise ValueError(
                     f"{named} keeps a recurrent state: speculative "
@@ -523,16 +514,21 @@ class ServingEngine:
                            if w is not None})
         if windowed:
             # a window layer keeps a ring of pages a slot (runtime/
-            # kv_pool.py WindowPageGroup): a prefix hit would need the
-            # window layers' last rows at the match point, a rejected
-            # draft position cannot be taken back out of a ring, and an
-            # interleaved chunk program does not seat one
+            # kv_pool.py WindowPageGroup). At a page-aligned match point
+            # its whole state is the pages of the window before it, so it
+            # joins the snapshot protocol of the recurrent state: a trie
+            # node that ends a published prefix carries those pages, a hit
+            # seats a COPY of them in the slot's ring. The host tier does
+            # not move a snapshot, a rejected draft position cannot be
+            # taken back out of a ring, and an interleaved chunk program
+            # does not seat one
             named = next(op.name for op in self.gen.attn_ops
                          if op_keeps(op) is not None)
             for bad, what in (
-                    (enable_prefix, "prefix_cache must be False (a hit "
-                     "needs the window layers' rows at the match point, "
-                     "which no trie page holds)"),
+                    (enable_prefix and hp, "host_kv_pages must be 0 (the "
+                     "host tier moves pages of the global table only, a "
+                     "demoted prefix would leave its window's snapshot "
+                     "behind)"),
                     (self.speculate_k > 0, "speculate_k must be 0 (a "
                      "ring of pages cannot take back rejected draft "
                      "positions)"),
@@ -543,6 +539,14 @@ class ServingEngine:
                     raise ValueError(
                         f"{named} keeps a window of {windowed[0]} "
                         f"positions: {what}")
+        if enable_prefix and (self.gen.state_ops or windowed):
+            self.state_snapshots = int(
+                self.slots if state_snapshots is None else state_snapshots)
+            if self.state_snapshots < 1:
+                raise ValueError(
+                    f"state_snapshots={state_snapshots}: a prefix cache "
+                    f"over {named}'s recurrent state or window needs at "
+                    "least one snapshot (or prefix_cache=False)")
         self.draft_gen = None
         if self.speculate_k > 0:
             if self.draft_model is None:
@@ -1296,6 +1300,8 @@ class ServingEngine:
         cdtype = gen._compute_dtype()
         caches = {}
         for op in gen.attn_ops:
+            if op_keeps(op) is not None:
+                continue    # a window layer resumes from its snapshot
             with jax.named_scope(op.name), jax.named_scope("gather"):
                 c = op.init_cache(1, bucket, cdtype)
                 g = op.gather_paged_kv(pool[op.name], prefix_pages)
@@ -1347,17 +1353,42 @@ class ServingEngine:
                 out[op.name] = op.seat_state(pool[op.name], state, at)
         return out
 
-    @staticmethod
-    def _take_snapshot(gen, snaps, caches, snap):
+    def _take_snapshot(self, gen, snaps, caches, snap, length):
         """Write the state every recurrent op ends a prefill with into row
         ``snap`` of the snapshot arrays (row 0, the scratch row, where the
-        prefill publishes nothing)."""
+        prefill publishes nothing), and of every window layer the pages of
+        the window that ends at the prompt's ``length`` (a snapshot is taken
+        where that is a page boundary: the window before a match point)."""
         out = {}
         for op in gen.state_ops:
             with jax.named_scope(op.name), jax.named_scope("seat"):
                 out[op.name] = op.seat_state(snaps[op.name],
                                              caches[op.name], snap)
+        for op in gen.attn_ops:
+            if op_keeps(op) is None:
+                continue
+            with jax.named_scope(op.name):
+                out[op.name] = op.take_window_snapshot(
+                    snaps[op.name], caches[op.name], length, snap,
+                    impl=self.paged_attention_impl)
         return out
+
+    def _seed_window_caches(self, gen, bucket, p0, snaps, snap):
+        """The contiguous caches of a hit prefill's WINDOW layers: zeros
+        but for the pages of the window before the match point ``p0``,
+        read from row ``snap`` of the snapshot arrays, READ-ONLY (the tail
+        is written behind them, and the seat copies them into the slot's
+        ring: copy-on-write)."""
+        cdtype = gen._compute_dtype()
+        caches = {}
+        for op in gen.attn_ops:
+            if op_keeps(op) is None:
+                continue
+            with jax.named_scope(op.name), jax.named_scope("gather"):
+                caches[op.name] = op.seed_window_cache(
+                    op.init_cache(1, bucket, cdtype), snaps[op.name], snap,
+                    p0)
+        return caches
 
     @staticmethod
     def _seed_state_caches(gen, snaps, snap, dtype):
@@ -1452,9 +1483,7 @@ class ServingEngine:
             # come last
             slot = seat[:1] if gen.state_ops else ()
             snaps, snap = seat[-2:] if self.kv.snapshots else (None, None)
-            # (window layers and snapshots never meet: the prefix cache is
-            # refused over a ring)
-            rings = seat[-1] if self.kv.window_groups else None
+            rings = seat[len(slot)] if self.kv.window_groups else None
             caches = gen.init_caches(1, bucket, cdtype)
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
@@ -1468,15 +1497,16 @@ class ServingEngine:
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
             taken = (() if snaps is None else
-                     (self._take_snapshot(gen, snaps, caches, snap),))
+                     (self._take_snapshot(gen, snaps, caches, snap, length),))
             return (tok, ok, self._scatter_tail(gen, pool, caches, pages,
                                                 slot=slot, length=length,
                                                 rings=rings),
                     *self._routing_sum(routing, rows, static_rows), *taken)
 
-        # the snapshot arrays are the 15th argument: donated where present
+        # the snapshot arrays come last but one: donated where present
         return jax.jit(prefill, donate_argnums=(
-            (4, 14) if self.kv.snapshots else (4,)))
+            (4, 13 + len(self._seat_args(0))) if self.kv.snapshots
+            else (4,)))
 
     def _build_prefill_hit(self, bucket: int, full: int, took=None,
                            static_rows=None):
@@ -1497,17 +1527,24 @@ class ServingEngine:
                     prefix_pages, tail_pages, poison,
                     temps, top_ps, top_ks, seeds, lora_pool, lora_pages,
                     *resume):
-            # `resume`, for a model with recurrent-state ops: the pool row
-            # the request is seated in, the snapshot arrays, the row the
-            # tail resumes FROM and the row it writes (0 = scratch)
+            # `resume`, for an engine that holds snapshots (a model with
+            # recurrent-state ops or window layers): what `_seat_args`
+            # gives (the pool row the request is seated in, the slot's ring
+            # tables), then the snapshot arrays, the row the tail resumes
+            # FROM and the row it writes (0 = scratch)
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
             caches = self._seed_prefix_caches(gen, bucket, p0, pool,
                                               prefix_pages)
+            slot = rings = None
             if resume:
-                slot, snaps, snap_from, snap = resume
+                *seat, snaps, snap_from, snap = resume
+                slot = seat[0] if gen.state_ops else None
+                rings = seat[-1] if self.kv.window_groups else None
                 caches.update(self._seed_state_caches(
                     gen, snaps, snap_from, gen._compute_dtype()))
+                caches.update(self._seed_window_caches(
+                    gen, bucket, p0, snaps, snap_from))
             routing = [] if gen.dropless_moe_ops else None
             rows = []
             # row_lengths on the tail walk: its attention does not read
@@ -1526,14 +1563,16 @@ class ServingEngine:
             tok, ok = self._first_token(logits, poison, temps, top_ps,
                                         top_ks, seeds)
             taken = (() if not resume else
-                     (self._take_snapshot(gen, snaps, caches, snap),))
+                     (self._take_snapshot(gen, snaps, caches, snap, length),))
             return (tok, ok, self._scatter_tail(
                         gen, pool, caches, tail_pages, p0,
-                        slot=(slot,) if resume else None),
+                        slot=None if slot is None else (slot,),
+                        length=length, rings=rings),
                     *self._routing_sum(routing, rows, static_rows), *taken)
 
         return jax.jit(prefill, donate_argnums=(
-            (5, 16) if self.kv.snapshots else (5,)))
+            (5, 15 + len(self._seat_args(0))) if self.kv.snapshots
+            else (5,)))
 
     def _build_draft_prefill(self, bucket: int, n_pages: int):
         """Cold draft prefill: fill the draft pool's pages for the whole
@@ -1897,7 +1936,7 @@ class ServingEngine:
                 f"{what}: {self.gen.state_ops[0].name} keeps a recurrent "
                 "state, which no page slab carries (export, import and "
                 "evacuation move pages of per-token rows only)")
-        if self.kv.window_groups:
+        if self.kv.window_groups and not with_snapshot:
             raise NotImplementedError(
                 f"{what}: a window layer's rows live in a ring of pages a "
                 "slot, which no page slab carries (export, import and "
@@ -1916,7 +1955,12 @@ class ServingEngine:
         groups' ring tables of the slot; nothing for most models."""
         if not self.kv.window_groups:
             return self._state_slot_args(slot)
-        return (*self._state_slot_args(slot), self.kv.window_tables(slot))
+        rings = self.kv.window_tables(max(slot, 0))
+        if slot < 0:
+            # a publisher (`prefill_into_cache`) holds no slot: its window
+            # layers' tail lands in the scratch page
+            rings = {w: np.zeros_like(t) for w, t in rings.items()}
+        return (*self._state_slot_args(slot), rings)
 
     def _snapshot_args(self, lease, hit: bool):
         """What a prefill program of an engine that holds snapshots takes
@@ -1976,7 +2020,7 @@ class ServingEngine:
                 np.asarray([[prompt[-1]]], np.int32), length, kv.pool,
                 prefix_pages, tail_pages, poison, *sampling,
                 *self._lora_args_1(adapter_page),
-                *(self._state_slot_args(slot) if kv.snapshots else ()),
+                *(self._seat_args(slot) if kv.snapshots else ()),
                 *self._snapshot_args(lease, True))
         else:
             if self.prefill_chunk_loop and self.buckets:

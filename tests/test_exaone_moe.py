@@ -221,8 +221,10 @@ def test_a_window_layers_pool_is_its_ring_whatever_the_context(ff):
 
 def test_engine_refuses_what_a_ring_of_pages_cannot_do(ff):
     kw = dict(serve_slots=2, kv_page_size=PAGE, max_seq_len=48)
-    with pytest.raises(ValueError, match="prefix_cache must be False"):
-        ff.make_serving_engine(prefix_cache=True, **kw)
+    with pytest.raises(ValueError, match="host_kv_pages must be 0"):
+        ff.make_serving_engine(prefix_cache=True, host_kv_pages=8, **kw)
+    with pytest.raises(ValueError, match="at least one snapshot"):
+        ff.make_serving_engine(prefix_cache=True, state_snapshots=0, **kw)
     with pytest.raises(ValueError, match="speculate_k must be 0"):
         ff.make_serving_engine(prefix_cache=False, draft_model=ff,
                                speculate_k=2, **kw)
@@ -232,14 +234,49 @@ def test_engine_refuses_what_a_ring_of_pages_cannot_do(ff):
     eng = ff.make_serving_engine(prefix_cache=False, **kw)
     p = prompts([16])[0]
     for call in (lambda: eng.export_prefix_slab(p),
-                 lambda: eng.import_prefix_slab({}),
-                 lambda: eng.prefill_into_cache(p)):
+                 lambda: eng.import_prefix_slab({})):
         with pytest.raises(NotImplementedError, match="ring of pages"):
             call()
+    with pytest.raises(RuntimeError, match="needs the radix prefix cache"):
+        eng.prefill_into_cache(p)
     op = ff.get_op_by_name("attn_window_0")
     with pytest.raises(NotImplementedError, match="verification"):
         op.paged_verify_forward({}, [None] * 3, {}, None, None, None, None,
                                 None)
+
+
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_a_prefix_hit_over_window_layers_is_a_cold_prefill(ff, impl):
+    """What the engine refused until PR 46: `prefix_cache=True` over window
+    layers. A document of 8 pages is published once (its global layer's
+    pages and ONE page a window layer on the trie's last node); questions
+    hit it, prefill their tail from the match point and decode 20 tokens
+    (the rings wrap) token for token as a cold prefill of the same prompt
+    does, and as the reference's full forward says."""
+    kw = dict(serve_slots=2, kv_page_size=PAGE, max_seq_len=160,
+              decode_chunk=4, prefill_chunk=16, paged_attention_impl=impl)
+    doc = prompts([64], seed=50)[0]
+    cold = ff.make_serving_engine(prefix_cache=False, **kw)
+    warm = ff.make_serving_engine(prefix_cache=True, state_snapshots=3, **kw)
+    assert warm.prefill_into_cache(doc) == 64 // PAGE
+    assert sorted(warm.kv.snapshots) == [
+        f"attn_window_{i}" for i, w in enumerate(WINDOWS) if w]
+    for q in prompts([5, 8] if impl == "einsum" else [7], seed=60):
+        p = np.concatenate([doc, q])
+        a, b = (e.submit(p, max_new_tokens=20) for e in (cold, warm))
+        for e in (cold, warm):
+            while e.pending():
+                e.step()
+        assert (a.prefix_tokens, b.prefix_tokens) == (0, 64)
+        assert a.tokens == b.tokens
+        assert margins(ff, b).max() < MARGIN_ATOL
+    st = warm.stats()
+    assert st["state_snapshot_hits"] == st["prefix_lookups"] >= 1
+    warm.drain()
+    warm.flush_prefix_cache()
+    st = warm.stats()
+    assert st["free_pages"] == warm.num_pages - 1
+    assert st["state_snapshots_held"] == 0
 
 
 def test_decode_dispatch_says_what_each_kind_of_layer_read(ff):

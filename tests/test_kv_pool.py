@@ -383,7 +383,7 @@ class _WindowOp:
         return {"k": np.zeros((num_pages, page_size, 1, 2), np.float32)}
 
 
-def window_pool(prefix_cache=False, draft=None, slots=3):
+def window_pool(prefix_cache=False, draft=None, slots=3, snapshots=0):
     gen = SimpleNamespace(
         attn_ops=[_WindowOp("attn_global_0", None),
                   _WindowOp("attn_window_1", 5),
@@ -391,7 +391,8 @@ def window_pool(prefix_cache=False, draft=None, slots=3):
         _compute_dtype=lambda: np.float32, model=_NO_OPS.model)
     return KVPagePool(gen, draft, PAGES, PS, pages_per_slot=4,
                       kv_dtype=None, prefix_cache=prefix_cache,
-                      host_pages=0, page_import=None, slots=slots)
+                      host_pages=0, page_import=None, slots=slots,
+                      snapshots=snapshots)
 
 
 @pytest.mark.parametrize("window,page,ring", [(5, 2, 4), (4, 4, 2),
@@ -462,10 +463,19 @@ def test_the_pool_groups_its_ops_by_what_they_keep():
     assert g.held_pages == 0
 
 
-@pytest.mark.parametrize("kw,what", [
-    (dict(prefix_cache=True), "prefix_cache=False"),
-    (dict(draft=SimpleNamespace(attn_ops=[])), "no\\s+draft model"),
-])
-def test_a_pool_with_window_groups_refuses_a_trie_and_a_draft_pool(kw, what):
-    with pytest.raises(ValueError, match=what):
-        window_pool(**kw)
+def test_a_pool_with_window_groups_refuses_a_draft_pool():
+    with pytest.raises(ValueError, match="no\\s+draft model"):
+        window_pool(draft=SimpleNamespace(attn_ops=[]))
+
+
+def test_a_trie_over_window_groups_holds_a_snapshot_a_window_op():
+    """Since PR 46 a window layer is a citizen of the snapshot protocol: under
+    a prefix cache the pool holds, for each window op, ids + 1 times the
+    pages of one window (ring - 1) in the op's own page format, and the trie
+    counts the ids; the ops that keep everything get none."""
+    pool = window_pool(prefix_cache=True, snapshots=2)
+    assert sorted(pool.snapshots) == ["attn_window_1", "attn_window_2"]
+    ring = pool.window_groups[5].ring
+    assert pool.snapshots["attn_window_1"]["k"].shape[0] == 3 * (ring - 1)
+    assert pool.prefix_cache.snapshots == 2
+    assert window_pool(prefix_cache=False, snapshots=2).snapshots is None

@@ -908,3 +908,152 @@ def test_a_ring_too_narrow_for_its_window_is_refused():
     q, pool, table, pos = _window_inputs(3, 47)
     with pytest.raises(AssertionError, match="cannot hold a window"):
         _run_window_kernel(q, pool, table, pos, 9)
+
+
+# ---- a sink in the denominator; keys of 192 beside values of 128 (flat pages)
+
+
+def _sink_softmax(logits, sink):
+    """Softmax over the last axis with one more logit a head (kvh, grp) in
+    the denominator, whose probability is dropped."""
+    col = jnp.broadcast_to(sink[None, :, :, None, None],
+                           logits.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([logits, col], axis=-1),
+                          axis=-1)[..., :-1]
+
+
+def _flat_oracle(q, pool, table, live, kvh, scale, sink=None):
+    """Gather + grouped einsum over a FLAT pool ((pages, page, KVH x D): a
+    token's KV heads side by side), `live` (B, S, L) given."""
+    b, s, h, d = q.shape
+    gk = pool["k"][table].reshape(b, -1, kvh, d)
+    gv = pool["v"][table].reshape(b, gk.shape[1], kvh, -1)
+    qg = q.reshape(b, s, kvh, h // kvh, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, gk,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(live[:, None, None, :, :], logits,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1) if sink is None \
+        else _sink_softmax(logits, sink.reshape(kvh, h // kvh))
+    return jnp.einsum("bkgqs,bskd->bqkgd", probs, gv).reshape(b, s, h, -1)
+
+
+# MiMo-V2's two kinds of page at a quarter of the query heads: (kv heads,
+# window, sink)
+FLAT_CASES = {"global-4kv": (4, None, False), "global-4kv-sink": (4, None, True),
+              "window-8kv-sink": (8, 4, True), "window-8kv": (8, 4, False)}
+
+
+@pytest.mark.parametrize("turn", [1, 2])
+@pytest.mark.parametrize("name", sorted(FLAT_CASES))
+def test_flat_pages_of_wide_keys_and_a_sink_match_the_oracle(monkeypatch,
+                                                             name, turn):
+    """Keys of 192 beside values of 128 in one row a token (768 / 512 lanes
+    at 4 KV heads, 1536 / 1024 at 8), 16 query heads; slots of one page, of a
+    block and of a block and a tail; a sink a query head or none."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    kvh, window, has_sink = FLAT_CASES[name]
+    rs = np.random.RandomState(len(name))
+    page, h, d, dv = 4, 16, 192, 128
+    pps = 2 if window else 6
+    slots = [3, 9, 22, 0] if window else [(3, 4, 9), (1, 2, 3), (7, 8, 19),
+                                          (0, 0, 0)]
+    b = len(slots)
+    n_pages = 1 + b * pps
+    pool = {"k": jnp.asarray(rs.randn(n_pages, page, kvh * d), jnp.float32),
+            "v": jnp.asarray(rs.randn(n_pages, page, kvh * dv), jnp.float32)}
+    table = rs.permutation(np.arange(1, n_pages)).reshape(b, pps) \
+        .astype(np.int32)
+    q = jnp.asarray(rs.randn(b, 1, h, d), jnp.float32)
+    sink = jnp.asarray(2 * rs.randn(h), jnp.float32) if has_sink else None
+    monkeypatch.setattr(pallas_kernels, "_PAGED_TURN_COLS",
+                        turn * page * (kvh * d // 128))
+    assert pallas_kernels.paged_turn_pages(page, 1, pps, kvh * d) == turn
+    if window:
+        pos = np.asarray(slots, np.int32)
+        at = _ring_positions(pos, pps, page)
+        live = (at >= 0) & (at <= pos[:, None]) & (at > pos[:, None] - window)
+        zero = jnp.zeros_like(jnp.asarray(pos))
+        args = (jnp.asarray(pos)[:, None], zero, zero)
+    else:
+        row_len, pad, wp = (np.asarray(x, np.int32) for x in zip(*slots))
+        idx = np.arange(pps * page)
+        live = (idx[None] < row_len[:, None]) \
+            | ((idx[None] >= pad[:, None]) & (idx[None] <= wp[:, None]))
+        args = (jnp.asarray(wp)[:, None], jnp.asarray(row_len),
+                jnp.asarray(pad))
+    out = paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table), *args, 0.07,
+        window=window, sink=sink, kv_heads=kvh)
+    want = _flat_oracle(q, pool, table, jnp.asarray(live)[:, None, :], kvh,
+                        0.07, sink)
+    assert out.shape == (b, 1, h, dv) and bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+    if has_sink:    # and the sink is not nothing
+        none = _flat_oracle(q, pool, table, jnp.asarray(live)[:, None, :],
+                            kvh, 0.07)
+        assert np.abs(np.asarray(none) - np.asarray(want)).max() > 1e-2
+
+
+@pytest.mark.parametrize("window", sorted(WINDOW_CASES))
+def test_window_kernel_with_a_sink_matches_the_oracle(window):
+    """The sink in the kernel that existed (rows of KV heads, not flat)."""
+    q, pool, table, pos = _window_inputs(window, 43)
+    sink = jnp.asarray([1.5, -0.5, 0.25, 3.0], jnp.float32)
+    zero = jnp.zeros_like(jnp.asarray(pos))
+    out = paged_attention_fwd_pallas(
+        q, pool["k"], pool["v"], jnp.asarray(table),
+        jnp.asarray(pos)[:, None], zero, zero, 0.29, window=window, sink=sink)
+    b, s, h, d = q.shape
+    flat = {n: x.reshape(*x.shape[:2], -1) for n, x in pool.items()}
+    at = _ring_positions(pos, table.shape[1], 4)
+    live = (at >= 0) & (at <= pos[:, None]) & (at > pos[:, None] - window)
+    want = _flat_oracle(q, flat, table, jnp.asarray(live)[:, None, :], 2,
+                        0.29, sink)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+def test_an_op_with_wide_keys_holds_flat_pages_and_both_impls_agree():
+    """`MultiHeadAttention` with keys of 192 beside values of 128: its pool
+    is (pages, page, KVH x D) whatever the kind of layer, the prefill write,
+    the decode append and the decode attention agree between the einsum
+    oracle and the kernels, and a sink and a window ride through both."""
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+
+    m = FFModel(FFConfig(batch_size=1, mesh_shape={"data": 1}))
+    x = m.create_tensor([1, 8, 32], name="x")
+    for kvh, window, sink in ((2, 0, None), (4, 4, 1.0)):
+        op = MultiHeadAttention(
+            m, f"attn_{kvh}", [x, x, x], 32, 8, kdim=8 * 192, vdim=8 * 128,
+            bias=False, causal=True, num_kv_heads=kvh, rope=True,
+            rope_dim=64, window=window, sink=sink, value_scale=0.707)
+        assert op.pool_pack() == kvh and op.pool_pack(quantized=True) == 1
+        rs = np.random.RandomState(kvh)
+        params = {w.name: jnp.asarray(0.2 * rs.randn(*w.shape), jnp.float32)
+                  for w in op.weights()}
+        kh = jnp.asarray(rs.randn(1, 6, kvh, 192), jnp.float32)
+        vh = jnp.asarray(rs.randn(1, 6, kvh, 128), jnp.float32)
+        pools = {}
+        for impl in ("einsum", "pallas"):
+            cache = op.init_paged_cache(7, 4, jnp.float32)
+            assert cache["k"].shape == (7, 4, kvh * 192)
+            assert cache["v"].shape == (7, 4, kvh * 128)
+            pages = jnp.asarray([3, 5], jnp.int32)
+            cache = op.paged_prefill_write(cache, kh, vh, pages, impl=impl)
+            got = op.gather_paged_kv(cache, pages)
+            np.testing.assert_array_equal(got["k"][0, :6], kh[0])
+            np.testing.assert_array_equal(got["v"][0, :6], vh[0])
+            tok = jnp.asarray(np.random.RandomState(9).randn(2, 1, 32),
+                              jnp.float32)
+            table = jnp.asarray([[3, 5], [0, 0]], jnp.int32)
+            pos = jnp.asarray([6, 0], jnp.int32)
+            zero = jnp.zeros_like(pos)
+            out, cache = op.paged_decode_forward(
+                params, [tok, tok, tok], cache, table, pos, pos,
+                jnp.asarray([6, 0], jnp.int32), jnp.asarray([6, 0], jnp.int32)
+                if not window else zero, impl=impl)
+            pools[impl] = (out, cache)
+        (a, ca), (b, cb) = pools["einsum"], pools["pallas"]
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
+        np.testing.assert_array_equal(np.asarray(ca["k"]), np.asarray(cb["k"]))
