@@ -1397,6 +1397,13 @@ class MultiHeadAttention(Op):
         # batch, seq (ring attention), hidden (head split)
         return [0, 1, 2]
 
+    def partial_sum_axes(self, axis_map):
+        # a head split (an axis on the hidden dim) shards wo on its input
+        # side: each shard's output is one term of the sum over heads,
+        # reduced before the op's own output constraint
+        return super().partial_sum_axes(axis_map) + [
+            ax for ax, d in (axis_map or {}).items() if d == 2]
+
     def single_axis_dims(self):
         # the ring/Ulysses lowering rotates around ONE named mesh axis; a
         # seq dim sharded over two axes is rejected at execution

@@ -131,6 +131,16 @@ class Op:
         return {ax: (d if d is not None and d >= 0 else None)
                 for ax, d in (axis_map or {}).items()}
 
+    def partial_sum_axes(self, axis_map: Dict[str, Optional[int]]
+                         ) -> List[str]:
+        """Mesh axes over which the op's output is a PARTIAL SUM before it
+        is reduced: a CONTRACT axis (the row-parallel matmul's shards each
+        hold one term). The cost model prices that reduction on the edge,
+        by what the consumer keeps (`CostModel.edge_held_time`)."""
+        from flexflow_tpu.parallel.pconfig import CONTRACT
+
+        return [ax for ax, d in (axis_map or {}).items() if d == CONTRACT]
+
     def weight_partition(self, axis_map: Dict[str, Optional[int]]):
         """Given the op's output axis_map (mesh axis -> output dim), return
         {weight_name: PartitionSpec}. Default: fully replicated weights

@@ -20,7 +20,9 @@ strategies run on the four-chip host): the job's own `compute_dtype`
 (element size of activations, edges and gradient syncs, the MXU's peak),
 `master_dtype` and optimizer (a weight with its gradient and moments, WHOLE
 on every mesh axis that does not shard it); every edge is charged its
-reshard forward and the transpose of it backward; the optimizer's pass
+reshard forward and the transpose of it backward, and the reductions it
+causes by what both of its ends hold (`edge_held_time`, PR 47: a psum is
+no part of an op's own time); the optimizer's pass
 over the state a chip holds, FSDP's passes over the gathered weights and a
 sequence-sharded attention's key/value rotation are part of an op's
 compute time; the share of a gradient all-reduce that the backend cannot
@@ -133,6 +135,7 @@ class CostModel:
             machine = MachineModel(dcn_axes=dict(dcn)) if dcn \
                 else MachineModel()
         self.machine = machine
+        self._readers = None    # (len(model.ops), ids of every input tensor)
         self.measured = measured or {}  # (op_name, parts) -> seconds (fwd+bwd)
         self.dtype_bytes = dtype_bytes
         # FSDP (FFConfig.fsdp_axis): weights + opt state further shard over
@@ -152,6 +155,13 @@ class CostModel:
             cfg_axis = getattr(getattr(model, "config", None),
                                "fsdp_axis", "") or ""
             self.fsdp_axis = cfg_axis if cfg_axis in self.mesh_shape else ""
+
+    def _consumed(self, op: Op) -> bool:
+        """Whether an op of the graph reads one of ``op``'s outputs."""
+        ops = self.model.ops
+        if self._readers is None or self._readers[0] != len(ops):
+            self._readers = (len(ops), {id(t) for o in ops for t in o.inputs})
+        return any(id(t) in self._readers[1] for t in op.outputs)
 
     @property
     def num_devices(self) -> int:
@@ -202,17 +212,17 @@ class CostModel:
                         / max(_parts_out(axis_map, self.mesh_shape), 1))
             fwd = self.machine.compute_time(flops, io_bytes, self.dtype_bytes)
             t = 3.0 * fwd  # fwd + ~2x bwd (reference measures both)
-        # CONTRACT (row-parallel) axes psum the output activations: once in
-        # forward, once for the mirror collective in backward. Added on top
-        # of EITHER cost tier (the measured shard time excludes comm) and
-        # folded into the op's serial cost — it gates consumers exactly
-        # like compute.
-        if contract_axes:
+        # CONTRACT's psum is priced on the edge that knows what the consumer
+        # keeps of it (`edge_held_time`), on top of either cost tier (the
+        # measured shard time excludes comm). An output no op of the graph
+        # consumes (the head's: the loss reads it) has no such edge and is
+        # reduced whole, here.
+        if contract_axes and not self._consumed(op):
             out_bytes = (sum(t_.volume() for t_ in op.outputs)
                          * self.dtype_bytes
                          / max(_parts_out(axis_map, self.mesh_shape), 1))
             for ax in contract_axes:
-                t += 2.0 * self.machine.all_reduce_time(
+                t += self.machine.all_reduce_time(
                     out_bytes, self.mesh_shape[ax], ax)
         # STAGE (pipeline-parallel) axes: the op's layers shard n ways (the
         # 1/n compute is already in `parts`), but the schedule pays (a) the
@@ -489,10 +499,77 @@ class CostModel:
                   tensor) -> float:
         """One edge of a TRAINING step: the tensor's reshard forward and
         its gradient's reshard back, the transpose of the first (an
-        all-gather returns as a reduce-scatter, a slice as an all-gather,
-        an all-to-all as itself), as CONTRACT's psum is charged twice."""
+        all-gather returns as a slice of a whole gradient, a slice as an
+        all-gather, an all-to-all as itself). These run beside the compute
+        (the schedule's comm stream); the REDUCTIONS an edge causes are
+        `edge_held_time`'s."""
         return (self.resharding_time(producer_map, consumer_map, tensor)
                 + self.resharding_time(consumer_map, producer_map, tensor))
+
+    def edge_held_time(self, src_op: Op, src_map: AxisMap, dst_op: Op,
+                       dst_map: AxisMap, input_idx: int, tensor) -> float:
+        """The reductions one edge of a training step causes, priced by
+        what BOTH ends hold (the ops' raw maps: `output_axis_map` has
+        forgotten a CONTRACT). This backend runs them synchronously (no
+        `-start` / `-done` pair: PERF.md, PR 36 and PR 47), so the schedule
+        lets them hold the compute streams of both ends; a tensor pays them
+        once however many consumers it has (the largest of its edges).
+
+        Forward, a producer whose output is a partial sum over an axis
+        (`Op.partial_sum_axes`: a CONTRACT matmul, a head-split
+        attention's output projection): a consumer that shards the axis on
+        the dim the matmul produced (the feature dim) takes a
+        reduce-scatter (its gradient's all-gather back is `edge_time`'s
+        transpose of the slice), any other an all-reduce (and a free
+        slice, if it keeps one: on the chip a slice of the BATCH dim is an
+        all-reduce and a slice, PERF.md PR 47's control). Backward, a
+        consumer whose PARAMETER dim is sharded over an axis (a
+        column-parallel Linear, a head-split attention) computes its
+        input's gradient as a partial sum over that axis: a producer that
+        shards the axis on the dim the consumer contracts takes a
+        reduce-scatter (the transpose of the forward all-gather), any
+        other an all-reduce. A Megatron pair pays one all-reduce each way,
+        as it did when both were booked to the row-parallel op; a
+        parameter-sharded head with no such partner pays its own."""
+        src_map, dst_map = src_map or {}, dst_map or {}
+        pam = src_op.output_axis_map(src_map)
+        try:
+            want = dst_op.input_axis_map(dst_map, input_idx)
+        except Exception:
+            want = dst_map
+        tbytes = tensor.volume() * self.dtype_bytes
+
+        def reduce(nbytes, ax, scatter):
+            f = (self.machine.reduce_scatter_time if scatter
+                 else self.machine.all_reduce_time)
+            return f(nbytes, self.mesh_shape[ax], ax)
+
+        t = 0.0
+        psum_axes = [ax for ax in src_op.partial_sum_axes(src_map)
+                     if self.mesh_shape.get(ax, 1) > 1]
+        if psum_axes:
+            # one chip's term of the sum: the tensor over the axes that
+            # shard it apart from the ones being summed over
+            partial = tbytes / max(_parts(
+                {ax: d for ax, d in pam.items() if ax not in psum_axes},
+                self.mesh_shape), 1)
+            made = {d % tensor.num_dims
+                    for d in src_op._contracted_output_dims}
+            t += sum(reduce(partial, ax, want.get(ax) in made)
+                     for ax in psum_axes)
+        if not np.issubdtype(np.dtype(tensor.np_dtype()), np.floating):
+            return t          # an index tensor has no gradient to reduce
+        nd_out = dst_op.outputs[0].num_dims
+        produced = {d % nd_out for d in dst_op._contracted_output_dims}
+        contracted = dst_op.contract_input_dim(input_idx)
+        if contracted is None:       # an attention's projections: the last
+            contracted = tensor.num_dims - 1
+        partial = tbytes / max(_parts(want, self.mesh_shape), 1)
+        for ax, d in dst_map.items():
+            if (d in produced and want.get(ax) is None
+                    and self.mesh_shape.get(ax, 1) > 1):
+                t += reduce(partial, ax, pam.get(ax) == contracted)
+        return t
 
     # ---- whole strategy ------------------------------------------------------
 
@@ -512,6 +589,7 @@ class CostModel:
         dev_mem = [0.0] * D
         finish: Dict[str, float] = {}
         blocks: Dict[str, tuple] = {}
+        paid: Dict[int, float] = {}   # per tensor: reductions already paid
 
         def block_of(op, am):
             ndev = max(1, min(_parts(am, self.mesh_shape), D))
@@ -541,20 +619,37 @@ class CostModel:
                 if ps != pi:
                     c += (t.volume() * self.dtype_bytes / max(ns, 1)
                           / self.machine.ici_bw) + self.machine.ici_latency
+                arrive = finish.get(src, 0.0)
                 if c > 0.0:
-                    start = finish.get(src, 0.0)
+                    start = arrive
                     for d in range(ps, ps + ns):
                         start = max(start, dev_comm[d])
                     for d in range(pi, pi + ni):
                         start = max(start, dev_comm[d])
-                    end = start + c
+                    arrive = start + c
                     for d in range(ps, ps + ns):
-                        dev_comm[d] = end
+                        dev_comm[d] = arrive
                     for d in range(pi, pi + ni):
-                        dev_comm[d] = end
-                    ready = max(ready, end)
-                else:
-                    ready = max(ready, finish.get(src, 0.0))
+                        dev_comm[d] = arrive
+                # the edge's reductions: what this tensor has not paid on
+                # an earlier edge, where nothing else computes on either
+                # block
+                h = self.edge_held_time(t.owner_op, strategy.get(src, {}),
+                                        op, am, input_idx, t) \
+                    - paid.get(id(t), 0.0)
+                if h > 0.0:
+                    paid[id(t)] = paid.get(id(t), 0.0) + h
+                    start = arrive
+                    for d in range(ps, ps + ns):
+                        start = max(start, dev_compute[d])
+                    for d in range(pi, pi + ni):
+                        start = max(start, dev_compute[d])
+                    arrive = start + h
+                    for d in range(ps, ps + ns):
+                        dev_compute[d] = arrive
+                    for d in range(pi, pi + ni):
+                        dev_compute[d] = arrive
+                ready = max(ready, arrive)
             start = ready
             for d in range(pi, pi + ni):
                 start = max(start, dev_compute[d])

@@ -3,9 +3,11 @@
 Builds the cost tables the C++ simulator consumes: per-op choice lists
 (legal axis maps) with compute + grad-sync (and the part of it that holds
 the compute stream) + per-device-memory costs and the device count each
-choice spans, per-edge resharding cost matrices (forward and backward) and
-tensor sizes (for placement transfers), and the annealer's structured moves
-(tied groups, followers: `set_moves`). Compiles the simulator on first use
+choice spans, per-edge resharding cost matrices (forward and backward), the
+reductions each edge causes (`CostModel.edge_held_time`, from the two ops'
+raw maps) and tensor sizes (for placement transfers), and the annealer's
+structured moves (tied groups, followers: `set_moves`). Compiles the
+simulator on first use
 (g++ through _native.build_native_lib — plain C ABI + ctypes).
 
 Strategies evaluated here are (choice, place) pairs per op: the axis map
@@ -45,7 +47,7 @@ def _load_lib():
     cd = ctypes.c_double
     tables = [ctypes.c_int, ctypes.c_int, ctypes.c_int,  # ops, edges, devices
               i64, d, d, d, d, i32,                      # op tables
-              i32, i32, i64, d, d]                       # edge tables
+              i32, i32, i64, d, d, d, i32]               # edge tables
     lib.ff_simulate.restype = cd
     lib.ff_simulate.argtypes = tables + [i32, i32, cd, cd, cd, cd]
     lib.ff_simulate_timeline.restype = cd
@@ -115,8 +117,14 @@ class CompiledSearchProblem:
         self.edge_dst = np.asarray([e[1] for e in edges], np.int32)
         self.edge_bytes = np.asarray(
             [e[3].volume() * cost.dtype_bytes for e in edges], np.float64)
+        # which tensor an edge carries: its reductions are paid once
+        tensor_ids: Dict[int, int] = {}
+        self.edge_tensor = np.asarray(
+            [tensor_ids.setdefault(id(e[3]), len(tensor_ids)) for e in edges],
+            np.int32)
         eoffsets = [0]
         ecosts: List[float] = []
+        eheld: List[float] = []
         for src_idx, dst_idx, input_idx, t in edges:
             src_maps = self.op_maps[src_idx]
             dst_maps = self.op_maps[dst_idx]
@@ -129,9 +137,13 @@ class CompiledSearchProblem:
                 for cm in dst_maps:
                     want = dst_op.input_axis_map(cm, input_idx)
                     ecosts.append(cost.edge_time(pm_out, want, t))
+                    # the reductions the edge causes read the RAW maps
+                    eheld.append(cost.edge_held_time(src_op, pm, dst_op, cm,
+                                                     input_idx, t))
             eoffsets.append(len(ecosts))
         self.edge_cost_offsets = np.asarray(eoffsets, np.int64)
         self.edge_costs = np.asarray(ecosts, np.float64)
+        self.edge_held_costs = np.asarray(eheld, np.float64)
         self.num_edges = len(edges)
         self.set_moves(tied_groups(model), follow_sources(model))
 
@@ -167,7 +179,8 @@ class CompiledSearchProblem:
                 self.op_sync_costs, self.op_exposed_costs, self.op_mem_bytes,
                 self.op_ndev,
                 self.edge_src, self.edge_dst, self.edge_cost_offsets,
-                self.edge_costs, self.edge_bytes)
+                self.edge_costs, self.edge_bytes, self.edge_held_costs,
+                self.edge_tensor)
 
     def _machine_args(self):
         from flexflow_tpu.search.cost_model import MEM_PENALTY_PER_BYTE

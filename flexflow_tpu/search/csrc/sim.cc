@@ -10,7 +10,12 @@
 //     seconds (and the share of them that holds the compute stream: a
 //     synchronous all-reduce), per-device memory bytes, devices spanned,
 //   * per graph edge, per (producer choice, consumer choice) pair:
-//     resharding comm seconds (GSPMD collectives within a device block).
+//     resharding comm seconds (GSPMD collectives within a device block), and
+//     the seconds of the REDUCTIONS that edge causes (a CONTRACT producer's
+//     psum by what the consumer keeps, a parameter-sharded consumer's input
+//     gradient by what the producer keeps): synchronous on this backend, so
+//     they hold the compute streams of both ends, and one tensor pays them
+//     once however many consumers it has (the largest of its edges).
 // This library evaluates a strategy — a (choice, placement) pair per op —
 // with PER-DEVICE compute and comm timelines (reference
 // simulator.cc:325-621): ops placed on disjoint device blocks overlap, ops
@@ -47,6 +52,10 @@ struct Tables {
   const int64_t* edge_cost_offsets; // [num_edges+1]
   const double* edge_costs;         // row-major [src_choice][dst_choice]
   const double* edge_bytes;         // [num_edges]: full tensor bytes
+  const double* edge_held_costs;    // like edge_costs: the edge's reductions,
+                                    // which hold both ends' compute streams
+  const int32_t* edge_tensor;       // [num_edges]: which tensor the edge
+                                    // carries (ids < num_edges)
   double hbm_bytes, ici_bw, ici_latency, mem_penalty_per_byte;
 };
 
@@ -80,6 +89,7 @@ double schedule(const Tables& T, const int32_t* choices,
   // edge behind queued grad traffic and poison the search landscape.
   std::vector<double> dev_sync(D, 0.0);     // per-device grad-sync stream
   std::vector<double> dev_mem(D, 0.0);      // per-device HBM footprint
+  std::vector<double> paid(T.num_edges + 1, 0.0);  // per tensor: reductions paid
 
   auto block = [&](int op) {
     int64_t off = T.op_cost_offsets[op];
@@ -107,6 +117,7 @@ double schedule(const Tables& T, const int32_t* choices,
         // p2p push over ICI (reference inter-device transfer tasks)
         c += T.edge_bytes[e] / std::max(ns, 1) / T.ici_bw + T.ici_latency;
       }
+      double begin = finish[s], arrive = finish[s];
       if (c > 0.0) {
         // the transfer occupies the comm streams of both blocks
         double start = finish[s];
@@ -115,12 +126,29 @@ double schedule(const Tables& T, const int32_t* choices,
         double end = start + c;
         for (int d = ps; d < ps + ns; ++d) dev_comm[d] = end;
         for (int d = pi; d < pi + ni; ++d) dev_comm[d] = end;
-        if (tl && tl->comm_start) { tl->comm_start[e] = start; tl->comm_finish[e] = end; }
-        ready = std::max(ready, end);
-      } else {
-        if (tl && tl->comm_start) { tl->comm_start[e] = tl->comm_finish[e] = finish[s]; }
-        ready = std::max(ready, finish[s]);
+        begin = start; arrive = end;
       }
+      // the edge's reductions: what this tensor has not paid on an earlier
+      // edge, run where nothing else computes on either block
+      double h = 0.0;
+      if (T.edge_held_costs) {
+        int t = T.edge_tensor[e];
+        h = T.edge_held_costs[off + (int64_t)choices[s] * n_dst + choices[i]]
+            - paid[t];
+        if (h > 0.0) paid[t] += h;
+      }
+      if (h > 0.0) {
+        double start = arrive;
+        for (int d = ps; d < ps + ns; ++d) start = std::max(start, dev_compute[d]);
+        for (int d = pi; d < pi + ni; ++d) start = std::max(start, dev_compute[d]);
+        double end = start + h;
+        for (int d = ps; d < ps + ns; ++d) dev_compute[d] = end;
+        for (int d = pi; d < pi + ni; ++d) dev_compute[d] = end;
+        if (c <= 0.0) begin = start;
+        arrive = end;
+      }
+      if (tl && tl->comm_start) { tl->comm_start[e] = begin; tl->comm_finish[e] = arrive; }
+      ready = std::max(ready, arrive);
       ++e;
     }
     int64_t off = T.op_cost_offsets[i];
@@ -176,6 +204,8 @@ Tables make_tables(int num_ops, int num_edges, int num_devices,
                    const int64_t* edge_cost_offsets,
                    const double* edge_costs,
                    const double* edge_bytes,
+                   const double* edge_held_costs,
+                   const int32_t* edge_tensor,
                    double hbm_bytes, double ici_bw, double ici_latency,
                    double mem_penalty_per_byte) {
   Tables T;
@@ -191,6 +221,8 @@ Tables make_tables(int num_ops, int num_edges, int num_devices,
   T.edge_cost_offsets = edge_cost_offsets;
   T.edge_costs = edge_costs;
   T.edge_bytes = edge_bytes;
+  T.edge_held_costs = edge_held_costs;
+  T.edge_tensor = edge_tensor;
   T.hbm_bytes = hbm_bytes;
   T.ici_bw = ici_bw > 0 ? ici_bw : 4.5e10;
   T.ici_latency = ici_latency;
@@ -217,13 +249,16 @@ double ff_simulate(int num_ops, int num_edges, int num_devices,
                    const int64_t* edge_cost_offsets,
                    const double* edge_costs,
                    const double* edge_bytes,
+                   const double* edge_held_costs,
+                   const int32_t* edge_tensor,
                    const int32_t* choices, const int32_t* places,
                    double hbm_bytes, double ici_bw, double ici_latency,
                    double mem_penalty_per_byte) {
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
                          op_compute_costs, op_sync_costs, op_exposed_costs,
                          op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
-                         edge_costs, edge_bytes, hbm_bytes, ici_bw,
+                         edge_costs, edge_bytes, edge_held_costs, edge_tensor,
+                         hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   return schedule(T, choices, places, nullptr);
 }
@@ -239,6 +274,8 @@ double ff_simulate_timeline(int num_ops, int num_edges, int num_devices,
                             const int64_t* edge_cost_offsets,
                             const double* edge_costs,
                             const double* edge_bytes,
+                            const double* edge_held_costs,
+                            const int32_t* edge_tensor,
                             const int32_t* choices, const int32_t* places,
                             double hbm_bytes, double ici_bw,
                             double ici_latency, double mem_penalty_per_byte,
@@ -248,7 +285,8 @@ double ff_simulate_timeline(int num_ops, int num_edges, int num_devices,
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
                          op_compute_costs, op_sync_costs, op_exposed_costs,
                          op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
-                         edge_costs, edge_bytes, hbm_bytes, ici_bw,
+                         edge_costs, edge_bytes, edge_held_costs, edge_tensor,
+                         hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   Timeline tl{compute_start, compute_finish, comm_start, comm_finish,
               sync_start, sync_finish};
@@ -280,6 +318,8 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
                const int64_t* edge_cost_offsets,
                const double* edge_costs,
                const double* edge_bytes,
+               const double* edge_held_costs,
+               const int32_t* edge_tensor,
                const int32_t* init_choices, const int32_t* init_places,
                double hbm_bytes, double ici_bw, double ici_latency,
                double mem_penalty_per_byte,
@@ -298,7 +338,8 @@ double ff_mcmc(int num_ops, int num_edges, int num_devices,
   Tables T = make_tables(num_ops, num_edges, num_devices, op_cost_offsets,
                          op_compute_costs, op_sync_costs, op_exposed_costs,
                          op_mem_bytes, op_ndev, edge_src, edge_dst, edge_cost_offsets,
-                         edge_costs, edge_bytes, hbm_bytes, ici_bw,
+                         edge_costs, edge_bytes, edge_held_costs, edge_tensor,
+                         hbm_bytes, ici_bw,
                          ici_latency, mem_penalty_per_byte);
   const int D = T.num_devices;
   std::mt19937_64 rng(seed);

@@ -33,6 +33,19 @@ subject to divisibility) — the GSPMD-constrained SOAP space. The objective is
 CostModel.iteration_time; when the C++ simulator library is built it replaces
 the Python loop wholesale (flexflow_tpu/search/csim.py, csrc/sim.cc ff_mcmc:
 the same seeds, groups and moves).
+
+Where a collective is priced (PERF.md, PR 47): on the edge where it happens,
+by what the two ends of that edge hold. An op's own time holds no psum; a
+CONTRACT producer's (and a head-split attention's) partial sum is reduced on
+the edge to its consumer, a reduce-scatter where the consumer shards the axis
+on the dim the matmul produced and an all-reduce anywhere else (the chip
+all-reduces and slices for a slice of the batch dim); a consumer whose
+parameter dim is sharded over an axis hands back a partial input gradient,
+reduce-scattered into a producer that shards the axis on the dim the consumer
+contracts and all-reduced into any other (`CostModel.edge_held_time`, csim's
+`edge_held_costs`); a tensor pays the largest of its edges' once. Only the
+output nobody in the graph consumes (the head's, read by the loss) is reduced
+inside its op.
 """
 
 from __future__ import annotations

@@ -25,15 +25,37 @@ def build_wide(mesh_shape, batch=64):
 MESH = {"data": 4, "model": 2}
 
 
-def test_native_matches_python_objective_on_random_strategies():
+def build_decoder(mesh_shape):
+    """Two decoder layers: row-parallel matmuls (CONTRACT) whose consumers
+    (residual adds) may keep a slice, column-parallel ones with and without
+    a CONTRACT partner, an attention whose head split sums over heads."""
+    from flexflow_tpu.models.llama import llama_lm
+
+    cfg = FFConfig(batch_size=8, mesh_shape=mesh_shape,
+                   compute_dtype="bfloat16")
+    cfg.enable_parameter_parallel = True
+    ff = FFModel(cfg)
+    llama_lm(ff, 8, seq_len=32, hidden=64, layers=2, heads=4, kv_heads=2,
+             ffn_hidden=128, vocab_size=256)
+    return ff
+
+
+@pytest.mark.parametrize("build", [build_wide, build_decoder])
+def test_native_matches_python_objective_on_random_strategies(build):
     """The C++ scheduler and CostModel.iteration_time are the same algorithm
     (VERDICT r1 weak #3): they must agree to float tolerance on random
-    strategies, so the two objectives cannot drift silently."""
-    ff = build_wide(MESH)
+    strategies, so the two objectives cannot drift silently. The decoder's
+    random strategies hold CONTRACT producers with sliced consumers and
+    parameter-sharded ops with no CONTRACT partner (ISSUE 47: their
+    reductions are priced per edge and paid once per tensor)."""
+    from flexflow_tpu.parallel.pconfig import CONTRACT
+
+    ff = build(MESH)
     cost = CostModel(ff, MESH)
     prob = CompiledSearchProblem(ff, cost, MESH)
     rs = np.random.RandomState(0)
     ops = prob.ops
+    sliced = reduced = 0
     for trial in range(20):
         strategy = {op.name: prob.op_maps[i][rs.randint(len(prob.op_maps[i]))]
                     for i, op in enumerate(ops)}
@@ -41,6 +63,19 @@ def test_native_matches_python_objective_on_random_strategies():
         c_python = cost.iteration_time(strategy)
         assert c_native == pytest.approx(c_python, rel=1e-9), \
             f"trial {trial}: native {c_native} != python {c_python}"
+        for e in range(prob.num_edges):
+            src, dst = ops[prob.edge_src[e]], ops[prob.edge_dst[e]]
+            pm, cm = strategy[src.name], strategy[dst.name]
+            idx = next(i for i, t in enumerate(dst.inputs)
+                       if t.owner_op is src)
+            held = cost.edge_held_time(src, pm, dst, cm, idx,
+                                       dst.inputs[idx])
+            reduced += held > 0.0
+            sliced += any(d == CONTRACT and dst.input_axis_map(cm, idx)
+                          .get(ax) is not None for ax, d in pm.items())
+    assert reduced, "no random strategy held a reduction on an edge"
+    if build is build_decoder:
+        assert sliced, "no CONTRACT producer met a consumer keeping a slice"
 
 
 def test_native_matches_python_with_placement():

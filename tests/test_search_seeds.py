@@ -1,8 +1,9 @@
 """The strategy search's seeds, tied moves and prices (ISSUE 36): the result
 is never priced above a seed, repeated layers stay alike, memory counts a
 replicated weight whole, a bf16 job is priced as one, and the two
-simulators agree on the edge costs. Graphs only: nothing compiles, no device
-is used."""
+simulators agree on the edge costs. ISSUE 47: a reduction is priced on the
+edge where it happens, by what both ends hold. Graphs only: nothing
+compiles, no device is used."""
 
 import numpy as np
 import pytest
@@ -123,6 +124,20 @@ def test_cell_search_keeps_the_four_layers_alike(cell):
     assert s["resharded_edges"] <= s["edges"] // 4
     assert s["predicted_step_s"] <= min(s["seed_costs"].values())
     assert s["started_from"] == "data=batch,model=parameter"
+    # ISSUE 47: the residual stream stays hidden-sharded over `model` (what
+    # the chip runs fastest: PERF.md section 6, PR 47), every row-parallel
+    # matmul and every attention reduce-scatters into it, the head keeps
+    # the batch over `data`, and nothing is placed off block 0 (the step
+    # stays ONE GSPMD program)
+    maps = {n: {ax: d for ax, d in (pc.axis_map or {}).items()
+                if d is not None} for n, pc in best.items()}
+    for i in range(4):
+        assert maps[f"res1_{i}"] == maps[f"res2_{i}"] \
+            == {"data": 0, "model": 2}, (i, maps[f"res1_{i}"])
+        assert maps[f"ffn_down_{i}"] == {"data": 0, "model": CONTRACT}
+        assert maps[f"attn_{i}"] == {"data": 0, "model": 2}
+    assert maps["lm_head"] == {"data": 0, "model": 2}
+    assert {min(pc.device_ids) for pc in best.values()} == {0}
     assert s["seed_costs"]["data_parallel"] > 10 * s["predicted_step_s"], \
         "DP over one axis of two holds every weight whole: it must lose"
     assert not s["over_cap"] and s["peak_hbm_bytes"] < 16e9
@@ -212,18 +227,42 @@ def test_python_and_native_agree_on_the_edge_costs(mesh):
         resharded += a > cost.iteration_time(
             search_seeds(ff, mesh, cost, _maps(ff, mesh))["data_parallel"])
     assert resharded, "random strategies reshard: the edges were priced"
-    # every table entry is the forward reshard plus its transpose
-    e = 0
-    src, dst = prob.ops[prob.edge_src[e]], prob.ops[prob.edge_dst[e]]
-    off = prob.edge_cost_offsets[e]
-    n_dst = len(prob.op_maps[prob.edge_dst[e]])
-    for i, pm in enumerate(prob.op_maps[prob.edge_src[e]]):
-        for j, cm in enumerate(prob.op_maps[prob.edge_dst[e]]):
-            p, c = src.output_axis_map(pm), dst.input_axis_map(cm, 0)
-            t = dst.inputs[0]
-            assert prob.edge_costs[off + i * n_dst + j] == pytest.approx(
-                cost.resharding_time(p, c, t) + cost.resharding_time(c, p, t),
-                rel=1e-12)
+    # every seed too: the family's parameter member holds CONTRACT producers
+    # whose consumers keep a slice, and a head with no CONTRACT partner
+    sliced = 0
+    for name, strategy in search_seeds(ff, mesh, cost,
+                                       _maps(ff, mesh)).items():
+        assert prob.simulate(prob.choices_for(strategy)) == pytest.approx(
+            cost.iteration_time(strategy), rel=1e-12), name
+        sliced += any(
+            CONTRACT in strategy[op.name].values() and any(
+                strategy[c.name].get(ax) is not None
+                for c in _ops(ff) if op.outputs[0] in c.inputs
+                for ax, d in strategy[op.name].items() if d == CONTRACT)
+            for op in _ops(ff))
+    assert sliced, "no seed held a CONTRACT producer with a sliced consumer"
+    # every table entry is the forward reshard plus its transpose, and
+    # beside it the reductions the edge causes, from the RAW maps
+    held = 0
+    for e in range(prob.num_edges):
+        src, dst = prob.ops[prob.edge_src[e]], prob.ops[prob.edge_dst[e]]
+        off = prob.edge_cost_offsets[e]
+        n_dst = len(prob.op_maps[prob.edge_dst[e]])
+        idx, t = next((i, t) for i, t in enumerate(dst.inputs)
+                      if t.owner_op is src)
+        for i, pm in enumerate(prob.op_maps[prob.edge_src[e]]):
+            for j, cm in enumerate(prob.op_maps[prob.edge_dst[e]]):
+                p, c = src.output_axis_map(pm), dst.input_axis_map(cm, idx)
+                if dst.inputs.count(t) == 1:
+                    assert prob.edge_costs[off + i * n_dst + j] \
+                        == pytest.approx(cost.resharding_time(p, c, t)
+                                         + cost.resharding_time(c, p, t),
+                                         rel=1e-12)
+                    assert prob.edge_held_costs[off + i * n_dst + j] \
+                        == pytest.approx(cost.edge_held_time(
+                            src, pm, dst, cm, idx, t), rel=1e-12)
+                held += prob.edge_held_costs[off + i * n_dst + j] > 0.0
+    assert held, "no edge of the table holds a reduction"
 
 
 def test_tied_groups_come_from_the_graph():
@@ -263,3 +302,270 @@ def test_data_parallel_on_a_data_mesh_is_the_batch_seed():
                 "resharded_edges", "tied_groups"):
         assert key in s
     assert set(out) == {op.name for op in _ops(ff)}
+
+
+# ---- ISSUE 47: a reduction is priced on its edge, by what both ends hold ----
+
+def _edge(ff, src, dst):
+    src, dst = ff.get_op_by_name(src), ff.get_op_by_name(dst)
+    idx = next(i for i, t in enumerate(dst.inputs) if t.owner_op is src)
+    return src, dst, idx, dst.inputs[idx]
+
+
+@pytest.mark.parametrize("case", ["sliced", "sliced_on_batch", "whole",
+                                  "column_from_whole", "column_from_sliced",
+                                  "column_from_batch_sliced",
+                                  "head_split_sliced", "head_split_whole",
+                                  "batch_only"])
+def test_a_reduction_is_priced_by_what_both_ends_hold(case):
+    mesh = MESHES["data2_model2"]
+    ff = build_llama(mesh, compute_dtype="bfloat16")
+    cost = CostModel(ff, mesh)
+    m = cost.machine
+    dp, col, row = {"data": 0}, {"data": 0, "model": 2}, \
+        {"data": 0, "model": CONTRACT}
+    batch4 = {"data": 0, "model": 0}
+    src, dst, src_map, dst_map = {
+        # a row-parallel matmul's psum, by what the residual add keeps
+        "sliced": ("ffn_down_0", "res2_0", row, col),
+        # on the chip a slice of the batch dim is an all-reduce and a slice
+        "sliced_on_batch": ("ffn_down_0", "res2_0", row, batch4),
+        "whole": ("ffn_down_0", "res2_0", row, dp),
+        # a column-parallel matmul's input gradient, by what the norm holds
+        "column_from_whole": ("ln2_0", "ffn_gate_0", dp, col),
+        "column_from_sliced": ("ln2_0", "ffn_gate_0", col, col),
+        "column_from_batch_sliced": ("ln2_0", "ffn_gate_0", batch4, col),
+        # a head split sums over heads in the output projection
+        "head_split_sliced": ("attn_0", "res1_0", col, col),
+        "head_split_whole": ("attn_0", "res1_0", col, dp),
+        "batch_only": ("ffn_down_0", "res2_0", dp, dp),
+    }[case]
+    src, dst, idx, t = _edge(ff, src, dst)
+    share = t.volume() * 2 / 2        # bf16, one `data` half of the tensor
+    held = cost.edge_held_time(src, src_map, dst, dst_map, idx, t)
+    edge = cost.edge_time(src.output_axis_map(src_map),
+                          dst.input_axis_map(dst_map, idx), t)
+    rs = m.reduce_scatter_time(share, 2, "model")
+    ar = m.all_reduce_time(share, 2, "model")
+    ag = m.all_gather_time(share / 2, 2, "model")
+    want_held, want_edge = {
+        # reduce-scatter forward, the gradient's all-gather back
+        "sliced": (rs, m.ici_latency + ag),
+        "sliced_on_batch": (ar, m.ici_latency + ag),
+        "whole": (ar, 0.0),
+        "column_from_whole": (ar, 0.0),
+        # all-gather forward, the partial gradient's reduce-scatter back
+        "column_from_sliced": (rs, ag + m.ici_latency),
+        "column_from_batch_sliced": (ar, ag + m.ici_latency),
+        "head_split_sliced": (rs, 0.0),
+        "head_split_whole": (ar, ag + m.ici_latency),
+        "batch_only": (0.0, 0.0),
+    }[case]
+    assert held == pytest.approx(want_held, rel=1e-12)
+    assert edge == pytest.approx(want_edge, rel=1e-12)
+    # nothing of the psum is left inside the op: its time is its roofline
+    # and the optimizer's pass over what it holds
+    if src_map is row:
+        io = (src.inputs[0].volume() / 4 + src.outputs[0].volume() / 2) * 2
+        assert cost.op_compute_time(src, row) == pytest.approx(
+            3.0 * m.compute_time(src.flops() / 4, io, 2)
+            + cost._state_pass_time(src, row), rel=1e-12)
+
+
+def _mlp4(mesh):
+    ff = FFModel(_config(mesh, compute_dtype="bfloat16"))
+    x = ff.create_tensor([8, 256], name="x")
+    t = ff.dense(x, 512, ActiMode.AC_MODE_RELU, name="fc0")
+    t = ff.dense(t, 1024, ActiMode.AC_MODE_RELU, name="fc1")
+    t = ff.dense(t, 512, name="fc2")
+    return ff, t
+
+
+def test_a_megatron_pair_costs_in_sum_what_it_cost_on_the_parent():
+    """Column-parallel fc1 + row-parallel fc2 between whole tensors: one
+    all-reduce forward (fc2 -> out) and one backward (fc0 -> fc1), where
+    the parent booked two to fc2. The price is the parent's, to the digit
+    (5.479840887937121e-05 at 55e419f)."""
+    mesh = MESHES["data2_model2"]
+    ff, t = _mlp4(mesh)
+    ff.dense(t, 16, name="out")
+    cost = CostModel(ff, mesh)
+    pair = {"fc0": {"data": 0}, "fc1": {"data": 0, "model": 1},
+            "fc2": {"data": 0, "model": CONTRACT}, "out": {"data": 0}}
+    assert cost.iteration_time(pair) == pytest.approx(5.479840887937121e-05,
+                                                      rel=1e-12)
+    prob = CompiledSearchProblem(ff, cost, mesh)
+    assert prob.simulate(prob.choices_for(pair)) == pytest.approx(
+        cost.iteration_time(pair), rel=1e-12)
+    ar = cost.machine.all_reduce_time(8 * 512 * 2 / 2, 2, "model")
+    rows = {r["name"]: r["finish"] - r["start"] for r in
+            prob.simulate_timeline(prob.choices_for(pair))[1]
+            if r["kind"] == "comm"}
+    assert rows == {"fc0->fc1": pytest.approx(ar), "fc2->out":
+                    pytest.approx(ar)}
+    # the first matmul of a graph has no gradient to hand back: a
+    # column-parallel fc0 pays nothing on the edge from the input
+    first = dict(pair, fc0={"data": 0, "model": 1},
+                 fc1={"data": 0, "model": CONTRACT}, fc2={"data": 0})
+    rows = [r for r in prob.simulate_timeline(prob.choices_for(first))[1]
+            if r["kind"] == "comm"]
+    assert [r["name"] for r in rows] == ["fc1->fc2"]
+
+
+def test_a_tensor_pays_its_psum_once_however_many_consumers_it_has():
+    mesh = MESHES["data2_model2"]
+    ff, t = _mlp4(mesh)
+    a = ff.relu(t, name="a")
+    b = ff.sigmoid(t, name="b")
+    ff.add(a, b, name="sum")
+    cost = CostModel(ff, mesh)
+    prob = CompiledSearchProblem(ff, cost, mesh)
+    dp = {"data": 0}
+    strat = {"fc0": dp, "fc1": {"data": 0, "model": 1},
+             "fc2": {"data": 0, "model": CONTRACT}, "a": dp, "b": dp,
+             "sum": dp}
+    ar = cost.machine.all_reduce_time(8 * 512 * 2 / 2, 2, "model")
+    total, rows = prob.simulate_timeline(prob.choices_for(strat))
+    comm = {r["name"]: r["finish"] - r["start"] for r in rows
+            if r["kind"] == "comm"}
+    assert comm == {"fc0->fc1": pytest.approx(ar),
+                    "fc2->a": pytest.approx(ar)}, "fc2->b paid again"
+    assert total == pytest.approx(cost.iteration_time(strat), rel=1e-12)
+    # a second consumer that keeps a slice where the first took the tensor
+    # whole adds nothing either: the largest of the edges is what is paid
+    strat["b"] = {"data": 0, "model": 1}
+    total2, rows = prob.simulate_timeline(prob.choices_for(strat))
+    assert total2 == pytest.approx(cost.iteration_time(strat), rel=1e-12)
+    assert sum(r["finish"] - r["start"] for r in rows
+               if r["kind"] == "comm" and r["name"] == "fc2->a") \
+        == pytest.approx(ar)
+
+
+def test_a_head_nobody_consumes_reduces_its_own_output():
+    """A CONTRACT op whose output no op of the graph reads (the loss does)
+    has no edge to carry its psum: it is reduced whole, in the op."""
+    mesh = MESHES["data2_model2"]
+    ff, t = _mlp4(mesh)
+    cost = CostModel(ff, mesh)
+    fc2, fc1 = ff.get_op_by_name("fc2"), ff.get_op_by_name("fc1")
+    row = {"data": 0, "model": CONTRACT}
+    ar = cost.machine.all_reduce_time(8 * 512 * 2 / 2, 2, "model")
+    plain = 3.0 * cost.machine.compute_time(
+        fc2.flops() / 4, (8 * 1024 / 4 + 8 * 512 / 2) * 2, 2)
+    assert cost.op_compute_time(fc2, row) - cost._state_pass_time(fc2, row) \
+        == pytest.approx(plain + ar, rel=1e-12)
+    # fc1 is read by fc2: nothing of a psum in it
+    assert cost.op_compute_time(fc1, {"data": 0, "model": CONTRACT}) \
+        - cost._state_pass_time(fc1, {"data": 0, "model": CONTRACT}) \
+        == pytest.approx(3.0 * cost.machine.compute_time(
+            fc1.flops() / 4, (8 * 512 / 4 + 8 * 1024 / 2) * 2, 2), rel=1e-12)
+
+
+def test_a_partnerless_sharded_head_is_not_priced_like_a_replicated_one():
+    ff = build_cell()
+    mesh = ff.config.mesh_shape
+    cost = CostModel(ff, mesh)
+    src, dst, idx, t = _edge(ff, "ln_f", "lm_head")
+    dp = {"data": 0}
+    assert cost.edge_held_time(src, dp, dst, dp, idx, t) == 0.0
+    # vocabulary over `model`: dX = dY W^T is a partial sum over `model`
+    half = t.volume() * 2 / 2
+    assert cost.edge_held_time(src, dp, dst, {"data": 0, "model": 2}, idx,
+                               t) == pytest.approx(
+        cost.machine.all_reduce_time(half, 2, "model"), rel=1e-12)
+    # vocabulary over both axes: an all-reduce over each (the norm shards
+    # `data` on the batch dim, not on the dim the head contracts)
+    both = cost.edge_held_time(src, dp, dst, {"data": 2, "model": 2}, idx, t)
+    assert both == pytest.approx(
+        cost.machine.all_reduce_time(2 * half, 2, "data")
+        + cost.machine.all_reduce_time(2 * half, 2, "model"), rel=1e-12)
+    # the embedding's input is token ids: no gradient, no reduction
+    emb = ff.get_op_by_name("tok_embed")
+    assert all(t.owner_op is None or isinstance(t.owner_op, InputOp)
+               for t in emb.inputs)
+
+
+PARENT_PRICES = {
+    ("mlp", "data4"): {
+        "data_parallel": 5.3204561489509626e-05,
+        "data=sequence": 3.046849824287388e-07,
+        "random0": 3.121635828251976e-05,
+        "random1": 4.079475256894152e-05,
+        "random2": 4.492747759779158e-05,
+        "random3": 3.121635828251976e-05,
+    },
+    ("mlp", "data2_model2"): {
+        "data_parallel": 5.0990196149750954e-05,
+        "data=batch,model=batch": 0.00010175187856268035,
+        "random0": 4.139599698528041e-05,
+        "random1": 8.493722680866984e-05,
+        "random2": 9.297430686608427e-05,
+        "random3": 5.538285012991713e-05,
+    },
+    ("cnn", "data4"): {
+        "data_parallel": 1.6416382024479583e-05,
+        "data=sequence": 1.0683146966854282e-05,
+        "data=none": 4.2104029304029306e-07,
+        "random0": 2.1251688376663987e-05,
+        "random1": 2.459286982935763e-05,
+        "random2": 2.4035157687840612e-05,
+        "random3": 1.0683146966854282e-05,
+    },
+    ("cnn", "data2_model2"): {
+        "data_parallel": 8.625349414812828e-06,
+        "data=batch,model=batch": 1.6934918609845435e-05,
+        "data=batch,model=sequence": 1.1637791476815867e-05,
+        "data=sequence,model=none": 6.845534173143929e-06,
+        "random0": 2.289448476726525e-05,
+        "random1": 2.1065393013490573e-05,
+        "random2": 2.1872796926650586e-05,
+        "random3": 2.0052878048780484e-05,
+    },
+    ("llama", "data4"): {
+        "data_parallel": 0.00012901265290806745,
+        "data=sequence": 0.000167593837577057,
+        "data=none": 1.5005538461538457e-05,
+        "random0": 0.00026484738747431427,
+        "random1": 0.0002664690259983919,
+        "random2": 0.0002600541917269721,
+        "random3": 0.00028501108764406323,
+    },
+    ("llama", "data2_model2"): {
+        "data_parallel": 7.45177936210131e-05,
+        "data=batch,model=batch": 0.00013778143339587232,
+        "data=batch,model=sequence": 0.0001480097632448851,
+        "data=sequence,model=none": 9.137211185562401e-05,
+        "random0": 0.0003037447554721701,
+        "random1": 0.0003124627091932457,
+        "random2": 0.00027601758027338504,
+        "random3": 0.00033095931314214256,
+    },
+}
+
+
+@pytest.mark.parametrize("build,mesh", sorted(PARENT_PRICES))
+def test_strategies_without_a_sharded_parameter_price_as_on_the_parent(
+        build, mesh):
+    """No CONTRACT, no parameter dim on an axis: no reduction on any edge.
+    Seeds that shard batch or sequence only, and random strategies drawn
+    with parameter parallelism off, price to the digit as at 55e419f (the
+    numbers were printed there by this loop)."""
+    want = PARENT_PRICES[build, mesh]
+    mesh = MESHES[mesh]
+    ff = {"mlp": build_mlp, "cnn": build_cnn,
+          "llama": build_llama}[build](mesh, compute_dtype="bfloat16")
+    cost = CostModel(ff, mesh)
+    seeds = search_seeds(ff, mesh, cost, _maps(ff, mesh))
+    got = {name: cost.iteration_time(seeds[name]) for name in want
+           if name in seeds}
+    rs = np.random.RandomState(7)
+    maps = {op.name: legal_axis_maps(op, mesh, False, True)
+            for op in _ops(ff)}
+    prob = CompiledSearchProblem(ff, cost, mesh, epp=False)
+    for k in range(4):
+        strat = {n: m[rs.randint(len(m))] for n, m in maps.items()}
+        got[f"random{k}"] = cost.iteration_time(strat)
+        assert prob.simulate(prob.choices_for(strat)) == pytest.approx(
+            got[f"random{k}"], rel=1e-12)
+    assert not prob.edge_held_costs.any()
+    assert got == want
