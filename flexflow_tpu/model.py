@@ -39,6 +39,7 @@ from flexflow_tpu.parallel.mesh import make_mesh
 from flexflow_tpu.parallel.strategy import (load_strategies_from_file,
                                             save_strategies_to_file)
 from flexflow_tpu.runtime import profiler
+from flexflow_tpu.runtime import telemetry as _telemetry
 from flexflow_tpu.runtime.executor import GraphExecutor
 from flexflow_tpu.runtime.loss import loss_type_from_name
 from flexflow_tpu.runtime.metrics import (ROUTING_COUNTS, PerfMetrics,
@@ -48,6 +49,17 @@ from flexflow_tpu.tensor import Tensor
 # process-wide model ids: the HBM ledger's per-instance source name
 # (two FFModels in one process must not overwrite each other's rows)
 _MODEL_IDS = iter(range(1 << 30))
+
+
+def _tree_nbytes(tree) -> int:
+    """Bytes of a tree's arrays, by shape and dtype (nothing is synced)."""
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def _batch_shapes(batch: Dict) -> tuple:
+    """What tells two executables of a forward program apart."""
+    return tuple((name, tuple(v.shape)) for name, v in sorted(batch.items()))
 
 
 class FFModel:
@@ -77,6 +89,8 @@ class FFModel:
         # name -> profiler.Program of a train program this model has run:
         # the process-wide registry holds them weakly
         self._registered = {}
+        # (program, batch shapes) whose first call has run (_first_call)
+        self._called = set()
         # divergence-guarded step + its device-resident guard carry
         # (runtime/resilience.py; built in compile() when
         # config.on_nonfinite != "none")
@@ -549,6 +563,35 @@ class FFModel:
                 return op
         return None
 
+    def _setup_span(self, name: str, **counts):
+        """A live lifecycle span on the ``setup`` track (ring event +
+        ``ff.<name>`` annotation); the shared no-op under
+        ``FFConfig.telemetry="off"``."""
+        if getattr(self.config, "telemetry", "on") == "off":
+            return _telemetry.NULL_SPAN
+        return _telemetry.tracer().span(name, track="setup", **counts)
+
+    def _first_call(self, name: str, fn, args, shapes=()):
+        """``fn(*args)`` for one of this model's jitted programs. Its
+        FIRST call (for eval and predict: the first of each batch shape)
+        is where it is traced, lowered and compiled or loaded, so it runs
+        under a ``compile`` span that ends when the outputs are ready;
+        every later call is the bare call behind one set lookup. A second
+        executable that jit builds on a mesh when the second call's
+        shardings differ opens no span: jax's durations for it land in
+        ``telemetry.setup_totals()``."""
+        key = (name, shapes)
+        if key in self._called:
+            return fn(*args)
+        self._called.add(key)
+        span = self._setup_span("compile", program=name)
+        if span is _telemetry.NULL_SPAN:    # off: neither span nor barrier
+            return fn(*args)
+        with span:
+            out = fn(*args)
+            jax.block_until_ready(out)
+        return out
+
     def compile(self, optimizer=None,
                 loss_type: Union[LossType, str] = LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
                 metrics: Sequence = (MetricsType.METRICS_ACCURACY,),
@@ -559,206 +602,235 @@ class FFModel:
         Reference: FFModel::compile (model.cc:1481-1646): optional strategy
         search, per-op create_output_and_partition/create_weights, fusion,
         label tensor, optimizer init.
+
+        The whole of it is the ``model_compile`` span on the ``setup``
+        track, over ``strategy_search``, ``init_params`` and
+        ``init_optimizer`` (runtime/telemetry.py ``LIFECYCLE_SPANS``). The
+        body stays in THIS frame: a wrapper frame between the caller and
+        ``init_params`` made jax trace and lower the init programs a third
+        slower on the chip's host (PERF.md section 6, PR 48).
         """
-        cfg = self.config
-        self.optimizer = optimizer
-        self.loss_type = loss_type_from_name(loss_type)
-        self.metric_types = metrics_from_names(metrics)
-        self.comp_mode = comp_mode
-        # strategy import must land BEFORE the elastic hook: on a relaunch
-        # with the original flags the imported file describes the OLD
-        # topology, and the hook's mesh-refit re-derivation has to win over
-        # it, not be clobbered by it
-        if cfg.import_strategy_file:
-            cfg.strategies.update(
-                load_strategies_from_file(cfg.import_strategy_file))
-        # elastic recovery (runtime/elastic.py): with a checkpoint_dir set,
-        # compare the newest intact checkpoint's recorded topology against
-        # what this process actually has BEFORE the mesh is built — a
-        # restart on fewer devices refits the mesh (csim-ranked), re-derives
-        # the saved strategy, and preserves the global batch via grad-accum,
-        # per cfg.on_topology_change; the later restore then re-shards the
-        # saved params onto whatever mesh this compile produces
-        self._elastic = None
-        if cfg.checkpoint_dir:
-            from flexflow_tpu.runtime.elastic import apply_elastic_policy
+        with self._setup_span("model_compile") as whole:
+            cfg = self.config
+            self.optimizer = optimizer
+            self.loss_type = loss_type_from_name(loss_type)
+            self.metric_types = metrics_from_names(metrics)
+            self.comp_mode = comp_mode
+            # strategy import must land BEFORE the elastic hook: on a relaunch
+            # with the original flags the imported file describes the OLD
+            # topology, and the hook's mesh-refit re-derivation has to win over
+            # it, not be clobbered by it
+            if cfg.import_strategy_file:
+                cfg.strategies.update(
+                    load_strategies_from_file(cfg.import_strategy_file))
+            # elastic recovery (runtime/elastic.py): with a checkpoint_dir set,
+            # compare the newest intact checkpoint's recorded topology against
+            # what this process actually has BEFORE the mesh is built — a
+            # restart on fewer devices refits the mesh (csim-ranked), re-derives
+            # the saved strategy, and preserves the global batch via grad-accum,
+            # per cfg.on_topology_change; the later restore then re-shards the
+            # saved params onto whatever mesh this compile produces
+            self._elastic = None
+            if cfg.checkpoint_dir:
+                from flexflow_tpu.runtime.elastic import apply_elastic_policy
 
-            self._elastic = apply_elastic_policy(self)
-        self.mesh = make_mesh(cfg.mesh_shape)
+                self._elastic = apply_elastic_policy(self)
+            self.mesh = make_mesh(cfg.mesh_shape)
 
-        if cfg.search_budget > 0:
-            from flexflow_tpu.search.driver import optimize_strategies_multi
+            if cfg.search_budget > 0:
+                with self._setup_span(
+                        "strategy_search", budget=cfg.search_budget) as span:
+                    from flexflow_tpu.search.driver import optimize_strategies_multi
 
-            # persistent cost DB: already-keyed op signatures load from
-            # disk instead of re-measuring/re-compiling (search/cost_db.py)
-            db_path = getattr(cfg, "cost_db_path", "") or None
-            measured = None
-            if cfg.measure_search_costs == "analyze":
-                from flexflow_tpu.search.measure import analyze_op_costs
+                    # persistent cost DB: already-keyed op signatures load from
+                    # disk instead of re-measuring/re-compiling (search/cost_db.py)
+                    db_path = getattr(cfg, "cost_db_path", "") or None
+                    measured = None
+                    if cfg.measure_search_costs == "analyze":
+                        from flexflow_tpu.search.measure import analyze_op_costs
 
-                measured = analyze_op_costs(
-                    self, cfg.mesh_shape,
-                    enable_parameter_parallel=cfg.enable_parameter_parallel,
-                    enable_attribute_parallel=cfg.enable_attribute_parallel,
-                    verbose=cfg.profiling, db_path=db_path)
-            elif cfg.measure_search_costs:
-                from flexflow_tpu.search.measure import measure_op_costs
+                        measured = analyze_op_costs(
+                            self, cfg.mesh_shape,
+                            enable_parameter_parallel=cfg.enable_parameter_parallel,
+                            enable_attribute_parallel=cfg.enable_attribute_parallel,
+                            verbose=cfg.profiling, db_path=db_path)
+                    elif cfg.measure_search_costs:
+                        from flexflow_tpu.search.measure import measure_op_costs
 
-                measured = measure_op_costs(
-                    self, cfg.mesh_shape,
-                    cfg.enable_parameter_parallel,
-                    cfg.enable_attribute_parallel,
-                    verbose=cfg.profiling, db_path=db_path)
-            machine = None
-            if cfg.dcn_mesh_shape:
-                # two-tier topology: axes listed in dcn_mesh_shape span that
-                # many hosts, so their collectives are priced at the DCN tier
-                from flexflow_tpu.search.machine import MachineModel
+                        measured = measure_op_costs(
+                            self, cfg.mesh_shape,
+                            cfg.enable_parameter_parallel,
+                            cfg.enable_attribute_parallel,
+                            verbose=cfg.profiling, db_path=db_path)
+                    machine = None
+                    if cfg.dcn_mesh_shape:
+                        # two-tier topology: axes listed in dcn_mesh_shape span that
+                        # many hosts, so their collectives are priced at the DCN tier
+                        from flexflow_tpu.search.machine import MachineModel
 
-                machine = MachineModel(dcn_axes=dict(cfg.dcn_mesh_shape))
-            # multi-objective: time subject to the per-chip HBM cap — when
-            # the time-optimal strategy fits (the common case) the relief
-            # loop is a no-op and this is exactly the old time-only search
-            best = optimize_strategies_multi(self, budget=cfg.search_budget,
-                                             alpha=cfg.search_alpha,
-                                             machine=machine,
-                                             measured=measured)
-            cfg.strategies.update(best)
-            if cfg.export_strategy_file:
-                save_strategies_to_file(cfg.export_strategy_file, cfg.strategies)
+                        machine = MachineModel(dcn_axes=dict(cfg.dcn_mesh_shape))
+                    # multi-objective: time subject to the per-chip HBM cap — when
+                    # the time-optimal strategy fits (the common case) the relief
+                    # loop is a no-op and this is exactly the old time-only search
+                    best = optimize_strategies_multi(self, budget=cfg.search_budget,
+                                                     alpha=cfg.search_alpha,
+                                                     machine=machine,
+                                                     measured=measured)
+                    cfg.strategies.update(best)
+                    report = getattr(self, "_search_summary", None) or {}
+                    seeds = len(report.get("seed_costs", ()))
+                    span.annotate(simulator=report.get("simulator", ""),
+                                  seeds=seeds,
+                                  candidates=seeds + cfg.search_budget)
+                    if cfg.export_strategy_file:
+                        save_strategies_to_file(cfg.export_strategy_file, cfg.strategies)
 
-        if cfg.strategy_lint != "off":
-            # fflint (analysis/): static validation of the now-final
-            # strategy table — pure graph+table checks, no tracing. A bad
-            # strategy is named HERE (op + pass + rule) instead of
-            # surfacing as a mesh-build/XLA error with no line back to
-            # the offending axis. The schema pass (text-file round-trip,
-            # a tempfile write per run) is file-facing and stays with the
-            # CLI/scripts callers — compile validates the in-memory table.
-            from flexflow_tpu.analysis import StrategyLintError, analyze
-            from flexflow_tpu.logger import fflogger
-
-            report = analyze(self, strategies=cfg.strategies,
-                             mesh_shape=cfg.mesh_shape,
-                             passes=("legality", "perf"))
-            if cfg.strategy_lint == "strict" and report.errors():
-                raise StrategyLintError(report)
-            report.log(fflogger)
-            # stash the footprint pass's per-chip HBM estimate for the
-            # accounting ledger's cross-check (runtime/flightrec.py:
-            # ff_hbm_lint_estimated_bytes vs the tracked byte ledger) —
-            # the lint already computed it, this costs nothing
-            rows = report.by_code("hbm-footprint")
-            if rows and rows[0].est_bytes:
-                self._lint_hbm_estimate = float(rows[0].est_bytes)
-
-        self._final_tensor = final_tensor or self.ops[-1].outputs[0]
-        # fused softmax + cross-entropy, the reference semantics: its CE
-        # loss kernels consume the Softmax OUTPUT with an identity backward
-        # through the softmax (loss_functions.cu grad = probs - one_hot),
-        # which equals CE-from-logits. compute_loss applies log_softmax
-        # itself, so a graph ending in Softmax must feed the loss its
-        # logits INPUT — otherwise training runs on a double softmax with
-        # flattened gradients. predict()/generate() still return the
-        # softmax output.
-        self._loss_tensor = self._final_tensor
-        if self.loss_type in (LossType.LOSS_CATEGORICAL_CROSSENTROPY,
-                              LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY):
-            fop = self._final_tensor.owner_op
-            from flexflow_tpu.ops.norm import Softmax as _Softmax
-
-            if isinstance(fop, _Softmax) \
-                    and fop.axis in (-1, fop.outputs[0].num_dims - 1):
-                self._loss_tensor = fop.inputs[0]
-
-        if cfg.perform_fusion:
-            # reference: FFModel::apply_fusion after search (model.cc:1538-1593)
-            from flexflow_tpu.ops.fused import apply_fusion
-
-            protected = [self._final_tensor, self._loss_tensor] + list(
-                getattr(self, "_aux_tensors", ()))
-            apply_fusion(self, protected=protected)
-
-        # label tensor shaped like the final op's sample dims (model.cc:1615-1646)
-        fdims = self._final_tensor.dims
-        if self.loss_type == LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY:
-            self.label_tensor = Tensor(dims=tuple(fdims[:-1]) + (1,),
-                                       dtype=DataType.DT_INT32, name="label")
-        else:
-            self.label_tensor = Tensor(dims=fdims, dtype=DataType.DT_FLOAT,
-                                       name="label")
-
-        from flexflow_tpu.parallel.placement import (PlacementExecutor,
-                                                     has_placement)
-
-        if has_placement(cfg.strategies, self.mesh.size):
-            # some op is placed on a proper device subset: lower via
-            # per-group sub-mesh programs (the reference mapper's per-op
-            # device_ids, mapper.cc:346-424)
-            self.executor = PlacementExecutor(self)
-        else:
-            self.executor = GraphExecutor(self)
-        self._rng, init_key = jax.random.split(self._rng)
-        self.params = self.executor.init_params(init_key)
-        self.bn_state = self.executor.init_state()
-        if self.optimizer is not None:
-            if getattr(self.config, "fused_optimizer", False):
-                self.optimizer = self._maybe_fuse_optimizer(self.optimizer)
-            if getattr(cfg, "overlap_grad_sync", False):
-                self.optimizer = self._maybe_shard_optimizer(self.optimizer)
-            self.opt_state = self.optimizer.init_state(self.params)
-            self._registered = {}   # programs of an earlier compile()
-            self._train_step = self.executor.make_train_step(
-                self.optimizer, self.loss_type, self.metric_types,
-                self._loss_tensor)
-            if cfg.on_nonfinite != "none":
+            if cfg.strategy_lint != "off":
+                # fflint (analysis/): static validation of the now-final
+                # strategy table — pure graph+table checks, no tracing. A bad
+                # strategy is named HERE (op + pass + rule) instead of
+                # surfacing as a mesh-build/XLA error with no line back to
+                # the offending axis. The schema pass (text-file round-trip,
+                # a tempfile write per run) is file-facing and stays with the
+                # CLI/scripts callers — compile validates the in-memory table.
+                from flexflow_tpu.analysis import StrategyLintError, analyze
                 from flexflow_tpu.logger import fflogger
 
-                if getattr(self.executor, "jits_per_group", False) \
-                        or cfg.grad_accum_steps > 1:
-                    fflogger.warning(
-                        "on_nonfinite=%r: divergence guard unsupported "
-                        "under operator placement / grad accumulation — "
-                        "training runs unguarded", cfg.on_nonfinite)
-                else:
-                    from flexflow_tpu.runtime.resilience import \
-                        init_guard_state
+                report = analyze(self, strategies=cfg.strategies,
+                                 mesh_shape=cfg.mesh_shape,
+                                 passes=("legality", "perf"))
+                if cfg.strategy_lint == "strict" and report.errors():
+                    raise StrategyLintError(report)
+                report.log(fflogger)
+                # stash the footprint pass's per-chip HBM estimate for the
+                # accounting ledger's cross-check (runtime/flightrec.py:
+                # ff_hbm_lint_estimated_bytes vs the tracked byte ledger) —
+                # the lint already computed it, this costs nothing
+                rows = report.by_code("hbm-footprint")
+                if rows and rows[0].est_bytes:
+                    self._lint_hbm_estimate = float(rows[0].est_bytes)
 
-                    self._guarded_step = \
-                        self.executor.make_guarded_train_step(
-                            self.optimizer, self.loss_type,
-                            self.metric_types, self._loss_tensor,
-                            guard_cfg={
-                                "on_nonfinite": cfg.on_nonfinite,
-                                "growth_interval":
-                                    cfg.loss_scale_growth_interval,
-                            })
-                    self._guard_state = init_guard_state(cfg.loss_scale)
-        self._eval_step = self.executor.make_eval_step(
-            self.loss_type, self.metric_types, self._loss_tensor)
+            self._final_tensor = final_tensor or self.ops[-1].outputs[0]
+            # fused softmax + cross-entropy, the reference semantics: its CE
+            # loss kernels consume the Softmax OUTPUT with an identity backward
+            # through the softmax (loss_functions.cu grad = probs - one_hot),
+            # which equals CE-from-logits. compute_loss applies log_softmax
+            # itself, so a graph ending in Softmax must feed the loss its
+            # logits INPUT — otherwise training runs on a double softmax with
+            # flattened gradients. predict()/generate() still return the
+            # softmax output.
+            self._loss_tensor = self._final_tensor
+            if self.loss_type in (LossType.LOSS_CATEGORICAL_CROSSENTROPY,
+                                  LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY):
+                fop = self._final_tensor.owner_op
+                from flexflow_tpu.ops.norm import Softmax as _Softmax
 
-        if cfg.taskgraph_file:
-            from flexflow_tpu.runtime.profiler import export_sim_taskgraph
+                if isinstance(fop, _Softmax) \
+                        and fop.axis in (-1, fop.outputs[0].num_dims - 1):
+                    self._loss_tensor = fop.inputs[0]
 
-            export_sim_taskgraph(self, cfg.taskgraph_file)
+            if cfg.perform_fusion:
+                # reference: FFModel::apply_fusion after search (model.cc:1538-1593)
+                from flexflow_tpu.ops.fused import apply_fusion
 
-        if getattr(cfg, "telemetry", "on") != "off":
-            # HBM accounting ledger (runtime/flightrec.py, ISSUE 15):
-            # params/opt-state byte rows + the lint footprint
-            # cross-check, published as ff_hbm_* gauges at scrape time
-            # and embedded in every post-mortem bundle. Registered ONCE
-            # per model (a recompile must not duplicate the source),
-            # under a per-instance name (two models in one process must
-            # not overwrite each other's rows).
-            from flexflow_tpu.runtime import flightrec
+                protected = [self._final_tensor, self._loss_tensor] + list(
+                    getattr(self, "_aux_tensors", ()))
+                apply_fusion(self, protected=protected)
 
-            led = flightrec.hbm_ledger()
-            if self._hbm_registered_on is not led:
-                self._hbm_registered_on = led
-                led.add_source(self._hbm_source)
-            if self._lint_hbm_estimate is not None:
-                flightrec.hbm_ledger().set_lint_estimate(
-                    self._lint_hbm_estimate)
+            # label tensor shaped like the final op's sample dims (model.cc:1615-1646)
+            fdims = self._final_tensor.dims
+            if self.loss_type == LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY:
+                self.label_tensor = Tensor(dims=tuple(fdims[:-1]) + (1,),
+                                           dtype=DataType.DT_INT32, name="label")
+            else:
+                self.label_tensor = Tensor(dims=fdims, dtype=DataType.DT_FLOAT,
+                                           name="label")
+
+            from flexflow_tpu.parallel.placement import (PlacementExecutor,
+                                                         has_placement)
+
+            if has_placement(cfg.strategies, self.mesh.size):
+                # some op is placed on a proper device subset: lower via
+                # per-group sub-mesh programs (the reference mapper's per-op
+                # device_ids, mapper.cc:346-424)
+                self.executor = PlacementExecutor(self)
+            else:
+                self.executor = GraphExecutor(self)
+            self._rng, init_key = jax.random.split(self._rng)
+            with self._setup_span("init_params") as span:
+                self.params = self.executor.init_params(init_key)
+                weights = jax.tree_util.tree_leaves(self.params)
+                # one jitted program a weight (executor.init_params)
+                span.annotate(weights=len(weights), programs=len(weights),
+                              bytes=_tree_nbytes(weights))
+            self.bn_state = self.executor.init_state()
+            if self.optimizer is not None:
+                if getattr(self.config, "fused_optimizer", False):
+                    self.optimizer = self._maybe_fuse_optimizer(self.optimizer)
+                if getattr(cfg, "overlap_grad_sync", False):
+                    self.optimizer = self._maybe_shard_optimizer(self.optimizer)
+                with self._setup_span("init_optimizer") as span:
+                    self.opt_state = self.optimizer.init_state(self.params)
+                    span.annotate(bytes=_tree_nbytes(self.opt_state))
+                self._registered = {}   # programs of an earlier compile()
+                self._train_step = self.executor.make_train_step(
+                    self.optimizer, self.loss_type, self.metric_types,
+                    self._loss_tensor)
+                if cfg.on_nonfinite != "none":
+                    from flexflow_tpu.logger import fflogger
+
+                    if getattr(self.executor, "jits_per_group", False) \
+                            or cfg.grad_accum_steps > 1:
+                        fflogger.warning(
+                            "on_nonfinite=%r: divergence guard unsupported "
+                            "under operator placement / grad accumulation — "
+                            "training runs unguarded", cfg.on_nonfinite)
+                    else:
+                        from flexflow_tpu.runtime.resilience import \
+                            init_guard_state
+
+                        self._guarded_step = \
+                            self.executor.make_guarded_train_step(
+                                self.optimizer, self.loss_type,
+                                self.metric_types, self._loss_tensor,
+                                guard_cfg={
+                                    "on_nonfinite": cfg.on_nonfinite,
+                                    "growth_interval":
+                                        cfg.loss_scale_growth_interval,
+                                })
+                        self._guard_state = init_guard_state(cfg.loss_scale)
+            self._eval_step = self.executor.make_eval_step(
+                self.loss_type, self.metric_types, self._loss_tensor)
+            self._called = set()        # every program compiles afresh
+
+            if cfg.taskgraph_file:
+                from flexflow_tpu.runtime.profiler import export_sim_taskgraph
+
+                export_sim_taskgraph(self, cfg.taskgraph_file)
+
+            if getattr(cfg, "telemetry", "on") != "off":
+                # HBM accounting ledger (runtime/flightrec.py, ISSUE 15):
+                # params/opt-state byte rows + the lint footprint
+                # cross-check, published as ff_hbm_* gauges at scrape time
+                # and embedded in every post-mortem bundle. Registered ONCE
+                # per model (a recompile must not duplicate the source),
+                # under a per-instance name (two models in one process must
+                # not overwrite each other's rows).
+                from flexflow_tpu.runtime import flightrec
+
+                led = flightrec.hbm_ledger()
+                if self._hbm_registered_on is not led:
+                    self._hbm_registered_on = led
+                    led.add_source(self._hbm_source)
+                if self._lint_hbm_estimate is not None:
+                    flightrec.hbm_ledger().set_lint_estimate(
+                        self._lint_hbm_estimate)
+            weights = jax.tree_util.tree_leaves(self.params)
+            whole.annotate(
+                ops=len(self.ops), weights=len(weights),
+                weight_bytes=_tree_nbytes(weights),
+                mesh=",".join(f"{a}={n}" for a, n
+                              in self.config.mesh_shape.items()))
 
     def _maybe_fuse_optimizer(self, opt):
         """FFConfig.fused_optimizer: replicated-param strategies (single
@@ -889,7 +961,8 @@ class FFModel:
                     jnp.asarray(bool(inject_nan)))
             self._register_program("train_step", self._guarded_step, args)
             (self.params, self.opt_state, self.bn_state, loss, mets,
-             self._guard_state) = self._guarded_step(*args)
+             self._guard_state) = self._first_call(
+                 "train_step", self._guarded_step, args)
         else:
             if inject_nan:
                 raise RuntimeError(
@@ -899,7 +972,7 @@ class FFModel:
                     step_key)
             self._register_program("train_step", self._train_step, args)
             (self.params, self.opt_state, self.bn_state, loss, mets) = \
-                self._train_step(*args)
+                self._first_call("train_step", self._train_step, args)
         self._step_count += 1
         self._last_loss = loss
         self._last_metrics = mets
@@ -967,7 +1040,7 @@ class FFModel:
                 start, n_steps)
         self._register_program("train_scan", self._train_scan, args)
         (self.params, self.opt_state, self.bn_state, losses, mets) = \
-            self._train_scan(*args)
+            self._first_call("train_scan", self._train_scan, args)
         for dl in self._dataloaders:  # keep per-step verbs in sync
             dl.next_index = ((start + n_steps) % nb) * dl.batch_size
         self._step_count += n_steps
@@ -1076,7 +1149,6 @@ class FFModel:
         # resilience.py, and step wall time feeds an SLO histogram —
         # one exported trace shows the overlap schedule end to end
         from flexflow_tpu.runtime import flightrec as _flightrec
-        from flexflow_tpu.runtime import telemetry as _telemetry
 
         tm_on = getattr(self.config, "telemetry", "on") != "off"
         # unconditional: configure() is how telemetry="off" reaches the
@@ -1410,26 +1482,11 @@ class FFModel:
             cb.on_train_end()
         return self._perf
 
-    def step_breakdown(self, batch: Optional[Dict[str, np.ndarray]] = None,
-                       iters: int = 3) -> Dict[str, float]:
-        """Per-step compute/collective/epilogue breakdown of the compiled
-        train step (runtime/profiler.py step_phase_breakdown): measured
-        full-step and optimizer-epilogue wall time, plus the production
-        program's collective instruction count/bytes — the observability
-        for the in-graph overlap work (is the epilogue actually
-        shrinking?). Merges into ``last_step_breakdown`` alongside fit()'s
-        host-side numbers and returns the merged dict."""
-        from flexflow_tpu.runtime.profiler import step_phase_breakdown
-
-        rows = step_phase_breakdown(self, batch=batch, iters=iters)
-        merged = dict(self.last_step_breakdown or {})
-        merged.update(rows)
-        self.last_step_breakdown = merged
-        return merged
-
     def evaluate(self, batch: Dict[str, np.ndarray]):
         sharded = self.executor.shard_batch(batch)
-        loss, mets, logits = self._eval_step(self.params, self.bn_state, sharded)
+        loss, mets, logits = self._first_call(
+            "eval_step", self._eval_step,
+            (self.params, self.bn_state, sharded), _batch_shapes(sharded))
         loss = float(loss)
         if not np.isfinite(loss):
             # eval already syncs the loss to host — a free divergence
@@ -1451,7 +1508,9 @@ class FFModel:
             self._predict_fn = fwd if getattr(
                 self.executor, "jits_per_group", False) else jax.jit(fwd)
         sharded = self.executor.shard_batch(batch)
-        return self._predict_fn(self.params, self.bn_state, sharded)[0]
+        return self._first_call(
+            "predict", self._predict_fn,
+            (self.params, self.bn_state, sharded), _batch_shapes(sharded))[0]
 
     def generate(self, tokens, max_new_tokens: int, temperature: float = 0.0,
                  top_k: int = 0, eos_token_id=None, pad_token_id: int = 0,
@@ -1518,15 +1577,11 @@ class FFModel:
     def _hbm_source(self):
         """HBM-ledger row (runtime/flightrec.py): what this model's
         training state holds on device, per subsystem."""
-        def _nbytes(tree):
-            return sum(int(getattr(a, "nbytes", 0))
-                       for a in jax.tree_util.tree_leaves(tree))
-
-        subs = {"params": _nbytes(self.params)}
+        subs = {"params": _tree_nbytes(self.params)}
         if self.opt_state is not None:
-            subs["opt_state"] = _nbytes(self.opt_state)
+            subs["opt_state"] = _tree_nbytes(self.opt_state)
         if self.bn_state:
-            subs["bn_state"] = _nbytes(self.bn_state)
+            subs["bn_state"] = _tree_nbytes(self.bn_state)
         return (self._hbm_name, subs)
 
     def dump_flight_record(self, directory: Optional[str] = None,
@@ -1583,7 +1638,13 @@ class FFModel:
         ServingEngine)."""
         from flexflow_tpu.runtime.serving import ServingEngine
 
-        return ServingEngine(self, **kwargs)
+        # to a usable engine: the pool arrays, the weight casts, an
+        # adapter pool's writer (its `compile` span nests here)
+        with self._setup_span("engine_build") as span:
+            eng = ServingEngine(self, **kwargs)
+            span.annotate(slots=eng.slots, pages=eng.num_pages,
+                          pool_bytes=int(eng.stats()["kv_pool_bytes"]))
+        return eng
 
     def serve(self, prompts, max_new_tokens: int = 32, **kwargs):
         """One-shot continuous-batching serve: run `prompts` (list of 1-D

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import itertools
 import re
-import time
 import weakref
 from typing import Dict, List, Optional, Tuple
 
@@ -166,13 +165,15 @@ def scope_table(hlo_text: str, graph_ops) -> Dict[str, Tuple[str, str]]:
             if not phase and _MOSAIC in body:
                 phase = kernel_phase.get(op, "")
             out[name] = (op, phase)
-    names = {name for name, _, _ in rows}
+    # the links of a chain: scope-less, and no source; a scoped instruction
+    # stays reachable as an operand wherever it stands in the text
+    names = {name for name, _, body in rows
+             if name not in out and not _NO_CHAIN.search(body)}
     bare, users = [], {}
     for name, _, body in rows:
-        if name in out or _NO_CHAIN.search(body):
-            names.discard(name)     # neither inherits nor hands on
-            continue
-        bare.append((name, [o for o in _operands(body) if o in names]))
+        if name in names:
+            bare.append((name, [o for o in _operands(body)
+                                if o in names or o in out]))
     for name, _, body in rows:          # who reads a scope-less instruction
         for o in _operands(body):
             if o not in out:
@@ -382,85 +383,6 @@ def hlo_collective_stats(hlo_text: str) -> Dict[str, float]:
     for kind, n in per_kind.items():
         out[f"collective_{kind.replace('-', '_')}"] = n
     return out
-
-
-def step_phase_breakdown(model, batch: Optional[Dict] = None,
-                         iters: int = 3) -> Dict[str, float]:
-    """Per-step compute/collective/epilogue breakdown of the train step —
-    the observability for the in-graph overlap work (ROADMAP item 4):
-
-      * ``device_step_ms`` — measured wall of the full fused step, run
-        through an UNDONATED re-jit of the production step body (the
-        model's own params/opt-state are never consumed, so this is safe
-        to call mid-training);
-      * ``epilogue_ms`` / ``epilogue_fraction`` — measured wall of the
-        optimizer update alone (zero gradients; elementwise update time
-        is value-independent): the scan epilogue that bucketed grad sync
-        + the ZeRO-1 sharded update shrink;
-      * ``collective_instructions`` / ``collective_bytes`` (+ per-kind
-        counts) — optimized-HLO collective ops of the PRODUCTION compiled
-        program, so an overlap regression (all-reduce where a
-        reduce-scatter should be) is visible without tracing;
-      * ``grad_sync_overlapped`` — whether FFConfig.overlap_grad_sync was
-        compiled in.
-
-    Surfaced through ``FFModel.step_breakdown`` which merges the result
-    into ``model.last_step_breakdown`` alongside fit()'s host-side
-    numbers."""
-    import jax.numpy as jnp
-
-    ex = model.executor
-    if getattr(ex, "jits_per_group", False):
-        raise RuntimeError(
-            "step_phase_breakdown needs the single-program executor "
-            "(operator-placement strategies jit per sub-mesh group)")
-    if model._train_step is None or model.optimizer is None:
-        raise RuntimeError("compile() with an optimizer first")
-    if batch is None:
-        batch = model._current_batch or model._stage_batch()
-    sharded = ex.shard_batch(batch)
-    rng = jax.random.PRNGKey(0)
-
-    # full step, re-jitted WITHOUT donation so the timing loop can feed
-    # the same (still-live) arguments every iteration
-    body = ex._train_step_body(model.optimizer, model.loss_type,
-                               model.metric_types, model._loss_tensor)
-    step = jax.jit(body)
-    args = (model.params, model.opt_state, model.bn_state, sharded, rng)
-    jax.block_until_ready(step(*args))  # compile + warm
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = step(*args)
-    jax.block_until_ready(out)
-    device_step_ms = (time.perf_counter() - t0) / iters * 1e3
-
-    # epilogue: the optimizer update alone (what the serial scan epilogue
-    # pays after the last microbatch's backward)
-    zeros_g = jax.tree_util.tree_map(jnp.zeros_like, model.params)
-    upd = jax.jit(model.optimizer.update)
-    jax.block_until_ready(upd(model.params, zeros_g, model.opt_state))
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        res = upd(model.params, zeros_g, model.opt_state)
-    jax.block_until_ready(res)
-    epilogue_ms = (time.perf_counter() - t0) / iters * 1e3
-
-    rows: Dict[str, float] = {
-        "device_step_ms": round(device_step_ms, 4),
-        "epilogue_ms": round(epilogue_ms, 4),
-        "compute_ms": round(max(device_step_ms - epilogue_ms, 0.0), 4),
-        "epilogue_fraction": round(
-            min(epilogue_ms / max(device_step_ms, 1e-9), 1.0), 4),
-        "grad_sync_overlapped": bool(
-            getattr(model.config, "overlap_grad_sync", False)),
-    }
-    try:
-        txt = model._train_step.lower(*args).compile().as_text()
-        rows.update(hlo_collective_stats(txt))
-    except Exception:  # pragma: no cover — HLO text is best-effort
-        rows.update({"collective_instructions": -1,
-                     "collective_bytes": -1.0})
-    return rows
 
 
 def export_taskgraph(model, filename: str):

@@ -137,8 +137,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from flexflow_tpu._env import (compilation_cache_dir,
-                               compilation_cache_entries)
 from flexflow_tpu.logger import fflogger
 from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import (faultinject, flightrec, locks, profiler,
@@ -1249,11 +1247,14 @@ class ServingEngine:
     # ---- compiled programs --------------------------------------------------
 
     def _compiled_call(self, key, build, *args):
-        """Program-cache lookup; a miss builds + runs the program and
-        bumps recompile_count, logging whether jax's persistent
-        compilation cache (placed by _env.resolve_compilation_cache or
-        JAX_COMPILATION_CACHE_DIR) absorbed the compile. Every shape-affecting datum is part of `key`, so this
-        counter is exactly the number of XLA compiles the engine caused."""
+        """Program-cache lookup; a miss builds + runs the program under a
+        ``compile`` span and bumps recompile_count, logging whether jax's
+        persistent compilation cache (placed by
+        _env.resolve_compilation_cache or JAX_COMPILATION_CACHE_DIR)
+        absorbed the compile: jax's own cache events, booked to the span
+        (runtime/telemetry.py). Every shape-affecting datum is part of
+        `key`, so this counter is exactly the number of XLA compiles the
+        engine caused."""
         fn = self._programs.get(key)
         if profiler.tracing() and key in self._registered:
             # a traced slice's tables are read after the window
@@ -1270,22 +1271,26 @@ class ServingEngine:
         # nothing is lowered until profiler.program_scopes() asks
         self._registered[key] = profiler.register_program(
             program_name(key), fn, args, self._graph_ops)
-        cache_dir = compilation_cache_dir()
-        before = compilation_cache_entries(cache_dir) if cache_dir else 0
         t0 = time.perf_counter()
         with self._span("compile", key=str(key),
-                        program=program_name(key)):
+                        program=program_name(key)) as span:
             out = fn(*args)
             with self._span("compile_fetch"):
                 jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        if cache_dir:
-            grew = compilation_cache_entries(cache_dir) - before
-            fflogger.info(
-                "serving: compiled %r in %.2fs — persistent cache %s",
-                key, dt, f"MISS (+{grew} entries)" if grew > 0 else "HIT")
-        else:
-            fflogger.info("serving: compiled %r in %.2fs", key, dt)
+            # what jax said of its persistent cache while this span was
+            # the innermost open (telemetry.JAX_COUNTS; nothing is booked
+            # under telemetry="off")
+            counts = getattr(span, "args", {})
+            asked = counts.get("cache_requests", 0)
+            missed = asked - counts.get("cache_hits", 0)
+            cache = "unobserved" if not asked else "miss" if missed \
+                else "hit"
+            span.annotate(cache=cache)
+        fflogger.info(
+            "serving: compiled %r in %.2fs — persistent cache %s", key,
+            time.perf_counter() - t0,
+            f"MISS ({missed} of {asked} programs compiled)" if missed
+            else cache.upper())
         return out
 
     @staticmethod
@@ -2405,46 +2410,48 @@ class ServingEngine:
             raise ValueError(
                 f"bucketed prompt ({bucket}) exceeds max_seq_len "
                 f"{self.max_seq_len}")
-        with self._lock:
-            apage = 0
-            if adapter is not None:
-                if self.lora is None or adapter not in self.lora.registry:
-                    raise ValueError(
-                        f"adapter {adapter!r} is not registered on this "
-                        f"engine")
-                got = self.lora.checkout(adapter)
-                if got is None:
-                    return None     # adapter-pool pressure: fall back
-                apage, ent = got
-                if ent is not None:
-                    self._write_adapter_page(apage, ent["payload"],
-                                             ent["scale"])
-            try:
-                # the checkout pins the adapter only for the duration of
-                # the prefill (no slot holds it afterwards)
-                ns = self._cache_ns(adapter)
-                last = prompt.size // self.page_size  # publishable pages
-                lease = self.kv.reserve(
-                    prompt, ns, (prompt.size - 1) // self.page_size,
-                    math.ceil(bucket / self.page_size), hold=False)
-                if lease is None:
-                    return None
-                if not lease.need:
-                    return last             # already fully published
-                self.kv.commit(lease)
-                # no slot is held: -1 seats the state nowhere
-                _, ok, *_ = self._run_prefill(
-                    prompt, bucket, lease, self._sampling_args_greedy(),
-                    apage, np.float32(0.0), slot=-1)
-                ok = bool(np.asarray(ok)[0])
-                self.kv.publish(lease, prompt, ns, ok)
-                if not ok:
-                    return None
-                self._prefill_only += 1
-                return last
-            finally:
+        with self._span("prefill_into_cache", prompts=1,
+                        prompt_tokens=int(prompt.size), tokens=0):
+            with self._lock:
+                apage = 0
                 if adapter is not None:
-                    self.lora.release(adapter)
+                    if self.lora is None or adapter not in self.lora.registry:
+                        raise ValueError(
+                            f"adapter {adapter!r} is not registered on this "
+                            f"engine")
+                    got = self.lora.checkout(adapter)
+                    if got is None:
+                        return None     # adapter-pool pressure: fall back
+                    apage, ent = got
+                    if ent is not None:
+                        self._write_adapter_page(apage, ent["payload"],
+                                                 ent["scale"])
+                try:
+                    # the checkout pins the adapter only for the duration of
+                    # the prefill (no slot holds it afterwards)
+                    ns = self._cache_ns(adapter)
+                    last = prompt.size // self.page_size  # publishable pages
+                    lease = self.kv.reserve(
+                        prompt, ns, (prompt.size - 1) // self.page_size,
+                        math.ceil(bucket / self.page_size), hold=False)
+                    if lease is None:
+                        return None
+                    if not lease.need:
+                        return last             # already fully published
+                    self.kv.commit(lease)
+                    # no slot is held: -1 seats the state nowhere
+                    _, ok, *_ = self._run_prefill(
+                        prompt, bucket, lease, self._sampling_args_greedy(),
+                        apage, np.float32(0.0), slot=-1)
+                    ok = bool(np.asarray(ok)[0])
+                    self.kv.publish(lease, prompt, ns, ok)
+                    if not ok:
+                        return None
+                    self._prefill_only += 1
+                    return last
+                finally:
+                    if adapter is not None:
+                        self.lora.release(adapter)
 
     def export_prefix_slab(self, prompt,
                            adapter: Optional[str] = None,
@@ -3025,15 +3032,22 @@ class ServingEngine:
         in submission order (with prompts=None: whatever was pending at
         entry). Extra kwargs (temperature/top_p/top_k/seed/adapter)
         forward to submit(). The engine holds no reference to retired
-        requests."""
-        if prompts is not None:
-            batch = [self.submit(p, max_new_tokens, **submit_kw)
-                     for p in prompts]
-        else:
-            batch = [r for r in self.slot_req if r is not None] \
-                + list(self._queue)
-        while self.step():
-            pass
+        requests. The whole call is one ``run`` span (how a caller warms
+        an engine: the ``compile`` spans of the programs it reaches nest
+        in it)."""
+        with self._span("run") as span:
+            if prompts is not None:
+                batch = [self.submit(p, max_new_tokens, **submit_kw)
+                         for p in prompts]
+            else:
+                batch = [r for r in self.slot_req if r is not None] \
+                    + list(self._queue)
+            while self.step():
+                pass
+            span.annotate(prompts=len(batch),
+                          prompt_tokens=sum(int(r.prompt.size)
+                                            for r in batch),
+                          tokens=sum(len(r.tokens) for r in batch))
         return batch
 
     # ---- graceful shutdown --------------------------------------------------

@@ -11,7 +11,7 @@ tracing a first-class subsystem because distributed dataflow is
 undebuggable without it; this module is that subsystem for both the
 serving fleet and the training loop.
 
-Three pieces, one process-wide substrate:
+Four pieces, one process-wide substrate:
 
   * **Metrics registry** — thread-safe counters, gauges and fixed-memory
     log-bucket histograms with labeled series (replica, role, tier,
@@ -43,6 +43,15 @@ Three pieces, one process-wide substrate:
     Export as Chrome trace-event JSON (``export_chrome_trace`` —
     perfetto-loadable: pid = replica/subsystem track, tid = thread).
 
+  * **Set-up** — the spans named in ``LIFECYCLE_SPANS`` (a model's
+    ``compile()``, an engine's build, each program's first call, the
+    engine's blocking ``run()`` / ``prefill_into_cache()``) are kept in a
+    deque of their own, so a saturated window's ticks never push a cold
+    start off the ring; jax's own compile durations (trace, lower,
+    backend compile, persistent-cache load and hit) are booked to the
+    innermost of them open on the compiling thread (``_on_jax_duration``),
+    or to the process totals ``setup_totals()`` where none is.
+
   * **Fault annotations** — ``runtime/faultinject.py`` reports every
     fired FF_FAULT event here (``annotate("fault", ...)``), so a fault
     drill's trace shows exactly where the fault landed
@@ -59,13 +68,16 @@ from __future__ import annotations
 
 import bisect
 import collections
+import heapq
 import json
 import math
+import operator
 import threading
 import time
 import weakref
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from flexflow_tpu.runtime import locks
@@ -75,7 +87,8 @@ __all__ = [
     "registry", "tracer", "reset", "set_enabled", "enabled",
     "annotate", "fault_events", "export_chrome_trace", "trace_tree",
     "start_http_server", "stop_http_server", "current_trace_id",
-    "DEFAULT_LATENCY_BOUNDS", "log_bounds", "now_us", "bucket_quantile",
+    "DEFAULT_LATENCY_BOUNDS", "log_bounds", "now_us", "to_us",
+    "bucket_quantile", "LIFECYCLE_SPANS", "JAX_COUNTS", "setup_totals",
 ]
 
 # ---------------------------------------------------------------- switch
@@ -450,7 +463,14 @@ def now_us() -> float:
     return _now_us()
 
 
+def to_us(t_s: float) -> float:
+    """A ``perf_counter()`` instant (a request's ``t_submit``) on the
+    ring's ``ts`` clock."""
+    return (t_s - _EPOCH) * 1e6
+
+
 _tls = threading.local()
+_event_ts = operator.itemgetter("ts")
 
 
 def current_trace_id() -> Optional[str]:
@@ -480,6 +500,98 @@ NULL_SPAN = _NullSpan()
 
 # a span's name in a profiler trace is its ring name with this in front
 PROFILER_PREFIX = "ff."
+
+
+# ---- set-up: lifecycle spans and jax's own compile durations -------------
+
+# The phases of a cold start, one span a phase or a program and never one
+# a weight, a layer or a tick (docs/observability.md "Set-up"). An event
+# of one of these names is kept apart from the ring (Tracer._life), and a
+# span of one collects what jax reports while it is the innermost open.
+LIFECYCLE_SPANS = frozenset({
+    "model_compile", "strategy_search", "init_params", "init_optimizer",
+    "engine_build", "compile", "run", "prefill_into_cache"})
+LIFECYCLE_CAP = 2048        # events; a process's set-up writes tens
+
+# jax.monitoring's names (jax/_src/dispatch.py, compiler.py) -> the count
+# each is booked under. They fire where a program compiles and nowhere
+# else: a warm tick or step pays nothing.
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+}
+JAX_COUNTS = tuple(_JAX_DURATIONS.values()) + tuple(_JAX_EVENTS.values())
+_JAX_DONE_CAP = 4096        # reported intervals remembered a thread
+
+_listening = False
+
+
+def _listen():
+    """Register the one listener pair, once a process, when the first
+    lifecycle span opens: a process whose telemetry is off from the start
+    (``FFConfig.telemetry="off"``: no call site opens a span) never does.
+    jax keeps a listener for good; ``set_enabled(False)`` turns both into
+    one predicate."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def _book(count: str, value):
+    """Onto the innermost lifecycle span open on this thread, else onto
+    the process totals ``unspanned_<count>``."""
+    stack = getattr(_tls, "lifecycle", None)
+    if stack:
+        args = stack[-1].args
+        args[count] = args.get(count, 0) + value
+    else:
+        _tracer.book_unspanned(count, value)
+
+
+def _on_jax_duration(event, duration, **kw):
+    """One of jax's four compile durations. ``cache_load_s`` is booked as
+    it comes (jax clocks the load inside the backend compile it replaces,
+    so ``backend_s`` holds it too). The other three are booked by OWN
+    time, so that a second is counted once: jax reports a duration when
+    its interval ENDS, so an interval that lies inside another (a jitted
+    function traced while its caller is traced, a kernel traced while a
+    program is lowered) has been booked when the outer one arrives, and
+    the outer one is booked less what it holds. The thread's reported
+    intervals ``(start, length)`` stand in order of start, so what the
+    new one holds is the tail that starts no earlier than it does."""
+    if not _enabled:
+        return
+    count = _JAX_DURATIONS.get(event)
+    if count is None:
+        return
+    own = duration
+    if count != "cache_load_s":
+        done = getattr(_tls, "jax_done", None)
+        if done is None:
+            done = _tls.jax_done = collections.deque(maxlen=_JAX_DONE_CAP)
+        start = time.time() - duration      # jax's own clock
+        while done and done[-1][0] >= start:
+            own -= done.pop()[1]
+        done.append((start, duration))
+    _book(count, max(own, 0.0))
+
+
+def _on_jax_event(event, **kw):
+    if not _enabled:
+        return
+    count = _JAX_EVENTS.get(event)
+    if count is not None:
+        _book(count, 1)
 
 
 class _Span:
@@ -537,6 +649,33 @@ class _Span:
         return False
 
 
+class _LifecycleSpan(_Span):
+    """A span of ``LIFECYCLE_SPANS``: while it is the innermost of them
+    open on its thread, jax's compile durations and cache events are added
+    to its counts (``JAX_COUNTS``), which leave with it for the ring event
+    and the annotation's stats."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        super().__enter__()
+        stack = getattr(_tls, "lifecycle", None)
+        if stack is None:
+            stack = _tls.lifecycle = []
+        stack.append(self)
+        return self
+
+    def __exit__(self, etype, evalue, tb):
+        stack = getattr(_tls, "lifecycle", None)
+        if stack and stack[-1] is self:
+            stack.pop()
+        booked = {k: round(self.args[k], 6) for k in JAX_COUNTS
+                  if k in self.args}
+        if booked:
+            self.annotate(**booked)
+        return super().__exit__(etype, evalue, tb)
+
+
 class Tracer:
     """Bounded-ring trace recorder. Events are plain dicts in Chrome
     trace-event shape: ``ph`` "X" (complete, with ``dur``) or "i"
@@ -547,6 +686,12 @@ class Tracer:
     def __init__(self, cap: int = TRACE_RING_CAP):
         self._lock = locks.make_lock("telemetry-tracer")
         self._ring: collections.deque = collections.deque(maxlen=cap)
+        # the LIFECYCLE_SPANS events, which a window's ticks must not evict
+        self._life: collections.deque = collections.deque(
+            maxlen=LIFECYCLE_CAP)
+        # what jax reported under no lifecycle span (never evicted)
+        self._unspanned = dict.fromkeys(
+            ("unspanned_" + c for c in JAX_COUNTS), 0)
         self._open: Dict[int, Dict] = {}    # begin() handles awaiting end()
         self._next_handle = 0
 
@@ -564,8 +709,21 @@ class Tracer:
         if a:
             ev["args"] = a
         with self._lock:
-            self._ring.append(ev)
+            (self._life if name in LIFECYCLE_SPANS
+             else self._ring).append(ev)
         return ev
+
+    def book_unspanned(self, count: str, value):
+        with self._lock:
+            self._unspanned["unspanned_" + count] += value
+
+    def setup_totals(self) -> Dict[str, float]:
+        """What jax reported while NO lifecycle span was open on the
+        compiling thread, ``unspanned_<count>`` for each of ``JAX_COUNTS``:
+        the caller's own jits, and any program of this package that opens
+        no ``compile`` span (the totals are the guard for that)."""
+        with self._lock:
+            return dict(self._unspanned)
 
     def span(self, name: str, trace_id: Optional[str] = None,
              track: Optional[str] = None, **args):
@@ -577,6 +735,10 @@ class Tracer:
             return NULL_SPAN
         if trace_id is None:
             trace_id = current_trace_id()
+        if name in LIFECYCLE_SPANS:
+            if not _listening:
+                _listen()
+            return _LifecycleSpan(self, name, trace_id, track, args)
         return _Span(self, name, trace_id, track, args)
 
     def begin(self, name: str, trace_id: Optional[str] = None,
@@ -646,9 +808,13 @@ class Tracer:
 
     def events(self, name: Optional[str] = None,
                trace_id: Optional[str] = None) -> List[Dict]:
-        """Ring contents (oldest first), optionally filtered."""
+        """Ring contents (oldest first) with the lifecycle events, which
+        outlive the ring, merged in by ``ts``; optionally filtered."""
         with self._lock:
-            evs = list(self._ring)
+            evs, life = list(self._ring), list(self._life)
+        if life:
+            life.sort(key=_event_ts)    # a parent before its children
+            evs = list(heapq.merge(life, evs, key=_event_ts))
         if name is not None:
             evs = [e for e in evs if e["name"] == name]
         if trace_id is not None:
@@ -689,11 +855,14 @@ class Tracer:
     def reset(self):
         with self._lock:
             self._ring.clear()
+            self._life.clear()
             self._open.clear()
+            for k in self._unspanned:
+                self._unspanned[k] = 0
 
     def __len__(self):
         with self._lock:
-            return len(self._ring)
+            return len(self._ring) + len(self._life)
 
 
 def _tree_complete(root, spans) -> bool:
@@ -739,6 +908,10 @@ def reset():
 
 def trace_tree(trace_id: str) -> Dict:
     return _tracer.trace_tree(trace_id)
+
+
+def setup_totals() -> Dict[str, float]:
+    return _tracer.setup_totals()
 
 
 # ------------------------------------------------------------ fault marks

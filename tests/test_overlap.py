@@ -304,6 +304,12 @@ def test_sync_fit_has_single_warmup_barrier(monkeypatch):
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: calls.append(1) or orig(x))
     ff.fit(verbose=False)
+    # (the step program's FIRST call is a `compile` span that ends when its
+    # outputs are ready: one barrier a program's life, telemetry on)
+    assert len(calls) == 3, \
+        f"expected first call + warm + final barriers only, saw {len(calls)}"
+    del calls[:]
+    ff.fit(verbose=False)
     assert len(calls) == 2, \
         f"expected warm + final barriers only, saw {len(calls)}"
 

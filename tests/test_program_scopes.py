@@ -102,6 +102,7 @@ ENTRY %main.9 (p: f32[8]) -> f32[8] {
   %dot.4 = f32[8]{0} dot(%p, %p), metadata={op_name="jit(step)/jit(main)/transpose(jvp(attn_3))/project/dot_general"}
   %sort.12 = f32[8]{0} sort(%p), metadata={op_name="jit(decode)/jit(main)/while/body/sampler/sort"}
   %sub.1 = f32[8]{0} subtract(%p, %p), metadata={op_name="jit(step)/jit(main)/optimizer/attn_3/sub"}
+  %copy.3 = f32[8]{0} copy(%sub.1)
   %copy.1 = f32[8]{0} copy(%p), metadata={op_name="jit(decode)/jit(main)/while/body/dynamic_update_slice"}
   ROOT %copy.2 = f32[8]{0} copy(%p)
 }
@@ -127,6 +128,8 @@ def test_scope_table_books_each_instruction_to_its_op_and_phase():
         "mul.3": ("ffn_up_1", ""),
         # what XLA made without a scope: computed FROM the sort (a chain)...
         "rw.1": ("sampler", ""), "copy.5": ("sampler", ""),
+        # ... also FROM an operand that stands EARLIER in the text
+        "copy.3": ("optimizer", ""),
         # ... or FOR the loop body's reader of that position of its state,
         # be it fed in front of the loop or by the turn before (a prefetch
         # of the next turn's weight)
@@ -234,17 +237,25 @@ def test_the_samplers_gate_and_both_its_branches_are_booked_to_the_sampler():
     # in) hold device ops of their own; what a fusion or a reduction
     # applies is part of that ONE op
     seen = hlo_text.reach(calls, branches, through=hlo_text.CONTROL_FLOW)
-    ops = collections.Counter()
+    ops, copies = collections.Counter(), 0
+    bodies = {name: body for ins in rows.values() for name, _, body in ins}
     for comp in seen:
         for name, _, body in rows[comp]:
             opcode = hlo_text.opcode(body)
-            # (but for the copies the CPU makes into the state of a loop it
-            # wraps in a call: they feed a tuple, which hands no scope on)
-            if opcode not in NO_DEVICE_OP \
-                    and not (opcode == "copy" and name not in table):
+            # (but for the copies of a constant the CPU makes into the state
+            # of a loop it wraps in a call, a counter's zero: a source is
+            # no link. The copy of a FUSION into that state, which stands
+            # after its operand in the text, is booked FROM it.)
+            if opcode == "copy" and name not in table:
+                source, = profiler._operands(body)
+                assert hlo_text.opcode(bodies[source]) == "constant", name
+                continue
+            copies += opcode == "copy"
+            if opcode not in NO_DEVICE_OP:
                 assert table.get(name, ("", ""))[0] == "sampler", (comp, name)
                 ops[opcode] += 1
     assert ops["sort"] == 1 and len(seen) > 2      # the draw's loops too
+    assert copies       # a scope-less copy in the branches, and booked
     # outside the gate and still the sampler's: the finite check
     finite = [n for n, p in traced(text) if p.endswith("/is_finite")]
     assert finite and all(table[n] == ("sampler", "") for n in finite)

@@ -221,6 +221,53 @@ def test_trace_ring_bounded():
     assert evs[0]["args"]["trace_id"] == "t436"    # oldest fell off
 
 
+def test_lifecycle_events_are_kept_apart_and_merged_by_ts():
+    """A span of LIFECYCLE_SPANS does not ride the ring: the ring's cap
+    evicts ticks only, `events()` hands back one list with the lifecycle
+    events placed by `ts`, and `reset()` clears both."""
+    tr = Tracer(cap=8)
+    with tr.span("model_compile", track="setup"):       # parent: first by ts
+        with tr.span("init_params", track="setup"):
+            pass
+        tr.instant("mark")
+    for i in range(50):
+        tr.instant("tick", trace_id=f"t{i}")
+    with tr.span("run", track="replica0"):
+        pass
+    names = [e["name"] for e in tr.events()]
+    assert names == ["model_compile", "init_params"] + ["tick"] * 8 + ["run"]
+    assert len(tr) == 11
+    assert [e["name"] for e in tr.events(name="init_params")] \
+        == ["init_params"]
+    stamps = [e["ts"] for e in tr.events()]
+    assert stamps == sorted(stamps)
+    tr.reset()
+    assert len(tr) == 0 and tr.events() == []
+
+
+@pytest.mark.parametrize("name,lifecycle", [
+    ("model_compile", True), ("strategy_search", True),
+    ("init_params", True), ("init_optimizer", True), ("engine_build", True),
+    ("compile", True), ("run", True), ("prefill_into_cache", True),
+    ("compile_fetch", False), ("engine_step", False), ("prefill", False)])
+def test_which_spans_collect_jaxs_compile_counts(name, lifecycle):
+    """Only a lifecycle span is the target of jax's durations while it is
+    the innermost open; a tick's span neither collects nor hides one."""
+    tr = Tracer()
+    with tr.span("engine_build", track="setup") as outer:
+        with tr.span(name, track="setup") as sp:
+            telemetry._on_jax_duration(
+                "/jax/core/compile/backend_compile_duration", 0.25)
+            telemetry._on_jax_event("/jax/compilation_cache/cache_hits")
+            telemetry._on_jax_event("/jax/not/ours")
+        inner, outer_args = dict(sp.args), dict(outer.args)
+    took = inner if lifecycle else outer_args
+    assert took["backend_s"] == 0.25 and took["cache_hits"] == 1
+    assert not (outer_args if lifecycle else inner)
+    ev = tr.events(name=name)[-1]       # the inner one began later
+    assert ("backend_s" in ev.get("args", {})) == lifecycle
+
+
 def test_span_nesting_thread_local_and_tree():
     tr = Tracer()
     with tr.span("root", trace_id="tX", track="a"):
@@ -448,6 +495,39 @@ def test_engine_emits_histograms_and_spans(ff):
     assert ('ff_serving_completed{replica="t0",role="solo-test"}'
             in text)
     assert 'ff_serving_ttft_seconds_bucket{replica="t0"' in text
+
+
+def test_engine_compile_says_hit_or_miss_from_jaxs_own_events(ff, caplog):
+    """`_compiled_call` lists no cache directory: what its span and its log
+    line say of the persistent cache is what jax reported while the span
+    was the innermost open."""
+    import logging
+
+    from flexflow_tpu.runtime import serving
+
+    assert not hasattr(serving, "compilation_cache_entries")
+    assert not hasattr(serving, "compilation_cache_dir")
+    telemetry.reset()
+    eng = ff.make_serving_engine(max_seq_len=32, kv_page_size=8)
+    from flexflow_tpu.logger import fflogger
+
+    fflogger.addHandler(caplog.handler)     # it does not propagate to root
+    try:
+        with caplog.at_level(logging.INFO, logger="flexflow_tpu"):
+            eng.run(_prompts(31, [5]), max_new_tokens=2)
+    finally:
+        fflogger.removeHandler(caplog.handler)
+    spans = telemetry.tracer().events(name="compile")
+    assert len(spans) == eng.recompile_count >= 2
+    lines = [r.getMessage() for r in caplog.records
+             if "serving: compiled" in r.getMessage()]
+    assert len(lines) == len(spans)
+    for ev, line in zip(spans, lines):
+        a = ev["args"]
+        # the suite runs without a persistent cache: jax asked, nothing hit
+        assert a["cache"] == "miss" and a.get("cache_hits", 0) == 0
+        assert "persistent cache MISS" in line
+        assert f"{a['cache_requests']} of {a['cache_requests']}" in line
 
 
 def test_engine_prefix_hit_span_kind(ff):
@@ -859,8 +939,9 @@ def test_ring_and_profiler_hold_the_same_tick_spans(profiled):
     assert ring_only <= set(ring)
     assert sorted("ff." + n for n in ring if n not in ring_only) \
         == sorted(plane)
-    assert {"ff.engine_step", "ff.admit", "ff.prefill", "ff.prefill_fetch",
-            "ff.decode_chunk", "ff.slo_tick"} \
+    # (`run` is the blocking call's own lifecycle span, around its ticks)
+    assert {"ff.run", "ff.engine_step", "ff.admit", "ff.prefill",
+            "ff.prefill_fetch", "ff.decode_chunk", "ff.slo_tick"} \
         | {"ff." + n for n in DECODE_PHASES} == set(plane)
     # the host blocked on the device only under a *_fetch name, and the
     # dispatch's counts reached the profiler as stats
