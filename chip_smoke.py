@@ -314,6 +314,68 @@ def kernel_cases(z: Sizes):
                 paged_args(s, store), paged("pallas"), paged("einsum"),
                 BF16_TOL))
 
+    # the paged kernel's shared-page form (a page several slots hold,
+    # streamed once a group) at the pools of the two document cells:
+    # MiMo-V2's flat row a token (4 KV heads, keys of 192 beside values of
+    # 128, with a sink) and granite's two heads of 64 a row. Eight slots: a
+    # document three hold, one two hold and a third held by the three the
+    # cap leaves no room for elsewhere, one slot alone, one idle
+    from flexflow_tpu.ops.attention import MultiHeadAttention
+    from flexflow_tpu.runtime.kv_pool import shared_page_groups
+
+    def doc_op(name, heads, kv, dqk, dv, sink):
+        return MultiHeadAttention(
+            graph, name, [x, x, x], z.hidden, heads, kdim=heads * dqk,
+            vdim=heads * dv, bias=False, causal=True, num_kv_heads=kv,
+            sink=sink)
+
+    def shared_args(op, cap):
+        def make(rs):
+            b, doc = 8, max(2, pps // 2)
+            width = doc + 3
+            cache = op.init_paged_cache(1 + b * width, ps, bf16)
+            pool = [jnp.asarray(rs.randn(*a.shape) * 0.5, bf16)
+                    for a in (cache["k"], cache["v"])]
+            table = rs.permutation(np.arange(1, 1 + b * width)).reshape(
+                b, width).astype(np.int32)
+            for holders in ((0, 2, 5), (1, 4), (3,)):
+                table[list(holders), :doc] = table[holders[0], :doc]
+            table[7] = 0
+            row_len = np.asarray([doc * ps + 3 + 5 * i for i in range(7)]
+                                 + [0], np.int32)
+            pad = np.where(row_len > 0, doc * ps + ps // 2, 0).astype(
+                np.int32)
+            wp = np.where(row_len > 0, pad + 1 + np.arange(b) * (ps // 4),
+                          0).astype(np.int32)[:, None]
+            groups = shared_page_groups(table, row_len // ps, cap)
+            assert sorted(groups) == [([0, 2, 5], doc), ([1, 4], doc)], groups
+            q = jnp.asarray(
+                rs.randn(b, 1, op.num_heads, op.qk_head_dim) * 0.5, bf16)
+            return (q, *pool, jnp.asarray(table), jnp.asarray(wp),
+                    jnp.asarray(row_len), jnp.asarray(pad),
+                    *(jnp.asarray(a) for a in pk.pack_shared_groups(
+                        groups, b, cap)),
+                    jnp.asarray(rs.randn(op.num_heads), jnp.float32))
+        return make
+
+    def shared(op, impl):
+        return lambda q, kp, vp, pt, wp, rl, pp, groups, slot_of, sink: \
+            op._paged_attention_ctx(
+                q, {"k": kp, "v": vp}, pt, wp, rl, pp, impl,
+                sink=sink if op.sink is not None else None,
+                shared=(groups, slot_of))
+
+    doc_shapes = [("mimo-flat", 64, 4, 192, 128, 1.0),
+                  ("granite-packed", 32, 8, 64, 64, None)] if z is FULL \
+        else [("flat", 8, 2, 192, 128, 1.0), ("packed", 8, 4, 64, 64, None)]
+    for label, heads, kv, dqk, dv, sink in doc_shapes:
+        op = doc_op(f"attn_{label}", heads, kv, dqk, dv, sink)
+        cases.append(KernelCase(
+            f"paged_attention shared pages {label} slots8 h{heads} kvh{kv} "
+            f"dqk{dqk} dv{dv} page{ps} bf16",
+            shared_args(op, min(op.shared_members_cap(), 8)),
+            shared(op, "pallas"), shared(op, "einsum"), BF16_TOL))
+
     # prefill page write: a whole-bucket slab, a slab whose tail pads its
     # last page, quantized pools
     def write_args(s, store):

@@ -950,8 +950,18 @@ class MultiHeadAttention(Op):
                 out[name] = x.reshape(1, -1, *x.shape[2:])
         return out
 
+    def shared_members_cap(self) -> int:
+        """Most slots one group of the paged kernel's shared-page form
+        scores against a page fetched once (ops/pallas_kernels.py
+        `shared_members_cap`): a static of the op's heads, for the engine
+        that forms the groups."""
+        from flexflow_tpu.ops.pallas_kernels import shared_members_cap
+
+        return shared_members_cap(self.num_heads)
+
     def _paged_attention_ctx(self, qh, cache, page_table, write_pos,
-                             row_len, prompt_pad, impl, sink=None):
+                             row_len, prompt_pad, impl, sink=None,
+                             shared=None):
         """Shared attention body of the paged decode/verify paths: q
         (B, S, H, Dh) against the updated pool through the per-slot page
         tables, write_pos (B, S) per-position frontiers. Two impls
@@ -972,7 +982,13 @@ class MultiHeadAttention(Op):
             scales — the wide KV never exists in HBM. Numerics match
             the einsum path to kernel tolerance (accumulation order
             differs); greedy token streams are pinned identical by
-            tests/test_pallas_paged.py and test_quantized_serving.py."""
+            tests/test_pallas_paged.py and test_quantized_serving.py.
+
+        ``shared`` (a decode step's; `pallas_kernels.pack_shared_groups`):
+        the groups of slots whose rows begin with the same pool pages. The
+        kernel streams such pages once a group on a pool at full width; a
+        quantized pool and the einsum oracle read every slot's pages for
+        the slot, as without it."""
         resolved = resolve_paged_attention_impl(impl)
         ck, cv = cache["k"], cache["v"]
         if resolved == "pallas":
@@ -988,7 +1004,8 @@ class MultiHeadAttention(Op):
                 qh, ck, cv, page_table, write_pos, row_len, prompt_pad,
                 scale, k_scales=cache.get("k_scale"),
                 v_scales=cache.get("v_scale"), sink=sink,
-                kv_heads=self.num_kv_heads)
+                kv_heads=self.num_kv_heads,
+                shared=None if "k_scale" in cache else shared)
         b = qh.shape[0]
         max_len = page_table.shape[1] * ck.shape[1]
         with jax.named_scope("gather"):
@@ -1009,7 +1026,8 @@ class MultiHeadAttention(Op):
             qh, gk, gv, live[:, None, None, :, :], sink=sink)
 
     def paged_decode_forward(self, params, xs, cache, page_table, write_pos,
-                             rope_pos, row_len, prompt_pad, impl=None):
+                             rope_pos, row_len, prompt_pad, impl=None,
+                             shared=None):
         """One continuous-batching decode step over the paged pool.
 
         xs[0]: (B_slots, 1, D) — each slot's last sampled token embedding
@@ -1026,7 +1044,9 @@ class MultiHeadAttention(Op):
         quantized-append protocol when the pool carries scales
         (_paged_append); attention then runs through
         _paged_attention_ctx — `impl` picks the page-gather einsum
-        oracle or the Pallas paged kernel."""
+        oracle or the Pallas paged kernel, `shared` names the slots whose
+        rows begin with the same pages (a window layer's ring is a slot's
+        own: it takes none)."""
         page_size = cache["k"].shape[1]
         qh, kh, vh = self._project_qkv(params, xs[0], xs[1], xs[2],
                                        rope_offset=rope_pos)
@@ -1045,7 +1065,8 @@ class MultiHeadAttention(Op):
         ctx = self._paged_attention_ctx(qh, cache, page_table,
                                         write_pos[:, None], row_len,
                                         prompt_pad, impl,
-                                        sink=self._sink_of(params))
+                                        sink=self._sink_of(params),
+                                        shared=shared)
         return self._out_proj(params, ctx), cache
 
     def _paged_window_decode(self, params, qh, kh, vh, cache, ring, pos,

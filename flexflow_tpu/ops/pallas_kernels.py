@@ -987,7 +987,7 @@ def _paged_ring(g: int, dtype, *pages) -> int:
 
 
 def _page_stream(pt_ref, last_ref, pools, bufs, sem, cur, nbuf, g, *,
-                 tail=False, first_ref=None, ring=None):
+                 tail=False, first_ref=None, ring=None, n_ref=None):
     """The hand-issued page stream of the pool arrays `pools` (HBM; K and V,
     or the index keys alone) that share one page table, a TURN at a time:
     (prime, take, fetch_next). A turn is a block of g consecutive logical
@@ -1002,8 +1002,10 @@ def _page_stream(pt_ref, last_ref, pools, bufs, sem, cur, nbuf, g, *,
     consumed; turn n of the stream lives in ring buffer n % nbuf (bufs[i]:
     (nbuf, g, *page)), its page copies signal that buffer's one semaphore a
     pool, and the cursor runs ahead across slots, so the DMA queue never
-    drains between them."""
-    nb = pl.num_programs(0)
+    drains between them. `n_ref` (SMEM, (1,)): the stream ends after that
+    many of the grid's steps (the shared-page stream's live groups, which
+    come first), not after all of them."""
+    nb = pl.num_programs(0) if n_ref is None else n_ref[0]
     first_of = (lambda s: 0) if first_ref is None else (lambda s: first_ref[s])
 
     def start(fs, fp, b, pages):
@@ -1070,7 +1072,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
                        s: int, h: int, kvh: int, ps: int, nbuf: int, g: int,
                        scale: float, quantized: bool = False,
                        window: Optional[int] = None, pack: int = 1,
-                       sink: bool = False, flat: bool = False):
+                       sink: bool = False, flat: bool = False,
+                       carry: bool = False):
     """One slot per grid step: score the slot's (S*H, Dqk) query rows
     against its live pages, a turn of g pages at a time (the pages past the
     last whole block one at a time), and fold each turn into the running
@@ -1113,16 +1116,31 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     With `sink` (static) one more input after the queries, the (S*H, 1)
     sink logit of each query row: the running maximum starts there and the
     denominator at exp(sink - max) = 1, so the sink takes its share and adds
-    no value; without it the kernel is the one it always was."""
-    fp_ref = None
-    if window is not None:
+    no value; without it the kernel is the one it always was.
+
+    With `carry` (static; the second half of the shared-page form,
+    `_paged_shared_kernel`) two more scalar-prefetch refs, each slot's first
+    page of its OWN (as a window layer's first page, on a table that is no
+    ring) and its row of its group's partials (read only for its sign: -1,
+    the slot is in no group), and three more inputs after the sink, the slot's
+    (H, 128) running maximum and denominator (a row's value in every lane)
+    and its (H, Dv) accumulator (of a flat pool the row's own KV head's Dv
+    lanes): a slot of a group starts its online softmax from them, which
+    IS the exact merge of the softmax over the shared pages with the softmax
+    over its own, and streams its own pages only."""
+    fp_ref = src_ref = None
+    if window is not None or carry:
         fp_ref, rest = rest[0], rest[1:]
+    if carry:
+        src_ref, rest = rest[0], rest[1:]
     ks_ref = vs_ref = sink_ref = None
     if quantized:
         ks_ref, vs_ref, rest = rest[0], rest[1], rest[2:]
     q_ref, rest = rest[0], rest[1:]
     if sink:
         sink_ref, rest = rest[0], rest[1:]
+    if carry:
+        m_ref, l_ref, acc_ref, rest = *rest[:3], rest[3:]
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, cur = rest
     b = pl.program_id(0)
     ring = pt_ref.shape[1] if window is not None else None
@@ -1175,8 +1193,8 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
         cols = pages * ps * kvh
         own_head, tok = columns(pages)
 
-        def body(t, carry):
-            m_prev, l_prev, acc = carry
+        def body(t, state):
+            m_prev, l_prev, acc = state
             fetch_next(jax.lax.rem(cur[2] + nbuf - 1, nbuf))
             buf = take(pages)
             # (pages, ps, KVH, D), or dense (pages, ps x KVH, D)
@@ -1208,21 +1226,32 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
 
         return body
 
-    first = 0 if window is None else fp_ref[b]
-    carry = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+    first = 0 if fp_ref is None else fp_ref[b]
+    state = (jnp.full((rows, 1), NEG_INF, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
              jnp.zeros((rows, v_buf.shape[-1]), jnp.float32))
     if sink_ref is not None:
-        carry = (sink_ref[...], jnp.ones((rows, 1), jnp.float32), carry[2])
+        state = (sink_ref[...], jnp.ones((rows, 1), jnp.float32), state[2])
+    if carry:
+        # a select, not arithmetic: the row of a slot in no group holds
+        # whatever an idle grid step of the shared stream left there
+        grouped, acc0 = src_ref[b] >= 0, acc_ref[0]
+        if flat:
+            # the group's stream kept the row's own KV head's lanes alone
+            acc0 = jnp.concatenate(
+                [jnp.where((row % h) // grp == i, acc0, 0.0)
+                 for i in range(heads_kv)], axis=1)
+        state = tuple(jnp.where(grouped, x, x0) for x, x0 in zip(
+            (m_ref[0][:, 0:1], l_ref[0][:, 0:1], acc0), state))
     if g > 1:
         block, blocks = turn(g), (lp_ref[b] + 1 - first) // g
-        carry = jax.lax.fori_loop(
-            0, blocks, lambda i, c, t0=first: block(t0 + i * g, c), carry)
+        state = jax.lax.fori_loop(
+            0, blocks, lambda i, c, t0=first: block(t0 + i * g, c), state)
         first += blocks * g
     # at g = 1 every live page; else the pages past the slot's last whole
     # block, one a turn: a dead page is never fetched (its 0 x NaN in p v
     # would be NaN)
-    _, l_fin, acc = jax.lax.fori_loop(first, lp_ref[b] + 1, turn(1), carry)
+    _, l_fin, acc = jax.lax.fori_loop(first, lp_ref[b] + 1, turn(1), state)
     # every row has >= 1 live position (its own write frontier:
     # prompt_pad <= write_pos always holds, and the inactive-slot zeros
     # satisfy j == 0 <= write_pos == 0), so l > 0 — no guard. A turn in
@@ -1240,11 +1269,335 @@ def _paged_attn_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, *rest,
     o_ref[0] = out.astype(o_ref.dtype)
 
 
+# ---- a page several live slots hold, streamed once for all of them
+#
+# Live slots that were admitted on a prefix hit hold THE SAME pool pages in
+# the leading columns of their table rows (runtime/kv_pool.py: the trie's
+# pages are shared by reference). The per-slot kernel above fetches such a
+# page once a slot. The shared-page form fetches it once a GROUP:
+#
+#   * `_paged_shared_kernel`, grid = the groups: the group's shared pages go
+#     through the same page stream, and every fetched turn is scored against
+#     the query rows of ALL the group's members, a sub-block of
+#     `_SHARED_BLOCK_ROWS` rows (whole members) at a time, one online softmax
+#     a row (a FLAT pool's rows a KV head at a time against that head's own
+#     lane tiles: the per-slot kernel's zeros would cost a group of eight four
+#     times its stream). Every token of a shared page is live for every
+#     member (that is what makes the page one the group may share), so no
+#     live rule is evaluated here. What it leaves is each member's partial
+#     state (running maximum, denominator, accumulator, all f32).
+#   * `_paged_attn_kernel` with `carry`: each slot streams its OWN pages (its
+#     question, its answer, the page it writes) as ever, starting from its
+#     group's partial state instead of from nothing. That start IS the exact
+#     merge: m = max(m1, m2), l = l1 e^(m1 - m) + l2 e^(m2 - m), likewise the
+#     accumulator; a sink's logit enters once, at the start of whichever
+#     stream a slot's softmax begins in.
+#
+# Which slots form a group is the caller's to say (`pack_shared_groups`; the
+# serving engine reads it off its page tables, runtime/kv_pool.py
+# `shared_page_groups`): an engine that never admits a request on a prefix
+# hit hands its programs no groups, and they hold the per-slot kernel alone.
+
+# query rows one sub-block of a group's members takes through a fetched turn:
+# the turn's keys are the MXU's weights, which cost the same to load whether
+# 1 or 128 rows stream through them
+_SHARED_BLOCK_ROWS = 128
+# query rows of all the members one group scores against a fetched turn: what
+# their query block and their f32 state (running maximum, denominator,
+# accumulator: 128 lanes or more a row each, twice for the outputs' two
+# buffers) may take of VMEM beside the page ring. A larger group is split
+_SHARED_GROUP_ROWS = 512
+
+
+def shared_members_cap(h: int) -> int:
+    """Most members a group of the shared-page form may have at `h` query
+    heads: `_SHARED_GROUP_ROWS` query rows in all (8 at 64 heads, 12 at 40,
+    32 at 16)."""
+    return max(1, _SHARED_GROUP_ROWS // h)
+
+
+def _shared_block_members(m: int, rows: int) -> int:
+    """Members a sub-block takes of groups of at most `m`, at `rows` query
+    rows a member in one matmul: the most that divide `m` and put
+    `_SHARED_BLOCK_ROWS` rows or fewer through it."""
+    return max(d for d in range(1, m + 1)
+               if m % d == 0 and (d == 1 or d * rows <= _SHARED_BLOCK_ROWS))
+
+
+def pack_shared_groups(groups, slots: int, cap: int):
+    """The shared-page form's two arguments, as numpy int32 arrays, from
+    `groups`: [(member slots, shared pages)], each group's members at most
+    `cap`, a slot in at most one group.
+
+      * (slots // 2, 2 + cap): a row a group, the live ones first: its member
+        count (0: no group), its shared pages, its members (padded with slot
+        0, whose rows are then scored and dropped);
+      * (slots, 2): a row a slot: the row `group * cap + position` of the
+        partials its group's stream leaves for it (-1: in no group) and the
+        first page of its own (the group's shared pages; 0).
+
+    Every member's table row holds the same pool pages in its first `shared
+    pages` columns, every token of them is live for it, and it writes none of
+    them: the CALLER's promise, the kernel checks nothing."""
+    import numpy as np
+
+    packed = np.zeros((max(1, slots // 2), 2 + cap), np.int32)
+    slot_of = np.zeros((slots, 2), np.int32)
+    slot_of[:, 0] = -1
+    for gi, (members, pages) in enumerate(groups):
+        assert 2 <= len(members) <= cap and pages >= 1, (members, pages)
+        packed[gi, 0], packed[gi, 1] = len(members), pages
+        packed[gi, 2:2 + len(members)] = members
+        for j, slot in enumerate(members):
+            assert slot_of[slot, 0] < 0, (slot, groups)
+            slot_of[slot] = gi * cap + j, pages
+    return packed, slot_of
+
+
+def _flat_head_lanes(j: int, d: int):
+    """The whole lane tiles of a flat row that hold KV head j's `d` lanes
+    (keys of 192: head 1's lanes 192..383 lie in tiles 128..383)."""
+    return j * d // LANES * LANES, -(-(j + 1) * d // LANES) * LANES
+
+
+def _lanes_spread(x, n: int):
+    """x (rows, 128), the same value in every lane of a row -> (rows, n)."""
+    if n == LANES:
+        return x
+    if n % LANES == 0:
+        return pltpu.repeat(x, n // LANES, axis=1)
+    return jnp.broadcast_to(x[:, 0:1], (x.shape[0], n))
+
+
+def _paged_shared_kernel(pt_ref, lp_ref, n_ref, cnt_ref, q_ref, *rest,
+                         h: int, kvh: int, ps: int, nbuf: int, g: int,
+                         ms: int, scale: float, pack: int = 1,
+                         sink: bool = False, flat: bool = False):
+    """One GROUP per grid step: the group's shared pages streamed once (the
+    leader's table row pt_ref[group], pages 0 .. lp_ref[group]) and each
+    fetched turn scored against the members' query rows, `ms` members (ms x H
+    rows) a sub-block, q_ref (1, subs, ms x H, Dqk). n_ref (1,): the live
+    groups, which come first: a later grid step does nothing. cnt_ref: each
+    group's members; only the sub-blocks that hold one are scored.
+
+    The running state lives in the OUTPUT blocks, a sub-block a leading
+    index: m_ref and l_ref (1, subs, ms x H, 128) hold the running maximum
+    and the denominator, a row's value in every lane (a (rows, 1) value
+    would be sliced out of and spread back over the lanes every turn),
+    acc_ref (1, subs, ms x H, Dv) the accumulator. With `sink` the maximum
+    starts at the sink's logit and the denominator at 1, as in the per-slot
+    kernel. No live rule: every token of a shared page is live for every
+    member.
+
+    The rows of a sub-block lie (member, head) and meet every column of a
+    turn in ONE matmul, a column of another KV head masked, as per slot
+    (`pack` as there). A `flat` pool's sub-block lies (KV head, member, head
+    of the group) instead and takes a matmul a KV HEAD: its rows against the
+    whole lane tiles of the turn's rows that hold the head's key lanes (the
+    query's zeros cancel a neighbour's lanes in a shared tile), its
+    accumulator the head's own Dv lanes. The per-slot kernel pushes every
+    query row through every lane tile of a flat row, all but one KV head's
+    against zeros, which costs a lone slot nothing (its 64 rows take no
+    longer than the tiles take to load) and a group of eight four times its
+    stream. A flat pool's q_ref holds each row's window of those tiles alone
+    (`_flat_windows`). The KV heads' chains are independent: all are scored,
+    then all folded, and the state is read before and written after, so the
+    scheduler may run them side by side."""
+    sink_ref = None
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
+    k_hbm, v_hbm, m_ref, l_ref, acc_ref, k_buf, v_buf, sem, cur = rest
+    gi = pl.program_id(0)
+    grp = h // kvh
+    heads_kv = kvh
+    kvh = 1 if flat else kvh // pack    # rows a token takes in a page
+    rows = ms * h
+    prime, take, fetch_next = _page_stream(
+        pt_ref, lp_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem, cur, nbuf, g,
+        tail=True, n_ref=n_ref)
+    prime(nbuf - 1)
+    # the row blocks of a sub-block that take a matmul each, and for a flat
+    # pool the key and value lanes of each one's KV head
+    parts = [(slice(None), slice(None), slice(None))]
+    if flat:
+        dk = k_buf.shape[-1] // heads_kv
+        dv = v_buf.shape[-1] // heads_kv
+        parts = [(slice(j * ms * grp, (j + 1) * ms * grp),
+                  slice(*_flat_head_lanes(j, dk)),
+                  slice(j * dv, (j + 1) * dv)) for j in range(heads_kv)]
+
+    @pl.when(gi < n_ref[0])
+    def _():
+        subs = (cnt_ref[gi] + ms - 1) // ms
+        m0 = jnp.full((rows, LANES), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows, LANES), jnp.float32)
+        if sink_ref is not None:
+            m0 = jnp.broadcast_to(sink_ref[...], (rows, LANES))
+            l0 = jnp.ones((rows, LANES), jnp.float32)
+
+        def start(i, _):
+            m_ref[0, i], l_ref[0, i] = m0, l0
+            acc_ref[0, i] = jnp.zeros(acc_ref.shape[2:], jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(0, subs, start, 0)
+
+        def turn(pages):
+            cols = pages * ps * kvh
+            own_head = None
+            if not flat:
+                row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+                own_head = (row % h) // (grp * pack) == col % kvh
+
+            def sub(buf, i):
+                kept = [(m_ref[0, i, at], l_ref[0, i, at], acc_ref[0, i, at])
+                        for at, _, _ in parts]
+                scored = []
+                for at, lk, _ in parts:
+                    # a flat pool's query rows hold their head's window alone
+                    q = q_ref[0, i, at] if lk.start is None \
+                        else q_ref[0, i, at, :lk.stop - lk.start]
+                    k = k_buf[buf, :pages, ..., lk].reshape(cols, -1)
+                    sc = jax.lax.dot_general(
+                        q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+                    scored.append(sc if own_head is None
+                                  else jnp.where(own_head, sc, NEG_INF))
+                for (at, _, lv), sc, (m_prev, l_prev, acc) in zip(
+                        parts, scored, kept):
+                    v = v_buf[buf, :pages, ..., lv].reshape(cols, -1)
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(sc, axis=-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(sc - _lanes_spread(m_new, cols))
+                    l_ref[0, i, at] = l_prev * alpha + jnp.sum(
+                        p, axis=-1, keepdims=True)
+                    m_ref[0, i, at] = m_new
+                    acc_ref[0, i, at] = acc * _lanes_spread(
+                        alpha, acc.shape[-1]) + jnp.dot(
+                            p.astype(q_ref.dtype), v.astype(q_ref.dtype),
+                            preferred_element_type=jnp.float32)
+
+            def body(_, c):
+                fetch_next(jax.lax.rem(cur[2] + nbuf - 1, nbuf))
+                buf = take(pages)
+
+                def one(i, c):
+                    sub(buf, i)
+                    return c
+
+                return jax.lax.fori_loop(0, subs, one, c)
+
+            return body
+
+        n_pages, first = lp_ref[gi] + 1, 0
+        if g > 1:
+            first = (n_pages // g) * g
+            jax.lax.fori_loop(0, n_pages // g, turn(g), 0)
+        # the pages past the last whole block, one a turn, as per slot
+        jax.lax.fori_loop(first, n_pages, turn(1), 0)
+
+
+def _flat_windows(q, kvh: int):
+    """q (B, H, D) of a flat pool's op -> (B, H, W): each query row's entries
+    where they lie in the whole lane tiles that hold its KV head's keys
+    (`_flat_head_lanes`: at keys of 192, heads 1 and 3 start 64 lanes into
+    their first tile), zeros in the rest of the window: a third of the row
+    the per-slot kernel contracts."""
+    b, h, d = q.shape
+    spans = [_flat_head_lanes(j, d) for j in range(kvh)]
+    width = max(hi - lo for lo, hi in spans)
+    q = q.reshape(b, kvh, h // kvh, d)
+    return jnp.concatenate(
+        [jnp.pad(q[:, j:j + 1], ((0, 0),) * 3
+                 + ((j * d - lo, width - d - (j * d - lo)),))
+         for j, (lo, _) in enumerate(spans)], axis=1).reshape(b, h, width)
+
+
+def _shared_partials(q, k_pages, v_pages, page_table, groups, src, sink_rows,
+                     *, h, kvh, ps, g, nbuf, k_page, v_page, scale, pack,
+                     flat):
+    """The first half of the shared-page form: q (B, H, Dqk) as the per-slot
+    kernel takes it (a packed pool's zeros in place; of a flat pool its
+    heads' own D lanes), the pools as it streams them, `groups` as
+    `pack_shared_groups` packs them, `src` (B,) each slot's row of the
+    partials -> each SLOT's partial state ((B, H, 128) running maximum and
+    the same of the denominator, a row's value in every lane, (B, H, Dv)
+    accumulator, of a flat pool the row's own KV head's Dv lanes, f32;
+    whatever an idle step left, for a slot in no group)."""
+    n_groups, m = groups.shape[0], groups.shape[1] - 2
+    grp = h // kvh
+    ms = _shared_block_members(m, grp if flat else h)
+    subs, rows = m // ms, ms * h
+    dv = v_page[-1] // (kvh if flat else 1)
+    count, pages, members = groups[:, 0], groups[:, 1], groups[:, 2:]
+    # a sub-block's rows lie (member, head); of a flat pool (KV head,
+    # member, head of the group): a KV head's rows together
+    split = (kvh, grp) if flat else (1, h)
+
+    def sub_blocks(x, n=subs):  # (.., n x ms, H, D) -> (.., n, ms x H, D)
+        lead, d = x.shape[:-3], x.shape[-1]
+        x = jnp.swapaxes(x.reshape(*lead, n, ms, *split, d), -4, -3)
+        return x.reshape(*lead, n, rows, d)
+
+    with jax.named_scope("core"):
+        if flat:
+            q = _flat_windows(q, kvh)
+        # the live groups come first: their number ends the stream
+        prefetch = [page_table[members[:, 0]].astype(jnp.int32),
+                    jnp.maximum(pages - 1, 0),
+                    jnp.sum(count > 0, dtype=jnp.int32)[None], count]
+        operands = [sub_blocks(q[members])]
+    in_specs = [pl.BlockSpec((1, subs, rows, q.shape[-1]),
+                             lambda gi, *_: (gi, 0, 0, 0))]
+    if sink_rows is not None:
+        # one logit a query row, in a sub-block's order
+        operands.append(sub_blocks(
+            jnp.broadcast_to(sink_rows, (ms, h, 1)), 1)[0])
+        in_specs.append(pl.BlockSpec((rows, 1), lambda gi, *_: (0, 0)))
+    state = pl.BlockSpec((1, subs, rows, LANES), lambda gi, *_: (gi, 0, 0, 0))
+    partials = pl.pallas_call(
+        functools.partial(_paged_shared_kernel, h=h, kvh=kvh, ps=ps,
+                          nbuf=nbuf, g=g, ms=ms, scale=scale, pack=pack,
+                          sink=sink_rows is not None, flat=flat),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n_groups,),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY),
+                                 pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[
+                state, state,
+                pl.BlockSpec((1, subs, rows, dv),
+                             lambda gi, *_: (gi, 0, 0, 0))],
+            scratch_shapes=[
+                pltpu.VMEM((nbuf, g, *k_page), k_pages.dtype),
+                pltpu.VMEM((nbuf, g, *v_page), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, nbuf)),
+                pltpu.SMEM((3,), jnp.int32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((n_groups, subs, rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((n_groups, subs, rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((n_groups, subs, rows, dv), jnp.float32)],
+        compiler_params=_compiler_params(("arbitrary",)),
+        interpret=_interpret(),
+    )(*prefetch, *operands, k_pages, v_pages)
+    with jax.named_scope("core"):
+        # a slot's rows out of its group's sub-block: only they are read
+        at = jnp.maximum(src, 0)
+        gi, sub, pos = at // m, at % m // ms, at % ms
+        return tuple(
+            x.reshape(n_groups, subs, split[0], ms, split[1], x.shape[-1])
+            [gi, sub, :, pos].reshape(src.shape[0], h, x.shape[-1])
+            for x in partials)
+
+
 def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
                                row_len, prompt_pad, scale: float,
                                k_scales=None, v_scales=None,
                                window: Optional[int] = None, sink=None,
-                               kv_heads: Optional[int] = None):
+                               kv_heads: Optional[int] = None, shared=None):
     """Paged-pool attention: q (B, S, H, Dqk) against k_pages/v_pages
     ((P_pool, page_size, KVH, D)) through per-slot page tables
     ((B, pages_per_slot) int32) -> (B, S, H, Dv) context.
@@ -1285,9 +1638,17 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
 
     ``kv_heads`` marks a FLAT pool (ops/attention.py `pool_pack` == the KV
     heads): k_pages (P_pool, page_size, KVH x Dqk), v_pages (.., KVH x Dv),
-    one row a token."""
+    one row a token.
+
+    ``shared`` (`pack_shared_groups`' two arrays; a decode step over a pool
+    at full width that is no window's ring) marks the shared-page form:
+    the slots of a group hold the same pool pages in their first columns,
+    those pages are streamed once a group (`_paged_shared_kernel`) and each
+    slot streams its own pages from the partial state that leaves it. Arrays
+    that hold no group give the per-slot result at the cost of the idle
+    grid steps; None is the per-slot kernel alone."""
     b, s, h, dqk = q.shape
-    flat = k_pages.ndim == 3
+    flat, q_heads = k_pages.ndim == 3, q
     if flat:
         # one row a token: seen as one "KV head" row of KVH x D lanes, the
         # page a dense (ps, lanes) block whatever the turn
@@ -1371,6 +1732,23 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
         # one logit a query row, the same block at every slot
         operands.append(jnp.tile(sink.astype(jnp.float32), s)[:, None])
         in_specs.append(pl.BlockSpec((s * h, 1), lambda bi, *_: (0, 0)))
+    if shared is not None:
+        assert s == 1 and window is None and not quantized, \
+            "the shared-page form is a decode step's, over a full-width " \
+            "pool that is no ring"
+        groups, slot_of = (jnp.asarray(x, jnp.int32) for x in shared)
+        m_run, l_run, acc = _shared_partials(
+            q_heads[:, 0] if flat else operands[0], k_pages, v_pages,
+            prefetch[0], groups,
+            slot_of[:, 0], operands[1] if sink is not None else None, h=h,
+            kvh=kvh, ps=ps, g=g, nbuf=nbuf, k_page=k_page, v_page=v_page,
+            scale=scale, pack=pack, flat=flat)
+        # each slot's first own page, and whether it is in a group at all
+        prefetch += [slot_of[:, 1], slot_of[:, 0]]
+        operands += [m_run, l_run, acc]
+        in_specs += [pl.BlockSpec((1, h, LANES), slot_map),
+                     pl.BlockSpec((1, h, LANES), slot_map),
+                     pl.BlockSpec((1, h, acc.shape[-1]), slot_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b,),
@@ -1390,7 +1768,7 @@ def paged_attention_fwd_pallas(q, k_pages, v_pages, page_table, write_pos,
         functools.partial(_paged_attn_kernel, s=s, h=h, kvh=kvh, ps=ps,
                           nbuf=nbuf, g=g, scale=scale, quantized=quantized,
                           window=window, pack=pack, sink=sink is not None,
-                          flat=flat),
+                          flat=flat, carry=shared is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (b, s * h, d0 if flat else dv), q.dtype),

@@ -358,11 +358,18 @@ class Generator:
                                 paged["row_len"], paged["prompt_pad"],
                                 impl=paged.get("impl"))
                         else:
+                            # the groups of slots whose rows begin with
+                            # the same pages, for an op that can stream
+                            # such a page once a group (a ring is a
+                            # slot's own)
+                            share = {"shared": paged["shared"]} \
+                                if "shared" in paged and keep is None \
+                                and hasattr(op, "shared_members_cap") else {}
                             out, nc = op.paged_decode_forward(
                                 p, xs, cache, table,
                                 paged["write_pos"], paged["rope_pos"],
                                 paged["row_len"], paged["prompt_pad"],
-                                impl=paged.get("impl"))
+                                impl=paged.get("impl"), **share)
                     elif pos is None:
                         if gather_last:
                             # ragged chunked prefill: read-only query of

@@ -828,6 +828,59 @@ def op_keeps(op):
     return keep() if keep is not None else None
 
 
+def shared_page_groups(tables, shareable, cap: int):
+    """Which live slots the paged kernel's shared-page form scores against
+    one fetch of a page (ops/pallas_kernels.py `_paged_shared_kernel`), read
+    off what a decode dispatch is handed and nothing else: `tables` (slots,
+    width), each slot's page-table row; `shareable` (slots,), how many of
+    its leading columns a slot could share: the whole pages of its prompt,
+    which it attends in full and never writes (0: the slot is not live).
+    -> [(member slots, pages)], at most `cap` members each and never one
+    alone: every member's row holds the same pool pages in its first `pages`
+    columns. A slot is in at most one group.
+
+    Pages enter two rows only through the prefix trie (a hit's matched
+    pages, by reference), so rows that agree, agree from column 0. The rows
+    are sorted, which puts those with the longest common run side by side;
+    a run of neighbours is one group as long as taking the next neighbour in
+    saves no fewer page fetches than leaving it out (a slot that hit on a
+    document's first pages alone does not drag a group of whole-document
+    hits down to them), and a group over `cap` is split evenly: each part
+    streams the pages once."""
+    live = [int(s) for s in np.flatnonzero(np.asarray(shareable) > 0)]
+    if cap < 2 or len(live) < 2:
+        return []
+    live.sort(key=lambda s: tables[s, :shareable[s]].tobytes())
+
+    def common(a, b):
+        n = int(min(shareable[a], shareable[b]))
+        same = tables[a, :n] == tables[b, :n]
+        return n if same.all() else int(same.argmin())
+
+    run = [common(a, b) for a, b in zip(live, live[1:])] + [0]
+    groups, i = [], 0
+    while i < len(live) - 1:
+        # a pair whose next neighbours share over twice as much: leave the
+        # first of it alone
+        if run[i] < 1 or 2 * run[i] < run[i + 1]:
+            i += 1
+            continue
+        j, pages = i + 1, run[i]
+        # live[i..j] share `pages` and save pages x (j - i) fetches
+        while run[j] >= 1 \
+                and min(pages, run[j]) * (j - i + 1) >= pages * (j - i):
+            pages = min(pages, run[j])
+            j += 1
+        members = live[i:j + 1]
+        parts = -(-len(members) // cap)
+        for p in range(parts):
+            part = members[p::parts]
+            if len(part) > 1:
+                groups.append((part, pages))
+        i = j + 1
+    return groups
+
+
 class WindowPageGroup:
     """The pages of the attention ops that keep a window of ``window``
     positions: a RING of ``ring`` = ceil(window / page_size) + 1 pages a

@@ -142,7 +142,8 @@ from flexflow_tpu.ops import sampling as sampling_ops
 from flexflow_tpu.runtime import (faultinject, flightrec, locks, profiler,
                                   telemetry)
 from flexflow_tpu.runtime.generation import Generator
-from flexflow_tpu.runtime.kv_pool import KVPagePool, Lease, op_keeps
+from flexflow_tpu.runtime.kv_pool import (KVPagePool, Lease, op_keeps,
+                                          shared_page_groups)
 from flexflow_tpu.runtime.lora import LoraAdapterPool
 
 # process-wide engine ids: the default telemetry `replica` label when no
@@ -243,15 +244,17 @@ HELD_TICK_S = 1.0
 
 _KEY_FIELDS = {"prefill": "b", "prefill_hit": "bm", "draft_prefill": "b",
                "draft_prefill_hit": "bm", "prefill_ichunk": "bs",
-               "prefill_ifinal": "b", "decode": "k", "draft_propose": "k",
-               "verify": "k", "spec_uniforms": "k"}
+               "prefill_ifinal": "b", "decode": ("k", "shared"),
+               "draft_propose": "k", "verify": "k", "spec_uniforms": "k"}
 
 
 def program_name(key) -> str:
     """The short name of an engine program's key, as the registry
     (runtime/profiler.py), the ``program`` count of the dispatch spans and
     the benchmark's scope tables know it: ``("decode", 8)`` ->
-    ``decode_k8``, ``("prefill", 2048, 16, 0)`` -> ``prefill_b2048``,
+    ``decode_k8`` (``("decode", 8, 8)``, the program of an engine with
+    prefix-hit slots, whose groups have at most 8 members ->
+    ``decode_k8_shared8``), ``("prefill", 2048, 16, 0)`` -> ``prefill_b2048``,
     ``("prefill_hit", 128, 255)`` -> ``prefill_hit_b128_m255``. Fields a
     key's bucket already fixes (its page count, the engine's chunk) are
     left out."""
@@ -630,6 +633,29 @@ class ServingEngine:
                                      self.pages_per_slot)
                  for op in self.gen.attn_ops if op_keeps(op) is None),
                 default=1)
+        # the shared-page form of the paged kernel (a page several live
+        # slots hold, streamed once for all of them): the most members one
+        # group may have, by the ops that can take it (the kernel, a pool at
+        # full width, no window's ring), or None. Whether a dispatch TAKES
+        # it is read off the traffic: from the first request admitted on a
+        # prefix hit on (`_shared_seen`), every decode dispatch is handed
+        # the groups its live slots' page tables show (`_shared_plan`, kept
+        # from one seating or retirement to the next) and runs the program
+        # that reads them; an engine that never sees a hit runs the
+        # per-slot program it always ran
+        self._shared_cap = None
+        if self.paged_attention_impl == "pallas":
+            caps = [op.shared_members_cap() for op in self.gen.attn_ops
+                    if op_keeps(op) is None
+                    and hasattr(op, "shared_members_cap")
+                    and "k_scale" not in self.kv.pool[op.name]]
+            cap = min(*caps, self.slots) if caps else 0
+            if cap > 1:
+                self._shared_cap = cap
+        self._shared_seen = False
+        self._shared_plan = None
+        self._shared_groups = 0
+        self._shared_pages_saved = 0
         fflogger.info(
             "serving: paged decode attention and prefill write impl=%s "
             "kv_cache_dtype=%s "
@@ -791,6 +817,7 @@ class ServingEngine:
         self._last_pages_touched = 0
         self._kv_read_bytes = 0
         self._kv_streamed_bytes = 0
+        self._kv_attended_bytes = 0
         # pages held, summed over decode steps, by kind of table (a model
         # with window layers: `_reach_windows`)
         self._page_steps = {"global": 0, "window": 0}
@@ -1175,6 +1202,7 @@ class ServingEngine:
         self.active[slot] = False
         self.poison[slot] = 0.0
         self.page_tables[slot, :] = 0   # scratch page: dead writes land there
+        self._shared_plan = None
         self.kv.release_windows(slot)
         self.row_len[slot] = 0
         self.prompt_pad[slot] = 0
@@ -1200,6 +1228,9 @@ class ServingEngine:
         table = np.zeros((self.pages_per_slot,), np.int32)
         table[:len(req.lease.pages)] = req.lease.pages
         self.page_tables[slot] = table
+        self._shared_plan = None
+        if req.lease.matched:
+            self._shared_seen = True
         self.kv.seat_windows(slot, req.prompt.size)
         self.row_len[slot] = req.prompt.size
         self.prompt_pad[slot] = req.bucket
@@ -1732,17 +1763,21 @@ class ServingEngine:
 
         return jax.jit(decode_verify, donate_argnums=(2,))
 
-    def _build_decode(self, n_steps: int, took=None):
+    def _build_decode(self, n_steps: int, took=None, shared: bool = False):
         gen = self.gen
         has_lora = self.lora_pool is not None
 
         def decode(params, state, pool, page_table, last_tok, write_pos0,
                    rope_pos0, row_len, prompt_pad, budget, poison,
                    temps, top_ps, top_ks, seeds, ctr0,
-                   lora_pool, lora_pages, *rings):
-            """`n_steps` slot-decode steps as ONE in-graph scan. `rings`:
-            one more argument of a model with window layers, its groups'
-            ring tables as they will stand at the dispatch's last step. Past a
+                   lora_pool, lora_pages, *more):
+            """`n_steps` slot-decode steps as ONE in-graph scan. `more`:
+            of the program of an engine with prefix-hit slots (`shared`),
+            the two arrays of the groups of slots whose rows begin with the
+            same pages (`_shared_pages_plan`), fixed like the table over
+            the dispatch's steps; then, of a model with window layers, its
+            groups' ring tables as they will stand at the dispatch's last
+            step. Past a
             slot's own budget (prompt_pad + its max_new_tokens) the write
             position and RoPE clamp to the final allocated slot — those
             steps only produce tokens the host truncates, and the
@@ -1754,6 +1789,7 @@ class ServingEngine:
             rope_cap = budget - prompt_pad + row_len - 1
             lora = ({"pool": lora_pool, "pages": lora_pages}
                     if has_lora else None)
+            groups, rings = (more[:2], more[2:]) if shared else ((), more)
 
             def body(carry, i):
                 pool, tok = carry
@@ -1763,6 +1799,8 @@ class ServingEngine:
                     "rope_pos": jnp.minimum(rope_pos0 + i, rope_cap),
                     "row_len": row_len, "prompt_pad": prompt_pad,
                     "impl": self.paged_attention_impl}
+                if groups:
+                    paged["shared"] = groups
                 if rings:
                     paged["window_tables"] = rings[0]
                 routing = [] if gen.dropless_moe_ops else None
@@ -2644,42 +2682,91 @@ class ServingEngine:
                 budget[slot] = req.bucket + req.max_new_tokens
         return write_pos, rope_pos, budget
 
-    def _note_pages_touched(self, frontier, budget):
+    def _note_pages_touched(self, frontier, budget, held_again: int = 0,
+                            saved: int = 0):
         """Record the pool pages this dispatch's attention READS: per
         active slot, pages up to its final-step write frontier (what the
         pallas kernel streams through VMEM — the einsum path gathers the
         whole table width regardless, which is exactly the delta the
         kernel exists to remove). ``frontier`` is (slots, steps): the
         write position of each attention pass the dispatch makes.
-        Returns the KV bytes those passes read for the active slots
-        (live pages x page_size x kv_bytes_per_token, summed over
-        passes), which ``kv_read_bytes`` accumulates, and the bytes the
-        kernel's turns FETCH for them, which ``kv_streamed_bytes`` does:
-        a slot's whole blocks of `paged_turn_pages` pages and, one a turn,
-        the pages past its last whole block, so no page past the last live
-        one: the same bytes, whatever a turn takes (the index kernel's
-        stream rounds a slot up to whole blocks and reads more than it
-        needs: ``index_streamed_bytes``; a tail that fetched whole blocks
-        would round `pages` up to `paged_turn_pages` here)."""
+        Returns three byte counts of ONE layer over those passes (pages x
+        page_size x kv_bytes_per_token): what the active slots ATTEND, a
+        page once for every slot that holds it (``kv_attended_bytes``);
+        what must be READ for that, each distinct page once a pass
+        (``kv_read_bytes``: ``held_again`` pages of the slots' prompts are
+        held by an earlier slot too, `_shared_pages_plan`); and what the
+        kernel's turns FETCH (``kv_streamed_bytes``): a slot's whole blocks
+        of `paged_turn_pages` pages and, one a turn, the pages past its
+        last whole block, so no page past the last live one, less the
+        ``saved`` pages a pass that its group's stream fetched for a slot's
+        group and not for the slot. With no page held twice the three are
+        one number (the index kernel's stream rounds a slot up to whole
+        blocks and reads more than it needs: ``index_streamed_bytes``; a
+        tail that fetched whole blocks would round `pages` up to
+        `paged_turn_pages` here)."""
         fr = np.minimum(frontier, (budget - 1)[:, None])
         pages = (fr // self.page_size + 1)[self.active]  # (active, steps)
         touched = int(pages[:, -1].sum())
         self._last_pages_touched = touched
         self._pages_touched += touched
-        kv_read = kv_streamed = int(int(pages.sum()) * self.page_size
-                                    * self._kv_bytes_per_token)
+        page_bytes = self.page_size * self._kv_bytes_per_token
+        attended, steps = int(pages.sum()), frontier.shape[1]
+        kv_attended = int(attended * page_bytes)
+        kv_read = int((attended - steps * held_again) * page_bytes)
+        kv_streamed = int((attended - steps * saved) * page_bytes)
+        self._kv_attended_bytes += kv_attended
         self._kv_read_bytes += kv_read
         self._kv_streamed_bytes += kv_streamed
-        return kv_read, kv_streamed
+        return kv_attended, kv_read, kv_streamed
 
-    def _reach_windows(self, rope_pos, budget, k: int) -> Dict:
+    def _shared_pages_plan(self):
+        """What the live slots' page tables say of pages held by more than
+        one of them, for the next decode dispatch: (the shared-page form's
+        two arrays or None, its groups, the pages a step they save, the
+        pages held again). A slot may share the whole pages of its prompt:
+        it attends every token of them and writes none (decode writes at
+        `prompt_pad` and after). The GROUPS are the kernel's
+        (`kv_pool.shared_page_groups`; none on an engine whose ops cannot
+        take the form); `held again` is the traffic's: the columns of such
+        pages over all live slots less the distinct pool pages in them, which
+        is what ONE read of every distinct key leaves out of the per-slot
+        sums. Kept until a slot is seated or retired: a dispatch's tables
+        are fixed, and so is this. An engine that has admitted no request
+        on a prefix hit has no such page and is not asked."""
+        if not self._shared_seen:
+            return None, 0, 0, 0
+        if self._shared_plan is None:
+            shareable = np.where(self.active,
+                                 self.row_len // self.page_size, 0)
+            held = self.page_tables[
+                np.arange(self.pages_per_slot)[None] < shareable[:, None]]
+            groups = shared_page_groups(self.page_tables, shareable,
+                                        self._shared_cap or 0)
+            arrays = None
+            if self._shared_cap:
+                from flexflow_tpu.ops.pallas_kernels import \
+                    pack_shared_groups
+
+                arrays = pack_shared_groups(groups, self.slots,
+                                            self._shared_cap)
+            self._shared_plan = (
+                arrays, len(groups),
+                sum((len(m) - 1) * pages for m, pages in groups),
+                int(held.size - np.unique(held).size))
+        return self._shared_plan
+
+    def _reach_windows(self, rope_pos, budget, k: int,
+                       held_again: int = 0) -> Dict:
         """Before a decode dispatch of `k` steps over window layers: every
         live slot's rings are made to hold the page of its last step's
         position (the program is handed the tables as they will stand
         then: WindowPageGroup), and the dispatch's span gets the context
         tokens ONE layer of each kind reads over its steps, summed over
-        the live slots: all of a sequence on a global layer, the window at
-        most on a window layer."""
+        the live slots: all of a sequence on a global layer (less the
+        `held_again` pages a step that an earlier slot holds too: each
+        distinct key once; the per-slot sum is `..._attended_global`), the
+        window at most on a window layer, whose ring is a slot's own."""
         cap = budget - self.prompt_pad + self.row_len - 1
         # (slots, k): the sequence position each step's token takes
         pos = np.minimum(rope_pos[:, None] + np.arange(k), cap[:, None])
@@ -2690,7 +2777,9 @@ class ServingEngine:
         self._page_steps["window"] += k * sum(
             g.held_pages for g in self.kv.window_groups.values())
         seen = pos[self.active] + 1
-        counts = {"context_tokens_global": int(seen.sum())}
+        counts = {"context_tokens_global": int(seen.sum()) - k * held_again
+                  * self.page_size,
+                  "context_tokens_attended_global": int(seen.sum())}
         # one number for the window layers: the engine's cells have one
         # window size (each further size adds its own tokens here)
         counts["context_tokens_window"] = int(sum(
@@ -2705,10 +2794,15 @@ class ServingEngine:
             # what the paged kernel attends at the chunk's first step,
             # and what its k steps stream: the engine alone knows both
             # at dispatch (the bytes roofline of the kernel reads them)
-            context = int((np.minimum(write_pos, budget - 1)
-                           + 1)[self.active].sum())
-            kv_read, kv_streamed = self._note_pages_touched(
-                write_pos[:, None] + np.arange(k), budget)
+            attended = int((np.minimum(write_pos, budget - 1)
+                            + 1)[self.active].sum())
+            # pages more than one live slot holds: the groups the kernel
+            # streams once, and the counts with each distinct key once
+            arrays, groups, saved, held_again = self._shared_pages_plan()
+            self._shared_groups += groups
+            self._shared_pages_saved += k * saved
+            kv_attended, kv_read, kv_streamed = self._note_pages_touched(
+                write_pos[:, None] + np.arange(k), budget, held_again, saved)
             attn = collections.Counter()
             if self._counting_attn_ops:
                 # per live row and step, the tokens its attention may see
@@ -2727,21 +2821,32 @@ class ServingEngine:
                     self.row_len, self.prompt_pad, budget, self.poison,
                     self.temps, self.top_ps, self.top_ks, self.seeds,
                     self.emitted.copy(), *self._lora_args_slots())
+            if arrays is not None:
+                args += arrays
             if self.kv.window_groups:
-                attn.update(self._reach_windows(rope_pos, budget, k))
+                attn.update(self._reach_windows(rope_pos, budget, k,
+                                                held_again))
                 args += (self.kv.window_tables(),)
             if self.gen.state_ops:
                 # each step reads and writes every live slot's state once
                 attn["state_bytes"] = (2 * k * live
                                        * self._state_bytes_per_slot)
-        key = ("decode", k)
+        # an engine that has seen a prefix hit runs the program that reads
+        # the groups, whether or not two slots share a page right now
+        key = ("decode", k) if arrays is None \
+            else ("decode", k, self._shared_cap)
         with self._span("decode_dispatch", k=k, slots=live, sampled=sampled,
-                        context_tokens=context, kv_read_bytes=kv_read,
-                        kv_streamed_bytes=kv_streamed,
+                        context_tokens=attended
+                        - held_again * self.page_size,
+                        context_tokens_attended=attended,
+                        kv_read_bytes=kv_read, kv_streamed_bytes=kv_streamed,
+                        kv_attended_bytes=kv_attended,
+                        shared_groups=groups, shared_pages_saved=k * saved,
                         paged_turn_pages=self._paged_turn_pages,
                         program=program_name(key), **attn) as sp:
             toks, oks, self.kv.pool, *routed = self._compiled_call(
-                key, lambda: self._build_decode(k, self._moe_took_list(key)),
+                key, lambda: self._build_decode(k, self._moe_took_list(key),
+                                                shared=arrays is not None),
                 *args)
             self._note_moe_lowering(key, sp)
         with self._span("token_fetch"):
@@ -2813,8 +2918,10 @@ class ServingEngine:
         # over logits the walk already materialized)
         sampled_live = bool(self.active.any()) and bool(
             np.any(self.temps[self.active] > 0.0))
-        # verify-slab frontier (the draft's decode mirrors the same pages)
-        self._note_pages_touched((write_pos + k)[:, None], budget)
+        # verify-slab frontier (the draft's decode mirrors the same pages);
+        # the slab reads every slot's pages for the slot
+        self._note_pages_touched((write_pos + k)[:, None], budget,
+                                 self._shared_pages_plan()[3])
         d_toks, d_probs, self.kv.draft_pool = self._compiled_call(
             ("draft_propose", k),
             lambda: self._build_draft_propose(k),
@@ -3459,4 +3566,12 @@ class ServingEngine:
             "kv_streamed_bytes": self._kv_streamed_bytes,
             "paged_turn_pages": self._paged_turn_pages,
             "last_pages_touched": self._last_pages_touched,
+            # pages more than one live slot holds (`_shared_pages_plan`):
+            # `kv_read_bytes` counts such a page once a pass,
+            # `kv_attended_bytes` once a slot; the groups the decode
+            # dispatches streamed once, and the page fetches that saved
+            "kv_attended_bytes": self._kv_attended_bytes,
+            "shared_groups": self._shared_groups,
+            "shared_pages_saved": self._shared_pages_saved,
+            "shared_members_cap": self._shared_cap or 0,
         }

@@ -300,10 +300,16 @@ def test_heads_of_a_part_of_a_lane_tile_share_a_row_of_the_pool():
             assert margins(ff, r, sizes).max() <= 2 * LOGIT_ATOL
         # a page of 8 tokens x 1 row: the kernel's turn takes a block of as
         # many pages as the table is wide, and fetches no page past a slot's
-        # last live one (the pages past its last whole block go one a turn)
+        # last live one (the pages past its last whole block go one a turn).
+        # The two hits hold the document's 3 pages together: counted once a
+        # step in what must be read, fetched once for both by the kernel
+        # (one group), once each by the einsum's gather
         st = eng.stats()
         assert st["paged_turn_pages"] == (8 if impl == "pallas" else 1)
-        assert st["kv_streamed_bytes"] == st["kv_read_bytes"] > 0
+        assert st["kv_attended_bytes"] > st["kv_read_bytes"] > 0
+        assert st["kv_streamed_bytes"] == st[
+            "kv_read_bytes" if impl == "pallas" else "kv_attended_bytes"]
+        assert (st["shared_groups"] > 0) == (impl == "pallas")
 
 
 def test_a_chunk_loop_is_the_unrolled_chunks(ff):

@@ -297,7 +297,10 @@ def test_engine_counts_what_the_turns_fetch(ff, monkeypatch, turn):
     fetch for them, from `paged_turn_pages`, the pages a turn takes on its
     pools (a static of their shapes and the table's width). A slot's whole
     blocks and, one a turn, the pages past them: never less than is read,
-    the same at one page a turn, and under this tail the same at any."""
+    the same at one page a turn, and under this tail the same at any. With
+    pages that an earlier slot holds too (ISSUE 49) a step reads each
+    distinct page once and fetches it once a group: what the slots attend,
+    less `held_again` and less `saved` pages a step."""
     g = _set_turn(monkeypatch, 4, 2, 8, turn)
     eng = _engine(ff, "pallas")
     assert eng.stats()["paged_turn_pages"] == g
@@ -306,13 +309,20 @@ def test_engine_counts_what_the_turns_fetch(ff, monkeypatch, turn):
     budget = np.asarray([32, 32])
     # two steps a slot: 2 and 3 live pages, 6 and 8 (the budget's clamp)
     frontier = np.asarray([[7, 8], [22, 40]])
-    read, streamed = eng._note_pages_touched(frontier, budget)
+    attended, read, streamed = eng._note_pages_touched(frontier, budget)
     per_page = eng.page_size * eng.stats()["kv_bytes_per_token"]
     assert read == (2 + 3 + 6 + 8) * per_page
-    assert streamed == read
+    assert streamed == read == attended
     st = eng.stats()
-    assert (st["kv_read_bytes"], st["kv_streamed_bytes"]) == (read, streamed)
+    assert (st["kv_read_bytes"], st["kv_streamed_bytes"],
+            st["kv_attended_bytes"]) == (read, streamed, attended)
     assert st["pages_touched"] == st["last_pages_touched"] == 3 + 8
+    # both slots begin with the same 2 pages, fetched once for the pair
+    # (or, the group split, not at all: saved 0)
+    for saved in (2, 0):
+        assert eng._note_pages_touched(frontier, budget, 2, saved) == (
+            attended, attended - 2 * 2 * per_page,
+            attended - 2 * saved * per_page)
 
 
 FLASH_SEQ, FLASH_HEADS, FLASH_DIM = 256, 2, 16
@@ -1057,3 +1067,183 @@ def test_an_op_with_wide_keys_holds_flat_pages_and_both_impls_agree():
         (a, ca), (b, cb) = pools["einsum"], pools["pallas"]
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
         np.testing.assert_array_equal(np.asarray(ca["k"]), np.asarray(cb["k"]))
+
+
+# ---- a page several live slots hold, streamed once for all of them --------
+#
+# The shared-page form (ISSUE 49): the slots of a group hold the same pool
+# pages in their first columns; `_paged_shared_kernel` streams them once a
+# group and the per-slot kernel goes on from the partial state that leaves a
+# member. Every case is held to the oracle, which knows nothing of groups.
+
+# page size 4, a table of 9 pages. A slot: (document or None, row_len, pad,
+# frontier); `docs`: the pages a document's holders share. `cap`: the most
+# members of a group; `groups`: None for `shared_page_groups`' own answer,
+# else [(members, pages)] handed to the kernel as they are
+SHARED_CASES = {
+    # two groups of 2, one slot that holds a document alone (a group of 1
+    # is no group: it streams its pages itself), one with no document
+    "pairs-and-loners": dict(
+        docs={"a": 3, "b": 5, "c": 2},
+        slots=[("a", 13, 16, 18), ("b", 21, 24, 27), ("a", 14, 16, 16),
+               (None, 9, 12, 12), ("b", 22, 24, 24), ("c", 9, 12, 14)],
+        cap=4, want=[([0, 2], 3), ([1, 4], 5)]),
+    # a group of 5: three sub-blocks of two members, the last half empty
+    "five-members": dict(
+        docs={"a": 4},
+        slots=[("a", 17, 20, 20 + i) for i in range(5)] + [(None, 3, 4, 5)],
+        cap=8, want=[([0, 1, 2, 3, 4], 4)]),
+    # 7 holders under a cap of 4: two groups, each streams the document
+    "more-than-the-cap": dict(
+        docs={"a": 3},
+        slots=[("a", 13 + i % 3, 16, 17 + i) for i in range(7)],
+        cap=4, want=[([0, 2, 4, 6], 3), ([1, 3, 5], 3)]),
+    # nobody shares: the arrays hold no group, every grid step of the
+    # shared stream idles
+    "no-shared-page": dict(
+        docs={},
+        slots=[(None, 3, 4, 9), (None, 1, 2, 3), (None, 7, 8, 19),
+               (None, 2, 4, 6)],
+        cap=4, want=[]),
+    # every page shared but the one the slot writes
+    "all-but-the-last-page": dict(
+        docs={"a": 6},
+        slots=[("a", 24, 24, 24), ("a", 24, 24, 26), ("a", 24, 24, 27)],
+        cap=4, want=[([0, 1, 2], 6)]),
+    # one member a page past the document, one six pages
+    "unequal-frontiers": dict(
+        docs={"a": 2},
+        slots=[("a", 9, 12, 12), ("a", 11, 12, 35), ("a", 8, 8, 21)],
+        cap=4, want=[([0, 1, 2], 2)]),
+    # idle slots (zeroed rows, scratch page 0) between the members
+    "inactive-beside-live": dict(
+        docs={"a": 3},
+        slots=[(None, 0, 0, 0), ("a", 13, 16, 18), (None, 0, 0, 0),
+               ("a", 14, 16, 16), (None, 0, 0, 0), (None, 5, 8, 9)],
+        cap=4, want=[([1, 3], 3)]),
+    # slot 2 took the document's first 2 pages only, then pages of its own:
+    # by the rule it is left out of the pair that shares all 5
+    "agrees-on-the-first-pages": dict(
+        docs={"a": 5}, partial={2: 2},
+        slots=[("a", 21, 24, 24), ("a", 22, 24, 27), ("a", 23, 24, 25)],
+        cap=4, want=[([0, 1], 5)]),
+    # the same tables, the three handed over as ONE group of the 2 pages
+    # they all hold: the rest of the document is each member's own to stream
+    "one-group-of-the-first-pages": dict(
+        docs={"a": 5}, partial={2: 2},
+        slots=[("a", 21, 24, 24), ("a", 22, 24, 27), ("a", 23, 24, 25)],
+        cap=4, groups=[([0, 1, 2], 2)]),
+}
+# (query heads, KV heads, key width, value width, sink): the plain 4-d
+# pool, granite's two heads of 64 a row, MiMo's flat row a token with a sink
+SHARED_LAYOUTS = {"plain": (4, 2, 16, 16, False),
+                  "packed-64": (8, 4, 64, 64, False),
+                  "flat-192-sink": (16, 4, 192, 128, True)}
+
+
+def _shared_inputs(case, layout, seed):
+    page, width = 4, 9
+    h, kvh, d, dv, has_sink = SHARED_LAYOUTS[layout]
+    rs = np.random.RandomState(seed)
+    slots = case["slots"]
+    b = len(slots)
+    n_pages = 1 + b * width + sum(case["docs"].values())
+    k = rs.randn(n_pages, page, kvh, d).astype(np.float32)
+    v = rs.randn(n_pages, page, kvh, dv).astype(np.float32)
+    free = list(rs.permutation(np.arange(1, n_pages)))
+    docs = {name: [free.pop() for _ in range(n)]
+            for name, n in case["docs"].items()}
+    table = np.zeros((b, width), np.int32)
+    for s, (doc, row_len, pad, wp) in enumerate(slots):
+        if wp == 0:
+            continue                    # idle: the scratch page everywhere
+        table[s] = [free.pop() for _ in range(width)]
+        if doc is not None:
+            held = case.get("partial", {}).get(s, len(docs[doc]))
+            table[s, :held] = docs[doc][:held]
+    row_len, pad, wp = (np.asarray(x, np.int32)
+                        for x in zip(*[s[1:] for s in slots]))
+    q = jnp.asarray(rs.randn(b, 1, h, d), jnp.float32)
+    sink = jnp.asarray(2 * rs.randn(h), jnp.float32) if has_sink else None
+    return q, k, v, table, wp[:, None], row_len, pad, sink
+
+
+@pytest.mark.parametrize("layout", sorted(SHARED_LAYOUTS))
+@pytest.mark.parametrize("name", sorted(SHARED_CASES))
+def test_shared_pages_streamed_once_match_the_oracle(monkeypatch, name,
+                                                     layout):
+    """The shared-page form against the einsum oracle, on a pool whose
+    pages that are live for NO slot hold NaN: groups read off the tables
+    (`shared_page_groups`) or handed over, sub-blocks of two members."""
+    from flexflow_tpu.ops import pallas_kernels
+    from flexflow_tpu.runtime.kv_pool import shared_page_groups
+
+    case = SHARED_CASES[name]
+    h, kvh, d, dv, _ = SHARED_LAYOUTS[layout]
+    page = 4
+    # query rows a member puts through one matmul: a flat pool's go a KV
+    # head at a time
+    per = h // kvh if layout == "flat-192-sink" else h
+    monkeypatch.setattr(pallas_kernels, "_SHARED_BLOCK_ROWS", 2 * per)
+    assert pallas_kernels._shared_block_members(case["cap"], per) == 2
+    q, k, v, table, wp, row_len, pad, sink = _shared_inputs(
+        case, layout, len(name))
+    b = table.shape[0]
+    groups = case.get("groups")
+    if groups is None:
+        active = wp[:, 0] > 0
+        groups = shared_page_groups(
+            table, np.where(active, row_len // page, 0), case["cap"])
+        assert sorted(groups) == case["want"]
+    shared = pallas_kernels.pack_shared_groups(groups, b, case["cap"])
+    last = np.maximum(wp.max(axis=1), row_len - 1) // page
+    live_pages = {int(table[s, t]) for s in range(b)
+                  for t in range(int(last[s]) + 1)}
+    dead = np.asarray([p for p in range(k.shape[0]) if p not in live_pages])
+    if layout == "flat-192-sink":
+        pool = {"k": jnp.asarray(k.reshape(*k.shape[:2], -1)),
+                "v": jnp.asarray(v.reshape(*v.shape[:2], -1))}
+        idx = np.arange(table.shape[1] * page)
+        live = (idx[None] < row_len[:, None]) \
+            | ((idx[None] >= pad[:, None]) & (idx[None] <= wp))
+        want = _flat_oracle(q, pool, table, jnp.asarray(live)[:, None, :],
+                            kvh, 0.07, sink)
+    else:
+        pool = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
+        if layout == "packed-64":
+            pool = {n: x.reshape(x.shape[0], page, kvh // 2, 128)
+                    for n, x in pool.items()}
+        want = _oracle(q, pool, jnp.asarray(table), jnp.asarray(wp),
+                       jnp.asarray(row_len), jnp.asarray(pad), 0.07)
+    poisoned = {n: x.at[dead].set(jnp.nan) for n, x in pool.items()}
+    out = paged_attention_fwd_pallas(
+        q, poisoned["k"], poisoned["v"], jnp.asarray(table), jnp.asarray(wp),
+        jnp.asarray(row_len), jnp.asarray(pad), 0.07, sink=sink,
+        kv_heads=kvh, shared=shared)
+    assert out.shape == want.shape and bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
+
+
+def test_shared_members_cap_follows_the_heads():
+    """The most members of a group is a static of the op's heads: whole
+    sub-blocks of 128 query rows, 512 rows in all."""
+    from flexflow_tpu.ops import pallas_kernels as pk
+
+    assert [pk.shared_members_cap(h) for h in (64, 40, 32, 16, 4)] \
+        == [8, 12, 16, 32, 128]
+    assert [pk._shared_block_members(m, h)
+            for m, h in ((8, 64), (12, 40), (16, 32), (6, 4), (5, 64))] \
+        == [2, 3, 4, 6, 1]
+
+
+def test_shared_form_refuses_what_keeps_the_per_slot_path():
+    """A verify slab, a window's ring and a quantized pool are the per-slot
+    kernel's: handing them groups is a caller's error."""
+    from flexflow_tpu.ops import pallas_kernels
+
+    q, pool, table, wp, row_len, pad, _ = _stream_inputs("verify-straddle", 3)
+    shared = pallas_kernels.pack_shared_groups([], table.shape[0], 2)
+    with pytest.raises(AssertionError, match="shared-page form"):
+        paged_attention_fwd_pallas(
+            q, pool["k"], pool["v"], jnp.asarray(table), jnp.asarray(wp),
+            jnp.asarray(row_len), jnp.asarray(pad), 0.29, shared=shared)

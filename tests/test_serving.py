@@ -793,3 +793,175 @@ def test_explicit_buckets_and_per_request_max_new(ff):
                            max_new_tokens=r.max_new_tokens)
         np.testing.assert_array_equal(np.asarray(r.tokens, np.int32),
                                       solo[0, r.prompt.size:])
+
+
+# ---- a page several live slots hold, streamed once (ISSUE 49) -------------
+
+
+def _doc_questions(seed):
+    """Two documents of whole pages (6 and 4 pages of 4) and five questions
+    that begin with one of them."""
+    rs = np.random.RandomState(seed)
+    docs = [rs.randint(1, VOCAB, (24,)).astype(np.int32),
+            rs.randint(1, VOCAB, (16,)).astype(np.int32)]
+    asks = [np.concatenate([docs[i % 2], rs.randint(1, VOCAB, (3 + i,))])
+            .astype(np.int32) for i in range(5)]
+    return docs, asks
+
+
+def _hand_counts(eng, k):
+    """What the NEXT decode dispatch of `k` steps attends, must read and
+    would stream with every document fetched once for its holders, counted
+    from the page tables slot by slot and page by page: (attended pages,
+    distinct pages, streamed pages, first step's attended tokens, first
+    step's distinct tokens, groups)."""
+    ps = eng.page_size
+    live = [s for s in range(eng.slots) if eng.active[s]]
+    wp = {s: int(eng.prompt_pad[s] + eng.emitted[s] - 1) for s in live}
+    last = {s: eng.slot_req[s].bucket + eng.slot_req[s].max_new_tokens - 1
+            for s in live}
+    # a slot's document: the whole pages of its prompt another slot holds too
+    holders = {}
+    for s in live:
+        run = tuple(int(p) for p in eng.page_tables[s, :eng.row_len[s] // ps])
+        for t in live:
+            if t != s:
+                other = eng.page_tables[t, :eng.row_len[t] // ps]
+                n = 0
+                while n < min(len(run), len(other)) and run[n] == other[n]:
+                    n += 1
+                if n:
+                    holders.setdefault(run[:n], set()).update((s, t))
+    attended = distinct = streamed = 0
+    for i in range(k):
+        pages = {s: min(wp[s] + i, last[s]) // ps + 1 for s in live}
+        attended += sum(pages.values())
+        distinct += len({int(eng.page_tables[s, c])
+                         for s in live for c in range(pages[s])})
+        streamed += sum(pages.values()) - sum(
+            (len(m) - 1) * len(run) for run, m in holders.items())
+    first = {s: min(wp[s], last[s]) + 1 for s in live}
+    dup = sum((len(m) - 1) * len(run) for run, m in holders.items())
+    return (attended, distinct, streamed, sum(first.values()),
+            sum(first.values()) - dup * ps, len(holders))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "einsum"])
+def test_shared_documents_are_streamed_once_and_read_the_same(ff, impl):
+    """Two seated documents, five concurrent questions: every request
+    emits the tokens it emits ALONE, the documents' pages are bitwise what
+    they were, and the dispatch's counts are the hand count: each distinct
+    page once in `context_tokens` / `kv_read_bytes`, a document fetched
+    once for its holders in `kv_streamed_bytes` / `shared_pages_saved`
+    where the kernel forms groups (`pallas`), once a slot where the einsum
+    gathers (`kv_streamed_bytes` == `kv_attended_bytes`, no group)."""
+    from flexflow_tpu.runtime import telemetry
+
+    docs, asks = _doc_questions(7)
+    eng = ff.make_serving_engine(serve_slots=6, kv_page_size=4, kv_pages=80,
+                                 max_seq_len=64, decode_chunk=2,
+                                 prefix_cache=True,
+                                 paged_attention_impl=impl)
+    for d in docs:
+        eng.prefill_into_cache(d)
+    held = sorted({int(p) for n in eng.prefix_cache._iter_nodes()
+                   for p in [n.page]})
+    assert len(held) == 10
+    before = {op: {n: np.asarray(x[np.asarray(held)])
+                   for n, x in eng.kv.pool[op].items()}
+              for op in eng.kv.pool}
+    reqs = [eng.submit(a, 6) for a in asks]
+    eng.step()                      # all five seated, one dispatch done
+    assert int(eng.active.sum()) == 5
+    k, page_bytes = 2, 4 * eng.stats()["kv_bytes_per_token"]
+    attended, distinct, streamed, ctx, ctx_distinct, groups = \
+        _hand_counts(eng, k)
+    assert groups == 2 and distinct < attended
+    st0 = eng.stats()
+    eng.step()
+    st1 = eng.stats()
+    moved = {n: st1[n] - st0[n] for n in (
+        "kv_attended_bytes", "kv_read_bytes", "kv_streamed_bytes",
+        "shared_groups", "shared_pages_saved")}
+    sharing = impl == "pallas"
+    assert moved == {
+        "kv_attended_bytes": attended * page_bytes,
+        "kv_read_bytes": distinct * page_bytes,
+        "kv_streamed_bytes": (streamed if sharing else attended) * page_bytes,
+        "shared_groups": groups if sharing else 0,
+        "shared_pages_saved": attended - streamed if sharing else 0}
+    span = telemetry.tracer().events(name="decode_dispatch")[-1]["args"]
+    assert span["context_tokens"] == ctx_distinct
+    assert span["context_tokens_attended"] == ctx
+    assert span["shared_groups"] == moved["shared_groups"]
+    assert span["shared_pages_saved"] == moved["shared_pages_saved"]
+    assert span["program"] == ("decode_k2_shared6" if sharing
+                               else "decode_k2")
+    while eng.pending():
+        eng.step()
+    for r in reqs:
+        solo = ff.generate(r.prompt[None, :], max_new_tokens=6)
+        np.testing.assert_array_equal(
+            np.asarray(r.tokens, np.int32), solo[0, r.prompt.size:],
+            err_msg=f"request {r.rid} diverged from its solo run")
+    for op, arrays in before.items():
+        for n, x in arrays.items():
+            np.testing.assert_array_equal(
+                np.asarray(eng.kv.pool[op][n][np.asarray(held)]), x,
+                err_msg=f"{op}/{n}: a document's page changed")
+    assert eng.stats()["prefix_refs_live"] == 0
+
+
+def test_no_shared_page_counts_and_program_are_the_per_slot_ones(ff):
+    """Distinct prompts under a prefix cache: nobody hits, so the decode
+    program's key is the per-slot one and every count is the per-slot
+    formula: attended = read = streamed, no group, nothing saved."""
+    from flexflow_tpu.runtime import telemetry
+
+    eng = ff.make_serving_engine(serve_slots=4, kv_page_size=4,
+                                 max_seq_len=64, decode_chunk=2,
+                                 prefix_cache=True,
+                                 paged_attention_impl="pallas")
+    eng.run(_prompts(11, [9, 13, 6, 10]), max_new_tokens=5)
+    st = eng.stats()
+    assert st["shared_members_cap"] == 4
+    assert [k for k in eng._programs if k[0] == "decode"] == [("decode", 2)]
+    assert st["kv_attended_bytes"] == st["kv_read_bytes"] \
+        == st["kv_streamed_bytes"] > 0
+    assert st["shared_groups"] == st["shared_pages_saved"] == 0
+    for e in telemetry.tracer().events(name="decode_dispatch")[-2:]:
+        a = e["args"]
+        assert a["context_tokens"] == a["context_tokens_attended"]
+        assert a["kv_read_bytes"] == a["kv_streamed_bytes"] \
+            == a["kv_attended_bytes"]
+        assert a["shared_groups"] == a["shared_pages_saved"] == 0
+        assert a["program"] == "decode_k2"
+
+
+def test_shared_page_groups_follow_the_tables():
+    """`shared_page_groups`: rows that begin alike are one group, a slot
+    alone or idle is none, a group over the cap is split evenly, and a
+    slot that shares a document's first pages only is left out of the
+    group that shares all of them."""
+    from flexflow_tpu.runtime.kv_pool import shared_page_groups
+
+    t = np.zeros((8, 6), np.int32)
+    t[0] = [11, 12, 13, 14, 40, 41]
+    t[1] = [11, 12, 13, 14, 42, 43]
+    t[2] = [21, 22, 44, 45, 46, 47]
+    t[3] = [11, 12, 13, 14, 48, 49]
+    t[4] = [21, 22, 50, 51, 52, 53]
+    t[5] = [11, 12, 54, 55, 56, 57]     # the first two pages only
+    t[6] = [31, 32, 33, 58, 59, 60]     # alone with its document
+    share = np.asarray([4, 4, 2, 4, 2, 4, 3, 0])
+    assert sorted(shared_page_groups(t, share, 8)) == [
+        ([0, 1, 3], 4), ([2, 4], 2)]
+    assert sorted(shared_page_groups(t, share, 2)) == [
+        ([0, 3], 4), ([2, 4], 2)]
+    assert shared_page_groups(t, share, 1) == []
+    assert shared_page_groups(t, np.zeros(8, int), 8) == []
+    # without the three whole-document holders the two-page run IS the
+    # longest there is
+    share[[0, 1]] = 0
+    assert sorted(shared_page_groups(t, share, 8)) == [
+        ([2, 4], 2), ([3, 5], 2)]
