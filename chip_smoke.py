@@ -493,6 +493,32 @@ def kernel_cases(z: Sizes):
             # float32 throughout; y sums n products of order 1
             1e-4))
 
+    # the power-retention decode update over a pool of float32 states (S by
+    # phi's diagonals, z beside it), live slots only, against XLA's loop;
+    # phi is formed in the kernel by lane rotations
+    from flexflow_tpu.ops.retention import retention_state_update
+
+    def retention_args(s, kv, r, hd):
+        def make(rs):
+            live = np.ones((s,), bool)
+            live[[0, s // 2, s - 1]] = False
+            nd = hd // 2 + 1
+            f = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.float32)
+            return (f(s, kv, nd, hd, hd) * 0.1, f(s, kv, nd, hd) * 0.1,
+                    jnp.asarray(rs.uniform(0.9, 1.0, (s, kv)), jnp.float32),
+                    f(s, kv, r, hd), f(s, kv, hd), f(s, kv, hd),
+                    jnp.asarray(live))
+        return make
+
+    for shape in [(4, 2, 5, 128)] + ([(24, 8, 5, 128)] if z is FULL else []):
+        cases.append(KernelCase(
+            "retention_state_update slots{} kv{} r{} hd{} f32".format(*shape),
+            retention_args(*shape), pk.retention_state_update_pallas,
+            retention_state_update,
+            # float32 throughout; a read-out sums 8320 products of order
+            # 1e-2 against XLA's HIGHEST-precision einsum
+            1e-4))
+
     # fused add+layernorm at the model's hidden, forward (inference) and
     # with the backward's saved statistics; plus the 4096 x 4096 width the
     # README's encoder runs, which the row-block budget must fit

@@ -132,6 +132,7 @@ class OperatorType(enum.Enum):
     OP_ROTARY_EMBEDDING = enum.auto()
     OP_MAMBA2 = enum.auto()  # selective state-space mixer (ops/mamba.py)
     OP_GATED_MLP = enum.auto()  # SwiGLU feed-forward as one op (ops/dense.py)
+    OP_POWER_RETENTION = enum.auto()  # power-retention mixer (ops/retention.py)
 
 
 # --- dtype lowering ---------------------------------------------------------

@@ -360,6 +360,25 @@ class FFModel:
             n_groups, state_size, conv_kernel=conv_kernel,
             chunk_size=chunk_size, eps=eps))
 
+    def power_retention(self, input: Tensor, num_heads: int,
+                        num_kv_heads: int, head_dim: int,
+                        rope_theta: float = 1e6, chunk_size: int = 128,
+                        eps: float = 1e-6, norm_eps: float = 1e-5,
+                        decay_floor=(1e-4, 1e-2),
+                        name: Optional[str] = None) -> Tensor:
+        """Power-retention mixer (ops/retention.py): `num_heads` query heads
+        of `head_dim` over `num_kv_heads` key / value heads, weights the
+        square of the scaled scores under a gated decay; its cache is one
+        fixed-size state a sequence (the keys' symmetric square times the
+        values) and no per-token row."""
+        from flexflow_tpu.ops.retention import PowerRetention
+
+        return self._add(PowerRetention(
+            self, self._name("power_retention", name), [input], num_heads,
+            num_kv_heads, head_dim, rope_theta=rope_theta,
+            chunk_size=chunk_size, eps=eps, norm_eps=norm_eps,
+            decay_floor=decay_floor))
+
     def gated_mlp(self, input: Tensor, hidden_dim: int,
                   name: Optional[str] = None) -> Tensor:
         """SwiGLU feed-forward as one op (ops/dense.py `GatedMLP`): one

@@ -2468,3 +2468,155 @@ def mamba_state_update_pallas(h, decay, dtx, bm, cm, live):
     )(src, live.astype(jnp.int32), h, *lane_rows(decay, dtx, g),
       bm.reshape(s, g, 1, n), cm.reshape(s, g, 1, n))
     return y.reshape(dtx.shape), new
+
+
+# ---- power retention: the one-step state update ------------------------------
+# ops/retention.py holds a sequence's state as S (KV, ND, hd, hd) float32: by
+# phi's diagonals (ND = hd / 2 + 1), the value along the sublanes, the key
+# index a along the lanes; z (KV, ND, hd) beside it. A decode step is
+#     S <- decay S + phi(k)[d, a] v[v],   num_i[v] = sum_{d, a} phi(q_i)[d, a] S[d, v, a]
+# and the same of z with v = 1: a pure stream, every element of a live slot's
+# state read once, decayed, given one product and written back (2 x 34 MB a
+# slot and layer at hd 128, 8 KV heads) for 2 + 2 R FLOPs an element. One grid
+# step is one (KV head, slot) block of 4.26 MB, aliased in and out; the SLOT
+# is the inner grid axis, so a dead slot's step maps to the block of the
+# nearest live slot before it and nothing is fetched or written for it
+# (`_mamba_update_kernel`'s rule). phi is formed here from the step's tile of
+# rows, phi(x)[d] = c_d x roll(x, d) / hd: ND lane rotations of ONE register
+# that holds a KV group's R query heads, its key, its value and its decay.
+
+# rows of the step's tile: R query heads, k, v, the decay; one f32 register
+RETENTION_TILE_ROWS = 8
+# value rows a turn of the inner loop carries in registers (R accumulators of
+# this many rows each)
+_RETENTION_STRIP = 32
+
+
+def _retention_update_kernel(src_ref, live_ref, s_ref, z_ref, x_ref, o_ref,
+                             zo_ref, num_ref, den_ref, ph_ref, acc_ref, *,
+                             group: int, coef):
+    slot = pl.program_id(1)
+    nd, hd = s_ref.shape[2], s_ref.shape[4]
+    strip = min(_RETENTION_STRIP, hd)
+    r_k, r_v, r_dec = group, group + 1, group + 2
+
+    @pl.when(live_ref[slot] > 0)
+    def _():
+        x = x_ref[0, 0]                                    # (8, hd)
+        # phi of every row at once: row i < R is phi(q_i), row R is phi(k)
+        for d in range(nd):
+            rolled = pltpu.roll(x, d, 1) if d else x
+            ph_ref[d] = x * rolled * coef[d]
+        dec = x[r_dec:r_dec + 1]                           # (1, hd), all equal
+        # the value along the sublanes, the same in every lane
+        vcol = jnp.broadcast_to(x[r_v:r_v + 1], (hd, hd)).T
+
+        for st in range(hd // strip):
+            rows = pl.ds(st * strip, strip)
+            vs = vcol[st * strip:(st + 1) * strip]
+
+            def diagonal(d, carry, rows=rows, vs=vs, first=(st == 0)):
+                acc, dacc = carry
+                t = ph_ref[d]                              # (8, hd)
+                new = dec * s_ref[0, 0, d, rows, :] + vs * t[r_k:r_k + 1]
+                o_ref[0, 0, d, rows, :] = new
+                acc = tuple(a + new * t[i:i + 1] for i, a in enumerate(acc))
+                if first:
+                    zn = dec * z_ref[0, 0, pl.ds(d, 1), :] + t[r_k:r_k + 1]
+                    zo_ref[0, 0, pl.ds(d, 1), :] = zn
+                    dacc = dacc + t * zn
+                return acc, dacc
+
+            zero = jnp.zeros((strip, hd), jnp.float32)
+            acc, dacc = jax.lax.fori_loop(
+                0, nd, diagonal,
+                ((zero,) * group,
+                 jnp.zeros((RETENTION_TILE_ROWS, hd), jnp.float32)))
+            for i in range(group):
+                acc_ref[i, rows, :] = acc[i]
+            if st == 0:
+                # rows < R: phi(q_i) . z, still spread over the lanes
+                den_ref[0, 0] = dacc
+        for i in range(group):
+            # sum over a (the lanes): as a row over the values
+            num_ref[0, 0, i:i + 1, :] = jnp.sum(acc_ref[i].T, axis=0,
+                                                keepdims=True)
+
+    @pl.when(live_ref[slot] == 0)
+    def _():
+        num_ref[...] = jnp.zeros(num_ref.shape, num_ref.dtype)
+        den_ref[...] = jnp.zeros(den_ref.shape, den_ref.dtype)
+
+    # nothing live at all: every step of this KV head sits on slot 0's block,
+    # which then goes back as it came
+    @pl.when(jnp.logical_and(slot == 0, src_ref[0] < 0))
+    def _():
+        o_ref[...] = s_ref[...]
+        zo_ref[...] = z_ref[...]
+
+
+@functools.partial(jax.jit, inline=True)
+def retention_state_update_pallas(st, z, decay, q, k, v, live):
+    """One token of `S <- decay S + phi(k) v^T`, `z <- decay z + phi(k)`,
+    `num_i = phi(q_i)^T S`, `den_i = phi(q_i) . z` on a pool of states, live
+    slots only, in place: st (S, KV, ND, hd, hd), z (S, KV, ND, hd) f32 (the
+    held layout of ops/retention.py; aliased to the outputs), decay (S, KV)
+    f32, q (S, KV, R, hd), k, v (S, KV, hd) f32, live (S,) bool -> (num (S,
+    KV, R, hd), den (S, KV, R), zero for a dead slot; st; z). hd is one
+    register's lanes and R + 3 rows fit its sublanes."""
+    from flexflow_tpu.ops.retention import phi_coefficients
+
+    s, kv, nd, hd, _ = st.shape
+    group = q.shape[2]
+    rows = RETENTION_TILE_ROWS
+    assert group + 3 <= rows, (group, rows)
+    idx = jnp.arange(s, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, idx, -1))
+    first = jnp.min(jnp.where(live, idx, s))
+    src = jnp.where(before >= 0, before, first)
+    # `src[0] < 0` tells the kernel nothing is live; the index map clamps it
+    src = jnp.where(first < s, src, -1).astype(jnp.int32)
+    # the step's tile: R query heads, k, v, the decay across the lanes
+    x = jnp.concatenate(
+        [q, k[:, :, None], v[:, :, None],
+         jnp.broadcast_to(decay[..., None, None], (s, kv, 1, hd)),
+         jnp.zeros((s, kv, rows - group - 3, hd), jnp.float32)], axis=2)
+    coef = tuple(float(c) for c in phi_coefficients(hd))
+
+    def state5(g, i, src, live):
+        return (jnp.maximum(src[i], 0), g, 0, 0, 0)
+
+    def state4(g, i, src, live):
+        return (jnp.maximum(src[i], 0), g, 0, 0)
+
+    def own(g, i, *_):
+        return (i, g, 0, 0)
+
+    tile = pl.BlockSpec((1, 1, rows, hd), own)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(kv, s),
+        in_specs=[pl.BlockSpec((1, 1, nd, hd, hd), state5),
+                  pl.BlockSpec((1, 1, nd, hd), state4), tile],
+        out_specs=[pl.BlockSpec((1, 1, nd, hd, hd), state5),
+                   pl.BlockSpec((1, 1, nd, hd), state4), tile, tile],
+        scratch_shapes=[pltpu.VMEM((nd, rows, hd), jnp.float32),
+                        pltpu.VMEM((group, hd, hd), jnp.float32)],
+    )
+    block = nd * hd * hd * 4
+    new, zn, num, den = pl.pallas_call(
+        functools.partial(_retention_update_kernel, group=group, coef=coef),
+        name="retention_state_update",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(st.shape, st.dtype),
+                   jax.ShapeDtypeStruct(z.shape, z.dtype),
+                   jax.ShapeDtypeStruct((s, kv, rows, hd), jnp.float32),
+                   jax.ShapeDtypeStruct((s, kv, rows, hd), jnp.float32)],
+        # operands 2 and 3 (after the two prefetched scalars): S and z
+        input_output_aliases={2: 0, 3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(4 * block + (24 << 20))),
+        interpret=_interpret(),
+    )(src, live.astype(jnp.int32), st, z, x)
+    return num[:, :, :group], den[:, :, :group].sum(-1), new, zn

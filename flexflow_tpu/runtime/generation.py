@@ -165,7 +165,10 @@ class Generator:
                     "path; generate() supports transformer decoder graphs")
         cached = self.attn_ops + self.state_ops
         if not cached:
-            raise ValueError("graph has no attention ops; nothing to cache")
+            raise ValueError(
+                "graph has neither an attention op (per-token K/V rows) nor "
+                "a recurrent-state op: nothing to cache, nothing to decode "
+                "from")
         # dropless MoE ops count their routing inside the serve programs
         self.dropless_moe_ops = [
             op for op in model.ops
@@ -333,7 +336,7 @@ class Generator:
                 if getattr(op, "state_cache_protocol", False):
                     out, nc = self._state_step(
                         op, p, xs, caches[op.name], pos, paged, row_lengths,
-                        chunk_start, gather_last)
+                        chunk_start, gather_last, rope_pos)
                     new_caches[op.name] = nc
                     outs = [out]
                 elif getattr(op, "kv_cache_protocol", False):
@@ -434,7 +437,7 @@ class Generator:
 
     @staticmethod
     def _state_step(op, p, xs, state, pos, paged, row_lengths, chunk_start,
-                    gather_last):
+                    gather_last, rope_pos=None):
         """A recurrent-state op's part of a walk: the slab advances the
         sequence's one state (a prefill slab from `chunk_start`, padding
         rows past `row_lengths` leaving it alone; a decode token; the
@@ -445,9 +448,15 @@ class Generator:
                 raise NotImplementedError(
                     f"{op.name}: a recurrent state cannot be verified at "
                     "several positions in one pass (speculative decoding)")
+            at = {"positions": paged["rope_pos"]} if getattr(
+                op, "state_wants_positions", False) else {}
             return op.paged_step_forward(p, xs, state, paged["row_len"] > 0,
-                                         impl=paged.get("impl"))
+                                         impl=paged.get("impl"), **at)
         if pos is not None:
+            if getattr(op, "state_wants_positions", False):
+                # a rotary op: the token's own position (a ragged row's)
+                return op.step_forward(
+                    p, xs, state, pos if rope_pos is None else rope_pos)
             return op.step_forward(p, xs, state)
         if gather_last:
             return op.last_forward(p, xs, state)

@@ -485,10 +485,11 @@ class ServingEngine:
         if self.speculate_k < 0:
             raise ValueError(
                 f"speculate_k={self.speculate_k}: must be >= 0")
-        if not self.gen.attn_ops:
-            raise NotImplementedError(
-                "the serving engine pages per-token K/V rows: a graph of "
-                "recurrent-state ops alone has none (generate() runs it)")
+        # a graph whose cached ops are ALL recurrent states (every mixer a
+        # state: models/brumby.py) holds no per-token row: the page pool is
+        # then a free list with no array behind it (a page is only the unit
+        # in which the trie keys a prefix and a lease counts a context), and
+        # what fills the chip is the slots' states and their snapshots
         # snapshots of the recurrent state on the trie's nodes (runtime/
         # kv_pool.py): how many the pool holds, for a model with state
         # ops under a prefix cache; nothing is allocated for any other
@@ -611,6 +612,11 @@ class ServingEngine:
         self._bf16_bytes_per_token = sum(
             op.cache_bytes_per_token() for op in self.gen.attn_ops
             if op_keeps(op) is None)
+        # what a bf16 pool of the same geometry would hold against this
+        # one (1 where no op keeps a row: nothing to compare)
+        self._kv_capacity_vs_bf16 = (
+            self._bf16_bytes_per_token / self._kv_bytes_per_token
+            if self._kv_bytes_per_token else 1.0)
 
         # the paged pool's decode attention and its prefill/append page
         # write, one choice for both, resolved ONCE here ("auto" -> the
@@ -662,7 +668,7 @@ class ServingEngine:
             "weight_dtype=%s (%.1f KV bytes/token, %.2fx bf16 capacity)",
             self.paged_attention_impl, self.kv_cache_dtype,
             self.weight_dtype, self._kv_bytes_per_token,
-            self._bf16_bytes_per_token / self._kv_bytes_per_token)
+            self._kv_capacity_vs_bf16)
 
         # ---- paged LoRA adapter pool (ISSUE 14) ----
         # fixed-geometry adapter pages mirroring the KV pool's design: a
@@ -3469,13 +3475,14 @@ class ServingEngine:
             "state_snapshots_taken": (pc.snapshots_taken if pc else 0),
             "state_snapshots_evicted": (pc.snapshots_evicted if pc else 0),
             "kv_bytes_per_token": round(self._kv_bytes_per_token, 3),
-            "tokens_per_pool_gb": int((1 << 30)
-                                      / self._kv_bytes_per_token),
-            "kv_capacity_vs_bf16": round(
-                self._bf16_bytes_per_token / self._kv_bytes_per_token, 3),
+            # (0 tokens a GB where a token takes no bytes: a graph of
+            # recurrent states alone, whose context is free)
+            "tokens_per_pool_gb": (
+                int((1 << 30) / self._kv_bytes_per_token)
+                if self._kv_bytes_per_token else 0),
+            "kv_capacity_vs_bf16": round(self._kv_capacity_vs_bf16, 3),
             "kv_effective_page_capacity": round(
-                self.page_size * self._bf16_bytes_per_token
-                / self._kv_bytes_per_token, 1),
+                self.page_size * self._kv_capacity_vs_bf16, 1),
             # KV-pool observability (ROADMAP item 1: the router balances
             # on these): in-use counts every non-free page (live-private
             # + cached), cached the pages the radix trie holds (warm,
