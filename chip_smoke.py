@@ -257,6 +257,25 @@ def kernel_cases(z: Sizes):
             # gradients sum ~seq bf16 products per element
             4 * BF16_TOL))
 
+    # grouped-query heads as the dense train path hands them over: k and v
+    # keep their own few heads, the kernels' index maps find a query head's
+    # group, and the dkv kernel sums the group into one key head
+    def qkv_grouped(rs):
+        return tuple(jnp.asarray(rs.randn(1, z.seq, n, d) * 0.5, bf16)
+                     for n in (h, max(1, h // 4), max(1, h // 4)))
+
+    def every_head(x):
+        return jnp.repeat(x, h // x.shape[2], axis=2)
+
+    cases.append(KernelCase(
+        f"flash_fwd+bwd train b1 s{z.seq} h{h} kvh{max(1, h // 4)} d{d} bf16",
+        qkv_grouped,
+        flash_grads(lambda q, k, v: pk.flash_attention(q, k, v, True, None)),
+        flash_grads(lambda q, k, v: dense_attention_oracle(
+            q, every_head(k), every_head(v), True)),
+        # a key's gradient sums its group's heads too
+        8 * BF16_TOL))
+
     # the same at key and value widths that differ (latent attention's
     # expanded form: keys half as wide again as the values)
     def qkv_apart(rs):
@@ -268,6 +287,26 @@ def kernel_cases(z: Sizes):
         qkv_apart,
         flash_grads(lambda q, k, v: pk.flash_attention(q, k, v, True, None)),
         flash_grads(lambda q, k, v: dense_attention_oracle(q, k, v, True)),
+        4 * BF16_TOL))
+
+    # and with the key handed in its two parts (latent attention's own
+    # form: a head's part, and ONE rotary part a token, here head 0's): the
+    # kernels' second contraction pair on operands that stay (B, S, H * d)
+    def parts(q, k):
+        return ((q[..., :d], q[..., d:]), (k[..., :d], k[:, :, 0, d:]))
+
+    def one_rotary_key(k):
+        return jnp.concatenate(
+            [k[..., :d], jnp.broadcast_to(k[:, :, :1, d:],
+                                          k.shape[:3] + (d // 2,))], -1)
+
+    cases.append(KernelCase(
+        f"flash_fwd+bwd train b1 s{z.seq} h{h} dqk{d}+{d // 2} apart dv{d} "
+        "bf16", qkv_apart,
+        flash_grads(lambda q, k, v: pk.flash_attention(*parts(q, k), v, True,
+                                                       None)),
+        flash_grads(lambda q, k, v: dense_attention_oracle(
+            q, one_rotary_key(k), v, True)),
         4 * BF16_TOL))
 
     # paged attention: decode (S=1) and the speculative-verify slab

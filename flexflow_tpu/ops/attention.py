@@ -454,8 +454,14 @@ class MultiHeadAttention(Op):
         return kh, vh
 
     def _out_proj(self, params, ctx):
+        """(B, S, H, d_v) head outputs -> (B, S, D): one matmul over the
+        (B, S, H * d_v) rows as they lie (where the flash kernels leave
+        them; a contraction over two dims makes XLA copy ctx into a layout
+        of its own first)."""
         with jax.named_scope("out"):
-            out = jnp.einsum("bqhk,hkd->bqd", ctx, params["wo"])
+            wo = params["wo"]
+            out = ctx.reshape(ctx.shape[:2] + (-1,)) \
+                @ wo.reshape(-1, wo.shape[-1])
             if self.bias:
                 out = out + params["bias_o"]
         return out
@@ -463,7 +469,6 @@ class MultiHeadAttention(Op):
     def forward(self, params, xs, *, training=False, rng=None, shard_ctx=None):
         q, k, v = xs[0], xs[1], xs[2]
         qh, kh, vh = self._project_qkv(params, q, k, v)
-        kh, vh = self._broadcast_kv(kh, vh)
         scale = self.softmax_scale
 
         seq_axes = []
@@ -479,6 +484,7 @@ class MultiHeadAttention(Op):
                 raise NotImplementedError(
                     f"{self.name}: ring / Ulysses attention has no sink; a "
                     "layer with one cannot shard its sequence dim")
+            kh, vh = self._broadcast_kv(kh, vh)
             with jax.named_scope("core"):
                 ctx = self._sp_attention(qh, kh, vh, shard_ctx, seq_axes,
                                          scale, training, rng)
@@ -517,7 +523,6 @@ class MultiHeadAttention(Op):
                 "v": jax.lax.dynamic_update_slice(
                     cache["v"], vh.astype(cache["v"].dtype), (0, 0, 0, 0)),
             }
-        kh, vh = self._broadcast_kv(kh, vh)
         scale = self.softmax_scale
         ctx = self._dense_attention(qh, kh, vh, scale, False, None, None,
                                     sink=self._sink_of(params))
@@ -594,10 +599,9 @@ class MultiHeadAttention(Op):
         ks, vs = ck[:, lo:end], cv[:, lo:end]
         if self.flash_chunks and flash_eligible(
                 getattr(self.model, "config", None), True, c, end - lo):
-            kb, vb = self._broadcast_kv(ks.astype(qh.dtype),
-                                        vs.astype(qh.dtype))
-            ctx = self._flash_dense(qh, kb, vb, self.softmax_scale, None,
-                                    sink=self._sink_of(params))
+            ctx = self._flash_dense(qh, ks.astype(qh.dtype),
+                                    vs.astype(qh.dtype), self.softmax_scale,
+                                    None, sink=self._sink_of(params))
         else:
             live = self._sees((start + jnp.arange(c))[:, None],
                               jnp.arange(lo, end)[None, :])
@@ -1270,6 +1274,9 @@ class MultiHeadAttention(Op):
 
     def _dense_attention(self, qh, kh, vh, scale, training, rng,
                          shard_ctx=None, sink=None):
+        """qh (B, S, H, .) against kh, vh (B, S, KVH, .) as projected: the
+        flash kernels read a group's one key head through their index maps
+        (nothing is repeated in HBM); XLA's forms get the broadcast."""
         use_dropout = training and self.dropout > 0.0 and rng is not None
         # a window or a sink under a gradient is XLA's masked attention: the
         # flash backward kernels carry neither
@@ -1278,6 +1285,7 @@ class MultiHeadAttention(Op):
                 and (sink is None or self.causal) \
                 and self._flash_ok(qh, kh):
             return self._flash_dense(qh, kh, vh, scale, shard_ctx, sink)
+        kh, vh = self._broadcast_kv(kh, vh)
         with jax.named_scope("core"):
             return self._xla_attention(qh, kh, vh, scale, training, rng,
                                        use_dropout, sink)
@@ -1353,6 +1361,10 @@ class MultiHeadAttention(Op):
         ent = shard_entries(mesh, axis_map, qh.shape, (0, 2))
         if ent[0] is None and ent[2] is None:
             return flash(qh, kh, vh)
+        if shard_entries(mesh, axis_map, kh.shape, (0, 2)) != ent:
+            # the key heads do not divide as the query heads do: a shard's
+            # groups would straddle devices, so every query head gets its own
+            kh, vh = self._broadcast_kv(kh, vh)
         if sink is not None:
             raise NotImplementedError(
                 f"{self.name}: the flash forward with a sink runs on one "

@@ -23,8 +23,9 @@ Two forms of the same numbers. EXPANDED (`forward`: predict, fit): K and V
 are built per head from the latents. Without a selection that is a dense
 causal attention with keys of d_nope + d_R and values of d_v, and it takes
 the Pallas flash kernels and their FlashAttention-2 backward
-(`pallas_kernels.flash_attention`, which takes the two widths apart; the one
-rotary key is broadcast to the heads to make the 192-wide operand) wherever
+(`pallas_kernels.flash_attention`, which takes the two widths apart and the
+key in its two parts: the one rotary key a token is never broadcast to the
+heads, `_forward_flash`) wherever
 `attention.flash_eligible` says a dense attention does, on one device; with a
 selection, or where the rule refuses (the CPU, a mesh), it is the blocked XLA
 form below, which is differentiable too. ABSORBED (everything that reads a
@@ -373,6 +374,14 @@ class LatentAttention(Op):
             [rope_rotate(x[..., :r], pos, self.inv_freq, self.rope_amp),
              x[..., r:]], axis=-1)
 
+    def _latents(self, params, a, pos):
+        """(cKV (B, S, c) normed, kR (B, S, d_R) rotated): what a token
+        leaves for every head."""
+        c = self.kv_lora_rank
+        kv = a @ params["w_dkv"]
+        return (self._rms(kv[..., :c], params["kv_norm"]),
+                rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp))
+
     def _project(self, params, a, pos):
         """Everything one slab of tokens a (B, S, D) at positions pos (B, S)
         contributes: queries (`q_nope`, `q_rope` (B, S, H, .)) and what is
@@ -386,9 +395,7 @@ class LatentAttention(Op):
             else:
                 cq = self._rms(a @ params["w_dq"], params["q_norm"])
                 q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
-            kv = a @ params["w_dkv"]
-            ckv = self._rms(kv[..., :c], params["kv_norm"])
-            kr = rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp)
+            ckv, kr = self._latents(params, a, pos)
             pad = self.lat_width - c - self.d_rope
             lat = jnp.concatenate(
                 [ckv, kr] + ([jnp.zeros(kr.shape[:-1] + (pad,), kr.dtype)]
@@ -420,9 +427,12 @@ class LatentAttention(Op):
             return jnp.concatenate(parts, axis=-1)
 
     def _out(self, params, o):
-        """(B, S, H, d_v) head outputs -> (B, S, D)."""
+        """(B, S, H, d_v) head outputs -> (B, S, D): one matmul over the
+        (B, S, H * d_v) rows as they lie."""
         with jax.named_scope("out"):
-            return jnp.einsum("bshv,hvd->bsd", o, params["wo"])
+            wo = params["wo"]
+            return o.reshape(o.shape[:2] + (-1,)) @ wo.reshape(-1,
+                                                               wo.shape[-1])
 
     # ---- the blocked attention both forms share ----------------------------
 
@@ -500,6 +510,8 @@ class LatentAttention(Op):
         a = xs[0]
         b, s = a.shape[:2]
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        if self._takes_flash(s):
+            return [self._forward_flash(params, a, pos)]
         pr = self._project(params, a, pos)
         c = self.kv_lora_rank
         with jax.named_scope("project"):
@@ -511,15 +523,6 @@ class LatentAttention(Op):
                                   (b, s, self.num_heads, self.d_rope))],
                 axis=-1)
             v = jnp.einsum("blc,chv->blhv", ckv, params["w_uv"])
-        if self._takes_flash(s):
-            from flexflow_tpu.ops.pallas_kernels import flash_attention
-
-            with jax.named_scope("project"):
-                q = jnp.concatenate([pr["q_nope"], pr["q_rope"]], axis=-1)
-            # the flash calls say their own phases (layout `project`,
-            # kernels `core`)
-            return [self._out(params, flash_attention(q, k, v, True,
-                                                      self.scale))]
 
         def attend(blk, chosen):
             with jax.named_scope("core"):
@@ -535,6 +538,37 @@ class LatentAttention(Op):
         zero = jnp.zeros((b,), jnp.int32)
         return [self._out(params, self._blocked(
             pr, pos, zero, zero, s, pr.get("ki"), attend))]
+
+    def _forward_flash(self, params, a, pos):
+        """`forward` on the flash kernels, no selection: every operand is
+        made (B, S, H * d) wide by a matmul of its own against the weight's
+        (., H * d) view and stays there (the kernels read a head's tiles
+        through their index maps), and the key's two parts go apart:
+        [cKV W_UK] a head and the ONE rotary key a token, which no
+        (B, S, H, d_nope + d_R) array ever holds. The flash calls say their
+        own phases (layout `project`, kernels `core`)."""
+        from flexflow_tpu.ops.pallas_kernels import flash_attention
+
+        b, s = a.shape[:2]
+        h, dn = self.num_heads, self.d_nope
+
+        def heads(x, w):            # x (B, S, r) @ w (r, H, d) -> (B, S, H, d)
+            return (x @ w.reshape(w.shape[0], -1)).reshape(b, s, h, -1)
+
+        with jax.named_scope("project"):
+            if self.q_lora_rank is None:
+                x, wq = a, params["w_q"]
+            else:
+                x = self._rms(a @ params["w_dq"], params["q_norm"])
+                wq = params["w_uq"]
+            q_nope = heads(x, wq[..., :dn])
+            q_rope = rope_rotate(heads(x, wq[..., dn:]), pos, self.inv_freq,
+                                 self.rope_amp)
+            ckv, kr = self._latents(params, a, pos)
+            k_nope = heads(ckv, params["w_uk"])
+            v = heads(ckv, params["w_uv"])
+        return self._out(params, flash_attention(
+            (q_nope, q_rope), (k_nope, kr), v, True, self.scale))
 
     def _takes_flash(self, s: int) -> bool:
         """Whether `forward` over s tokens is the flash kernels: no

@@ -12,6 +12,18 @@ keys of 192 and values of 128); the three calls carry stable names
 (`flash_attention_fwd`, `flash_attention_bwd_dq`, `flash_attention_bwd_dkv`)
 that a device trace shows.
 
+Layout: q, k, v, o, dO and dq, dk, dv stay (B, S, H, d), which is (B, S,
+H * d) by a reshape: where d_qk and d_v are whole numbers of 128 lanes the
+kernels reach head i's (block, d) tile as block (b, j, i) of that view
+through their index maps, and no transposed copy is made in either pass
+(`_lane_heads` is the rule, read off the operands; a 64-wide or an undivided
+192-wide head is copied to (B * H, S, d) around the same kernels). Latent
+attention's 192-wide key goes in as its two parts, logits = q1 k1^T + q2
+k2^T with ONE k2 row a token for all heads (`flash_attention` with pairs):
+neither the concatenated key nor the broadcast rotary part exists. Grouped
+queries work the same way: k and v keep their KVH heads and a query head's
+index maps name its group's (`_head_at`), so no repeated copy is made either.
+
 Streaming design: the opposing sequence is NOT staged in VMEM. Every flash
 kernel runs on a 3-D grid (batch*heads, own-side blocks, opposing-side
 blocks) whose innermost axis streams opposing-side tiles through VMEM while
@@ -248,7 +260,8 @@ def _across(x, width: int):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
                       block_k: int, chunk: int, causal: bool, scale: float,
                       need_lse: bool, offset: int = 0,
-                      window: Optional[int] = None, sink: bool = False):
+                      window: Optional[int] = None, sink: bool = False,
+                      pair: bool = False):
     """One (block_q, block_k) tile a grid step, `chunk` query rows at a
     time. The running max and sum live LANE-WIDE in their (block_q, 128)
     scratch: every lane of m holds the row's max, so it meets the logits'
@@ -261,8 +274,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
     With `sink` (static) one more input, the (B*H,) sink logits in SMEM: a
     row's running max starts at its head's sink and its sum at exp(sink -
     max) = 1, so the sink takes its share of the denominator and adds no
-    value; without it the kernel is the one it always was."""
-    sink_ref = None
+    value; without it the kernel is the one it always was.
+
+    With `pair` (static) two more inputs, a second query and key whose
+    product joins the logits: s = q k^T + q2 k2^T (latent attention's
+    rotary part, one key for all heads)."""
+    q2_ref = k2_ref = sink_ref = None
+    if pair:
+        q2_ref, k2_ref, rest = rest[0], rest[1], rest[2:]
     if sink:
         sink_ref, rest = rest[0], rest[1:]
     o_ref, rest = rest[0], rest[1:]
@@ -308,7 +327,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
             # path; accumulation stays f32 via preferred_element_type)
             k = k_ref[0, 0:width, :]
             v = v_ref[0, 0:width, :]
-            s = _dot_nt(q, k) * scale                       # (chunk, width)
+            s = _dot_nt(q, k)                               # (chunk, width)
+            if pair:
+                s = s + _dot_nt(q2_ref[0, rows, :], k2_ref[0, 0:width, :])
+            s = s * scale
             if masked:
                 ahead = _row_minus_col(chunk, width)
                 seen = ahead >= -first
@@ -347,20 +369,82 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, block_q: int,
                                           (q_ref.shape[1], 8))
 
 
+def _lane_heads(*widths: int) -> bool:
+    """Whether the flash kernels reach a head's tiles where the projections
+    leave them: (B, S, H, d) is (B, S, H * d) by a reshape, and head i's
+    (block, d) tile is the block (b, j, i) of that view when every width is
+    a whole number of 128-lane tiles. A head the lanes do not divide (64,
+    an undivided 192) is copied to (B * H, S, d) first. The one rule of the
+    three kernels' wrappers, read off the operands."""
+    return all(w % LANES == 0 for w in widths)
+
+
+def _by_head(x, lanes: bool):
+    """A (B, S, H, d) operand as the kernels' grid reads it: the reshape
+    (B, S, H * d) where a lane block can take a head, else the transposed
+    copy (B * H, S, d)."""
+    b, s, h, d = x.shape
+    if lanes:
+        return x.reshape(b, s, h * d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _from_heads(y, b: int, h: int, lanes: bool):
+    """`_by_head`'s inverse on a kernel's result: -> (B, S, H, d)."""
+    if lanes:
+        return y.reshape(b, y.shape[1], h, y.shape[2] // h)
+    return y.reshape(b, h, y.shape[1], y.shape[2]).transpose(0, 2, 1, 3)
+
+
+def _head_at(h: int, lanes: bool, rep: int = 1):
+    """at(i, j): the block index of sequence block j for grid row i (batch
+    x one of h heads) in `_by_head`'s view of an operand with h // rep
+    heads: its own head at rep 1, else the key / value head its group of
+    `rep` query heads shares (grouped-query attention: head i % h reads
+    head (i % h) // rep, and nothing is repeated in HBM). The kernels'
+    bodies see the same (1, block, d) tile either way."""
+    if lanes:
+        return lambda i, j: (i // h, j, (i % h) // rep)
+    return lambda i, j: (i // rep, j, 0)
+
+
+def _group_size(q, k, v) -> int:
+    """Query heads a key / value head (1 without grouped queries)."""
+    h, hk = q.shape[2], k.shape[2]
+    assert h % hk == 0 and v.shape[2] == hk, (q.shape, k.shape, v.shape)
+    return h // hk
+
+
 def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
                                block_q: Optional[int] = None,
                                block_k: Optional[int] = None,
                                need_lse: bool = True,
-                               window: Optional[int] = None, sink=None):
-    """q, k: (B, S, H, d_qk), v: (B, S, H, d_v) -> (out (B*H, S_q, d_v),
-    lse|None). The key and value widths are independent (latent attention
-    has keys of 192 and values of 128): the logit contracts d_qk, the
-    accumulator and the output are d_v wide.
-    Grid: (B*H, S_q/block_q, S_k/block_k) — K/V tiles stream through the
+                               window: Optional[int] = None, sink=None,
+                               q2=None, k2=None):
+    """q (B, S, H, d_qk), k (B, S, KVH, d_qk), v (B, S, KVH, d_v) -> (out
+    (B, S_q, H, d_v), lse (B*H, S_q, 8) | None). The key and value widths
+    are independent (latent attention has keys of 192 and values of 128):
+    the logit contracts d_qk, the accumulator and the output are d_v wide.
+    KVH divides H (grouped-query attention): query head h reads key head
+    h // (H / KVH) through the index maps, and k, v are never repeated.
+
+    Layout: operands and result stay where the projections leave them. With
+    d_qk and d_v whole numbers of 128 lanes (`_lane_heads`) the kernel reads
+    head i's tiles out of the (B, S, H * d) view through its index maps and
+    writes the output the same way: no transposed copy exists. Other widths
+    (64, an undivided 192) are copied to (B * H, S, d) and back around the
+    SAME kernel. `lse` is the kernels' own and keeps its (B * H, S_q, 8)
+    form.
+
+    `q2` (B, S_q, H, d_2), `k2` (B, S_k, d_2): a second contraction pair,
+    logits = q k^T + q2 k2^T with ONE k2 row a token for all heads (latent
+    attention's rotary key); needs every width a whole number of lane tiles.
+
+    Grid: (B*H, S_q/block_q, S_k/block_k) - K/V tiles stream through the
     innermost axis. block_q/block_k default to `_resolve_blocks`' static
     rule down from `_OUTER_BLOCK`; explicit values pin the tile (degraded
     to a divisor of seq). need_lse=False (inference) skips materializing
-    the logsumexp residual — it exists only for the VJP and costs more HBM
+    the logsumexp residual - it exists only for the VJP and costs more HBM
     writes than the output itself at small head dims.
 
     `window` (causal only): query i sees keys i - window < j <= i. A key
@@ -383,12 +467,12 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
         block_q, block_k = _resolve_blocks(sq, sk, block_q, block_k)
     assert sq % block_q == 0 and sk % block_k == 0
     # sq > sk with causal would leave the first rows keyless (0/0 in the
-    # online softmax) — refused upstream in attention.flash_eligible
+    # online softmax) - refused upstream in attention.flash_eligible
     assert not (causal and sk < sq), "causal flash needs sq <= sk"
-    return _flash_fwd_call(q, k, v, sink, causal=causal, scale=float(scale),
-                           block_q=block_q, block_k=block_k,
-                           need_lse=need_lse, interpret=_interpret(),
-                           window=window)
+    return _flash_fwd_call(q, k, v, sink, q2, k2, causal=causal,
+                           scale=float(scale), block_q=block_q,
+                           block_k=block_k, need_lse=need_lse,
+                           interpret=_interpret(), window=window)
 
 
 # inline=True: traced once per shape and re-emitted under each caller's
@@ -401,57 +485,81 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool, scale: float,
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "causal", "scale", "block_q", "block_k", "need_lse", "interpret",
     "window"))
-def _flash_fwd_call(q, k, v, sink=None, *, causal, scale, block_q, block_k,
-                    need_lse, interpret, window=None):
+def _flash_fwd_call(q, k, v, sink=None, q2=None, k2=None, *, causal, scale,
+                    block_q, block_k, need_lse, interpret, window=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[3]
     # cross-attention diagonal offset (bottom-right aligned causality)
     offset = sk - sq
+    pair = q2 is not None
+    lanes = _lane_heads(d, dv)
+    if pair:
+        d2 = q2.shape[3]
+        assert lanes and _lane_heads(d2), (d, dv, d2)
+    rep = _group_size(q, k, v)
+    at, at_kv = _head_at(h, lanes), _head_at(h, lanes, rep)
 
-    # (B, S, H, D) -> (B*H, S, D); the phase scopes here and below are the
-    # calling attention op's (runtime/profiler.py PHASES)
+    # the phase scopes here and below are the calling attention op's
+    # (runtime/profiler.py PHASES); on the lane path `project` holds
+    # reshapes alone
     with jax.named_scope("project"):
-        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-        kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-        vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
+        operands = [_by_head(x, lanes) for x in (q, k, v)]
+        if pair:
+            operands += [_by_head(q2, True), k2]
 
     kernel = functools.partial(_flash_fwd_kernel, block_q=block_q,
                                block_k=block_k, chunk=_chunk_rows(block_q),
                                causal=causal, scale=scale,
                                need_lse=need_lse, offset=offset,
-                               window=window, sink=sink is not None)
+                               window=window, sink=sink is not None,
+                               pair=pair)
     nk = sk // block_k
     if window is not None:
         # the grid's key axis starts at the first tile the window reaches
         # and is as long as the longest such run
         nk = _window_tiles(sq, sk, block_q, block_k, window)
 
-        def kv_map(i, j, t):
-            return (i, jnp.minimum(
+        def k_tile(j, t):
+            return jnp.minimum(
                 t + _window_first_tile(j, block_q, block_k, offset, window),
-                ((j + 1) * block_q - 1 + offset) // block_k), 0)
+                ((j + 1) * block_q - 1 + offset) // block_k)
     elif causal:
         # clamp dead (fully-masked) inner steps to the last live tile: the
         # revisited block is already VMEM-resident, so masked steps cost no
         # DMA (pl.when(live) already skips their compute)
-        def kv_map(i, j, t):
-            return (i, jnp.minimum(
-                t, ((j + 1) * block_q - 1 + offset) // block_k), 0)
+        def k_tile(j, t):
+            return jnp.minimum(t, ((j + 1) * block_q - 1 + offset) // block_k)
     else:
-        def kv_map(i, j, t):
-            return (i, t, 0)
-    out_specs = [pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype)]
+        def k_tile(j, t):
+            return t
+
+    def q_map(i, j, t):
+        return at(i, j)
+
+    def kv_map(i, j, t):
+        return at_kv(i, k_tile(j, t))
+
+    def rows_map(i, j, t):          # the kernels' own (B*H, S_q, 8) rows
+        return (i, j, 0)
+
+    out_specs = [pl.BlockSpec((1, block_q, dv), q_map)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (b, sq, h * dv) if lanes else (b * h, sq, dv), q.dtype)]
     if need_lse:
-        out_specs.append(pl.BlockSpec((1, block_q, 8),
-                                      lambda i, j, t: (i, j, 0)))
+        out_specs.append(pl.BlockSpec((1, block_q, 8), rows_map))
         out_shape.append(jax.ShapeDtypeStruct((b * h, sq, 8), jnp.float32))
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+        pl.BlockSpec((1, block_q, d), q_map),
         pl.BlockSpec((1, block_k, d), kv_map),
         pl.BlockSpec((1, block_k, dv), kv_map),
     ]
-    operands = [qt, kt, vt]
+    if pair:
+        in_specs += [
+            pl.BlockSpec((1, block_q, d2), q_map),
+            # one row a token, the same block for every head
+            pl.BlockSpec((1, block_k, d2),
+                         lambda i, j, t: (i // h, k_tile(j, t), 0)),
+        ]
     if sink is not None:
         # one logit a (batch, head) grid row, read as a scalar
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -471,18 +579,25 @@ def _flash_fwd_call(q, k, v, sink=None, *, causal, scale, block_q, block_k,
             compiler_params=_compiler_params(),
             interpret=interpret, name="flash_attention_fwd",
         )(*operands)
-    return (outs[0], outs[1]) if need_lse else (outs[0], None)
+    with jax.named_scope("out"):
+        out = _from_heads(outs[0], b, h, lanes)
+    return (out, outs[1]) if need_lse else (out, None)
 
 
 # ---------------------------------------------------------------- backward
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, block_q: int, block_k: int,
-                         chunk: int, causal: bool, scale: float,
-                         offset: int = 0):
+                         *rest, block_q: int, block_k: int, chunk: int,
+                         causal: bool, scale: float, offset: int = 0,
+                         pair: bool = False):
     """One q tile, k/v tiles streaming, `chunk` query rows at a time:
-    dq = scale * sum_j ds_j @ k_j, ds = p * (do @ v^T - delta)."""
+    dq = scale * sum_j ds_j @ k_j, ds = p * (do @ v^T - delta). With `pair`
+    the logits hold q2 k2^T too and dq2 = scale * sum_j ds_j @ k2_j."""
+    if pair:
+        q2_ref, k2_ref, dq_ref, dq2_ref, dq_scr, dq2_scr = rest
+    else:
+        dq_ref, dq_scr = rest
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -493,6 +608,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        if pair:
+            dq2_scr[...] = jnp.zeros_like(dq2_scr)
 
     def step(masked: bool):
         for r0 in range(0, block_q, chunk):
@@ -505,39 +622,59 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             delta = delta_ref[0, rows, 0:1]
             k = k_ref[0, 0:width, :]
             v = v_ref[0, 0:width, :]
-            p = jnp.exp(_dot_nt(q, k) * scale - lse)        # (chunk, width)
+            s = _dot_nt(q, k)                               # (chunk, width)
+            if pair:
+                k2 = k2_ref[0, 0:width, :]
+                s = s + _dot_nt(q2_ref[0, rows, :], k2)
+            p = jnp.exp(s * scale - lse)
             if masked:
                 p = jnp.where(_row_minus_col(chunk, width) >= -first, p, 0.0)
             ds = (p * (_dot_nt(do, v) - delta)).astype(k.dtype)
             dq_scr[rows, :] = dq_scr[rows, :] + _dot(ds, k)
+            if pair:
+                dq2_scr[rows, :] = dq2_scr[rows, :] + _dot(ds, k2)
 
     _run_tile(step, causal, qi, ki, block_q, block_k, offset)
 
     @pl.when(ki == nk - 1)
     def _finish():
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        if pair:
+            dq2_ref[0] = (dq2_scr[...] * scale).astype(dq2_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
-                          block_k: int, chunk: int, causal: bool,
-                          scale: float, offset: int = 0):
+                          *rest, block_q: int, block_k: int, chunk: int,
+                          causal: bool, scale: float, offset: int = 0,
+                          pair: bool = False, nq: Optional[int] = None):
     """One k tile, q/do tiles streaming, `chunk` keys at a time, on the
     TRANSPOSED logits s^T = k q^T (keys down the rows, queries along the
     lanes), so that neither product needs a transpose: dv = sum_i p_i^T @
     do_i, dk = scale * sum_i ds_i^T @ q_i, with lse and delta laid along
-    lanes (lse_ref / delta_ref: (1, 1, block_q))."""
+    lanes (lse_ref / delta_ref: (1, 1, block_q)). With `pair` the logits
+    hold k2 q2^T too and dk2 = scale * sum_i ds_i^T @ q2_i is THIS head's
+    share of the one k2's gradient (the caller sums the heads). With `nq`
+    (static; grouped-query attention) the inner axis holds the `nq` q tiles
+    of each query head of this key head's group, one head after another."""
+    if pair:
+        (q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref,
+         dk_scr, dv_scr, dk2_scr) = rest
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step_i = pl.program_id(2)       # the grid's step, and its q tile
+    steps = pl.num_programs(2)
+    qi = step_i if nq is None else step_i % nq
     aligned = _diagonal_aligned(block_q, block_k, offset)
     # the tile's first query against its first key, offset added
     tile_first = qi * block_q + offset - ki * block_k
 
-    @pl.when(qi == 0)
+    @pl.when(step_i == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        if pair:
+            dk2_scr[...] = jnp.zeros_like(dk2_scr)
 
     def step(masked: bool):
         for r0 in range(0, block_k, chunk):
@@ -552,7 +689,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             do = do_ref[0, start:block_q, :]
             lse = lse_ref[0, :, start:block_q]              # (1, width)
             delta = delta_ref[0, :, start:block_q]
-            p = jnp.exp(_dot_nt(k, q) * scale - lse)        # (chunk, width)
+            s = _dot_nt(k, q)                               # (chunk, width)
+            if pair:
+                q2 = q2_ref[0, start:block_q, :]
+                s = s + _dot_nt(k2_ref[0, rows, :], q2)
+            p = jnp.exp(s * scale - lse)
             if masked:
                 # rows are keys here: the first streamed query is `first`
                 # positions (offset added) past the chunk's first key
@@ -561,29 +702,52 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_scr[rows, :] = dv_scr[rows, :] + _dot(p.astype(do.dtype), do)
             ds = (p * (_dot_nt(v, do) - delta)).astype(q.dtype)
             dk_scr[rows, :] = dk_scr[rows, :] + _dot(ds, q)
+            if pair:
+                dk2_scr[rows, :] = dk2_scr[rows, :] + _dot(ds, q2)
 
     _run_tile(step, causal, qi, ki, block_q, block_k, offset)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step_i == steps - 1)
     def _finish():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        if pair:
+            dk2_ref[0] = (dk2_scr[...] * scale).astype(dk2_ref.dtype)
+
+
+def _head_row_sums(x):
+    """(B, S, H, d) f32 -> (B * H, S): every head's row sums. Taken 8 rows
+    of the sequence at a time where it divides: 8 is the f32 sublane tile,
+    so the product of two (B, S, H * d) arrays that the lane path's
+    kernels wrote is read where it lies (a plain sum over the 4-D view
+    makes XLA copy the whole f32 product into a heads-on-sublanes layout
+    first: PERF.md section 6, PR 52)."""
+    b, s, h, d = x.shape
+    r = 8 if s % 8 == 0 else 1
+    sums = jnp.sum(x.reshape(b, s // r, r, h, d), axis=-1)
+    return sums.reshape(b, s, h).transpose(0, 2, 1).reshape(b * h, s)
 
 
 def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
                                scale: float, block_q: Optional[int] = None,
                                block_k: Optional[int] = None, dlse=None,
-                               delta_precomputed=None):
+                               delta_precomputed=None, q2=None, k2=None):
     """(dq, dk, dv) of `flash_attention_fwd_pallas`'s output o (B, S_q, H,
     d_v) under the cotangent do, from its lse (B*H, S_q, 8): two calls,
     `flash_attention_bwd_dq` and `flash_attention_bwd_dkv`. Blocks as the
-    forward's."""
+    forward's, and its layout rule (`_lane_heads`): q, k, v, o and do are
+    read, dq, dk and dv written as (B, S, H | KVH, d) where the widths are
+    whole lane tiles, through a transposed copy each otherwise; with KVH <
+    H the dkv call's grid rows are the key heads and its inner axis walks
+    the group's query heads. With the
+    forward's second pair `q2`, `k2` the result is (dq, dk, dv, dq2 (B,
+    S_q, H, d_2), dk2 (B, S_k, d_2): the heads' shares summed)."""
     sq, sk = q.shape[1], k.shape[1]
     block_q, block_k = _resolve_blocks(sq, sk, block_q, block_k)
     assert sq % block_q == 0 and sk % block_k == 0
     assert not (causal and sk < sq), "causal flash needs sq <= sk"
     return _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed,
-                           causal=causal, scale=float(scale),
+                           q2, k2, causal=causal, scale=float(scale),
                            block_q=block_q, block_k=block_k,
                            interpret=_interpret())
 
@@ -591,112 +755,168 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, causal: bool,
 # inline=True: as `_flash_fwd_call`
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret"))
-def _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed, *, causal,
-                    scale, block_q, block_k, interpret):
+def _flash_bwd_call(q, k, v, o, lse, do, dlse, delta_precomputed, q2=None,
+                    k2=None, *, causal, scale, block_q, block_k, interpret):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[3]
     offset = sk - sq
+    pair = q2 is not None
+    lanes = _lane_heads(d, dv)
+    d2 = q2.shape[3] if pair else None
+    assert not pair or (lanes and _lane_heads(d2)), (d, dv, d2)
+    hk, rep = k.shape[2], _group_size(q, k, v)
+    nq = sq // block_q
+    at, at_kv = _head_at(h, lanes), _head_at(h, lanes, rep)
 
     with jax.named_scope("project"):
-        qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-        kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-        vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
-        dot = do.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
-        ot = o.transpose(0, 2, 1, 3).reshape(b * h, sq, dv)
-    # delta_i = rowsum(do_i * o_i) — the softmax-normalization term of ds;
-    # an lse cotangent (if the lse output is ever differentiated) folds in
-    # as ds = p * (dp - delta + dlse), i.e. delta -= dlse. Loop callers
-    # (the ring backward) pass delta_precomputed to hoist this out of their
-    # scan body.
+        qt, kt, vt, dot = (_by_head(x, lanes) for x in (q, k, v, do))
+        extra = [_by_head(q2, True), k2] if pair else []
+    # delta_i = rowsum(do_i * o_i) - the softmax-normalization term of ds,
+    # read from do and o where they lie; an lse cotangent (if the lse output
+    # is ever differentiated) folds in as ds = p * (dp - delta + dlse), i.e.
+    # delta -= dlse. Loop callers (the ring backward) pass delta_precomputed
+    # (B, H, S_q) to hoist this out of their scan body.
     with jax.named_scope("core"):
         if delta_precomputed is not None:
             delta = delta_precomputed.reshape(b * h, sq).astype(jnp.float32)
         else:
-            delta = jnp.sum(dot.astype(jnp.float32)
-                            * ot.astype(jnp.float32), axis=-1)
+            delta = _head_row_sums(do.astype(jnp.float32)
+                                   * o.astype(jnp.float32))
         if dlse is not None:
             delta = delta - dlse.reshape(b * h, sq).astype(jnp.float32)
 
     if causal:
         # dead-tile clamps (see forward): masked inner steps re-reference a
         # resident block instead of fetching one
-        def kv_map(i, j, t):
-            return (i, jnp.minimum(
-                t, ((j + 1) * block_q - 1 + offset) // block_k), 0)
+        def k_tile(j, t):
+            return jnp.minimum(t, ((j + 1) * block_q - 1 + offset) // block_k)
 
         def q_tile(j, t):
             # first q tile whose last row reaches this k tile: q_pos >=
             # j*block_k - offset (floor div handles the negative numerator)
             return jnp.maximum(t, (j * block_k - offset) // block_q)
     else:
-        def kv_map(i, j, t):
-            return (i, t, 0)
+        def k_tile(j, t):
+            return t
 
         def q_tile(j, t):
             return t
 
-    def q_map(i, j, t):
-        return (i, q_tile(j, t), 0)
+    def own_q(i, j, t):             # the dq kernel's own side: grid axis 1
+        return at(i, j)
 
+    def rows_map(i, j, t):          # lse / delta rows: (B*H, S_q, 8)
+        return (i, j, 0)
+
+    def kv_map(i, j, t):
+        return at_kv(i, k_tile(j, t))
+
+    dq_in = [
+        pl.BlockSpec((1, block_q, d), own_q),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, dv), kv_map),
+        pl.BlockSpec((1, block_q, dv), own_q),
+        pl.BlockSpec((1, block_q, 8), rows_map),
+        pl.BlockSpec((1, block_q, 8), rows_map),
+    ]
+    dq_out = [pl.BlockSpec((1, block_q, d), own_q)]
+    dq_shape = [jax.ShapeDtypeStruct(qt.shape, q.dtype)]
+    dq_scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
+    if pair:
+        dq_in += [pl.BlockSpec((1, block_q, d2), own_q),
+                  pl.BlockSpec((1, block_k, d2),
+                               lambda i, j, t: (i // h, k_tile(j, t), 0))]
+        dq_out.append(pl.BlockSpec((1, block_q, d2), own_q))
+        dq_shape.append(jax.ShapeDtypeStruct(extra[0].shape, q2.dtype))
+        dq_scratch.append(pltpu.VMEM((block_q, d2), jnp.float32))
     with jax.named_scope("core"):
-        dq = pl.pallas_call(
+        dqs = pl.pallas_call(
             functools.partial(_flash_bwd_dq_kernel, block_q=block_q,
                               block_k=block_k, chunk=_chunk_rows(block_q),
-                              causal=causal, scale=scale, offset=offset),
+                              causal=causal, scale=scale, offset=offset,
+                              pair=pair),
             grid=(b * h, sq // block_q, sk // block_k),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), kv_map),
-                pl.BlockSpec((1, block_k, dv), kv_map),
-                pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_q, 8), lambda i, j, t: (i, j, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda i, j, t: (i, j, 0)),
-            out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            in_specs=dq_in, out_specs=dq_out, out_shape=dq_shape,
+            scratch_shapes=dq_scratch,
             compiler_params=_compiler_params(),
             interpret=interpret, name="flash_attention_bwd_dq",
         )(qt, kt, vt, dot, lse,
           # delta in the same 8-lane padded layout as lse
-          jnp.broadcast_to(delta[..., None], (b * h, sq, 8)))
+          jnp.broadcast_to(delta[..., None], (b * h, sq, 8)), *extra)
 
     # the dkv kernel works on transposed logits, so its two per-query rows
-    # lie along lanes
+    # lie along lanes. Its grid rows are the KEY heads (B * hk): the inner
+    # axis streams the q tiles of each of the group's `rep` query heads in
+    # turn into the one accumulator, so dk and dv leave at the keys' own
+    # width (at rep 1 the grid it always was)
     rows = (b * h, 1, sq)
-    row_spec = pl.BlockSpec((1, 1, block_q),
-                            lambda i, j, t: (i, 0, q_tile(j, t)))
+    own_kv = _head_at(hk, lanes)
+
+    def own_k(i, j, t):
+        return own_kv(i, j)
+
+    if rep == 1:
+        def q_side(i, j, t):        # (q's grid row, its sequence tile)
+            return i, q_tile(j, t)
+    else:
+        def q_side(i, j, t):
+            return ((i // hk) * h + (i % hk) * rep + t // nq,
+                    q_tile(j, t % nq))
+
+    def q_map(i, j, t):
+        return at(*q_side(i, j, t))
+
+    def row_map(i, j, t):
+        row, tile = q_side(i, j, t)
+        return (row, 0, tile)
+
+    row_spec = pl.BlockSpec((1, 1, block_q), row_map)
+    dkv_in = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_k, d), own_k),
+        pl.BlockSpec((1, block_k, dv), own_k),
+        pl.BlockSpec((1, block_q, dv), q_map),
+        row_spec,
+        row_spec,
+    ]
+    dkv_out = [pl.BlockSpec((1, block_k, d), own_k),
+               pl.BlockSpec((1, block_k, dv), own_k)]
+    dkv_shape = [jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vt.shape, v.dtype)]
+    dkv_scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+                   pltpu.VMEM((block_k, dv), jnp.float32)]
+    if pair:
+        dkv_in += [pl.BlockSpec((1, block_q, d2), q_map),
+                   pl.BlockSpec((1, block_k, d2),
+                                lambda i, j, t: (i // hk, j, 0))]
+        # a key head's share of the one k2's gradient
+        dkv_out.append(pl.BlockSpec((1, block_k, d2), own_k))
+        dkv_shape.append(jax.ShapeDtypeStruct((b, sk, hk * d2), k2.dtype))
+        dkv_scratch.append(pltpu.VMEM((block_k, d2), jnp.float32))
     with jax.named_scope("core"):
-        dk, dvt = pl.pallas_call(
+        dkvs = pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                               block_k=block_k, chunk=_chunk_rows(block_k),
-                              causal=causal, scale=scale, offset=offset),
-            grid=(b * h, sk // block_k, sq // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), q_map),
-                pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_q, dv), q_map),
-                row_spec,
-                row_spec,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-                pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, j, 0))],
-            out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, sk, dv), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, dv), jnp.float32)],
+                              causal=causal, scale=scale, offset=offset,
+                              pair=pair, nq=nq if rep > 1 else None),
+            grid=(b * hk, sk // block_k, rep * nq),
+            in_specs=dkv_in, out_specs=dkv_out, out_shape=dkv_shape,
+            scratch_shapes=dkv_scratch,
             compiler_params=_compiler_params(),
             interpret=interpret, name="flash_attention_bwd_dkv",
-        )(qt, kt, vt, dot, lse[..., 0].reshape(rows), delta.reshape(rows))
-
-    def back(x, s):
-        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
+        )(qt, kt, vt, dot, lse[..., 0].reshape(rows), delta.reshape(rows),
+          *extra)
 
     with jax.named_scope("project"):
-        return back(dq, sq), back(dk, sk), back(dvt, sk)
+        grads = (_from_heads(dqs[0], b, h, lanes),
+                 _from_heads(dkvs[0], b, hk, lanes),
+                 _from_heads(dkvs[1], b, hk, lanes))
+        if not pair:
+            return grads
+        dk2 = _from_heads(dkvs[2], b, hk, True)
+        return grads + (_from_heads(dqs[1], b, h, True),
+                        jnp.sum(dk2.astype(jnp.float32),
+                                axis=2).astype(k2.dtype))
 
 
 # ----------------------------------------------------- fused add+layernorm
@@ -827,18 +1047,49 @@ fused_add_layernorm.defvjp(_add_ln_fwd_rule, _add_ln_bwd_rule)
 # ------------------------------------------------------------- public API
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None):
     """Flash attention: Pallas forward + FlashAttention-2 Pallas backward
     (logsumexp residual; per-tile prob recompute; no S x S materialization
-    in either direction). q, k (B, S, H, d_qk) and v (B, S, H, d_v): the
-    two widths need not agree; the default scale is d_qk^-0.5."""
-    s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, _ = flash_attention_fwd_pallas(q, k, v, causal, s, need_lse=False)
-    b, sq, h, _ = q.shape
-    with jax.named_scope("out"):
-        return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
+    in either direction). q (B, S, H, d_qk), k (B, S, KVH, d_qk) and v (B,
+    S, KVH, d_v) -> (B, S_q, H, d_v): the two widths need not agree, and
+    KVH may be any divisor of H (a group of query heads shares one key
+    head; dk and dv come back KVH heads wide); the default scale is
+    d_qk^-0.5. Operands, result and gradients stay in that layout where the
+    widths are whole numbers of 128 lanes (`flash_attention_fwd_pallas`).
+
+    q and k may each come as a PAIR of parts whose products add up in the
+    logit, (q1 (B, S, H, d_1), q2 (B, S, H, d_2)) against (k1 (B, S, H,
+    d_1), k2 (B, S, d_2): one row a token for all heads): latent
+    attention's [nope ; rope] key, never concatenated. d_qk is d_1 + d_2.
+    Where d_1 and d_v are whole lane tiles the second part is padded to
+    one and rides the kernels' second contraction; else the parts are
+    joined and take the copied layout."""
+    if not isinstance(q, tuple):
+        s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+        return _flash(q, k, v, None, None, causal, s)
+    (q, q2), (k, k2) = q, k
+    s = scale if scale is not None \
+        else 1.0 / math.sqrt(q.shape[-1] + q2.shape[-1])
+    with jax.named_scope("project"):
+        if _lane_heads(q.shape[-1], v.shape[-1]):
+            pad = -q2.shape[-1] % LANES
+            q2 = jnp.pad(q2, ((0, 0),) * 3 + ((0, pad),))
+            k2 = jnp.pad(k2, ((0, 0),) * 2 + ((0, pad),))
+        else:
+            q = jnp.concatenate([q, q2], axis=-1)
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                k2[:, :, None, :], k.shape[:3] + k2.shape[-1:])], axis=-1)
+            q2 = k2 = None
+    return _flash(q, k, v, q2, k2, causal, s)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash(q, k, v, q2, k2, causal: bool, scale: float):
+    """`flash_attention` with its second pair (or None, None) in place."""
+    out, _ = flash_attention_fwd_pallas(q, k, v, causal, scale,
+                                        need_lse=False, q2=q2, k2=k2)
+    return out
 
 
 def flash_attention_window(q, k, v, window: Optional[int],
@@ -851,28 +1102,22 @@ def flash_attention_window(q, k, v, window: Optional[int],
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     out, _ = flash_attention_fwd_pallas(q, k, v, True, s, need_lse=False,
                                         window=window, sink=sink)
-    b, sq, h, _ = q.shape
-    with jax.named_scope("out"):
-        return out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
+    return out
 
 
-def _flash_fwd_rule(q, k, v, causal, scale):
-    s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, lse = flash_attention_fwd_pallas(q, k, v, causal, s)
-    b, sq, h, _ = q.shape
-    with jax.named_scope("out"):
-        o = out.reshape(b, h, sq, v.shape[3]).transpose(0, 2, 1, 3)
-    return o, (q, k, v, o, lse)
+def _flash_fwd_rule(q, k, v, q2, k2, causal, scale):
+    o, lse = flash_attention_fwd_pallas(q, k, v, causal, scale, q2=q2, k2=k2)
+    return o, (q, k, v, q2, k2, o, lse)
 
 
 def _flash_bwd_rule(causal, scale, res, g):
-    q, k, v, o, lse = res
-    s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    dq, dk, dv = flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, s)
-    return dq, dk, dv
+    q, k, v, q2, k2, o, lse = res
+    grads = flash_attention_bwd_pallas(q, k, v, o, lse, g, causal, scale,
+                                       q2=q2, k2=k2)
+    return grads if q2 is not None else grads + (None, None)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ----------------------------------------------------- paged attention

@@ -77,10 +77,9 @@ def _flash_block(q, k, v, causal: bool, scale: float):
     output (B,S,H,D) f32 and logsumexp (B,H,S)."""
     from flexflow_tpu.ops.pallas_kernels import flash_attention_fwd_pallas
 
-    b, sq, h, d = q.shape
+    b, sq, h, _ = q.shape
     out, lse8 = flash_attention_fwd_pallas(q, k, v, causal, scale)
-    o = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    return o.astype(jnp.float32), lse8[..., 0].reshape(b, h, sq)
+    return out.astype(jnp.float32), lse8[..., 0].reshape(b, h, sq)
 
 
 def _merge_blocks(o, lse, o_s, lse_s):

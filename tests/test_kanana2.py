@@ -263,8 +263,7 @@ def flash_case(request):
     b, h, dqk, dv = 2, 2, 48, 32
     q, k, v, do = _qkv(b, s, h, dqk, dv)
     scale = dqk ** -0.5
-    out, lse = flash_attention_fwd_pallas(q, k, v, True, scale, bq, bk)
-    o = out.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
+    o, lse = flash_attention_fwd_pallas(q, k, v, True, scale, bq, bk)
     got = dict(zip(("dq", "dk", "dv"), flash_attention_bwd_pallas(
         q, k, v, o, lse, do, True, scale, bq, bk)))
     got["forward"] = o
@@ -318,18 +317,14 @@ def walked_case(request):
     assert min(counts["live"] - counts["masked"], counts["masked"],
                counts["dead"]) >= 1, counts
 
-    def heads_last(x):
-        return x.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
-
-    out, lse = flash_attention_fwd_pallas(q, k, v, True, scale, block, block)
-    o = heads_last(out)
+    o, lse = flash_attention_fwd_pallas(q, k, v, True, scale, block, block)
     got = dict(zip(("dq", "dk", "dv"), flash_attention_bwd_pallas(
         q, k, v, o, lse, do, True, scale, block, block)))
     got["forward"] = o
     out, none = flash_attention_fwd_pallas(q, k, v, True, scale, block,
                                            block, need_lse=False)
     assert none is None
-    got["forward_without_lse"] = heads_last(out)
+    got["forward_without_lse"] = out
     want_o, vjp = jax.vjp(lambda q, k, v: _oracle(q, k, v, scale),
                           q, k, v)
     want = dict(zip(("dq", "dk", "dv"), vjp(do)))
@@ -356,8 +351,7 @@ def test_only_a_causal_kernel_builds_a_mask(causal):
     scale = 48 ** -0.5
 
     def both(q, k, v, do):
-        out, lse = flash_attention_fwd_pallas(q, k, v, causal, scale)
-        o = out.reshape(1, 1, 256, 32).transpose(0, 2, 1, 3)
+        o, lse = flash_attention_fwd_pallas(q, k, v, causal, scale)
         return flash_attention_bwd_pallas(q, k, v, o, lse, do, causal, scale)
 
     text = str(jax.make_jaxpr(both)(q, k, v, do))
