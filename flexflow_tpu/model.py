@@ -244,6 +244,7 @@ class FFModel:
             topk_group: int = 1, routed_scaling: float = 1.0,
             shared_hidden_dim: int = 0, experts_held=None,
             aux_weight: float = 1e-2, latent_dim: int = 0,
+            zero_experts: int = 0, router_f32: bool = False,
             name: Optional[str] = None) -> Tensor:
         """Mixture-of-experts FFN (net-new vs reference; expert-parallel over
         the 'expert' mesh axis). Returns the main output; the load-balancing
@@ -260,7 +261,9 @@ class FFModel:
         takes the router's form (scoring, score_bias, n_group / topk_group,
         routed_scaling), a shared expert (shared_hidden_dim) and
         experts_held=(first, count), one chip's share of an
-        expert-parallel layer: ops/moe.py."""
+        expert-parallel layer, and zero_experts, router columns past
+        num_experts whose pick returns the token itself times its gate
+        (router_f32: the softmax router's matmul in float32): ops/moe.py."""
         from flexflow_tpu.ops.moe import MoE
 
         op = MoE(self, self._name("moe", name), [input], num_experts,
@@ -270,7 +273,8 @@ class FFModel:
                  score_bias=score_bias, n_group=n_group,
                  topk_group=topk_group, routed_scaling=routed_scaling,
                  shared_hidden_dim=shared_hidden_dim,
-                 experts_held=experts_held, latent_dim=latent_dim)
+                 experts_held=experts_held, latent_dim=latent_dim,
+                 zero_experts=zero_experts, router_f32=router_f32)
         outs = self._add(op)
         self._aux_tensors.append(outs[1])
         return outs[0]
@@ -330,12 +334,16 @@ class FFModel:
                          rope_theta: float = 10000.0,
                          rope_scaling: Optional[dict] = None,
                          eps: float = 1e-6, uq_init_gain: float = 1.0,
+                         q_lora_scale: float = 1.0,
+                         kv_lora_scale: float = 1.0,
                          name: Optional[str] = None) -> Tensor:
         """Causal multi-head latent self-attention with a learned top-k
         selection of the cached tokens (DeepSeek MLA + lightning indexer,
         ops/mla.py): the cache holds one latent row and one index key a
         token instead of K and V per head. `q_lora_rank=None`: no query
-        compression; `index_topk=None`: no indexer, plain causal MLA."""
+        compression; `index_topk=None`: no indexer, plain causal MLA;
+        `q_lora_scale` / `kv_lora_scale`: factors on the projected query
+        and on the normalised latent (LongCat-Flash)."""
         from flexflow_tpu.ops.mla import LatentAttention
 
         return self._add(LatentAttention(
@@ -343,7 +351,8 @@ class FFModel:
             num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
             qk_rope_head_dim, v_head_dim, index_n_heads, index_head_dim,
             index_topk, rope_theta=rope_theta, rope_scaling=rope_scaling,
-            eps=eps, uq_init_gain=uq_init_gain))
+            eps=eps, uq_init_gain=uq_init_gain, q_lora_scale=q_lora_scale,
+            kv_lora_scale=kv_lora_scale))
 
     def mamba2(self, input: Tensor, num_heads: int, head_dim: int,
                n_groups: int, state_size: int, conv_kernel: int = 4,

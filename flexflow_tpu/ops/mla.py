@@ -1,5 +1,6 @@
-"""Multi-head LATENT attention with a learned sparse selection (DeepSeek-V2/V3
-MLA, DeepSeek-V3.2's lightning indexer): `FFModel.latent_attention`.
+"""Multi-head LATENT attention, plain (DeepSeek-V2/V3 MLA, Kanana-2,
+LongCat-Flash) or with a learned sparse selection (DeepSeek-V3.2's lightning
+indexer): `FFModel.latent_attention`.
 
 What is cached per token is not K and V per head but ONE latent row shared
 by all heads, and one index key:
@@ -18,6 +19,11 @@ performance selector. `q_lora_rank=None`: no query compression, q_i = a W_Q,i
 (one `w_q` (D, H, d_nope + d_R) in place of `w_dq`, `q_norm`, `w_uq`).
 `index_topk=None`: no indexer (no index weight, no `ki` rows, S_t = every
 live position: plain causal MLA, DeepSeek-V2/V3's and Kanana-2's).
+`q_lora_scale` / `kv_lora_scale` (LongCat-Flash's `mla_scale_q_lora` /
+`mla_scale_kv_lora`: (hidden / rank)^0.5) multiply the projected query
+`cQ W_UQ` (both its parts, before the rotary) and the normalised latent
+`RMSNorm(cKV)`; the rotary key is not scaled, and the cached row holds the
+SCALED latent, so nothing that reads a cache knows of the factor.
 
 Two forms of the same numbers. EXPANDED (`forward`: predict, fit): K and V
 are built per head from the latents. Without a selection that is a dense
@@ -48,7 +54,13 @@ score digit by digit on the scores' bit patterns (8 passes of 15 counts) and
 cuts ties by position (lowest first, 4 more passes); the same two numbers a
 row drive the XLA mask here (`dsa_chosen`) and both Pallas cores.
 
-What the paged decode core READS (`paged_decode_forward`, impl `pallas`):
+What the paged decode core READS (`paged_decode_forward`, impl `pallas`)
+WITHOUT an indexer: one pool, `lat`, on the page table, and
+`mla_dense_core_pallas` (kernel `mla_paged_core_dense`) walks each slot's live
+pages in place through the table, a block of pages a turn, one online softmax
+over all heads: no gather, no list. 2 x H x (c + d_R + c) FLOPs a cached row
+of LAT x 2 B: at 64 heads 109 FLOP/B, under the v5e's ridge of 240, so
+bandwidth-bound while every slot streams its own pages. WITH an indexer:
 only the rows the selection kept. `dsa_selected` turns the two numbers into
 a list of pool rows per slot (index_topk of them, ascending, the first
 n_sel real), XLA gathers those rows of the latent pool into (slots,
@@ -278,7 +290,8 @@ class LatentAttention(Op):
                  index_topk: Optional[int] = None,
                  rope_theta: float = 10000.0,
                  rope_scaling: Optional[dict] = None, eps: float = 1e-6,
-                 uq_init_gain: float = 1.0):
+                 uq_init_gain: float = 1.0, q_lora_scale: float = 1.0,
+                 kv_lora_scale: float = 1.0):
         super().__init__(model, name, inputs)
         self.embed_dim, self.num_heads = embed_dim, num_heads
         self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
@@ -304,6 +317,9 @@ class LatentAttention(Op):
         # the seeded draw of W_UQ is this much wider than glorot's (a
         # configuration that wants peaked attention from random weights)
         self.uq_init_gain = float(uq_init_gain)
+        # factors on the projected query and on the normalised latent
+        self.q_lora_scale = float(q_lora_scale)
+        self.kv_lora_scale = float(kv_lora_scale)
         self.in_dim = inputs[0].dims[-1]
         self.lat_width = -(-(kv_lora_rank + qk_rope_head_dim) // LANES) * LANES
         self.inv_freq = yarn_inv_freq(qk_rope_head_dim, self.rope_theta,
@@ -379,8 +395,11 @@ class LatentAttention(Op):
         leaves for every head."""
         c = self.kv_lora_rank
         kv = a @ params["w_dkv"]
-        return (self._rms(kv[..., :c], params["kv_norm"]),
-                rope_rotate(kv[..., c:], pos, self.inv_freq, self.rope_amp))
+        ckv = self._rms(kv[..., :c], params["kv_norm"])
+        if self.kv_lora_scale != 1.0:
+            ckv = ckv * self.kv_lora_scale
+        return ckv, rope_rotate(kv[..., c:], pos, self.inv_freq,
+                                self.rope_amp)
 
     def _project(self, params, a, pos):
         """Everything one slab of tokens a (B, S, D) at positions pos (B, S)
@@ -395,6 +414,8 @@ class LatentAttention(Op):
             else:
                 cq = self._rms(a @ params["w_dq"], params["q_norm"])
                 q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+            if self.q_lora_scale != 1.0:
+                q = q * self.q_lora_scale
             ckv, kr = self._latents(params, a, pos)
             pad = self.lat_width - c - self.d_rope
             lat = jnp.concatenate(
@@ -561,9 +582,11 @@ class LatentAttention(Op):
             else:
                 x = self._rms(a @ params["w_dq"], params["q_norm"])
                 wq = params["w_uq"]
-            q_nope = heads(x, wq[..., :dn])
-            q_rope = rope_rotate(heads(x, wq[..., dn:]), pos, self.inv_freq,
-                                 self.rope_amp)
+            q_nope, q_rope = heads(x, wq[..., :dn]), heads(x, wq[..., dn:])
+            if self.q_lora_scale != 1.0:
+                q_nope = q_nope * self.q_lora_scale
+                q_rope = q_rope * self.q_lora_scale
+            q_rope = rope_rotate(q_rope, pos, self.inv_freq, self.rope_amp)
             ckv, kr = self._latents(params, a, pos)
             k_nope = heads(ckv, params["w_uk"])
             v = heads(ckv, params["w_uv"])
@@ -668,7 +691,11 @@ class LatentAttention(Op):
         index keys read, tokens the selection keeps, tokens it saw; and,
         given the pool's page size, the bytes the index kernel's block
         stream moves for them (each row's pages rounded up to whole
-        blocks: over `index_read_bytes` it is the stream's over-fetch)."""
+        blocks: over `index_read_bytes` it is the stream's over-fetch).
+        Nothing without an indexer: the engine's own page counts say what
+        the dense core reads."""
+        if not self.indexed:
+            return {}
         ctx = np.asarray(context, np.int64)
         key = self.index_head_dim * 2
         counts = {"index_read_bytes": int(ctx.sum()) * key,
@@ -683,36 +710,30 @@ class LatentAttention(Op):
                 (-(-ctx // block)).sum()) * block * key
         return counts
 
-    @staticmethod
-    def paged_turn_pages(cache, width: int) -> int:
-        """`MultiHeadAttention.paged_turn_pages`: 1, the core reads gathered
-        rows and no page stream (the index kernel's blocks are counted by
-        `decode_span_counts`)."""
-        return 1
+    def paged_turn_pages(self, cache, width: int) -> int:
+        """`MultiHeadAttention.paged_turn_pages`. With an indexer 1: the
+        core reads gathered rows and no page stream (the index kernel's
+        blocks are counted by `decode_span_counts`). Without one, the pages
+        a turn of the dense core's stream takes."""
+        if self.indexed:
+            return 1
+        from flexflow_tpu.ops.pallas_kernels import mla_dense_turn_pages
+
+        return mla_dense_turn_pages(width)
 
     def init_paged_cache(self, num_pages: int, page_size: int, dtype,
                          kv_dtype=None):
-        if not self.indexed:
-            # the paged pool and its decode core are built around the
-            # selection (two pools on one page table, the selected rows as
-            # a list): refused here, where an engine first asks the op for
-            # anything, not somewhere inside a kernel
-            raise NotImplementedError(
-                f"{self.name}: a paged pool for a latent attention without "
-                f"an indexer (index_topk=None) is not built: the serving "
-                f"engine's paged decode reads the lightning indexer's "
-                f"selection; use generate() (contiguous cache) or "
-                f"predict()")
+        """The pools a token's cached rows live in, all on one page table:
+        `lat`, and with an indexer `ki`."""
         sdtype, qmax = kv_storage_dtype(kv_dtype)
         if qmax is not None:
             raise NotImplementedError(
                 f"{self.name}: a quantized latent cache ({kv_dtype}) is not "
                 f"built; kv_cache_dtype native or bf16")
         store = sdtype if sdtype is not None else dtype
-        return {"lat": jnp.zeros((num_pages, page_size, self.lat_width),
-                                 store),
-                "ki": jnp.zeros((num_pages, page_size, self.index_head_dim),
-                                store)}
+        widths = {"lat": self.lat_width, "ki": self.index_head_dim}
+        return {n: jnp.zeros((num_pages, page_size, widths[n]), store)
+                for n in self._cached}
 
     def scatter_cache_tail(self, pool, cache, p0: int, pages, impl="einsum"):
         """Write a request's contiguous cache past position p0 into its own
@@ -720,7 +741,7 @@ class LatentAttention(Op):
         ps = pool["lat"].shape[1]
         out = {}
         with jax.named_scope("core"):
-            for n in ("lat", "ki"):
+            for n in self._cached:
                 x = cache[n][0, p0:]
                 pad = pages.shape[0] * ps - x.shape[0]
                 if pad:
@@ -730,26 +751,29 @@ class LatentAttention(Op):
         return out
 
     def export_page(self, cache, page):
-        return {n: cache[n][page] for n in ("lat", "ki")}
+        return {n: cache[n][page] for n in self._cached}
 
     def import_page(self, cache, page, payload):
         return {n: cache[n].at[page].set(
             jnp.asarray(payload[n]).astype(cache[n].dtype))
-            for n in ("lat", "ki")}
+            for n in self._cached}
 
     def gather_paged_kv(self, cache, pages):
         with jax.named_scope("gather"):
             return {n: cache[n][pages].reshape(1, -1, cache[n].shape[-1])
-                    for n in ("lat", "ki")}
+                    for n in self._cached}
 
     def paged_decode_forward(self, params, xs, cache, page_table, write_pos,
                              rope_pos, row_len, prompt_pad, impl=None):
         """One decode step of every slot over the paged pools: append the
         token's latent row and index key at (page_table[b, write_pos //
         ps], write_pos % ps), then score, select and attend through the
-        page tables. `pallas`: the index kernel reads its pool in place, a
-        block of 8 pages a turn (so a slot's context is streamed rounded up
-        to whole blocks: `decode_span_counts`' `index_streamed_bytes`), and
+        page tables. Without an indexer `pallas` is the dense core, every
+        live page of a slot read in place through its table
+        (`mla_dense_core_pallas`). With one: the index kernel reads its pool
+        in place, a block of 8 pages a turn (so a slot's context is streamed
+        rounded up to whole blocks: `decode_span_counts`'
+        `index_streamed_bytes`), and
         the core reads the selected latent rows, gathered; `einsum`: the
         slots' pages gathered into contiguous rows and the blocked XLA
         attention, the parity oracle."""
@@ -760,16 +784,28 @@ class LatentAttention(Op):
                 page_table, (write_pos // ps)[:, None], axis=1)[:, 0]
             offs = write_pos % ps
             cache = {n: cache[n].at[page_ids, offs].set(
-                pr[n][:, 0].astype(cache[n].dtype)) for n in ("lat", "ki")}
+                pr[n][:, 0].astype(cache[n].dtype)) for n in self._cached}
         if resolve_paged_attention_impl(impl) != "pallas":
             b = page_table.shape[0]
             with jax.named_scope("gather"):
                 rows = {n: cache[n][page_table].reshape(
-                    b, -1, cache[n].shape[-1]) for n in ("lat", "ki")}
+                    b, -1, cache[n].shape[-1]) for n in self._cached}
             return self._attend_latent(params, pr, rows, write_pos[:, None],
                                        row_len, prompt_pad), cache
         from flexflow_tpu.ops.pallas_kernels import (
-            dsa_index_scores_pallas, mla_gathered_core_pallas)
+            dsa_index_scores_pallas, mla_dense_core_pallas,
+            mla_gathered_core_pallas)
+
+        if not self.indexed:
+            q_lat = self._absorb(params, pr["q_nope"][:, 0],
+                                 pr["q_rope"][:, 0])
+            # the call says its own phase (`core`)
+            ctx = mla_dense_core_pallas(
+                q_lat, cache["lat"], page_table, write_pos, row_len,
+                prompt_pad, scale=self.scale, c=self.kv_lora_rank)
+            with jax.named_scope("out"):
+                o = jnp.einsum("bhc,chv->bhv", ctx, params["w_uv"])
+            return self._out(params, o[:, None]), cache
 
         with jax.named_scope("index"):
             scores = dsa_index_scores_pallas(
@@ -793,8 +829,8 @@ class LatentAttention(Op):
     def paged_verify_forward(self, *args, **kw):
         raise NotImplementedError(
             f"{self.name}: speculative verify over a latent cache is not "
-            f"built (the selection would run per slab position); serve "
-            f"this model without a draft")
+            f"built (the selection, or the dense core's frontier, would run "
+            f"per slab position); serve this model without a draft")
 
     # ---- strategy search -----------------------------------------------------
 

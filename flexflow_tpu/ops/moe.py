@@ -56,6 +56,21 @@ chip's share of an expert-parallel layer (its output is the shared expert
 plus ITS experts' part of the routed sum; the exchange that adds the other
 chips' parts is not in this op).
 
+`zero_experts=Z` (LongCat-Flash's zero-computation experts) widens the
+router, the selection bias and the top-k to `num_experts + Z` columns, of which
+the last Z name NO weight: a pick of one returns the token itself times its
+gate. Their gates sum into one factor a token on the op's input (the identity
+term, scope `zero`), computed where the token lives, once, and by no held
+share's experts; everything that sizes or counts expert work (both lowerings,
+`_held_assignments`, `held_rows_cap`, whose even share is N k held /
+(num_experts + Z), the aux term, `flops`) sees the real experts alone. A
+token's expert work so varies from 0 to k real picks. `router_f32` computes
+the softmax router's matmul in float32 at the highest precision, as such a
+model's published gate does (the sigmoid router always did; OLMoE's softmax
+router stays a compute-dtype matmul under the default). With zero experts the
+routing counts grow from [assignments, experts hit] by [identity picks, real
+picks] of live rows.
+
 `expert="relu2"` is the two-matrix expert `relu(x w_up)^2 w_down` (Nemotron:
 no gate matrix), on the same dropless op and both of its lowerings; the
 shared expert then has that form too. `latent_dim` (LatentMoE) puts the
@@ -145,7 +160,9 @@ HELD_ROWS_TILE = 256
 
 
 def held_rows_cap(n_tokens: int, k: int, held: int, num_experts: int) -> int:
-    """Rows of the compact form: at most all N*k of them."""
+    """Rows of the compact form: at most all N*k of them. `num_experts` is
+    the router's width (zero-computation columns included: they take their
+    share of the N*k picks and send no row anywhere)."""
     even = n_tokens * k * held / num_experts
     tiles = -(-int(HELD_ROWS_SLACK * even) // HELD_ROWS_TILE)
     return min(n_tokens * k, max(1, tiles) * HELD_ROWS_TILE)
@@ -165,11 +182,17 @@ class MoE(Op):
                  score_bias: Optional[float] = None, n_group: int = 1,
                  topk_group: int = 1, routed_scaling: float = 1.0,
                  shared_hidden_dim: int = 0, experts_held=None,
-                 latent_dim: int = 0):
+                 latent_dim: int = 0, zero_experts: int = 0,
+                 router_f32: bool = False):
         super().__init__(model, name, inputs)
         self.num_experts = num_experts
         self.hidden_dim = hidden_dim
-        self.k = min(k, num_experts)
+        # router columns past `num_experts` that name no weight: a pick of
+        # one is the token itself times its gate
+        self.zero_experts = int(zero_experts)
+        self.router_width = num_experts + self.zero_experts
+        self.router_f32 = bool(router_f32)
+        self.k = min(k, self.router_width)
         self.capacity_factor = capacity_factor
         self.aux_weight = aux_weight
         if dispatch not in ("auto", "dense", "sort"):
@@ -196,14 +219,20 @@ class MoE(Op):
         plain = (scoring == "softmax" and score_bias is None
                  and self.n_group == 1 and self.routed_scaling == 1.0
                  and not self.shared_hidden_dim and experts_held is None
-                 and not self.latent_dim and expert != "relu2")
+                 and not self.latent_dim and expert != "relu2"
+                 and not self.zero_experts and not self.router_f32)
         if not plain and (capacity_factor is not None
                           or expert not in _DROPLESS_EXPERTS):
             raise ValueError(
                 "scoring, score_bias, n_group, routed_scaling, "
-                "shared_hidden_dim, experts_held, latent_dim and "
-                "expert='relu2' belong to the dropless op "
+                "shared_hidden_dim, experts_held, latent_dim, zero_experts, "
+                "router_f32 and expert='relu2' belong to the dropless op "
                 "(capacity_factor=None, expert='swiglu' or 'relu2')")
+        if self.zero_experts < 0 or (self.zero_experts and (
+                self.n_group > 1 or self.latent_dim)):
+            raise ValueError(
+                f"zero_experts {zero_experts}: >= 0, and neither a "
+                f"group-limited router nor latent experts beside them")
         if num_experts % self.n_group or not (
                 1 <= self.topk_group <= self.n_group):
             raise ValueError(
@@ -235,7 +264,7 @@ class MoE(Op):
                 [self.inputs[0].dtype, DataType.DT_FLOAT])
 
     def weights(self) -> List[WeightSpec]:
-        E, D, F = self.num_experts, self.dim, self.hidden_dim
+        E, D, F = self.router_width, self.dim, self.hidden_dim
         L = self.expert_dim
         H = self.held_count         # the expert matrices this layer holds
         *up, down = _DROPLESS_EXPERTS.get(self.expert, ("w_in", "w_out"))
@@ -420,16 +449,19 @@ class MoE(Op):
     def _route(self, params, t):
         """The dropless op's one routing function: (scores (N, E) f32,
         top_g (N, k) f32 gates, top_e (N, k) int32 experts) over ALL
-        `num_experts`, whichever of them this layer holds.
+        `router_width` columns (the experts, whichever of them this layer
+        holds, then the zero-computation ones).
 
-        softmax: top-k of the f32 softmax of a compute-dtype matmul (OLMoE).
+        softmax: top-k of the f32 softmax of a compute-dtype matmul (OLMoE),
+        of a float32 matmul with `router_f32` (LongCat-Flash); with a
+        `score_bias` the selection runs on p + b and the gates are p's.
         sigmoid: s = sigmoid(t W_r), the matmul in f32; the selection runs on
         s' = s + score_bias and, with n_group > 1, only inside the
         `topk_group` groups whose two best s' sum highest; the gates are
         s (never s') of the chosen experts, renormalised over them and
         times `routed_scaling` (DeepSeek-V3)."""
-        E, k = self.num_experts, self.k
-        if self.scoring == "softmax":
+        E, k = self.router_width, self.k
+        if self.scoring == "softmax" and not self.router_f32:
             router = params["router"].astype(t.dtype)
             scores = jax.nn.softmax((t @ router).astype(jnp.float32),
                                     axis=-1)
@@ -437,9 +469,11 @@ class MoE(Op):
             # float32 inputs, as the published gate computes it: a bf16
             # product's rounding would flip choices at near-ties that the
             # model itself does not have
-            scores = jax.nn.sigmoid(jnp.dot(
+            logits = jnp.dot(
                 t.astype(jnp.float32), params["router"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
+                precision=jax.lax.Precision.HIGHEST)
+            scores = jax.nn.softmax(logits, axis=-1) \
+                if self.scoring == "softmax" else jax.nn.sigmoid(logits)
         sel = scores
         if self.score_bias is not None:
             sel = sel + params["score_bias"].astype(jnp.float32)
@@ -508,12 +542,25 @@ class MoE(Op):
                 y = y.astype(t.dtype) @ params["w_latent_out"].astype(t.dtype)
         if group_sizes is not None:
             group_sizes.append(sizes)
+        counts = [jnp.sum(sizes), jnp.sum(sizes > 0)]
+        if self.zero_experts:
+            with jax.named_scope("zero"):
+                # the identity term: the gates of a row's zero-computation
+                # picks, summed, times the row itself (0 for a dead row)
+                zero = top_e >= self.num_experts                # (N, k)
+                if live is not None:
+                    zero &= live[:, None]
+                y = y.astype(jnp.float32) + jnp.sum(
+                    jnp.where(zero, top_g, 0.0), axis=-1,
+                    keepdims=True) * t.astype(jnp.float32)
+                picks = jnp.sum(zero)
+                alive = N if live is None else jnp.sum(live)
+                counts += [picks, alive * k - picks]
         if routing is not None:
-            routing.append(jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
-                           .astype(jnp.int32))
+            routing.append(jnp.stack(counts).astype(jnp.int32))
         with jax.named_scope("route"):
             me = jnp.mean(gates, axis=0)
-            if E != self.num_experts:
+            if E != self.router_width:
                 me = me[lo:lo + E]
             ce = sizes.astype(jnp.float32) / N
             aux = self.aux_weight * E * jnp.sum(me * (ce / k))
@@ -546,9 +593,10 @@ class MoE(Op):
         N = top_e.shape[0]
         flat_e = top_e.reshape(-1)                          # token-major
         masked = live is not None
-        if E != self.num_experts:
-            # assignments to experts held elsewhere, like a dead row's, go
-            # to no expert here
+        if E != self.router_width:
+            # assignments to experts held elsewhere (and to the zero-
+            # computation columns, which hold nothing), like a dead row's,
+            # go to no expert here
             masked = True
             flat_e = flat_e - lo
             here = (flat_e >= 0) & (flat_e < E)
@@ -567,10 +615,10 @@ class MoE(Op):
         """Sorted rows the grouped lowering works on at a time: all N*k
         where the layer holds every expert, a held share's
         `held_rows_cap`."""
-        if self.held_count == self.num_experts:
+        if self.held_count == self.router_width:
             return n_tokens * self.k
         return held_rows_cap(n_tokens, self.k, self.held_count,
-                             self.num_experts)
+                             self.router_width)
 
     def _grouped_rows(self, params, t, top_g, order, sizes, masked):
         """y (N, D) f32 of `_experts_grouped` from the sorted order."""
@@ -740,7 +788,8 @@ class MoE(Op):
         ntokens = self.inputs[0].volume() // self.dim
         matmuls = 3 if self.expert == "swiglu" else 2
         # the share of a token's k picks that lands on the experts held here
-        routed = ntokens * self.k * self.held_count / self.num_experts
+        # (a pick of a zero-computation column costs nothing)
+        routed = ntokens * self.k * self.held_count / self.router_width
         if self.latent_dim:
             return int(2 * matmuls * (routed * self.latent_dim
                                       * self.hidden_dim + ntokens * self.dim

@@ -2597,6 +2597,127 @@ def mla_gathered_core_pallas(q_lat, rows, n_sel, lat_pages, *, scale: float,
         )(n_sel.astype(jnp.int32), q_lat, gathered)
 
 
+# ---- the DENSE latent core: no selection, every live page read in place
+#
+#   mla_paged_core_dense
+#                     a latent attention WITHOUT an indexer attends every live
+#                     row, so there is nothing to list and nothing to gather:
+#                     the grid is the slots, `_page_stream` walks each slot's
+#                     live pages through its page table (blocks of
+#                     `mla_dense_turn_pages` pages, the pages past the last
+#                     whole block one a turn: no page past the last live one
+#                     is fetched), and a turn scores the H absorbed queries
+#                     (H, W) against the block's rows in one matmul, folds it
+#                     into the online softmax and adds p @ cKV (the rows'
+#                     first c columns: K and V are one buffer). A page is
+#                     streamed once a SLOT: slots that hold the same document
+#                     each stream it (the shared-page form of the paged
+#                     kernel above is not built for this core).
+
+# pages of one turn of the dense latent core: 512 rows of 640 lanes (655 KB
+# a ring buffer in bf16) against 64-128 query rows
+_MLA_DENSE_TURN_PAGES = 4
+
+
+def mla_dense_turn_pages(width: int) -> int:
+    """Pages one turn of `mla_paged_core_dense` takes over a table `width`
+    pages wide: the largest power of two up to _MLA_DENSE_TURN_PAGES (one
+    itself) that the table holds."""
+    return min(_MLA_DENSE_TURN_PAGES, 1 << (width.bit_length() - 1))
+
+
+def _mla_dense_kernel(pt_ref, lp_ref, wp_ref, rl_ref, pp_ref, q_ref, lat_hbm,
+                      o_ref, buf, sem, cur, *, ps: int, nbuf: int, g: int,
+                      scale: float, c: int):
+    b = pl.program_id(0)
+    prime, take, fetch_next = _page_stream(
+        pt_ref, lp_ref, (lat_hbm,), (buf,), sem, cur, nbuf, g, tail=True)
+    prime(nbuf - 1)
+    q = q_ref[0]                                        # (H, W)
+    h, wdt = q.shape
+    rl, pp, wp = rl_ref[b], pp_ref[b], wp_ref[b]
+
+    def turn(pages):
+        cols = pages * ps
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+
+        def body(t, state):
+            m_prev, l_prev, acc = state
+            fetch_next(jax.lax.rem(cur[2] + nbuf - 1, nbuf))
+            got = take(pages)
+            rows = buf[got, :pages].reshape(cols, wdt).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, rows, _NT, preferred_element_type=jnp.float32) * scale
+            j = t * ps + col
+            s = jnp.where((j < rl) | ((j >= pp) & (j <= wp)), s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.dot(p.astype(rows.dtype), rows[:, :c],
+                                        preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
+
+        return body
+
+    state = (jnp.full((h, 1), NEG_INF, jnp.float32),
+             jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, c), jnp.float32))
+    first = 0
+    if g > 1:
+        block, blocks = turn(g), (lp_ref[b] + 1) // g
+        state = jax.lax.fori_loop(
+            0, blocks, lambda i, st: block(i * g, st), state)
+        first = blocks * g
+    # every slot has >= 1 live position (an inactive slot's zeros satisfy
+    # j == 0 <= write_pos == 0), so l > 0; a turn in which no column is live
+    # leaves p = 1 under m = NEG_INF, which the first live score scales away
+    _, l_fin, acc = jax.lax.fori_loop(first, lp_ref[b] + 1, turn(1), state)
+    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+
+
+# inline=True: as `moe_expert_stream_pallas`, so a program's layers share one
+# trace and one Mosaic lowering of the kernel
+@functools.partial(jax.jit, inline=True, static_argnames=("scale", "c"))
+def mla_dense_core_pallas(q_lat, lat_pages, page_table, write_pos, row_len,
+                          prompt_pad, *, scale: float, c: int):
+    """The attention core of one decode step over EVERY live row of the
+    latent pool, read in place: q_lat (B, H, W) absorbed queries, lat_pages
+    (P_pool, ps, W), page tables (B, P), the live rule's bounds (B,) (a
+    position j of a slot is live iff j < row_len or prompt_pad <= j <=
+    write_pos) -> (B, H, c): softmax over the live rows of q . latent *
+    scale, times the latents' first c columns."""
+    b, h, wdt = q_lat.shape
+    ps = lat_pages.shape[1]
+    g = mla_dense_turn_pages(page_table.shape[1])
+    nbuf = _paged_ring(g, lat_pages.dtype, (ps, wdt))
+    last = jnp.maximum(write_pos, row_len - 1) // ps
+    prefetch = [page_table.astype(jnp.int32), last.astype(jnp.int32),
+                write_pos.astype(jnp.int32), row_len.astype(jnp.int32),
+                prompt_pad.astype(jnp.int32)]
+
+    def slot_map(bi, *_):
+        return (bi, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, wdt), slot_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, c), slot_map),
+        scratch_shapes=[pltpu.VMEM((nbuf, g, ps, wdt), lat_pages.dtype),
+                        pltpu.SemaphoreType.DMA((1, nbuf)),
+                        pltpu.SMEM((3,), jnp.int32)])
+    with jax.named_scope("core"):
+        return pl.pallas_call(
+            functools.partial(_mla_dense_kernel, ps=ps, nbuf=nbuf, g=g,
+                              scale=scale, c=c),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
+            # sequential: the cursor and the ring carry over between slots
+            compiler_params=_compiler_params(("arbitrary",)),
+            interpret=_interpret(), name="mla_paged_core_dense",
+        )(*prefetch, q_lat, lat_pages)
+
+
 # ---------------------------------------------------------------------------
 # Mamba-2 decode: one token of the recurrence over a pool of per-slot states
 # ---------------------------------------------------------------------------

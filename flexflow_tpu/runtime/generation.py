@@ -173,12 +173,23 @@ class Generator:
         self.dropless_moe_ops = [
             op for op in model.ops
             if op.op_type == OperatorType.OP_MOE and op.dropless]
-        # topo index of the last attention (or recurrent-state) op: beyond
-        # it every op is per-position, so the prefill tail (lm_head
-        # included) can run on the final position only instead of the whole
-        # prompt
-        self._last_attn_idx = max(i for i, op in enumerate(model.ops)
-                                  if op in cached)
+        # the prefill TAIL: the ops that no attention (or recurrent-state)
+        # op is downstream of. Each is per-position (validated above) and
+        # feeds the logits alone, so a prefill runs it on the final position
+        # only (lm_head included) and a non-final chunk not at all. By
+        # dependency, not by place in `model.ops`: a branch that leaves the
+        # residual stream before the last cached op and rejoins it after
+        # (a shortcut-connected expert layer, models/longcat_flash.py) is
+        # tail wherever the model file lists it. In a plain decoder these
+        # are exactly the ops past the last cached one.
+        feeds_cache = set()     # tensors some cached op reads, transitively
+        for op in reversed(model.ops):
+            if op in cached or any(t in feeds_cache for t in op.outputs):
+                feeds_cache.update(op.inputs)
+        self._tail_ops = {
+            op for op in model.ops
+            if not isinstance(op, InputOp) and op not in cached
+            and not any(t in feeds_cache for t in op.outputs)}
 
     # ---- weight-only quantization (int8 / fp8) -----------------------------
 
@@ -271,9 +282,9 @@ class Generator:
         """Interpret the graph on a (B, S) token slab. pos=None means
         prefill (positions 0..S-1, fills cache); otherwise S == 1 and pos
         is the traced cache slot of the token. last_only=True narrows the
-        prefill tail: past the last attention op every op is per-position
-        (validated in __init__), so only the final position flows through
-        the lm_head — O(1/S) of its FLOPs and no (B, S, V) logits
+        prefill tail (`_tail_ops`: every op no cached op depends on, each
+        per-position, validated in __init__), so only the final position
+        flows through it and the lm_head — O(1/S) of its FLOPs and no (B, S, V) logits
         materialization; with `row_lengths` (ragged right-padded prompts)
         the tail gathers each row's own last valid position instead of
         column -1, and decode steps get per-row RoPE positions + a pad-
@@ -292,16 +303,17 @@ class Generator:
         s_full = tokens.shape[1]
         vals = {self.token_input.outputs[0]: tokens}
         new_caches = {}
-        for idx, op in enumerate(self.model.ops):
+        for op in self.model.ops:
             if isinstance(op, InputOp):
                 continue
-            if skip_tail and idx > self._last_attn_idx:
+            tail = op in self._tail_ops
+            if skip_tail and tail:
                 # non-final prefill chunk: only the caches matter; the
-                # post-attention tail (final norm + lm_head) is unused
-                return None, new_caches
+                # tail (final norm + lm_head, a last layer's shortcut
+                # branch) is unused
+                continue
             xs = [vals[t] for t in op.inputs]
-            if (last_only and pos is None and idx > self._last_attn_idx
-                    and s_full > 1):
+            if last_only and pos is None and tail and s_full > 1:
                 if row_lengths is None:
                     xs = [x[:, -1:] if (x.ndim >= 2 and x.shape[1] == s_full)
                           else x for x in xs]
@@ -433,6 +445,8 @@ class Generator:
                                           **kwargs)
             for i, t in enumerate(op.outputs):
                 vals[t] = outs[i]
+        if skip_tail:
+            return None, new_caches
         return vals[self.model._final_tensor], new_caches
 
     @staticmethod

@@ -842,6 +842,15 @@ class ServingEngine:
         # only): assignments, experts with at least one row
         self._moe_assignments = 0
         self._moe_experts_hit = 0
+        # of a model with zero-computation experts (ops/moe.py
+        # `zero_experts`), counted on the device beside them: the live
+        # rows' picks of an identity column and of a real expert (held
+        # here or elsewhere); the real picks that landed on a held expert
+        # are the assignments
+        self._moe_counts_picks = any(
+            op.zero_experts for op in self.gen.dropless_moe_ops)
+        self._moe_zero_picks = 0
+        self._moe_real_picks = 0
         # {program key: 'streamed' | 'grouped' of each dropless MoE call of
         # the program, in trace order}: a static fact of the program
         # (ops/moe.py `dropless_lowering`), collected by a list of the
@@ -1461,7 +1470,8 @@ class ServingEngine:
     def _routing_sum(routing, expert_rows=(), static_rows=None):
         """The extra trailing output of a serve program whose model has
         dropless MoE ops: their int32 (2,) routing counts [assignments,
-        experts hit] summed over the ops one walk ran.
+        experts hit] (and [identity picks, real picks] of a model with
+        zero-computation experts) summed over the ops one walk ran.
         Nothing for any other model, whose programs are unchanged.
         A prefill also collects `expert_rows`: what is counted on the
         device (a held share's passes) rides as a third entry, what is
@@ -1469,7 +1479,11 @@ class ServingEngine:
         program of a model that holds every expert is the one it was."""
         if not routing:
             return ()
-        counts = sum(routing)
+        # an op with zero-computation experts counts two picks more
+        width = max(r.shape[0] for r in routing)
+        counts = sum(r if r.shape[0] == width
+                     else jnp.pad(r, (0, width - r.shape[0]))
+                     for r in routing)
         static = [r for r in expert_rows if isinstance(r, int)]
         if static_rows is not None:
             static_rows[:] = static     # the same again if traced again
@@ -2281,6 +2295,10 @@ class ServingEngine:
                 if routed:
                     # a held share's passes are counted on the device
                     assigned, hit, *counted = (int(v) for v in routed[0])
+                    if self._moe_counts_picks:
+                        # a prefill's picks are not summed: the counters
+                        # are the decode dispatches'
+                        counted = counted[2:]
                     rows += sum(counted)
                     psp.annotate(assignments=assigned, experts_hit=hit,
                                  expert_rows=rows)
@@ -2697,7 +2715,7 @@ class ServingEngine:
         kernel exists to remove). ``frontier`` is (slots, steps): the
         write position of each attention pass the dispatch makes.
         Returns three byte counts of ONE layer over those passes (pages x
-        page_size x kv_bytes_per_token): what the active slots ATTEND, a
+        page_size x kv_bytes_per_token), and the pages of the second: what the active slots ATTEND, a
         page once for every slot that holds it (``kv_attended_bytes``);
         what must be READ for that, each distinct page once a pass
         (``kv_read_bytes``: ``held_again`` pages of the slots' prompts are
@@ -2724,7 +2742,8 @@ class ServingEngine:
         self._kv_attended_bytes += kv_attended
         self._kv_read_bytes += kv_read
         self._kv_streamed_bytes += kv_streamed
-        return kv_attended, kv_read, kv_streamed
+        return (kv_attended, kv_read, kv_streamed,
+                attended - steps * held_again)
 
     def _shared_pages_plan(self):
         """What the live slots' page tables say of pages held by more than
@@ -2807,7 +2826,8 @@ class ServingEngine:
             arrays, groups, saved, held_again = self._shared_pages_plan()
             self._shared_groups += groups
             self._shared_pages_saved += k * saved
-            kv_attended, kv_read, kv_streamed = self._note_pages_touched(
+            (kv_attended, kv_read, kv_streamed,
+             distinct) = self._note_pages_touched(
                 write_pos[:, None] + np.arange(k), budget, held_again, saved)
             attn = collections.Counter()
             if self._counting_attn_ops:
@@ -2848,6 +2868,11 @@ class ServingEngine:
                         kv_read_bytes=kv_read, kv_streamed_bytes=kv_streamed,
                         kv_attended_bytes=kv_attended,
                         shared_groups=groups, shared_pages_saved=k * saved,
+                        # the DISTINCT live pages of its steps: what a
+                        # stream that fetched a shared page once would read
+                        live_pages_distinct=distinct,
+                        live_row_tokens=int(
+                            (rope_pos + 1)[self.active].sum()),
                         paged_turn_pages=self._paged_turn_pages,
                         program=program_name(key), **attn) as sp:
             toks, oks, self.kv.pool, *routed = self._compiled_call(
@@ -2863,10 +2888,15 @@ class ServingEngine:
         with self._span("record_tokens") as sp:
             if routed:
                 # counted on the device, over live rows only
-                assigned, hit = (int(v) for v in routed[0])
+                assigned, hit, *picks = (int(v) for v in routed[0])
                 self._moe_assignments += assigned
                 self._moe_experts_hit += hit
                 sp.annotate(assignments=assigned, experts_hit=hit)
+                if picks:
+                    self._moe_zero_picks += picks[0]
+                    self._moe_real_picks += picks[1]
+                    sp.annotate(zero_picks=picks[0], real_picks=picks[1],
+                                held_picks=assigned)
             kept0 = self._tokens_emitted
             for slot in range(self.slots):
                 for t in range(k):
@@ -3430,6 +3460,13 @@ class ServingEngine:
             **self._attn_counts,
             "moe_assignments": self._moe_assignments,
             "moe_experts_hit": self._moe_experts_hit,
+            # a model with zero-computation experts: its decode dispatches'
+            # picks of an identity column, of a real expert, and the real
+            # picks that landed on an expert held here
+            **({"moe_zero_picks": self._moe_zero_picks,
+                "moe_real_picks": self._moe_real_picks,
+                "moe_held_picks": self._moe_assignments}
+               if self._moe_counts_picks else {}),
             # decode and run-to-completion prefill dispatches whose
             # program's MoE calls all took the expert-stream kernel
             "moe_streamed_dispatches": self._moe_streamed_dispatches,

@@ -482,9 +482,10 @@ def test_deepseek_v32_graph_is_what_it_was():
         out[1, -1, :4], [-0.21993, 1.10307, 0.64815, -0.77445], atol=1e-5)
 
 
-def test_generate_runs_without_an_indexer_and_the_paged_pool_refuses():
-    """`generate()` (the contiguous latent cache) serves the layer; the
-    serving engine's paged pool is built around the selection and says so."""
+def test_generate_and_the_paged_pool_serve_the_layer_without_an_indexer():
+    """`generate()` (the contiguous latent cache) serves the layer, and so
+    does the serving engine: a latent attention without an indexer gets a
+    pool of `lat` alone (refused until PR 53) and the dense core reads it."""
     ff, _ = build(batch=1, seq=32)
     prompt = np.random.RandomState(1).randint(1, VOCAB, (1, 8)) \
         .astype(np.int32)
@@ -495,10 +496,12 @@ def test_generate_runs_without_an_indexer_and_the_paged_pool_refuses():
     for t in range(8, 12):
         assert want[t - 1].max() - want[t - 1, full[t]] < 1e-4
     attn = ff.get_op_by_name("attn_0")
-    with pytest.raises(NotImplementedError, match="without an indexer"):
-        attn.init_paged_cache(4, 8, jnp.float32)
-    with pytest.raises(NotImplementedError, match="without an indexer"):
-        ff.make_serving_engine(max_seq_len=32, serve_slots=2)
+    pool = attn.init_paged_cache(4, 8, jnp.float32)
+    assert set(pool) == {"lat"} and pool["lat"].shape == (4, 8, attn.lat_width)
+    eng = ff.make_serving_engine(max_seq_len=32, serve_slots=2,
+                                 kv_page_size=8)
+    req = eng.run([prompt[0]], max_new_tokens=4)[0]
+    assert req.state == "done" and list(req.tokens) == list(full[8:12])
 
 
 # ---- one chip's share of an expert layer, under a gradient -------------------

@@ -309,9 +309,10 @@ def test_engine_counts_what_the_turns_fetch(ff, monkeypatch, turn):
     budget = np.asarray([32, 32])
     # two steps a slot: 2 and 3 live pages, 6 and 8 (the budget's clamp)
     frontier = np.asarray([[7, 8], [22, 40]])
-    attended, read, streamed = eng._note_pages_touched(frontier, budget)
+    attended, read, streamed, distinct = eng._note_pages_touched(frontier,
+                                                                 budget)
     per_page = eng.page_size * eng.stats()["kv_bytes_per_token"]
-    assert read == (2 + 3 + 6 + 8) * per_page
+    assert read == distinct * per_page == (2 + 3 + 6 + 8) * per_page
     assert streamed == read == attended
     st = eng.stats()
     assert (st["kv_read_bytes"], st["kv_streamed_bytes"],
@@ -322,7 +323,7 @@ def test_engine_counts_what_the_turns_fetch(ff, monkeypatch, turn):
     for saved in (2, 0):
         assert eng._note_pages_touched(frontier, budget, 2, saved) == (
             attended, attended - 2 * 2 * per_page,
-            attended - 2 * saved * per_page)
+            attended - 2 * saved * per_page, distinct - 2 * 2)
 
 
 FLASH_SEQ, FLASH_HEADS, FLASH_DIM = 256, 2, 16
